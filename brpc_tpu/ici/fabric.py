@@ -46,10 +46,22 @@ from typing import Any, Dict, Optional, Tuple
 from ..butil.flags import get_flag
 from ..butil.logging_util import LOG
 
+def _new_domain_token() -> bytes:
+    """16 random bytes with no ``@`` in them: a domain id is
+    ``token[@address]`` and :func:`domain_token` splits at the first
+    ``@``, so a token that contained one (6% of raw draws) could never
+    match itself and the whole process silently fell back to
+    host-staged attachments."""
+    while True:
+        tok = os.urandom(16)
+        if b"@" not in tok:
+            return tok
+
+
 # 16-byte process-unique token: same token on both ends of a connection
 # ⇒ both ends share this process's JAX runtime (loopback / same host
 # single-controller), so the in-process fabric can bridge them.
-_LOCAL_DOMAIN = os.urandom(16)
+_LOCAL_DOMAIN = _new_domain_token()
 
 
 _domain_cache: Optional[bytes] = None

@@ -181,14 +181,10 @@ class PrefillService(LMService):
     def _ensure_prefill(self):
         with self._prefill_lock:
             if self._prefill_j is None:
-                import functools
-
-                import jax
-
-                from ..models.transformer_lm import make_decode
+                from ..models.transformer_lm import (jit_with_params,
+                                                     make_decode)
                 prefill, _step = make_decode(self.cfg)
-                self._prefill_j = jax.jit(
-                    functools.partial(prefill, self.params))
+                self._prefill_j = jit_with_params(prefill, self.params)
             return self._prefill_j
 
     def Decode(self, cntl, request):

@@ -515,11 +515,10 @@ class ContinuousBatcher:
         run inside an engine loop's batched GIL entry)."""
         if self._prefill is not None and self._cache is not None:
             return
-        import functools
-
         import jax
 
-        from .transformer_lm import empty_batch_cache, make_batch_decode
+        from .transformer_lm import (empty_batch_cache, jit_with_params,
+                                     make_batch_decode)
 
         if self.paged:
             self._ensure_paged_engine()
@@ -527,13 +526,13 @@ class ContinuousBatcher:
         if self._prefill is None:
             prefill, step, chunk_step = make_batch_decode(
                 self.cfg, chunk=self._chunk_w)
-            self._prefill = jax.jit(functools.partial(prefill,
-                                                      self.params))
-            self._step = jax.jit(functools.partial(step, self.params),
-                                 donate_argnums=(0,))
-            self._chunk_j = jax.jit(
-                functools.partial(chunk_step, self.params),
-                donate_argnums=(0,))
+            # weights are ARGUMENTS of every program, bound outside
+            # the jit (jit_with_params) — never closure constants
+            self._prefill = jit_with_params(prefill, self.params)
+            self._step = jit_with_params(step, self.params,
+                                         donate_argnums=(0,))
+            self._chunk_j = jit_with_params(chunk_step, self.params,
+                                            donate_argnums=(0,))
             self._insert = jax.jit(_contig_insert(self.cfg),
                                    donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
@@ -544,15 +543,14 @@ class ContinuousBatcher:
         """Paged-mode engine build: the shared page pools, the block-
         paged step, the page-granular I/O programs, and the allocator /
         prefix-cache / host-tier triple from ``kv.pages``."""
-        import functools
-
         import jax
         import jax.numpy as jnp
 
         from ..kv.pages import (HostPagePool, PageAllocator,
                                 PrefixCache)
         from .transformer_lm import (empty_batch_cache,
-                                     empty_paged_cache, make_paged_io,
+                                     empty_paged_cache, jit_with_params,
+                                     make_paged_io,
                                      make_batch_decode,
                                      make_paged_batch_decode,
                                      make_paged_spec_verify,
@@ -560,18 +558,16 @@ class ContinuousBatcher:
 
         if self._prefill is None:
             prefill, step = make_paged_batch_decode(self.cfg, self.page)
-            self._prefill = jax.jit(functools.partial(prefill,
-                                                      self.params))
-            self._step = jax.jit(functools.partial(step, self.params),
-                                 donate_argnums=(0,))
+            self._prefill = jit_with_params(prefill, self.params)
+            self._step = jit_with_params(step, self.params,
+                                         donate_argnums=(0,))
             gather, scatter, insert, chunk_prefill = make_paged_io(
                 self.cfg, self.page, chunk=self._chunk_w)
             self._gather_j = jax.jit(gather)
             self._scatter_j = jax.jit(scatter, donate_argnums=(0,))
             self._insert = jax.jit(insert, donate_argnums=(0,))
-            self._chunk_j = jax.jit(
-                functools.partial(chunk_prefill, self.params),
-                donate_argnums=(0,))
+            self._chunk_j = jit_with_params(chunk_prefill, self.params,
+                                            donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
             if self.spec_k > 0:
                 # draft engine: the SMALL model runs k cheap
@@ -581,17 +577,16 @@ class ContinuousBatcher:
                 # steps len = L + k, the target accepted m, so the
                 # draft keeps rows for L..L+m and rewinds k-1-m.
                 d_prefill, d_step = make_batch_decode(self.cfg)
-                self._d_prefill = jax.jit(functools.partial(
-                    d_prefill, self.draft_params))
-                self._d_step = jax.jit(functools.partial(
-                    d_step, self.draft_params), donate_argnums=(0,))
+                self._d_prefill = jit_with_params(d_prefill,
+                                                  self.draft_params)
+                self._d_step = jit_with_params(d_step, self.draft_params,
+                                               donate_argnums=(0,))
                 self._d_insert = jax.jit(_contig_insert(self.cfg),
                                          donate_argnums=(0,))
                 verify = make_paged_spec_verify(self.cfg, self.page,
                                                 self.spec_k + 1)
-                self._verify_j = jax.jit(
-                    functools.partial(verify, self.params),
-                    donate_argnums=(0,))
+                self._verify_j = jit_with_params(verify, self.params,
+                                                 donate_argnums=(0,))
                 k = self.spec_k
 
                 def _d_sync(cache, m, active):

@@ -114,6 +114,26 @@ def init_params(rng, cfg: LMConfig) -> Dict[str, Any]:
     return params
 
 
+def jit_with_params(fn, params, donate_argnums=()):
+    """``jax.jit`` a ``fn(params, *args)`` program and bind ``params``
+    OUTSIDE the jit, returning a ``functools.partial`` callers invoke
+    as ``f(*args)`` — the one way serving code holds a compiled
+    program.  The weights enter the executable as ARGUMENTS: an array
+    closed over inside ``jit`` lowers to a ``stablehlo.constant``, so
+    every program (each prefill bucket, the step, the chunk slice)
+    would embed its own copy of the weights — gigabyte modules, one
+    HBM copy per executable, and a compile-cache key that hashes the
+    weight values.  ``donate_argnums`` index ``*args`` (the bound
+    params are never donated).  ``.func`` is the jitted program and
+    ``.args[0]`` the params, for callers that lower it."""
+    import functools
+
+    import jax
+
+    fn_j = jax.jit(fn, donate_argnums=tuple(i + 1 for i in donate_argnums))
+    return functools.partial(fn_j, params)
+
+
 def _rmsnorm(x, g):
     import jax.numpy as jnp
     return x * g / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
@@ -1005,11 +1025,10 @@ def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
 def make_decode_loop(cfg: LMConfig, steps: int):
     """Greedy generation as ONE compiled program: ``lax.scan`` feeds the
     argmax token back through ``decode_step`` for ``steps`` tokens, so a
-    whole generation burst costs a single device dispatch.  This is the
-    serving shape for dispatch-dominated runtimes (a per-token program
-    pays the host/tunnel round trip per TOKEN; the scan pays it per
-    BURST) and the honest harness for weight-streaming measurements —
-    per-token time becomes pure device time.
+    whole generation burst costs a single device dispatch (a per-token
+    program pays the host round trip per TOKEN; the scan pays it per
+    BURST) — the harness for weight-streaming measurements, where
+    per-token time is device time.
 
     Returns (prefill, loop) where loop(params, cache, token) ->
     (cache, tokens (steps, b))."""
@@ -1040,15 +1059,12 @@ def make_generator(cfg: LMConfig, params):
     greedy; > 0 samples and REQUIRES an rng key (each call should pass
     a fresh one).  The decode step donates the cache for in-place
     updates."""
-    import functools as _ft
-
     import jax
     import jax.numpy as jnp
 
     prefill, decode_step = make_decode(cfg)
-    prefill_j = jax.jit(prefill)
-    step_j = jax.jit(_ft.partial(decode_step, params),
-                     donate_argnums=(0,))
+    prefill_j = jit_with_params(prefill, params)
+    step_j = jit_with_params(decode_step, params, donate_argnums=(0,))
 
     def pick(logits, temperature, rng):
         if temperature <= 0.0:
@@ -1061,7 +1077,7 @@ def make_generator(cfg: LMConfig, params):
         """temperature 0 = greedy (deterministic); > 0 samples from the
         softmax at that temperature (pass ``rng`` for reproducibility)."""
         _validate_gen_args(cfg, prompt_ids, max_new, temperature, rng)
-        cache, logits = prefill_j(params, prompt_ids)
+        cache, logits = prefill_j(prompt_ids)
         out = []
         for i in range(max_new):
             if temperature > 0.0:
@@ -1097,9 +1113,9 @@ def make_scan_generator(cfg: LMConfig, params):
     the host dispatches twice per request instead of once per token.
 
     Single-stream decode at small model sizes is dispatch-bound (each
-    per-token program launch costs more than its compute); scanning the
-    steps moved the measured rate from ~200 to ~530 tok/s on the test
-    chip.  One program compiles per (batch, prompt_len, max_new,
+    per-token program launch costs more than its compute; round 5,
+    earlier set-up: ~200 -> ~530 tok/s, not measured on the current
+    chip).  One program compiles per (batch, prompt_len, max_new,
     sampled?) tuple — serving paths should bucket ``max_new``
     (LMService rounds up to the next power of two and slices); the
     greedy specialization carries no sampling ops at all.
@@ -1113,8 +1129,9 @@ def make_scan_generator(cfg: LMConfig, params):
 
     prefill, decode_step = make_decode(cfg)
 
-    @_ft.partial(jax.jit, static_argnums=(1, 2))
-    def run(prompt_ids, max_new, sample, temperature, rng):
+    # params is an ARGUMENT of the program (see jit_with_params)
+    @_ft.partial(jax.jit, static_argnums=(2, 3))
+    def run(params, prompt_ids, max_new, sample, temperature, rng):
         cache, logits = prefill(params, prompt_ids)
 
         def pick(logits, sub):
@@ -1154,9 +1171,10 @@ def make_scan_generator(cfg: LMConfig, params):
         sample = temperature > 0.0
         if rng is None:
             rng = jax.random.PRNGKey(0)   # unused on the greedy path
-        return run(jnp.asarray(prompt_ids), int(max_new), sample,
+        return run(params, jnp.asarray(prompt_ids), int(max_new), sample,
                    jnp.float32(temperature), rng)
 
+    gen.program = run        # the jitted program, for callers that lower it
     return gen
 
 

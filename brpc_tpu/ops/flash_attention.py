@@ -23,9 +23,12 @@ playbook (/opt/skills/guides/pallas_guide.md):
   cotangents and dd are zero (they do attend real keys forward, but the
   rows are sliced off and contribute nothing backward).  The lse/dd
   blocks use a 1-wide lane (legal: equal to the array's last dim —
-  verified compiling and running on real TPU hardware);
-- ``interpret=True`` automatically off-TPU, so the same code paths are
-  unit-tested on the CPU mesh.
+  forward and both backward kernels compile and run on the v5e under
+  JAX 0.9 / libtpu 0.0.34 with float32 and bf16 inputs:
+  ``chip_smoke.py``'s kernel stage);
+- ``interpret=True`` automatically on the cpu backend, so the same code
+  paths are unit-tested on the CPU mesh; any other non-TPU backend is
+  an error.
 """
 
 from __future__ import annotations
@@ -118,9 +121,10 @@ def _fwd_kernel(*refs, scale: float, causal: bool, bq: int, bk: int,
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
-    import jax
-    return jax.default_backend() != "tpu" if interpret is None \
-        else interpret
+    """None = compile on the TPU, interpret on the cpu backend the
+    tests force; any other backend raises (``device_ops._on_tpu``)."""
+    from .device_ops import _on_tpu
+    return (not _on_tpu()) if interpret is None else interpret
 
 
 def _make_prep(s_pad: int, d_pad: int, s: int, d: int):
@@ -187,11 +191,12 @@ def _block_geometry(s: int, d: int, block_q, block_k,
                     causal: bool = False):
     d_pad = _ceil_to(max(d, 1), 128)
     if block_q is None or block_k is None:
-        # measured on v5e (post bf16-MXU-input rework): 1024/1024 is
-        # fastest everywhere the kernel is actually dispatched (the
-        # auto impl uses dense below 2k) — bigger blocks amortize the
-        # per-block scratch round trips, and fp32 scores stay within
-        # the 16MB VMEM at 1024^2
+        # round 5, earlier set-up (not re-timed on the current chip):
+        # 1024/1024 was fastest everywhere the kernel is actually
+        # dispatched (the auto impl uses dense below 2k) — bigger
+        # blocks amortize the per-block scratch round trips.  fp32
+        # scores at 1024^2 fit the 16MB scoped VMEM for float32 inputs
+        # too (compiled and run by chip_smoke.py); 2048^2 does not
         auto = 1024 if s >= 2048 else 256
         block_q = auto if block_q is None else block_q
         block_k = auto if block_k is None else block_k
@@ -585,18 +590,20 @@ def flash_attention(q, k, v, causal: bool = False,
 
     Forward AND backward run fused Pallas kernels (interpret mode
     off-TPU) — O(seq) memory in both directions.  ``block_q``/
-    ``block_k`` default to None = auto (256 for short context, 512 from
-    4k tokens — measured on v5e); pass explicit sizes to override."""
+    ``block_k`` default to None = auto (``_block_geometry``: 256 for
+    short context, 1024 from 2k tokens); pass explicit sizes to
+    override."""
     out, _ = _pallas_forward(q, k, v, causal, block_q, block_k, interpret)
     return out
 
 
-# Measured crossover on the v5e-class chip (bench.py device-compute
-# section): at 2k tokens one XLA-fused einsum→softmax→einsum chain is
-# on par with or ahead of the kernel's block pipeline (0.8-1.3x), while
-# from ~4k the O(s) memory + streaming K/V blocks win decisively (2-2.6x
-# at 16k).  Dense also costs O(s^2) activation memory, so the crossover
-# stays low enough that the scores tensor is cheap.
+# Crossover from round 5's earlier set-up, not re-measured on the
+# current chip (bench.py device-compute section): at 2k tokens one
+# XLA-fused einsum→softmax→einsum chain was on par with or ahead of the
+# kernel's block pipeline (0.8-1.3x), while from ~4k the O(s) memory +
+# streaming K/V blocks won (2-2.6x at 16k).  Dense also costs O(s^2)
+# activation memory, so the crossover stays low enough that the scores
+# tensor is cheap.
 DENSE_FLASH_CROSSOVER = 2048
 
 
@@ -632,9 +639,9 @@ def attention(q, k, v, causal: bool = False, impl: str = "auto",
     ``impl="dense"``/``"flash"`` force.  Shapes are static under jit,
     so the choice is made at trace time: no runtime branching."""
     if impl == "auto":
-        import jax
+        from .device_ops import _on_tpu
         impl = "flash" if (q.shape[1] >= DENSE_FLASH_CROSSOVER
-                           and jax.default_backend() == "tpu") else "dense"
+                           and _on_tpu()) else "dense"
     if impl == "dense":
         return dense_attention(q, k, v, causal)
     if impl == "flash":

@@ -46,16 +46,9 @@ def _jax_mod():
 
 
 def _shard_map(jax):
-    """jax.shard_map (0.8+) or the experimental fallback; the VMA /
-    replication check is off because collective outputs (psum/all_gather)
-    are intentionally replicated across the axis."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa
-    try:
-        return functools.partial(sm, check_vma=False)
-    except TypeError:                                    # older signature
-        return functools.partial(sm, check_rep=False)
+    """``jax.shard_map`` with the VMA check off: collective outputs
+    (psum/all_gather) are intentionally replicated across the axis."""
+    return functools.partial(jax.shard_map, check_vma=False)
 
 
 def default_mesh(axis_name: str = "ici", devices=None):

@@ -48,7 +48,7 @@ WATCHED_RATIOS = (
     "cntl_vs_raw_gap",
     # multi-core engine (ISSUE 11): qps(N)/(N*qps(1)) medians over
     # paired interleaved rounds — phase-immune like the other ratios.
-    # NOTE the 1-core caveat (PERF §14): the hardware ceiling is ~1/N
+    # NOTE the 1-core caveat (PERF_HISTORY §14): the hardware ceiling is ~1/N
     # there, so the recorded baseline, not an absolute bar, is the gate
     "loop_scaling_efficiency",
     "loop_scaling_efficiency_4loop",
@@ -95,7 +95,7 @@ RECORDED_BASELINE = {
     "sweep_64b_pipelined_4loop_p99_us": 460.8,
     # ISSUE 12 operability keys (session box, 2026-08): the victims'
     # p99 during a full 3-replica roll, and the 10k-idle-conn RSS
-    # probe (client+server halves in one process — PERF §15)
+    # probe (client+server halves in one process — PERF_HISTORY §15)
     "drain_p99_victim_ms": 1.83,
     "conns_10k_rss_mb": 31.6,
     # ISSUE 13 streaming-lane keys (session box, 2026-08): c=64

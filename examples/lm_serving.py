@@ -22,7 +22,9 @@ def main():
                                             pack_generate_request,
                                             unpack_generated)
     from brpc_tpu.server import Server
+    from brpc_tpu.utils.compile_cache import enable_compile_cache
 
+    print("compile cache:", enable_compile_cache())
     srv = Server()
     srv.add_service(LMService(), name="LM")
     assert srv.start("127.0.0.1:0") == 0

@@ -28,7 +28,9 @@ def main():
 
     from brpc_tpu.models import (LMConfig, batch_specs, init_params,
                                  make_forward, make_train_step, param_specs)
+    from brpc_tpu.utils.compile_cache import enable_compile_cache
 
+    print("compile cache:", enable_compile_cache())
     n = len(jax.devices())
     tp = 2 if n % 2 == 0 else 1
     dp = n // tp

@@ -62,6 +62,19 @@ def test_descriptor_codec_roundtrip():
     assert decode_descriptor(d) == (KIND_INLINE, 0, 8, "int8", (), b"")
 
 
+def test_domain_token_never_contains_the_separator(monkeypatch):
+    """A domain id is ``token[@address]`` split at the first ``@``: a
+    random token holding one could never match itself (the whole
+    process fell back to host staging, ~6% of processes)."""
+    from brpc_tpu.ici import fabric
+
+    draws = iter([b"ab@" + bytes(13), bytes(range(65, 81))])
+    monkeypatch.setattr(fabric.os, "urandom", lambda n: next(draws))
+    assert fabric._new_domain_token() == bytes(range(65, 81))
+    monkeypatch.undo()
+    assert fabric.in_process_fabric().can_reach(fabric.local_domain_id())
+
+
 def test_in_process_fabric_post_redeem_release():
     f = InProcessFabric()
     x = jnp.ones((128,), jnp.float32)
