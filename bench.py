@@ -1524,114 +1524,6 @@ def bench_slo_sched(extra: dict) -> None:
             statistics.median(vic_off) / statistics.median(vic_on), 3)
 
 
-def bench_lm_telemetry(extra: dict) -> None:
-    """§20 inference-plane observability (ISSUE 18): the observer
-    effect of the serving-plane telemetry on the batcher step loop.
-
-    - ``lm_telemetry_overhead_pct``: decode tokens/s with the
-      ``lm_telemetry`` flag ON (per-phase histogram samples, session
-      timelines, SLO verdicts) vs OFF (the ``_live[0]`` branch only) on
-      ONE paged+chunked batcher — paired interleaved A/B with
-      alternating order and the MEDIAN per-round overhead reported,
-      methodology of ``native_telemetry_overhead_pct``.
-    - ``lm_telemetry_ab_noise_pct``: the CONTROL pair (OFF vs OFF,
-      same methodology) — this box's A/B noise floor; the overhead key
-      is only meaningful next to it.
-    - ``lm_telemetry_within_noise``: the perf_guard gate — 1.0 when
-      the measured overhead sits within the control noise (2x margin,
-      1pp floor: sub-percent jitter on a quiet box must not fail the
-      build), else 0.0.  The design contract is ZERO locks/allocs per
-      sample, so the honest claim is "indistinguishable from noise",
-      not a hard pct bar.
-    """
-    import jax
-    import numpy as np
-
-    from brpc_tpu.butil.flags import set_flag
-    from brpc_tpu.models import lm_telemetry as lmt
-    from brpc_tpu.models.lm_service import ContinuousBatcher
-    from brpc_tpu.models.transformer_lm import LMConfig, init_params
-    from brpc_tpu.kv import pages as kv_pages
-    from brpc_tpu.streaming import StreamOptions
-
-    class Rec:
-        def __init__(self):
-            self.closed = False
-            self.close_reason = None
-            self.n = 0
-            self.id = 0
-            self._native_tx = None
-            self.options = StreamOptions()
-
-        def write(self, data):
-            self.n += 1
-            return 0
-
-        def close(self, reason=None):
-            self.closed = True
-            self.close_reason = reason
-
-    def wait(pred, timeout=120.0):
-        deadline = time.perf_counter() + timeout
-        while not pred() and time.perf_counter() < deadline:
-            time.sleep(0.001)
-        return pred()
-
-    # paged + chunked so every phase site is live (prefix lookup, page
-    # alloc, chunk slices, decode rounds, stream emits) — the arm with
-    # telemetry ON pays the FULL per-sample cost, not a subset
-    cfg = LMConfig(vocab=256, dim=64, heads=4, depth=2, max_seq=96,
-                   remat=False)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    kv_pages._reset_for_tests()
-    bat = ContinuousBatcher(cfg, params, slots=8, paged=True, page=8,
-                            prefill_chunk_tokens=8)
-    prompts = [(np.arange(12, dtype=np.int32) * (3 + i)) % cfg.vocab
-               for i in range(6)]
-    MAX_NEW = 32
-
-    def phase(tel_on: bool) -> float:
-        set_flag("lm_telemetry", tel_on)
-        recs = []
-        t0 = time.perf_counter()
-        for p in prompts:
-            r = Rec()
-            recs.append(r)
-            bat.join(r, p, MAX_NEW)
-        if not wait(lambda: all(r.closed for r in recs)):
-            raise RuntimeError("telemetry-bench sessions never closed")
-        dt = time.perf_counter() - t0
-        return sum(r.n for r in recs) / dt
-
-    def paired_ab(a_on: bool, rounds: int = 5) -> float:
-        """Median per-round (B - A)/B pct, order alternated; arm B is
-        always telemetry-OFF."""
-        pcts = []
-        for r in range(rounds):
-            if r % 2 == 0:
-                qa = phase(a_on)
-                qb = phase(False)
-            else:
-                qb = phase(False)
-                qa = phase(a_on)
-            if qb > 0:
-                pcts.append((qb - qa) / qb * 100)
-        pcts.sort()
-        return round(pcts[len(pcts) // 2], 2) if pcts else 0.0
-
-    try:
-        phase(True)                       # warm prefill/step programs
-        phase(False)
-        pct = paired_ab(True)             # on vs off
-        noise = paired_ab(False)          # off vs off: the noise floor
-        extra["lm_telemetry_overhead_pct"] = pct
-        extra["lm_telemetry_ab_noise_pct"] = noise
-        extra["lm_telemetry_within_noise"] = \
-            1.0 if pct <= max(2.0 * abs(noise), 1.0) else 0.0
-    finally:
-        set_flag("lm_telemetry", True)
-
-
 def bench_fleet_obs(extra: dict) -> None:
     """§21 fleet observability (ISSUE 19): propagation latency of the
     load-report plane and its observer effect on a serving workload.
@@ -1645,9 +1537,8 @@ def bench_fleet_obs(extra: dict) -> None:
       the ``fleet_obs`` flag ON (cadence reporter pushing every 0.25s,
       flight-recorder writes live) vs OFF.  A localhost echo loop
       drifts ±20% across contiguous half-second phases (scheduler +
-      allocator weather), so contiguous A/B phases à la
-      ``lm_telemetry_overhead_pct`` cannot resolve a sub-percent
-      effect here; instead each round interleaves sixteen 100ms
+      allocator weather), so contiguous A/B phases cannot resolve a
+      sub-percent effect here; instead each round interleaves sixteen 100ms
       slices A/B/A/B and aggregates qps per side, which cancels drift
       at the slice scale.  Reported value is the median round pct.
     - ``fleet_obs_ab_noise_pct``: the OFF/OFF control — the same
@@ -3303,7 +3194,6 @@ SECTIONS = (
     ("decode_stream", bench_decode_stream, True),
     ("kv_disagg", bench_kv_disagg, True),
     ("slo_sched", bench_slo_sched, True),
-    ("lm_telemetry", bench_lm_telemetry, True),
     ("fleet_obs", bench_fleet_obs, False),
     ("fanout", bench_fanout, False),
     ("http", bench_http, False),

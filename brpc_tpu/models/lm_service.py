@@ -17,7 +17,6 @@ from __future__ import annotations
 import struct
 import threading
 from collections import deque
-from time import monotonic_ns as _mono_ns
 from typing import Optional
 
 import numpy as np
@@ -29,11 +28,13 @@ from ..server.admission import _MAX_TENANTS, normalize_tenant
 from ..server.service import Service
 from . import lm_telemetry as _lmt
 from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
-                           PH_DECODE_ROUND, PH_HOST_RESUME,
-                           PH_HOST_SPILL, PH_PAGE_ALLOC,
-                           PH_PREFIX_LOOKUP, PH_SPEC_DRAFT,
-                           PH_SPEC_VERIFY, PH_STREAM_EMIT)
-from .lm_telemetry import record_phase as _rec_phase
+                           PH_DEVICE_WAIT, PH_EVICT, PH_HOST_RESUME,
+                           PH_HOST_SPILL, PH_IDLE_WAIT,
+                           PH_INSERT_DISPATCH, PH_PAGE_ALLOC,
+                           PH_PREFILL_DISPATCH, PH_PREFIX_LOOKUP,
+                           PH_SCHED, PH_SPEC_DRAFT, PH_SPEC_VERIFY,
+                           PH_STEP_DISPATCH, PH_STREAM_EMIT,
+                           PH_TOKEN_WALK)
 from .transformer_lm import LMConfig, init_params
 
 
@@ -424,6 +425,9 @@ class ContinuousBatcher:
         self.prefills_run = 0
         self.spills = 0
         self.resumes = 0
+        # the batcher thread's cursor over the loop's phases; _run
+        # replaces it with one that also annotates the profiler's clock
+        self._clock = _lmt.PhaseClock()
 
     # -- public -----------------------------------------------------------
 
@@ -498,7 +502,10 @@ class ContinuousBatcher:
                "spills": self.spills, "resumes": self.resumes,
                "parked": len(self._parked),
                "sched": sched_counters(), "spec": spec_counters(),
-               "phases": _lmt.phase_counters()}
+               "phases": _lmt.phase_counters(),
+               "phase_ns": _lmt.phase_total_ns(),
+               "loop_ns": _lmt.loop_ns(),
+               "queue": _lmt.queue_counters()}
         if self._alloc is not None:
             out["alloc"] = self._alloc.stats()
         if self._prefix is not None:
@@ -686,6 +693,7 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         # free = unOCCUPIED, not merely inactive: a chunk-filling
         # session holds its slot while _active is still False
+        ph = self._clock.switch
         free = next(i for i in range(self.slots)
                     if i not in self._sessions)
         if sess.cache1 is None and self.chunk_budget \
@@ -694,6 +702,7 @@ class ContinuousBatcher:
             # scatter the context under the per-step budget; the
             # session activates (and teacher-forces its last prompt
             # token) when fill reaches ctx_len
+            ph(PH_INSERT_DISPATCH)
             self._cache = self._setlen_j(self._cache, jnp.int32(free),
                                          jnp.int32(0))
             sess.ctx_len = len(sess.prompt) - 1
@@ -707,10 +716,12 @@ class ContinuousBatcher:
             last = int(sess.last_token)
             sess.cache1 = None   # the pool owns the rows after insert
         else:
+            ph(PH_PREFILL_DISPATCH)
             cache1, ctx_len = bucketed_prefill(self._prefill, self.cfg,
                                                sess.prompt)
             self.prefills_run += 1
             last = int(sess.prompt[-1])
+        ph(PH_INSERT_DISPATCH)
         self._cache = self._insert(self._cache, cache1,
                                    jnp.int32(free),
                                    jnp.int32(ctx_len))
@@ -751,6 +762,7 @@ class ContinuousBatcher:
         import jax.numpy as jnp
 
         from ..kv.pages import count_evict
+        ph = self._clock.switch
         imported = sess.cache1 is not None
         if imported:
             ctx_len = sess.ctx_len
@@ -760,17 +772,16 @@ class ContinuousBatcher:
             ctx = sess.prompt[:-1]
             ctx_len = len(ctx)
             if self._prefix is not None:
-                t0 = _mono_ns()
+                ph(PH_PREFIX_LOOKUP)
                 aliased, covered = self._prefix.lookup(ctx)
-                _rec_phase(PH_PREFIX_LOOKUP, _mono_ns() - t0)
             else:
                 aliased, covered = [], 0
+        ph(PH_PAGE_ALLOC)
         n_total = self._pages_for(ctx_len, sess.max_new)
-        t0 = _mono_ns()
         priv, why = self._alloc_with_reclaim(n_total - len(aliased),
                                              rank=sess.tier_rank)
-        _rec_phase(PH_PAGE_ALLOC, _mono_ns() - t0)
         if priv is None:
+            ph(PH_EVICT)
             for p in aliased:
                 self._alloc.release(p)
             count_evict(why)
@@ -780,6 +791,7 @@ class ContinuousBatcher:
             return
         # free = unOCCUPIED, not merely inactive: a chunk-filling
         # session holds its slot while _active is still False
+        ph(PH_INSERT_DISPATCH)
         free = next(i for i in range(self.slots)
                     if i not in self._sessions)
         n_alias = len(aliased)
@@ -802,9 +814,11 @@ class ContinuousBatcher:
             last = int(sess.prompt[-1])
             start_len = ctx_len
         elif covered == 0 and not self.chunk_budget:
+            ph(PH_PREFILL_DISPATCH)
             cache1, ctx_len = bucketed_prefill(self._prefill, self.cfg,
                                                sess.prompt)
             self.prefills_run += 1
+            ph(PH_INSERT_DISPATCH)
             self._cache = self._insert(self._cache, jnp.asarray(row),
                                        cache1)
             last = int(sess.prompt[-1])
@@ -876,7 +890,13 @@ class ContinuousBatcher:
             count_sched("sched_preempt_batch")
             if victim.tl is not None:
                 victim.tl.preempts += 1
-        return self._park(victim)
+        # a phase of its own inside the caller's (a page allocation):
+        # the caller's resumes as a second sample when the park is done
+        outer = self._clock.switch(PH_HOST_SPILL)
+        try:
+            return self._park(victim)
+        finally:
+            self._clock.switch(outer)
 
     def _park(self, sess: _Session) -> Optional[str]:
         """Move a live session's private pages device → host and free
@@ -884,7 +904,6 @@ class ContinuousBatcher:
         page contents, len, the last fed token, the chunk-fill
         watermark — survives in the session object + host tier."""
         import jax.numpy as jnp
-        t0 = _mono_ns()
         if not self._host.begin_spill():
             return self._host.abort_reason() or "kv_host_tier_full"
         handles = []
@@ -922,7 +941,6 @@ class ContinuousBatcher:
             sess.tl.spills += 1
         if sess.span is not None:
             sess.span.annotate("lm_spill")
-        _rec_phase(PH_HOST_SPILL, _mono_ns() - t0)
         return None
 
     def _resume(self, sess: _Session) -> bool:
@@ -935,7 +953,7 @@ class ContinuousBatcher:
                      if i not in self._sessions), None)
         if free is None:
             return False
-        t0 = _mono_ns()
+        outer = self._clock.switch(PH_HOST_RESUME)
         priv = self._alloc.alloc(sess.n_priv)
         while priv is None:
             # prefix-cache holds are reclaimable — a parked session
@@ -943,6 +961,7 @@ class ContinuousBatcher:
             if self._prefix is not None and self._prefix.evict_lru():
                 priv = self._alloc.alloc(sess.n_priv)
                 continue
+            self._clock.switch(outer)
             return False
         hd = self.cfg.dim // self.cfg.heads
         n_alias = sess.n_alias
@@ -993,7 +1012,7 @@ class ContinuousBatcher:
                 tl.pages_peak = len(sess.pages)
         if sess.span is not None:
             sess.span.annotate("lm_resume")
-        _rec_phase(PH_HOST_RESUME, _mono_ns() - t0)
+        self._clock.switch(outer)
         return True
 
     def _drop_parked(self, sess: _Session,
@@ -1047,11 +1066,15 @@ class ContinuousBatcher:
         if self._d_cache is None or sess.prompt is None:
             return
         import jax.numpy as jnp
+        ph = self._clock.switch
+        outer = ph(PH_PREFILL_DISPATCH)
         cache1, ctx_len = bucketed_prefill(self._d_prefill, self.cfg,
                                            sess.prompt)
+        ph(PH_INSERT_DISPATCH)
         self._d_cache = self._d_insert(self._d_cache, cache1,
                                        jnp.int32(sess.slot),
                                        jnp.int32(ctx_len))
+        ph(outer)
 
     def _activate(self, sess: _Session) -> None:
         """A fully chunk-filled session goes live: the prompt's LAST
@@ -1090,6 +1113,7 @@ class ContinuousBatcher:
         if not filling:
             return
         import jax.numpy as jnp
+        ph = self._clock.switch
         filling.sort(key=lambda s: (s.tier_rank, s.slot))
         if filling[0].tier_rank == _TIER_RANK["interactive"] \
                 and any(s.tier_rank > filling[0].tier_rank
@@ -1100,11 +1124,13 @@ class ContinuousBatcher:
             if budget <= 0:
                 break
             if sess.stream.closed:
+                ph(PH_EVICT)
                 self._evict(sess, None)
+                ph(PH_SCHED)
                 continue
             catchup = sess.n_alias > 0
             while budget > 0 and sess.fill < sess.ctx_len:
-                t0 = _mono_ns()
+                ph(PH_CATCHUP_SLICE if catchup else PH_CHUNK_SLICE)
                 n = int(min(self._chunk_w, sess.ctx_len - sess.fill,
                             budget))
                 ids = np.zeros((self._chunk_w,), np.int32)
@@ -1123,10 +1149,9 @@ class ContinuousBatcher:
                 budget -= n
                 count_sched("sched_catchup_slice" if catchup
                             else "sched_chunk_slice")
-                _rec_phase(PH_CATCHUP_SLICE if catchup
-                           else PH_CHUNK_SLICE, _mono_ns() - t0)
                 if sess.span is not None:
                     sess.span.annotate("lm_chunk_slice")
+                ph(PH_SCHED)
             if sess.fill >= sess.ctx_len:
                 self._activate(sess)
 
@@ -1149,7 +1174,8 @@ class ContinuousBatcher:
         """One plain decode step over the active slots; returns
         ``(pairs, finished)`` for the emit/evict epilogue."""
         import jax.numpy as jnp
-        t0 = _mono_ns()
+        ph = self._clock.switch
+        ph(PH_STEP_DISPATCH)
         if self.paged:
             cache, logits = self._step(
                 self._cache, jnp.asarray(self._bt),
@@ -1160,8 +1186,11 @@ class ContinuousBatcher:
                 jnp.asarray(self._active))
         self._cache = cache
         self._steps += 1
-        _rec_phase(PH_DECODE_ROUND, _mono_ns() - t0)
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
+        toks = jnp.argmax(logits, axis=-1)
+        # the round's one sync, in a phase of its own: one sample a step
+        ph(PH_DEVICE_WAIT)
+        toks = np.asarray(toks)
+        ph(PH_TOKEN_WALK)
         pairs, finished = [], []
         for slot, sess in list(self._sessions.items()):
             if not self._active[slot]:
@@ -1187,7 +1216,8 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         k = self.spec_k
         count_spec("spec_round")
-        t_round = _mono_ns()
+        ph = self._clock.switch
+        ph(PH_SPEC_DRAFT)
         active = self._active.copy()
         act_j = jnp.asarray(active)
         cur = self._tokens.copy()
@@ -1197,18 +1227,18 @@ class ContinuousBatcher:
                                              jnp.asarray(cur), act_j)
             cur = np.asarray(jnp.argmax(dl, axis=-1)).astype(np.int32)
             drafts.append(cur)
-        t_verify = _mono_ns()
-        _rec_phase(PH_SPEC_DRAFT, t_verify - t_round)
+        # draft, verify, walk: three leaves and no enclosing sample;
+        # spec_verify holds the round's sync, one sample a step
+        ph(PH_SPEC_VERIFY)
         u = np.stack([self._tokens] + drafts, axis=1).astype(np.int32)
         self._cache, out, m = self._verify_j(
             self._cache, jnp.asarray(self._bt), jnp.asarray(u), act_j)
         out = np.asarray(out)
         m = np.asarray(m)
-        _rec_phase(PH_SPEC_VERIFY, _mono_ns() - t_verify)
+        ph(PH_TOKEN_WALK)
         self._d_cache = self._d_sync_j(self._d_cache, jnp.asarray(m),
                                        act_j)
         self._steps += 1
-        _rec_phase(PH_DECODE_ROUND, _mono_ns() - t_round)
         pairs, finished = [], []
         for slot, sess in list(self._sessions.items()):
             if not active[slot]:
@@ -1255,9 +1285,22 @@ class ContinuousBatcher:
         self._finalize_obs(sess, reason or "finished")
 
     def _run(self) -> None:
+        # the loop's phases PARTITION it: ``ph`` moves the clock's
+        # cursor from one leaf of LM_STEP_PHASES to the next, so from
+        # the top of the ``while`` to the top of the next pass every
+        # nanosecond belongs to exactly one of them, under the same
+        # name on the profiler's clock
+        clock = self._clock
         try:
             self._ensure_engine()
+            import jax.profiler as _prof
+            clock = self._clock = _lmt.PhaseClock(
+                _prof.TraceAnnotation, _prof.StepTraceAnnotation)
+            ph = clock.switch
             while True:
+                clock.round_end()
+                ph(PH_SCHED)
+                clock.tick()
                 if self.paged:
                     # parked sessions re-enter BEFORE new admits (they
                     # were serving first), and a drain-aborted host
@@ -1286,13 +1329,23 @@ class ContinuousBatcher:
                     with self._lock:
                         if self._pending:
                             continue
+                    ph(PH_IDLE_WAIT)
+                    clock.tick()    # level before the loop blocks
                     if not self._wake.wait(self.idle_linger_s):
                         with self._lock:
                             if not self._pending \
                                     and not self._sessions:
                                 self._thread = None
+                                clock.close()
                                 return
                     continue
+                if pending or self._sessions:
+                    # a pass with sessions to serve: it runs a step
+                    # unless every admission is refused or (under a
+                    # chunk budget) every slot is still filling
+                    clock.round_begin(self._steps)
+                # queue wait ends here, before the admission's work
+                _lmt.on_admit(pending)
                 for sess in pending:
                     # join-mid-batch: bucketed prefill + slot insert,
                     # BETWEEN steps (bucketing keeps a fresh prompt
@@ -1301,6 +1354,7 @@ class ContinuousBatcher:
                     # or, chunked, just the slot grab: _chunk_round
                     # below scatters the context under the budget
                     self._admit(sess)
+                    ph(PH_SCHED)
                 # the Sarathi half BEFORE the decode round: a fill
                 # completed this round teacher-forces its first token
                 # on THIS round's step
@@ -1311,6 +1365,7 @@ class ContinuousBatcher:
                         # resume yet (another holder must release
                         # first): timed poll, never a busy spin
                         import time as _time
+                        ph(PH_IDLE_WAIT)
                         _time.sleep(0.005)
                     continue
                 if not self._active.any():
@@ -1323,10 +1378,11 @@ class ContinuousBatcher:
                         pairs, finished = self._plain_round()
                 else:
                     pairs, finished = self._plain_round()
-                t0 = _mono_ns()
+                ph(PH_STREAM_EMIT)
                 dead = self._emit(pairs)
-                _rec_phase(PH_STREAM_EMIT, _mono_ns() - t0)
                 _lmt.on_emit(pairs)
+                if dead or finished:
+                    ph(PH_EVICT)
                 evicted = set()
                 for sess, reason in dead:
                     # a spec round emits several tokens per session —
@@ -1340,6 +1396,7 @@ class ContinuousBatcher:
         except Exception:
             LOG.exception("continuous batcher crashed; closing "
                           "sessions")
+            clock.close()
             with self._lock:
                 sessions = list(self._sessions.values()) \
                     + list(self._pending) + list(self._parked)

@@ -3,17 +3,25 @@ of the native engine's telemetry table.
 
 Three planes, all fed by the ONE batcher thread and read passively:
 
-- **step profiler** — per-phase monotonic-ns log2 histograms around the
-  continuous batcher's step loop (decode round, chunk/catch-up slices,
-  spec draft/verify, prefix lookup, page alloc, host spill/resume,
-  stream emit).  The write side is the engine-telemetry pattern: plain
-  per-thread counters bumped by the batcher thread ONLY — never a lock,
-  never an allocation in the step loop (the histograms are preallocated
-  lists; ``record_phase`` is entry-listed in the blocking linter).
+- **step profiler** — per-phase monotonic-ns log2 histograms that
+  PARTITION the continuous batcher's loop: the batcher thread moves a
+  cursor (:class:`PhaseClock`) from one member of ``LM_STEP_PHASES`` to
+  the next, so phases never nest and every nanosecond between the top
+  of one pass and the top of the next belongs to exactly one of them
+  (``loop_ns``, read on its own, is what they have to add up to).  The
+  same cursor wraps each phase in ``jax.profiler.TraceAnnotation
+  ("lm/<phase>")`` and each pass that runs a step in
+  ``StepTraceAnnotation("lm_round")``, so a profile taken with the host
+  tracer on shows the loop under these names beside the device's
+  programs.  The write side is the engine-telemetry pattern: plain
+  per-thread counters bumped by the batcher thread ONLY — never a lock
+  in the step loop (the histograms are preallocated lists;
+  ``PhaseClock.switch`` is entry-listed in the blocking linter).
   Readers see racy-but-monotonic values, exactly like
   ``engine.telemetry()`` readers do;
 - **session timelines** — a bounded ring of per-session records
-  (tier/tenant, prompt length, TTFT, per-token ITL log2 histogram,
+  (tier/tenant, prompt length, queue wait, TTFT, per-token ITL log2
+  histogram,
   prefix hit class, peak pages held, spill/resume/preempt counts, close
   reason) that feeds per-tier ``lm_ttft_ms``/``lm_itl_ms`` percentile
   rows and the CLOSED ``LM_SLO_VERDICTS`` attainment counters
@@ -27,7 +35,8 @@ Three planes, all fed by the ONE batcher thread and read passively:
   keys stay where perf_guard reads them).
 
 Everything here must stay importable without the native engine and
-without jax — the module is pure-Python bookkeeping.
+without jax — the module is pure-Python bookkeeping; the batcher hands
+the profiler's annotation classes to its :class:`PhaseClock`.
 """
 
 from __future__ import annotations
@@ -58,34 +67,53 @@ define_flag("lm_timeline_ring", 256,
 # ---------------------------------------------------------------------------
 
 # CLOSED enum (tools/check/enums.py pins every member to a test): the
-# step loop's named phases.  Indexes are the write-side API — the
-# batcher binds the PH_* constants as locals, so the hot path is two
-# list increments and an int add per phase sample.
+# leaves that partition the batcher thread's loop, in the order a pass
+# meets them.  Indexes are the write-side API — the batcher binds the
+# PH_* constants and its clock's ``switch`` as locals.  A phase marked
+# "enqueue" returns when the work is QUEUED for the device: its device
+# time is the trace's, not the phase's.
 LM_STEP_PHASES = (
-    "decode_round",      # one decode round (plain step or spec round)
-    "chunk_slice",       # one bounded prefill slice (fresh prompt)
-    "catchup_slice",     # slice replaying past a partial prefix hit
-    "spec_draft",        # the k draft-model steps of a spec round
-    "spec_verify",       # the width-(k+1) target verification
+    "sched",             # parked sessions, the lock, pending sort+pop, activate
+    "idle_wait",         # nothing to do: the wake event / the parked poll
     "prefix_lookup",     # prefix-cache probe at admit
     "page_alloc",        # page allocation incl. the reclaim walk
+    "prefill_dispatch",  # host padding + the bucketed prefill (enqueue)
+    "insert_dispatch",   # insert + setlen + the block-table row (enqueue)
+    "chunk_slice",       # one bounded prefill slice, fresh prompt (enqueue)
+    "catchup_slice",     # slice replaying past a partial prefix hit (enqueue)
+    "step_dispatch",     # the step's inputs, _step and argmax (enqueue)
+    "device_wait",       # the tokens come back: the round's one sync
+    "spec_draft",        # the k draft-model steps of a spec round
+    "spec_verify",       # the width-(k+1) target verification and its sync
+    "token_walk",        # tokens -> (session, token) pairs; spec: accept walk
+    "stream_emit",       # one step's token writes + the timelines' stamps
+    "evict",             # dead and finished sessions leave
     "host_spill",        # one session's D2H park
     "host_resume",       # one session's H2D un-park
-    "stream_emit",       # one step's token writes across all sessions
 )
 
-PH_DECODE_ROUND = 0
-PH_CHUNK_SLICE = 1
-PH_CATCHUP_SLICE = 2
-PH_SPEC_DRAFT = 3
-PH_SPEC_VERIFY = 4
-PH_PREFIX_LOOKUP = 5
-PH_PAGE_ALLOC = 6
-PH_HOST_SPILL = 7
-PH_HOST_RESUME = 8
-PH_STREAM_EMIT = 9
+PH_SCHED = 0
+PH_IDLE_WAIT = 1
+PH_PREFIX_LOOKUP = 2
+PH_PAGE_ALLOC = 3
+PH_PREFILL_DISPATCH = 4
+PH_INSERT_DISPATCH = 5
+PH_CHUNK_SLICE = 6
+PH_CATCHUP_SLICE = 7
+PH_STEP_DISPATCH = 8
+PH_DEVICE_WAIT = 9
+PH_SPEC_DRAFT = 10
+PH_SPEC_VERIFY = 11
+PH_TOKEN_WALK = 12
+PH_STREAM_EMIT = 13
+PH_EVICT = 14
+PH_HOST_SPILL = 15
+PH_HOST_RESUME = 16
 
 _NPHASES = len(LM_STEP_PHASES)
+# the ONE source of the names on the profiler's clock
+_TRACE_NAMES = tuple("lm/" + p for p in LM_STEP_PHASES)
+ROUND_TRACE_NAME = "lm_round"
 
 # engine Hist layout: bucket 0 holds zeros, bucket i covers
 # [2^(i-1), 2^i) ns; 40 buckets reach ~9 minutes — beyond any phase
@@ -94,15 +122,27 @@ NBUCKETS = 40
 _phase_buckets = [[0] * NBUCKETS for _ in LM_STEP_PHASES]
 _phase_count = [0] * _NPHASES
 _phase_total_ns = [0] * _NPHASES
+# wall time of the loop, advanced once a pass by a clock read of its
+# own: what the phases' totals have to add up to
+_loop_ns = [0]
 
 # flag-cached enable gate (the rpcz _rpcz_live idiom): one list read on
-# the hot path instead of a flags-table lookup per phase sample
-_live = [bool(get_flag("lm_telemetry", True))]
-watch_flag("lm_telemetry", lambda v: _live.__setitem__(0, bool(v)))
+# the hot path instead of a flags-table lookup per phase sample.  0 is
+# off; on, it holds the count of times the flag came on, so a cursor
+# that slept through an off spell knows its last stamp is stale
+_live = [1 if get_flag("lm_telemetry", True) else 0]
+_on_spells = itertools.count(2)
+watch_flag("lm_telemetry", lambda v: _live.__setitem__(
+    0, next(_on_spells) if v else 0))
 
 
 def telemetry_enabled() -> bool:
-    return _live[0]
+    return bool(_live[0])
+
+
+def _log2_bucket(ns: int) -> int:
+    b = ns.bit_length() if ns > 0 else 0
+    return b if b < NBUCKETS else NBUCKETS - 1
 
 
 def phase_index(name: str) -> int:
@@ -110,19 +150,92 @@ def phase_index(name: str) -> int:
     return LM_STEP_PHASES.index(name)
 
 
-def record_phase(idx: int, ns: int) -> None:
-    """One phase sample (batcher thread only).  Lock-free and
-    allocation-free by construction: preallocated per-phase lists, an
-    int bit_length for the log2 bucket — the whole per-sample cost the
-    observer-effect bench measures."""
-    if not _live[0]:
-        return
-    b = ns.bit_length() if ns > 0 else 0
-    if b >= NBUCKETS:
-        b = NBUCKETS - 1
-    _phase_buckets[idx][b] += 1
-    _phase_count[idx] += 1
-    _phase_total_ns[idx] += ns if ns > 0 else 0
+class PhaseClock:
+    """The batcher thread's cursor over ``LM_STEP_PHASES``: ``switch``
+    ends the phase the loop was in and starts the next on ONE clock
+    read, so the phases partition the loop and cannot nest.  A callee
+    that is a phase of its own inside another (a spill inside a page
+    allocation) switches back to what ``switch`` returned.
+
+    ``trace_cls`` / ``step_cls`` are ``jax.profiler.TraceAnnotation`` /
+    ``StepTraceAnnotation`` (handed in: this module imports no jax);
+    with no profile session, or one whose host tracer is off, building
+    one is a flag test inside the profiler.  With ``lm_telemetry`` off
+    nothing is built and ``switch`` is the gate's single list read."""
+
+    __slots__ = ("cur", "_t", "_gen", "_loop_t", "_ann", "_round",
+                 "_trace_cls", "_step_cls")
+
+    def __init__(self, trace_cls=None, step_cls=None):
+        self.cur = -1               # the open phase; -1: none
+        self._t = 0
+        self._gen = -1              # the on-spell (_live[0]) of _t, _loop_t
+        self._loop_t = 0
+        self._ann = None
+        self._round = None
+        self._trace_cls = trace_cls
+        self._step_cls = step_cls
+
+    def switch(self, idx: int) -> int:
+        """Enter phase ``idx`` (-1: none), crediting the time since the
+        last switch to the phase that was open; returns that phase.
+        Lock-free: preallocated per-phase lists, an int bit_length for
+        the log2 bucket."""
+        gen = _live[0]
+        if not gen:
+            return -1
+        t = _mono_ns()
+        cur = self.cur
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        if self._gen != gen:        # first switch, or off and on again:
+            self._gen = gen         # phases and loop_ns start level
+            self._loop_t = t
+            cur = -1
+        if cur >= 0:
+            ns = t - self._t
+            _phase_buckets[cur][_log2_bucket(ns)] += 1
+            _phase_count[cur] += 1
+            _phase_total_ns[cur] += ns if ns > 0 else 0
+        self.cur = idx
+        self._t = t
+        if idx >= 0 and self._trace_cls is not None:
+            self._ann = ann = self._trace_cls(_TRACE_NAMES[idx])
+            ann.__enter__()
+        return cur
+
+    def tick(self) -> None:
+        """Advance ``loop_ns`` to now: once a pass, after the pass's
+        first ``switch`` (so the phases never lead it at that point),
+        and once more before the loop blocks."""
+        if self._gen != _live[0]:   # off, or on again and no switch yet
+            return
+        t = _mono_ns()
+        _loop_ns[0] += t - self._loop_t
+        self._loop_t = t
+
+    def round_begin(self, step: int) -> None:
+        """A pass that has work opens its ``lm_round``; the top of the
+        next pass ends it."""
+        if _live[0] and self._step_cls is not None:
+            self._round = r = self._step_cls(ROUND_TRACE_NAME,
+                                             step_num=step)
+            r.__enter__()
+
+    def round_end(self) -> None:
+        r = self._round
+        if r is not None:
+            self._round = None
+            r.__exit__(None, None, None)
+
+    def close(self) -> None:
+        """The loop is leaving (idle exit or crash): credit the open
+        phase and bring ``loop_ns`` level with it."""
+        self.round_end()
+        self.switch(-1)
+        self.tick()
 
 
 def bucket_label(i: int, nbuckets: int = NBUCKETS) -> str:
@@ -143,6 +256,10 @@ def phase_total_ns() -> dict:
 
 def phase_histogram(name: str) -> list:
     return list(_phase_buckets[phase_index(name)])
+
+
+def loop_ns() -> int:
+    return _loop_ns[0]
 
 
 # ---------------------------------------------------------------------------
@@ -196,7 +313,7 @@ class SessionTimeline:
     only."""
 
     __slots__ = ("seq", "tier", "tenant", "prompt_len", "max_new",
-                 "join_ns", "first_ns", "last_ns", "tokens",
+                 "join_ns", "admit_ns", "first_ns", "last_ns", "tokens",
                  "itl_buckets", "itl_max_ns", "prefix", "pages_peak",
                  "spills", "resumes", "preempts", "close_reason",
                  "verdict")
@@ -209,6 +326,7 @@ class SessionTimeline:
         self.prompt_len = prompt_len
         self.max_new = max_new
         self.join_ns = _mono_ns()
+        self.admit_ns = 0             # the batcher took it from _pending
         self.first_ns = 0
         self.last_ns = 0
         self.tokens = 0
@@ -227,10 +345,16 @@ class SessionTimeline:
             return None
         return (self.first_ns - self.join_ns) / 1e6
 
+    def queue_ms(self) -> Optional[float]:
+        if not self.admit_ns:
+            return None
+        return (self.admit_ns - self.join_ns) / 1e6
+
     def describe(self) -> dict:
         return {"seq": self.seq, "tier": self.tier,
                 "tenant": self.tenant, "prompt_len": self.prompt_len,
                 "max_new": self.max_new, "tokens": self.tokens,
+                "queue_ms": self.queue_ms(),
                 "ttft_ms": self.ttft_ms(),
                 "itl_max_ms": self.itl_max_ns / 1e6,
                 "prefix": self.prefix, "pages_peak": self.pages_peak,
@@ -248,10 +372,15 @@ _live_sessions: dict = {}
 _ring_max = int(get_flag("lm_timeline_ring", 256))
 _ring: deque = deque(maxlen=_ring_max)
 
-# per-tier latency histograms (batcher-thread writes): TTFT observed at
-# the first emitted token, ITL per subsequent token
+# per-tier latency histograms (batcher-thread writes): queue wait
+# observed when the batcher takes the session from its pending queue,
+# TTFT at the first emitted token, ITL per subsequent token
+_tier_queue: dict = {}
 _tier_ttft: dict = {}
 _tier_itl: dict = {}
+# join -> taken from the pending queue, summed over the sessions taken
+_queue_wait_ns = [0]
+_admitted = [0]
 
 
 def open_timeline(tier: str, tenant, prompt_len: int, max_new: int,
@@ -263,6 +392,7 @@ def open_timeline(tier: str, tenant, prompt_len: int, max_new: int,
     from .lm_service import SLO_TIERS
     assert tier in SLO_TIERS, f"unregistered SLO tier: {tier}"
     if tier not in _tier_ttft:
+        _tier_queue[tier] = [0] * NBUCKETS
         _tier_ttft[tier] = [0] * NBUCKETS
         _tier_itl[tier] = [0] * NBUCKETS
     if isinstance(tenant, (bytes, bytearray, memoryview)):
@@ -271,6 +401,30 @@ def open_timeline(tier: str, tenant, prompt_len: int, max_new: int,
                          int(max_new), source)
     _live_sessions[tl.seq] = tl
     return tl
+
+
+def on_admit(sessions) -> None:
+    """The batcher took these sessions from its pending queue (batcher
+    thread only, before it admits them): ONE monotonic read for them
+    all closes each one's queue wait.  Lock-free, like ``on_emit``."""
+    if not _live[0] or not sessions:
+        return
+    now = _mono_ns()
+    for sess in sessions:
+        tl = sess.tl
+        if tl is None:
+            continue
+        tl.admit_ns = now
+        d = now - tl.join_ns
+        _tier_queue[tl.tier][_log2_bucket(d)] += 1
+        _queue_wait_ns[0] += d if d > 0 else 0
+        _admitted[0] += 1
+        if sess.span is not None:
+            sess.span.annotate("lm_admit")
+
+
+def queue_counters() -> dict:
+    return {"wait_ns": _queue_wait_ns[0], "admitted": _admitted[0]}
 
 
 def on_emit(pairs) -> None:
@@ -288,19 +442,14 @@ def on_emit(pairs) -> None:
         if tl.tokens == 0:
             tl.first_ns = now
             d = now - tl.join_ns
-            b = d.bit_length() if d > 0 else 0
-            if b >= NBUCKETS:
-                b = NBUCKETS - 1
-            _tier_ttft[tl.tier][b] += 1
+            _tier_ttft[tl.tier][_log2_bucket(d)] += 1
             if sess.span is not None:
                 sess.span.annotate("lm_first_token")
         else:
             d = now - tl.last_ns
             if d > tl.itl_max_ns:
                 tl.itl_max_ns = d
-            b = d.bit_length() if d > 0 else 0
-            if b >= NBUCKETS:
-                b = NBUCKETS - 1
+            b = _log2_bucket(d)
             tl.itl_buckets[b] += 1
             _tier_itl[tl.tier][b] += 1
         tl.last_ns = now
@@ -378,20 +527,24 @@ def _hist_quantile_ms(buckets, q: float) -> float:
     return (1 << (len(buckets) - 1)) / 1e6
 
 
-def _ttft_rows() -> dict:
+def _quantile_rows(by_tier: dict) -> dict:
     out = {}
-    for tier, h in _tier_ttft.items():
+    for tier, h in by_tier.items():
         for name, q in _QUANTILES:
             out[(tier, name)] = round(_hist_quantile_ms(h, q), 3)
     return out
+
+
+def _queue_rows() -> dict:
+    return _quantile_rows(_tier_queue)
+
+
+def _ttft_rows() -> dict:
+    return _quantile_rows(_tier_ttft)
 
 
 def _itl_rows() -> dict:
-    out = {}
-    for tier, h in _tier_itl.items():
-        for name, q in _QUANTILES:
-            out[(tier, name)] = round(_hist_quantile_ms(h, q), 3)
-    return out
+    return _quantile_rows(_tier_itl)
 
 
 # ---------------------------------------------------------------------------
@@ -425,12 +578,15 @@ class LmTelemetryCache:
         return {
             "phases": phase_counters(),
             "phase_ns": phase_total_ns(),
+            "loop_ns": loop_ns(),
+            "queue": queue_counters(),
             "phase_hists": {p: list(_phase_buckets[i])
                             for i, p in enumerate(LM_STEP_PHASES)},
             "sched": sched_counters(),
             "spec": spec_counters(),
             "prefix_events": prefix,
             "slo": slo_counters(),
+            "queue_ms": _queue_rows(),
             "ttft_ms": _ttft_rows(),
             "itl_ms": _itl_rows(),
             "live": live_sessions(),
@@ -556,6 +712,8 @@ _phase_hist_var = PassiveDimension(("phase", "bin"), _phase_bucket_rows,
                                    name="lm_step_phase_ns")
 _slo_var = PassiveDimension(("tier", "verdict"), slo_counters,
                             name="lm_slo_attained_total")
+_queue_var = PassiveDimension(("tier", "quantile"), _queue_rows,
+                              name="lm_queue_ms")
 _ttft_var = PassiveDimension(("tier", "quantile"), _ttft_rows,
                              name="lm_ttft_ms")
 _itl_var = PassiveDimension(("tier", "quantile"), _itl_rows,
@@ -572,6 +730,7 @@ _LM_VARS = (
     (_phase_ns_var, "lm_step_phase_ns_total"),
     (_phase_hist_var, "lm_step_phase_ns"),
     (_slo_var, "lm_slo_attained_total"),
+    (_queue_var, "lm_queue_ms"),
     (_ttft_var, "lm_ttft_ms"),
     (_itl_var, "lm_itl_ms"),
     (_windowed_var, "lm_windowed"),
@@ -595,9 +754,13 @@ def _reset_for_tests(ring: Optional[int] = None) -> None:
         _phase_total_ns[i] = 0
         for b in range(NBUCKETS):
             _phase_buckets[i][b] = 0
+    _loop_ns[0] = 0
+    _queue_wait_ns[0] = 0
+    _admitted[0] = 0
     _slo_table()
     for k in _slo:
         _slo[k] = 0
+    _tier_queue.clear()
     _tier_ttft.clear()
     _tier_itl.clear()
     _live_sessions.clear()

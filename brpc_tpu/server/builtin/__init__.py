@@ -426,6 +426,7 @@ def _native(server, msg, rest):
             "feedbacks_in": st.get("feedbacks_in", 0),
             "credit_stalls": st.get("credit_stalls", 0),
             "write_batches": st.get("write_batches", 0),
+            "write_ns": st.get("write_ns", 0),
             "chunks_per_burst": _hist_view(
                 st["chunk_burst"], st["chunk_burst_count"],
                 st["chunk_burst_sum"]),
@@ -464,9 +465,11 @@ def _native(server, msg, rest):
 
 def _lm(server, msg, rest):
     """/lm — the serving-plane telemetry page (ISSUE 18): live decode
-    sessions, recently finished session timelines, per-tier TTFT/ITL
-    percentiles and SLO attainment, the batcher step-phase histograms,
-    KV pool / prefix cache / host tier occupancy, and the WINDOWED
+    sessions, recently finished session timelines, per-tier
+    queue-wait/TTFT/ITL percentiles and SLO attainment, the batcher's
+    loop by phase (histograms, totals, and how much of ``loop_ns`` they
+    account for), the queue counters, KV pool / prefix cache / host
+    tier occupancy, and the WINDOWED
     spec-accept and prefix-hit ratios (current behavior — the lifetime
     cumulative keys stay on the bench/perf_guard plane).  One
     LmTelemetryCache window renders the whole page, same discipline as
@@ -503,6 +506,8 @@ def _lm(server, msg, rest):
     out = {
         "live_sessions": cur["live"],
         "recent_sessions": cur["ring"][-32:],
+        "queue_ms": {f"{t}|{q}": v
+                     for (t, q), v in sorted(cur["queue_ms"].items())},
         "ttft_ms": {f"{t}|{q}": v
                     for (t, q), v in sorted(cur["ttft_ms"].items())},
         "itl_ms": {f"{t}|{q}": v
@@ -510,6 +515,11 @@ def _lm(server, msg, rest):
         "slo_attained_total": {f"{t}|{v}": n for (t, v), n
                                in sorted(cur["slo"].items())},
         "phases": phases,
+        # the phases partition the batcher's loop: their totals against
+        # its wall time, read by a clock of its own
+        "loop": {"loop_ns": cur["loop_ns"],
+                 "accounted_ns": sum(cur["phase_ns"].values())},
+        "queue": cur["queue"],
         "windowed": {
             "window_s": round(dt, 3),
             "spec_accept_rate":

@@ -124,7 +124,9 @@ ENTRY_POINTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     # GIL-atomic list/dict increments on the ONE batcher thread (the
     # reader side, LmTelemetryCache, holds its snapshot lock off-loop
     # and is deliberately NOT entry-listed)
-    ("brpc_tpu/models/lm_telemetry.py", ("record_phase",)),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "switch")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "tick")),
+    ("brpc_tpu/models/lm_telemetry.py", ("on_admit",)),
     ("brpc_tpu/models/lm_telemetry.py", ("on_emit",)),
     ("brpc_tpu/models/lm_telemetry.py", ("open_timeline",)),
     ("brpc_tpu/models/lm_telemetry.py", ("close_timeline",)),

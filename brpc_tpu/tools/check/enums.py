@@ -167,7 +167,7 @@ def check_enums(tree: Tree) -> List[Finding]:
                             reason_names.append((s, f"{rel} (sched)"))
         if rel.endswith("models/lm_telemetry.py"):
             # the serving-observability plane's closed enums (step-loop
-            # phase names + SLO attainment verdicts): record_phase
+            # phase names + SLO attainment verdicts): PhaseClock.switch
             # indexes the phase table and count_slo asserts verdict
             # membership at runtime; every member needs a test anchor
             # here — an unpinned phase or verdict is free to drift out

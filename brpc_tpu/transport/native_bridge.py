@@ -547,6 +547,10 @@ class NativeBridge:
             lambda c=cache: c.get().get("streams", {}).get(
                 "credit_stalls", 0),
             name="native_stream_credit_stalls"))
+        add(PassiveStatus(
+            lambda c=cache: c.get().get("streams", {}).get(
+                "write_ns", 0),
+            name="native_stream_write_ns"))
 
         def _chunk_burst(_c=cache):
             bks = _c.get().get("streams", {}).get("chunk_burst", [])

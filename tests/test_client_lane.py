@@ -468,6 +468,11 @@ def test_demux_unit_stream_frame_and_eof():
 # ---------------------------------------------------------------------------
 
 def test_pooled_client_controller_resets():
+    # the free list is FIFO: with two or more controllers already pooled
+    # by earlier tests of this process, obtain() after recycle() hands
+    # back another instance — start from an empty list
+    from brpc_tpu.client.controller import _cntl_pool
+    _cntl_pool.clear()
     c = Controller.obtain()
     c.timeout_ms = 123
     c.trace_id = 0xDEAD

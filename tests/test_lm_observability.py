@@ -9,9 +9,15 @@ Five planes:
   anchored here); an unregistered verdict asserts loudly at the first
   count;
 - PROFILER INVARIANTS: per-phase histogram mass equals the phase
-  count, counts are monotonic across sessions, and the decode-round
-  count equals the batcher's step counter exactly — the profiler is
-  wired to the loop, not near it;
+  count, counts are monotonic across sessions, and the count of the
+  phase that holds the round's sync (``device_wait``; ``spec_verify``
+  in a speculative round) equals the batcher's step counter exactly —
+  the profiler is wired to the loop, not near it;
+- THE LOOP, ACCOUNTED FOR (ISSUE 25): the phases partition the loop
+  (they add up to ``loop_ns`` and never nest), the sync falls in
+  ``device_wait`` and not in ``step_dispatch``, the same names reach
+  the profiler's annotations, queue wait is stamped per session, the
+  engine times its write batches, and the kill switch stops all of it;
 - SLO ATTAINMENT: per-tier verdict deltas against
   ``TierRegistry.set_slo`` targets (ok / ttft-miss / itl-miss /
   untargeted), judged at session close;
@@ -53,9 +59,11 @@ from brpc_tpu.streaming import StreamOptions, stream_create
 # ---------------------------------------------------------------------------
 
 LM_STEP_PHASE_PINS = (
-    "decode_round", "chunk_slice", "catchup_slice", "spec_draft",
-    "spec_verify", "prefix_lookup", "page_alloc", "host_spill",
-    "host_resume", "stream_emit",
+    "sched", "idle_wait", "prefix_lookup", "page_alloc",
+    "prefill_dispatch", "insert_dispatch", "chunk_slice",
+    "catchup_slice", "step_dispatch", "device_wait", "spec_draft",
+    "spec_verify", "token_walk", "stream_emit", "evict", "host_spill",
+    "host_resume",
 )
 LM_SLO_VERDICT_PINS = ("slo_ok", "slo_ttft_miss", "slo_itl_miss",
                        "slo_untargeted")
@@ -110,6 +118,7 @@ def _reset():
 class _FakeStream:
     def __init__(self):
         self.closed = False
+        self.closed_at = None
         self.close_reason = None
         self.tokens = []
         self.id = 0
@@ -121,14 +130,27 @@ class _FakeStream:
         return 0
 
     def close(self, reason=None):
+        self.closed_at = time.monotonic()
         self.closed = True
         self.close_reason = reason
 
 
-def _join(bat, prompt, max_new, tenant=None):
+def _join(bat, prompt, max_new, tenant=None, span=None):
     st = _FakeStream()
-    bat.join(st, prompt, max_new, tenant=tenant)
+    bat.join(st, prompt, max_new, tenant=tenant, span=span)
     return st
+
+
+def _quiet(bat, timeout=30.0):
+    """A stream closes INSIDE the loop's evict phase; the samples of
+    that pass's tail land after it.  Wait until the batcher sits in
+    its idle wait (or its thread has left), so counters read level."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if bat._thread is None or bat._clock.cur == lmt.PH_IDLE_WAIT:
+            return
+        time.sleep(0.002)
+    raise AssertionError("the batcher never went idle")
 
 
 def _finish(*streams, timeout=120.0):
@@ -149,18 +171,21 @@ def _prompt(seed, n, vocab=64):
 # ---------------------------------------------------------------------------
 
 def test_phase_profiler_invariants():
-    """Histogram mass == phase count for every phase; the decode-round
-    count equals the batcher's own step counter EXACTLY (the profiler
-    brackets the loop, one sample per round); counts are monotonic
-    across sessions; total_ns is consistent with the counts."""
+    """Histogram mass == phase count for every phase; the count of
+    ``device_wait`` (the phase that holds the round's sync) equals the
+    batcher's own step counter EXACTLY (one sample per round); counts
+    are monotonic across sessions; total_ns is consistent with the
+    counts."""
     _reset()
     cfg, params = _setup()
     bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
                             prefill_chunk_tokens=4)
     st = _join(bat, _prompt(3, 17), 6)
     _finish(st)
+    _quiet(bat)
     c1 = lmt.phase_counters()
-    assert c1["decode_round"] == bat.steps_run()
+    assert c1["device_wait"] == bat.steps_run()
+    assert c1["step_dispatch"] == c1["token_walk"] == bat.steps_run()
     assert c1["chunk_slice"] >= 4                # ceil(16/4) slices
     assert c1["prefix_lookup"] >= 1
     assert c1["page_alloc"] >= 1
@@ -171,16 +196,19 @@ def test_phase_profiler_invariants():
         assert sum(hist) == c1[name], name
         assert all(v >= 0 for v in hist)
     totals = lmt.phase_total_ns()
-    assert totals["decode_round"] > 0
+    assert totals["device_wait"] > 0 and totals["sched"] > 0
     assert totals["host_spill"] == 0             # nothing spilled here
+    assert c1["spec_draft"] == c1["spec_verify"] == 0
     # monotonic across a second session, and still step-exact
     st2 = _join(bat, _prompt(4, 9), 4)
     _finish(st2)
+    _quiet(bat)
     c2 = lmt.phase_counters()
     assert all(c2[p] >= c1[p] for p in lmt.LM_STEP_PHASES)
-    assert c2["decode_round"] == bat.steps_run()
-    assert sum(lmt.phase_histogram("decode_round")) \
-        == c2["decode_round"]
+    assert c2["idle_wait"] > c1["idle_wait"]     # the wait between them
+    assert c2["device_wait"] == bat.steps_run()
+    assert sum(lmt.phase_histogram("device_wait")) \
+        == c2["device_wait"]
 
 
 def test_spec_round_phases_recorded():
@@ -190,10 +218,13 @@ def test_spec_round_phases_recorded():
                             spec_decode_k=3, draft_params=params)
     st = _join(bat, _prompt(4, 8), 6)
     _finish(st)
+    _quiet(bat)
     c = lmt.phase_counters()
-    assert c["spec_draft"] >= 1
-    assert c["spec_verify"] >= 1
-    assert c["decode_round"] == bat.steps_run()
+    # a speculative round is three leaves and no enclosing sample:
+    # spec_verify holds its sync, one sample a step
+    assert c["spec_draft"] == c["spec_verify"] == bat.steps_run() >= 1
+    assert c["token_walk"] == bat.steps_run()
+    assert c["device_wait"] == c["step_dispatch"] == 0
 
 
 def test_profiler_disable_flag_stops_sampling():
@@ -206,11 +237,264 @@ def test_profiler_disable_flag_stops_sampling():
         assert not lmt.telemetry_enabled()
         st = _join(bat, _prompt(5, 6), 3)
         _finish(st)
-        assert lmt.phase_counters()["decode_round"] == 0
+        assert lmt.phase_counters()["device_wait"] == 0
         assert lmt.live_sessions() == [] and lmt.ring_len() == 0
     finally:
         assert set_flag("lm_telemetry", "true")
     assert lmt.telemetry_enabled()
+
+
+# ---------------------------------------------------------------------------
+# The loop, accounted for (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+class _SlowTokens:
+    """What ``jnp.argmax`` hands back in the test below: the tokens,
+    behind an ``__array__`` that takes 20 ms to bring them over, as a
+    device that is still computing does."""
+
+    def __init__(self, real):
+        self.real = real
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.020)
+        return np.asarray(self.real)
+
+
+def test_sync_falls_in_device_wait_not_dispatch(monkeypatch):
+    """The S2 repair: the barrier of a round lies inside a phase of its
+    own.  With tokens that take 20 ms to come back, ``device_wait``
+    grows by >= 20 ms a step and ``step_dispatch`` does not."""
+    _reset()
+    cfg, params = _setup()
+    real = jnp.argmax
+
+    def slow_argmax(x, axis=None, **kw):
+        out = real(x, axis=axis, **kw)
+        return _SlowTokens(out) if axis == -1 and x.ndim == 2 else out
+
+    bat = ContinuousBatcher(cfg, params, slots=2)
+    st = _join(bat, _prompt(5, 6), 2)            # compiles, unpatched
+    _finish(st)
+    _quiet(bat)
+    steps0 = bat.steps_run()
+    ns0 = lmt.phase_total_ns()
+    monkeypatch.setattr(jnp, "argmax", slow_argmax)
+    st = _join(bat, _prompt(6, 6), 5)
+    _finish(st)
+    _quiet(bat)
+    monkeypatch.undo()
+    steps = bat.steps_run() - steps0
+    ns1 = lmt.phase_total_ns()
+    assert steps == 5
+    assert ns1["device_wait"] - ns0["device_wait"] >= steps * 20e6
+    assert ns1["step_dispatch"] - ns0["step_dispatch"] < steps * 10e6
+
+
+def test_phases_partition_the_loop():
+    """Over 50 steps and more, with joins into a running batch and
+    evictions: the phases' totals add up to the loop's wall time (never
+    more than ``loop_ns``, at least 0.9 of it), every sample is in a
+    histogram, and ``kv_stats`` carries all three counters."""
+    _reset()
+    cfg, params = _setup()
+    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    streams = []
+    for i in range(8):
+        streams.append(_join(bat, _prompt(10 + i, 5 + i), 13 + i % 3))
+        if i % 3 == 2:
+            _finish(streams[-2])                 # a join into a running batch
+    _finish(*streams)
+    _quiet(bat)
+    assert bat.steps_run() >= 50
+    kv = bat.kv_stats()
+    assert kv["phase_ns"] == lmt.phase_total_ns()
+    accounted, loop = sum(kv["phase_ns"].values()), kv["loop_ns"]
+    assert loop > 0 and 0.9 * loop <= accounted <= loop
+    c = kv["phases"]
+    assert c["evict"] >= 1 and c["prefill_dispatch"] >= 1
+    assert c["insert_dispatch"] >= c["prefill_dispatch"]
+    assert c["sched"] >= bat.steps_run()         # once a pass, and more
+    assert kv["queue"]["admitted"] == 8
+    for name in lmt.LM_STEP_PHASES:
+        assert sum(lmt.phase_histogram(name)) == c[name], name
+
+
+class _Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation`` and
+    ``StepTraceAnnotation``: notes what is built, entered and left."""
+
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name = name
+        _Recorder.log.append(("new", name, kw))
+
+    def __enter__(self):
+        _Recorder.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        _Recorder.log.append(("exit", self.name))
+
+
+def test_phases_reach_the_profiler_under_their_names(monkeypatch):
+    """The same names on the profiler's clock: every phase whose count
+    grew was annotated ``lm/<phase>`` and no other name was, one
+    annotation is open at a time (a phase is left before the next is
+    entered), and each step has its ``lm_round``."""
+    _reset()
+    monkeypatch.setattr(_Recorder, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
+    cfg, params = _setup()
+    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+                            idle_linger_s=0.05)
+    st1 = _join(bat, _prompt(3, 9), 5)
+    st2 = _join(bat, _prompt(4, 7), 3)
+    _finish(st1, st2)
+    deadline = time.monotonic() + 30
+    while bat._thread is not None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert bat._thread is None                   # lingered out: all closed
+    log = list(_Recorder.log)
+    grew = {p for p, n in lmt.phase_counters().items() if n}
+    phases = [e for e in log if e[1] != lmt.ROUND_TRACE_NAME]
+    assert {e[1] for e in phases} == {"lm/" + p for p in grew}
+    assert {"lm/step_dispatch", "lm/device_wait", "lm/token_walk",
+            "lm/stream_emit", "lm/evict", "lm/sched", "lm/idle_wait",
+            "lm/prefill_dispatch", "lm/insert_dispatch"} \
+        <= {e[1] for e in phases}
+    # never two open: new, enter, exit, new, enter, exit, ...
+    assert [e[0] for e in phases] \
+        == ["new", "enter", "exit"] * (len(phases) // 3)
+    assert len(phases) // 3 == sum(lmt.phase_counters().values())
+    rounds = [e for e in log if e[1] == lmt.ROUND_TRACE_NAME]
+    new_rounds = [e for e in rounds if e[0] == "new"]
+    assert len(new_rounds) == bat.steps_run() >= 5
+    assert [e[2]["step_num"] for e in new_rounds] \
+        == list(range(bat.steps_run()))
+    assert [e[0] for e in rounds] \
+        == ["new", "enter", "exit"] * len(new_rounds)
+
+
+class _FakeSpan:
+    def __init__(self):
+        self.notes = []
+        self.finished = False
+
+    def annotate(self, text):
+        self.notes.append(text)
+
+    def finish(self, _code):
+        self.finished = True
+
+
+def test_queue_wait_is_stamped_when_the_batcher_takes_the_session():
+    """A session joined while every slot is taken waits in the pending
+    queue until one frees: its ``queue_ms`` is at least that long, its
+    span carries ``lm_admit`` between ``lm_join`` and the first token,
+    and the queue counters grew by one a session."""
+    _reset()
+    cfg, params = _setup()
+    bat = ContinuousBatcher(cfg, params, slots=1)
+    q0 = bat.kv_stats()["queue"]
+    assert q0 == {"wait_ns": 0, "admitted": 0}
+    first = _join(bat, _prompt(7, 6), 10)
+    span = _FakeSpan()
+    second = _join(bat, _prompt(8, 6), 2, span=span)
+    joined = time.monotonic()                    # after its join_ns
+    _finish(first, second)
+    _quiet(bat)
+    recs = {r["max_new"]: r for r in lmt.timeline_records()}
+    freed_after_ms = (first.closed_at - joined) * 1e3
+    assert freed_after_ms > 0
+    assert recs[2]["queue_ms"] >= freed_after_ms
+    assert recs[2]["queue_ms"] <= recs[2]["ttft_ms"]
+    assert recs[10]["queue_ms"] < recs[2]["queue_ms"]
+    assert span.notes[:2] == ["lm_join", "lm_admit"]
+    assert span.notes[2:] == ["lm_first_token", "lm_evict:finished"]
+    assert span.finished
+    q1 = bat.kv_stats()["queue"]
+    assert q1["admitted"] == 2
+    assert q1["wait_ns"] >= recs[2]["queue_ms"] * 1e6 * 0.999
+    rows = lmt._queue_rows()
+    assert rows[("standard", "p99")] >= recs[2]["queue_ms"]
+
+
+def test_engine_times_its_write_batches():
+    """``streams.write_ns``: 0 before any write batch, then growing
+    with ``write_batches`` (two clock reads a ``stream_write_many``)."""
+    from brpc_tpu import native
+    from brpc_tpu.server import ServerOptions
+    if native.load() is None:
+        pytest.skip("the native engine does not build here")
+    _reset()
+    cfg, params = _setup()
+    lm = LMService(cfg=cfg, params=params, decode_slots=2)
+    opts = ServerOptions()
+    opts.native = True
+    opts.usercode_inline = True
+    srv = Server(opts)
+    srv.add_service(lm, name="LM")
+    assert srv.start("127.0.0.1:0") == 0
+    try:
+        assert srv._native_bridge is not None
+        tele = srv._native_bridge.engine.telemetry
+        st0 = tele()["streams"]
+        assert st0["write_batches"] == 0 and st0["write_ns"] == 0
+        toks, reason = _stream_decode_traced(
+            srv, _prompt(2, 8)[None, :], 4, 0)
+        assert reason == "finished" and len(toks) == 4
+        st1 = tele()["streams"]
+        assert st1["write_batches"] >= 4 and st1["write_ns"] > 0
+        # a write batch is microseconds, not the step it follows
+        assert st1["write_ns"] / st1["write_batches"] < 50e6
+        toks, reason = _stream_decode_traced(
+            srv, _prompt(3, 8)[None, :], 3, 0)
+        assert len(toks) == 3
+        st2 = tele()["streams"]
+        assert st2["write_batches"] > st1["write_batches"]
+        assert st2["write_ns"] > st1["write_ns"]
+    finally:
+        srv.stop()
+
+
+def test_kill_switch_stops_phases_annotations_and_queue(monkeypatch):
+    """With ``lm_telemetry`` off nothing grows and no annotation is so
+    much as constructed; switched back on, the clock starts from a
+    fresh stamp (the off spell is credited to no phase)."""
+    from brpc_tpu.butil.flags import set_flag
+    _reset()
+    monkeypatch.setattr(_Recorder, "log", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
+    cfg, params = _setup()
+    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    assert set_flag("lm_telemetry", "false")
+    t_off = time.monotonic_ns()
+    try:
+        st = _join(bat, _prompt(5, 6), 3)        # compiles: a long spell
+        _finish(st)
+        assert _Recorder.log == []
+        assert not any(lmt.phase_counters().values())
+        assert not any(lmt.phase_total_ns().values())
+        kv = bat.kv_stats()
+        assert kv["loop_ns"] == 0
+        assert kv["queue"] == {"wait_ns": 0, "admitted": 0}
+    finally:
+        assert set_flag("lm_telemetry", "true")
+    t_on = time.monotonic_ns()
+    st = _join(bat, _prompt(6, 6), 3)
+    _finish(st)
+    _quiet(bat)
+    kv = bat.kv_stats()
+    assert kv["phases"]["device_wait"] == 3
+    assert _Recorder.log
+    # the spell spent off is in no phase and not in loop_ns
+    assert sum(kv["phase_ns"].values()) <= kv["loop_ns"] \
+        <= time.monotonic_ns() - t_on
+    assert t_on - t_off > 0
 
 
 # ---------------------------------------------------------------------------
@@ -467,33 +751,41 @@ def test_lm_portal_and_metrics_exposition():
         st = _FakeStream()
         lm.batcher().join(st, _prompt(2, 8), 4)
         _finish(st)
+        _quiet(lm.batcher())
         ep = srv.listen_endpoint
         status, body = _http_get(ep, "/lm")
         assert status == 200
         page = json.loads(body)
         assert page["enabled"] is True
-        assert page["phases"]["decode_round"]["count"] \
+        assert page["phases"]["device_wait"]["count"] \
             == lm.batcher().steps_run()
-        assert page["phases"]["decode_round"]["buckets_ns"]
+        assert page["phases"]["device_wait"]["buckets_ns"]
+        assert set(page["phases"]) == set(LM_STEP_PHASE_PINS)
+        assert 0 < page["loop"]["accounted_ns"] <= page["loop"]["loop_ns"]
+        assert page["queue"]["admitted"] == 1
+        assert "standard|p50" in page["queue_ms"]
         recent = page["recent_sessions"]
         assert len(recent) == 1 and recent[0]["tokens"] == 4
         assert recent[0]["verdict"] == "slo_untargeted"
+        assert 0 <= recent[0]["queue_ms"] <= recent[0]["ttft_ms"]
         assert page["live_sessions"] == []
         assert "spec_accept_rate" in page["windowed"]
         assert "prefix_cache_hit_ratio" in page["windowed"]
         assert page["lifetime"]["spec_accept_rate"] == 0.0
         assert page["timeline_ring"]["len"] == 1
-        assert page["kv"]["phases"]["decode_round"] \
+        assert page["kv"]["phases"]["device_wait"] \
             == lm.batcher().steps_run()
+        assert page["kv"]["queue"]["admitted"] == 1
         # the same counters ride the Prometheus exposition
         status, body = _http_get(ep, "/metrics")
         assert status == 200
         text = body.decode()
-        assert 'lm_step_phase_total{phase="decode_round"}' in text
+        assert 'lm_step_phase_total{phase="device_wait"}' in text
+        assert 'lm_queue_ms{tier="standard",quantile="p50"}' in text
         assert 'lm_slo_attained_total{tier="standard",' \
             'verdict="slo_untargeted"}' in text
         assert 'lm_ttft_ms{tier="standard",quantile="p50"}' in text
         assert 'lm_windowed{ratio="spec_accept_rate"}' in text
-        assert 'lm_step_phase_ns{phase="decode_round",bin=' in text
+        assert 'lm_step_phase_ns{phase="device_wait",bin=' in text
     finally:
         srv.stop()

@@ -452,19 +452,21 @@ def test_drift_unregistered_slo_verdict():
 
 def test_drift_lock_in_step_loop_profiler():
     """A lock acquisition seeded into the per-sample profiler write
-    path (record_phase runs inside every batcher decode round) must be
-    caught by the step-loop entry points — the ZERO-locks hot-path
-    contract is linter-enforced, not reviewed-by-hope."""
+    path (PhaseClock.switch runs at every phase boundary of the
+    batcher's loop) must be caught by the step-loop entry points — the
+    ZERO-locks hot-path contract is linter-enforced, not
+    reviewed-by-hope."""
     LM_TEL = "brpc_tpu/models/lm_telemetry.py"
-    ov = _mutate(LM_TEL, "    _phase_buckets[idx][b] += 1",
-                 "    _obs_lock.acquire()\n"
-                 "    _phase_buckets[idx][b] += 1")
+    ov = _mutate(LM_TEL, "            _phase_count[cur] += 1",
+                 "            _obs_lock.acquire()\n"
+                 "            _phase_count[cur] += 1")
     ov[LM_TEL] = ov[LM_TEL].replace(
-        '_live = [bool(get_flag("lm_telemetry", True))]',
+        '_live = [1 if get_flag("lm_telemetry", True) else 0]',
         "_obs_lock = threading.Lock()\n"
-        '_live = [bool(get_flag("lm_telemetry", True))]', 1)
+        '_live = [1 if get_flag("lm_telemetry", True) else 0]', 1)
+    assert "_obs_lock = threading.Lock()" in ov[LM_TEL]
     findings = check_blocking(Tree(overrides=ov))
-    assert any("record_phase" in f.message and "acquire" in f.message
+    assert any("switch" in f.message and "acquire" in f.message
                for f in findings), findings
 
 
