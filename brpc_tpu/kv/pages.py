@@ -42,7 +42,10 @@ courier.  Three more planes live here:
 
 - :class:`PageAllocator` — host-side bookkeeping for the continuous
   batcher's device page pool (block-paged attention,
-  ``models/transformer_lm.make_paged_batch_decode``): a fixed pool of
+  ``models/transformer_lm.make_paged_batch_decode``: the step writes
+  its row into the slot's current page and attends over the slot's
+  LIVE pages where they lie, ``ops/paged_attention``, never over a
+  gathered ``max_seq`` copy of the block table): a fixed pool of
   fixed-size token pages, REFCOUNTED so the prefix cache can alias a
   session's immutable full pages, generation-checked so a stale alias
   fails loudly instead of reading the slot's next tenant;

@@ -405,6 +405,10 @@ class ContinuousBatcher:
         self._wake = threading.Event()
         self._thread = None
         self._steps = 0                           # decode steps run
+        # paged steps' attention reach, over active slots: pages of
+        # live positions against the block table's whole width
+        self._attn_pages_read = 0
+        self._attn_pages_table = 0
         # paged-mode engine state (built in _ensure_engine)
         self._alloc = None                        # kv.pages.PageAllocator
         self._prefix = None                       # kv.pages.PrefixCache
@@ -506,6 +510,9 @@ class ContinuousBatcher:
                "phase_ns": _lmt.phase_total_ns(),
                "loop_ns": _lmt.loop_ns(),
                "queue": _lmt.queue_counters()}
+        if self.paged:
+            out["attn"] = {"pages_read": self._attn_pages_read,
+                           "pages_table": self._attn_pages_table}
         if self._alloc is not None:
             out["alloc"] = self._alloc.stats()
         if self._prefix is not None:
@@ -564,6 +571,16 @@ class ContinuousBatcher:
                                      paged_page_bytes)
 
         if self._prefill is None:
+            from ..ops import paged_attention
+            from ..ops.device_ops import _on_tpu
+            if _on_tpu():
+                # the step's kernel needs Pallas, 1.2 s of import on
+                # the chip's host: beside the first prefill programs'
+                # loads (which release the GIL), not inside the first
+                # step's trace, where set-up would wait for all of it
+                threading.Thread(target=paged_attention.import_pallas,
+                                 name="lm-pallas-import",
+                                 daemon=True).start()
             prefill, step = make_paged_batch_decode(self.cfg, self.page)
             self._prefill = jit_with_params(prefill, self.params)
             self._step = jit_with_params(step, self.params,
@@ -1192,15 +1209,22 @@ class ContinuousBatcher:
         toks = np.asarray(toks)
         ph(PH_TOKEN_WALK)
         pairs, finished = [], []
+        last, pages_read = self.cfg.max_seq - 1, 0
         for slot, sess in list(self._sessions.items()):
             if not self._active[slot]:
                 continue
             tok = int(toks[slot])
             self._tokens[slot] = tok
+            # the step attended over positions 0..ctx_len + sent
+            pages_read += min(sess.ctx_len + sess.sent, last) \
+                // self.page + 1
             sess.sent += 1
             pairs.append((sess, tok))
             if sess.sent >= sess.max_new:
                 finished.append(sess)
+        if self.paged:
+            self._attn_pages_read += pages_read
+            self._attn_pages_table += len(pairs) * self._pps
         return pairs, finished
 
     def _spec_round(self):

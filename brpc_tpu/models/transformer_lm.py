@@ -683,10 +683,14 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     and ``bt`` is the (slots, max_seq // page) int32 block table (host-
     owned, passed per step — NOT part of the donated cache).  The step
     scatters the new k/v row into ``pool[bt[b, pos // page], pos % page]``
-    and gathers ``pool[bt]`` back into exactly the (b, max_seq, heads,
-    hd) array the contiguous step attends over, then runs the SAME
-    masked attention — token identity with :func:`make_batch_decode` by
-    construction, which the per-lane pins assert."""
+    and attends over positions ``0..pos`` through the block table
+    (``ops.paged_attention``): on the TPU a kernel that reads each
+    slot's live pages from the pool once, with an online softmax; off
+    it the plain gather of ``pool[bt]`` under the contiguous step's
+    mask.  Token identity with :func:`make_batch_decode` is no longer
+    by construction on the TPU: the per-lane tests pin it on the cpu
+    backend and ``tests/test_paged_attention.py`` holds the kernel to
+    the plain formulation."""
     import jax
     import jax.numpy as jnp
 
@@ -702,6 +706,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         from .moe import forward_grouped as moe_forward
         moe_cfg = cfg.moe_cfg()
 
+    from ..ops import paged_attention
     from ..ops.quant import qmatmul
 
     def mlp(bp, h):
@@ -711,7 +716,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         up = qmatmul(h, bp["w1"])
         return qmatmul(jax.nn.gelu(up), bp["w2"])
 
-    def decode_layer(bp, x, pk, pv, bt, pos):
+    def decode_layer(bp, x, pk, pv, bt, pos, att_pos):
         """One block, one token per slot, block-table addressing."""
         b = x.shape[0]
         h = _rmsnorm(x, bp["ln1"])
@@ -728,19 +733,11 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         pk = pk.at[page_idx, row].set(k[:, 0])
         pv = pv.at[page_idx, row].set(v[:, 0])
 
-        # gather the block table back into the contiguous view the
-        # un-paged step attends over (unwritten pages are garbage but
-        # sit beyond the live mask by construction)
-        kc = pk[bt].reshape(b, cfg.max_seq, cfg.heads, hd)
-        vc = pv[bt].reshape(b, cfg.max_seq, cfg.heads, hd)
-        s_mat = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
-                           preferred_element_type=jnp.float32
-                           ) / (hd ** 0.5)
-        live = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
-        s_mat = jnp.where(live[:, None, None, :], s_mat, -1e30)
-        p = jax.nn.softmax(s_mat, axis=-1)
-        att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
-                         preferred_element_type=jnp.float32)
+        # attend over each slot's live pages where they lie (rows past
+        # ``att_pos`` are garbage and are never admitted); an inactive
+        # slot's output is discarded, so it reads one page, not the
+        # ``len`` its last session left behind
+        att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos)
         x = x + qmatmul(att.reshape(b, 1, cfg.dim), bp["wo"])
         x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
         return x, pk, pv
@@ -748,11 +745,12 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     def step(params, cache, bt, token, active):
         cache = dict(cache)
         pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
+        att_pos = jnp.where(active, pos, 0)
         x = params["embed"][token][:, None, :]
         for i in range(cfg.depth):
             x, pk, pv = decode_layer(params[f"blk{i}"], x,
                                      cache[f"pk{i}"], cache[f"pv{i}"],
-                                     bt, pos)
+                                     bt, pos, att_pos)
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])

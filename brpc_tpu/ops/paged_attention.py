@@ -1,0 +1,207 @@
+"""Paged decode attention — the batched step's attention over the LIVE
+pages of each slot, read where they lie in the layer's page pool.
+
+The pool keeps its layout, ``(num_pages, page, heads, hd)`` float32
+(``make_paged_io``, the KV export and the prefix cache depend on it):
+one page is one contiguous block, the DMA unit.  Per slot the kernel
+walks ``pos // page + 1`` pages of the block table in waves of a few
+pages, double-buffered (the next wave — of this slot, or the first of
+the next slot — is in flight while this one is computed), with an
+online softmax; no ``(slots, max_seq, heads, hd)`` copy is ever built.
+
+Heads lie on the sublanes of a page and ``hd`` on its lanes, so a wave
+``(tokens, heads, hd)`` is used as it arrives, in float32 on the VPU:
+scores are ``sum(k * q, lanes)``, one per (token, head); weights and
+the accumulator ``(heads, hd)`` follow.  With one query row a head the
+MXU would want the page relaid out (tokens on sublanes) or bfloat16
+operands; the VPU needs neither and keeps up with the pages' bytes
+(measured on the v5e: 710-745 GB/s of 819 over live pages, PERF.md).
+The arithmetic is float32 throughout, as XLA runs the einsums of
+:func:`reference` (the formulation the step had, and has off the TPU).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+
+from .flash_attention import _resolve_interpret
+
+# one wave's K (or V) bytes in VMEM: two of each are resident, plus two
+# products of a wave's size.  Measured on the v5e at 0.5, 1, 2 and 4 MB:
+# the same rate over long contexts, the smallest best over short ones
+_WAVE_BYTES = 512 << 10
+
+
+def reference(q, pk, pv, bt, pos):
+    """The plain formulation: gather every slot's whole block table
+    into a ``(slots, max_seq, heads, hd)`` view and run a dense masked
+    attention over it.  What the step runs off the TPU, and what the
+    kernel is tested against."""
+    import jax.numpy as jnp
+
+    b, heads, hd = q.shape
+    page = pk.shape[1]
+    max_seq = bt.shape[1] * page
+    kc = pk[bt].reshape(b, max_seq, heads, hd)
+    vc = pv[bt].reshape(b, max_seq, heads, hd)
+    s_mat = jnp.einsum("bhd,bkhd->bhk", q, kc,
+                       preferred_element_type=jnp.float32) / (hd ** 0.5)
+    live = jnp.arange(max_seq)[None, :] <= pos[:, None]
+    s_mat = jnp.where(live[:, None, :], s_mat, -1e30)
+    p = jax.nn.softmax(s_mat, axis=-1)
+    return jnp.einsum("bhk,bkhd->bhd", p, vc,
+                      preferred_element_type=jnp.float32)
+
+
+def pages_per_wave(page: int, heads: int, hd: int, pps: int) -> int:
+    """Pages a wave fetches, from the bytes of one page as given."""
+    return max(1, min(pps, _WAVE_BYTES // (page * heads * hd * 4)))
+
+
+def _kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
+            kbuf, vbuf, sems, m_scr, l_scr, acc_scr, *,
+            page: int, heads: int, wave: int):
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, _, hd = q_ref.shape
+    toks = wave * page
+    scale = 1.0 / (hd ** 0.5)
+
+    def n_pages(b):
+        return pos_ref[b] // page + 1
+
+    def wave_dma(b, w, buf, go):
+        """Start (or wait for) the live pages of wave ``w`` of slot
+        ``b`` into buffer ``buf``: nothing past the slot's last live
+        page is fetched."""
+        for i in range(wave):
+            idx = w * wave + i
+
+            @pl.when(idx < n_pages(b))
+            def _():
+                pid = bt_ref[b, idx]
+                dst = pl.ds(i * page, page)
+                for hbm, vmem, j in ((pk_hbm, kbuf, 0), (pv_hbm, vbuf, 1)):
+                    go(pltpu.make_async_copy(
+                        hbm.at[pid], vmem.at[buf, dst], sems.at[j, buf]))
+
+    start = functools.partial(wave_dma, go=lambda c: c.start())
+    wait = functools.partial(wave_dma, go=lambda c: c.wait())
+
+    tok = lax.broadcasted_iota(jnp.int32, (toks, heads, 1), 0)
+
+    start(0, 0, 0)
+
+    def slot_body(b, g):
+        n_waves = (n_pages(b) + wave - 1) // wave
+        m_scr[:] = jnp.full_like(m_scr, -1e30)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+        q = q_ref[b] * scale
+
+        def wave_body(w, g):
+            buf = lax.rem(g, 2)
+
+            @pl.when(w + 1 < n_waves)
+            def _():
+                start(b, w + 1, 1 - buf)
+
+            @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
+            def _():
+                start(b + 1, 0, 1 - buf)
+
+            wait(b, w, buf)
+            # tokens of this wave at positions <= pos
+            lim = pos_ref[b] + 1 - w * toks
+
+            @pl.when(lim < toks)
+            def _():
+                # what lies past pos (stale rows, pages not fetched)
+                # would meet a zero weight, and 0 * NaN is NaN
+                vbuf[buf] = jnp.where(tok < lim, vbuf[buf], 0.0)
+
+            s = jnp.sum(kbuf[buf] * q[None], axis=-1, keepdims=True)
+            s = jnp.where(tok < lim, s, -1e30)          # (toks, heads, 1)
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, s.max(axis=0))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[None])
+            l_scr[:] = l_scr[:] * corr + p.sum(axis=0)
+            m_scr[:] = m_new
+            acc_scr[:] = acc_scr[:] * corr + (p * vbuf[buf]).sum(axis=0)
+            return g + 1
+
+        g = lax.fori_loop(0, n_waves, wave_body, g)
+        o_ref[b] = acc_scr[:] / l_scr[:]
+        return g
+
+    lax.fori_loop(0, slots, slot_body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_call(q, pk, pv, bt, pos, interpret: bool):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, hd = q.shape
+    page = pk.shape[1]
+    wave = pages_per_wave(page, heads, hd, bt.shape[1])
+    toks = wave * page
+    whole = pl.BlockSpec((slots, heads, hd), lambda i, bt, pos: (0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel, page=page, heads=heads, wave=wave),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, toks, heads, hd), pk.dtype),
+                pltpu.VMEM((2, toks, heads, hd), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((heads, 1), jnp.float32),    # running max
+                pltpu.VMEM((heads, 1), jnp.float32),    # running denom
+                pltpu.VMEM((heads, hd), jnp.float32),   # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, hd), jnp.float32),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(bt, pos, q, pk, pv)
+
+
+def paged_decode_attention(q, pk, pv, bt, pos,
+                           interpret: Optional[bool] = None):
+    """``q (slots, heads, hd)`` against positions ``0..pos[b]`` of slot
+    ``b``, whose pages ``bt[b, :pos[b] // page + 1]`` lie in the pools
+    ``pk`` / ``pv (num_pages, page, heads, hd)`` -> ``(slots, heads,
+    hd)`` float32.  Block-table entries past the last live page are
+    never read.  Traced once however many layers call it."""
+    return _paged_call(q, pk, pv, bt, pos,
+                       interpret=_resolve_interpret(interpret))
+
+
+def import_pallas() -> None:
+    """Import what the kernel is written in.  Over a second of pure
+    Python on the chip's host, and the first thing the first traced
+    step asks for: a serving process that will run the paged step
+    starts this beside the loads of its first programs."""
+    import jax.experimental.pallas.tpu  # noqa: F401
+
+
+def attention(q, pk, pv, bt, pos):
+    """The step's attention: the kernel on the TPU, :func:`reference`
+    on the cpu backend (where the kernel would only be interpreted), as
+    ``flash_attention.attention(impl="auto")`` chooses for prefill."""
+    from .device_ops import _on_tpu
+    if _on_tpu():
+        return paged_decode_attention(q, pk, pv, bt, pos)
+    return reference(q, pk, pv, bt, pos)

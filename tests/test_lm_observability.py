@@ -34,6 +34,7 @@ Five planes:
 import http.client
 import json
 import struct
+import threading
 import time
 
 import jax
@@ -343,6 +344,13 @@ def test_phases_reach_the_profiler_under_their_names(monkeypatch):
     grew was annotated ``lm/<phase>`` and no other name was, one
     annotation is open at a time (a phase is left before the next is
     entered), and each step has its ``lm_round``."""
+    # the phase table is the process's: an earlier test's batcher that
+    # lingers out (5 s) during this one would add a sample whose
+    # annotation was opened before the recorder stood in
+    deadline = time.monotonic() + 30
+    while any(t.name == "lm-decode-batcher" for t in threading.enumerate()) \
+            and time.monotonic() < deadline:
+        time.sleep(0.02)
     _reset()
     monkeypatch.setattr(_Recorder, "log", [])
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
