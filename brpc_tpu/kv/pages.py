@@ -533,10 +533,19 @@ class PrefixCache:
     invariant assertion rather than a race guard.  Eviction is
     leaf-first LRU (a parent is never younger than a live child), so
     the tree stays a valid prefix set under any budget.
+
+    ``state_layers``: the model also carries per-sequence recurrent
+    state, which a page of keys does not restore.  Every lookup then
+    DECLINES (no pages, nothing covered, ``declined_state`` counted)
+    and nothing is ever inserted: such a model never gets a keys-only
+    hit.  (Sharing would need state snapshots at page boundaries.)
     """
 
     def __init__(self, alloc: PageAllocator,
-                 budget_pages: Optional[int] = None):
+                 budget_pages: Optional[int] = None,
+                 state_layers: bool = False):
+        self._state_layers = bool(state_layers)
+        self.declined_state = 0
         self._alloc = alloc
         self._page = alloc.page_tokens
         self._budget = budget_pages
@@ -573,7 +582,11 @@ class PrefixCache:
         ``(pages, covered_tokens)`` with one reference TAKEN per page —
         the caller owns those holds and must release them with the rest
         of the session's block table.  Counts exactly one of
-        prefix_hit / prefix_partial_hit / prefix_miss."""
+        prefix_hit / prefix_partial_hit / prefix_miss (or, for a model
+        with state layers, ``declined_state`` alone)."""
+        if self._state_layers:
+            self.declined_state += 1
+            return [], 0
         digs = self._digests(ctx_tokens)
         with self._lock:
             self._tick += 1
@@ -612,6 +625,8 @@ class PrefixCache:
         """Cache the full pages of a freshly prefilled context.
         ``page_ids[i]`` must hold chunk ``i``'s KV rows.  Takes one
         cache-owned ref per NEW node; returns how many were new."""
+        if self._state_layers:
+            return 0
         digs = self._digests(ctx_tokens)
         new = 0
         with self._lock:
@@ -693,7 +708,8 @@ class PrefixCache:
                 "partial_hits": self.partial_hits,
                 "misses": self.misses,
                 "inserts": self.inserts,
-                "evictions": self.evictions}
+                "evictions": self.evictions,
+                "declined_state": self.declined_state}
 
 
 # ---------------------------------------------------------------------------

@@ -35,7 +35,8 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_SCHED, PH_SPEC_DRAFT, PH_SPEC_VERIFY,
                            PH_STEP_DISPATCH, PH_STREAM_EMIT,
                            PH_TOKEN_WALK)
-from .transformer_lm import LMConfig, init_params
+from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
+                             require_plain_block, state_slot_bytes)
 
 
 def pack_generate_request(prompt: np.ndarray, max_new: int) -> bytes:
@@ -231,7 +232,10 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     tier both run exactly this, which is the token-identity contract
     between monolithic and disaggregated serving (the prompt's last
     token then rides the first batch step on whichever tier decodes —
-    teacher-forced equivalence, see :meth:`ContinuousBatcher._admit`)."""
+    teacher-forced equivalence, see :meth:`ContinuousBatcher._admit`).
+    A block beyond the first (``transformer_lm._make_block_prefill``)
+    is handed the true length beside the bucket: a causal attention
+    forgives the zero padding, a recurrent state does not."""
     ctx = prompt[:-1]
     bucket = 1
     while bucket < max(len(ctx), 1):
@@ -239,7 +243,10 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     bucket = min(bucket, cfg.max_seq)
     padded = np.zeros((bucket,), np.int32)
     padded[:len(ctx)] = ctx
-    cache1, _logits = prefill_j(padded[None, :])
+    if cfg.plain_block():
+        cache1, _logits = prefill_j(padded[None, :])
+    else:
+        cache1, _logits = prefill_j(padded[None, :], np.int32(len(ctx)))
     return cache1, len(ctx)
 
 
@@ -319,6 +326,19 @@ class ContinuousBatcher:
       (``kv.pages.host_inflight_spills``) and expiry closes parked
       sessions under ``kv_spill_drain_aborted`` instead of leaking.
 
+    **Two kinds of state** (a layer schedule with state-space layers,
+    ``LMConfig.mixers``; paged mode only): the attention layers keep
+    pages per TOKEN as above; each state layer keeps one fixed block
+    per SLOT in the state pool (``sh<i>``/``sc<i>`` of the cache, its
+    size follows ``slots``).  Admission writes the prefill's state at
+    the prompt's true length over whatever the slot's last session
+    left; the step moves it only where the slot is active; eviction
+    just lets the slot go.  A page of keys restores no recurrent
+    state, so for such a model the prefix cache declines every lookup
+    (counted, ``kv_stats()["prefix"]["declined_state"]``) and the
+    options that would park, catch up, import or speculate are refused
+    at construction (:class:`UnsupportedBlock`).
+
     **SLO-tiered scheduling** (ROADMAP item 4): the step loop is a
     latency-SLO scheduler over three per-tenant tiers resolved from
     the TLV-22 identity via a :class:`TierRegistry`:
@@ -387,6 +407,22 @@ class ContinuousBatcher:
                              "len rewind)")
         if self.spec_k > 0 and draft_params is None:
             raise ValueError("spec_decode_k requires draft_params")
+        if not cfg.plain_block():
+            # nothing runs such a model wrong silently: what this
+            # engine does not port declines here, by name
+            for on, what in (
+                    (not self.paged, "paged=False (the contiguous cache)"),
+                    (self.spec_k > 0, "spec_decode_k (speculative verify)"),
+                    (self.host_slots > 0,
+                     "host_slots (park/resume and host spill)"),
+                    (self.chunk_budget > 0,
+                     "prefill_chunk_tokens (chunked prefill)")):
+                if on:
+                    raise UnsupportedBlock(
+                        f"{what} runs the program's first block only: "
+                        "a layer schedule with state layers, grouped "
+                        "heads or a gated FFN serves through the plain "
+                        "paged engine")
         self.tiers = tiers
         # the HEAVY half (jit wrappers + the device KV-pool allocation)
         # is deferred to the batcher thread's first iteration: the
@@ -409,6 +445,12 @@ class ContinuousBatcher:
         # live positions against the block table's whole width
         self._attn_pages_read = 0
         self._attn_pages_table = 0
+        # the state pool: blocks written by an admission and let go by
+        # an eviction (state layers only), and slots held, over steps
+        self._state_inserts = 0
+        self._state_releases = 0
+        self._state_held_steps = 0
+        self._state_slot_steps = 0
         # paged-mode engine state (built in _ensure_engine)
         self._alloc = None                        # kv.pages.PageAllocator
         self._prefix = None                       # kv.pages.PrefixCache
@@ -468,6 +510,7 @@ class ContinuousBatcher:
         prefill's, and the imported last prompt token rides the next
         step — so the token stream is identical with the monolithic
         path by the same teacher-forcing argument as `_admit`'s."""
+        require_plain_block(self.cfg, "join_imported (KV import / disagg)")
         sess = _Session(stream, None, int(max_new))
         sess.cache1 = cache1
         sess.ctx_len = int(ctx_len)
@@ -513,6 +556,16 @@ class ContinuousBatcher:
         if self.paged:
             out["attn"] = {"pages_read": self._attn_pages_read,
                            "pages_table": self._attn_pages_table}
+            # a slot's block of the state pool (no bytes where the
+            # schedule has no state layer)
+            out["state"] = {"slots": self.slots,
+                            "held": len(self._sessions),
+                            "held_steps": self._state_held_steps,
+                            "slot_steps": self._state_slot_steps,
+                            "bytes": self.slots
+                            * state_slot_bytes(self.cfg),
+                            "inserts": self._state_inserts,
+                            "releases": self._state_releases}
         if self._alloc is not None:
             out["alloc"] = self._alloc.stats()
         if self._prefix is not None:
@@ -587,11 +640,14 @@ class ContinuousBatcher:
                                          donate_argnums=(0,))
             gather, scatter, insert, chunk_prefill = make_paged_io(
                 self.cfg, self.page, chunk=self._chunk_w)
-            self._gather_j = jax.jit(gather)
-            self._scatter_j = jax.jit(scatter, donate_argnums=(0,))
             self._insert = jax.jit(insert, donate_argnums=(0,))
-            self._chunk_j = jit_with_params(chunk_prefill, self.params,
-                                            donate_argnums=(0,))
+            if self.cfg.plain_block():
+                # (a block beyond the first has no spill, resume or
+                # catch-up program: __init__ refused what enters them)
+                self._gather_j = jax.jit(gather)
+                self._scatter_j = jax.jit(scatter, donate_argnums=(0,))
+                self._chunk_j = jit_with_params(
+                    chunk_prefill, self.params, donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
             if self.spec_k > 0:
                 # draft engine: the SMALL model runs k cheap
@@ -630,9 +686,15 @@ class ContinuousBatcher:
         if self._alloc is None:
             pb = paged_page_bytes(self.cfg, self.page)
             self._alloc = PageAllocator(self.num_pages, self.page, pb)
+            # a catch-up slice is the first block's only: grouped
+            # heads without state layers serve with no prefix cache; a
+            # model with state layers keeps one that declines, counted
             self._prefix = PrefixCache(
-                self._alloc, budget_pages=self.prefix_budget) \
-                if self.prefix_enabled else None
+                self._alloc, budget_pages=self.prefix_budget,
+                state_layers=self.cfg.has_state) \
+                if self.prefix_enabled and (self.cfg.plain_block()
+                                            or self.cfg.has_state) \
+                else None
             if self.host_slots > 0 and self._host is None:
                 self._host = HostPagePool(self.host_slots, pb)
 
@@ -824,10 +886,12 @@ class ContinuousBatcher:
             sess.cache1 = None
             last = int(sess.last_token)
             start_len = ctx_len
-        elif covered == ctx_len:
+        elif covered == ctx_len and not self.cfg.has_state:
             # full prefix hit (or empty context): the aliased pages
             # ARE the covered context's KV (prefill is deterministic —
-            # identical values), no prefill and ZERO copies
+            # identical values), no prefill and ZERO copies.  (With
+            # state layers even an empty context takes the next branch:
+            # its insert is what clears the slot's state.)
             last = int(sess.prompt[-1])
             start_len = ctx_len
         elif covered == 0 and not self.chunk_budget:
@@ -836,8 +900,13 @@ class ContinuousBatcher:
                                                sess.prompt)
             self.prefills_run += 1
             ph(PH_INSERT_DISPATCH)
+            # a block beyond the first also takes the slot: in the
+            # same program the state layers' blocks are written over
+            # whatever the slot's last session left there
+            slot = () if self.cfg.plain_block() else (jnp.int32(free),)
             self._cache = self._insert(self._cache, jnp.asarray(row),
-                                       cache1)
+                                       cache1, *slot)
+            self._state_inserts += int(self.cfg.has_state)
             last = int(sess.prompt[-1])
             start_len = ctx_len
             if self._prefix is not None:
@@ -1203,6 +1272,8 @@ class ContinuousBatcher:
                 jnp.asarray(self._active))
         self._cache = cache
         self._steps += 1
+        self._state_held_steps += len(self._sessions)
+        self._state_slot_steps += self.slots
         toks = jnp.argmax(logits, axis=-1)
         # the round's one sync, in a phase of its own: one sample a step
         ph(PH_DEVICE_WAIT)
@@ -1300,6 +1371,9 @@ class ContinuousBatcher:
     def _evict(self, sess: _Session, reason: Optional[str]) -> None:
         self._sessions.pop(sess.slot, None)
         self._active[sess.slot] = False
+        # (the slot's block of the state pool is simply let go: the
+        # next admission writes over it)
+        self._state_releases += int(self.cfg.has_state)
         if self.paged and sess.pages:
             self._alloc.release_all(sess.pages)
             sess.pages = []
@@ -1491,7 +1565,8 @@ class LMService(Service):
         # single-stream decode).  Programs compile per
         # (batch, prompt_len, bucketed max_new) and are reused.
         from .transformer_lm import make_scan_generator
-        self._gen = make_scan_generator(self.cfg, self.params)
+        self._gen = make_scan_generator(self.cfg, self.params) \
+            if self.cfg.plain_block() else None
         # continuous-batching decode engine, built lazily at the first
         # Decode call (Generate-only deployments never pay the batch
         # step compile).  scan_layers configs serve Generate only.
@@ -1532,6 +1607,12 @@ class LMService(Service):
                                    offset=12).reshape(b, s)
         except (struct.error, ValueError) as e:
             cntl.set_failed(Errno.EREQUEST, f"bad generate request: {e}")
+            return None
+        if self._gen is None:
+            cntl.set_failed(
+                Errno.EREQUEST,
+                "Generate (the contiguous cache) serves the program's "
+                "first block only: use Decode")
             return None
         if b == 0 or s == 0:
             cntl.set_failed(Errno.EREQUEST, "empty prompt")
@@ -1611,8 +1692,14 @@ class LMService(Service):
         and wrong only for same-shape different-weight deployments,
         which a fleet rollout should version explicitly anyway."""
         c = self.cfg
-        return (f"{c.vocab}:{c.dim}:{c.heads}:{c.depth}:{c.max_seq}:"
-                f"{self._param_bytes}:{int(self.quantized)}").encode()
+        fp = (f"{c.vocab}:{c.dim}:{c.heads}:{c.depth}:{c.max_seq}:"
+              f"{self._param_bytes}:{int(self.quantized)}")
+        if not c.plain_block():
+            # what else decides the layout of pages and state blocks
+            fp += (f":{c.kv_heads}:{int(c.rope)}:{c.ffn}:{c.ffn_dim}:"
+                   f"{c.schedule()}:"
+                   f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
+        return fp.encode()
 
     def Decode(self, cntl, request):
         """Server-streaming decode: same request wire format as
@@ -1659,9 +1746,16 @@ class LMService(Service):
     def Info(self, cntl, request):
         import json
         c = self.cfg
-        return json.dumps({"vocab": c.vocab, "dim": c.dim,
-                           "heads": c.heads, "depth": c.depth,
-                           "max_seq": c.max_seq,
-                           "quantized": self.quantized,
-                           "param_bytes": self._param_bytes,
-                           }).encode()
+        info = {"vocab": c.vocab, "dim": c.dim,
+                "heads": c.heads, "depth": c.depth,
+                "max_seq": c.max_seq,
+                "quantized": self.quantized,
+                "param_bytes": self._param_bytes}
+        if not c.plain_block():
+            info.update(
+                kv_heads=c.kv_heads, ffn=c.ffn, ffn_dim=c.ffn_dim,
+                mixers=c.schedule(),
+                state_pool={"slots": self.decode_slots,
+                            "bytes": self.decode_slots
+                            * state_slot_bytes(c)})
+        return json.dumps(info).encode()

@@ -35,12 +35,47 @@ class LMConfig:
                  lr: float = 0.05, moe_experts: int = 0,
                  moe_capacity: float = 2.0, moe_aux_weight: float = 0.01,
                  moe_top_k: int = 1, use_flash: bool = False,
-                 scan_layers: bool = False, attn_impl: str = "auto"):
+                 scan_layers: bool = False, attn_impl: str = "auto",
+                 kv_heads: Optional[int] = None, rope: bool = True,
+                 ffn: str = "gelu", ffn_dim: Optional[int] = None,
+                 tie_embed: bool = False, final_norm: bool = False,
+                 mixers: Optional[Tuple[str, ...]] = None,
+                 ssm_expand: int = 2, ssm_state: int = 16,
+                 ssm_conv: int = 4, ssm_dt_rank: Optional[int] = None):
         assert dim % heads == 0
-        assert (dim // heads) % 2 == 0, "head dim must be even for RoPE"
+        assert not rope or (dim // heads) % 2 == 0, \
+            "head dim must be even for RoPE"
         self.vocab = vocab
         self.dim = dim
         self.heads = heads
+        # the block beyond the program's first one (every default is
+        # that block): key/value heads shared by groups of query heads,
+        # attention with no positional term, a gated FFN of its own
+        # width, the embedding table as the unembedding, a final norm,
+        # and a per-layer schedule of mixers, "attn" or "ssm" (a
+        # Mamba-1 state-space layer, models/ssm_mixer.py, whose state
+        # is per SEQUENCE, not per token).  The paged serving factories
+        # run all of it; every other factory runs the first block only
+        # and declines the rest by name (UnsupportedBlock)
+        self.kv_heads = heads if kv_heads is None else int(kv_heads)
+        assert heads % self.kv_heads == 0
+        self.head_dim = dim // heads
+        self.rope = bool(rope)
+        assert ffn in ("gelu", "gated_silu")
+        self.ffn = ffn
+        self.ffn_dim = dim * mlp_mult if ffn_dim is None else int(ffn_dim)
+        self.tie_embed = bool(tie_embed)
+        self.final_norm = bool(final_norm)
+        self.mixers = ("attn",) * depth if mixers is None \
+            else tuple(mixers)
+        assert len(self.mixers) == depth \
+            and set(self.mixers) <= {"attn", "ssm"}
+        self.has_state = "ssm" in self.mixers
+        self.ssm_inner = int(ssm_expand) * dim
+        self.ssm_state = int(ssm_state)
+        self.ssm_conv = int(ssm_conv)
+        self.ssm_dt_rank = -(-dim // 16) if ssm_dt_rank is None \
+            else int(ssm_dt_rank)
         self.depth = depth
         self.mlp_mult = mlp_mult
         self.max_seq = max_seq
@@ -65,6 +100,25 @@ class LMConfig:
         # instead of O(depth) — the XLA-idiomatic deep-model form
         self.scan_layers = scan_layers
 
+    def plain_block(self) -> bool:
+        """True for the program's first block, the only one the
+        training, contiguous, scanned, speculative and export
+        factories run."""
+        return (not self.has_state and self.kv_heads == self.heads
+                and self.rope and self.ffn == "gelu"
+                and self.ffn_dim == self.dim * self.mlp_mult
+                and not self.tie_embed and not self.final_norm)
+
+    def schedule(self) -> str:
+        """The mixers' initials in layer order (``"sass"``)."""
+        return "".join(m[0] for m in self.mixers)
+
+    def attn_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, m in enumerate(self.mixers) if m == "attn")
+
+    def ssm_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, m in enumerate(self.mixers) if m == "ssm")
+
     def moe_cfg(self):
         from .moe import MoEConfig
         return MoEConfig(dim=self.dim, hidden=self.dim * self.mlp_mult,
@@ -74,10 +128,31 @@ class LMConfig:
                          top_k=self.moe_top_k)
 
 
+class UnsupportedBlock(NotImplementedError):
+    """A factory was asked for a block it does not run: state layers,
+    grouped key/value heads, attention without rotary, the gated FFN,
+    a tied table or a final norm outside the paged serving factories.
+    Nothing runs such a model wrong silently."""
+
+
+def require_plain_block(cfg: LMConfig, what: str) -> None:
+    """Raise :class:`UnsupportedBlock` naming the path ``what`` unless
+    ``cfg`` is the program's first block."""
+    if cfg.plain_block():
+        return
+    why = "state layers (per-sequence recurrent state)" if cfg.has_state \
+        else "a block other than MHA + rotary + GELU MLP + untied table"
+    raise UnsupportedBlock(
+        f"{what} declines {why}: only the paged serving factories "
+        "(make_paged_batch_decode, make_paged_io) run it")
+
+
 def init_params(rng, cfg: LMConfig) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
+    if not cfg.plain_block():
+        return _init_block_params(rng, cfg)
     ks = jax.random.split(rng, 2 + cfg.depth)
     scale = 1.0 / math.sqrt(cfg.dim)
     params: Dict[str, Any] = {
@@ -111,6 +186,47 @@ def init_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         blks = [params.pop(f"blk{i}") for i in range(cfg.depth)]
         params["blocks"] = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *blks)
+    return params
+
+
+def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
+    """Seeded weights of a block beyond the first (see ``LMConfig``):
+    matrices normal at ``1/sqrt(fan_in)``, norms one.  ``wqkv`` holds
+    ``heads`` query and ``2 * kv_heads`` key/value heads side by side;
+    a gated FFN's ``w1`` holds gate and up side by side."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import ssm_mixer
+
+    if cfg.scan_layers or cfg.moe_experts > 0:
+        raise UnsupportedBlock(
+            "scan_layers and MoE run the program's first block only")
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.ffn_dim
+
+    def normal(k, shape, fan_in):
+        return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
+
+    ks = jax.random.split(rng, 2 + cfg.depth)
+    params: Dict[str, Any] = {"embed": normal(ks[0], (cfg.vocab, d), d)}
+    if not cfg.tie_embed:
+        params["unembed"] = normal(ks[1], (d, cfg.vocab), d)
+    if cfg.final_norm:
+        params["norm_f"] = jnp.ones((d,), jnp.float32)
+    gated = cfg.ffn == "gated_silu"
+    for i in range(cfg.depth):
+        bk = jax.random.split(ks[2 + i], 5)
+        if cfg.mixers[i] == "ssm":
+            blk = ssm_mixer.init_layer(bk[0], cfg)
+        else:
+            blk = {"wqkv": normal(
+                bk[0], (d, (cfg.heads + 2 * cfg.kv_heads) * hd), d),
+                "wo": normal(bk[1], (cfg.heads * hd, d), cfg.heads * hd)}
+        blk["ln1"] = jnp.ones((d,), jnp.float32)
+        blk["ln2"] = jnp.ones((d,), jnp.float32)
+        blk["w1"] = normal(bk[2], (d, 2 * f if gated else f), d)
+        blk["w2"] = normal(bk[3], (f, d), f)
+        params[f"blk{i}"] = blk
     return params
 
 
@@ -161,6 +277,40 @@ def _rope(x, sin, cos):
                             x1 * sin + x2 * cos], axis=-1)
 
 
+def _split_qkv(cfg: LMConfig, qkv):
+    """The fused projection's columns: ``heads`` query heads, then
+    ``kv_heads`` key and as many value heads."""
+    import jax.numpy as jnp
+    nq = cfg.heads * cfg.head_dim
+    nkv = cfg.kv_heads * cfg.head_dim
+    return jnp.split(qkv, [nq, nq + nkv], axis=-1)
+
+
+def _ffn(cfg: LMConfig, bp, h):
+    """The dense feed-forward part of a serving layer: ``gelu(h w1)
+    w2``, or gated, ``(silu(gate) * up) w2`` with gate and up side by
+    side in ``w1``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.quant import qmatmul
+    up = qmatmul(h, bp["w1"])
+    if cfg.ffn == "gated_silu":
+        gate, up = jnp.split(up, 2, axis=-1)
+        return qmatmul(jax.nn.silu(gate) * up, bp["w2"])
+    return qmatmul(jax.nn.gelu(up), bp["w2"])
+
+
+def _logits(cfg: LMConfig, params, x):
+    """Final norm (where the block has one) and the unembedding, the
+    embedding table itself where it is tied."""
+    from ..ops.quant import qmatmul
+    if cfg.final_norm:
+        x = _rmsnorm(x, params["norm_f"])
+    return qmatmul(x, params["embed"].T if cfg.tie_embed
+                   else params["unembed"])
+
+
 def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
     """Forward fn: (params, ids[b, s]) -> logits[b, s, vocab].
     With ``mesh`` + ``sp_axis``, attention is ring attention over the
@@ -168,6 +318,7 @@ def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
     import jax
     import jax.numpy as jnp
 
+    require_plain_block(cfg, "make_forward (training)")
     if mesh is not None and sp_axis is not None:
         from ..parallel.ring_attention import make_ring_attention
         attend = make_ring_attention(mesh, sp_axis, causal=cfg.causal)
@@ -235,6 +386,85 @@ def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
     return forward
 
 
+def _prefill_attn_layer(cfg: LMConfig, bp, x, sin, cos, ffn):
+    """One attention layer of prompt processing, the one home of every
+    serving prefill's: returns (x, k, v) with k/v (``kv_heads`` of
+    them) written into fresh max_seq caches.  ``ffn(bp, h)`` is the
+    caller's feed-forward part."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.quant import qmatmul
+
+    b, s = x.shape[0], x.shape[1]
+    hd = cfg.head_dim
+    h = _rmsnorm(x, bp["ln1"])
+    qkv = qmatmul(h, bp["wqkv"])
+    q, k, v = _split_qkv(cfg, qkv)
+    q = q.reshape(b, s, cfg.heads, hd)
+    k = k.reshape(b, s, cfg.kv_heads, hd)
+    v = v.reshape(b, s, cfg.kv_heads, hd)
+    if cfg.rope:
+        q, k = (_rope(t, sin, cos) for t in (q, k))
+    kc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, hd), jnp.float32)
+    vc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, hd), jnp.float32)
+    kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
+    vc = jax.lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
+    if cfg.kv_heads != cfg.heads:
+        k, v = (jnp.repeat(t, cfg.heads // cfg.kv_heads, axis=2)
+                for t in (k, v))
+    # seq-adaptive: long prompts prefill through the flash kernel
+    # (O(s) memory) instead of materializing (s, s) scores per
+    # layer — honoring the same impl override as make_forward
+    from ..ops.flash_attention import attention
+    impl = "flash" if cfg.use_flash else cfg.attn_impl
+    att = attention(q, k, v, causal=cfg.causal, impl=impl)
+    x = x + qmatmul(att.reshape(b, s, cfg.heads * hd), bp["wo"])
+    x = x + ffn(bp, _rmsnorm(x, bp["ln2"]))
+    return x, kc, vc
+
+
+def _make_block_prefill(cfg: LMConfig):
+    """The prompt pass of a block beyond the first (a mixer schedule,
+    grouped heads, ...): ``prefill(params, ids[1, s], ctx_len) ->
+    (cache, logits)``.  ``ids`` is a zero-padded bucket and ``ctx_len``
+    its true length: a causal attention forgives the padding, a
+    recurrence does not, so a state layer returns its state AT
+    ``ctx_len`` (``h<i>`` and the convolution's tail ``c<i>``), an
+    attention layer ``k<i>``/``v<i>`` as :func:`make_decode`'s does;
+    the logits are those of position ``ctx_len - 1``."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from . import ssm_mixer
+
+    ffn = functools.partial(_ffn, cfg)
+
+    def prefill(params, ids, ctx_len):
+        b, s = ids.shape
+        assert b == 1 and s <= cfg.max_seq
+        x = params["embed"][ids]
+        sin, cos = _rope_tables(s, cfg.head_dim) if cfg.rope \
+            else (None, None)
+        cache = {"len": jnp.int32(s)}
+        for i in range(cfg.depth):
+            bp = params[f"blk{i}"]
+            if cfg.mixers[i] == "ssm":
+                out, h, tail = ssm_mixer.prefill(
+                    cfg, bp, _rmsnorm(x, bp["ln1"]), ctx_len)
+                x = x + out
+                x = x + ffn(bp, _rmsnorm(x, bp["ln2"]))
+                cache[f"h{i}"], cache[f"c{i}"] = h, tail
+            else:
+                x, kc, vc = _prefill_attn_layer(cfg, bp, x, sin, cos, ffn)
+                cache[f"k{i}"], cache[f"v{i}"] = kc, vc
+        last = jnp.take(x, jnp.maximum(ctx_len - 1, 0), axis=1)
+        return cache, _logits(cfg, params, last)
+
+    return prefill
+
+
 def _rope_at(x, pos, head_dim: int):
     """Rotary embedding for ONE position (traced scalar) — same math as
     the table path, built for a single position and fed to _rope so the
@@ -263,6 +493,7 @@ def make_decode(cfg: LMConfig):
     import jax
     import jax.numpy as jnp
 
+    require_plain_block(cfg, "make_decode (the contiguous cache)")
     hd = cfg.dim // cfg.heads
     if cfg.scan_layers and cfg.moe_experts > 0:
         raise NotImplementedError(
@@ -289,28 +520,7 @@ def make_decode(cfg: LMConfig):
         return qmatmul(x_last, params["unembed"])
 
     def prefill_layer(bp, x, sin, cos):
-        """One block of prompt processing; returns (x, k, v) with k/v
-        written into fresh max_seq caches."""
-        b, s = x.shape[0], x.shape[1]
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (b, s, cfg.heads, hd)
-        q, k = (_rope(t.reshape(shp), sin, cos) for t in (q, k))
-        v = v.reshape(shp)
-        kc = jnp.zeros((b, cfg.max_seq, cfg.heads, hd), jnp.float32)
-        vc = jnp.zeros((b, cfg.max_seq, cfg.heads, hd), jnp.float32)
-        kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
-        vc = jax.lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
-        # seq-adaptive: long prompts prefill through the flash kernel
-        # (O(s) memory) instead of materializing (s, s) scores per
-        # layer — honoring the same impl override as make_forward
-        from ..ops.flash_attention import attention
-        impl = "flash" if cfg.use_flash else cfg.attn_impl
-        att = attention(q, k, v, causal=cfg.causal, impl=impl)
-        x = x + qmatmul(att.reshape(b, s, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, kc, vc
+        return _prefill_attn_layer(cfg, bp, x, sin, cos, mlp)
 
     def decode_layer(bp, x, kc, vc, pos):
         """One block of single-token decode; returns (x, kc, vc) with
@@ -415,6 +625,7 @@ def kv_page_specs(cfg: LMConfig, batch: int = 1):
     :func:`export_decode_cache` emits and the import side rebuilds
     from.  Layout is owned by the MODEL (like :func:`empty_cache`):
     the wire carries sizes for validation only, never shape."""
+    require_plain_block(cfg, "kv_page_specs (KV export / disagg)")
     if cfg.scan_layers:
         raise NotImplementedError(
             "paged KV export supports unrolled layers only (the "
@@ -431,6 +642,7 @@ def export_decode_cache(cfg: LMConfig, cache):
     :func:`kv_page_specs` order.  No data motion here: the pages ARE
     the live cache arrays — the transfer plane decides whether they
     move as registered memory (descriptor) or bytes."""
+    require_plain_block(cfg, "export_decode_cache (KV export / disagg)")
     if cfg.scan_layers:
         raise NotImplementedError(
             "paged KV export supports unrolled layers only")
@@ -537,6 +749,7 @@ def make_batch_decode(cfg: LMConfig, chunk: Optional[int] = None):
     import jax
     import jax.numpy as jnp
 
+    require_plain_block(cfg, "make_batch_decode (paged=False)")
     hd = cfg.dim // cfg.heads
     if cfg.scan_layers:
         raise NotImplementedError(
@@ -690,56 +903,75 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     mask.  Token identity with :func:`make_batch_decode` is no longer
     by construction on the TPU: the per-lane tests pin it on the cpu
     backend and ``tests/test_paged_attention.py`` holds the kernel to
-    the plain formulation."""
+    the plain formulation.
+
+    A block beyond the first (``LMConfig.mixers``, ``kv_heads``, ...)
+    is served here and nowhere else.  Only attention layers have pools
+    (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
+    state layer has ``sh<i>``/``sc<i>``, one block of recurrent state
+    for each SLOT, which the step moves one position where the slot is
+    ``active``.  ``prefill`` is then ``prefill(params, ids[1, s],
+    ctx_len)`` (:func:`_make_block_prefill`)."""
     import jax
     import jax.numpy as jnp
 
-    hd = cfg.dim // cfg.heads
+    hd = cfg.head_dim
     if cfg.scan_layers:
         raise NotImplementedError(
             "paged batch decode supports unrolled layers only")
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    pps = cfg.max_seq // page       # pages per slot (block-table width)
     if cfg.moe_experts > 0:
         from .moe import forward_grouped as moe_forward
         moe_cfg = cfg.moe_cfg()
 
     from ..ops import paged_attention
     from ..ops.quant import qmatmul
+    from . import ssm_mixer
+
+    grouped = cfg.kv_heads != cfg.heads
+    kvh = cfg.kv_heads
 
     def mlp(bp, h):
         if cfg.moe_experts > 0:
             out, _ = moe_forward(bp["moe"], h, moe_cfg)
             return out
-        up = qmatmul(h, bp["w1"])
-        return qmatmul(jax.nn.gelu(up), bp["w2"])
+        return _ffn(cfg, bp, h)
 
-    def decode_layer(bp, x, pk, pv, bt, pos, att_pos):
-        """One block, one token per slot, block-table addressing."""
+    def attn_mixer(bp, x, pk, pv, bt, pos, att_pos):
+        """One attention mixer, one token per slot, block-table
+        addressing; the residual added."""
         b = x.shape[0]
         h = _rmsnorm(x, bp["ln1"])
         qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (b, 1, cfg.heads, hd)
-        q = _rope_at_vec(q.reshape(shp), pos, hd)
-        k = _rope_at_vec(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
+        q, k, v = _split_qkv(cfg, qkv)
+        q = q.reshape(b, 1, cfg.heads, hd)
+        k = k.reshape(b, 1, kvh, hd)
+        v = v.reshape(b, 1, kvh, hd)
+        if cfg.rope:
+            q = _rope_at_vec(q, pos, hd)
+            k = _rope_at_vec(k, pos, hd)
 
         # scatter this step's row into each slot's CURRENT page
         page_idx = bt[jnp.arange(b), pos // page]
         row = pos % page
-        pk = pk.at[page_idx, row].set(k[:, 0])
-        pv = pv.at[page_idx, row].set(v[:, 0])
+        if grouped:
+            # rows of a grouped pool are (token, key/value head) pairs
+            rows = row[:, None] * kvh + jnp.arange(kvh)[None, :]
+            pk = pk.at[page_idx[:, None], rows].set(k[:, 0])
+            pv = pv.at[page_idx[:, None], rows].set(v[:, 0])
+        else:
+            pk = pk.at[page_idx, row].set(k[:, 0])
+            pv = pv.at[page_idx, row].set(v[:, 0])
 
         # attend over each slot's live pages where they lie (rows past
         # ``att_pos`` are garbage and are never admitted); an inactive
         # slot's output is discarded, so it reads one page, not the
         # ``len`` its last session left behind
-        att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos)
-        x = x + qmatmul(att.reshape(b, 1, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
+        att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
+                                        page)
+        x = x + qmatmul(att.reshape(b, 1, cfg.heads * hd), bp["wo"])
         return x, pk, pv
 
     def step(params, cache, bt, token, active):
@@ -748,45 +980,81 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         att_pos = jnp.where(active, pos, 0)
         x = params["embed"][token][:, None, :]
         for i in range(cfg.depth):
-            x, pk, pv = decode_layer(params[f"blk{i}"], x,
-                                     cache[f"pk{i}"], cache[f"pv{i}"],
-                                     bt, pos, att_pos)
-            cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+            bp = params[f"blk{i}"]
+            if cfg.mixers[i] == "ssm":
+                # the slot's recurrent state moves one position where
+                # the slot is active and stays where it is not
+                out, h, tail = ssm_mixer.step(
+                    cfg, bp, _rmsnorm(x[:, 0], bp["ln1"]),
+                    cache[f"sh{i}"], cache[f"sc{i}"], active)
+                x = x + out[:, None]
+                cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
+            else:
+                x, pk, pv = attn_mixer(bp, x, cache[f"pk{i}"],
+                                       cache[f"pv{i}"], bt, pos, att_pos)
+                cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+            x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
-        return cache, qmatmul(x[:, 0], params["unembed"])
+        return cache, _logits(cfg, params, x[:, 0])
 
-    prefill, _ = make_decode(cfg)
+    if cfg.plain_block():
+        prefill, _ = make_decode(cfg)
+    else:
+        prefill = _make_block_prefill(cfg)
     return prefill, step
+
+
+def _paged_pool_shape(cfg: LMConfig, num_pages: int, page: int) -> tuple:
+    """One attention layer's k (or v) pool: ``(num_pages, page, heads,
+    hd)``, or, where fewer key/value heads serve groups of query
+    heads, ``(num_pages, page * kv_heads, hd)``, a row a (token,
+    key/value head) pair (``ops.paged_attention``'s two layouts)."""
+    if cfg.kv_heads != cfg.heads:
+        return (num_pages, page * cfg.kv_heads, cfg.head_dim)
+    return (num_pages, page, cfg.heads, cfg.head_dim)
 
 
 def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
                       page: int):
     """A fresh page-pool KV cache for :func:`make_paged_batch_decode`:
-    per layer one ``(num_pages, page, heads, hd)`` k and v pool (page 0
-    reserved as the garbage page) plus the per-slot ``len`` vector.
+    per attention layer one ``(num_pages, page, heads, hd)`` k and v
+    pool (page 0 reserved as the garbage page), per state layer the
+    slots' recurrent state, plus the per-slot ``len`` vector.
     The block table is NOT here — it is host state
     (``kv.pages.PageAllocator`` decides it), passed to the step."""
     import jax.numpy as jnp
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    hd = cfg.dim // cfg.heads
+    from . import ssm_mixer
+
+    shape = _paged_pool_shape(cfg, num_pages, page)
     cache = {}
-    for i in range(cfg.depth):
-        cache[f"pk{i}"] = jnp.zeros((num_pages, page, cfg.heads, hd),
-                                    jnp.float32)
-        cache[f"pv{i}"] = jnp.zeros((num_pages, page, cfg.heads, hd),
-                                    jnp.float32)
+    for i in cfg.attn_layers():
+        cache[f"pk{i}"] = jnp.zeros(shape, jnp.float32)
+        cache[f"pv{i}"] = jnp.zeros(shape, jnp.float32)
+    # a state layer holds no page: one block of recurrent state for
+    # each SLOT, whatever the context length (the state pool)
+    for i in cfg.ssm_layers():
+        h, tail = ssm_mixer.state_shapes(cfg, slots)
+        cache[f"sh{i}"] = jnp.zeros(h, jnp.float32)
+        cache[f"sc{i}"] = jnp.zeros(tail, jnp.float32)
     cache["len"] = jnp.zeros((slots,), jnp.int32)
     return cache
 
 
 def paged_page_bytes(cfg: LMConfig, page: int) -> int:
-    """Device bytes one LOGICAL page pins across every layer's k+v
-    pools (the allocator's per-page accounting unit)."""
-    hd = cfg.dim // cfg.heads
-    return 2 * cfg.depth * page * cfg.heads * hd * 4       # float32
+    """Device bytes one LOGICAL page pins across every attention
+    layer's k+v pools (the allocator's per-page accounting unit)."""
+    return 2 * len(cfg.attn_layers()) * page * cfg.kv_heads \
+        * cfg.head_dim * 4                                 # float32
+
+
+def state_slot_bytes(cfg: LMConfig) -> int:
+    """Device bytes one SLOT pins across every state layer's pool."""
+    from . import ssm_mixer
+    return len(cfg.ssm_layers()) * ssm_mixer.state_bytes(cfg)
 
 
 def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
@@ -822,6 +1090,8 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
+    if not cfg.plain_block():
+        return _make_block_paged_io(cfg, page, chunk)
     pps = cfg.max_seq // page
     hd = cfg.dim // cfg.heads
 
@@ -911,6 +1181,48 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
     return gather, scatter, insert, chunk_prefill
 
 
+def _make_block_paged_io(cfg: LMConfig, page: int, chunk):
+    """:func:`make_paged_io` for a block beyond the first.  ``insert``
+    is ``insert(cache, page_ids[pps], src, slot) -> cache``: an
+    attention layer's prefilled ``k<i>``/``v<i>`` blockified into the
+    session's pages, a state layer's ``h<i>``/``c<i>`` written over
+    WHATEVER the slot's last session left in its block of the state
+    pool.  The other three decline by name: a page of keys restores no
+    recurrent state, so spill/resume (``gather``/``scatter``) and the
+    catch-up slices (``chunk_prefill``) are not entered for such a
+    model (the batcher refuses the options that would)."""
+    import jax
+
+    pps = cfg.max_seq // page
+
+    def declined(what):
+        def fn(*_a, **_k):
+            require_plain_block(cfg, what)
+        return fn
+
+    def insert(cache, page_ids, src, slot):
+        cache = dict(cache)
+        for i in range(cfg.depth):
+            if cfg.mixers[i] == "ssm":
+                for pool, new in ((f"sh{i}", f"h{i}"), (f"sc{i}", f"c{i}")):
+                    cache[pool] = jax.lax.dynamic_update_slice(
+                        cache[pool], src[new],
+                        (slot,) + (0,) * (src[new].ndim - 1))
+                continue
+            shape = _paged_pool_shape(cfg, pps, page)
+            cache[f"pk{i}"] = cache[f"pk{i}"].at[page_ids].set(
+                src[f"k{i}"][0].reshape(shape))
+            cache[f"pv{i}"] = cache[f"pv{i}"].at[page_ids].set(
+                src[f"v{i}"][0].reshape(shape))
+        return cache
+
+    io = (declined("make_paged_io gather (host spill)"),
+          declined("make_paged_io scatter (host resume)"), insert)
+    if chunk is None:
+        return io
+    return io + (declined("make_paged_io chunk_prefill (catch-up)"),)
+
+
 def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
     """Speculative-decoding TARGET verification over the paged cache —
     one multi-token step per round: ``width = k + 1`` candidate tokens
@@ -943,6 +1255,8 @@ def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
     import jax
     import jax.numpy as jnp
 
+    require_plain_block(cfg, "make_paged_spec_verify (speculative "
+                        "verify)")
     hd = cfg.dim // cfg.heads
     if cfg.scan_layers:
         raise NotImplementedError(

@@ -18,6 +18,11 @@ operands; the VPU needs neither and keeps up with the pages' bytes
 (measured on the v5e: 710-745 GB/s of 819 over live pages, PERF.md).
 The arithmetic is float32 throughout, as XLA runs the einsums of
 :func:`reference` (the formulation the step had, and has off the TPU).
+
+Where fewer key/value heads serve groups of query heads the pool is
+``(num_pages, page * kv_heads, hd)``, a row a (token, key/value head)
+pair, and a second kernel under the same name walks it
+(:func:`_grouped_kernel`).
 """
 
 from __future__ import annotations
@@ -35,24 +40,34 @@ from .flash_attention import _resolve_interpret
 _WAVE_BYTES = 512 << 10
 
 
-def reference(q, pk, pv, bt, pos):
+def reference(q, pk, pv, bt, pos, page: Optional[int] = None):
     """The plain formulation: gather every slot's whole block table
     into a ``(slots, max_seq, heads, hd)`` view and run a dense masked
     attention over it.  What the step runs off the TPU, and what the
-    kernel is tested against."""
+    kernels are tested against.  Pools of three dimensions are the
+    grouped layout, ``(num_pages, page * kv_heads, hd)`` with a row a
+    (token, key/value head) pair and each key/value head shared by
+    ``heads // kv_heads`` query heads; they need ``page``."""
     import jax.numpy as jnp
 
     b, heads, hd = q.shape
-    page = pk.shape[1]
+    if pk.ndim == 4:
+        page, kv_heads = pk.shape[1], heads
+    else:
+        kv_heads = pk.shape[1] // page
     max_seq = bt.shape[1] * page
-    kc = pk[bt].reshape(b, max_seq, heads, hd)
-    vc = pv[bt].reshape(b, max_seq, heads, hd)
-    s_mat = jnp.einsum("bhd,bkhd->bhk", q, kc,
+
+    def view(pool):
+        x = pool[bt].reshape(b, max_seq, kv_heads, hd)
+        return x if kv_heads == heads else \
+            jnp.repeat(x, heads // kv_heads, axis=2)
+
+    s_mat = jnp.einsum("bhd,bkhd->bhk", q, view(pk),
                        preferred_element_type=jnp.float32) / (hd ** 0.5)
     live = jnp.arange(max_seq)[None, :] <= pos[:, None]
     s_mat = jnp.where(live[:, None, :], s_mat, -1e30)
     p = jax.nn.softmax(s_mat, axis=-1)
-    return jnp.einsum("bhk,bkhd->bhd", p, vc,
+    return jnp.einsum("bhk,bkhd->bhd", p, view(pv),
                       preferred_element_type=jnp.float32)
 
 
@@ -189,6 +204,144 @@ def paged_decode_attention(q, pk, pv, bt, pos,
                        interpret=_resolve_interpret(interpret))
 
 
+def _grouped_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
+                    kbuf, vbuf, sems, *, page: int, kv_heads: int,
+                    wave: int):
+    """Key/value heads shared by groups of query heads: a wave's rows
+    are (token, key/value head) pairs on the sublanes, so a group's
+    ``(g, hd)`` queries meet one head's ``(tokens, hd)`` keys as the
+    two matrices they are, on the MXU in float32 (``highest``: the
+    arithmetic of :func:`reference`).  Same walk over live
+    pages, same double buffer, same online softmax as :func:`_kernel`."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, hd = q_ref.shape
+    g = heads // kv_heads
+    toks = wave * page
+    rows = page * kv_heads
+    scale = 1.0 / (hd ** 0.5)
+    hi = lax.Precision.HIGHEST
+
+    def n_pages(b):
+        return pos_ref[b] // page + 1
+
+    def wave_dma(b, w, buf, go):
+        for i in range(wave):
+            idx = w * wave + i
+
+            @pl.when(idx < n_pages(b))
+            def _():
+                pid = bt_ref[b, idx]
+                dst = pl.ds(i * rows, rows)
+                for hbm, vmem, j in ((pk_hbm, kbuf, 0), (pv_hbm, vbuf, 1)):
+                    go(pltpu.make_async_copy(
+                        hbm.at[pid], vmem.at[buf, dst], sems.at[j, buf]))
+
+    start = functools.partial(wave_dma, go=lambda c: c.start())
+    wait = functools.partial(wave_dma, go=lambda c: c.wait())
+
+    tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
+    tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
+
+    start(0, 0, 0)
+
+    def slot_body(b, gcount):
+        n_waves = (n_pages(b) + wave - 1) // wave
+        q = q_ref[b] * scale                              # (heads, hd)
+
+        def wave_body(w, carry):
+            gcount, m, l, acc = carry
+            buf = lax.rem(gcount, 2)
+
+            @pl.when(w + 1 < n_waves)
+            def _():
+                start(b, w + 1, 1 - buf)
+
+            @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
+            def _():
+                start(b + 1, 0, 1 - buf)
+
+            wait(b, w, buf)
+            lim = pos_ref[b] + 1 - w * toks
+            ms, ls, accs = [], [], []
+            for kh in range(kv_heads):
+                sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
+                    else pl.ds(0, toks)
+                k = kbuf[buf, sel, :]                     # (toks, hd)
+                # rows past pos are stale or were never fetched: they
+                # meet a zero weight, and 0 * NaN is NaN
+                v = jnp.where(tok_col < lim, vbuf[buf, sel, :], 0.0)
+                qh = q[kh * g:(kh + 1) * g]               # (g, hd)
+                s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
+                                    precision=hi,
+                                    preferred_element_type=jnp.float32)
+                s = jnp.where(tok_row < lim, s, -1e30)    # (g, toks)
+                m_prev = m[kh]
+                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+                corr = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                ms.append(m_new)
+                ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
+                accs.append(acc[kh] * corr + jnp.dot(
+                    p, v, precision=hi,
+                    preferred_element_type=jnp.float32))
+            return gcount + 1, ms, ls, accs
+
+        init = (gcount,
+                [jnp.full((g, 1), -1e30, jnp.float32)] * kv_heads,
+                [jnp.zeros((g, 1), jnp.float32)] * kv_heads,
+                [jnp.zeros((g, hd), jnp.float32)] * kv_heads)
+        gcount, _m, l, acc = lax.fori_loop(0, n_waves, wave_body, init)
+        for kh in range(kv_heads):
+            o_ref[b, kh * g:(kh + 1) * g, :] = acc[kh] / l[kh]
+        return gcount
+
+    lax.fori_loop(0, slots, slot_body, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("page", "interpret"))
+def _grouped_call(q, pk, pv, bt, pos, page: int, interpret: bool):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, hd = q.shape
+    kv_heads = pk.shape[1] // page
+    wave = pages_per_wave(page, kv_heads, hd, bt.shape[1])
+    whole = pl.BlockSpec((slots, heads, hd), lambda i, bt, pos: (0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_grouped_kernel, page=page, kv_heads=kv_heads,
+                          wave=wave),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[whole,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((2, wave * page * kv_heads, hd), pk.dtype),
+                pltpu.VMEM((2, wave * page * kv_heads, hd), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, hd), jnp.float32),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(bt, pos, q, pk, pv)
+
+
+def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
+                                   interpret: Optional[bool] = None):
+    """:func:`paged_decode_attention` for ``heads`` query heads over
+    fewer key/value heads: the pools are ``(num_pages, page *
+    kv_heads, hd)``, a row a (token, key/value head) pair."""
+    return _grouped_call(q, pk, pv, bt, pos, page=page,
+                         interpret=_resolve_interpret(interpret))
+
+
 def import_pallas() -> None:
     """Import what the kernel is written in.  Over a second of pure
     Python on the chip's host, and the first thing the first traced
@@ -197,11 +350,15 @@ def import_pallas() -> None:
     import jax.experimental.pallas.tpu  # noqa: F401
 
 
-def attention(q, pk, pv, bt, pos):
+def attention(q, pk, pv, bt, pos, page: Optional[int] = None):
     """The step's attention: the kernel on the TPU, :func:`reference`
     on the cpu backend (where the kernel would only be interpreted), as
-    ``flash_attention.attention(impl="auto")`` chooses for prefill."""
+    ``flash_attention.attention(impl="auto")`` chooses for prefill.
+    Pools of three dimensions are the grouped layout and need
+    ``page``."""
     from .device_ops import _on_tpu
-    if _on_tpu():
-        return paged_decode_attention(q, pk, pv, bt, pos)
-    return reference(q, pk, pv, bt, pos)
+    if not _on_tpu():
+        return reference(q, pk, pv, bt, pos, page)
+    if pk.ndim == 3:
+        return paged_decode_attention_grouped(q, pk, pv, bt, pos, page)
+    return paged_decode_attention(q, pk, pv, bt, pos)

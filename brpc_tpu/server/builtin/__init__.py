@@ -468,8 +468,9 @@ def _lm(server, msg, rest):
     sessions, recently finished session timelines, per-tier
     queue-wait/TTFT/ITL percentiles and SLO attainment, the batcher's
     loop by phase (histograms, totals, and how much of ``loop_ns`` they
-    account for), the queue counters, KV pool / prefix cache / host
-    tier occupancy, and the WINDOWED
+    account for), the queue counters, KV pool / state pool / prefix cache
+    / host tier occupancy, the model as ``LM.Info`` gives it (with its
+    layer schedule), and the WINDOWED
     spec-accept and prefix-hit ratios (current behavior — the lifetime
     cumulative keys stay on the bench/perf_guard plane).  One
     LmTelemetryCache window renders the whole page, same discipline as
@@ -538,6 +539,9 @@ def _lm(server, msg, rest):
         "spec": cur["spec"],
         "prefix_events": cur["prefix_events"],
         "kv": kv,
+        # what is served: widths and, for a block beyond the first, the
+        # layer schedule and the state pool (``LM.Info``'s own answer)
+        "model": json.loads(lm.Info(None, b"")) if lm is not None else {},
         "timeline_ring": {"len": lmt.ring_len(),
                           "max": lmt.ring_maxlen()},
         "enabled": lmt.telemetry_enabled(),
