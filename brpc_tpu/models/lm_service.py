@@ -17,7 +17,7 @@ from __future__ import annotations
 import struct
 import threading
 from collections import deque
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -178,7 +178,8 @@ _spec_var = PassiveDimension(("event",), lambda: spec_counters(),
 
 
 class _Session:
-    __slots__ = ("stream", "prompt", "max_new", "sent", "slot",
+    __slots__ = ("stream", "prompt", "max_new", "sent", "queued",
+                 "slot",
                  "cache1", "ctx_len", "last_token",
                  # SLO scheduling: resolved tier + rank, and the
                  # chunked-prefill fill watermark (context positions
@@ -202,6 +203,9 @@ class _Session:
         self.prompt = prompt
         self.max_new = max_new
         self.sent = 0
+        # steps dispatched for it: ``sent`` and, while the batcher has
+        # a step in flight that it takes part in, one more
+        self.queued = 0
         self.slot = -1
         self.tier = "standard"
         self.tier_rank = _TIER_RANK["standard"]
@@ -286,6 +290,24 @@ def _setlen(cache, slot, val):
     return cache
 
 
+def _settok(tokens, slot, val):
+    """Jittable one-entry poke of the step's token vector: a joining
+    session's first token goes in ON the device, over whatever the
+    vector holds for the other slots (the last step's argmax, which
+    the host may not have read yet)."""
+    import jax.lax as lax
+    return lax.dynamic_update_slice(tokens, val[None], (slot,))
+
+
+class _Flight(NamedTuple):
+    """A decode step that has been dispatched and not yet read: its
+    tokens on the device, and who held which slot when it left (a slot
+    may change hands before the step is walked)."""
+
+    toks: object            # (slots,) int32, on the device
+    snap: list              # [(slot, _Session)] of its active slots
+
+
 class ContinuousBatcher:
     """Continuous-batching decode engine: ONE decode-step loop over a
     fixed pool of session slots.  Per step, every live session advances
@@ -303,6 +325,25 @@ class ContinuousBatcher:
 
     The loop runs on one daemon thread, started lazily at the first
     join and exiting after ``idle_linger_s`` with nothing to serve.
+
+    **One step in flight.**  A pass admits, DISPATCHES the next step
+    and only then blocks on the tokens of the step before it, walks,
+    emits and evicts: the device always has the next step queued while
+    the host reads the last one.  What makes that possible: the step's
+    inputs live on the device (block table and mask are uploaded only
+    in a pass where their host mirrors changed, a joining session's
+    first token is poked into the device's token vector, every other
+    entry of which is the last step's argmax as it lies), and a session
+    ends at ``max_new``, which the host knows before the step that
+    makes the last token runs, so the mask of a step that runs ahead
+    already excludes it.  A client that hung up is found only at emit:
+    its one step in flight writes a row into pages and a state block it
+    still owned when the step was queued, nobody reads the token, and
+    the device runs programs in order, so a later join's insert lands
+    after it.  With nothing in flight (the first step after idle, every
+    slot still filling) the step is dispatched alone; a speculative
+    round reads its tokens before it ends, so nothing runs ahead there.
+    ``kv_stats()["lookahead"]`` counts which way each step left.
 
     **Paged mode** (``paged=True``, the kv/pages allocator round): the
     per-slot contiguous cache stripes are replaced by one shared page
@@ -433,8 +474,21 @@ class ContinuousBatcher:
         self._step = None
         self._insert = None
         self._cache = None
+        # the step's inputs: host mirrors here, what the device holds
+        # below.  ``_active[slot]``: the slot's session takes part in
+        # the NEXT step (False again once its last step is dispatched)
         self._tokens = np.zeros((self.slots,), np.int32)
         self._active = np.zeros((self.slots,), bool)
+        self._tokens_d = None     # the last argmax, joins poked in
+        self._tok_set = set()     # slots whose token the host has set
+        # mask and block table on the device, and as uploaded (None:
+        # never, which equals no mirror)
+        self._active_d = self._active_up = None
+        self._bt_d = self._bt_up = None
+        self._flight = None       # the _Flight not yet read
+        self._ahead = 0           # steps dispatched with one in flight
+        self._sync = 0            # ... with nothing in flight
+        self._uploads = 0         # of block table, mask, token entries
         self._sessions = {}                       # slot -> _Session
         self._pending: deque = deque()
         self._lock = threading.Lock()
@@ -459,6 +513,7 @@ class ContinuousBatcher:
         self._gather_j = None
         self._scatter_j = None
         self._setlen_j = None
+        self._settok_j = None
         self._chunk_j = None                      # chunked prefill slice
         # spec-decode engine state (built when spec_k > 0)
         self._d_prefill = None
@@ -552,7 +607,9 @@ class ContinuousBatcher:
                "phases": _lmt.phase_counters(),
                "phase_ns": _lmt.phase_total_ns(),
                "loop_ns": _lmt.loop_ns(),
-               "queue": _lmt.queue_counters()}
+               "queue": _lmt.queue_counters(),
+               "lookahead": {"ahead": self._ahead, "sync": self._sync,
+                             "uploads": self._uploads}}
         if self.paged:
             out["attn"] = {"pages_read": self._attn_pages_read,
                            "pages_table": self._attn_pages_table}
@@ -603,6 +660,7 @@ class ContinuousBatcher:
             self._insert = jax.jit(_contig_insert(self.cfg),
                                    donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
+            self._settok_j = jax.jit(_settok)
         if self._cache is None:
             self._cache = empty_batch_cache(self.cfg, self.slots)
 
@@ -649,6 +707,7 @@ class ContinuousBatcher:
                 self._chunk_j = jit_with_params(
                     chunk_prefill, self.params, donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
+            self._settok_j = jax.jit(_settok)
             if self.spec_k > 0:
                 # draft engine: the SMALL model runs k cheap
                 # contiguous steps per round; the target verifies all
@@ -806,7 +865,7 @@ class ContinuousBatcher:
                                    jnp.int32(ctx_len))
         sess.ctx_len = ctx_len
         sess.fill = ctx_len      # fully prefilled = active
-        self._tokens[free] = last
+        self._set_token(free, last)
         self._active[free] = True
         sess.slot = free
         sess.sent = 0            # first token leaves on the next step
@@ -823,6 +882,13 @@ class ContinuousBatcher:
         policy picks.  Returns ``(pages, None)`` or ``(None, reason)``
         with the reason a KV_EVICT_REASONS member."""
         pages = self._alloc.alloc(need)
+        if pages is None and self._flight is not None and any(
+                s.queued >= s.max_new for _slot, s in self._flight.snap):
+            # the step in flight is somebody's last: the pages it
+            # frees come before any reclaim, as they did when every
+            # step was read in the pass that ran it
+            self._land_now()
+            pages = self._alloc.alloc(need)
         while pages is None:
             if rank < _RANK_BATCH \
                     and self._spill_one(min_rank=_RANK_BATCH) is None:
@@ -945,7 +1011,7 @@ class ContinuousBatcher:
         if filling:
             return
         sess.fill = ctx_len
-        self._tokens[free] = last
+        self._set_token(free, last)
         self._active[free] = True
         if self.spec_k > 0:
             self._draft_admit(sess)
@@ -963,6 +1029,9 @@ class ContinuousBatcher:
         could spill."""
         if self._host is None:
             return "kv_pool_exhausted"
+        # a park reads the victim's last token from the host's mirror,
+        # which is whole only with nothing in flight
+        self._land_now()
         ab = self._host.abort_reason()
         if ab is not None:
             return ab
@@ -1073,7 +1142,7 @@ class ContinuousBatcher:
         row[n_alias:n_used] = priv
         sess.pages = list(sess.pages) + list(priv)
         self._bt[free] = row
-        self._tokens[free] = sess.last_token
+        self._set_token(free, sess.last_token)
         # a session parked MID-FILL resumes still inactive and the
         # chunk rounds finish its context; an active one re-enters the
         # decode batch directly
@@ -1170,7 +1239,7 @@ class ContinuousBatcher:
         cache exactly like a prefilled one would."""
         slot = sess.slot
         sess.fill = sess.ctx_len
-        self._tokens[slot] = int(sess.prompt[-1])
+        self._set_token(slot, int(sess.prompt[-1]))
         self._active[slot] = True
         if sess.n_alias == 0 and sess.ctx_len > 0:
             # a chunk-filled context counts as one prefill (capacity
@@ -1256,33 +1325,95 @@ class ContinuousBatcher:
                 return False
         return True
 
-    def _plain_round(self):
-        """One plain decode step over the active slots; returns
-        ``(pairs, finished)`` for the emit/evict epilogue."""
+    def _set_token(self, slot: int, tok: int) -> None:
+        """The host names the token a slot feeds the next step (a
+        prompt's last, or a parked session's): into the mirror, and
+        marked so that the next dispatch pokes it into the device's
+        vector."""
+        self._tokens[slot] = tok
+        self._tok_set.add(slot)
+
+    def _step_inputs(self):
+        """The step's inputs ON the device.  The token vector is the
+        last step's argmax with the entries the host has set since
+        poked in one by one (never the host's vector, which is a step
+        stale for every other slot while a step is in flight); mask and
+        block table are uploaded where their mirror differs from what
+        was uploaded last: at admission, eviction, park, resume and a
+        session's last step.  Uploads are of private copies: the
+        mirrors go on changing under a step that is still running."""
+        import jax.numpy as jnp
+        if self._tokens_d is None:
+            self._tokens_d = jnp.asarray(self._tokens.copy())
+            self._uploads += 1
+        else:
+            for slot in self._tok_set:
+                self._tokens_d = self._settok_j(
+                    self._tokens_d, np.int32(slot),
+                    np.int32(self._tokens[slot]))
+            self._uploads += len(self._tok_set)
+        self._tok_set.clear()
+        if not np.array_equal(self._active, self._active_up):
+            self._active_up = self._active.copy()
+            self._active_d = jnp.asarray(self._active_up)
+            self._uploads += 1
+        if not self.paged:
+            return self._tokens_d, self._active_d
+        if not np.array_equal(self._bt, self._bt_up):
+            self._bt_up = self._bt.copy()
+            self._bt_d = jnp.asarray(self._bt_up)
+            self._uploads += 1
+        return self._bt_d, self._tokens_d, self._active_d
+
+    def _dispatch(self, ahead: bool) -> _Flight:
+        """Queue one plain decode step over the active slots and the
+        copy of its tokens to the host; nothing here waits for the
+        device.  ``ahead``: the step before it has not been read."""
         import jax.numpy as jnp
         ph = self._clock.switch
         ph(PH_STEP_DISPATCH)
-        if self.paged:
-            cache, logits = self._step(
-                self._cache, jnp.asarray(self._bt),
-                jnp.asarray(self._tokens), jnp.asarray(self._active))
-        else:
-            cache, logits = self._step(
-                self._cache, jnp.asarray(self._tokens),
-                jnp.asarray(self._active))
-        self._cache = cache
+        self._cache, logits = self._step(self._cache,
+                                         *self._step_inputs())
+        # greedy, a program of its own: its result feeds the next step
+        # as it lies, and starts on its way to the host for the walk
+        self._tokens_d = toks = jnp.argmax(logits, axis=-1)
+        toks.copy_to_host_async()
         self._steps += 1
+        if ahead:
+            self._ahead += 1
+        else:
+            self._sync += 1
         self._state_held_steps += len(self._sessions)
         self._state_slot_steps += self.slots
-        toks = jnp.argmax(logits, axis=-1)
+        snap = []
+        for slot, sess in self._sessions.items():
+            if not self._active[slot]:
+                continue
+            snap.append((slot, sess))
+            sess.queued += 1
+            if sess.queued >= sess.max_new:
+                # its last step: the next one's mask leaves it out (a
+                # state layer's block must not move a position past
+                # the session's end), whenever this one is read
+                self._active[slot] = False
+        return _Flight(toks, snap)
+
+    def _land(self, flight: _Flight) -> int:
+        """Block on a dispatched step's tokens, walk, emit, evict.
+        Returns the phase the loop was in, for a caller that was in
+        the middle of one."""
+        ph = self._clock.switch
         # the round's one sync, in a phase of its own: one sample a step
-        ph(PH_DEVICE_WAIT)
-        toks = np.asarray(toks)
+        outer = ph(PH_DEVICE_WAIT)
+        toks = self._read_tokens(flight.toks)
         ph(PH_TOKEN_WALK)
         pairs, finished = [], []
         last, pages_read = self.cfg.max_seq - 1, 0
-        for slot, sess in list(self._sessions.items()):
-            if not self._active[slot]:
+        for slot, sess in flight.snap:
+            if self._sessions.get(slot) is not sess:
+                # evicted with this step in flight (its client hung
+                # up): nobody reads the token, and whoever holds the
+                # slot now must not get it
                 continue
             tok = int(toks[slot])
             self._tokens[slot] = tok
@@ -1296,7 +1427,41 @@ class ContinuousBatcher:
         if self.paged:
             self._attn_pages_read += pages_read
             self._attn_pages_table += len(pairs) * self._pps
-        return pairs, finished
+        self._deliver(pairs, finished)
+        return outer
+
+    def _land_now(self) -> None:
+        """Read the step in flight from the middle of a pass (an
+        admission short of pages), and go on in the phase it was in."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._clock.switch(self._land(flight))
+
+    @staticmethod
+    def _read_tokens(toks) -> np.ndarray:
+        """The round's one sync (by name, so that a test can put a
+        slow device here: the array itself feeds the next step)."""
+        return np.asarray(toks)
+
+    def _deliver(self, pairs, finished) -> None:
+        """A round's epilogue: write its tokens to their streams, evict
+        the sessions that ended and the ones whose stream is gone."""
+        ph = self._clock.switch
+        ph(PH_STREAM_EMIT)
+        dead = self._emit(pairs)
+        _lmt.on_emit(pairs)
+        if dead or finished:
+            ph(PH_EVICT)
+        evicted = set()
+        for sess, reason in dead:
+            # a spec round emits several tokens per session —
+            # one eviction decision each
+            if id(sess) not in evicted:
+                evicted.add(id(sess))
+                self._evict(sess, reason)
+        for sess in finished:
+            if self._sessions.get(sess.slot) is sess:
+                self._evict(sess, "finished")
 
     def _spec_round(self):
         """One speculative round: k draft proposals per active slot
@@ -1347,8 +1512,12 @@ class ContinuousBatcher:
                 self._tokens[slot] = tok
                 sess.sent += 1
                 pairs.append((sess, tok))
+            sess.queued = sess.sent
             if sess.sent >= sess.max_new:
                 finished.append(sess)
+        # a plain step after this one feeds from the host's vector
+        self._tokens_d = None
+        self._sync += 1
         return pairs, finished
 
     def _finalize_obs(self, sess: _Session, reason: str) -> None:
@@ -1417,7 +1586,8 @@ class ContinuousBatcher:
                             < self.slots:
                         pending.append(self._pending.popleft())
                     idle = not self._sessions and not pending \
-                        and not self._pending and not self._parked
+                        and not self._pending and not self._parked \
+                        and self._flight is None
                 if idle:
                     self._wake.clear()
                     # re-check AFTER the clear: a join landing between
@@ -1438,9 +1608,10 @@ class ContinuousBatcher:
                                 return
                     continue
                 if pending or self._sessions:
-                    # a pass with sessions to serve: it runs a step
-                    # unless every admission is refused or (under a
-                    # chunk budget) every slot is still filling
+                    # a pass with sessions to serve: it dispatches a
+                    # step unless every admission is refused, every
+                    # slot is still filling (under a chunk budget) or
+                    # every session's last step is already in flight
                     clock.round_begin(self._steps)
                 # queue wait ends here, before the admission's work
                 _lmt.on_admit(pending)
@@ -1450,14 +1621,17 @@ class ContinuousBatcher:
                     # length from stalling live sessions on an XLA
                     # compile; the next step emits the first token) —
                     # or, chunked, just the slot grab: _chunk_round
-                    # below scatters the context under the budget
+                    # below scatters the context under the budget.
+                    # The host's share (prefix lookup, page alloc)
+                    # runs beside the step in flight and the programs
+                    # queue behind it
                     self._admit(sess)
                     ph(PH_SCHED)
                 # the Sarathi half BEFORE the decode round: a fill
                 # completed this round teacher-forces its first token
                 # on THIS round's step
                 self._chunk_round()
-                if not self._sessions:
+                if not self._sessions and self._flight is None:
                     if self.paged and self._parked:
                         # only parked sessions left and none could
                         # resume yet (another holder must release
@@ -1466,31 +1640,25 @@ class ContinuousBatcher:
                         ph(PH_IDLE_WAIT)
                         _time.sleep(0.005)
                     continue
+                # the step in flight is read AFTER the next is queued:
+                # the device goes from one to the other while the host
+                # walks, emits and evicts
+                landing, self._flight = self._flight, None
                 if not self._active.any():
-                    continue    # every occupied slot still filling
-                if self.spec_k > 0:
-                    if self._spec_ok():
-                        pairs, finished = self._spec_round()
-                    else:
-                        count_spec("spec_fallback_plain")
-                        pairs, finished = self._plain_round()
+                    # every occupied slot is still filling, or has its
+                    # last step in flight
+                    pass
+                elif self.spec_k == 0:
+                    self._flight = self._dispatch(landing is not None)
+                elif self._spec_ok():
+                    self._deliver(*self._spec_round())
                 else:
-                    pairs, finished = self._plain_round()
-                ph(PH_STREAM_EMIT)
-                dead = self._emit(pairs)
-                _lmt.on_emit(pairs)
-                if dead or finished:
-                    ph(PH_EVICT)
-                evicted = set()
-                for sess, reason in dead:
-                    # a spec round emits several tokens per session —
-                    # one eviction decision each
-                    if id(sess) not in evicted:
-                        evicted.add(id(sess))
-                        self._evict(sess, reason)
-                for sess in finished:
-                    if self._sessions.get(sess.slot) is sess:
-                        self._evict(sess, "finished")
+                    # a speculating batcher reads every round before
+                    # the next: the plain step too
+                    count_spec("spec_fallback_plain")
+                    landing = self._dispatch(False)
+                if landing is not None:
+                    self._land(landing)
         except Exception:
             LOG.exception("continuous batcher crashed; closing "
                           "sessions")
@@ -1505,6 +1673,11 @@ class ContinuousBatcher:
                 # next incarnation's _admit run out of slots forever
                 self._active[:] = False
                 self._tokens[:] = 0
+                # nothing in flight survives, and the device's copies
+                # of the step's inputs are made again from the mirrors
+                self._flight = None
+                self._tok_set.clear()
+                self._tokens_d = self._active_up = self._bt_up = None
                 # the crashed _step DONATED self._cache — on donating
                 # backends those buffers are gone; drop the pool so
                 # the next incarnation's _ensure_engine rebuilds it.

@@ -352,6 +352,114 @@ def test_batcher_slot_reuse_prefix_declines_and_state_counters(
     assert st["phases"]["insert_dispatch"] >= 3
 
 
+# -- one step in flight (ISSUE 28): the state pool under a step that ---------
+# -- runs ahead of the host's reading of the last one -------------------------
+
+def _join(bat, prompt, max_new, hang_up_after=None):
+    st = _FakeStream()
+    if hang_up_after is not None:
+        plain = st.write
+
+        def write(data):
+            rc = plain(data)
+            st.closed = len(st.tokens) >= hang_up_after
+            return rc
+
+        st.write = write
+    bat.join(st, prompt, max_new)
+    return st
+
+
+def _closed(*streams):
+    deadline = time.monotonic() + 90.0
+    while not all(s.closed for s in streams) \
+            and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert all(s.closed for s in streams), "a session never closed"
+
+
+def test_batcher_sessions_joining_and_ending_mid_batch(model, f32_matmuls):
+    """Five sessions of different ``max_new`` over two slots: sessions
+    end while another decodes, the queued ones take over slots (and
+    state blocks) that have just been let go, and every step but the
+    first of a busy spell leaves before the last one's tokens are
+    read: each session is served what the plain forward decodes."""
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    cfg, params = model
+    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+                            idle_linger_s=0.2)
+    asks = [(_seq(n, 30 + i), m) for i, (n, m) in enumerate(
+        [(9, 6), (4, 1), (12, 3), (1, 2), (6, 5)])]
+    streams = [_join(bat, p, m) for p, m in asks]
+    _closed(*streams)
+    for (p, m), st in zip(asks, streams):
+        assert st.close_reason == "finished"
+        assert st.tokens == _greedy(cfg, params, p, m), (len(p), m)
+    look = bat.kv_stats()["lookahead"]
+    assert look["ahead"] + look["sync"] == bat.steps_run()
+    assert look["ahead"] > look["sync"] >= 1
+
+
+def test_step_that_runs_ahead_leaves_an_ended_sessions_state_alone(
+        model, f32_matmuls):
+    """A ends at ``max_new`` = 4 while B decodes on: the steps queued
+    while A's last tokens were still unread must not have moved A's
+    slot.  Its state blocks and ``len`` are those of a session fed
+    exactly its context and four tokens (the prompt's last and three of
+    its own), one step at a time, and NOT those of one step more."""
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    cfg, params = model
+    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+                            idle_linger_s=0.2)
+    pa, pb = _seq(10, 41), _seq(6, 42)
+    a, b = _join(bat, pa, 4), _join(bat, pb, 14)
+    _closed(a, b)
+    assert a.tokens == _greedy(cfg, params, pa, 4)
+    assert b.tokens == _greedy(cfg, params, pb, 14)
+    assert bat.kv_stats()["lookahead"]["ahead"] >= 10
+    fed = list(pa) + a.tokens[:3]               # 9 of context + 4 steps
+    assert int(bat._cache["len"][0]) == len(fed) == len(pa) - 1 + 4
+    run = _Paged(cfg, params, slots=2)
+    run.admit(0, np.asarray(fed[:9], np.int32), first_page=1)
+    for t in fed[9:]:
+        run.feed({0: t})
+    keys = [k for k in run.cache if k[:2] in ("sh", "sc")]
+    assert len(keys) == 2 * 3
+    for k in keys:
+        np.testing.assert_allclose(np.asarray(bat._cache[k][0]),
+                                   np.asarray(run.cache[k][0]),
+                                   rtol=1e-5, atol=1e-6, err_msg=k)
+    run.feed({0: a.tokens[3]})                  # the step too many
+    moved = max(float(np.abs(np.asarray(bat._cache[k][0])
+                             - np.asarray(run.cache[k][0])).max())
+                for k in keys)
+    assert moved > 1e-3
+
+
+def test_hung_up_sessions_step_in_flight_is_overwritten_by_the_next_join(
+        model, f32_matmuls):
+    """One slot.  A's client hangs up after two tokens, which the
+    batcher finds at the next emit, with one more step of A's queued:
+    that step moves the slot's state and writes a row of A's page.  B
+    takes the slot in the next pass; its insert is queued after the
+    step nobody reads, so B decodes from ITS state, and is never handed
+    a token of A's."""
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    cfg, params = model
+    bat = ContinuousBatcher(cfg, params, slots=1, paged=True, page=PAGE,
+                            idle_linger_s=0.2)
+    pa, pb = _seq(13, 51), _seq(9, 52)
+    a = _join(bat, pa, 20, hang_up_after=2)
+    b = _join(bat, pb, 7)
+    _closed(b)
+    assert a.tokens == _greedy(cfg, params, pa, 2)
+    assert a.close_reason is None
+    assert b.tokens == _greedy(cfg, params, pb, 7)
+    assert bat.steps_run() == 2 + 2 + 7         # read, found out, unread
+    state = bat.kv_stats()["state"]
+    assert state["inserts"] == state["releases"] == 2
+
+
 def test_info_shows_the_schedule_and_the_state_pool(model):
     import json
 

@@ -249,30 +249,20 @@ def test_profiler_disable_flag_stops_sampling():
 # The loop, accounted for (ISSUE 25)
 # ---------------------------------------------------------------------------
 
-class _SlowTokens:
-    """What ``jnp.argmax`` hands back in the test below: the tokens,
-    behind an ``__array__`` that takes 20 ms to bring them over, as a
-    device that is still computing does."""
-
-    def __init__(self, real):
-        self.real = real
-
-    def __array__(self, dtype=None, copy=None):
-        time.sleep(0.020)
-        return np.asarray(self.real)
-
-
 def test_sync_falls_in_device_wait_not_dispatch(monkeypatch):
     """The S2 repair: the barrier of a round lies inside a phase of its
-    own.  With tokens that take 20 ms to come back, ``device_wait``
-    grows by >= 20 ms a step and ``step_dispatch`` does not."""
+    own.  With tokens that take 20 ms to come back (the argmax's result
+    feeds the next step as it lies, so the delay sits where the batcher
+    reads it, as a device that is still computing puts it),
+    ``device_wait`` grows by >= 20 ms a step and ``step_dispatch`` does
+    not."""
     _reset()
     cfg, params = _setup()
-    real = jnp.argmax
+    real = ContinuousBatcher._read_tokens
 
-    def slow_argmax(x, axis=None, **kw):
-        out = real(x, axis=axis, **kw)
-        return _SlowTokens(out) if axis == -1 and x.ndim == 2 else out
+    def slow_read(toks):
+        time.sleep(0.020)
+        return real(toks)
 
     bat = ContinuousBatcher(cfg, params, slots=2)
     st = _join(bat, _prompt(5, 6), 2)            # compiles, unpatched
@@ -280,7 +270,8 @@ def test_sync_falls_in_device_wait_not_dispatch(monkeypatch):
     _quiet(bat)
     steps0 = bat.steps_run()
     ns0 = lmt.phase_total_ns()
-    monkeypatch.setattr(jnp, "argmax", slow_argmax)
+    monkeypatch.setattr(ContinuousBatcher, "_read_tokens",
+                        staticmethod(slow_read))
     st = _join(bat, _prompt(6, 6), 5)
     _finish(st)
     _quiet(bat)
@@ -343,7 +334,9 @@ def test_phases_reach_the_profiler_under_their_names(monkeypatch):
     """The same names on the profiler's clock: every phase whose count
     grew was annotated ``lm/<phase>`` and no other name was, one
     annotation is open at a time (a phase is left before the next is
-    entered), and each step has its ``lm_round``."""
+    entered), and each step has its ``lm_round``: the pass that
+    dispatched it, under the step's number; one pass more lands the
+    last step and dispatches none."""
     # the phase table is the process's: an earlier test's batcher that
     # lingers out (5 s) during this one would add a sample whose
     # annotation was opened before the recorder stood in
@@ -379,9 +372,9 @@ def test_phases_reach_the_profiler_under_their_names(monkeypatch):
     assert len(phases) // 3 == sum(lmt.phase_counters().values())
     rounds = [e for e in log if e[1] == lmt.ROUND_TRACE_NAME]
     new_rounds = [e for e in rounds if e[0] == "new"]
-    assert len(new_rounds) == bat.steps_run() >= 5
+    assert len(new_rounds) == bat.steps_run() + 1 >= 6
     assert [e[2]["step_num"] for e in new_rounds] \
-        == list(range(bat.steps_run()))
+        == list(range(bat.steps_run() + 1))
     assert [e[0] for e in rounds] \
         == ["new", "enter", "exit"] * len(new_rounds)
 
