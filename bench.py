@@ -706,824 +706,6 @@ def bench_streaming(extra: dict) -> None:
         srv.stop()
 
 
-def _stream_count_child(addr: str, n: int, q) -> None:
-    """Subprocess client for the stream A/B: opens ``n`` sessions,
-    counts every received token chunk, and reports (tokens, seconds)
-    measured first-chunk → all-streams-closed.  A separate PROCESS so
-    the client's Python chunk parsing does not share the server
-    pusher's GIL (in-process the two arms compress into each other)."""
-    import os
-    import time as _t
-
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from brpc_tpu.client import Channel, Controller
-    from brpc_tpu.streaming import StreamOptions, stream_create
-
-    import threading as _th
-
-    got = [0]
-    first = []
-    lock = _th.Lock()       # deliver callbacks run on several runtime
-                            # threads; a bare += would lose increments
-
-    def on_recv(s, msgs):
-        with lock:
-            if not first:
-                first.append(_t.perf_counter())
-            got[0] += len(msgs)
-
-    chans = []
-    for _ in range(4):
-        ch = Channel()
-        ch.init(addr)
-        chans.append(ch)
-    streams = []
-    try:
-        for i in range(n):
-            cntl = Controller()
-            cntl.timeout_ms = 30_000
-            st = stream_create(cntl,
-                               StreamOptions(on_received=on_recv))
-            c = chans[i % len(chans)].call_method("PS.Open", b"",
-                                                  cntl=cntl)
-            if c.failed:
-                q.put(("error", c.error_text))
-                return
-            if not st.wait_established(15):
-                q.put(("error", "establish timeout"))
-                return
-            streams.append(st)
-    except Exception as e:
-        q.put(("error", f"{type(e).__name__}: {e}"))
-        return
-    q.put(("ready", None))
-    deadline = _t.time() + 90
-    while any(not s.closed for s in streams) and _t.time() < deadline:
-        _t.sleep(0.02)
-    end = _t.perf_counter()
-    dt = (end - first[0]) if first else 0.0
-    q.put(("done", (got[0], dt)))
-
-
-def bench_decode_stream(extra: dict) -> None:
-    """Kind-5 streaming lane + continuous-batching LLM decode.
-
-    Two halves:
-
-    - ``stream_native_vs_py``: PAIRED interleaved A/B of the stream
-      TRANSPORT at c=64 sessions — a server-side pusher emits one
-      token-sized chunk per session per step (the decode service's
-      write shape: native arm batch-writes the step through
-      ``stream_write_many`` → one coalesced writev per conn; Python
-      arm pays per-chunk ``Stream.write``).  Arms alternate per round
-      on the SAME server via the live lane flag, so the ratio is
-      phase-immune.
-    - ``stream_tokens_per_s`` / ``stream_ttft_p99_ms`` /
-      ``decode_stream_sessions``: the real LMService ``Decode`` path —
-      64 concurrent sessions riding the continuous batcher, aggregate
-      tokens/s and time-to-first-token p99 measured end-to-end.
-    """
-    import struct as _struct
-    import threading
-
-    from brpc_tpu.butil.flags import set_flag
-    from brpc_tpu.client import Channel, Controller
-    from brpc_tpu.server import Server, ServerOptions, Service
-    from brpc_tpu.streaming import (StreamOptions, stream_accept,
-                                    stream_create)
-
-    C = 64                              # concurrent decode sessions
-
-    # ---- transport A/B: synthetic token pusher ------------------------
-    class Push(Service):
-        def __init__(self):
-            self.streams = []
-
-        def Open(self, cntl, request):
-            s = stream_accept(cntl, StreamOptions(write_timeout_s=5.0))
-            assert s is not None
-            self.streams.append(s)
-            return b"ok"
-
-    opts = ServerOptions()
-    opts.native = True
-    opts.usercode_inline = True
-    srv = Server(opts)
-    svc = Push()
-    srv.add_service(svc, name="PS")
-    assert srv.start("127.0.0.1:0") == 0
-    engine = srv._native_bridge.engine
-    tok = _struct.pack("<i", 7)
-
-    def push_window(server_streams, seconds):
-        """Emit one token per session per step until the window ends;
-        returns steps emitted.  Native streams batch through the
-        engine (ONE coalesced call per step); Python ones pay
-        per-chunk writes — exactly the two transports under
-        measurement."""
-        t_end = time.perf_counter() + seconds
-        steps = 0
-        native = [s for s in server_streams if s._native_tx is not None]
-        pys = [s for s in server_streams if s._native_tx is None]
-        items = [(s.id, tok) for s in native]
-        while time.perf_counter() < t_end:
-            if items:
-                # batch-bounded credit wait: stalled/dead sessions fail
-                # fast instead of eating the window
-                engine.stream_write_many(items, 1000)
-            if pys:
-                # drop a failed session from the loop (its write just
-                # burned its timeout) — re-writing it every step would
-                # stall the whole py arm and corrupt the gated ratio;
-                # dropping ONLY it keeps the rest of the step honest
-                pys = [s for s in pys if s.write(tok) == 0]
-            steps += 1
-        return steps
-
-    def run_arm(native_on, nprocs=4):
-        """One arm: C sessions split over ``nprocs`` CLIENT PROCESSES
-        (a single client process's chunk parsing caps near the py
-        arm's rate and would mask the native lane's headroom), server
-        pushes one window, aggregate rate = Σtokens / max(dt)."""
-        set_flag("rpc_native_stream_lane", bool(native_on))
-        ctx = mp.get_context("spawn")
-        per = C // nprocs
-        procs = []
-        try:
-            for _ in range(nprocs):
-                q = ctx.Queue()
-                p = ctx.Process(target=_stream_count_child,
-                                args=(str(srv.listen_endpoint), per, q))
-                p.start()
-                procs.append((p, q))
-            for _p, q in procs:
-                tag, info = q.get(timeout=120)
-                assert tag == "ready", (tag, info)
-            mine = svc.streams[-C:]
-            want_native = bool(native_on)
-            assert all((s._native_tx is not None) == want_native
-                       for s in mine)
-            push_window(mine, 0.15)               # warm the pipe
-            push_window(mine, 1.0)                # the measured window
-            for s in mine:
-                s.close()
-            toks = 0
-            dt = 0.0
-            for _p, q in procs:
-                tag, (t, d) = q.get(timeout=120)
-                assert tag == "done", tag
-                toks += t
-                dt = max(dt, d)
-            return toks / dt if dt > 0 else 0.0
-        finally:
-            for p, _q in procs:
-                p.join(15)
-                if p.is_alive():
-                    p.kill()
-                    p.join(10)
-
-    try:
-        ratios = []
-        a_best = b_best = 0.0
-        for r in range(4):               # interleaved, alternating order
-            if r % 2 == 0:
-                a = run_arm(True)
-                b = run_arm(False)
-            else:
-                b = run_arm(False)
-                a = run_arm(True)
-            a_best = max(a_best, a)
-            b_best = max(b_best, b)
-            ratios.append(a / b if b > 0 else 0.0)
-        ratios.sort()
-        extra["stream_native_tokens_per_s"] = round(a_best, 1)
-        extra["stream_py_tokens_per_s"] = round(b_best, 1)
-        extra["stream_native_vs_py"] = round(ratios[len(ratios) // 2], 2)
-    finally:
-        set_flag("rpc_native_stream_lane", True)
-        srv.stop()
-
-    # ---- end-to-end: continuous-batching LM decode at c=64 ------------
-    import numpy as np
-
-    from brpc_tpu.models.lm_service import (LMService,
-                                            pack_generate_request)
-    from brpc_tpu.models.transformer_lm import LMConfig
-
-    cfg = LMConfig(vocab=256, dim=64, heads=4, depth=2, max_seq=96,
-                   remat=False)
-    opts2 = ServerOptions()
-    opts2.native = True
-    opts2.usercode_inline = True
-    srv2 = Server(opts2)
-    lm = LMService(cfg=cfg, decode_slots=C)
-    srv2.add_service(lm, name="LM")
-    assert srv2.start("127.0.0.1:0") == 0
-    MAX_NEW = 24
-    prompt = np.arange(8, dtype=np.int32)[None, :] % cfg.vocab
-    try:
-        chans = []
-        for _ in range(4):
-            ch = Channel()
-            ch.init(str(srv2.listen_endpoint))
-            chans.append(ch)
-
-        def warm():
-            done = threading.Event()
-            cntl = Controller()
-            cntl.timeout_ms = 120_000
-            st = stream_create(cntl, StreamOptions(
-                on_closed=lambda s: done.set()))
-            c = chans[0].call_method(
-                "LM.Decode", pack_generate_request(prompt, MAX_NEW),
-                cntl=cntl)
-            assert not c.failed, c.error_text
-            assert done.wait(120)
-
-        warm()                           # compile prefill + step once
-
-        ttfts = []
-        counts = [0] * C
-        closed = [threading.Event() for _ in range(C)]
-        lock = threading.Lock()
-
-        def one(i):
-            first = []
-            t_start = time.perf_counter()
-
-            def on_recv(s, msgs, _i=i, _first=first, _t=t_start):
-                if not _first:
-                    _first.append(time.perf_counter() - _t)
-                counts[_i] += len(msgs)
-
-            cntl = Controller()
-            cntl.timeout_ms = 120_000
-            st = stream_create(cntl, StreamOptions(
-                on_received=on_recv,
-                on_closed=lambda s, _i=i: closed[_i].set()))
-            c = chans[i % len(chans)].call_method(
-                "LM.Decode", pack_generate_request(prompt, MAX_NEW),
-                cntl=cntl)
-            if c.failed:
-                closed[i].set()
-                return
-            if closed[i].wait(180) and first:
-                with lock:
-                    ttfts.append(first[0])
-
-        t0 = time.perf_counter()
-        threads = [threading.Thread(target=one, args=(i,))
-                   for i in range(C)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(240)
-        dt = time.perf_counter() - t0
-        total = sum(counts)
-        extra["decode_stream_sessions"] = int(
-            sum(1 for e in closed if e.is_set()))
-        if dt > 0 and total:
-            extra["stream_tokens_per_s"] = round(total / dt, 1)
-        if ttfts:
-            ttfts.sort()
-            extra["stream_ttft_p99_ms"] = round(
-                ttfts[min(len(ttfts) - 1,
-                          int(len(ttfts) * 0.99))] * 1e3, 2)
-    finally:
-        srv2.stop()
-
-
-def bench_kv_disagg(extra: dict) -> None:
-    """§17 disaggregated prefill/decode + the KV transfer plane
-    (ISSUE 15):
-
-    - ``kv_transfer_gbps``: the page plane's same-host byte lane —
-      2MB pages staged into the shm ring (the lane's ONE memcpy),
-      resolved and landed on the import side; GB/s over the full
-      stage→resolve→land cycle.
-    - ``disagg_handoff_copies``: payload copies (engine ledgers of
-      BOTH tiers + Python copy_audit) across one full ici-lane
-      handoff session — PINNED at exactly 0 (the "zero payload bytes
-      through the message path" acceptance, perf_guard PINNED_ZERO).
-    - ``disagg_ttft_p99_ms`` / ``mono_ttft_p99_ms`` /
-      ``disagg_vs_mono_ttft``: PAIRED interleaved A/B — the same
-      C-session decode workload against the two-tier stack (prefill
-      tier hands every session to the decode tier mid-request) and
-      against one monolithic server; TTFT p99 per arm, order
-      alternated per round, ratio from per-round pairs (phase-immune).
-    - ``disagg_sessions_per_box``: sessions completed by the two-tier
-      stack with the PAGED decode tier (ISSUE 16) — 128 concurrent
-      sessions against a device page pool sized to the 16 contiguous
-      slots' bytes of the round-15 arm, overflow spilling to the host
-      tier (the "sessions-per-box at fixed p99" lever the ROADMAP
-      names, now the paged allocator's headline).
-    - ``kv_bytes_per_session``: device-pool peak bytes ÷ sessions
-      completed in that round — the KV footprint the box paid per
-      served session (contiguous would pay max_seq bytes regardless
-      of use; PERF_HISTORY §18).
-    - ``prefix_cache_hit_ttft_p99_ms`` / ``prefix_alias_copies``: C
-      sessions re-sending a prompt whose context pages sit in the
-      cross-session prefix cache — TTFT p99 with prefill skipped, and
-      the copy-audit total while the hits alias shared pages (PINNED
-      at exactly 0: a hit that copies is a prefix cache in name only).
-    """
-    import threading
-
-    import numpy as np
-
-    from brpc_tpu.butil import copy_audit
-    from brpc_tpu.client import Channel, Controller
-    from brpc_tpu.kv import DecodeTierService, KvTransport, \
-        PrefillService
-    from brpc_tpu.kv import pages as kv_pages
-    from brpc_tpu.kv import transport as kv_transport
-    from brpc_tpu.models.lm_service import (LMService,
-                                            pack_generate_request)
-    from brpc_tpu.models.transformer_lm import LMConfig
-    from brpc_tpu.server import Server, ServerOptions
-    from brpc_tpu.streaming import StreamOptions, stream_create
-    from brpc_tpu.transport import shm_ring
-
-    # ---- page-plane transfer throughput (shm byte lane) ---------------
-    if shm_ring.shm_supported():
-        import jax.numpy as jnp
-        PAGE = 2 * 1024 * 1024 - 4096     # fits the default ring slot
-        page_host = np.zeros((PAGE,), np.uint8)
-        moved = 0
-        t0 = time.perf_counter()
-        while time.perf_counter() - t0 < 1.0:
-            staged = shm_ring.stage_page(page_host, owner=("kv", -1))
-            if staged is None:
-                break
-            desc, lease = staged
-            parsed = shm_ring.decode_desc(desc)
-            view = shm_ring.resolve(parsed[0], parsed[2], parsed[3])
-            landed = jnp.asarray(np.frombuffer(view, np.uint8))
-            landed.block_until_ready()
-            del view, landed
-            shm_ring.client_complete(lease)
-            moved += PAGE
-        dt = time.perf_counter() - t0
-        if moved:
-            extra["kv_transfer_gbps"] = round(moved / dt / 1e9, 3)
-
-    # ---- the two-tier stack (shared by the copy pin and the A/B) ------
-    C = 16                               # concurrent decode sessions
-    MAX_NEW = 16
-    cfg = LMConfig(vocab=256, dim=64, heads=4, depth=2, max_seq=96,
-                   remat=False)
-    prompt = np.arange(8, dtype=np.int32)[None, :] % cfg.vocab
-
-    def native_opts():
-        o = ServerOptions()
-        o.native = True
-        o.usercode_inline = False        # prefill runs nested RPCs
-        return o
-
-    kv_pages._reset_for_tests()
-    kv_transport._reset_for_tests()
-    dec_lm = LMService(cfg=cfg, decode_slots=C)
-    dec_srv = Server(native_opts())
-    dec_srv.add_service(dec_lm, name="LM")
-    dec_srv.add_service(DecodeTierService(dec_lm), name="KV")
-    assert dec_srv.start("127.0.0.1:0") == 0
-    dch = Channel()
-    dch.init(str(dec_srv.listen_endpoint))
-    pre_svc = PrefillService(cfg=cfg, params=dec_lm.params,
-                             decode_channel=dch,
-                             transport=KvTransport(), decode_slots=C)
-    pre_srv = Server(native_opts())
-    pre_srv.add_service(pre_svc, name="LM")
-    assert pre_srv.start("127.0.0.1:0") == 0
-
-    mono_lm = LMService(cfg=cfg, params=dec_lm.params, decode_slots=C)
-    mono_srv = Server(native_opts())
-    mono_srv.add_service(mono_lm, name="LM")
-    assert mono_srv.start("127.0.0.1:0") == 0
-
-    def one_session(srv, chans, i, ttfts, done_counter, lock, p=None):
-        first = []
-        t_start = time.perf_counter()
-
-        def on_recv(s, msgs, _first=first, _t=t_start):
-            if not _first:
-                _first.append(time.perf_counter() - _t)
-
-        ok = threading.Event()
-        cntl = Controller()
-        cntl.timeout_ms = 120_000
-        stream_create(cntl, StreamOptions(
-            on_received=on_recv, on_closed=lambda s: ok.set()))
-        c = chans[i % len(chans)].call_method(
-            "LM.Decode",
-            pack_generate_request(prompt if p is None else p, MAX_NEW),
-            cntl=cntl)
-        if c.failed:
-            return
-        if ok.wait(120) and first:
-            with lock:
-                ttfts.append(first[0])
-                done_counter[0] += 1
-
-    def run_arm(srv, n=C, p=None):
-        chans = []
-        for _ in range(4):
-            ch = Channel()
-            ch.init(str(srv.listen_endpoint))
-            chans.append(ch)
-        ttfts = []
-        done = [0]
-        lock = threading.Lock()
-        threads = [threading.Thread(target=one_session,
-                                    args=(srv, chans, i, ttfts, done,
-                                          lock, p))
-                   for i in range(n)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(180)
-        ttfts.sort()
-        p99 = ttfts[min(len(ttfts) - 1, int(len(ttfts) * 0.99))] * 1e3 \
-            if ttfts else None
-        return p99, done[0]
-
-    try:
-        run_arm(pre_srv)                 # compile both tiers once
-        run_arm(mono_srv)
-
-        # ---- the copy pin: one full ici handoff, both ledgers ---------
-        engines = [s._native_bridge.engine for s in (pre_srv, dec_srv)]
-
-        def ledgers():
-            return sum(sum(e.telemetry()["data_plane_copies"].values())
-                       for e in engines)
-
-        base = ledgers()
-        with copy_audit.audit() as snap:
-            p99_once, done_once = run_arm(pre_srv)
-            counts, _nb = snap()
-        if done_once:
-            extra["disagg_handoff_copies"] = \
-                sum(counts.values()) + (ledgers() - base)
-
-        # ---- paired interleaved A/B -----------------------------------
-        dis_p, mono_p, ratios = [], [], []
-        dis_done = 0
-        for r in range(3):
-            arms = [("disagg", pre_srv), ("mono", mono_srv)]
-            if r % 2:
-                arms.reverse()
-            vals = {}
-            for name, srv in arms:
-                p99, done = run_arm(srv)
-                vals[name] = p99
-                if name == "disagg":
-                    dis_done = max(dis_done, done)
-            if vals.get("disagg") is not None:
-                dis_p.append(vals["disagg"])
-            if vals.get("mono") is not None:
-                mono_p.append(vals["mono"])
-            if vals.get("disagg") and vals.get("mono"):
-                ratios.append(vals["disagg"] / vals["mono"])
-        if dis_p:
-            extra["disagg_ttft_p99_ms"] = round(
-                statistics.median(dis_p), 2)
-        if mono_p:
-            extra["mono_ttft_p99_ms"] = round(
-                statistics.median(mono_p), 2)
-        if ratios:
-            ratios.sort()
-            extra["disagg_vs_mono_ttft"] = round(
-                ratios[len(ratios) // 2], 2)
-        extra["disagg_sessions_per_box"] = dis_done
-        st = kv_transport.kv_stats()
-        extra["disagg_handoff_sessions"] = st["sessions"]
-        extra["disagg_local_fallbacks"] = st["local_fallbacks"]
-
-        # ---- paged decode tier: 8x the sessions on the SAME device
-        # KV byte budget (ISSUE 16).  The pool is C*pps pages — byte-
-        # identical to the 16 contiguous slots above — while 128
-        # concurrent sessions ride it; the overflow parks in the host
-        # tier and resumes as pages free.  Sessions completed is the
-        # headline (every close is a failed session, so churn cannot
-        # fake it).
-        PAGE_TOK = 16
-        PPS = cfg.max_seq // PAGE_TOK
-        C_PAGED = 128
-        page_bytes = 2 * cfg.depth * PAGE_TOK * cfg.dim * 4   # k+v, f32
-        kv_pages._reset_for_tests()
-        kv_transport._reset_for_tests()
-        pag_lm = LMService(cfg=cfg, params=dec_lm.params,
-                           decode_slots=C_PAGED, paged=True,
-                           page=PAGE_TOK, kv_pages=C * PPS + 1,
-                           kv_host_slots=2 * C_PAGED + 32)
-        pag_srv = Server(native_opts())
-        pag_srv.add_service(pag_lm, name="LM")
-        pag_srv.add_service(DecodeTierService(pag_lm), name="KV")
-        assert pag_srv.start("127.0.0.1:0") == 0
-        pch = Channel()
-        pch.init(str(pag_srv.listen_endpoint))
-        pre2 = PrefillService(cfg=cfg, params=dec_lm.params,
-                              decode_channel=pch,
-                              transport=KvTransport(),
-                              decode_slots=C_PAGED)
-        pre2_srv = Server(native_opts())
-        pre2_srv.add_service(pre2, name="LM")
-        assert pre2_srv.start("127.0.0.1:0") == 0
-        try:
-            run_arm(pre2_srv, 8)         # compile the paged step once
-            _p99, paged_done = run_arm(pre2_srv, C_PAGED)
-            if paged_done:
-                extra["disagg_sessions_per_box"] = paged_done
-                if _p99 is not None:
-                    extra["paged_ttft_p99_ms"] = round(_p99, 2)
-                kv = pag_lm.batcher().kv_stats()
-                extra["kv_bytes_per_session"] = round(
-                    page_bytes * kv["alloc"]["peak_in_use"]
-                    / paged_done)
-                extra["paged_spills"] = kv["spills"]
-        finally:
-            pre2_srv.stop()
-            pag_srv.stop()
-
-        # ---- cross-session prefix cache: TTFT with prefill skipped,
-        # and the alias-copy pin (a hit ALIASES the cached context
-        # pages — refcounts move, bytes do not)
-        kv_pages._reset_for_tests()
-        hit_lm = LMService(cfg=cfg, params=dec_lm.params,
-                           decode_slots=C, paged=True, page=PAGE_TOK)
-        hit_srv = Server(native_opts())
-        hit_srv.add_service(hit_lm, name="LM")
-        assert hit_srv.start("127.0.0.1:0") == 0
-        try:
-            # 17-token prompt: the 16-token context is exactly one
-            # full page, cached by the seeding session's prefill
-            long_p = np.arange(17, dtype=np.int32)[None, :] % cfg.vocab
-            run_arm(hit_srv, 1, long_p)          # seed + compile
-            pf = hit_lm.batcher().prefills_run
-            with copy_audit.audit() as snap:
-                hp99, hit_done = run_arm(hit_srv, C, long_p)
-                counts, _nb = snap()
-            if hit_done and hp99 is not None:
-                extra["prefix_cache_hit_ttft_p99_ms"] = round(hp99, 2)
-                extra["prefix_alias_copies"] = sum(counts.values())
-                pst = kv_pages.prefix_event_counters()
-                extra["prefix_cache_hits"] = pst["prefix_hit"] \
-                    + pst["prefix_partial_hit"]
-                extra["prefix_prefills_skipped"] = \
-                    hit_done - (hit_lm.batcher().prefills_run - pf)
-        finally:
-            hit_srv.stop()
-    finally:
-        pre_srv.stop()
-        mono_srv.stop()
-        dec_srv.stop()
-
-
-def bench_slo_sched(extra: dict) -> None:
-    """§19 SLO-tiered batch scheduler (ISSUE 17), direct-batcher
-    benches (no RPC: the scheduler itself is the unit under test):
-
-    - ``decode_itl_p99_ms`` / ``decode_itl_p99_ms_chunked_off`` /
-      ``decode_itl_idle_p99_ms`` / ``slo_chunked_itl_gain``: a live
-      decode session's inter-token latency p99 while long-prompt
-      sessions join — PAIRED interleaved A/B, chunked prefill ON
-      (budget 16) vs OFF (whole-prompt prefill between steps, the
-      head-of-line block); idle p99 from the same session before the
-      joins start; the gain ratio is OFF/ON from per-round pairs
-      (phase-immune).
-    - ``spec_decode_tokens_per_s`` / ``spec_decode_tokens_per_s_plain``
-      / ``spec_accept_rate``: paired A/B of the draft+verify batcher
-      mode (k=3, self-draft) vs plain decode on the same paged config;
-      acceptance from the spec counters.  NOTE (PARITY §19): with
-      random init weights the draft and verify programs split argmax
-      near-ties, so acceptance — and therefore the speedup — is far
-      below a trained model's; the recorded baseline gates collapse,
-      it does not claim a win on this box.
-    - ``slo_tier_victim_goodput``: an INTERACTIVE session live while a
-      batch session coexists and a third join forces a spill — time to
-      complete the interactive stream with the tier registry ON
-      (batch victim parked) vs OFF (fattest-first parks the
-      interactive one); ratio is OFF/ON medians over interleaved
-      rounds, mirroring ``overload_fairness_victim_goodput``.
-    """
-    import jax
-    import numpy as np
-
-    from brpc_tpu.models.lm_service import (ContinuousBatcher,
-                                            TierRegistry,
-                                            _reset_sched_for_tests,
-                                            spec_counters)
-    from brpc_tpu.models.transformer_lm import LMConfig, init_params
-    from brpc_tpu.kv import pages as kv_pages
-    from brpc_tpu.streaming import StreamOptions
-
-    class Rec:
-        """Batcher-facing stream stub recording per-token arrival."""
-
-        def __init__(self):
-            self.closed = False
-            self.close_reason = None
-            self.stamps = []
-            self.id = 0
-            self._native_tx = None
-            self.options = StreamOptions()
-
-        def write(self, data):
-            self.stamps.append(time.perf_counter())
-            return 0
-
-        def close(self, reason=None):
-            self.closed = True
-            self.close_reason = reason
-
-    def wait(pred, timeout=120.0):
-        deadline = time.perf_counter() + timeout
-        while not pred() and time.perf_counter() < deadline:
-            time.sleep(0.001)
-        return pred()
-
-    def p99(vals):
-        s = sorted(vals)
-        return s[min(len(s) - 1, int(len(s) * 0.99))] * 1e3 if s else None
-
-    # ---- (a) chunked-prefill ITL A/B ---------------------------------
-    # prefill cost must dominate a decode step for the HOL block to be
-    # visible: 192-token context, 4 layers
-    cfg = LMConfig(vocab=256, dim=128, heads=4, depth=4, max_seq=256,
-                   remat=False)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    live_p = np.arange(8, dtype=np.int32) % cfg.vocab
-    long_p = (np.arange(193, dtype=np.int32) * 7) % cfg.vocab
-
-    def itl_arm(chunk):
-        """Returns (idle_p99_ms, loaded_p99_ms) for one arm."""
-        _reset_sched_for_tests()
-        bat = ContinuousBatcher(cfg, params, slots=8,
-                                prefill_chunk_tokens=chunk)
-        live = Rec()
-        bat.join(live, live_p, 140)
-        if not wait(lambda: len(live.stamps) >= 10):
-            return None, None
-        idle_from = len(live.stamps)
-        # idle and loaded windows get comparable sample counts (p99
-        # of a small sample is its max; asymmetry would skew the ratio)
-        wait(lambda: len(live.stamps) >= idle_from + 60)
-        idle = np.diff(live.stamps[idle_from:]).tolist()
-        # long-prompt joins arrive while the live session decodes
-        joiners = []
-        load_from = len(live.stamps)
-        for _ in range(3):
-            j = Rec()
-            joiners.append(j)
-            bat.join(j, long_p, 4)
-            time.sleep(0.05)
-        wait(lambda: all(j.closed for j in joiners))
-        loaded = np.diff(live.stamps[load_from:len(live.stamps)])
-        loaded = loaded.tolist()
-        wait(lambda: live.closed)
-        return p99(idle), p99(loaded)
-
-    on_idle, on_load, off_load, gains = [], [], [], []
-    for r in range(3):
-        arms = [(16, True), (None, False)]
-        if r % 2:
-            arms.reverse()
-        pair = {}
-        for chunk, is_on in arms:
-            i, l = itl_arm(chunk)
-            if l is None:
-                continue
-            pair[is_on] = l
-            if is_on:
-                on_load.append(l)
-                if i is not None:
-                    on_idle.append(i)
-            else:
-                off_load.append(l)
-        if True in pair and False in pair and pair[True] > 0:
-            gains.append(pair[False] / pair[True])
-    if on_load:
-        extra["decode_itl_p99_ms"] = round(statistics.median(on_load), 2)
-    if on_idle:
-        extra["decode_itl_idle_p99_ms"] = \
-            round(statistics.median(on_idle), 2)
-    if off_load:
-        extra["decode_itl_p99_ms_chunked_off"] = \
-            round(statistics.median(off_load), 2)
-    if gains:
-        extra["slo_chunked_itl_gain"] = \
-            round(statistics.median(gains), 3)
-
-    # ---- (b) speculative decoding A/B --------------------------------
-    cfg2 = LMConfig(vocab=256, dim=64, heads=4, depth=2, max_seq=96,
-                    remat=False)
-    params2 = init_params(jax.random.PRNGKey(1), cfg2)
-    sp_prompt = np.arange(8, dtype=np.int32) % cfg2.vocab
-
-    def spec_arm(spec):
-        kv_pages._reset_for_tests()
-        _reset_sched_for_tests()
-        kw = dict(spec_decode_k=3, draft_params=params2) if spec else {}
-        bat = ContinuousBatcher(cfg2, params2, slots=4, paged=True,
-                                page=16, **kw)
-        # warm the programs off the clock
-        w = Rec()
-        bat.join(w, sp_prompt, 4)
-        if not wait(lambda: w.closed):
-            return None, None
-        sc0 = spec_counters()
-        recs = [Rec() for _ in range(4)]
-        t0 = time.perf_counter()
-        for rec in recs:
-            bat.join(rec, sp_prompt, 64)
-        if not wait(lambda: all(rec.closed for rec in recs)):
-            return None, None
-        dt = time.perf_counter() - t0
-        sc1 = spec_counters()
-        acc = sc1["spec_accept"] - sc0["spec_accept"]
-        rej = sc1["spec_reject"] - sc0["spec_reject"]
-        rate = acc / (acc + rej) if (acc + rej) else None
-        return 4 * 64 / dt, rate
-
-    sp_on, sp_off, rates = [], [], []
-    for r in range(2):
-        arms = [True, False]
-        if r % 2:
-            arms.reverse()
-        for spec in arms:
-            tps, rate = spec_arm(spec)
-            if tps is None:
-                continue
-            (sp_on if spec else sp_off).append(tps)
-            if spec and rate is not None:
-                rates.append(rate)
-    if sp_on:
-        extra["spec_decode_tokens_per_s"] = \
-            round(statistics.median(sp_on), 1)
-    if sp_off:
-        extra["spec_decode_tokens_per_s_plain"] = \
-            round(statistics.median(sp_off), 1)
-    if rates:
-        extra["spec_accept_rate"] = round(statistics.median(rates), 3)
-
-    # ---- (c) tier-aware victim choice --------------------------------
-    cfg3 = LMConfig(vocab=64, dim=32, heads=4, depth=2, max_seq=32,
-                    remat=False)
-    params3 = init_params(jax.random.PRNGKey(0), cfg3)
-    pi = np.arange(14, dtype=np.int32) % cfg3.vocab    # 6 pages
-    pb = np.arange(10, dtype=np.int32) % cfg3.vocab    # 4 pages
-    pc = np.arange(6, dtype=np.int32) % cfg3.vocab     # 3 pages
-
-    def victim_arm(tiered):
-        """Interactive session's wall time to complete while a spill
-        lands; 10 usable pages of 4 — A(6) + B(4) fill the pool, C(3)
-        forces one park."""
-        kv_pages._reset_for_tests()
-        _reset_sched_for_tests()
-        reg = None
-        if tiered:
-            reg = TierRegistry()
-            reg.set_tier(b"vic", "interactive")
-            reg.set_tier(b"hog", "batch")
-        bat = ContinuousBatcher(cfg3, params3, slots=3, paged=True,
-                                page=4, pages=11, host_slots=64,
-                                prefix=False, tiers=reg)
-        a, b, c = Rec(), Rec(), Rec()
-        bat.join(a, pi, 11, tenant=b"vic")
-        if not wait(lambda: a.stamps):
-            return None
-        bat.join(b, pb, 7, tenant=b"hog")
-        if not wait(lambda: b.stamps):
-            return None
-        # clock starts at the CONTENDING join (per-batcher compiles
-        # landed above): the window is the contested phase only
-        t0 = time.perf_counter()
-        bat.join(c, pc, 7)
-        if not wait(lambda: a.closed and b.closed and c.closed):
-            return None
-        return (a.stamps[-1] - t0) * 1e3 if a.stamps else None
-
-    vic_on, vic_off = [], []
-    for r in range(3):
-        arms = [True, False]
-        if r % 2:
-            arms.reverse()
-        for tiered in arms:
-            d = victim_arm(tiered)
-            if d is not None:
-                (vic_on if tiered else vic_off).append(d)
-    if vic_on:
-        extra["slo_tier_victim_ms"] = \
-            round(statistics.median(vic_on), 1)
-    if vic_off:
-        extra["slo_tier_victim_ms_untiered"] = \
-            round(statistics.median(vic_off), 1)
-    if vic_on and vic_off and statistics.median(vic_on) > 0:
-        extra["slo_tier_victim_goodput"] = round(
-            statistics.median(vic_off) / statistics.median(vic_on), 3)
-
-
 def bench_fleet_obs(extra: dict) -> None:
     """§21 fleet observability (ISSUE 19): propagation latency of the
     load-report plane and its observer effect on a serving workload.
@@ -3191,9 +2373,6 @@ SECTIONS = (
     ("loop_scaling", bench_loop_scaling, False),
     ("data_plane", bench_data_plane, False),
     ("streaming", bench_streaming, False),
-    ("decode_stream", bench_decode_stream, True),
-    ("kv_disagg", bench_kv_disagg, True),
-    ("slo_sched", bench_slo_sched, True),
     ("fleet_obs", bench_fleet_obs, False),
     ("fanout", bench_fanout, False),
     ("http", bench_http, False),

@@ -182,9 +182,9 @@ class PrefillService(LMService):
         with self._prefill_lock:
             if self._prefill_j is None:
                 from ..models.transformer_lm import (jit_with_params,
-                                                     make_decode)
-                prefill, _step = make_decode(self.cfg)
-                self._prefill_j = jit_with_params(prefill, self.params)
+                                                     make_prefill)
+                self._prefill_j = jit_with_params(make_prefill(self.cfg),
+                                                  self.params)
             return self._prefill_j
 
     def Decode(self, cntl, request):

@@ -177,6 +177,13 @@ _spec_var = PassiveDimension(("event",), lambda: spec_counters(),
                              name="lm_spec_decode_total")
 
 
+# ``paged`` stays a keyword (default True) of ``ContinuousBatcher`` and
+# ``LMService`` only because the benchmark's model files pass it
+# (ROADMAP D2): the contiguous slot cache it once deselected is gone
+_PAGED_ONLY = ("paged=False: the contiguous slot cache was removed in "
+               "PR 29; the paged engine is the only serving engine")
+
+
 class _Session:
     __slots__ = ("stream", "prompt", "max_new", "sent", "queued",
                  "slot",
@@ -186,7 +193,7 @@ class _Session:
                  # written so far; fill < ctx_len means the session
                  # occupies its slot but is NOT yet decoding)
                  "tier", "tier_rank", "fill",
-                 # paged mode (kv/pages allocator): the session's
+                 # the kv/pages allocator: the session's
                  # block-table pages, its prefix-cache aliases, and
                  # its host-tier parking state
                  "pages", "n_alias", "n_priv",
@@ -217,7 +224,7 @@ class _Session:
         self.cache1 = None
         self.ctx_len = 0
         self.last_token = 0
-        # paged mode: block-table pages this session HOLDS (one ref
+        # block-table pages this session HOLDS (one ref
         # each; the first n_alias are prefix-cache aliases, the next
         # n_priv private), and the host-tier handles while parked
         self.pages: list = []
@@ -237,9 +244,9 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     between monolithic and disaggregated serving (the prompt's last
     token then rides the first batch step on whichever tier decodes —
     teacher-forced equivalence, see :meth:`ContinuousBatcher._admit`).
-    A block beyond the first (``transformer_lm._make_block_prefill``)
-    is handed the true length beside the bucket: a causal attention
-    forgives the zero padding, a recurrent state does not."""
+    The prefill (``transformer_lm.make_prefill``) is handed the true
+    length beside the bucket: a causal attention forgives the zero
+    padding, a recurrent state does not."""
     ctx = prompt[:-1]
     bucket = 1
     while bucket < max(len(ctx), 1):
@@ -247,42 +254,13 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     bucket = min(bucket, cfg.max_seq)
     padded = np.zeros((bucket,), np.int32)
     padded[:len(ctx)] = ctx
-    if cfg.plain_block():
-        cache1, _logits = prefill_j(padded[None, :])
-    else:
-        cache1, _logits = prefill_j(padded[None, :], np.int32(len(ctx)))
+    cache1, _logits = prefill_j(padded[None, :], np.int32(len(ctx)))
     return cache1, len(ctx)
 
 
-def _contig_insert(cfg: LMConfig):
-    """Jittable contiguous-pool slot insert with the pool DONATED: an
-    eager .at[].set chain would copy the whole (slots, max_seq, ...)
-    pool 2*depth+1 times per join, stalling every live session between
-    steps in proportion to pool size.  ONE home for the def — the
-    contiguous batcher's cache and the spec-decode DRAFT cache insert
-    through exactly this."""
-
-    def _insert(cache, cache1, slot, ctx_len):
-        import jax.lax as lax
-        cache = dict(cache)
-        for i in range(cfg.depth):
-            cache[f"k{i}"] = lax.dynamic_update_slice(
-                cache[f"k{i}"], cache1[f"k{i}"],
-                (slot, 0, 0, 0))
-            cache[f"v{i}"] = lax.dynamic_update_slice(
-                cache[f"v{i}"], cache1[f"v{i}"],
-                (slot, 0, 0, 0))
-        cache["len"] = lax.dynamic_update_slice(
-            cache["len"], ctx_len[None], (slot,))
-        return cache
-
-    return _insert
-
-
 def _setlen(cache, slot, val):
-    """Jittable per-slot ``len`` poke (layout-agnostic: jit re-traces
-    per cache pytree, so one def serves paged, contiguous, and the
-    spec-decode draft cache)."""
+    """Jittable per-slot ``len`` poke (the engine's cache and the
+    spec-decode draft's)."""
     import jax.lax as lax
     cache = dict(cache)
     cache["len"] = lax.dynamic_update_slice(cache["len"], val[None],
@@ -345,12 +323,11 @@ class ContinuousBatcher:
     round reads its tokens before it ends, so nothing runs ahead there.
     ``kv_stats()["lookahead"]`` counts which way each step left.
 
-    **Paged mode** (``paged=True``, the kv/pages allocator round): the
-    per-slot contiguous cache stripes are replaced by one shared page
-    pool per layer plus a per-slot block table, so a session pins only
-    ``ctx_len``-rounded pages instead of a ``max_seq`` stripe — the
-    slot count decouples from device KV bytes and sessions-per-box
-    scales with MEAN context, not max.  Three consequences ride along:
+    **Paged KV** (the kv/pages allocator): one shared page pool per
+    layer plus a per-slot block table, so a session pins only
+    ``ctx_len``-rounded pages, never a ``max_seq`` stripe — the slot
+    count decouples from device KV bytes and sessions-per-box scales
+    with MEAN context, not max.  Three consequences ride along:
 
     - a cross-session :class:`~brpc_tpu.kv.pages.PrefixCache` lets a
       re-sent context ALIAS already-prefilled pages (refcounted, zero
@@ -368,7 +345,7 @@ class ContinuousBatcher:
       sessions under ``kv_spill_drain_aborted`` instead of leaking.
 
     **Two kinds of state** (a layer schedule with state-space layers,
-    ``LMConfig.mixers``; paged mode only): the attention layers keep
+    ``LMConfig.mixers``): the attention layers keep
     pages per TOKEN as above; each state layer keeps one fixed block
     per SLOT in the state pool (``sh<i>``/``sc<i>`` of the cache, its
     size follows ``slots``).  Admission writes the prefill's state at
@@ -398,18 +375,19 @@ class ContinuousBatcher:
       interactive last) with batch victims taken even BEFORE
       prefix-cache holds when the requester outranks them.  Every
       decision counts under the closed ``SLO_SCHED_EVENTS`` enum;
-    - **speculative decoding** (``spec_decode_k``, paged mode): a
-      small draft model proposes k tokens per active slot (k cheap
-      contiguous steps), the target verifies all of them in ONE
-      batched multi-token program, accepted prefixes advance the page
-      table and rejections are a pure ``len`` rewind (the refuted
+    - **speculative decoding** (``spec_decode_k``): a small draft
+      model proposes k tokens per active slot (k cheap steps over a
+      page pool of its own, addressed through the target's block
+      table), the target verifies all of them in ONE batched
+      multi-token program, accepted prefixes advance the page table
+      and rejections are a pure ``len`` rewind (the refuted
       rows sit beyond the mask and are rewritten before ever being
       admitted) — token identity with plain decode holds on both
       paths.  Acceptance telemetry rides ``SPEC_DECODE_EVENTS``.
     """
 
     def __init__(self, cfg: LMConfig, params, slots: int = 8,
-                 idle_linger_s: float = 5.0, paged: bool = False,
+                 idle_linger_s: float = 5.0, paged: bool = True,
                  page: int = 16, pages: Optional[int] = None,
                  host_slots: int = 0, prefix: bool = True,
                  prefix_budget: Optional[int] = None,
@@ -420,10 +398,10 @@ class ContinuousBatcher:
         self.params = params
         self.slots = int(slots)
         self.idle_linger_s = idle_linger_s
-        # paged-KV knobs (inert unless paged=True)
-        self.paged = bool(paged)
+        if not paged:
+            raise ValueError(_PAGED_ONLY)
         self.page = int(page)
-        self._pps = cfg.max_seq // self.page if self.paged else 0
+        self._pps = cfg.max_seq // self.page
         # +1: page 0 is the allocator's reserved garbage page
         self.num_pages = int(pages) if pages is not None \
             else self.slots * self._pps + 1
@@ -442,17 +420,12 @@ class ContinuousBatcher:
             if self.chunk_budget else min(64, cfg.max_seq)
         self.spec_k = int(spec_decode_k)
         self.draft_params = draft_params
-        if self.spec_k > 0 and not self.paged:
-            raise ValueError("spec_decode_k requires paged=True "
-                             "(rejection rollback is a block-table "
-                             "len rewind)")
         if self.spec_k > 0 and draft_params is None:
             raise ValueError("spec_decode_k requires draft_params")
         if not cfg.plain_block():
             # nothing runs such a model wrong silently: what this
             # engine does not port declines here, by name
             for on, what in (
-                    (not self.paged, "paged=False (the contiguous cache)"),
                     (self.spec_k > 0, "spec_decode_k (speculative verify)"),
                     (self.host_slots > 0,
                      "host_slots (park/resume and host spill)"),
@@ -505,11 +478,11 @@ class ContinuousBatcher:
         self._state_releases = 0
         self._state_held_steps = 0
         self._state_slot_steps = 0
-        # paged-mode engine state (built in _ensure_engine)
+        # the allocator triple (built in _ensure_engine)
         self._alloc = None                        # kv.pages.PageAllocator
         self._prefix = None                       # kv.pages.PrefixCache
         self._host = None                         # kv.pages.HostPagePool
-        self._bt = np.zeros((self.slots, max(self._pps, 1)), np.int32)
+        self._bt = np.zeros((self.slots, self._pps), np.int32)
         self._gather_j = None
         self._scatter_j = None
         self._setlen_j = None
@@ -518,7 +491,6 @@ class ContinuousBatcher:
         # spec-decode engine state (built when spec_k > 0)
         self._d_prefill = None
         self._d_step = None
-        self._d_insert = None
         self._d_cache = None
         self._verify_j = None
         self._d_sync_j = None
@@ -597,9 +569,10 @@ class ContinuousBatcher:
         return self._steps
 
     def kv_stats(self) -> dict:
-        """Allocator-plane observability (paged mode; minimal shape
-        otherwise) — the bench and the capacity tests read this."""
-        out = {"paged": self.paged, "steps": self._steps,
+        """Allocator-plane observability — the benchmark and the
+        capacity tests read this (``alloc``, ``prefix`` and ``host``
+        once the engine is built)."""
+        out = {"steps": self._steps,
                "prefills_run": self.prefills_run,
                "spills": self.spills, "resumes": self.resumes,
                "parked": len(self._parked),
@@ -609,20 +582,18 @@ class ContinuousBatcher:
                "loop_ns": _lmt.loop_ns(),
                "queue": _lmt.queue_counters(),
                "lookahead": {"ahead": self._ahead, "sync": self._sync,
-                             "uploads": self._uploads}}
-        if self.paged:
-            out["attn"] = {"pages_read": self._attn_pages_read,
-                           "pages_table": self._attn_pages_table}
-            # a slot's block of the state pool (no bytes where the
-            # schedule has no state layer)
-            out["state"] = {"slots": self.slots,
-                            "held": len(self._sessions),
-                            "held_steps": self._state_held_steps,
-                            "slot_steps": self._state_slot_steps,
-                            "bytes": self.slots
-                            * state_slot_bytes(self.cfg),
-                            "inserts": self._state_inserts,
-                            "releases": self._state_releases}
+                             "uploads": self._uploads},
+               "attn": {"pages_read": self._attn_pages_read,
+                        "pages_table": self._attn_pages_table},
+               # a slot's block of the state pool (no bytes where the
+               # schedule has no state layer)
+               "state": {"slots": self.slots,
+                         "held": len(self._sessions),
+                         "held_steps": self._state_held_steps,
+                         "slot_steps": self._state_slot_steps,
+                         "bytes": self.slots * state_slot_bytes(self.cfg),
+                         "inserts": self._state_inserts,
+                         "releases": self._state_releases}}
         if self._alloc is not None:
             out["alloc"] = self._alloc.stats()
         if self._prefix is not None:
@@ -634,49 +605,18 @@ class ContinuousBatcher:
     # -- internals (batcher thread only past the pending handoff) ---------
 
     def _ensure_engine(self) -> None:
-        """Build the compiled programs + device KV pool, ON the batcher
-        thread (see __init__: the constructor must stay cheap enough to
-        run inside an engine loop's batched GIL entry)."""
-        if self._prefill is not None and self._cache is not None:
-            return
-        import jax
-
-        from .transformer_lm import (empty_batch_cache, jit_with_params,
-                                     make_batch_decode)
-
-        if self.paged:
-            self._ensure_paged_engine()
-            return
-        if self._prefill is None:
-            prefill, step, chunk_step = make_batch_decode(
-                self.cfg, chunk=self._chunk_w)
-            # weights are ARGUMENTS of every program, bound outside
-            # the jit (jit_with_params) — never closure constants
-            self._prefill = jit_with_params(prefill, self.params)
-            self._step = jit_with_params(step, self.params,
-                                         donate_argnums=(0,))
-            self._chunk_j = jit_with_params(chunk_step, self.params,
-                                            donate_argnums=(0,))
-            self._insert = jax.jit(_contig_insert(self.cfg),
-                                   donate_argnums=(0,))
-            self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
-            self._settok_j = jax.jit(_settok)
-        if self._cache is None:
-            self._cache = empty_batch_cache(self.cfg, self.slots)
-
-    def _ensure_paged_engine(self) -> None:
-        """Paged-mode engine build: the shared page pools, the block-
-        paged step, the page-granular I/O programs, and the allocator /
-        prefix-cache / host-tier triple from ``kv.pages``."""
+        """Build the compiled programs + the device page pools, ON the
+        batcher thread (see __init__: the constructor must stay cheap
+        enough to run inside an engine loop's batched GIL entry): the
+        block-paged step, the page-granular I/O programs, and the
+        allocator / prefix-cache / host-tier triple from ``kv.pages``."""
         import jax
         import jax.numpy as jnp
 
         from ..kv.pages import (HostPagePool, PageAllocator,
                                 PrefixCache)
-        from .transformer_lm import (empty_batch_cache,
-                                     empty_paged_cache, jit_with_params,
+        from .transformer_lm import (empty_paged_cache, jit_with_params,
                                      make_paged_io,
-                                     make_batch_decode,
                                      make_paged_batch_decode,
                                      make_paged_spec_verify,
                                      paged_page_bytes)
@@ -693,35 +633,36 @@ class ContinuousBatcher:
                                  name="lm-pallas-import",
                                  daemon=True).start()
             prefill, step = make_paged_batch_decode(self.cfg, self.page)
+            # weights are ARGUMENTS of every program, bound outside
+            # the jit (jit_with_params) — never closure constants
             self._prefill = jit_with_params(prefill, self.params)
             self._step = jit_with_params(step, self.params,
                                          donate_argnums=(0,))
+            # (for a block beyond the first the spill, resume and
+            # catch-up programs decline when traced: __init__ refused
+            # what enters them)
             gather, scatter, insert, chunk_prefill = make_paged_io(
                 self.cfg, self.page, chunk=self._chunk_w)
             self._insert = jax.jit(insert, donate_argnums=(0,))
-            if self.cfg.plain_block():
-                # (a block beyond the first has no spill, resume or
-                # catch-up program: __init__ refused what enters them)
-                self._gather_j = jax.jit(gather)
-                self._scatter_j = jax.jit(scatter, donate_argnums=(0,))
-                self._chunk_j = jit_with_params(
-                    chunk_prefill, self.params, donate_argnums=(0,))
+            self._gather_j = jax.jit(gather)
+            self._scatter_j = jax.jit(scatter, donate_argnums=(0,))
+            self._chunk_j = jit_with_params(
+                chunk_prefill, self.params, donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
             self._settok_j = jax.jit(_settok)
             if self.spec_k > 0:
-                # draft engine: the SMALL model runs k cheap
-                # contiguous steps per round; the target verifies all
-                # k proposals in one width-(k+1) program.  Draft len
-                # sync is a pure arithmetic rewind — after k draft
+                # draft engine: the SMALL model runs k cheap steps per
+                # round over a page pool of its own, addressed through
+                # the SAME block table (its inserts are the engine's
+                # ``insert`` over ``sess.pages``); the target verifies
+                # all k proposals in one width-(k+1) program.  Draft
+                # len sync is a pure arithmetic rewind — after k draft
                 # steps len = L + k, the target accepted m, so the
                 # draft keeps rows for L..L+m and rewinds k-1-m.
-                d_prefill, d_step = make_batch_decode(self.cfg)
-                self._d_prefill = jit_with_params(d_prefill,
+                self._d_prefill = jit_with_params(prefill,
                                                   self.draft_params)
-                self._d_step = jit_with_params(d_step, self.draft_params,
+                self._d_step = jit_with_params(step, self.draft_params,
                                                donate_argnums=(0,))
-                self._d_insert = jax.jit(_contig_insert(self.cfg),
-                                         donate_argnums=(0,))
                 verify = make_paged_spec_verify(self.cfg, self.page,
                                                 self.spec_k + 1)
                 self._verify_j = jit_with_params(verify, self.params,
@@ -741,7 +682,8 @@ class ContinuousBatcher:
                                             self.slots, self.page)
             self._bt[:] = 0
         if self.spec_k > 0 and self._d_cache is None:
-            self._d_cache = empty_batch_cache(self.cfg, self.slots)
+            self._d_cache = empty_paged_cache(self.cfg, self.num_pages,
+                                              self.slots, self.page)
         if self._alloc is None:
             pb = paged_page_bytes(self.cfg, self.page)
             self._alloc = PageAllocator(self.num_pages, self.page, pb)
@@ -759,8 +701,7 @@ class ContinuousBatcher:
 
     def _pages_for(self, ctx_len: int, max_new: int) -> int:
         """Pages a session needs end-to-end: every position it will
-        ever write, ctx-ROUNDED — the whole point of paging (vs the
-        contiguous pool's unconditional max_seq stripe)."""
+        ever write, ctx-ROUNDED — the whole point of paging."""
         return max(1, -(-(ctx_len + max_new) // self.page))
 
     # credit wait bound for one step's token writes: a healthy client
@@ -813,65 +754,7 @@ class ContinuousBatcher:
                     dead.append((sess, None))
         return dead
 
-    def _admit(self, sess: _Session) -> None:
-        # Prefill the prompt CONTEXT (all but the last token), padded
-        # to a power-of-two bucket so distinct prompt lengths share
-        # compiled programs — an unbucketed per-length jit would stall
-        # EVERY live session for a fresh XLA compile at each new
-        # length.  The prompt's LAST token then rides the next batch
-        # step (teacher-forced equivalence: step logits at pos s-1 ==
-        # full-prefill last-position logits), which both yields the
-        # first generated token and overwrites the padded garbage rows
-        # before the mask ever admits them.  A session imported from a
-        # prefill tier (kv/ handoff) skips the prefill: its caches
-        # arrived as pages and insert the same way.
-        if self.paged:
-            self._admit_paged(sess)
-            return
-        import jax.numpy as jnp
-        # free = unOCCUPIED, not merely inactive: a chunk-filling
-        # session holds its slot while _active is still False
-        ph = self._clock.switch
-        free = next(i for i in range(self.slots)
-                    if i not in self._sessions)
-        if sess.cache1 is None and self.chunk_budget \
-                and len(sess.prompt) > 1:
-            # chunked admit: take the slot now, let _chunk_round
-            # scatter the context under the per-step budget; the
-            # session activates (and teacher-forces its last prompt
-            # token) when fill reaches ctx_len
-            ph(PH_INSERT_DISPATCH)
-            self._cache = self._setlen_j(self._cache, jnp.int32(free),
-                                         jnp.int32(0))
-            sess.ctx_len = len(sess.prompt) - 1
-            sess.fill = 0
-            sess.slot = free
-            sess.sent = 0
-            self._sessions[free] = sess
-            return
-        if sess.cache1 is not None:
-            cache1, ctx_len = sess.cache1, sess.ctx_len
-            last = int(sess.last_token)
-            sess.cache1 = None   # the pool owns the rows after insert
-        else:
-            ph(PH_PREFILL_DISPATCH)
-            cache1, ctx_len = bucketed_prefill(self._prefill, self.cfg,
-                                               sess.prompt)
-            self.prefills_run += 1
-            last = int(sess.prompt[-1])
-        ph(PH_INSERT_DISPATCH)
-        self._cache = self._insert(self._cache, cache1,
-                                   jnp.int32(free),
-                                   jnp.int32(ctx_len))
-        sess.ctx_len = ctx_len
-        sess.fill = ctx_len      # fully prefilled = active
-        self._set_token(free, last)
-        self._active[free] = True
-        sess.slot = free
-        sess.sent = 0            # first token leaves on the next step
-        self._sessions[free] = sess
-
-    # -- paged mode: admit / spill / park / resume -------------------------
+    # -- admit / spill / park / resume --------------------------------------
 
     def _alloc_with_reclaim(self, need: int, rank: int = 1):
         """Allocate ``need`` pages, reclaiming under pressure in SLO
@@ -903,7 +786,18 @@ class ContinuousBatcher:
             pages = self._alloc.alloc(need)
         return pages, None
 
-    def _admit_paged(self, sess: _Session) -> None:
+    def _admit(self, sess: _Session) -> None:
+        # Prefill the prompt CONTEXT (all but the last token), padded
+        # to a power-of-two bucket so distinct prompt lengths share
+        # compiled programs — an unbucketed per-length jit would stall
+        # EVERY live session for a fresh XLA compile at each new
+        # length.  The prompt's LAST token then rides the next batch
+        # step (teacher-forced equivalence: step logits at pos s-1 ==
+        # full-prefill last-position logits), which both yields the
+        # first generated token and overwrites the padded garbage rows
+        # before the mask ever admits them.  A session imported from a
+        # prefill tier (kv/ handoff) skips the prefill: its caches
+        # arrived as pages and insert the same way.
         import jax.numpy as jnp
 
         from ..kv.pages import count_evict
@@ -948,7 +842,7 @@ class ContinuousBatcher:
         if sess.cache1 is not None:
             # disagg import: blockify the imported contiguous cache
             self._cache = self._insert(self._cache, jnp.asarray(row),
-                                       sess.cache1)
+                                       sess.cache1, jnp.int32(free))
             sess.cache1 = None
             last = int(sess.last_token)
             start_len = ctx_len
@@ -966,12 +860,11 @@ class ContinuousBatcher:
                                                sess.prompt)
             self.prefills_run += 1
             ph(PH_INSERT_DISPATCH)
-            # a block beyond the first also takes the slot: in the
-            # same program the state layers' blocks are written over
-            # whatever the slot's last session left there
-            slot = () if self.cfg.plain_block() else (jnp.int32(free),)
+            # the insert also takes the slot: in the same program
+            # the state layers' blocks are written over whatever the
+            # slot's last session left there
             self._cache = self._insert(self._cache, jnp.asarray(row),
-                                       cache1, *slot)
+                                       cache1, jnp.int32(free))
             self._state_inserts += int(self.cfg.has_state)
             last = int(sess.prompt[-1])
             start_len = ctx_len
@@ -1214,10 +1107,13 @@ class ContinuousBatcher:
     # -- SLO scheduler: chunk rounds, spec rounds, plain rounds ------------
 
     def _draft_admit(self, sess: _Session) -> None:
-        """Seed the DRAFT model's contiguous cache for a newly active
-        slot (spec mode).  The draft is small — one bucketed prefill
-        here is cheap, and it keeps the draft's rows position-aligned
-        with the target's context."""
+        """Seed the DRAFT model's page pool for a newly active slot
+        (spec mode), through the slot's row of the block table.  The
+        draft is small — one bucketed prefill here is cheap, and it
+        keeps the draft's rows position-aligned with the target's
+        context (a page the target aliases from the prefix cache is
+        written again with the values it holds: the same tokens at the
+        same positions)."""
         if self._d_cache is None or sess.prompt is None:
             return
         import jax.numpy as jnp
@@ -1226,8 +1122,10 @@ class ContinuousBatcher:
         cache1, ctx_len = bucketed_prefill(self._d_prefill, self.cfg,
                                            sess.prompt)
         ph(PH_INSERT_DISPATCH)
-        self._d_cache = self._d_insert(self._d_cache, cache1,
-                                       jnp.int32(sess.slot),
+        slot = jnp.int32(sess.slot)
+        self._d_cache = self._insert(
+            self._d_cache, jnp.asarray(self._bt[sess.slot]), cache1, slot)
+        self._d_cache = self._setlen_j(self._d_cache, slot,
                                        jnp.int32(ctx_len))
         ph(outer)
 
@@ -1246,7 +1144,7 @@ class ContinuousBatcher:
             # accounting); prefix-hit catch-up does NOT — the hit
             # avoided it
             self.prefills_run += 1
-            if self.paged and self._prefix is not None:
+            if self._prefix is not None:
                 self._prefix.insert(sess.prompt[:-1],
                                     sess.pages[sess.n_alias:])
         if self.spec_k > 0:
@@ -1290,16 +1188,10 @@ class ContinuousBatcher:
                             budget))
                 ids = np.zeros((self._chunk_w,), np.int32)
                 ids[:n] = sess.prompt[sess.fill:sess.fill + n]
-                if self.paged:
-                    self._cache = self._chunk_j(
-                        self._cache, jnp.asarray(self._bt[sess.slot]),
-                        jnp.int32(sess.slot), jnp.int32(sess.fill),
-                        jnp.int32(n), jnp.asarray(ids))
-                else:
-                    self._cache = self._chunk_j(
-                        self._cache, jnp.int32(sess.slot),
-                        jnp.int32(sess.fill), jnp.int32(n),
-                        jnp.asarray(ids))
+                self._cache = self._chunk_j(
+                    self._cache, jnp.asarray(self._bt[sess.slot]),
+                    jnp.int32(sess.slot), jnp.int32(sess.fill),
+                    jnp.int32(n), jnp.asarray(ids))
                 sess.fill += n
                 budget -= n
                 count_sched("sched_catchup_slice" if catchup
@@ -1357,8 +1249,6 @@ class ContinuousBatcher:
             self._active_up = self._active.copy()
             self._active_d = jnp.asarray(self._active_up)
             self._uploads += 1
-        if not self.paged:
-            return self._tokens_d, self._active_d
         if not np.array_equal(self._bt, self._bt_up):
             self._bt_up = self._bt.copy()
             self._bt_d = jnp.asarray(self._bt_up)
@@ -1424,9 +1314,8 @@ class ContinuousBatcher:
             pairs.append((sess, tok))
             if sess.sent >= sess.max_new:
                 finished.append(sess)
-        if self.paged:
-            self._attn_pages_read += pages_read
-            self._attn_pages_table += len(pairs) * self._pps
+        self._attn_pages_read += pages_read
+        self._attn_pages_table += len(pairs) * self._pps
         self._deliver(pairs, finished)
         return outer
 
@@ -1465,7 +1354,7 @@ class ContinuousBatcher:
 
     def _spec_round(self):
         """One speculative round: k draft proposals per active slot
-        (k cheap contiguous draft steps), ONE width-(k+1) target
+        (k cheap draft steps), ONE width-(k+1) target
         verification, host-side emission of the accepted prefix plus
         the target's own next token.  Token identity with plain decode
         holds on BOTH paths: an accepted row holds exactly the k/v a
@@ -1480,10 +1369,11 @@ class ContinuousBatcher:
         ph(PH_SPEC_DRAFT)
         active = self._active.copy()
         act_j = jnp.asarray(active)
+        bt_j = jnp.asarray(self._bt)
         cur = self._tokens.copy()
         drafts = []
         for _ in range(k):
-            self._d_cache, dl = self._d_step(self._d_cache,
+            self._d_cache, dl = self._d_step(self._d_cache, bt_j,
                                              jnp.asarray(cur), act_j)
             cur = np.asarray(jnp.argmax(dl, axis=-1)).astype(np.int32)
             drafts.append(cur)
@@ -1492,7 +1382,7 @@ class ContinuousBatcher:
         ph(PH_SPEC_VERIFY)
         u = np.stack([self._tokens] + drafts, axis=1).astype(np.int32)
         self._cache, out, m = self._verify_j(
-            self._cache, jnp.asarray(self._bt), jnp.asarray(u), act_j)
+            self._cache, bt_j, jnp.asarray(u), act_j)
         out = np.asarray(out)
         m = np.asarray(m)
         ph(PH_TOKEN_WALK)
@@ -1543,7 +1433,7 @@ class ContinuousBatcher:
         # (the slot's block of the state pool is simply let go: the
         # next admission writes over it)
         self._state_releases += int(self.cfg.has_state)
-        if self.paged and sess.pages:
+        if sess.pages:
             self._alloc.release_all(sess.pages)
             sess.pages = []
             self._bt[sess.slot] = 0
@@ -1568,11 +1458,10 @@ class ContinuousBatcher:
                 clock.round_end()
                 ph(PH_SCHED)
                 clock.tick()
-                if self.paged:
-                    # parked sessions re-enter BEFORE new admits (they
-                    # were serving first), and a drain-aborted host
-                    # tier closes them under its named reason here
-                    self._service_parked()
+                # parked sessions re-enter BEFORE new admits (they
+                # were serving first), and a drain-aborted host tier
+                # closes them under its named reason here
+                self._service_parked()
                 with self._lock:
                     if len(self._pending) > 1:
                         # SLO order: interactive joins drain first
@@ -1632,7 +1521,7 @@ class ContinuousBatcher:
                 # on THIS round's step
                 self._chunk_round()
                 if not self._sessions and self._flight is None:
-                    if self.paged and self._parked:
+                    if self._parked:
                         # only parked sessions left and none could
                         # resume yet (another holder must release
                         # first): timed poll, never a busy spin
@@ -1683,9 +1572,9 @@ class ContinuousBatcher:
                 # the next incarnation's _ensure_engine rebuilds it.
                 # State reset (incl. _thread) happens BEFORE any
                 # fallible allocation: a rebuild failure under the
-                # same pressure must not wedge join() forever.  Paged
-                # mode drops the allocator triple with the pool: its
-                # refcounts describe rows that no longer exist.
+                # same pressure must not wedge join() forever.  The
+                # allocator triple goes with the pool: its refcounts
+                # describe rows that no longer exist.
                 self._cache = None
                 self._d_cache = None   # the draft pool donated too
                 self._bt[:] = 0
@@ -1711,7 +1600,7 @@ class LMService(Service):
 
     def __init__(self, cfg: Optional[LMConfig] = None, params=None,
                  max_new_cap: int = 128, quantize: bool = False,
-                 decode_slots: int = 8, paged: bool = False,
+                 decode_slots: int = 8, paged: bool = True,
                  page: int = 16, kv_pages: Optional[int] = None,
                  kv_host_slots: int = 0, prefix: bool = True,
                  prefill_chunk_tokens: Optional[int] = None,
@@ -1744,8 +1633,9 @@ class LMService(Service):
         # Decode call (Generate-only deployments never pay the batch
         # step compile).  scan_layers configs serve Generate only.
         self.decode_slots = int(decode_slots)
-        # paged-KV serving knobs (kv/pages allocator; inert when off)
-        self.paged = bool(paged)
+        # paged-KV serving knobs (kv/pages allocator)
+        if not paged:
+            raise ValueError(_PAGED_ONLY)
         self.page = int(page)
         self.kv_pages = kv_pages
         self.kv_host_slots = int(kv_host_slots)
@@ -1763,8 +1653,7 @@ class LMService(Service):
             if self._batcher is None:
                 self._batcher = ContinuousBatcher(
                     self.cfg, self.params, slots=self.decode_slots,
-                    paged=self.paged, page=self.page,
-                    pages=self.kv_pages,
+                    page=self.page, pages=self.kv_pages,
                     host_slots=self.kv_host_slots,
                     prefix=self.prefix,
                     prefill_chunk_tokens=self.prefill_chunk_tokens,

@@ -287,18 +287,81 @@ def _split_qkv(cfg: LMConfig, qkv):
 
 
 def _ffn(cfg: LMConfig, bp, h):
-    """The dense feed-forward part of a serving layer: ``gelu(h w1)
-    w2``, or gated, ``(silu(gate) * up) w2`` with gate and up side by
-    side in ``w1``."""
+    """The feed-forward part of every serving layer: ``gelu(h w1) w2``;
+    gated, ``(silu(gate) * up) w2`` with gate and up side by side in
+    ``w1``; or the Mixture-of-Experts FFN (models/moe.py)."""
     import jax
     import jax.numpy as jnp
 
+    # every weight matmul goes through qmatmul: plain arrays take the
+    # usual bf16 path, QuantTensors (quantize_lm_params) stream int8
+    # weights — the serving win, since single-token decode is bound by
+    # weight bytes read per step, not FLOPs (ops/quant.py)
     from ..ops.quant import qmatmul
+    if cfg.moe_experts > 0:
+        from .moe import forward_grouped
+        out, _aux = forward_grouped(bp["moe"], h, cfg.moe_cfg())
+        return out
     up = qmatmul(h, bp["w1"])
     if cfg.ffn == "gated_silu":
         gate, up = jnp.split(up, 2, axis=-1)
         return qmatmul(jax.nn.silu(gate) * up, bp["w2"])
     return qmatmul(jax.nn.gelu(up), bp["w2"])
+
+
+def _ffn_residual(cfg: LMConfig, bp, x):
+    """The second half of a serving layer, after either mixer: norm,
+    :func:`_ffn`, residual."""
+    return x + _ffn(cfg, bp, _rmsnorm(x, bp["ln2"]))
+
+
+def _rope_at(cfg: LMConfig, pos):
+    """sin/cos of the rotary embedding at the positions ``pos``,
+    anything that broadcasts to a program's ``(b, w)``: a scalar (one
+    stream's step), ``(b, 1)`` (a step of slots at their own depths),
+    ``(w,)`` (a prompt, a chunk), ``(b, w)`` (speculative verify);
+    None where the block has no rotary.  The math of
+    :func:`_rope_tables`, so a slice rotates as the whole prompt does;
+    the serving rotation's one home.  A program makes them once and
+    hands them to every layer, as ``make_forward`` does its tables."""
+    import jax.numpy as jnp
+    if not cfg.rope:
+        return None
+    half = cfg.head_dim // 2
+    freq = jnp.exp(-math.log(10000.0)
+                   * jnp.arange(half, dtype=jnp.float32) / half)
+    ang = jnp.asarray(pos).astype(jnp.float32)[..., None, None] * freq
+    return jnp.sin(ang), jnp.cos(ang)
+
+
+def _qkv(cfg: LMConfig, bp, x, rot):
+    """The first half of a serving attention layer for ``x`` ``(b, w,
+    dim)``: norm, the fused projection, split into ``heads`` query and
+    ``kv_heads`` key and value heads, rotated by ``rot``
+    (:func:`_rope_at`'s) where the block has rotary.  What a program
+    does with them (where the new rows are written, how attention
+    reaches the cached ones) is its own."""
+    from ..ops.quant import qmatmul
+    b, w, _ = x.shape
+    q, k, v = _split_qkv(cfg, qmatmul(_rmsnorm(x, bp["ln1"]), bp["wqkv"]))
+    q = q.reshape(b, w, cfg.heads, cfg.head_dim)
+    k = k.reshape(b, w, cfg.kv_heads, cfg.head_dim)
+    v = v.reshape(b, w, cfg.kv_heads, cfg.head_dim)
+    if rot is not None:
+        q = _rope(q, *rot)
+        k = _rope(k, *rot)
+    return q, k, v
+
+
+def _attn_out(cfg: LMConfig, bp, x, att):
+    """The second half of a serving attention layer: the attended
+    values ``att`` (``(b, w, heads, hd)``, or a step's ``(b, heads,
+    hd)`` as its kernel returns them) through ``wo``, residual, then
+    :func:`_ffn_residual`."""
+    from ..ops.quant import qmatmul
+    b, w, _ = x.shape
+    x = x + qmatmul(att.reshape(b, w, cfg.heads * cfg.head_dim), bp["wo"])
+    return _ffn_residual(cfg, bp, x)
 
 
 def _logits(cfg: LMConfig, params, x):
@@ -386,28 +449,19 @@ def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
     return forward
 
 
-def _prefill_attn_layer(cfg: LMConfig, bp, x, sin, cos, ffn):
+def _prefill_attn_layer(cfg: LMConfig, bp, x, rot):
     """One attention layer of prompt processing, the one home of every
     serving prefill's: returns (x, k, v) with k/v (``kv_heads`` of
-    them) written into fresh max_seq caches.  ``ffn(bp, h)`` is the
-    caller's feed-forward part."""
+    them) written into fresh max_seq caches."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.quant import qmatmul
-
-    b, s = x.shape[0], x.shape[1]
-    hd = cfg.head_dim
-    h = _rmsnorm(x, bp["ln1"])
-    qkv = qmatmul(h, bp["wqkv"])
-    q, k, v = _split_qkv(cfg, qkv)
-    q = q.reshape(b, s, cfg.heads, hd)
-    k = k.reshape(b, s, cfg.kv_heads, hd)
-    v = v.reshape(b, s, cfg.kv_heads, hd)
-    if cfg.rope:
-        q, k = (_rope(t, sin, cos) for t in (q, k))
-    kc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, hd), jnp.float32)
-    vc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, hd), jnp.float32)
+    b = x.shape[0]
+    q, k, v = _qkv(cfg, bp, x, rot)
+    kc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, cfg.head_dim),
+                   jnp.float32)
+    vc = jnp.zeros((b, cfg.max_seq, cfg.kv_heads, cfg.head_dim),
+                   jnp.float32)
     kc = jax.lax.dynamic_update_slice(kc, k, (0, 0, 0, 0))
     vc = jax.lax.dynamic_update_slice(vc, v, (0, 0, 0, 0))
     if cfg.kv_heads != cfg.heads:
@@ -419,62 +473,42 @@ def _prefill_attn_layer(cfg: LMConfig, bp, x, sin, cos, ffn):
     from ..ops.flash_attention import attention
     impl = "flash" if cfg.use_flash else cfg.attn_impl
     att = attention(q, k, v, causal=cfg.causal, impl=impl)
-    x = x + qmatmul(att.reshape(b, s, cfg.heads * hd), bp["wo"])
-    x = x + ffn(bp, _rmsnorm(x, bp["ln2"]))
-    return x, kc, vc
+    return _attn_out(cfg, bp, x, att), kc, vc
 
 
-def _make_block_prefill(cfg: LMConfig):
-    """The prompt pass of a block beyond the first (a mixer schedule,
-    grouped heads, ...): ``prefill(params, ids[1, s], ctx_len) ->
-    (cache, logits)``.  ``ids`` is a zero-padded bucket and ``ctx_len``
-    its true length: a causal attention forgives the padding, a
-    recurrence does not, so a state layer returns its state AT
-    ``ctx_len`` (``h<i>`` and the convolution's tail ``c<i>``), an
-    attention layer ``k<i>``/``v<i>`` as :func:`make_decode`'s does;
-    the logits are those of position ``ctx_len - 1``."""
-    import functools
-
+def make_prefill(cfg: LMConfig):
+    """The serving engine's prompt pass, for every ``LMConfig`` it
+    serves: ``prefill(params, ids[1, s], ctx_len) -> (cache, logits)``.
+    ``ids`` is a zero-padded bucket and ``ctx_len`` its true length: a
+    causal attention forgives the padding, a recurrence does not, so a
+    state layer returns its state AT ``ctx_len`` (``h<i>`` and the
+    convolution's tail ``c<i>``), an attention layer ``k<i>``/``v<i>``
+    as :func:`make_decode`'s does; the logits are those of position
+    ``ctx_len - 1``."""
     import jax.numpy as jnp
 
     from . import ssm_mixer
-
-    ffn = functools.partial(_ffn, cfg)
 
     def prefill(params, ids, ctx_len):
         b, s = ids.shape
         assert b == 1 and s <= cfg.max_seq
         x = params["embed"][ids]
-        sin, cos = _rope_tables(s, cfg.head_dim) if cfg.rope \
-            else (None, None)
+        rot = _rope_at(cfg, jnp.arange(s))
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
             if cfg.mixers[i] == "ssm":
                 out, h, tail = ssm_mixer.prefill(
                     cfg, bp, _rmsnorm(x, bp["ln1"]), ctx_len)
-                x = x + out
-                x = x + ffn(bp, _rmsnorm(x, bp["ln2"]))
+                x = _ffn_residual(cfg, bp, x + out)
                 cache[f"h{i}"], cache[f"c{i}"] = h, tail
             else:
-                x, kc, vc = _prefill_attn_layer(cfg, bp, x, sin, cos, ffn)
+                x, kc, vc = _prefill_attn_layer(cfg, bp, x, rot)
                 cache[f"k{i}"], cache[f"v{i}"] = kc, vc
         last = jnp.take(x, jnp.maximum(ctx_len - 1, 0), axis=1)
         return cache, _logits(cfg, params, last)
 
     return prefill
-
-
-def _rope_at(x, pos, head_dim: int):
-    """Rotary embedding for ONE position (traced scalar) — same math as
-    the table path, built for a single position and fed to _rope so the
-    rotation (and any future base/NTK change) has one home."""
-    import jax.numpy as jnp
-    half = head_dim // 2
-    freq = jnp.exp(-math.log(10000.0)
-                   * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = (pos.astype(jnp.float32) * freq)[None, None, None, :]
-    return _rope(x, jnp.sin(ang), jnp.cos(ang))
 
 
 def make_decode(cfg: LMConfig):
@@ -494,45 +528,16 @@ def make_decode(cfg: LMConfig):
     import jax.numpy as jnp
 
     require_plain_block(cfg, "make_decode (the contiguous cache)")
-    hd = cfg.dim // cfg.heads
+    hd = cfg.head_dim
     if cfg.scan_layers and cfg.moe_experts > 0:
         raise NotImplementedError(
             "scanned decode does not support MoE blocks — use "
             "scan_layers=False for MoE serving")
-    if cfg.moe_experts > 0:
-        from .moe import forward_grouped as moe_forward
-        moe_cfg = cfg.moe_cfg()
 
-    # every weight matmul goes through qmatmul: plain arrays take the
-    # usual bf16 path, QuantTensors (quantize_lm_params) stream int8
-    # weights — the serving win, since single-token decode is bound by
-    # weight bytes read per step, not FLOPs (ops/quant.py)
-    from ..ops.quant import qmatmul
-
-    def mlp(bp, h):
-        if cfg.moe_experts > 0:
-            out, _ = moe_forward(bp["moe"], h, moe_cfg)
-            return out
-        up = qmatmul(h, bp["w1"])
-        return qmatmul(jax.nn.gelu(up), bp["w2"])
-
-    def unembed(params, x_last):
-        return qmatmul(x_last, params["unembed"])
-
-    def prefill_layer(bp, x, sin, cos):
-        return _prefill_attn_layer(cfg, bp, x, sin, cos, mlp)
-
-    def decode_layer(bp, x, kc, vc, pos):
+    def decode_layer(bp, x, kc, vc, pos, rot):
         """One block of single-token decode; returns (x, kc, vc) with
         this token's k/v written at ``pos``."""
-        b = x.shape[0]
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (b, 1, cfg.heads, hd)
-        q = _rope_at(q.reshape(shp), pos, hd)
-        k = _rope_at(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
+        q, k, v = _qkv(cfg, bp, x, rot)
         kc = jax.lax.dynamic_update_slice(kc, k, (0, pos, 0, 0))
         vc = jax.lax.dynamic_update_slice(vc, v, (0, pos, 0, 0))
         # attend the single query over the cached prefix
@@ -544,54 +549,54 @@ def make_decode(cfg: LMConfig):
         p = jax.nn.softmax(s_mat, axis=-1)
         att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
                          preferred_element_type=jnp.float32)
-        x = x + qmatmul(att.reshape(b, 1, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, kc, vc
+        return _attn_out(cfg, bp, x, att), kc, vc
 
     def prefill(params, ids):
         b, s = ids.shape
         assert s <= cfg.max_seq
         x = params["embed"][ids]
-        sin, cos = _rope_tables(s, hd)
+        rot = _rope_at(cfg, jnp.arange(s))
         if cfg.scan_layers:
             # one compiled layer body regardless of depth — the serving
             # answer to compile-time scaling (the train path's story,
             # make_forward): caches come back stacked (depth, ...)
             def body(x, bp):
-                x, kc, vc = prefill_layer(bp, x, sin, cos)
+                x, kc, vc = _prefill_attn_layer(cfg, bp, x, rot)
                 return x, (kc, vc)
 
             x, (kcs, vcs) = jax.lax.scan(body, x, params["blocks"])
             cache = {"len": jnp.int32(s), "k": kcs, "v": vcs}
-            return cache, unembed(params, x[:, -1])
+            return cache, _logits(cfg, params, x[:, -1])
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
-            x, kc, vc = prefill_layer(params[f"blk{i}"], x, sin, cos)
+            x, kc, vc = _prefill_attn_layer(cfg, params[f"blk{i}"], x, rot)
             cache[f"k{i}"], cache[f"v{i}"] = kc, vc
-        return cache, unembed(params, x[:, -1])
+        return cache, _logits(cfg, params, x[:, -1])
 
     def decode_step(params, cache, token):
         cache = dict(cache)      # never mutate the caller's dict (an
                                  # eager caller may fork it — beam/retry)
         pos = cache["len"]                           # traced scalar
         x = params["embed"][token][:, None, :]       # (b, 1, d)
+        rot = _rope_at(cfg, pos)
         if cfg.scan_layers:
             def body(x, layer):
                 bp, kc, vc = layer
-                x, kc, vc = decode_layer(bp, x, kc, vc, pos)
+                x, kc, vc = decode_layer(bp, x, kc, vc, pos, rot)
                 return x, (kc, vc)
 
             x, (kcs, vcs) = jax.lax.scan(
                 body, x, (params["blocks"], cache["k"], cache["v"]))
             cache["k"], cache["v"] = kcs, vcs
             cache["len"] = pos + 1
-            return cache, unembed(params, x[:, 0])
+            return cache, _logits(cfg, params, x[:, 0])
         for i in range(cfg.depth):
             x, kc, vc = decode_layer(params[f"blk{i}"], x,
-                                     cache[f"k{i}"], cache[f"v{i}"], pos)
+                                     cache[f"k{i}"], cache[f"v{i}"], pos,
+                                     rot)
             cache[f"k{i}"], cache[f"v{i}"] = kc, vc
         cache["len"] = pos + 1
-        return cache, unembed(params, x[:, 0])
+        return cache, _logits(cfg, params, x[:, 0])
 
     return prefill, decode_step
 
@@ -668,220 +673,15 @@ def decode_cache_from_pages(cfg: LMConfig, arrays):
     return cache
 
 
-def _rope_at_vec(x, pos, head_dim: int):
-    """Rotary embedding at PER-ELEMENT positions — the continuous-
-    batching variant of :func:`_rope_at`: ``x`` is (b, 1, heads, hd)
-    and ``pos`` is a (b,) vector, so every batch slot rotates at its
-    own sequence position (sessions in one batch sit at different
-    depths).  Same math, same single home for the rotation."""
-    import jax.numpy as jnp
-    half = head_dim // 2
-    freq = jnp.exp(-math.log(10000.0)
-                   * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None, None, None] \
-        * freq[None, None, None, :]
-    return _rope(x, jnp.sin(ang), jnp.cos(ang))
-
-
-def _rope_span_vec(x, pos, head_dim: int):
-    """Rotary embedding for a SPAN of positions shared across batch —
-    the chunked-prefill variant: ``x`` is (b, s, heads, hd) and ``pos``
-    is an (s,) position vector (typically ``start + arange(chunk)``),
-    the exact math :func:`_rope_tables` produces for ``arange(s)`` —
-    so a chunk slice rotates identically with the whole-prompt pass."""
-    import jax.numpy as jnp
-    half = head_dim // 2
-    freq = jnp.exp(-math.log(10000.0)
-                   * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[None, :, None, None] \
-        * freq[None, None, None, :]
-    return _rope(x, jnp.sin(ang), jnp.cos(ang))
-
-
-def _rope_at_mat(x, pos, head_dim: int):
-    """Rotary embedding at PER-(slot, offset) positions — the
-    speculative-verify variant: ``x`` is (b, w, heads, hd) and ``pos``
-    is a (b, w) position matrix (each slot's ``len + arange(w)``).
-    Same rotation, same single home."""
-    import jax.numpy as jnp
-    half = head_dim // 2
-    freq = jnp.exp(-math.log(10000.0)
-                   * jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, :, None, None] \
-        * freq[None, None, None, :]
-    return _rope(x, jnp.sin(ang), jnp.cos(ang))
-
-
-def make_batch_decode(cfg: LMConfig, chunk: Optional[int] = None):
-    """Continuous-batching decode: one compiled step over a FIXED pool
-    of session slots, each at its OWN position — the serving shape
-    where new sessions join the live batch between steps and finished
-    ones evict (the streaming LM service's engine).
-
-    Returns ``(prefill, step)``:
-      - ``prefill`` is :func:`make_decode`'s prompt pass, run per
-        joining session at batch 1 — the batcher copies the resulting
-        per-layer caches into the session's slot;
-      - ``step(params, cache, token[b], active[b]) -> (cache, logits)``
-        advances every ACTIVE slot one token.  ``cache["len"]`` is a
-        per-slot (b,) int32 position vector (vs the scalar in
-        :func:`make_decode`); inactive slots are position-clamped and
-        never advance, and their logits are garbage by contract.
-
-    With ``chunk`` set, a third program is returned — the
-    chunk-scatter path of SLO-tiered scheduling (Sarathi-style chunked
-    prefill): ``chunk_step(params, cache, slot, start, n, ids[chunk])
-    -> cache`` prefills ``n`` context tokens of one slot at positions
-    ``start..start+n-1`` and sets that slot's len to ``start + n``.
-    Padding entries (``j >= n``) write their garbage k/v into row
-    ``max_seq - 1``, which every admissible session rewrites before
-    the live mask admits it (``ctx + max_new <= max_seq`` with
-    ``max_new >= 1`` keeps valid context rows strictly below it).
-    The slice attends with the same masked softmax as the decode step,
-    so a fully chunk-prefilled slot is identical-by-construction to a
-    whole-prompt prefill insert — the chunked-prefill identity pin.
-
-    Per-element math is independent (attention never crosses the batch
-    axis), so an active slot's tokens are identical with a solo
-    :func:`make_decode` run of the same session.  Unrolled dense/MoE
-    blocks only — ``scan_layers`` serving should batch per-depth
-    shards instead."""
-    import jax
-    import jax.numpy as jnp
-
-    require_plain_block(cfg, "make_batch_decode (paged=False)")
-    hd = cfg.dim // cfg.heads
-    if cfg.scan_layers:
-        raise NotImplementedError(
-            "batch decode supports unrolled layers only — scan_layers "
-            "serving uses make_decode per shard")
-    if cfg.moe_experts > 0:
-        from .moe import forward_grouped as moe_forward
-        moe_cfg = cfg.moe_cfg()
-
-    from ..ops.quant import qmatmul
-
-    def mlp(bp, h):
-        if cfg.moe_experts > 0:
-            out, _ = moe_forward(bp["moe"], h, moe_cfg)
-            return out
-        up = qmatmul(h, bp["w1"])
-        return qmatmul(jax.nn.gelu(up), bp["w2"])
-
-    def decode_layer(bp, x, kc, vc, pos):
-        """One block, one token per slot, per-slot positions."""
-        b = x.shape[0]
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (b, 1, cfg.heads, hd)
-        q = _rope_at_vec(q.reshape(shp), pos, hd)
-        k = _rope_at_vec(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
-
-        def upd(cache_b, new_b, pos_b):
-            return jax.lax.dynamic_update_slice(cache_b, new_b,
-                                                (pos_b, 0, 0))
-
-        kc = jax.vmap(upd)(kc, k, pos)
-        vc = jax.vmap(upd)(vc, v, pos)
-        s_mat = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
-                           preferred_element_type=jnp.float32
-                           ) / (hd ** 0.5)
-        live = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
-        s_mat = jnp.where(live[:, None, None, :], s_mat, -1e30)
-        p = jax.nn.softmax(s_mat, axis=-1)
-        att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
-                         preferred_element_type=jnp.float32)
-        x = x + qmatmul(att.reshape(b, 1, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, kc, vc
-
-    def step(params, cache, token, active):
-        cache = dict(cache)
-        pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
-        x = params["embed"][token][:, None, :]
-        for i in range(cfg.depth):
-            x, kc, vc = decode_layer(params[f"blk{i}"], x,
-                                     cache[f"k{i}"], cache[f"v{i}"],
-                                     pos)
-            cache[f"k{i}"], cache[f"v{i}"] = kc, vc
-        cache["len"] = jnp.where(active, cache["len"] + 1,
-                                 cache["len"])
-        return cache, qmatmul(x[:, 0], params["unembed"])
-
-    prefill, _ = make_decode(cfg)
-    if chunk is None:
-        return prefill, step
-
-    cw = int(chunk)
-
-    def chunk_layer(bp, x, kc, vc, slot, rows, pos):
-        """One block of a chunked prefill slice for ONE slot: scatter
-        the slice's k/v rows, then attend each slice query over the
-        slot's full cached stripe under the same causal live mask the
-        decode step uses."""
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (1, cw, cfg.heads, hd)
-        q = _rope_span_vec(q.reshape(shp), pos, hd)
-        k = _rope_span_vec(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
-        kc = kc.at[slot, rows].set(k[0])
-        vc = vc.at[slot, rows].set(v[0])
-        kcs = kc[slot]                    # (max_seq, heads, hd)
-        vcs = vc[slot]
-        s_mat = jnp.einsum("qhd,khd->hqk", q[0], kcs,
-                           preferred_element_type=jnp.float32
-                           ) / (hd ** 0.5)
-        live = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
-        s_mat = jnp.where(live[None, :, :], s_mat, -1e30)
-        p = jax.nn.softmax(s_mat, axis=-1)
-        att = jnp.einsum("hqk,khd->qhd", p, vcs,
-                         preferred_element_type=jnp.float32)
-        x = x + qmatmul(att.reshape(1, cw, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, kc, vc
-
-    def chunk_step(params, cache, slot, start, n, ids):
-        cache = dict(cache)
-        j = jnp.arange(cw)
-        valid = j < n
-        pos = start + j
-        # invalid (padding) rows land on max_seq-1: a garbage row every
-        # admissible session overwrites before its mask admits it
-        rows = jnp.where(valid, jnp.minimum(pos, cfg.max_seq - 1),
-                         cfg.max_seq - 1)
-        x = params["embed"][ids][None]            # (1, chunk, dim)
-        for i in range(cfg.depth):
-            x, kc, vc = chunk_layer(params[f"blk{i}"], x,
-                                    cache[f"k{i}"], cache[f"v{i}"],
-                                    slot, rows, pos)
-            cache[f"k{i}"], cache[f"v{i}"] = kc, vc
-        cache["len"] = cache["len"].at[slot].set(start + n)
-        return cache
-
-    return prefill, step, chunk_step
-
-
-def empty_batch_cache(cfg: LMConfig, slots: int):
-    """A fresh slot-pool KV cache for :func:`make_batch_decode` —
-    ``len`` is the per-slot position vector (all zero = every slot
-    free); layer layouts match :func:`empty_cache`'s unrolled form."""
-    import jax.numpy as jnp
-    cache = empty_cache(cfg, slots, start_len=1)
-    cache["len"] = jnp.zeros((slots,), jnp.int32)
-    return cache
-
-
 def make_paged_batch_decode(cfg: LMConfig, page: int):
-    """Block-paged continuous batching: :func:`make_batch_decode` with
-    the per-slot contiguous cache arrays replaced by ONE shared page
-    pool per layer plus a per-slot **block table** — the serving shape
-    where a session holds only ``ctx_len``-rounded pages instead of a
-    full ``max_seq`` stripe, and where two sessions may ALIAS the same
-    page (the cross-session prefix cache).
+    """Block-paged continuous batching: one compiled step over a FIXED
+    pool of session slots, each at its OWN position — the serving shape
+    where new sessions join the live batch between steps and finished
+    ones evict (the streaming LM service's engine).  KV lives in ONE
+    shared page pool per layer plus a per-slot **block table**: a
+    session holds only ``ctx_len``-rounded pages, never a ``max_seq``
+    stripe, and two sessions may ALIAS the same page (the
+    cross-session prefix cache).
 
     Layout (one logical address space across layers): logical page ``p``
     is row-block ``p`` of EVERY layer's k/v pool, shaped
@@ -891,67 +691,53 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     ``live`` mask only reaches rows <= pos, all of which the owning
     session has written).
 
-    Returns ``(prefill, step)`` where
+    Returns ``(prefill, step)``: ``prefill`` is :func:`make_prefill`'s,
+    run per joining session at batch 1 (the batcher blockifies its
+    caches into the session's pages, :func:`make_paged_io`), and
     ``step(params, cache, bt, token[b], active[b]) -> (cache, logits)``
-    and ``bt`` is the (slots, max_seq // page) int32 block table (host-
-    owned, passed per step — NOT part of the donated cache).  The step
-    scatters the new k/v row into ``pool[bt[b, pos // page], pos % page]``
-    and attends over positions ``0..pos`` through the block table
-    (``ops.paged_attention``): on the TPU a kernel that reads each
-    slot's live pages from the pool once, with an online softmax; off
-    it the plain gather of ``pool[bt]`` under the contiguous step's
-    mask.  Token identity with :func:`make_batch_decode` is no longer
-    by construction on the TPU: the per-lane tests pin it on the cpu
-    backend and ``tests/test_paged_attention.py`` holds the kernel to
-    the plain formulation.
+    advances every ACTIVE slot one token.  ``cache["len"]`` is a
+    per-slot (b,) int32 position vector; inactive slots are
+    position-clamped and never advance, and their logits are garbage
+    by contract.  ``bt`` is the (slots, max_seq // page) int32 block
+    table (host-owned, passed per step — NOT part of the donated
+    cache).  The step scatters the new k/v row into
+    ``pool[bt[b, pos // page], pos % page]`` and attends over positions
+    ``0..pos`` through the block table (``ops.paged_attention``): on
+    the TPU a kernel that reads each slot's live pages from the pool
+    once, with an online softmax; off it the plain gather of
+    ``pool[bt]`` under the live mask.  Per-element math is independent
+    (attention never crosses the batch axis), so an active slot's
+    tokens are those of a solo :func:`make_decode` run of the same
+    session: by construction off the TPU (the per-lane tests pin it),
+    and ``tests/test_paged_attention.py`` holds the kernel to the
+    plain formulation.
 
     A block beyond the first (``LMConfig.mixers``, ``kv_heads``, ...)
     is served here and nowhere else.  Only attention layers have pools
     (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
     state layer has ``sh<i>``/``sc<i>``, one block of recurrent state
     for each SLOT, which the step moves one position where the slot is
-    ``active``.  ``prefill`` is then ``prefill(params, ids[1, s],
-    ctx_len)`` (:func:`_make_block_prefill`)."""
-    import jax
+    ``active``.  Unrolled layers only."""
     import jax.numpy as jnp
 
-    hd = cfg.head_dim
     if cfg.scan_layers:
         raise NotImplementedError(
             "paged batch decode supports unrolled layers only")
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    if cfg.moe_experts > 0:
-        from .moe import forward_grouped as moe_forward
-        moe_cfg = cfg.moe_cfg()
 
     from ..ops import paged_attention
-    from ..ops.quant import qmatmul
     from . import ssm_mixer
 
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
 
-    def mlp(bp, h):
-        if cfg.moe_experts > 0:
-            out, _ = moe_forward(bp["moe"], h, moe_cfg)
-            return out
-        return _ffn(cfg, bp, h)
-
-    def attn_mixer(bp, x, pk, pv, bt, pos, att_pos):
-        """One attention mixer, one token per slot, block-table
-        addressing; the residual added."""
+    def attn_layer(bp, x, pk, pv, bt, pos, att_pos, rot):
+        """One attention layer, one token per slot, block-table
+        addressing."""
         b = x.shape[0]
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = _split_qkv(cfg, qkv)
-        q = q.reshape(b, 1, cfg.heads, hd)
-        k = k.reshape(b, 1, kvh, hd)
-        v = v.reshape(b, 1, kvh, hd)
-        if cfg.rope:
-            q = _rope_at_vec(q, pos, hd)
-            k = _rope_at_vec(k, pos, hd)
+        q, k, v = _qkv(cfg, bp, x, rot)
 
         # scatter this step's row into each slot's CURRENT page
         page_idx = bt[jnp.arange(b), pos // page]
@@ -971,14 +757,14 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         # ``len`` its last session left behind
         att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
                                         page)
-        x = x + qmatmul(att.reshape(b, 1, cfg.heads * hd), bp["wo"])
-        return x, pk, pv
+        return _attn_out(cfg, bp, x, att), pk, pv
 
     def step(params, cache, bt, token, active):
         cache = dict(cache)
         pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
         att_pos = jnp.where(active, pos, 0)
         x = params["embed"][token][:, None, :]
+        rot = _rope_at(cfg, pos[:, None])
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
             if cfg.mixers[i] == "ssm":
@@ -987,22 +773,18 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
                 out, h, tail = ssm_mixer.step(
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"]),
                     cache[f"sh{i}"], cache[f"sc{i}"], active)
-                x = x + out[:, None]
+                x = _ffn_residual(cfg, bp, x + out[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
             else:
-                x, pk, pv = attn_mixer(bp, x, cache[f"pk{i}"],
-                                       cache[f"pv{i}"], bt, pos, att_pos)
+                x, pk, pv = attn_layer(bp, x, cache[f"pk{i}"],
+                                       cache[f"pv{i}"], bt, pos, att_pos,
+                                       rot)
                 cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
-            x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
         return cache, _logits(cfg, params, x[:, 0])
 
-    if cfg.plain_block():
-        prefill, _ = make_decode(cfg)
-    else:
-        prefill = _make_block_prefill(cfg)
-    return prefill, step
+    return make_prefill(cfg), step
 
 
 def _paged_pool_shape(cfg: LMConfig, num_pages: int, page: int) -> tuple:
@@ -1057,6 +839,37 @@ def state_slot_bytes(cfg: LMConfig) -> int:
     return len(cfg.ssm_layers()) * ssm_mixer.state_bytes(cfg)
 
 
+def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
+    """One attention layer over a SPAN of ``w`` new positions a slot,
+    block-table addressing: the chunk slice's (its ``b = 1`` case) and
+    the speculative verify's.  ``x`` is ``(b, w, dim)``, ``bt`` ``(b,
+    max_seq // page)``; ``page_idx``, ``row`` and ``pos`` are ``(b,
+    w)``: where each new row is written, and the position it is
+    rotated and masked at.  Scatter before gather: the rows are
+    written, then each query attends over its slot's block table
+    gathered back into the contiguous ``max_seq`` view, under the live
+    mask ``key <= pos`` — the decode step's own, so a span is
+    identical by construction with as many single steps."""
+    import jax
+    import jax.numpy as jnp
+
+    b = x.shape[0]
+    q, k, v = _qkv(cfg, bp, x, _rope_at(cfg, pos))
+    pk = pk.at[page_idx, row].set(k)
+    pv = pv.at[page_idx, row].set(v)
+    kc = pk[bt].reshape(b, cfg.max_seq, cfg.heads, cfg.head_dim)
+    vc = pv[bt].reshape(b, cfg.max_seq, cfg.heads, cfg.head_dim)
+    s_mat = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
+                       preferred_element_type=jnp.float32
+                       ) / (cfg.head_dim ** 0.5)
+    live = jnp.arange(cfg.max_seq)[None, None, :] <= pos[:, :, None]
+    s_mat = jnp.where(live[:, None, :, :], s_mat, -1e30)
+    p = jax.nn.softmax(s_mat, axis=-1)
+    att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
+                     preferred_element_type=jnp.float32)
+    return _attn_out(cfg, bp, x, att), pk, pv
+
+
 def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
     """Page-granular device I/O for the paged cache — the spill /
     resume / prefill-insert data motion, all fixed-shape (padded to the
@@ -1068,32 +881,66 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         block (k then v per layer on axis 1);
       - ``scatter(cache, page_ids[pps], block) -> cache`` — the
         inverse (resume's H2D landing);
-      - ``insert(cache, page_ids[pps], src) -> cache`` — a batch-1
-        prefilled contiguous cache (``make_decode``'s) blockified into
-        the session's pages.
+      - ``insert(cache, page_ids[pps], src, slot) -> cache`` — a
+        batch-1 prefilled cache (:func:`make_prefill`'s): an attention
+        layer's ``k<i>``/``v<i>`` blockified into the session's pages,
+        a state layer's ``h<i>``/``c<i>`` written over WHATEVER the
+        slot's last session left in its block of the state pool (for a
+        schedule without state layers ``slot`` addresses nothing).
     Padding entries point at page 0 and only ever write garbage there.
 
     With ``chunk`` set a FOURTH program rides along — the block-paged
-    chunk-scatter path of SLO-tiered scheduling:
-    ``chunk_prefill(params, cache, bt_row[pps], slot, start, n,
-    ids[chunk]) -> cache`` prefills ``n`` context tokens of one slot
+    chunk-scatter path of SLO-tiered scheduling (Sarathi-style chunked
+    prefill): ``chunk_prefill(params, cache, bt_row[pps], slot, start,
+    n, ids[chunk]) -> cache`` prefills ``n`` context tokens of one slot
     at positions ``start..start+n-1``, scattering each row into
     ``bt_row[pos // page]`` and setting the slot's len to
     ``start + n``.  Padding entries write the garbage page 0 (the
     established paged-padding idiom), and a partial prefix hit's
     catch-up starts at a page-aligned ``covered`` — so aliased prefix
-    pages are never written.  The slice gathers the block table back
-    into the contiguous view and attends under the decode step's own
-    live mask: a fully chunk-prefilled slot is
-    identical-by-construction to a whole-prompt prefill insert."""
+    pages are never written.  The slice attends as
+    :func:`_paged_span_layer` does: a fully chunk-prefilled slot is
+    identical-by-construction to a whole-prompt prefill insert.
+
+    For a block beyond the first the other three decline by name: a
+    page of keys restores no recurrent state, so spill/resume
+    (``gather``/``scatter``) and the catch-up slices
+    (``chunk_prefill``) are not entered for such a model (the batcher
+    refuses the options that would)."""
+    import jax
     import jax.numpy as jnp
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    if not cfg.plain_block():
-        return _make_block_paged_io(cfg, page, chunk)
     pps = cfg.max_seq // page
-    hd = cfg.dim // cfg.heads
+
+    def insert(cache, page_ids, src, slot):
+        cache = dict(cache)
+        shape = _paged_pool_shape(cfg, pps, page)
+        for i in range(cfg.depth):
+            if cfg.mixers[i] == "ssm":
+                for pool, new in ((f"sh{i}", f"h{i}"), (f"sc{i}", f"c{i}")):
+                    cache[pool] = jax.lax.dynamic_update_slice(
+                        cache[pool], src[new],
+                        (slot,) + (0,) * (src[new].ndim - 1))
+                continue
+            cache[f"pk{i}"] = cache[f"pk{i}"].at[page_ids].set(
+                src[f"k{i}"][0].reshape(shape))
+            cache[f"pv{i}"] = cache[f"pv{i}"].at[page_ids].set(
+                src[f"v{i}"][0].reshape(shape))
+        return cache
+
+    if not cfg.plain_block():
+        def declined(what):
+            def fn(*_a, **_k):
+                require_plain_block(cfg, what)
+            return fn
+
+        io = (declined("make_paged_io gather (host spill)"),
+              declined("make_paged_io scatter (host resume)"), insert)
+        if chunk is None:
+            return io
+        return io + (declined("make_paged_io chunk_prefill (catch-up)"),)
 
     def gather(cache, page_ids):
         blocks = []
@@ -1111,55 +958,10 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
                 block[:, 2 * i + 1])
         return cache
 
-    def insert(cache, page_ids, src):
-        cache = dict(cache)
-        for i in range(cfg.depth):
-            kb = src[f"k{i}"][0].reshape(pps, page, cfg.heads, hd)
-            vb = src[f"v{i}"][0].reshape(pps, page, cfg.heads, hd)
-            cache[f"pk{i}"] = cache[f"pk{i}"].at[page_ids].set(kb)
-            cache[f"pv{i}"] = cache[f"pv{i}"].at[page_ids].set(vb)
-        return cache
-
     if chunk is None:
         return gather, scatter, insert
 
-    import jax
-    from ..ops.quant import qmatmul
-    if cfg.moe_experts > 0:
-        from .moe import forward_grouped as moe_forward
-        moe_cfg = cfg.moe_cfg()
     cw = int(chunk)
-
-    def mlp(bp, h):
-        if cfg.moe_experts > 0:
-            out, _ = moe_forward(bp["moe"], h, moe_cfg)
-            return out
-        up = qmatmul(h, bp["w1"])
-        return qmatmul(jax.nn.gelu(up), bp["w2"])
-
-    def chunk_layer(bp, x, pk, pv, bt_row, page_idx, row, pos):
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (1, cw, cfg.heads, hd)
-        q = _rope_span_vec(q.reshape(shp), pos, hd)
-        k = _rope_span_vec(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
-        pk = pk.at[page_idx, row].set(k[0])
-        pv = pv.at[page_idx, row].set(v[0])
-        kcs = pk[bt_row].reshape(cfg.max_seq, cfg.heads, hd)
-        vcs = pv[bt_row].reshape(cfg.max_seq, cfg.heads, hd)
-        s_mat = jnp.einsum("qhd,khd->hqk", q[0], kcs,
-                           preferred_element_type=jnp.float32
-                           ) / (hd ** 0.5)
-        live = jnp.arange(cfg.max_seq)[None, :] <= pos[:, None]
-        s_mat = jnp.where(live[None, :, :], s_mat, -1e30)
-        p = jax.nn.softmax(s_mat, axis=-1)
-        att = jnp.einsum("hqk,khd->qhd", p, vcs,
-                         preferred_element_type=jnp.float32)
-        x = x + qmatmul(att.reshape(1, cw, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, pk, pv
 
     def chunk_prefill(params, cache, bt_row, slot, start, n, ids):
         cache = dict(cache)
@@ -1171,56 +973,15 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         row = posc % page
         x = params["embed"][ids][None]            # (1, chunk, dim)
         for i in range(cfg.depth):
-            x, pk, pv = chunk_layer(params[f"blk{i}"], x,
-                                    cache[f"pk{i}"], cache[f"pv{i}"],
-                                    bt_row, page_idx, row, start + j)
+            x, pk, pv = _paged_span_layer(
+                cfg, params[f"blk{i}"], x, cache[f"pk{i}"],
+                cache[f"pv{i}"], bt_row[None], page_idx[None], row[None],
+                (start + j)[None])
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = cache["len"].at[slot].set(start + n)
         return cache
 
     return gather, scatter, insert, chunk_prefill
-
-
-def _make_block_paged_io(cfg: LMConfig, page: int, chunk):
-    """:func:`make_paged_io` for a block beyond the first.  ``insert``
-    is ``insert(cache, page_ids[pps], src, slot) -> cache``: an
-    attention layer's prefilled ``k<i>``/``v<i>`` blockified into the
-    session's pages, a state layer's ``h<i>``/``c<i>`` written over
-    WHATEVER the slot's last session left in its block of the state
-    pool.  The other three decline by name: a page of keys restores no
-    recurrent state, so spill/resume (``gather``/``scatter``) and the
-    catch-up slices (``chunk_prefill``) are not entered for such a
-    model (the batcher refuses the options that would)."""
-    import jax
-
-    pps = cfg.max_seq // page
-
-    def declined(what):
-        def fn(*_a, **_k):
-            require_plain_block(cfg, what)
-        return fn
-
-    def insert(cache, page_ids, src, slot):
-        cache = dict(cache)
-        for i in range(cfg.depth):
-            if cfg.mixers[i] == "ssm":
-                for pool, new in ((f"sh{i}", f"h{i}"), (f"sc{i}", f"c{i}")):
-                    cache[pool] = jax.lax.dynamic_update_slice(
-                        cache[pool], src[new],
-                        (slot,) + (0,) * (src[new].ndim - 1))
-                continue
-            shape = _paged_pool_shape(cfg, pps, page)
-            cache[f"pk{i}"] = cache[f"pk{i}"].at[page_ids].set(
-                src[f"k{i}"][0].reshape(shape))
-            cache[f"pv{i}"] = cache[f"pv{i}"].at[page_ids].set(
-                src[f"v{i}"][0].reshape(shape))
-        return cache
-
-    io = (declined("make_paged_io gather (host spill)"),
-          declined("make_paged_io scatter (host resume)"), insert)
-    if chunk is None:
-        return io
-    return io + (declined("make_paged_io chunk_prefill (catch-up)"),)
 
 
 def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
@@ -1236,8 +997,8 @@ def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
     - row ``j`` of ``out`` is the greedy argmax at position
       ``len + j`` given context rows ``0..len+j`` — exactly the token
       the plain decode step would emit after feeding ``tokens[:, :j+1]``
-      (same scatter-before-gather, same live mask, same einsum
-      attention), which is the spec-decode token-identity contract;
+      (:func:`_paged_span_layer`: same scatter-before-gather, same live
+      mask), which is the spec-decode token-identity contract;
     - ``accepted`` is the per-slot length ``m`` of the draft prefix
       matching the target (``d_i == out_{i-1}``), CAPPED at ``k - 1``
       so the draft cache — which holds k/v for inputs ``u_0..u_{k-1}``
@@ -1252,12 +1013,10 @@ def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
 
     The caller must guarantee ``len + width <= max_seq`` for every
     active slot (the batcher falls back to a plain step otherwise)."""
-    import jax
     import jax.numpy as jnp
 
     require_plain_block(cfg, "make_paged_spec_verify (speculative "
                         "verify)")
-    hd = cfg.dim // cfg.heads
     if cfg.scan_layers:
         raise NotImplementedError(
             "spec verify supports unrolled layers only")
@@ -1267,60 +1026,24 @@ def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
     w = int(width)
     if w < 2:
         raise ValueError("spec verify needs width >= 2 (k >= 1)")
-    if cfg.moe_experts > 0:
-        from .moe import forward_grouped as moe_forward
-        moe_cfg = cfg.moe_cfg()
-
-    from ..ops.quant import qmatmul
-
-    def mlp(bp, h):
-        if cfg.moe_experts > 0:
-            out, _ = moe_forward(bp["moe"], h, moe_cfg)
-            return out
-        up = qmatmul(h, bp["w1"])
-        return qmatmul(jax.nn.gelu(up), bp["w2"])
-
-    def verify_layer(bp, x, pk, pv, bt, pos):
-        b = x.shape[0]
-        h = _rmsnorm(x, bp["ln1"])
-        qkv = qmatmul(h, bp["wqkv"])
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        shp = (b, w, cfg.heads, hd)
-        q = _rope_at_mat(q.reshape(shp), pos, hd)
-        k = _rope_at_mat(k.reshape(shp), pos, hd)
-        v = v.reshape(shp)
-        # scatter all w candidate rows (rejected ones become the
-        # garbage a later scatter overwrites — see docstring)
-        page_idx = bt[jnp.arange(b)[:, None], pos // page]
-        row = pos % page
-        pk = pk.at[page_idx, row].set(k)
-        pv = pv.at[page_idx, row].set(v)
-        kc = pk[bt].reshape(b, cfg.max_seq, cfg.heads, hd)
-        vc = pv[bt].reshape(b, cfg.max_seq, cfg.heads, hd)
-        s_mat = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
-                           preferred_element_type=jnp.float32
-                           ) / (hd ** 0.5)
-        live = jnp.arange(cfg.max_seq)[None, None, :] <= pos[:, :, None]
-        s_mat = jnp.where(live[:, None, :, :], s_mat, -1e30)
-        p = jax.nn.softmax(s_mat, axis=-1)
-        att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
-                         preferred_element_type=jnp.float32)
-        x = x + qmatmul(att.reshape(b, w, cfg.dim), bp["wo"])
-        x = x + mlp(bp, _rmsnorm(x, bp["ln2"]))
-        return x, pk, pv
 
     def verify(params, cache, bt, tokens, active):
         cache = dict(cache)
+        b = tokens.shape[0]
         pos = jnp.minimum(
             cache["len"][:, None] + jnp.arange(w)[None, :],
             cfg.max_seq - 1)                       # (b, w)
+        # all w candidate rows are scattered (rejected ones become the
+        # garbage a later scatter overwrites — see docstring)
+        page_idx = bt[jnp.arange(b)[:, None], pos // page]
+        row = pos % page
         x = params["embed"][tokens]                # (b, w, dim)
         for i in range(cfg.depth):
-            x, pk, pv = verify_layer(params[f"blk{i}"], x,
-                                     cache[f"pk{i}"], cache[f"pv{i}"],
-                                     bt, pos)
+            x, pk, pv = _paged_span_layer(
+                cfg, params[f"blk{i}"], x, cache[f"pk{i}"],
+                cache[f"pv{i}"], bt, page_idx, row, pos)
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
-        logits = qmatmul(x, params["unembed"])     # (b, w, vocab)
+        logits = _logits(cfg, params, x)           # (b, w, vocab)
         out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # accepted prefix: d_i (= tokens[:, i]) vs out[:, i-1], capped
         # at k-1 = w-2 (the bonus-token discard)

@@ -52,20 +52,6 @@ WATCHED_RATIOS = (
     # there, so the recorded baseline, not an absolute bar, is the gate
     "loop_scaling_efficiency",
     "loop_scaling_efficiency_4loop",
-    # kind-5 streaming lane (ISSUE 13): paired interleaved A/B of the
-    # native stream transport vs the forced-Python lane at c=64
-    "stream_native_vs_py",
-    # SLO-tiered scheduler (ISSUE 17): all three are paired interleaved
-    # A/B medians.  itl_gain = chunked-OFF loaded p99 / idle p99 (the
-    # head-of-line stall a monolithic prefill inflicts — chunking keeps
-    # the loaded p99 within noise of idle, so the gain is the whole
-    # stall); victim_goodput = untiered/tiered interactive finish time
-    # under batch contention; accept_rate = accepted draft tokens /
-    # proposed (self-draft on the bench cfg is deterministic at 1.0 —
-    # the verify pass is the identity ground truth either way)
-    "slo_chunked_itl_gain",
-    "slo_tier_victim_goodput",
-    "spec_accept_rate",
     # fleet observability (ISSUE 19): 1.0 when the A/B overhead sits
     # within the same-methodology control noise — the serving path
     # pays a flag read and a deque append, so the bar is "A/B median
@@ -95,48 +81,6 @@ RECORDED_BASELINE = {
     # probe (client+server halves in one process — PERF_HISTORY §15)
     "drain_p99_victim_ms": 1.83,
     "conns_10k_rss_mb": 31.6,
-    # ISSUE 13 streaming-lane keys (session box, 2026-08): c=64
-    # sessions, 4 client processes; the A/B ratio is the native stream
-    # transport vs the forced-Python lane, paired interleaved
-    "stream_native_vs_py": 4.68,
-    "stream_tokens_per_s": 3391.3,
-    "stream_ttft_p99_ms": 319.66,
-    "decode_stream_sessions": 64.0,
-    # ISSUE 15 disaggregated prefill/decode keys (session box,
-    # 2026-08): shm page-plane transfer, and the two-tier A/B at c=16
-    # (disagg TTFT carries the handoff RPC; the ratio is paired).
-    # Recorded at the WORSE of two runs (quiet: 9.11 GB/s / 28.4ms /
-    # 1.52x; contended: 4.0 / 58.1 / 1.9) — conservative gates, the
-    # guard exists to catch collapses
-    "kv_transfer_gbps": 4.0,
-    "disagg_ttft_p99_ms": 58.1,
-    "disagg_vs_mono_ttft": 1.9,
-    # ISSUE 16 paged-KV allocator keys (session box, 2026-08): the
-    # sessions-per-box headline moves to the paged decode tier — 128
-    # concurrent sessions on the SAME device byte budget as the 16
-    # contiguous slots above (the overflow rides the host tier), so
-    # the recorded bar moves 16 -> 128 with the bench.  Bytes/session
-    # is near-deterministic (capped pool ÷ completed sessions); the
-    # hit-TTFT is one decode step + RPC, recorded as measured
-    "disagg_sessions_per_box": 128.0,
-    "kv_bytes_per_session": 12288.0,
-    "prefix_cache_hit_ttft_p99_ms": 17.7,
-    # ISSUE 17 SLO-tiered scheduler keys (session box, 2026-08),
-    # recorded at the WORSE of two runs of the final config (chunk
-    # budget 16).  The loaded ITL p99 is stable (10.27/10.88); the
-    # idle p99 is the noisy side of the pair (7.67-10.77 — p99 of a
-    # 60-sample window is near-max statistics on a 1-core box), which
-    # is why the gain ratio gates the contrast instead of an absolute
-    # loaded/idle bar.  The contrast arms (chunked_off, spec plain,
-    # untiered victim) are deliberately-degraded configs and are NOT
-    # recorded — their ratios gate them
-    "decode_itl_p99_ms": 10.88,
-    "decode_itl_idle_p99_ms": 10.77,
-    "slo_chunked_itl_gain": 120.5,
-    "spec_decode_tokens_per_s": 2054.7,
-    "spec_accept_rate": 1.0,
-    "slo_tier_victim_ms": 588.2,
-    "slo_tier_victim_goodput": 1.29,
     # ISSUE 19 fleet observability (session box, 2026-08): one report
     # push → visible on the registry's /fleet page over HTTP, end to
     # end (RPC ingest + page render + one poll round-trip).  Recorded
@@ -150,27 +94,15 @@ RECORDED_BASELINE = {
 # regardless of tolerance (a failed request during a rolling restart is
 # a correctness bug, not a perf regression) — the zero-base rule that
 # exempts ratio denominators must not exempt these
-PINNED_ZERO = ("rolling_restart_failed_rpcs",
-               # a same-host KV handoff moving payload bytes through
-               # the message path is a data-plane regression, not noise
-               "disagg_handoff_copies",
-               # a prefix-cache hit ALIASES the cached context pages
-               # (refcounts move, bytes do not) — any copy during the
-               # hit sessions means the cache degenerated to memcpy
-               "prefix_alias_copies")
+PINNED_ZERO = ("rolling_restart_failed_rpcs",)
 
 _HIGHER = ("_qps", "_gbps", "gbps", "_rps", "_tok_s", "tokens_per_s",
-           "_tflops", "_speedup", "_frac", "_factor_inverse",
-           "_sessions", "_sessions_per_box")
-_LOWER = ("_us", "_ms", "_p50", "_p99", "_rss_mb",
-          "_bytes_per_session")
+           "_tflops", "_speedup", "_frac", "_factor_inverse")
+_LOWER = ("_us", "_ms", "_p50", "_p99", "_rss_mb")
 # gap keys measure raw/cntl — LOWER is better (a shrinking gap is the
 # win); amplification likewise
 _LOWER_RATIOS = ("cntl_vs_raw_gap", "fanout_cntl_vs_raw_gap",
-                 "retry_amplification_factor",
-                 # paired two-tier/monolithic TTFT: the handoff's cost,
-                 # shrinking is the win
-                 "disagg_vs_mono_ttft")
+                 "retry_amplification_factor")
 
 
 def direction_of(key: str) -> Optional[int]:

@@ -234,7 +234,7 @@ def stage_serving(lm_kw: dict, requests, gen_req, slots: int, page: int,
     cfg = LMConfig(remat=False, **lm_kw)
     params = init_params(jax.random.PRNGKey(0), cfg)
     svc = LMService(cfg=cfg, params=params, decode_slots=slots,
-                    paged=True, page=page)
+                    page=page)
     log(f"LMService: {lm_kw}  params={svc._param_bytes / 1e9:.3f} GB "
         f"float32  paged page={page} slots={slots}")
     prompts = make_prompts(requests, cfg.vocab)
@@ -297,7 +297,8 @@ def stage_serving(lm_kw: dict, requests, gen_req, slots: int, page: int,
         long_ctx = max(len(p) for p in prompts) - 1
         bucket = np.zeros((1, cfg.max_seq), np.int32)
         n_kernels = batcher._prefill.func.lower(
-            *batcher._prefill.args, bucket).as_text().count(
+            *batcher._prefill.args, bucket,
+            np.int32(long_ctx)).as_text().count(
                 "tpu_custom_call")
         log(f"prefill bucket {cfg.max_seq} (served the {long_ctx}-token "
             f"context) holds {n_kernels} compiled Mosaic kernel calls")
@@ -489,7 +490,7 @@ def stage_mesh(devices, lm_kw: dict, lm_seq: int) -> None:
                       lm_seq=lm_seq, tp=2)
     # a finding for the replica work (ROADMAP R5), not a check: where
     # does a second service in this process put its weights and cache?
-    svc = LMService(paged=True)
+    svc = LMService()
     batcher = svc.batcher()
     batcher._ensure_engine()
     log("second LMService in this process: weights on devices "

@@ -97,7 +97,7 @@ def test_serving_programs_do_not_embed_weights():
                    remat=False)
     params = init_params(jax.random.PRNGKey(0), cfg)
     nbytes = quantized_nbytes(params)
-    b = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    b = ContinuousBatcher(cfg, params, slots=2, page=16)
     b._ensure_engine()
 
     def size(prog, *args):
@@ -109,7 +109,7 @@ def test_serving_programs_do_not_embed_weights():
         "step": size(b._step, b._cache, b._bt, b._tokens, b._active),
         "chunk": size(b._chunk_j, b._cache, bt_row, i32(0), i32(0), i32(1),
                       jnp.zeros((b._chunk_w,), jnp.int32)),
-        "prefill": size(b._prefill, jnp.zeros((1, 16), jnp.int32)),
+        "prefill": size(b._prefill, jnp.zeros((1, 16), jnp.int32), i32(16)),
     }
     gen = make_scan_generator(cfg, params)
     sizes["scan_generator"] = len(gen.program.lower(

@@ -331,7 +331,7 @@ def test_batcher_slot_reuse_prefix_declines_and_state_counters(
     the prefix cache declining under a counted reason."""
     from brpc_tpu.models.lm_service import ContinuousBatcher
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=1, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=1, page=PAGE,
                             idle_linger_s=0.2)
     p1, p3 = _seq(19, 11), _seq(1, 12)
     want1 = _greedy(cfg, params, p1, 6)
@@ -386,7 +386,7 @@ def test_batcher_sessions_joining_and_ending_mid_batch(model, f32_matmuls):
     read: each session is served what the plain forward decodes."""
     from brpc_tpu.models.lm_service import ContinuousBatcher
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.2)
     asks = [(_seq(n, 30 + i), m) for i, (n, m) in enumerate(
         [(9, 6), (4, 1), (12, 3), (1, 2), (6, 5)])]
@@ -409,7 +409,7 @@ def test_step_that_runs_ahead_leaves_an_ended_sessions_state_alone(
     its own), one step at a time, and NOT those of one step more."""
     from brpc_tpu.models.lm_service import ContinuousBatcher
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.2)
     pa, pb = _seq(10, 41), _seq(6, 42)
     a, b = _join(bat, pa, 4), _join(bat, pb, 14)
@@ -446,7 +446,7 @@ def test_hung_up_sessions_step_in_flight_is_overwritten_by_the_next_join(
     a token of A's."""
     from brpc_tpu.models.lm_service import ContinuousBatcher
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=1, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=1, page=PAGE,
                             idle_linger_s=0.2)
     pa, pb = _seq(13, 51), _seq(9, 52)
     a = _join(bat, pa, 20, hang_up_after=2)
@@ -465,7 +465,7 @@ def test_info_shows_the_schedule_and_the_state_pool(model):
 
     from brpc_tpu.models.lm_service import LMService
     cfg, params = model
-    svc = LMService(cfg=cfg, params=params, paged=True, page=PAGE,
+    svc = LMService(cfg=cfg, params=params, page=PAGE,
                     decode_slots=2)
     info = json.loads(svc.Info(None, b""))
     assert info["mixers"] == "sass" and info["kv_heads"] == 1
@@ -479,7 +479,7 @@ def test_info_shows_the_schedule_and_the_state_pool(model):
 def _batcher(**kw):
     from brpc_tpu.models.lm_service import ContinuousBatcher
     cfg = _cfg()
-    return ContinuousBatcher(cfg, {}, **{"paged": True, "page": PAGE, **kw})
+    return ContinuousBatcher(cfg, {}, **{"page": PAGE, **kw})
 
 
 def _generate_declines():
@@ -487,7 +487,7 @@ def _generate_declines():
     from brpc_tpu.models.lm_service import LMService, pack_generate_request
     cfg = _cfg()
     svc = LMService(cfg=cfg, params=T.init_params(jax.random.PRNGKey(1),
-                                                  cfg), paged=True)
+                                                  cfg))
     cntl = Controller()
     assert svc.Generate(cntl, pack_generate_request(
         np.zeros((1, 4), np.int32), 2)) is None
@@ -498,7 +498,6 @@ def _generate_declines():
 DECLINES = {
     "training": lambda: T.make_forward(_cfg()),
     "contiguous_decode": lambda: T.make_decode(_cfg()),
-    "contiguous_batch": lambda: T.make_batch_decode(_cfg()),
     "spec_verify": lambda: T.make_paged_spec_verify(_cfg(), PAGE, 3),
     "kv_export_specs": lambda: T.kv_page_specs(_cfg()),
     "kv_export": lambda: T.export_decode_cache(_cfg(), {}),
@@ -507,7 +506,6 @@ DECLINES = {
     "host_spill": lambda: T.make_paged_io(_cfg(), PAGE)[0]({}, None),
     "host_resume": lambda: T.make_paged_io(_cfg(), PAGE)[1]({}, None, None),
     "catch_up": lambda: T.make_paged_io(_cfg(), PAGE, chunk=8)[3](),
-    "batcher_contiguous": lambda: _batcher(paged=False),
     "batcher_spec": lambda: _batcher(spec_decode_k=2, draft_params={}),
     "batcher_park": lambda: _batcher(host_slots=4),
     "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
@@ -537,7 +535,7 @@ def test_default_config_is_the_first_block_and_serves_as_before():
     assert sorted(params["blk0"]) == ["ln1", "ln2", "w1", "w2", "wo", "wqkv"]
     prompt = _seq(21, 5) % 64
     want = np.asarray(T.generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             idle_linger_s=0.2)
     assert _serve(bat, prompt, 6) == want.tolist()
     assert bat.kv_stats()["state"]["bytes"] == 0      # no state layer

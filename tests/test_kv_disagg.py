@@ -877,7 +877,7 @@ def test_paged_decode_identity_and_prefix_hit_skips_prefill():
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(3), (17,),
                                            0, cfg.vocab, jnp.int32))
     want = np.asarray(generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=4, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=4, page=16)
     st1 = _paged_run(bat, prompt, 6)
     assert st1.tokens == want.tolist()
     assert st1.close_reason == "finished"
@@ -914,7 +914,7 @@ def test_prefix_hit_partial_page_teacher_forced_identity():
     pa = np.concatenate([base, np.asarray([3, 9], np.int32)])
     pb = np.concatenate([base, np.asarray([7, 1, 4, 2, 8], np.int32)])
     want_b = np.asarray(generate(params, cfg, pb[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=4, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=4, page=16)
     _paged_run(bat, pa, 4)            # seeds the shared prefix's page
     pf = bat.prefills_run
     st = _paged_run(bat, pb, 6)       # ctx 20: page cached, 4 forced
@@ -946,7 +946,7 @@ def test_prefix_partial_hit_teacher_forced_identity():
     pa = np.concatenate([base, ta])   # ctx 32: two full pages cached
     pb = np.concatenate([base, tb])   # ctx 32: only page 1 matches
     want_b = np.asarray(generate(params, cfg, pb[None, :], 4))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16)
     _paged_run(bat, pa, 4)
     pf = bat.prefills_run
     st = _paged_run(bat, pb, 4)
@@ -971,14 +971,14 @@ def test_two_tier_into_paged_decode_tier_identical(lane):
     cfg, params, prompt = _setup()
     pre_srv, dec_srv, dec_lm, _pre, _dch = _two_tier(
         cfg, params, force_lane=lane,
-        decode_lm_kw={"paged": True, "page": 16})
+        decode_lm_kw={"page": 16})
     try:
         toks, reason, _ = _stream_decode(pre_srv, prompt, 6)
         want = np.asarray(generate(params, cfg, prompt, 6))[0]
         assert toks == want.tolist()
         assert reason == "finished"
         bst = dec_lm.batcher().kv_stats()
-        assert bst["paged"] and bst["steps"] >= 6
+        assert bst["alloc"] and bst["steps"] >= 6
         assert bst["alloc"]["in_use"] == 0   # imported pages settled
         assert outstanding_pages() == 0
     finally:
@@ -1002,7 +1002,7 @@ def test_evict_resume_roundtrip_token_identity():
     want_b = np.asarray(generate(params, cfg, pb[None, :], 6))[0]
     # 2 usable pages (page 0 reserved): A's 2-page session fills the
     # pool; B's 1-page admit must spill A
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             pages=3, host_slots=8, prefix=False)
     sta = _FakeStream()
     bat.join(sta, pa, 12)                 # pages_for(7, 12) = 2
@@ -1029,7 +1029,7 @@ def test_pool_exhausted_closes_with_named_reason():
     from brpc_tpu.models.lm_service import ContinuousBatcher
     _reset_kv()
     cfg, params, prompt = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             pages=2, host_slots=0, prefix=False)
     st = _paged_run(bat, prompt[0], 12)   # needs 2 pages, pool has 1
     assert st.close_reason == "kv_pool_exhausted"
@@ -1050,7 +1050,7 @@ def test_host_tier_full_closes_with_named_reason():
                                        0, cfg.vocab, jnp.int32))
     want_a = np.asarray(generate(params, cfg, pa[None, :], 12))[0]
     # host tier holds ONE page; spilling A needs two
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             pages=3, host_slots=1, prefix=False)
     sta = _FakeStream()
     bat.join(sta, pa, 12)
@@ -1101,7 +1101,7 @@ def test_drain_abort_closes_parked_under_named_reason():
     pb = np.asarray(jax.random.randint(jax.random.PRNGKey(17), (8,),
                                        0, cfg.vocab, jnp.int32))
     want_b = np.asarray(generate(params, cfg, pb[None, :], 20))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             pages=3, host_slots=4, prefix=False)
     sta = _FakeStream()
     bat.join(sta, pa, 24)                 # 2 pages
@@ -1198,7 +1198,7 @@ def test_paged_leak_pin_1k_sessions_alias_and_evict():
                                        0, cfg.vocab, jnp.int32))
     want = {0: np.asarray(generate(params, cfg, pa[None, :], 2))[0],
             1: np.asarray(generate(params, cfg, pb[None, :], 2))[0]}
-    bat = ContinuousBatcher(cfg, params, slots=4, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=4, page=16)
     streams = []
     for i in range(1000):
         st = _FakeStream()

@@ -119,28 +119,33 @@ def test_lm_service_generates_over_rpc():
 # ---------------------------------------------------------------------------
 
 def test_batch_decode_matches_solo_decode():
-    """A slot inside the continuous batch produces the same tokens as
-    a solo make_decode run (per-element math is independent)."""
+    """A slot of the paged step produces the same tokens as a solo
+    make_decode run (per-element math is independent)."""
     import functools as ft
 
-    from brpc_tpu.models.transformer_lm import (empty_batch_cache,
-                                                make_batch_decode)
+    from brpc_tpu.models.transformer_lm import (empty_paged_cache,
+                                                make_paged_batch_decode,
+                                                make_paged_io)
 
     cfg, params, prompt = _setup()
-    prefill, step = make_batch_decode(cfg)
-    cache = empty_batch_cache(cfg, 4)
+    page, s = 8, prompt.shape[1]
+    pps = cfg.max_seq // page
+    prefill, step = make_paged_batch_decode(cfg, page)
+    _gather, _scatter, insert = make_paged_io(cfg, page)
+    cache = empty_paged_cache(cfg, 4 * pps + 1, 4, page)
     # insert session 0 (prompt row 0) into slot 2, nothing else active
-    c1, logits = jax.jit(ft.partial(prefill, params))(prompt[:1])
-    for i in range(cfg.depth):
-        cache[f"k{i}"] = cache[f"k{i}"].at[2].set(c1[f"k{i}"][0])
-        cache[f"v{i}"] = cache[f"v{i}"].at[2].set(c1[f"v{i}"][0])
-    cache["len"] = cache["len"].at[2].set(prompt.shape[1])
+    c1, logits = jax.jit(ft.partial(prefill, params))(prompt[:1],
+                                                      jnp.int32(s))
+    bt = np.zeros((4, pps), np.int32)
+    bt[2] = 1 + np.arange(pps)
+    cache = insert(cache, jnp.asarray(bt[2]), c1, jnp.int32(2))
+    cache["len"] = cache["len"].at[2].set(s)
     active = jnp.zeros((4,), bool).at[2].set(True)
     toks = [int(jnp.argmax(logits[0]))]
     tokens = jnp.zeros((4,), jnp.int32).at[2].set(toks[0])
     step_j = jax.jit(ft.partial(step, params))
     for _ in range(5):
-        cache, lg = step_j(cache, tokens, active)
+        cache, lg = step_j(cache, jnp.asarray(bt), tokens, active)
         t = int(jnp.argmax(lg[2]))
         toks.append(t)
         tokens = tokens.at[2].set(t)
@@ -149,11 +154,11 @@ def test_batch_decode_matches_solo_decode():
 
 
 def test_batch_decode_scan_layers_rejected():
-    from brpc_tpu.models.transformer_lm import make_batch_decode
+    from brpc_tpu.models.transformer_lm import make_paged_batch_decode
     cfg = LMConfig(vocab=64, dim=32, heads=2, depth=2, max_seq=16,
                    scan_layers=True)
     with pytest.raises(NotImplementedError, match="unrolled"):
-        make_batch_decode(cfg)
+        make_paged_batch_decode(cfg, 16)
 
 
 def _decode_server(cfg, params, slots=4):

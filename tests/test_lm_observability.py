@@ -179,7 +179,7 @@ def test_phase_profiler_invariants():
     counts."""
     _reset()
     cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             prefill_chunk_tokens=4)
     st = _join(bat, _prompt(3, 17), 6)
     _finish(st)
@@ -215,7 +215,7 @@ def test_phase_profiler_invariants():
 def test_spec_round_phases_recorded():
     _reset()
     cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             spec_decode_k=3, draft_params=params)
     st = _join(bat, _prompt(4, 8), 6)
     _finish(st)
@@ -290,7 +290,7 @@ def test_phases_partition_the_loop():
     histogram, and ``kv_stats`` carries all three counters."""
     _reset()
     cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16)
     streams = []
     for i in range(8):
         streams.append(_join(bat, _prompt(10 + i, 5 + i), 13 + i % 3))
@@ -349,7 +349,7 @@ def test_phases_reach_the_profiler_under_their_names(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
     monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
     cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             idle_linger_s=0.05)
     st1 = _join(bat, _prompt(3, 9), 5)
     st2 = _join(bat, _prompt(4, 7), 3)
@@ -471,7 +471,7 @@ def test_kill_switch_stops_phases_annotations_and_queue(monkeypatch):
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
     monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
     cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16)
     assert set_flag("lm_telemetry", "false")
     t_off = time.monotonic_ns()
     try:

@@ -23,7 +23,7 @@ from brpc_tpu.models.transformer_lm import LMConfig, generate, init_params
 from brpc_tpu.streaming import StreamOptions
 
 PAGE = 8
-ENGINES = {"paged": dict(paged=True, page=PAGE), "contiguous": {}}
+PAGES = (PAGE, 16)
 
 
 @pytest.fixture(scope="module")
@@ -92,9 +92,9 @@ def _want(model, prompt, max_new):
 
 # -- (a) the tokens of the synchronous order ---------------------------------
 
-@pytest.mark.parametrize("engine", sorted(ENGINES))
+@pytest.mark.parametrize("page", PAGES)
 def test_sessions_joining_and_ending_mid_batch_get_their_own_tokens(
-        model, engine):
+        model, page):
     """Six sessions of different ``max_new`` (1 and 2 among them: a
     session whose first step is its last) over three slots, so that
     sessions end while others decode and the queued ones join into
@@ -102,7 +102,7 @@ def test_sessions_joining_and_ending_mid_batch_get_their_own_tokens(
     served alone."""
     cfg, params = model
     bat = ContinuousBatcher(cfg, params, slots=3, idle_linger_s=0.2,
-                            **ENGINES[engine])
+                            page=page)
     asks = [(_prompt(20 + i, n), m) for i, (n, m) in enumerate(
         [(9, 12), (5, 1), (17, 7), (3, 2), (12, 9), (1, 5)])]
     streams = [_join(bat, p, m) for p, m in asks]
@@ -129,7 +129,7 @@ def test_hung_up_session_leaves_nothing_to_the_slots_next_holder(model):
     prefill cached, and decodes as from a cold cache: the step nobody
     read wrote a row of A's own, not into a page it shared."""
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=1, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=1, page=PAGE,
                             idle_linger_s=0.2)
     pa, pb = _prompt(31, 20), _prompt(32, 11)
     a = _join(bat, pa, 30, hang_up_after=3)
@@ -155,15 +155,15 @@ def test_hung_up_session_leaves_nothing_to_the_slots_next_holder(model):
 
 # -- (d) the counter ----------------------------------------------------------
 
-@pytest.mark.parametrize("engine", sorted(ENGINES))
-def test_uploads_only_where_membership_changes(model, engine):
+@pytest.mark.parametrize("page", PAGES)
+def test_uploads_only_where_membership_changes(model, page):
     """A session decoding alone: between its admission and its last
     step nothing is uploaded, however many steps run; every step but
     the first leaves with the one before it unread."""
     cfg, params = model
     lmt._reset_for_tests()
     bat = ContinuousBatcher(cfg, params, slots=2, idle_linger_s=0.2,
-                            **ENGINES[engine])
+                            page=page)
     seen = []                   # the counter as each token is emitted
 
     class Watching(_Stream):
@@ -180,11 +180,10 @@ def test_uploads_only_where_membership_changes(model, engine):
     look = end["lookahead"]
     assert end["steps"] == 50
     assert (look["sync"], look["ahead"]) == (1, 49)
-    # the admission: token vector, mask and (paged) block table.  Its
-    # last step and its eviction change the mirrors too, and no step
+    # the admission: token vector, mask and block table.  Its last
+    # step and its eviction change the mirrors too, and no step
     # followed that would have needed them on the device
-    paged = engine == "paged"
-    assert look["uploads"] == 2 + paged
+    assert look["uploads"] == 3
     # a second session into the same slot: its first token is poked
     # into the vector the device holds, its pages go up with the block
     # table, and the mask on the device is already the one it needs
@@ -192,14 +191,14 @@ def test_uploads_only_where_membership_changes(model, engine):
     _finish(st2)
     _quiet(bat)
     look2 = bat.kv_stats()["lookahead"]
-    assert look2["uploads"] - look["uploads"] == 1 + paged
+    assert look2["uploads"] - look["uploads"] == 2
     assert look2["ahead"] + look2["sync"] == bat.steps_run() == 70
     assert look2["sync"] == 2
 
 
 def test_speculative_rounds_never_run_ahead(model):
     cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             spec_decode_k=3, draft_params=params,
                             idle_linger_s=0.2)
     p = _prompt(51, 8)
@@ -241,7 +240,7 @@ def test_a_pass_dispatches_the_next_step_before_it_reads_the_last(
     monkeypatch.setattr(_Recorder, "log", [])
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
     monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.05)
     st = _join(bat, _prompt(61, 6), 4)
     _finish(st)

@@ -226,7 +226,7 @@ def test_kv_stats_counts_the_pages_a_step_attends_over():
     cfg = LMConfig(vocab=64, dim=32, heads=4, depth=2, max_seq=64,
                    remat=False)
     params = init_params(jax.random.PRNGKey(0), cfg)
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=PAGE)
+    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE)
     assert bat.kv_stats()["attn"] == {"pages_read": 0, "pages_table": 0}
     pps = cfg.max_seq // PAGE
     read = table = 0
@@ -239,5 +239,3 @@ def test_kv_stats_counts_the_pages_a_step_attends_over():
         assert bat.kv_stats()["attn"] == {"pages_read": read,
                                           "pages_table": table}
     assert table == bat.steps_run() * pps
-    plain = ContinuousBatcher(cfg, params, slots=2)
-    assert "attn" not in plain.kv_stats()

@@ -4,7 +4,7 @@ preemption, speculative decoding.
 Three planes:
 
 - IDENTITY: every scheduler mode must emit the exact tokens of the
-  monolithic greedy path — chunked prefill (contiguous + paged),
+  monolithic greedy path — chunked prefill (slices of 4 and of 16),
   partial prefix-hit catch-up, spec decode on BOTH the rejection and
   the acceptance path, and a batch-tier session across park/resume;
 - POLICY: interactive sessions get chunk budget first, and under pool
@@ -156,41 +156,27 @@ def _prompt(seed, n, vocab=64):
 # chunked prefill: identity + budget priority
 # ---------------------------------------------------------------------------
 
-def test_chunked_prefill_identity_contiguous():
-    """A chunk-filled session (ctx 16 in slices of 4) emits the exact
-    tokens of whole-prompt prefill — the garbage-beyond-mask argument
-    made checkable."""
-    _reset()
-    cfg, params = _setup()
-    prompt = _prompt(3, 17)
-    want = np.asarray(generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2,
-                            prefill_chunk_tokens=4)
-    st = _join(bat, prompt, 6)
-    _finish(st)
-    assert st.tokens == want.tolist()
-    assert st.close_reason == "finished"
-    assert sched_counters()["sched_chunk_slice"] >= 4   # ceil(16/4)
-
-
-def test_chunked_prefill_identity_paged():
-    """Same pin on the paged engine: chunk slices scatter through the
-    block table and the stream is bit-identical with the monolithic
-    path; the chunk-filled context enters the prefix cache exactly
-    like a prefilled one (second session full-hits it)."""
+@pytest.mark.parametrize("chunk", [4, 16])
+def test_chunked_prefill_identity(chunk):
+    """A chunk-filled session (ctx 16 in slices of 4, or in one of 16)
+    emits the exact tokens of whole-prompt prefill — the
+    garbage-beyond-mask argument made checkable: chunk slices scatter
+    through the block table and the stream is bit-identical with the
+    monolithic path; the chunk-filled context enters the prefix cache
+    exactly like a prefilled one (second session full-hits it)."""
     from brpc_tpu.kv import pages as kv_pages
     _reset()
     cfg, params = _setup()
     prompt = _prompt(3, 17)
     want = np.asarray(generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=4, paged=True, page=16,
-                            prefill_chunk_tokens=4)
+    bat = ContinuousBatcher(cfg, params, slots=4,
+                            prefill_chunk_tokens=chunk)
     st = _join(bat, prompt, 6)
     _finish(st)
     assert st.tokens == want.tolist()
     assert st.close_reason == "finished"
     assert bat.prefills_run == 1
-    assert sched_counters()["sched_chunk_slice"] >= 4
+    assert sched_counters()["sched_chunk_slice"] >= 16 // chunk
     st2 = _join(bat, prompt, 6)
     _finish(st2)
     assert st2.tokens == want.tolist()
@@ -236,7 +222,7 @@ def test_partial_prefix_hit_catches_up_via_chunks():
     pa = np.concatenate([base, _prompt(7, 17)])   # two full pages cached
     pb = np.concatenate([base, _prompt(8, 17)])   # only page 1 matches
     want_b = np.asarray(generate(params, cfg, pb[None, :], 4))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16)
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16)
     st_a = _join(bat, pa, 4)
     _finish(st_a)
     pf = bat.prefills_run
@@ -263,7 +249,7 @@ def test_spec_decode_identity_rejection_path():
     draft = init_params(jax.random.PRNGKey(1), cfg)
     prompt = _prompt(4, 8)
     want = np.asarray(generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             spec_decode_k=3, draft_params=draft)
     st = _join(bat, prompt, 6)
     _finish(st)
@@ -285,7 +271,7 @@ def test_spec_decode_acceptance_and_fallback():
     cfg, params = _setup()
     prompt = _prompt(4, 8)
     want = np.asarray(generate(params, cfg, prompt[None, :], 24))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, paged=True, page=16,
+    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
                             spec_decode_k=3, draft_params=params)
     st = _join(bat, prompt, 24)
     _finish(st)
@@ -308,11 +294,19 @@ def test_spec_decode_acceptance_and_fallback():
 
 def test_spec_decode_constructor_contract():
     cfg, params = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(cfg, params, spec_decode_k=3,
-                          draft_params=params)
     with pytest.raises(ValueError, match="draft_params"):
-        ContinuousBatcher(cfg, params, paged=True, spec_decode_k=3)
+        ContinuousBatcher(cfg, params, spec_decode_k=3)
+
+
+@pytest.mark.parametrize("cls", ["ContinuousBatcher", "LMService"])
+def test_paged_false_raises_by_name(cls):
+    """The contiguous slot cache is gone (PR 29): the keyword that
+    once chose it stays only for the benchmark's files, and ``False``
+    is refused, naming the PR."""
+    from brpc_tpu.models import lm_service
+    cfg, params = _setup()
+    with pytest.raises(ValueError, match="paged=False.*PR 29"):
+        getattr(lm_service, cls)(cfg, params, paged=False)
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +336,7 @@ def test_interactive_never_spilled_while_batch_victim_exists(monkeypatch):
     want_alice = np.asarray(generate(params, cfg, prompt[None, :], 8))[0]
     # 10 usable pages of 4: bob (ctx 13 + 16 new -> 8 pages) fits
     # alone; alice (6 pages) only if bob spills
-    bat = ContinuousBatcher(cfg, params, slots=3, paged=True, page=4,
+    bat = ContinuousBatcher(cfg, params, slots=3, page=4,
                             pages=11, host_slots=32, prefix=False,
                             tiers=reg)
     st_bob = _join(bat, prompt, 16, tenant=b"bob")
