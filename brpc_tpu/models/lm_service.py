@@ -318,10 +318,23 @@ class ContinuousBatcher:
     its one step in flight writes a row into pages and a state block it
     still owned when the step was queued, nobody reads the token, and
     the device runs programs in order, so a later join's insert lands
-    after it.  With nothing in flight (the first step after idle, every
-    slot still filling) the step is dispatched alone; a speculative
+    after it.  With nothing in flight (the first step after idle) the
+    step is dispatched alone; a speculative
     round reads its tokens before it ends, so nothing runs ahead there.
     ``kv_stats()["lookahead"]`` counts which way each step left.
+
+    **A catch-up slice rides the step.**  A session that fills its
+    context by slices (a partial prefix hit's remainder; a fresh prompt
+    under ``prefill_chunk_tokens``) holds a slot with ``fill <
+    ctx_len``.  A pass that sees one puts its next slice ON BOARD the
+    step it dispatches (``make_paged_batch_decode``'s riding step: the
+    decode rows and the slice's rows cross every weight together, the
+    weights are read once), one slice a step; the slice that completes
+    the context activates the session first, so its first token comes
+    out of the same step.  A slice that cannot ride is a program of
+    its own before the step, as all were: a speculative round's, and
+    what a ``prefill_chunk_tokens`` budget allows a round beyond one.
+    ``kv_stats()["lookahead"]`` counts ``slices`` and ``slices_rode``.
 
     **Paged KV** (the kv/pages allocator): one shared page pool per
     layer plus a per-slot block table, so a session pins only
@@ -363,7 +376,8 @@ class ContinuousBatcher:
 
     - **chunked prefill** (``prefill_chunk_tokens``, Sarathi-style):
       each loop round runs ONE decode step plus a bounded budget of
-      prefill slices, so a long prompt never head-of-line-blocks live
+      prefill slices (the last of them on board the step), so a long
+      prompt never head-of-line-blocks live
       sessions' next token.  A joining session occupies its slot
       immediately but stays INACTIVE (``fill < ctx_len``) while chunk
       rounds scatter its context; its first generated token is
@@ -445,6 +459,7 @@ class ContinuousBatcher:
         # would stall every connection the loop owns
         self._prefill = None
         self._step = None
+        self._step_riding = None
         self._insert = None
         self._cache = None
         # the step's inputs: host mirrors here, what the device holds
@@ -462,6 +477,8 @@ class ContinuousBatcher:
         self._ahead = 0           # steps dispatched with one in flight
         self._sync = 0            # ... with nothing in flight
         self._uploads = 0         # of block table, mask, token entries
+        self._slices = 0          # chunk slices run ...
+        self._slices_rode = 0     # ... of which on board a step
         self._sessions = {}                       # slot -> _Session
         self._pending: deque = deque()
         self._lock = threading.Lock()
@@ -582,7 +599,9 @@ class ContinuousBatcher:
                "loop_ns": _lmt.loop_ns(),
                "queue": _lmt.queue_counters(),
                "lookahead": {"ahead": self._ahead, "sync": self._sync,
-                             "uploads": self._uploads},
+                             "uploads": self._uploads,
+                             "slices": self._slices,
+                             "slices_rode": self._slices_rode},
                "attn": {"pages_read": self._attn_pages_read,
                         "pages_table": self._attn_pages_table},
                # a slot's block of the state pool (no bytes where the
@@ -632,12 +651,17 @@ class ContinuousBatcher:
                 threading.Thread(target=paged_attention.import_pallas,
                                  name="lm-pallas-import",
                                  daemon=True).start()
-            prefill, step = make_paged_batch_decode(self.cfg, self.page)
+            prefill, step, riding = make_paged_batch_decode(
+                self.cfg, self.page, chunk=self._chunk_w)
             # weights are ARGUMENTS of every program, bound outside
             # the jit (jit_with_params) — never closure constants
             self._prefill = jit_with_params(prefill, self.params)
             self._step = jit_with_params(step, self.params,
                                          donate_argnums=(0,))
+            # the step with a slice on board: traced at its first
+            # call, so never where no session fills by slices
+            self._step_riding = jit_with_params(riding, self.params,
+                                                donate_argnums=(0,))
             # (for a block beyond the first the spill, resume and
             # catch-up programs decline when traced: __init__ refused
             # what enters them)
@@ -1150,7 +1174,7 @@ class ContinuousBatcher:
         if self.spec_k > 0:
             self._draft_admit(sess)
 
-    def _chunk_round(self) -> None:
+    def _chunk_round(self):
         """Spend this round's chunk budget: bounded prefill slices
         over the chunk-filling sessions, INTERACTIVE tier first — the
         Sarathi-style half of the step loop (each round = one decode
@@ -1160,11 +1184,20 @@ class ContinuousBatcher:
         garbage-beyond-mask argument: a filling slot's rows beyond
         ``fill`` are junk, but the attention mask admits a row only
         once ``len`` passes it, and every admissible row has been
-        rewritten by a slice first."""
+        rewritten by a slice first.
+
+        The round's LAST slice is not run here: it is returned, as
+        ``_dispatch``'s ``ride``, and goes on board the round's step
+        (one pass over the weights for both); a session it completes
+        is activated here all the same, so that its first token comes
+        out of that step.  With no budget set (partial prefix hits
+        only) that is the round's one slice.  A speculating batcher
+        reads every round before the next, so nothing rides there:
+        every slice runs here, as a program of its own."""
         filling = [s for s in self._sessions.values()
                    if s.fill < s.ctx_len]
         if not filling:
-            return
+            return None
         import jax.numpy as jnp
         ph = self._clock.switch
         filling.sort(key=lambda s: (s.tier_rank, s.slot))
@@ -1172,35 +1205,49 @@ class ContinuousBatcher:
                 and any(s.tier_rank > filling[0].tier_rank
                         for s in filling):
             count_sched("sched_interactive_first")
+        rides = self.spec_k == 0
         budget = self.chunk_budget if self.chunk_budget else (1 << 30)
+        most = 1 if rides and not self.chunk_budget else (1 << 30)
+        plan = []                       # (session, rows), in order
         for sess in filling:
-            if budget <= 0:
+            if budget <= 0 or len(plan) >= most:
                 break
             if sess.stream.closed:
                 ph(PH_EVICT)
                 self._evict(sess, None)
                 ph(PH_SCHED)
                 continue
-            catchup = sess.n_alias > 0
-            while budget > 0 and sess.fill < sess.ctx_len:
-                ph(PH_CATCHUP_SLICE if catchup else PH_CHUNK_SLICE)
-                n = int(min(self._chunk_w, sess.ctx_len - sess.fill,
-                            budget))
-                ids = np.zeros((self._chunk_w,), np.int32)
-                ids[:n] = sess.prompt[sess.fill:sess.fill + n]
-                self._cache = self._chunk_j(
-                    self._cache, jnp.asarray(self._bt[sess.slot]),
-                    jnp.int32(sess.slot), jnp.int32(sess.fill),
-                    jnp.int32(n), jnp.asarray(ids))
-                sess.fill += n
+            fill = sess.fill
+            while budget > 0 and fill < sess.ctx_len \
+                    and len(plan) < most:
+                n = int(min(self._chunk_w, sess.ctx_len - fill, budget))
+                plan.append((sess, n))
+                fill += n
                 budget -= n
-                count_sched("sched_catchup_slice" if catchup
-                            else "sched_chunk_slice")
-                if sess.span is not None:
-                    sess.span.annotate("lm_chunk_slice")
-                ph(PH_SCHED)
+        ride = None
+        for i, (sess, n) in enumerate(plan):
+            catchup = sess.n_alias > 0
+            ph(PH_CATCHUP_SLICE if catchup else PH_CHUNK_SLICE)
+            ids = np.zeros((self._chunk_w,), np.int32)
+            ids[:n] = sess.prompt[sess.fill:sess.fill + n]
+            span = (np.int32(sess.slot), np.int32(sess.fill), np.int32(n),
+                    ids)
+            if rides and i == len(plan) - 1:
+                ride = span
+                self._slices_rode += 1
+            else:
+                self._cache = self._chunk_j(
+                    self._cache, jnp.asarray(self._bt[sess.slot]), *span)
+            self._slices += 1
+            sess.fill += n
+            count_sched("sched_catchup_slice" if catchup
+                        else "sched_chunk_slice")
+            if sess.span is not None:
+                sess.span.annotate("lm_chunk_slice")
+            ph(PH_SCHED)
             if sess.fill >= sess.ctx_len:
                 self._activate(sess)
+        return ride
 
     def _spec_ok(self) -> bool:
         """Spec rounds need width = k+1 rows of headroom in EVERY
@@ -1255,15 +1302,21 @@ class ContinuousBatcher:
             self._uploads += 1
         return self._bt_d, self._tokens_d, self._active_d
 
-    def _dispatch(self, ahead: bool) -> _Flight:
+    def _dispatch(self, ahead: bool, ride=None) -> _Flight:
         """Queue one plain decode step over the active slots and the
         copy of its tokens to the host; nothing here waits for the
-        device.  ``ahead``: the step before it has not been read."""
+        device.  ``ahead``: the step before it has not been read.
+        ``ride``: the slice that goes on board (``_chunk_round``'s),
+        ``(slot, start, n, ids)``."""
         import jax.numpy as jnp
         ph = self._clock.switch
         ph(PH_STEP_DISPATCH)
-        self._cache, logits = self._step(self._cache,
-                                         *self._step_inputs())
+        if ride is None:
+            self._cache, logits = self._step(self._cache,
+                                             *self._step_inputs())
+        else:
+            self._cache, logits = self._step_riding(
+                self._cache, *self._step_inputs(), *ride)
         # greedy, a program of its own: its result feeds the next step
         # as it lies, and starts on its way to the host for the walk
         self._tokens_d = toks = jnp.argmax(logits, axis=-1)
@@ -1498,8 +1551,7 @@ class ContinuousBatcher:
                     continue
                 if pending or self._sessions:
                     # a pass with sessions to serve: it dispatches a
-                    # step unless every admission is refused, every
-                    # slot is still filling (under a chunk budget) or
+                    # step unless every admission is refused or
                     # every session's last step is already in flight
                     clock.round_begin(self._steps)
                 # queue wait ends here, before the admission's work
@@ -1518,8 +1570,9 @@ class ContinuousBatcher:
                     ph(PH_SCHED)
                 # the Sarathi half BEFORE the decode round: a fill
                 # completed this round teacher-forces its first token
-                # on THIS round's step
-                self._chunk_round()
+                # on THIS round's step, which also carries the
+                # round's last slice
+                ride = self._chunk_round()
                 if not self._sessions and self._flight is None:
                     if self._parked:
                         # only parked sessions left and none could
@@ -1533,12 +1586,14 @@ class ContinuousBatcher:
                 # the device goes from one to the other while the host
                 # walks, emits and evicts
                 landing, self._flight = self._flight, None
-                if not self._active.any():
-                    # every occupied slot is still filling, or has its
-                    # last step in flight
+                if ride is None and not self._active.any():
+                    # every occupied slot has its last step in flight
+                    # (or, speculating, is still filling)
                     pass
                 elif self.spec_k == 0:
-                    self._flight = self._dispatch(landing is not None)
+                    # with nothing active too: the slice is work
+                    self._flight = self._dispatch(landing is not None,
+                                                  ride)
                 elif self._spec_ok():
                     self._deliver(*self._spec_round())
                 else:

@@ -673,7 +673,8 @@ def decode_cache_from_pages(cfg: LMConfig, arrays):
     return cache
 
 
-def make_paged_batch_decode(cfg: LMConfig, page: int):
+def make_paged_batch_decode(cfg: LMConfig, page: int,
+                            chunk: Optional[int] = None):
     """Block-paged continuous batching: one compiled step over a FIXED
     pool of session slots, each at its OWN position — the serving shape
     where new sessions join the live batch between steps and finished
@@ -717,7 +718,21 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
     state layer has ``sh<i>``/``sc<i>``, one block of recurrent state
     for each SLOT, which the step moves one position where the slot is
-    ``active``.  Unrolled layers only."""
+    ``active``.  Unrolled layers only.
+
+    With ``chunk`` set a THIRD program rides along, also named
+    ``step``: the step with one catch-up slice on board
+    (Sarathi-style, one pass over the weights for both), ``step(params,
+    cache, bt, token[b], active[b], slot, start, n, ids[chunk]) ->
+    (cache, logits[b])``.  The span is :func:`make_paged_io`'s
+    ``chunk_prefill``'s (``n`` context tokens of ``slot`` at
+    ``start..start+n-1``, padding rows to page 0, the slot's len set to
+    ``start + n``) and the rest the step's; in every layer the ``b``
+    decode rows and the ``chunk`` span rows share one operand a weight
+    and part only for attention, the span first: a slot whose context
+    the slice completes may be ``active`` in the same call, and its
+    row (the prompt's last token, at ``start + n``) attends over what
+    the span has just written.  The first block only."""
     import jax.numpy as jnp
 
     if cfg.scan_layers:
@@ -733,12 +748,11 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
 
-    def attn_layer(bp, x, pk, pv, bt, pos, att_pos, rot):
-        """One attention layer, one token per slot, block-table
-        addressing."""
-        b = x.shape[0]
-        q, k, v = _qkv(cfg, bp, x, rot)
-
+    def attend(q, k, v, pk, pv, bt, pos, att_pos):
+        """The step's own half of an attention layer, one token per
+        slot (``q``/``k``/``v`` ``(b, 1, heads, hd)``), block-table
+        addressing: write the row, attend over the live pages."""
+        b = q.shape[0]
         # scatter this step's row into each slot's CURRENT page
         page_idx = bt[jnp.arange(b), pos // page]
         row = pos % page
@@ -757,6 +771,12 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
         # ``len`` its last session left behind
         att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
                                         page)
+        return att, pk, pv
+
+    def attn_layer(bp, x, pk, pv, bt, pos, att_pos, rot):
+        """One attention layer, one token per slot."""
+        q, k, v = _qkv(cfg, bp, x, rot)
+        att, pk, pv = attend(q, k, v, pk, pv, bt, pos, att_pos)
         return _attn_out(cfg, bp, x, att), pk, pv
 
     def step(params, cache, bt, token, active):
@@ -784,7 +804,51 @@ def make_paged_batch_decode(cfg: LMConfig, page: int):
                                  cache["len"])
         return cache, _logits(cfg, params, x[:, 0])
 
-    return make_prefill(cfg), step
+    if chunk is None:
+        return make_prefill(cfg), step
+    return make_prefill(cfg), step, _riding_step(cfg, page, int(chunk),
+                                                 attend)
+
+
+def _riding_step(cfg: LMConfig, page: int, cw: int, attend):
+    """:func:`make_paged_batch_decode`'s step with a slice of ``cw``
+    rows on board; ``attend`` is its step's attention half."""
+    import jax.numpy as jnp
+
+    if not cfg.plain_block():
+        def declined(*_a, **_k):
+            require_plain_block(cfg, "make_paged_batch_decode's riding "
+                                "step (catch-up)")
+        return declined
+
+    # under the plain step's name: a device trace books both under
+    # ``jit_step``, and both produce the round's tokens
+    def step(params, cache, bt, token, active, slot, start, n, ids):
+        cache = dict(cache)
+        b = token.shape[0]
+        bt_row = bt[slot]
+        page_idx, row, pos_s = _slice_rows(cfg, page, bt_row, start, n, cw)
+        # the slot's len BEFORE the decode rows read it
+        cache["len"] = cache["len"].at[slot].set(start + n)
+        pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
+        att_pos = jnp.where(active, pos, 0)
+        x = params["embed"][jnp.concatenate([token, ids])][:, None, :]
+        rot = _rope_at(cfg, jnp.concatenate([pos, pos_s])[:, None])
+        for i in range(cfg.depth):
+            bp, pk, pv = params[f"blk{i}"], cache[f"pk{i}"], cache[f"pv{i}"]
+            q, k, v = _qkv(cfg, bp, x, rot)      # (b + cw, 1, heads, hd)
+            att_s, pk, pv = _span_attend(
+                cfg, q[None, b:, 0], k[None, b:, 0], v[None, b:, 0], pk,
+                pv, bt_row[None], page_idx[None], row[None], pos_s[None])
+            att, pk, pv = attend(q[:b], k[:b], v[:b], pk, pv, bt, pos,
+                                 att_pos)
+            x = _attn_out(cfg, bp, x, jnp.concatenate([att, att_s[0]]))
+            cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+        cache["len"] = jnp.where(active, cache["len"] + 1,
+                                 cache["len"])
+        return cache, _logits(cfg, params, x[:b, 0])
+
+    return step
 
 
 def _paged_pool_shape(cfg: LMConfig, num_pages: int, page: int) -> tuple:
@@ -850,11 +914,20 @@ def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
     gathered back into the contiguous ``max_seq`` view, under the live
     mask ``key <= pos`` — the decode step's own, so a span is
     identical by construction with as many single steps."""
+    q, k, v = _qkv(cfg, bp, x, _rope_at(cfg, pos))
+    att, pk, pv = _span_attend(cfg, q, k, v, pk, pv, bt, page_idx, row,
+                               pos)
+    return _attn_out(cfg, bp, x, att), pk, pv
+
+
+def _span_attend(cfg: LMConfig, q, k, v, pk, pv, bt, page_idx, row, pos):
+    """A span's own half of an attention layer
+    (:func:`_paged_span_layer`; ``q``/``k``/``v`` ``(b, w, heads,
+    hd)``, rotated): write the rows, attend through the block table."""
     import jax
     import jax.numpy as jnp
 
-    b = x.shape[0]
-    q, k, v = _qkv(cfg, bp, x, _rope_at(cfg, pos))
+    b = q.shape[0]
     pk = pk.at[page_idx, row].set(k)
     pv = pv.at[page_idx, row].set(v)
     kc = pk[bt].reshape(b, cfg.max_seq, cfg.heads, cfg.head_dim)
@@ -867,7 +940,19 @@ def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
     p = jax.nn.softmax(s_mat, axis=-1)
     att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
                      preferred_element_type=jnp.float32)
-    return _attn_out(cfg, bp, x, att), pk, pv
+    return att, pk, pv
+
+
+def _slice_rows(cfg: LMConfig, page: int, bt_row, start, n, width: int):
+    """Where a chunk slice of ``width`` rows, ``n`` of them real,
+    writes a slot's positions ``start..start+n-1``: ``(page_idx, row,
+    pos)``, each ``(width,)``.  Padding rows go to the reserved
+    garbage page 0."""
+    import jax.numpy as jnp
+    j = jnp.arange(width)
+    posc = jnp.minimum(start + j, cfg.max_seq - 1)
+    page_idx = jnp.where(j < n, bt_row[posc // page], 0)
+    return page_idx, posc % page, start + j
 
 
 def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
@@ -965,18 +1050,13 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
 
     def chunk_prefill(params, cache, bt_row, slot, start, n, ids):
         cache = dict(cache)
-        j = jnp.arange(cw)
-        valid = j < n
-        posc = jnp.minimum(start + j, cfg.max_seq - 1)
-        # padding entries write the reserved garbage page 0
-        page_idx = jnp.where(valid, bt_row[posc // page], 0)
-        row = posc % page
+        page_idx, row, pos = _slice_rows(cfg, page, bt_row, start, n, cw)
         x = params["embed"][ids][None]            # (1, chunk, dim)
         for i in range(cfg.depth):
             x, pk, pv = _paged_span_layer(
                 cfg, params[f"blk{i}"], x, cache[f"pk{i}"],
                 cache[f"pv{i}"], bt_row[None], page_idx[None], row[None],
-                (start + j)[None])
+                pos[None])
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = cache["len"].at[slot].set(start + n)
         return cache

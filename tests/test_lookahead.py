@@ -129,6 +129,11 @@ def test_hung_up_session_leaves_nothing_to_the_slots_next_holder(model):
     prefill cached, and decodes as from a cold cache: the step nobody
     read wrote a row of A's own, not into a page it shared."""
     cfg, params = model
+    # the phase table is the process's: level it, whatever ran before
+    _wait(lambda: not any(t.name == "lm-decode-batcher"
+                          for t in threading.enumerate()),
+          "an earlier test's batcher never lingered out", 30.0)
+    lmt._reset_for_tests()
     bat = ContinuousBatcher(cfg, params, slots=1, page=PAGE,
                             idle_linger_s=0.2)
     pa, pb = _prompt(31, 20), _prompt(32, 11)
@@ -226,12 +231,17 @@ class _Recorder:
         pass
 
 
+@pytest.mark.parametrize("riding", [False, True])
 def test_a_pass_dispatches_the_next_step_before_it_reads_the_last(
-        model, monkeypatch):
+        model, monkeypatch, riding):
     """The phases of one session's decode, in the order the loop ran
     them: the first step leaves alone; from then on every
     ``device_wait`` has a ``step_dispatch`` before it in its own pass,
-    until the last step is out and there is nothing left to queue."""
+    until the last step is out and there is nothing left to queue.
+    ``riding``: the session is a partial prefix hit with 70 rows to
+    catch up: two slices, each in the pass of the step it boards (the
+    first of which has no decode row, and is read like any other); the
+    second activates the session and the rest is as before."""
     cfg, params = model
     _wait(lambda: not any(t.name == "lm-decode-batcher"
                           for t in threading.enumerate()),
@@ -240,9 +250,22 @@ def test_a_pass_dispatches_the_next_step_before_it_reads_the_last(
     monkeypatch.setattr(_Recorder, "log", [])
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Recorder)
     monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", _Recorder)
+    if riding:
+        # (a config of its own: 70 rows do not fit the module's 64)
+        cfg = LMConfig(vocab=64, dim=32, heads=4, depth=2, max_seq=128,
+                       remat=False)
+        params = init_params(jax.random.PRNGKey(0), cfg)
     bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.05)
-    st = _join(bat, _prompt(61, 6), 4)
+    prompt = _prompt(61, 6)
+    if riding:
+        base = _prompt(62, 2 * PAGE)
+        _finish(_join(bat, np.concatenate([base, _prompt(63, 5)]), 2))
+        _wait(lambda: bat._thread is None,
+              "the batcher never lingered out")
+        _Recorder.log.clear()
+        prompt = np.concatenate([base, _prompt(64, 70 + 1)])
+    st = _join(bat, prompt, 4)
     _finish(st)
     _wait(lambda: bat._thread is None, "the batcher never lingered out")
     passes, cur = [], None
@@ -251,12 +274,15 @@ def test_a_pass_dispatches_the_next_step_before_it_reads_the_last(
             cur = []
             passes.append(cur)
         elif cur is not None and name in (
-                "lm/step_dispatch", "lm/device_wait", "lm/token_walk",
-                "lm/stream_emit", "lm/evict"):
+                "lm/catchup_slice", "lm/step_dispatch", "lm/device_wait",
+                "lm/token_walk", "lm/stream_emit", "lm/evict"):
             cur.append(name[3:])
     land = ["device_wait", "token_walk", "stream_emit"]
-    assert passes == [["step_dispatch"],
-                      ["step_dispatch"] + land,
-                      ["step_dispatch"] + land,
-                      ["step_dispatch"] + land,
-                      land + ["evict"]]
+    first = [["catchup_slice", "step_dispatch"],
+             ["catchup_slice", "step_dispatch"] + land] if riding \
+        else [["step_dispatch"]]
+    assert passes == first + [["step_dispatch"] + land,
+                              ["step_dispatch"] + land,
+                              ["step_dispatch"] + land,
+                              land + ["evict"]]
+    assert bat.kv_stats()["lookahead"]["slices_rode"] == 2 * riding
