@@ -36,7 +36,8 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_STEP_DISPATCH, PH_STREAM_EMIT,
                            PH_TOKEN_WALK)
 from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
-                             require_plain_block, state_slot_bytes)
+                             latent_row_bytes, require_plain_block,
+                             state_slot_bytes)
 
 
 def pack_generate_request(prompt: np.ndarray, max_new: int) -> bytes:
@@ -284,6 +285,8 @@ class _Flight(NamedTuple):
 
     toks: object            # (slots,) int32, on the device
     snap: list              # [(slot, _Session)] of its active slots
+    counts: object = None   # the expert layers' routing counts, (3,)
+    #                         int32 on the device (None: no such layer)
 
 
 class ContinuousBatcher:
@@ -448,9 +451,9 @@ class ContinuousBatcher:
                 if on:
                     raise UnsupportedBlock(
                         f"{what} runs the program's first block only: "
-                        "a layer schedule with state layers, grouped "
-                        "heads or a gated FFN serves through the plain "
-                        "paged engine")
+                        "a layer schedule with state layers, latent "
+                        "attention, experts, grouped heads or a gated "
+                        "FFN serves through the plain paged engine")
         self.tiers = tiers
         # the HEAVY half (jit wrappers + the device KV-pool allocation)
         # is deferred to the batcher thread's first iteration: the
@@ -495,6 +498,12 @@ class ContinuousBatcher:
         self._state_releases = 0
         self._state_held_steps = 0
         self._state_slot_steps = 0
+        # the expert layers' routing, as the steps' own counts say
+        # (read with each step's tokens): steps and rows stepped,
+        # (token, expert) pairs on a held expert, held experts touched
+        # (both summed over layers and steps), the most rows one took
+        self._moe = {"steps": 0, "rows": 0, "local_pairs": 0,
+                     "experts_touched": 0, "max_load": 0}
         # the allocator triple (built in _ensure_engine)
         self._alloc = None                        # kv.pages.PageAllocator
         self._prefix = None                       # kv.pages.PrefixCache
@@ -613,6 +622,18 @@ class ContinuousBatcher:
                          "bytes": self.slots * state_slot_bytes(self.cfg),
                          "inserts": self._state_inserts,
                          "releases": self._state_releases}}
+        cfg = self.cfg
+        if cfg.has_experts:
+            lo, hi = cfg.experts_held
+            out["moe"] = {"layers": len(cfg.expert_layers()),
+                          "held": hi - lo, "routed": cfg.experts_routed,
+                          "top_k": cfg.experts_top_k, **self._moe}
+        if cfg.has_latent:
+            # one row a token and layer, key and value at once
+            out["latent"] = {"row_bytes": cfg.latent_row() * 4,
+                             "layers": len(cfg.mla_layers()),
+                             "pool_bytes": self.num_pages * self.page
+                             * latent_row_bytes(cfg)}
         if self._alloc is not None:
             out["alloc"] = self._alloc.stats()
         if self._prefix is not None:
@@ -1311,12 +1332,18 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         ph = self._clock.switch
         ph(PH_STEP_DISPATCH)
-        if ride is None:
-            self._cache, logits = self._step(self._cache,
-                                             *self._step_inputs())
-        else:
+        counts = None
+        if ride is not None:
             self._cache, logits = self._step_riding(
                 self._cache, *self._step_inputs(), *ride)
+        elif self.cfg.has_experts:
+            # the routing counts leave the device beside the tokens
+            self._cache, logits, counts = self._step(
+                self._cache, *self._step_inputs())
+            counts.copy_to_host_async()
+        else:
+            self._cache, logits = self._step(self._cache,
+                                             *self._step_inputs())
         # greedy, a program of its own: its result feeds the next step
         # as it lies, and starts on its way to the host for the walk
         self._tokens_d = toks = jnp.argmax(logits, axis=-1)
@@ -1339,7 +1366,7 @@ class ContinuousBatcher:
                 # state layer's block must not move a position past
                 # the session's end), whenever this one is read
                 self._active[slot] = False
-        return _Flight(toks, snap)
+        return _Flight(toks, snap, counts)
 
     def _land(self, flight: _Flight) -> int:
         """Block on a dispatched step's tokens, walk, emit, evict.
@@ -1349,6 +1376,16 @@ class ContinuousBatcher:
         # the round's one sync, in a phase of its own: one sample a step
         outer = ph(PH_DEVICE_WAIT)
         toks = self._read_tokens(flight.toks)
+        if flight.counts is not None:
+            # the same program made them: no second wait
+            pairs_, touched, load = (int(c) for c in
+                                     np.asarray(flight.counts))
+            moe = self._moe
+            moe["steps"] += 1
+            moe["rows"] += len(flight.snap)
+            moe["local_pairs"] += pairs_
+            moe["experts_touched"] += touched
+            moe["max_load"] = max(moe["max_load"], load)
         ph(PH_TOKEN_WALK)
         pairs, finished = [], []
         last, pages_read = self.cfg.max_seq - 1, 0
@@ -1816,6 +1853,11 @@ class LMService(Service):
             fp += (f":{c.kv_heads}:{int(c.rope)}:{c.ffn}:{c.ffn_dim}:"
                    f"{c.schedule()}:"
                    f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
+        if c.has_latent or c.has_experts:
+            fp += (f":{c.q_lora_rank}x{c.kv_lora_rank}x{c.qk_nope_dim}x"
+                   f"{c.qk_rope_dim}x{c.v_head_dim}:{c.ffn_schedule()}:"
+                   f"{c.expert_dim}x{c.experts_routed}x{c.experts_top_k}:"
+                   f"{c.experts_held[0]}-{c.experts_held[1]}")
         return fp.encode()
 
     def Decode(self, cntl, request):
@@ -1875,4 +1917,18 @@ class LMService(Service):
                 state_pool={"slots": self.decode_slots,
                             "bytes": self.decode_slots
                             * state_slot_bytes(c)})
+        if c.has_latent:
+            # one pool a latent layer, a row a token: key and value
+            info["latent_pool"] = {
+                "layers": len(c.mla_layers()), "row": c.latent_row(),
+                "row_bytes": c.latent_row() * 4,
+                "token_bytes": latent_row_bytes(c)}
+        if c.has_experts:
+            info.update(
+                ffns=c.ffn_schedule(),
+                experts={"routed": c.experts_routed,
+                         "held": list(c.experts_held),
+                         "top_k": c.experts_top_k, "dim": c.expert_dim,
+                         "shared": c.shared_experts,
+                         "route_scale": c.route_scale})
         return json.dumps(info).encode()

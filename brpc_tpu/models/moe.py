@@ -166,6 +166,139 @@ def forward_grouped(params: Dict[str, Any], x, cfg: MoEConfig
     return out, aux.mean()
 
 
+# -- serving: routed experts without drops, beside shared ones -------------
+#
+# The expert layer of a served schedule (``LMConfig.ffns``
+# ``"experts"``), as DeepSeek-V3's modelling code has it: sigmoid
+# scores, a correction bias that CHOOSES and does not weigh, the chosen
+# weights renormalised and scaled, every expert a gated MLP, shared
+# experts on every token.  Nothing is dropped: the rows routed to the
+# experts held here are sorted by expert into a buffer of the worst
+# case and run as a grouped matrix product whose cost follows the rows
+# present.  The layer is TOLD which experts it holds: it routes over
+# all of them, normalises over all the chosen ones, and adds only its
+# own experts' part; what the others would add is another chip's.
+# (``forward`` above, the capacity-factor layer that drops, is
+# training's.)
+
+
+class ExpertConfig:
+    def __init__(self, dim: int, hidden: int, routed: int,
+                 held: Tuple[int, int], top_k: int,
+                 route_scale: float = 1.0, shared: int = 0):
+        assert 0 <= held[0] < held[1] <= routed and 1 <= top_k <= routed
+        self.dim, self.hidden, self.routed = dim, hidden, routed
+        self.held = (int(held[0]), int(held[1]))
+        self.n_held = self.held[1] - self.held[0]
+        self.top_k = top_k
+        self.route_scale = route_scale
+        self.shared = shared
+
+    def buffer_rows(self, rows: int) -> int:
+        """The sorted buffer's rows for ``rows`` tokens: a token's
+        chosen experts are distinct, so at most ``min(top_k, held)`` of
+        them are held here."""
+        return rows * min(self.top_k, self.n_held)
+
+
+def init_served(rng, cfg: ExpertConfig) -> Dict[str, Any]:
+    """Seeded weights of one served expert layer: the router over ALL
+    routed experts and its correction bias, the HELD experts' gated
+    MLPs stacked (``w1`` holds gate and up side by side), the shared
+    experts as one gated MLP of ``shared * hidden``."""
+    import jax
+    import jax.numpy as jnp
+
+    d, e, n = cfg.dim, cfg.hidden, cfg.n_held
+    ks = jax.random.split(rng, 6)
+
+    def normal(k, shape, fan_in):
+        return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
+
+    p = {"router": normal(ks[0], (d, cfg.routed), d),
+         "bias": jax.random.normal(ks[1], (cfg.routed,), jnp.float32) * 0.1,
+         "w1": normal(ks[2], (n, d, 2 * e), d),
+         "w2": normal(ks[3], (n, e, d), e)}
+    if cfg.shared:
+        p["ws1"] = normal(ks[4], (d, 2 * cfg.shared * e), d)
+        p["ws2"] = normal(ks[5], (cfg.shared * e, d), cfg.shared * e)
+    return p
+
+
+def route(p: Dict[str, Any], t, cfg: ExpertConfig) -> Tuple[Any, Any]:
+    """``t (T, dim)`` float32 -> ``(ids (T, k), w (T, k))``: the top-k
+    of ``sigmoid(t W_r) + bias`` over ALL routed experts, weighed by
+    the scores WITHOUT the bias, renormalised over the chosen and
+    scaled.  Float32 at ``highest``: a flipped choice changes a token's
+    output by a whole expert."""
+    import jax
+    import jax.numpy as jnp
+
+    sc = jax.nn.sigmoid(jnp.dot(
+        t.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _top, ids = jax.lax.top_k(sc + p["bias"], cfg.top_k)
+    w = jnp.take_along_axis(sc, ids, axis=-1)
+    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20) * cfg.route_scale
+    return ids, w
+
+
+def _gated(x, w1, w2):
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops.quant import mxu_matmul
+
+    gate, up = jnp.split(mxu_matmul(x, w1), 2, axis=-1)
+    return mxu_matmul(jax.nn.silu(gate) * up, w2)
+
+
+def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
+          ) -> Tuple[Any, Any]:
+    """The expert layer: ``t (T, dim)`` float32, normed -> ``(out (T,
+    dim), counts (3,) int32)``: ``sum_k w_k E_k(t)`` over the chosen
+    experts HELD here plus the shared experts.  Rows not ``live`` (a
+    bucket's padding, an idle slot) are routed nowhere, so they cost no
+    expert and count nowhere.  ``counts``: (token, expert) pairs that
+    fell on a held expert, held experts with at least one row, the most
+    rows one expert took."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import quant
+
+    T, k, n = t.shape[0], cfg.top_k, cfg.n_held
+    lo, hi = cfg.held
+    ids, w = route(p, t, cfg)
+    local = (ids >= lo) & (ids < hi)
+    if live is not None:
+        local = local & live[:, None]
+    # sort the (token, choice) pairs by held expert; the others last
+    key = jnp.where(local, ids - lo, n).reshape(T * k)
+    order = jnp.argsort(key, stable=True)[:cfg.buffer_rows(T)]
+    skey = key[order]
+    tok = order // k
+    sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                    dtype=jnp.int32)
+    bf = quant.mxu_operand
+    xs = bf(t)[tok]                                         # (M, dim)
+    gate, up = jnp.split(jax.lax.ragged_dot(
+        xs, bf(p["w1"]), sizes,
+        preferred_element_type=jnp.float32), 2, axis=-1)
+    ys = jax.lax.ragged_dot(
+        bf(jax.nn.silu(gate) * up), bf(p["w2"]), sizes,
+        preferred_element_type=jnp.float32)                 # (M, dim)
+    # rows past the last group were not computed
+    ys = jnp.where((skey < n)[:, None], ys, 0.0) \
+        * w.reshape(T * k)[order][:, None]
+    out = jnp.zeros((T, cfg.dim), jnp.float32).at[tok].add(ys)
+    if cfg.shared:
+        out = out + _gated(t, p["ws1"], p["ws2"])
+    counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()]
+                       ).astype(jnp.int32)
+    return out, counts
+
+
 def make_train_step(cfg: MoEConfig, lr: float = 0.1):
     """(params, x, target) -> (new_params, loss): regression toy task
     exercising routing + expert grads end to end."""

@@ -87,10 +87,11 @@ def _coefficients(cfg, bp, u):
     r, n = cfg.ssm_dt_rank, cfg.ssm_state
     rbc = qmatmul(u, bp["x_proj"])
     dt, b, c = jnp.split(rbc, [r, r + n], axis=-1)
-    dt = qmatmul(_rmsnorm(dt, bp["dt_norm"]), bp["dt_proj"]) \
+    eps = cfg.norm_eps
+    dt = qmatmul(_rmsnorm(dt, bp["dt_norm"], eps), bp["dt_proj"]) \
         + bp["dt_bias"]
-    return (jax.nn.softplus(dt), _rmsnorm(b, bp["b_norm"]),
-            _rmsnorm(c, bp["c_norm"]))
+    return (jax.nn.softplus(dt), _rmsnorm(b, bp["b_norm"], eps),
+            _rmsnorm(c, bp["c_norm"], eps))
 
 
 def _grouped(cfg, x):

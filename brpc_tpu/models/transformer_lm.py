@@ -41,7 +41,19 @@ class LMConfig:
                  tie_embed: bool = False, final_norm: bool = False,
                  mixers: Optional[Tuple[str, ...]] = None,
                  ssm_expand: int = 2, ssm_state: int = 16,
-                 ssm_conv: int = 4, ssm_dt_rank: Optional[int] = None):
+                 ssm_conv: int = 4, ssm_dt_rank: Optional[int] = None,
+                 norm_eps: float = 1e-6, rope_theta: float = 10000.0,
+                 rope_yarn: Optional[Dict[str, float]] = None,
+                 q_lora_rank: Optional[int] = None,
+                 kv_lora_rank: Optional[int] = None,
+                 qk_nope_dim: Optional[int] = None,
+                 qk_rope_dim: Optional[int] = None,
+                 v_head_dim: Optional[int] = None,
+                 ffns: Optional[Tuple[str, ...]] = None,
+                 expert_dim: Optional[int] = None, experts_routed: int = 0,
+                 experts_held: Optional[Tuple[int, int]] = None,
+                 experts_top_k: int = 1, route_scale: float = 1.0,
+                 shared_experts: int = 0):
         assert dim % heads == 0
         assert not rope or (dim // heads) % 2 == 0, \
             "head dim must be even for RoPE"
@@ -52,11 +64,13 @@ class LMConfig:
         # that block): key/value heads shared by groups of query heads,
         # attention with no positional term, a gated FFN of its own
         # width, the embedding table as the unembedding, a final norm,
-        # and a per-layer schedule of mixers, "attn" or "ssm" (a
+        # and a per-layer schedule of mixers, "attn", "ssm" (a
         # Mamba-1 state-space layer, models/ssm_mixer.py, whose state
-        # is per SEQUENCE, not per token).  The paged serving factories
-        # run all of it; every other factory runs the first block only
-        # and declines the rest by name (UnsupportedBlock)
+        # is per SEQUENCE, not per token) or "mla" (latent attention,
+        # models/mla_mixer.py, whose cache is ONE latent row a token).
+        # The paged serving factories run all of it; every other
+        # factory runs the first block only and declines the rest by
+        # name (UnsupportedBlock)
         self.kv_heads = heads if kv_heads is None else int(kv_heads)
         assert heads % self.kv_heads == 0
         self.head_dim = dim // heads
@@ -69,8 +83,53 @@ class LMConfig:
         self.mixers = ("attn",) * depth if mixers is None \
             else tuple(mixers)
         assert len(self.mixers) == depth \
-            and set(self.mixers) <= {"attn", "ssm"}
+            and set(self.mixers) <= {"attn", "ssm", "mla"}
         self.has_state = "ssm" in self.mixers
+        self.norm_eps = float(norm_eps)
+        # latent attention: low-rank query and key/value paths, a
+        # rotary part of the head apart from the rest, YaRN-scaled
+        # frequencies (``rope_yarn``: factor, original_max, beta_fast,
+        # beta_slow, mscale, mscale_all_dim) and the softmax scale they
+        # bring.  ``heads`` latent heads need not divide ``dim``
+        self.has_latent = "mla" in self.mixers
+        self.rope_theta = float(rope_theta)
+        self.rope_yarn = dict(rope_yarn) if rope_yarn else None
+        self.q_lora_rank, self.kv_lora_rank = q_lora_rank, kv_lora_rank
+        self.qk_nope_dim, self.qk_rope_dim = qk_nope_dim, qk_rope_dim
+        self.v_head_dim = v_head_dim
+        if self.has_latent:
+            assert all(w and int(w) > 0 for w in (
+                q_lora_rank, kv_lora_rank, qk_nope_dim, qk_rope_dim,
+                v_head_dim)), "an mla mixer needs its five widths"
+            assert qk_rope_dim % 2 == 0
+            # one rotation a program (``_rope_at``): the latent one
+            assert not (self.rope and "attn" in self.mixers), \
+                "rotary 'attn' layers beside 'mla' layers are not served"
+        # a per-layer feed-forward schedule, "dense" (the block's FFN)
+        # or "experts" (models/moe.py ``serve``: routed experts without
+        # drops beside shared ones).  ``experts_held`` is the range of
+        # routed expert ids THIS program holds: it routes over all
+        # ``experts_routed`` and adds its own experts' part
+        self.ffns = ("dense",) * depth if ffns is None else tuple(ffns)
+        assert len(self.ffns) == depth \
+            and set(self.ffns) <= {"dense", "experts"}
+        self.has_experts = "experts" in self.ffns
+        self.expert_dim = expert_dim
+        self.experts_routed = int(experts_routed)
+        self.experts_held = (0, self.experts_routed) \
+            if experts_held is None else tuple(int(e) for e in experts_held)
+        self.experts_top_k = int(experts_top_k)
+        self.route_scale = float(route_scale)
+        self.shared_experts = int(shared_experts)
+        if self.has_experts:
+            lo, hi = self.experts_held
+            assert expert_dim and 0 <= lo < hi <= self.experts_routed \
+                and 1 <= self.experts_top_k <= self.experts_routed
+            if set(m for m, f in zip(self.mixers, self.ffns)
+                   if f == "experts") != {"mla"}:
+                raise UnsupportedBlock(
+                    "an expert feed-forward layer is served beside an "
+                    "'mla' mixer only")
         self.ssm_inner = int(ssm_expand) * dim
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
@@ -104,7 +163,9 @@ class LMConfig:
         """True for the program's first block, the only one the
         training, contiguous, scanned, speculative and export
         factories run."""
-        return (not self.has_state and self.kv_heads == self.heads
+        return (set(self.mixers) == {"attn"} and not self.has_experts
+                and self.norm_eps == 1e-6
+                and self.kv_heads == self.heads
                 and self.rope and self.ffn == "gelu"
                 and self.ffn_dim == self.dim * self.mlp_mult
                 and not self.tie_embed and not self.final_norm)
@@ -113,11 +174,41 @@ class LMConfig:
         """The mixers' initials in layer order (``"sass"``)."""
         return "".join(m[0] for m in self.mixers)
 
+    def ffn_schedule(self) -> str:
+        """The feed-forward parts' initials in layer order
+        (``"dee"``)."""
+        return "".join(f[0] for f in self.ffns)
+
     def attn_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, m in enumerate(self.mixers) if m == "attn")
 
     def ssm_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, m in enumerate(self.mixers) if m == "ssm")
+
+    def mla_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, m in enumerate(self.mixers) if m == "mla")
+
+    def expert_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, f in enumerate(self.ffns) if f == "experts")
+
+    def latent_row(self) -> int:
+        """Values one token keeps in one latent layer: the normed
+        latent and the rotated shared key part."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    def latent_row_padded(self) -> int:
+        """A latent pool's row as it lies: the values, then zeros up to
+        the next multiple of 128 lanes (what the device's tiled layout
+        takes anyway, and what lets a page be one DMA)."""
+        return -(-self.latent_row() // 128) * 128
+
+    def expert_cfg(self):
+        from .moe import ExpertConfig
+        return ExpertConfig(
+            dim=self.dim, hidden=self.expert_dim,
+            routed=self.experts_routed, held=self.experts_held,
+            top_k=self.experts_top_k, route_scale=self.route_scale,
+            shared=self.shared_experts)
 
     def moe_cfg(self):
         from .moe import MoEConfig
@@ -141,6 +232,8 @@ def require_plain_block(cfg: LMConfig, what: str) -> None:
     if cfg.plain_block():
         return
     why = "state layers (per-sequence recurrent state)" if cfg.has_state \
+        else "latent attention (an 'mla' mixer and its latent cache)" \
+        if cfg.has_latent \
         else "a block other than MHA + rotary + GELU MLP + untied table"
     raise UnsupportedBlock(
         f"{what} declines {why}: only the paged serving factories "
@@ -197,7 +290,7 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
-    from . import ssm_mixer
+    from . import mla_mixer, moe, ssm_mixer
 
     if cfg.scan_layers or cfg.moe_experts > 0:
         raise UnsupportedBlock(
@@ -218,14 +311,19 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         bk = jax.random.split(ks[2 + i], 5)
         if cfg.mixers[i] == "ssm":
             blk = ssm_mixer.init_layer(bk[0], cfg)
+        elif cfg.mixers[i] == "mla":
+            blk = mla_mixer.init_layer(bk[0], cfg)
         else:
             blk = {"wqkv": normal(
                 bk[0], (d, (cfg.heads + 2 * cfg.kv_heads) * hd), d),
                 "wo": normal(bk[1], (cfg.heads * hd, d), cfg.heads * hd)}
         blk["ln1"] = jnp.ones((d,), jnp.float32)
         blk["ln2"] = jnp.ones((d,), jnp.float32)
-        blk["w1"] = normal(bk[2], (d, 2 * f if gated else f), d)
-        blk["w2"] = normal(bk[3], (f, d), f)
+        if cfg.ffns[i] == "experts":
+            blk["moe"] = moe.init_served(bk[2], cfg.expert_cfg())
+        else:
+            blk["w1"] = normal(bk[2], (d, 2 * f if gated else f), d)
+            blk["w2"] = normal(bk[3], (f, d), f)
         params[f"blk{i}"] = blk
     return params
 
@@ -250,9 +348,9 @@ def jit_with_params(fn, params, donate_argnums=()):
     return functools.partial(fn_j, params)
 
 
-def _rmsnorm(x, g):
+def _rmsnorm(x, g, eps: float = 1e-6):
     import jax.numpy as jnp
-    return x * g / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
+    return x * g / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
 def _rope_tables(seq: int, head_dim: int):
@@ -312,7 +410,31 @@ def _ffn(cfg: LMConfig, bp, h):
 def _ffn_residual(cfg: LMConfig, bp, x):
     """The second half of a serving layer, after either mixer: norm,
     :func:`_ffn`, residual."""
-    return x + _ffn(cfg, bp, _rmsnorm(x, bp["ln2"]))
+    return x + _ffn(cfg, bp, _rmsnorm(x, bp["ln2"], cfg.norm_eps))
+
+
+def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live):
+    """Layer ``i``'s second half by the feed-forward schedule
+    (``LMConfig.ffns``): :func:`_ffn_residual`, or norm, the expert
+    layer (``moe.serve``) over the rows that are ``live``, residual.
+    ``x`` and ``live`` are ``(b, w, dim)`` and ``(b, w)``.  Returns
+    ``(x, counts)``, ``counts`` the expert layer's or None."""
+    if cfg.ffns[i] != "experts":
+        return _ffn_residual(cfg, bp, x), None
+    from . import moe
+    b, w, d = x.shape
+    out, counts = moe.serve(
+        bp["moe"], _rmsnorm(x, bp["ln2"], cfg.norm_eps).reshape(b * w, d),
+        cfg.expert_cfg(), live.reshape(b * w))
+    return x + out.reshape(b, w, d), counts
+
+
+def _embed_rows(params, ids):
+    """The table's rows as the float32 residual (a table stored in
+    bfloat16 is widened here, once)."""
+    import jax.numpy as jnp
+    x = params["embed"][ids]
+    return x if x.dtype == jnp.float32 else x.astype(jnp.float32)
 
 
 def _rope_at(cfg: LMConfig, pos):
@@ -325,6 +447,10 @@ def _rope_at(cfg: LMConfig, pos):
     the serving rotation's one home.  A program makes them once and
     hands them to every layer, as ``make_forward`` does its tables."""
     import jax.numpy as jnp
+    if cfg.has_latent:
+        # the rotary part of a latent head, at its own frequencies
+        from . import mla_mixer
+        return mla_mixer.rotation(cfg, pos)
     if not cfg.rope:
         return None
     half = cfg.head_dim // 2
@@ -343,7 +469,8 @@ def _qkv(cfg: LMConfig, bp, x, rot):
     reaches the cached ones) is its own."""
     from ..ops.quant import qmatmul
     b, w, _ = x.shape
-    q, k, v = _split_qkv(cfg, qmatmul(_rmsnorm(x, bp["ln1"]), bp["wqkv"]))
+    q, k, v = _split_qkv(cfg, qmatmul(
+        _rmsnorm(x, bp["ln1"], cfg.norm_eps), bp["wqkv"]))
     q = q.reshape(b, w, cfg.heads, cfg.head_dim)
     k = k.reshape(b, w, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, w, cfg.kv_heads, cfg.head_dim)
@@ -369,7 +496,7 @@ def _logits(cfg: LMConfig, params, x):
     embedding table itself where it is tied."""
     from ..ops.quant import qmatmul
     if cfg.final_norm:
-        x = _rmsnorm(x, params["norm_f"])
+        x = _rmsnorm(x, params["norm_f"], cfg.norm_eps)
     return qmatmul(x, params["embed"].T if cfg.tie_embed
                    else params["unembed"])
 
@@ -483,25 +610,32 @@ def make_prefill(cfg: LMConfig):
     causal attention forgives the padding, a recurrence does not, so a
     state layer returns its state AT ``ctx_len`` (``h<i>`` and the
     convolution's tail ``c<i>``), an attention layer ``k<i>``/``v<i>``
-    as :func:`make_decode`'s does; the logits are those of position
-    ``ctx_len - 1``."""
+    as :func:`make_decode`'s does, a latent layer its latent rows
+    ``l<i>``; the logits are those of position ``ctx_len - 1``."""
     import jax.numpy as jnp
 
-    from . import ssm_mixer
+    from . import mla_mixer, ssm_mixer
 
     def prefill(params, ids, ctx_len):
         b, s = ids.shape
         assert b == 1 and s <= cfg.max_seq
-        x = params["embed"][ids]
+        x = _embed_rows(params, ids)
         rot = _rope_at(cfg, jnp.arange(s))
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
             if cfg.mixers[i] == "ssm":
                 out, h, tail = ssm_mixer.prefill(
-                    cfg, bp, _rmsnorm(x, bp["ln1"]), ctx_len)
+                    cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps), ctx_len)
                 x = _ffn_residual(cfg, bp, x + out)
                 cache[f"h{i}"], cache[f"c{i}"] = h, tail
+            elif cfg.mixers[i] == "mla":
+                # a latent layer returns the rows its tokens cache
+                # (``l<i>``); the bucket's padding is routed nowhere
+                out, cache[f"l{i}"] = mla_mixer.prefill(
+                    cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps), rot)
+                x, _counts = _ffn_scheduled(
+                    cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
             else:
                 x, kc, vc = _prefill_attn_layer(cfg, bp, x, rot)
                 cache[f"k{i}"], cache[f"v{i}"] = kc, vc
@@ -696,7 +830,9 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     run per joining session at batch 1 (the batcher blockifies its
     caches into the session's pages, :func:`make_paged_io`), and
     ``step(params, cache, bt, token[b], active[b]) -> (cache, logits)``
-    advances every ACTIVE slot one token.  ``cache["len"]`` is a
+    advances every ACTIVE slot one token (a schedule with expert
+    layers returns a third value, the step's routing counts ``(3,)``
+    int32: ``moe.serve``'s, over its layers).  ``cache["len"]`` is a
     per-slot (b,) int32 position vector; inactive slots are
     position-clamped and never advance, and their logits are garbage
     by contract.  ``bt`` is the (slots, max_seq // page) int32 block
@@ -718,7 +854,10 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
     state layer has ``sh<i>``/``sc<i>``, one block of recurrent state
     for each SLOT, which the step moves one position where the slot is
-    ``active``.  Unrolled layers only.
+    ``active``; a latent layer has ONE pool, ``pc<i>`` ``(num_pages,
+    page, kv_lora + rope`` padded to 128 lanes``)``: its keys and
+    values are the same rows.
+    Unrolled layers only.
 
     With ``chunk`` set a THIRD program rides along, also named
     ``step``: the step with one catch-up slice on board
@@ -743,7 +882,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
             f"page size {page} must divide max_seq {cfg.max_seq}")
 
     from ..ops import paged_attention
-    from . import ssm_mixer
+    from . import mla_mixer, ssm_mixer
 
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
@@ -783,15 +922,27 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         cache = dict(cache)
         pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
         att_pos = jnp.where(active, pos, 0)
-        x = params["embed"][token][:, None, :]
+        x = _embed_rows(params, token)[:, None, :]
         rot = _rope_at(cfg, pos[:, None])
+        counts = []
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
-            if cfg.mixers[i] == "ssm":
+            if cfg.mixers[i] == "mla":
+                # the slot's latent row is written, then the absorbed
+                # attention reads its live pages; an idle slot's row is
+                # routed to no expert
+                out, cache[f"pc{i}"] = mla_mixer.step(
+                    cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
+                    cache[f"pc{i}"], bt, pos, att_pos, rot, page)
+                x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
+                                        active[:, None])
+                if cnt is not None:
+                    counts.append(cnt)
+            elif cfg.mixers[i] == "ssm":
                 # the slot's recurrent state moves one position where
                 # the slot is active and stays where it is not
                 out, h, tail = ssm_mixer.step(
-                    cfg, bp, _rmsnorm(x[:, 0], bp["ln1"]),
+                    cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
                     cache[f"sh{i}"], cache[f"sc{i}"], active)
                 x = _ffn_residual(cfg, bp, x + out[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
@@ -802,6 +953,13 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
+        if counts:
+            # the expert layers' routing counts leave with the tokens:
+            # pairs on held experts and experts touched, summed over
+            # the layers, and the most rows one expert took
+            c = jnp.stack(counts)
+            return cache, _logits(cfg, params, x[:, 0]), jnp.concatenate(
+                [c[:, :2].sum(axis=0), c[:, 2:].max(axis=0)])
         return cache, _logits(cfg, params, x[:, 0])
 
     if chunk is None:
@@ -886,15 +1044,27 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
         h, tail = ssm_mixer.state_shapes(cfg, slots)
         cache[f"sh{i}"] = jnp.zeros(h, jnp.float32)
         cache[f"sc{i}"] = jnp.zeros(tail, jnp.float32)
+    # a latent layer holds one row a token: one pool, key and value
+    for i in cfg.mla_layers():
+        cache[f"pc{i}"] = jnp.zeros(
+            (num_pages, page, cfg.latent_row_padded()), jnp.float32)
     cache["len"] = jnp.zeros((slots,), jnp.int32)
     return cache
 
 
 def paged_page_bytes(cfg: LMConfig, page: int) -> int:
     """Device bytes one LOGICAL page pins across every attention
-    layer's k+v pools (the allocator's per-page accounting unit)."""
+    layer's k+v pools and every latent layer's pool (the allocator's
+    per-page accounting unit)."""
     return 2 * len(cfg.attn_layers()) * page * cfg.kv_heads \
-        * cfg.head_dim * 4                                 # float32
+        * cfg.head_dim * 4 + latent_row_bytes(cfg) * page  # float32
+
+
+def latent_row_bytes(cfg: LMConfig) -> int:
+    """Device bytes one TOKEN pins across every latent layer's pool."""
+    if not cfg.has_latent:
+        return 0
+    return len(cfg.mla_layers()) * cfg.latent_row_padded() * 4  # float32
 
 
 def state_slot_bytes(cfg: LMConfig) -> int:
@@ -971,7 +1141,8 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         layer's ``k<i>``/``v<i>`` blockified into the session's pages,
         a state layer's ``h<i>``/``c<i>`` written over WHATEVER the
         slot's last session left in its block of the state pool (for a
-        schedule without state layers ``slot`` addresses nothing).
+        schedule without state layers ``slot`` addresses nothing), a
+        latent layer's rows ``l<i>`` blockified into ``pc<i>``.
     Padding entries point at page 0 and only ever write garbage there.
 
     With ``chunk`` set a FOURTH program rides along — the block-paged
@@ -1008,6 +1179,10 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
                     cache[pool] = jax.lax.dynamic_update_slice(
                         cache[pool], src[new],
                         (slot,) + (0,) * (src[new].ndim - 1))
+                continue
+            if cfg.mixers[i] == "mla":
+                cache[f"pc{i}"] = cache[f"pc{i}"].at[page_ids].set(
+                    src[f"l{i}"][0].reshape(pps, page, -1))
                 continue
             cache[f"pk{i}"] = cache[f"pk{i}"].at[page_ids].set(
                 src[f"k{i}"][0].reshape(shape))

@@ -342,6 +342,193 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
                          interpret=_resolve_interpret(interpret))
 
 
+# -- latent attention -------------------------------------------------------
+#
+# A third layout and a third kernel, under its own name
+# (``mla_decode_attention``; ``models/mla_mixer.py``'s step calls it).
+# One row a token and layer, ``(num_pages, page, row)`` float32: the
+# normed latent, then the rotated shared key part, then zeros up to a
+# multiple of 128 lanes (the device's tiled layout pads a row to that
+# anyway, and a DMA moves whole tiles).  Keys
+# and values are the SAME rows (values the first ``kv_lora`` of each), so
+# the absorbed decode form reads every live page once: ``heads``
+# queries ``[q_lat (kv_lora), q_rope (rope)]`` against one shared row.
+
+# tokens a wave of the latent kernel holds: two MXU tiles of key rows
+_LATENT_WAVE_TOKENS = 256
+
+
+def mla_reference(q_lat, q_rope, pc, bt, pos, scale: float):
+    """The plain formulation over the latent layout: gather every
+    slot's whole block table into ``(slots, max_seq, kv_lora + rope)``
+    and run a dense masked attention over it, float32.  What the step
+    runs off the TPU, and what :func:`mla_decode_attention` is tested
+    against."""
+    import jax.numpy as jnp
+
+    b = q_lat.shape[0]
+    kl, rope = q_lat.shape[-1], q_rope.shape[-1]
+    page = pc.shape[1]
+    max_seq = bt.shape[1] * page
+    rows = pc[bt].reshape(b, max_seq, pc.shape[-1])
+    s_mat = (jnp.einsum("bhc,bkc->bhk", q_lat, rows[..., :kl],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bhr,bkr->bhk", q_rope,
+                          rows[..., kl:kl + rope],
+                          preferred_element_type=jnp.float32)) * scale
+    live = jnp.arange(max_seq)[None, :] <= pos[:, None]
+    s_mat = jnp.where(live[:, None, :], s_mat, -1e30)
+    p = jax.nn.softmax(s_mat, axis=-1)
+    return jnp.einsum("bhk,bkc->bhc", p, rows[..., :kl],
+                      preferred_element_type=jnp.float32)
+
+
+def _latent_kernel(bt_ref, pos_ref, ql_ref, qr_ref, pc_hbm, o_ref,
+                   cbuf, sems, g_ref, m_scr, l_scr, acc_scr, *,
+                   page: int, wave: int, scale: float):
+    """One grid step a slot (the queries of one slot are a block; the
+    wave buffers, their semaphores and the buffer parity ``g`` live
+    across steps, so the first wave of the next slot is in flight while
+    this one's last is computed).  A wave's rows are tokens on the
+    sublanes: ``(heads, kv_lora + rope)`` queries meet ``(tokens,
+    kv_lora + rope)`` rows as the two matrices they are, on the MXU in
+    bfloat16 with float32 accumulation; the weights then meet the same
+    rows' first ``kv_lora`` lanes."""
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    kl, rope = ql_ref.shape[-1], qr_ref.shape[-1]
+    toks = wave * page
+    bf = jnp.bfloat16
+
+    def n_pages(s):
+        return pos_ref[s] // page + 1
+
+    def wave_dma(s, w, buf, go):
+        for i in range(wave):
+            idx = w * wave + i
+
+            @pl.when(idx < n_pages(s))
+            def _():
+                go(pltpu.make_async_copy(
+                    pc_hbm.at[bt_ref[s, idx]],
+                    cbuf.at[buf, pl.ds(i * page, page)], sems.at[buf]))
+
+    start = functools.partial(wave_dma, go=lambda c: c.start())
+    wait = functools.partial(wave_dma, go=lambda c: c.wait())
+
+    @pl.when(b == 0)
+    def _():
+        g_ref[0] = 0
+        start(0, 0, 0)
+
+    tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
+    tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
+    n_waves = (n_pages(b) + wave - 1) // wave
+    m_scr[:] = jnp.full_like(m_scr, -1e30)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+    ql = (ql_ref[0] * scale).astype(bf)                   # (heads, kl)
+    qr = (qr_ref[0] * scale).astype(bf)                   # (heads, rope)
+    nt = (((1,), (1,)), ((), ()))
+
+    def wave_body(w, _):
+        g = g_ref[0]
+        buf = lax.rem(g, 2)
+
+        @pl.when(w + 1 < n_waves)
+        def _():
+            start(b, w + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
+        def _():
+            start(b + 1, 0, 1 - buf)
+
+        wait(b, w, buf)
+        lim = pos_ref[b] + 1 - w * toks
+        # rows past pos are stale or were never fetched: they meet a
+        # zero weight, and 0 * NaN is NaN
+        rows = jnp.where(tok_col < lim, cbuf[buf], 0.0).astype(bf)
+        c = rows[:, :kl]                                  # (toks, kl)
+        s = lax.dot_general(ql, c, nt,
+                            preferred_element_type=jnp.float32) \
+            + lax.dot_general(qr, rows[:, kl:kl + rope], nt,
+                              preferred_element_type=jnp.float32)
+        s = jnp.where(tok_row < lim, s, -1e30)            # (heads, toks)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[:] = l_scr[:] * corr + p.sum(axis=1, keepdims=True)
+        m_scr[:] = m_new
+        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
+            p.astype(bf), c, preferred_element_type=jnp.float32)
+        g_ref[0] = g + 1
+        return 0
+
+    lax.fori_loop(0, n_waves, wave_body, 0)
+    o_ref[0] = acc_scr[:] / l_scr[:]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _latent_call(q_lat, q_rope, pc, bt, pos, scale: float,
+                 interpret: bool):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, kl = q_lat.shape
+    rope = q_rope.shape[-1]
+    page = pc.shape[1]
+    wave = max(1, min(bt.shape[1], _LATENT_WAVE_TOKENS // page))
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, page=page, wave=wave,
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots,),
+            in_specs=[pl.BlockSpec((1, heads, kl),
+                                   lambda b, bt, pos: (b, 0, 0)),
+                      pl.BlockSpec((1, heads, rope),
+                                   lambda b, bt, pos: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, heads, kl),
+                                   lambda b, bt, pos: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, wave * page, pc.shape[-1]), pc.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),            # waves so far
+                pltpu.VMEM((heads, 1), jnp.float32),    # running max
+                pltpu.VMEM((heads, 1), jnp.float32),    # running denom
+                pltpu.VMEM((heads, kl), jnp.float32),   # accumulator
+            ]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, kl), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="mla_decode_attention",
+    )(bt, pos, q_lat, q_rope, pc)
+
+
+def mla_decode_attention(q_lat, q_rope, pc, bt, pos, scale: float,
+                         interpret: Optional[bool] = None):
+    """Absorbed latent attention of one decode step: ``q_lat (slots,
+    heads, kv_lora)`` and ``q_rope (slots, heads, rope)`` against
+    positions ``0..pos[b]`` of slot ``b``, whose pages ``bt[b, :pos[b]
+    // page + 1]`` lie in the latent pool ``pc (num_pages, page,
+    row)`` (``row`` = ``kv_lora + rope`` padded to 128) -> ``sum p c``, ``(slots, heads, kv_lora)``
+    float32 (the caller's ``W_kvb^V`` makes values of it).  Scores are
+    ``(q_lat . c + q_rope . k_rope) * scale``; operands bfloat16,
+    accumulation and softmax float32.  Each live page is fetched
+    once; entries past the last live page are never read."""
+    return _latent_call(q_lat, q_rope, pc, bt, pos, scale=float(scale),
+                        interpret=_resolve_interpret(interpret))
+
+
 def import_pallas() -> None:
     """Import what the kernel is written in.  Over a second of pure
     Python on the chip's host, and the first thing the first traced
@@ -362,3 +549,12 @@ def attention(q, pk, pv, bt, pos, page: Optional[int] = None):
     if pk.ndim == 3:
         return paged_decode_attention_grouped(q, pk, pv, bt, pos, page)
     return paged_decode_attention(q, pk, pv, bt, pos)
+
+
+def mla_attention(q_lat, q_rope, pc, bt, pos, scale: float):
+    """The step's latent attention: :func:`mla_decode_attention` on the
+    TPU, :func:`mla_reference` on the cpu backend."""
+    from .device_ops import _on_tpu
+    if not _on_tpu():
+        return mla_reference(q_lat, q_rope, pc, bt, pos, scale)
+    return mla_decode_attention(q_lat, q_rope, pc, bt, pos, scale)

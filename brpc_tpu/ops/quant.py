@@ -69,6 +69,24 @@ def qmatmul(x, w):
     return y * w.s
 
 
+def mxu_operand(x):
+    """A matmul operand as the MXU takes it: bfloat16 (a weight stored
+    in bfloat16 as it lies).  The latent-attention and expert layers
+    cast through here and accumulate in float32; a test that pins
+    float32 arithmetic patches this one name, as it does
+    :func:`qmatmul`."""
+    import jax.numpy as jnp
+    return x.astype(jnp.bfloat16)
+
+
+def mxu_matmul(x, w):
+    """``x @ w`` on :func:`mxu_operand` operands, accumulated in
+    float32."""
+    import jax.numpy as jnp
+    return jnp.dot(mxu_operand(x), mxu_operand(w),
+                   preferred_element_type=jnp.float32)
+
+
 def dequantize(w):
     """Materialize the f32 weight (tests / fallback paths)."""
     import jax.numpy as jnp

@@ -1,0 +1,533 @@
+"""Latent attention over a paged latent cache and dropless routed
+experts beside a shared one (``LMConfig.mixers`` ``"mla"``,
+``LMConfig.ffns`` ``"experts"``), at toy widths with the structure of
+the benchmark's ``kimi-k2.7-code``: a leading dense layer and two
+expert layers, 4 latent heads, YaRN rotary, 64 routed experts of which
+this program holds 4, 4 a token, one shared expert.
+
+The yardstick is ``benchmarks/models/kimi_k2.py``'s ``Reference``: the
+whole sequence at once, the EXPANDED attention as a full causal
+softmax, the expert layer a plain loop over the held experts; it
+imports nothing of the program.
+"""
+
+import hashlib
+import json
+import math
+import os
+import re
+import struct
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from brpc_tpu.models import mla_mixer, moe
+from brpc_tpu.models import transformer_lm as T
+from brpc_tpu.ops import paged_attention, quant
+from brpc_tpu.streaming import StreamOptions
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGE = 16
+
+
+def _bench(name="tests/toy_kimi/config.json"):
+    from benchmarks.harness import spec
+    cfg = spec.load_json(os.path.join(spec.BENCH_DIR, name))
+    return cfg, spec.load_module("models", cfg["model"])
+
+
+@pytest.fixture(scope="module")
+def model():
+    """``(file, module, LMConfig, params)`` of the toy configuration,
+    weights float32 (the benchmark's are bfloat16: widened once, so
+    that float32 arithmetic is exact on both sides)."""
+    cfg, m = _bench()
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    m.make_params(cfg, 3))
+    return cfg, m, T.LMConfig(remat=False, **m.lm_kwargs(cfg)), params
+
+
+@pytest.fixture
+def f32_matmuls(monkeypatch):
+    """Every matmul of the serving path in float32: the paged path and
+    the reference then differ by summation order alone, which is what
+    lets a tolerance catch a latent row kept in bf16."""
+    monkeypatch.setattr(quant, "qmatmul", lambda x, w: x @ w)
+    monkeypatch.setattr(quant, "mxu_operand", lambda x: x)
+
+
+class _Paged:
+    """One session in slot 1 of 2: bucketed prefill, insert, steps."""
+
+    def __init__(self, lm, params, ctx):
+        self.lm, self.params = lm, params
+        prefill, step = T.make_paged_batch_decode(lm, PAGE)
+        insert = T.make_paged_io(lm, PAGE)[2]
+        bucket = 1
+        while bucket < max(len(ctx), 1):
+            bucket <<= 1
+        ids = np.zeros((bucket,), np.int32)
+        ids[:len(ctx)] = ctx
+        cache1, _ = jax.jit(prefill)(params, ids[None], jnp.int32(len(ctx)))
+        self.cache = T.empty_paged_cache(lm, 33, 2, PAGE)
+        self.bt = np.zeros((2, lm.max_seq // PAGE), np.int32)
+        self.bt[1] = 1 + np.arange(self.bt.shape[1])
+        self.cache = jax.jit(insert)(self.cache, jnp.asarray(self.bt[1]),
+                                     cache1, jnp.int32(1))
+        self.cache["len"] = self.cache["len"].at[1].set(len(ctx))
+        self._step = jax.jit(step)
+
+    def feed(self, tok):
+        self.cache, logits, counts = self._step(
+            self.params, self.cache, jnp.asarray(self.bt),
+            jnp.asarray([0, tok], jnp.int32), jnp.asarray([False, True]))
+        return np.asarray(logits[1]), np.asarray(counts)
+
+
+def _gaps(model, n_ctx, spoil=None, seed=0):
+    """The paged path's logits against the reference's at every served
+    position, in units of the position's logit standard deviation."""
+    cfg, m, lm, params = model
+    rng = np.random.default_rng(seed + n_ctx)
+    prompt = rng.integers(0, 256, (n_ctx + 1,), dtype=np.int32)
+    served = rng.integers(0, 256, (10,), dtype=np.int32)
+    with jax.default_matmul_precision("highest"):
+        run = _Paged(lm, params, prompt[:-1])
+        got = []
+        for tok in np.concatenate([prompt[-1:], served[:-1]]):
+            if spoil is not None:
+                spoil(run)
+            got.append(run.feed(tok)[0])
+    want = m.Reference(cfg, params).served_logits(prompt, served)
+    return np.abs(np.stack(got) - want).max(axis=-1) / want.std(axis=-1)
+
+
+# float32 on both sides: readings 3e-6 to 9e-6 at this size; a latent
+# row kept in bf16 reads 2e-3 and more
+@pytest.mark.parametrize("n_ctx", [0, 1, 15, 16, 17, 31])
+def test_prefill_then_paged_steps_match_the_reference(model, f32_matmuls,
+                                                      n_ctx):
+    assert _gaps(model, n_ctx).max() < 1e-4
+
+
+def test_bf16_latent_rows_would_fail_the_tolerance(model, f32_matmuls):
+    def spoil(run):
+        for k in run.cache:
+            if k.startswith("pc"):
+                run.cache[k] = run.cache[k].astype(jnp.bfloat16) \
+                    .astype(jnp.float32)
+    assert _gaps(model, 17, spoil).max() > 1e-3
+
+
+def test_served_precision_stays_near_the_reference(model):
+    """As served (bf16 operands): a position's logits lie a few
+    hundredths of their standard deviation from the reference's,
+    except where the rounding flips a router's choice (a whole expert:
+    about 1); so the median is held, and the worst to what a wrong
+    formula would pass."""
+    gaps = np.concatenate([_gaps(model, n) for n in (1, 16, 31)])
+    assert np.median(gaps) < 0.15 and gaps.max() < 3.0, gaps
+
+
+def test_expanded_and_absorbed_attention_agree(model, f32_matmuls):
+    """The bucket's expanded form at its last position against the
+    step's absorbed form over the same cached rows."""
+    _cfg, _m, lm, params = model
+    bp = params["blk1"]
+    rng = np.random.default_rng(5)
+    s = 16
+    x = jnp.asarray(rng.normal(size=(1, s, lm.dim)).astype(np.float32))
+    out, latent = mla_mixer.prefill(lm, bp, x, T._rope_at(lm, jnp.arange(s)))
+    pages = lm.max_seq // PAGE
+    pc = jnp.zeros((pages + 1, PAGE, lm.latent_row_padded()), jnp.float32)
+    bt = 1 + jnp.arange(pages)[None]
+    pc = pc.at[bt[0]].set(latent[0].reshape(pages, PAGE, -1))
+    # the row of position s - 1 is written again by the step
+    pos = jnp.asarray([s - 1])
+    got, pc2 = mla_mixer.step(lm, bp, x[:, s - 1], pc, bt, pos, pos,
+                              T._rope_at(lm, pos[:, None]), PAGE)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(out[0, s - 1]),
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(pc2), np.asarray(pc), atol=1e-6)
+    assert latent.shape == (1, lm.max_seq, 128) and lm.latent_row() == 40
+    assert not np.asarray(latent[0, :s, 40:]).any()        # the padding
+
+
+@pytest.mark.parametrize("slots,heads,kl,rope,page,pps,pos", [
+    (3, 4, 16, 8, 4, 8, [0, 13, 31]),
+    (2, 8, 128, 64, 16, 6, [95, 40]),          # more than one wave
+    (1, 2, 32, 16, 16, 20, [300]),
+])
+def test_mla_decode_attention_kernel(slots, heads, kl, rope, page, pps, pos):
+    """The kernel, interpreted, against the plain gather (bf16
+    operands in the kernel: a few thousandths)."""
+    r = np.random.default_rng(slots + heads)
+    pages = slots * pps + 1
+    ql = r.normal(size=(slots, heads, kl)).astype(np.float32)
+    qr = r.normal(size=(slots, heads, rope)).astype(np.float32)
+    pc = r.normal(size=(pages, page, -(-(kl + rope) // 128) * 128)) \
+        .astype(np.float32)
+    pc[..., kl + rope:] = 0.0
+    bt = (1 + r.permutation(pages - 1)).reshape(slots, pps).astype(np.int32)
+    pos = np.asarray(pos, np.int32)
+    scale = (kl + rope) ** -0.5
+    want = paged_attention.mla_reference(ql, qr, pc, bt, pos, scale)
+    # pages past a slot's last live one are never read: poison them
+    for b in range(slots):
+        pc[bt[b, pos[b] // page + 1:]] = np.nan
+    got = paged_attention.mla_decode_attention(ql, qr, pc, bt, pos, scale,
+                                               interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2)
+
+
+def test_router_against_hand_arithmetic():
+    """The bias chooses and does not weigh; the chosen scores are
+    renormalised, then scaled."""
+    ecfg = moe.ExpertConfig(dim=2, hidden=4, routed=4, held=(0, 2),
+                            top_k=2, route_scale=2.5)
+    logits = np.array([[2.0, 1.0, 0.0, -1.0]], np.float32)
+    p = {"router": jnp.asarray(np.vstack([logits, np.zeros((1, 4))])
+                               .astype(np.float32)),
+         "bias": jnp.asarray([0.0, 0.0, 0.0, 0.6], jnp.float32)}
+    t = jnp.asarray([[1.0, 7.0]], jnp.float32)
+    ids, w = moe.route(p, t, ecfg)
+    sc = 1.0 / (1.0 + np.exp(-logits[0]))
+    # sc = .881 .731 .5 .269; with the bias expert 3 reads .869 and
+    # passes expert 1: chosen 0 and 3, weighed .881 and .269
+    assert ids.tolist() == [[0, 3]]
+    want = np.array([sc[0], sc[3]]) / (sc[0] + sc[3] + 1e-20) * 2.5
+    np.testing.assert_allclose(np.asarray(w[0]), want, rtol=1e-6)
+
+
+def test_nothing_is_dropped_where_the_capacity_layer_drops(f32_matmuls):
+    """Sixteen identical tokens choose the same experts: the
+    capacity-factor layer (training's ``forward``) serves the first
+    few and drops the rest; ``serve`` gives every one the same
+    output."""
+    d = 8
+    x = jnp.tile(jnp.asarray(np.random.default_rng(0).normal(size=(1, d))
+                             .astype(np.float32)), (16, 1))
+    tcfg = moe.MoEConfig(dim=d, hidden=16, num_experts=4,
+                         capacity_factor=1.0, top_k=1)
+    out, _aux = moe.forward(moe.init_params(jax.random.PRNGKey(0), tcfg),
+                            x, tcfg)
+    kept = np.abs(np.asarray(out)).sum(axis=-1) > 0
+    assert kept.sum() == tcfg.capacity(16) == 4            # 12 dropped
+    ecfg = moe.ExpertConfig(dim=d, hidden=16, routed=4, held=(0, 4),
+                            top_k=2, route_scale=1.0, shared=0)
+    p = moe.init_served(jax.random.PRNGKey(1), ecfg)
+    out, counts = moe.serve(p, x, ecfg)
+    out = np.asarray(out)
+    assert np.abs(out[0]).sum() > 0
+    np.testing.assert_allclose(out, np.tile(out[:1], (16, 1)), atol=1e-6)
+    # 32 pairs on 2 experts, 16 rows each
+    assert counts.tolist() == [32, 2, 16]
+    ids, w = moe.route(p, x[:1], ecfg)
+    hand = sum(float(w[0, k]) * np.asarray(moe._gated(
+        x[:1], p["w1"][int(ids[0, k])], p["w2"][int(ids[0, k])]))
+        for k in range(2))
+    np.testing.assert_allclose(out[:1], hand, atol=1e-5)
+
+
+def test_rows_that_are_not_live_route_nowhere(f32_matmuls):
+    ecfg = moe.ExpertConfig(dim=8, hidden=16, routed=8, held=(2, 6),
+                            top_k=3, route_scale=1.0, shared=1)
+    p = moe.init_served(jax.random.PRNGKey(2), ecfg)
+    x = jnp.asarray(np.random.default_rng(1).normal(size=(6, 8))
+                    .astype(np.float32))
+    live = jnp.asarray([True, False, True, True, False, False])
+    out, counts = moe.serve(p, x, ecfg, live)
+    only, counts3 = moe.serve(p, x[np.asarray(live)], ecfg)
+    assert counts.tolist() == counts3.tolist()
+    np.testing.assert_allclose(np.asarray(out)[np.asarray(live)],
+                               np.asarray(only), atol=1e-5)
+    # a row that is not live keeps the shared expert alone
+    np.testing.assert_allclose(
+        np.asarray(out[1:2]), np.asarray(moe._gated(x[1:2], p["ws1"],
+                                                    p["ws2"])), atol=1e-5)
+    assert ecfg.buffer_rows(6) == 18
+
+
+def test_the_shares_add_up_to_the_uncut_layer(f32_matmuls):
+    """Over all sixteen shares of the toy's 64 experts, the routed
+    parts plus the shared expert counted ONCE equal the uncut layer,
+    which the benchmark's reference computes with every expert."""
+    cfg, m = _bench()
+    d, e = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    routed, k = cfg["n_routed_experts_published"], cfg["num_experts_per_tok"]
+    whole = moe.ExpertConfig(dim=d, hidden=e, routed=routed,
+                             held=(0, routed), top_k=k,
+                             route_scale=cfg["routed_scaling_factor"],
+                             shared=1)
+    p = moe.init_served(jax.random.PRNGKey(4), whole)
+    t = jnp.asarray(np.random.default_rng(2).normal(size=(24, d))
+                    .astype(np.float32))
+    with jax.default_matmul_precision("highest"):
+        uncut = m._experts(t, p, cfg, False, held=(0, routed))
+        shared = moe._gated(t, p["ws1"], p["ws2"])
+        total, pairs = shared, 0
+        for lo in range(0, routed, 4):
+            share = moe.ExpertConfig(dim=d, hidden=e, routed=routed,
+                                     held=(lo, lo + 4), top_k=k,
+                                     route_scale=whole.route_scale, shared=1)
+            mine = {**p, "w1": p["w1"][lo:lo + 4], "w2": p["w2"][lo:lo + 4]}
+            out, counts = moe.serve(mine, t, share)
+            total = total + (out - shared)
+            pairs += int(counts[0])
+            # the reference, given the same share, says the same
+            np.testing.assert_allclose(
+                np.asarray(out), np.asarray(m._experts(
+                    t, mine, cfg, False, held=(lo, lo + 4))), atol=2e-5)
+    assert pairs == 24 * k                   # every pair fell somewhere
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               atol=5e-5)
+
+
+def test_yarn_frequencies_and_the_scale_against_the_formulas():
+    cfg, m = _bench("configs/kimi-k2.7-code.json")
+    lm = T.LMConfig(remat=False, **m.lm_kwargs(cfg))
+    base, dim = 50000.0, 64
+    f = np.array([base ** (-2 * i / dim) for i in range(32)])
+
+    def cd(r):
+        return dim * math.log(4096 / (2 * math.pi * r)) / (2 * math.log(base))
+
+    low, high = math.floor(cd(32)), math.ceil(cd(1))
+    assert (low, high) == (8, 20)
+    ramp = np.clip((np.arange(32) - low) / (high - low), 0, 1)
+    want = f / 64 * ramp + f * (1 - ramp)
+    np.testing.assert_allclose(mla_mixer.inv_freq(lm), want, rtol=1e-6)
+    np.testing.assert_allclose(m.yarn_inv_freq(cfg), want, rtol=1e-12)
+    assert want[0] == 1.0 and want[31] == pytest.approx(f[31] / 64)
+    mscale = 0.1 * math.log(64) + 1
+    assert mscale == pytest.approx(1.4159, abs=1e-4)
+    for s in (mla_mixer.softmax_scale(lm), m.softmax_scale(cfg)):
+        assert s == pytest.approx(192 ** -0.5 * mscale ** 2, rel=1e-12)
+    sin, cos = T._rope_at(lm, jnp.asarray([3]))
+    np.testing.assert_allclose(np.asarray(sin[0, 0]), np.sin(3 * want),
+                               rtol=1e-4, atol=1e-6)
+    assert lm.norm_eps == 1e-5 and lm.latent_row() == 576 \
+        and lm.latent_row_padded() == 640
+
+
+def test_counts_against_hand_arithmetic():
+    cfg, m = _bench("configs/kimi-k2.7-code.json")
+    mla = 7168 * 1536 + 1536 * 64 * 192 + 7168 * 576 + 512 * 64 * 256 \
+        + 8192 * 7168
+    assert m.mla_params(cfg) == mla == 101_122_048
+    expert = 3 * 7168 * 2048
+    assert m.expert_params(cfg) == expert == 44_040_192
+    layer = mla + 7168 * 384 + 13 * expert
+    assert m.expert_layer_params(cfg) == layer and round(layer / 1e5) == 6764
+    dense = mla + 3 * 7168 * 18432
+    total = 7 * layer + dense + 2 * 20480 * 7168
+    assert m.total_params(cfg) == total
+    assert round(2 * total / 1e7) == 1105                  # 11.05 GB
+    lm = T.LMConfig(remat=False, **m.lm_kwargs(cfg))
+    assert T.paged_page_bytes(lm, 16) == 16 * 8 * 640 * 4  # as it lies
+    # a step of 64 rows at 1,000 live positions each
+    lives = [1000] * 64
+    flops, nbytes = m.step_work(cfg, lives, 1)
+    every_step = 7 * (layer - 12 * expert) + dense + 7168 * 20480
+    touched = 7 * 12 * (1 - (47 / 48) ** 64)
+    assert touched / 84 == pytest.approx(0.74, abs=0.005)
+    assert nbytes == pytest.approx(
+        2 * (every_step + touched * expert) + 8 * 576 * 4 * (64_000 + 64))
+    assert 9.5e9 < nbytes < 10.5e9                          # ~10 GB a step
+    counted = {"experts_touched": 50, "local_pairs": 100}
+    _f, nb2 = m.step_work(cfg, lives, 1, counted)
+    assert nb2 == 2 * (every_step + 50 * expert) + 8 * 576 * 4 * 64_064
+    att = 8 * 2 * 64 * (576 + 512) * 64_000
+    assert m.mla_decode_work(cfg, lives, 1) == (att, 8 * 576 * 4 * 64_000)
+    per_row = 2 * (every_step - 8 * 512 * 64 * 256) \
+        + 8 * 2 * 64 * 512 * 256
+    assert flops == pytest.approx(
+        64 * per_row + att + 2 * expert * 7 * 64 * 8 * 12 / 384)
+    assert m.kernel_calls(cfg, "mla_decode_attention") == 8
+    assert m.expert_work(cfg, lives, 1, counted) == (2 * expert * 100,
+                                                     2 * 50 * expert)
+
+
+# -- through the batcher -------------------------------------------------------
+
+class _FakeStream:
+    def __init__(self):
+        self.closed, self.close_reason, self.tokens = False, None, []
+        self.id, self._native_tx = 0, None
+        self.options = StreamOptions()
+
+    def write(self, data):
+        self.tokens.append(struct.unpack("<i", bytes(data))[0])
+        return 0
+
+    def close(self, reason=None):
+        self.closed, self.close_reason = True, reason
+
+
+def test_batcher_serves_the_references_tokens_and_counts_routing(
+        model, f32_matmuls):
+    """Three sessions on two slots (one waits, one slot is reused):
+    each is served what the reference decodes greedily; the routing
+    counts arrive with the tokens; the latent pool and the pages
+    read are accounted; ``LM.Info`` shows the schedule."""
+    from brpc_tpu.models.lm_service import ContinuousBatcher, LMService
+    cfg, m, lm, params = model
+    ref = m.Reference(cfg, params)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 256, (n,), dtype=np.int32)
+               for n in (5, 18, 1)]
+    bat = ContinuousBatcher(lm, params, slots=2, page=PAGE, pages=17,
+                            idle_linger_s=0.2)
+    streams = [_FakeStream() for _ in prompts]
+    with jax.default_matmul_precision("highest"):
+        for st, p in zip(streams, prompts):
+            bat.join(st, p, 6)
+        deadline = time.monotonic() + 120.0
+        while not all(s.closed for s in streams) \
+                and time.monotonic() < deadline:
+            time.sleep(0.002)
+    for st, p in zip(streams, prompts):
+        assert st.close_reason == "finished"
+        toks = np.asarray(st.tokens, np.int32)
+        logits = ref.served_logits(p, toks)
+        best = logits.max(axis=-1)
+        assert (best - logits[np.arange(6), toks]
+                <= 1e-4 * logits.std(axis=-1)).all()
+    kv = bat.kv_stats()
+    moe_c = kv["moe"]
+    assert {k: moe_c[k] for k in ("layers", "held", "routed", "top_k")} \
+        == {"layers": 2, "held": 4, "routed": 64, "top_k": 4}
+    assert moe_c["rows"] == 18 and moe_c["steps"] == kv["steps"]
+    assert 0 < moe_c["local_pairs"] <= 18 * 4 * 2
+    assert 0 < moe_c["experts_touched"] <= moe_c["local_pairs"]
+    assert 1 <= moe_c["max_load"] <= 2
+    assert kv["latent"] == {"row_bytes": 160, "layers": 3,
+                            "pool_bytes": 17 * PAGE * 3 * 128 * 4}
+    assert kv["attn"]["pages_read"] > 0 and "prefix" not in kv
+    assert bat._alloc.page_bytes == T.paged_page_bytes(lm, PAGE) \
+        == PAGE * 3 * 128 * 4
+    svc = LMService(cfg=lm, params=params, page=PAGE, decode_slots=2)
+    info = json.loads(svc.Info(None, b""))
+    assert info["mixers"] == "mmm" and info["ffns"] == "dee"
+    assert info["experts"]["held"] == [0, 4] \
+        and info["experts"]["routed"] == 64
+    assert info["latent_pool"] == {"layers": 3, "row": 40, "row_bytes": 160,
+                                   "token_bytes": 3 * 128 * 4}
+    assert b":dee:" in svc.model_fingerprint()
+
+
+# -- what declines, by name ----------------------------------------------------
+
+def _lm(**kw):
+    cfg, m = _bench()
+    return T.LMConfig(**{"remat": False, **m.lm_kwargs(cfg), **kw})
+
+
+def _batcher(**kw):
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    return ContinuousBatcher(_lm(), {}, **{"page": PAGE, **kw})
+
+
+def _generate_declines():
+    from brpc_tpu.client.controller import Controller
+    from brpc_tpu.models.lm_service import LMService, pack_generate_request
+    lm = _lm()
+    svc = LMService(cfg=lm, params=T.init_params(jax.random.PRNGKey(1), lm))
+    cntl = Controller()
+    assert svc.Generate(cntl, pack_generate_request(
+        np.zeros((1, 4), np.int32), 2)) is None
+    raise T.UnsupportedBlock(cntl.error_text)
+
+
+def _flash_declines():
+    lm = _lm(attn_impl="flash")
+    params = T.init_params(jax.random.PRNGKey(0), lm)
+    T.make_prefill(lm)(params, np.zeros((1, 16), np.int32), np.int32(3))
+
+
+DECLINES = {
+    "training": lambda: T.make_forward(_lm()),
+    "train_step": lambda: T.make_train_step(_lm()),
+    "contiguous_decode": lambda: T.make_decode(_lm()),
+    "spec_verify": lambda: T.make_paged_spec_verify(_lm(), PAGE, 3),
+    "kv_export_specs": lambda: T.kv_page_specs(_lm()),
+    "kv_export": lambda: T.export_decode_cache(_lm(), {}),
+    "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
+                                         _lm(scan_layers=True)),
+    "host_spill": lambda: T.make_paged_io(_lm(), PAGE)[0]({}, None),
+    "host_resume": lambda: T.make_paged_io(_lm(), PAGE)[1]({}, None, None),
+    "catch_up": lambda: T.make_paged_io(_lm(), PAGE, chunk=8)[3](),
+    "riding_step": lambda: T.make_paged_batch_decode(
+        _lm(), PAGE, chunk=8)[2](),
+    "batcher_spec": lambda: _batcher(spec_decode_k=2, draft_params={}),
+    "batcher_park": lambda: _batcher(host_slots=4),
+    "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
+    "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),
+    "generate": _generate_declines,
+    "flash_prefill": _flash_declines,
+    # experts beside another mixer than the latent one
+    "experts_beside_mha": lambda: T.LMConfig(
+        depth=2, ffns=("dense", "experts"), expert_dim=8, experts_routed=4,
+        experts_top_k=2),
+}
+
+
+@pytest.mark.parametrize("path", sorted(DECLINES))
+def test_unported_paths_decline_by_name(path):
+    with pytest.raises(T.UnsupportedBlock):
+        DECLINES[path]()
+
+
+# -- the blocks that were there are served by the programs that were there ------
+
+def _program_hashes(cfg, page):
+    """The step's and a 16-token prefill's lowered text, locations
+    stripped, hashed."""
+    spec = lambda tree: jax.tree_util.tree_map(        # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    params = spec(jax.eval_shape(
+        lambda: T.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = spec(jax.eval_shape(
+        lambda: T.empty_paged_cache(cfg, 9, 2, page)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)   # noqa: E731
+    prefill, step = T.make_paged_batch_decode(cfg, page)
+    out = {}
+    for name, fn, args in (
+            ("step", step, (params, cache, i32(2, cfg.max_seq // page),
+                            i32(2), jax.ShapeDtypeStruct((2,), jnp.bool_))),
+            ("prefill", prefill, (params, i32(1, 16), i32()))):
+        text = jax.jit(fn).lower(*args).as_text()
+        text = re.sub(r"\s*loc\([^\n]*\)$", "", text, flags=re.M)
+        text = "\n".join(ln for ln in text.splitlines()
+                         if not ln.startswith("#loc"))
+        out[name] = hashlib.sha256(text.encode()).hexdigest()[:16]
+    return out
+
+
+# read at the commit before latent attention and the served experts
+# (817a8ef), with this function, on this installation
+PARENTS = {
+    "first_block": (
+        dict(vocab=64, dim=32, heads=4, depth=2, max_seq=64, remat=False),
+        16, {"step": "66a4fce0a8f0e8b9", "prefill": "d3a796e6d3661691"}),
+    "state_layers_grouped_heads": (
+        dict(vocab=97, dim=40, heads=5, kv_heads=1, depth=4, max_seq=64,
+             remat=False, rope=False, ffn="gated_silu", ffn_dim=96,
+             tie_embed=True, final_norm=True,
+             mixers=("ssm", "attn", "ssm", "ssm"), ssm_dt_rank=6),
+        8, {"step": "41894b873c845530", "prefill": "128f3ea7122e3b66"}),
+}
+
+
+@pytest.mark.parametrize("block", sorted(PARENTS))
+def test_default_blocks_traced_programs_are_unchanged(block):
+    kw, page, want = PARENTS[block]
+    prev = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    try:
+        assert _program_hashes(T.LMConfig(**kw), page) == want
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", prev)
