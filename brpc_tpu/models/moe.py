@@ -174,10 +174,17 @@ def forward_grouped(params: Dict[str, Any], x, cfg: MoEConfig
 # weights renormalised and scaled, every expert a gated MLP, shared
 # experts on every token.  Nothing is dropped: the rows routed to the
 # experts held here are sorted by expert into a buffer of the worst
-# case and run as a grouped matrix product whose cost follows the rows
-# present.  The layer is TOLD which experts it holds: it routes over
-# all of them, normalises over all the chosen ones, and adds only its
-# own experts' part; what the others would add is another chip's.
+# case and run as two grouped matrix products, each one call of the
+# program's own kernel (``ops/expert_gmm.py``, ``expert_gmm`` in a
+# device trace): a grid step a (row tile, expert) pair that has rows, so
+# the cost follows the experts TOUCHED (their weights, once, at ~90% of
+# the HBM rate on the v5e) and not the buffer.  (``jax.lax.ragged_dot``,
+# which stood here until PR 32, followed the buffer's rows: a 512-row
+# MXU tile a touched expert, 36% of the HBM rate at a decode step's 16
+# rows in 512; PERF.md §6.)  The layer is TOLD which experts it holds:
+# it routes over all of them, normalises over all the chosen ones, and
+# adds only its own experts' part; what the others would add is another
+# chip's.
 # (``forward`` above, the capacity-factor layer that drops, is
 # training's.)
 
@@ -266,6 +273,7 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
     import jax.numpy as jnp
 
     from ..ops import quant
+    from ..ops.expert_gmm import expert_gmm
 
     T, k, n = t.shape[0], cfg.top_k, cfg.n_held
     lo, hi = cfg.held
@@ -282,13 +290,9 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
                     dtype=jnp.int32)
     bf = quant.mxu_operand
     xs = bf(t)[tok]                                         # (M, dim)
-    gate, up = jnp.split(jax.lax.ragged_dot(
-        xs, bf(p["w1"]), sizes,
-        preferred_element_type=jnp.float32), 2, axis=-1)
-    ys = jax.lax.ragged_dot(
-        bf(jax.nn.silu(gate) * up), bf(p["w2"]), sizes,
-        preferred_element_type=jnp.float32)                 # (M, dim)
-    # rows past the last group were not computed
+    gate, up = jnp.split(expert_gmm(xs, bf(p["w1"]), sizes), 2, axis=-1)
+    ys = expert_gmm(bf(jax.nn.silu(gate) * up), bf(p["w2"]), sizes)
+    # rows past the last group were not computed: (M, dim)
     ys = jnp.where((skey < n)[:, None], ys, 0.0) \
         * w.reshape(T * k)[order][:, None]
     out = jnp.zeros((T, cfg.dim), jnp.float32).at[tok].add(ys)

@@ -26,6 +26,7 @@ import pytest
 
 from brpc_tpu.models import mla_mixer, moe
 from brpc_tpu.models import transformer_lm as T
+from brpc_tpu.ops import expert_gmm as gmm
 from brpc_tpu.ops import paged_attention, quant
 from brpc_tpu.streaming import StreamOptions
 
@@ -284,6 +285,119 @@ def test_the_shares_add_up_to_the_uncut_layer(f32_matmuls):
     assert pairs == 24 * k                   # every pair fell somewhere
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
                                atol=5e-5)
+
+
+
+# -- the grouped product's kernel ---------------------------------------------
+
+# a decode step's layer at the cell's load: 16 rows on 9 of 12 experts
+STEP_SIZES = [2, 0, 1, 3, 2, 0, 1, 2, 0, 3, 1, 1]
+
+# name: (buffer rows, K, N, sizes, row tile, (tk, tn) or None for whole)
+GMM_CASES = {
+    "empty_groups_first_last_and_between":
+        (64, 32, 48, [0, 3, 0, 5, 2, 0], 8, None),
+    "every_row_in_one_group": (64, 32, 48, [0, 64, 0, 0], 8, None),
+    "groups_cross_row_tiles_in_a_full_buffer":
+        (64, 32, 48, [10, 11, 10, 11, 11, 11], 8, None),
+    "no_rows_at_all": (64, 32, 48, [0, 0, 0, 0], 8, None),
+    "a_step_one_row_tile_of_four": (512, 64, 128, STEP_SIZES, 128, None),
+    "a_buffer_that_is_no_multiple_of_the_tile":
+        (10, 32, 48, [1, 2, 3], 128, None),
+    "two_blocks_of_n": (40, 128, 256, [7, 0, 20, 5], 16, (128, 128)),
+    "two_blocks_of_k_and_of_n": (40, 256, 256, [7, 0, 20, 5], 16,
+                                 (128, 128)),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", sorted(GMM_CASES))
+def test_expert_gmm_kernel(case, dtype):
+    """The kernel, interpreted, against ``jax.lax.ragged_dot`` on the
+    same operands: the live rows agree; the rows a visited tile holds
+    behind the last group read 0 whatever the buffer held there (NaN
+    here); an expert without rows is multiplied into nothing."""
+    rows, k, n, sizes, tm, blocks = GMM_CASES[case]
+    dtype = jnp.dtype(dtype)
+    r = np.random.default_rng(rows + k)
+    live = sum(sizes)
+    xs = r.normal(size=(rows, k)).astype(np.float32)
+    xs[live:] = np.nan
+    w = r.normal(size=(len(sizes), k, n)).astype(np.float32) / math.sqrt(k)
+    xs, w = jnp.asarray(xs, dtype), jnp.asarray(w, dtype)
+    sz = jnp.asarray(sizes, jnp.int32)
+    budget = gmm._BLOCK_BYTES
+    if blocks is not None:
+        budget = blocks[0] * blocks[1] * dtype.itemsize
+        assert gmm._blocks(k, n, dtype.itemsize, budget) == blocks
+    got = np.asarray(gmm.expert_gmm(xs, w, sz, tm=tm, block_bytes=budget,
+                                    interpret=True))
+    assert got.shape == (rows, n) and got.dtype == np.float32
+    want = np.asarray(jax.lax.ragged_dot(
+        xs, w, sz, preferred_element_type=jnp.float32))
+    np.testing.assert_allclose(got[:live], want[:live], atol=1e-5)
+    tm = gmm._row_tile(rows, tm)
+    reached = min(rows, -(-live // tm) * tm)
+    assert (got[live:reached] == 0).all()
+
+
+@pytest.mark.parametrize("sizes,rows,tm", [
+    (STEP_SIZES, 512, 128), (STEP_SIZES, 512, 8), ([0, 0, 0], 64, 16),
+    ([0, 64, 0, 0], 64, 16), ([10, 11, 10, 11, 11, 11], 64, 8),
+    ([1, 0, 0, 0, 0, 5], 32, 4), ([16, 16], 32, 16),
+])
+def test_the_visit_list_names_only_groups_with_rows(sizes, rows, tm):
+    """Against a walk over every (row tile, group) pair: a visit is a
+    pair that shares a row; they come in row order; a group without
+    rows is in none, a row tile behind the last group in none; the
+    entries past the last visit repeat it."""
+    tiles_m = rows // tm
+    offs, group, tile, visits = [np.asarray(a) for a in gmm.visit_list(
+        jnp.asarray(sizes, jnp.int32), tiles_m, tm)]
+    ends = np.cumsum(sizes)
+    starts = ends - sizes
+    want = [(g, t) for g in range(len(sizes)) for t in range(tiles_m)
+            if max(starts[g], t * tm) < min(ends[g], (t + 1) * tm)]
+    assert offs.tolist() == [0] + ends.tolist()
+    assert int(visits) == len(want) <= tiles_m + len(sizes) - 1 == len(group)
+    assert list(zip(group[:len(want)], tile[:len(want)])) == want
+    assert all(sizes[g] > 0 for g in group[:len(want)])
+    if want:
+        assert set(zip(group[len(want):], tile[len(want):])) <= {want[-1]}
+
+
+def _count_eqns(jaxpr, pred) -> int:
+    """The equations ``pred`` holds for, in ``jaxpr`` and in every
+    jaxpr its equations carry (pjit, cond, scan, while)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += bool(pred(eqn))
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else (val,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_eqns(sub, pred)
+    return n
+
+
+def test_the_step_calls_expert_gmm_twice_an_expert_layer(model):
+    """What ``benchmarks/models/kimi_k2.py kernel_calls`` counts on:
+    the traced step holds two kernels named ``expert_gmm`` an expert
+    layer (gate and up in one product, down in the other) and no
+    grouped product of XLA's."""
+    cfg, m, lm, params = model
+    _prefill, step = T.make_paged_batch_decode(lm, PAGE)
+    cache = T.empty_paged_cache(lm, 9, 2, PAGE)
+    jaxpr = jax.make_jaxpr(step)(
+        params, cache, jnp.zeros((2, lm.max_seq // PAGE), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.asarray([True, True])).jaxpr
+    calls = _count_eqns(
+        jaxpr, lambda e: e.primitive.name == "pallas_call"
+        and e.params["name"] == "expert_gmm")
+    assert calls == 2 * lm.ffns.count("experts") == 4 \
+        == m.kernel_calls(cfg, "expert_gmm")
+    assert _count_eqns(
+        jaxpr, lambda e: e.primitive.name.startswith("ragged_dot")) == 0
 
 
 def test_yarn_frequencies_and_the_scale_against_the_formulas():

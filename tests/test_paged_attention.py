@@ -190,6 +190,28 @@ def test_kernel_compiles_for_the_v5e_at_real_widths(heads, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
+
+@pytest.mark.parametrize("rows", [512, 8192])
+@pytest.mark.parametrize("k,n", [(7168, 4096), (2048, 7168)])
+def test_expert_gmm_compiles_for_the_v5e_at_real_widths(rows, k, n, one_chip):
+    """Mosaic takes the grouped product's kernel at ``kimi-k2.7-code``'s
+    shapes (12 held experts, the step's buffer and the longest
+    prefill's) under the name a device trace is read by, the weights go
+    in as they lie (no copy), and nothing is run."""
+    from brpc_tpu.ops.expert_gmm import expert_gmm
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: expert_gmm(*a, interpret=False)
+    ).lower(arg((rows, k), jnp.bfloat16), arg((12, k, n), jnp.bfloat16),
+            arg((12,), jnp.int32)).compile()
+    assert re.search(rf"%expert_gmm[.\d]* = f32\[{rows},{n}\]\S* custom-call",
+                     compiled.as_text())
+    assert "ragged-dot" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
 # -- the counter ------------------------------------------------------------
 
 class _Stream:
