@@ -501,6 +501,89 @@ class PageAllocator:
                     "bytes_in_use": used * self.page_bytes}
 
 
+class WindowTable:
+    """The block table of the SECOND page class: the pages of layers
+    that attend a window of the context (``LMConfig.windows``).
+
+    A global layer's page lives as long as its session; a window
+    layer's only until every position in it lies behind the window.
+    So this class has an allocator of its own (over pools of its own,
+    with a garbage page 0 of their own) and a table that MOVES:
+    :meth:`cover` is told the positions a slot's next program reaches
+    (``first..last``), gives back the pages wholly behind ``first``
+    (their entries read 0 again) and takes fresh ones up to ``last``'s.
+    A slot therefore holds at most ``window // page + 2`` pages while it
+    decodes (the window, the page its head lies in, the page being
+    written), and one span more while its prompt is filled.
+
+    Giving a page back while a program that reads it is still queued
+    is safe for the reason a finished session's pages are: the device
+    runs programs in order, and whoever gets the page next writes it in
+    a later one.  Each page's generation is kept beside its id, so a
+    test can show that no entry outlives its page."""
+
+    def __init__(self, alloc: PageAllocator, slots: int, pps: int):
+        import numpy as np      # not at import: the transport plane's
+        #                         importers of this module stay cheap
+        self.alloc = alloc
+        self.page = alloc.page_tokens
+        self.bt = np.zeros((slots, pps), np.int32)
+        self.gen = np.zeros((slots, pps), np.int64)
+        # entries lo[s] <= i < hi[s] of row s are live
+        self.lo = [0] * slots
+        self.hi = [0] * slots
+        self.released = 0
+
+    def held(self, slot: int) -> int:
+        return self.hi[slot] - self.lo[slot]
+
+    def cover(self, slot: int, first: int, last: int) -> bool:
+        """Make row ``slot`` hold exactly the pages of positions
+        ``first..last`` and what it already holds between them.  False
+        (and nothing taken) where the pool cannot cover it."""
+        lo, hi = first // self.page, last // self.page + 1
+        row, held_lo, held_hi = self.bt[slot], self.lo[slot], self.hi[slot]
+        for i in range(held_lo, min(lo, held_hi)):   # wholly behind first
+            self.alloc.release(int(row[i]))
+            row[i] = 0
+            self.released += 1
+        held_lo = max(held_lo, min(lo, held_hi))
+        if held_hi <= held_lo:               # nothing held: start at lo
+            held_lo = held_hi = lo
+        ok = True
+        if hi > held_hi:
+            pages = self.alloc.alloc(hi - held_hi)
+            ok = pages is not None
+            if ok:
+                row[held_hi:hi] = pages
+                self.gen[slot, held_hi:hi] = [self.alloc.gen_of(p)
+                                              for p in pages]
+                held_hi = hi
+        self.lo[slot], self.hi[slot] = held_lo, held_hi
+        return ok
+
+    def release_slot(self, slot: int) -> None:
+        """The slot's session is gone: every page back (not counted as
+        ``released``: that counts what the window let go of)."""
+        row = self.bt[slot]
+        for i in range(self.lo[slot], self.hi[slot]):
+            self.alloc.release(int(row[i]))
+        row[:] = 0
+        self.lo[slot] = self.hi[slot] = 0
+
+    def check(self, slot: int, first: int, last: int) -> None:
+        """Raise unless every page of positions ``first..last`` is held
+        by this row at the generation it was taken at."""
+        for i in range(first // self.page, last // self.page + 1):
+            p = int(self.bt[slot, i])
+            if not (self.lo[slot] <= i < self.hi[slot]) or p <= 0 \
+                    or self.alloc.refcount(p) <= 0 \
+                    or self.alloc.gen_of(p) != self.gen[slot, i]:
+                raise KvPageError(
+                    f"window page {p} (slot {slot}, entry {i}) read "
+                    "after release")
+
+
 class _PrefixNode:
     __slots__ = ("digest", "page", "gen", "children", "parent", "tick")
 

@@ -373,6 +373,26 @@ class ContinuousBatcher:
     options that would park, catch up, import or speculate are refused
     at construction (:class:`UnsupportedBlock`).
 
+    **Two page classes** (a schedule with window layers,
+    ``LMConfig.windows``): a global layer's pages live as long as their
+    session, as above; a window layer's are a class of their own
+    (:class:`~brpc_tpu.kv.pages.WindowTable`: pools, allocator and block
+    table apart), taken as the context grows and GIVEN BACK once every
+    position in them lies behind the window, so a session holds
+    ``window // page + 2`` of them however long it runs.  The step is
+    handed both tables; before a step that writes a slot's position
+    ``p`` the slot's row is made to cover ``p - window + 1 .. p``
+    (inside ``page_alloc``).  Such a model's prompt is not prefilled
+    into a ``max_seq`` cache and inserted: it is written straight into
+    the pages in spans of ``LMConfig.fill_span`` rows
+    (``make_paged_span_fill``, one program whatever the prompt's
+    length, each span a ``prefill_dispatch``), all of them at
+    admission, between two steps.  The prefix cache declines it (a
+    page it aliased would have to know its class), as do park/resume,
+    slices, speculation and KV import (refused at construction).
+    ``kv_stats()["window"]`` counts pages held against what whole
+    contexts would hold, over steps, and pages given back.
+
     **SLO-tiered scheduling** (ROADMAP item 4): the step loop is a
     latency-SLO scheduler over three per-tenant tiers resolved from
     the TLV-22 identity via a :class:`TierRegistry`:
@@ -504,6 +524,13 @@ class ContinuousBatcher:
         # (both summed over layers and steps), the most rows one took
         self._moe = {"steps": 0, "rows": 0, "local_pairs": 0,
                      "experts_touched": 0, "max_load": 0}
+        # the window class (kv.pages.WindowTable, built with the
+        # engine): pages its sessions held at each step against what
+        # their whole contexts would hold, summed over steps
+        self._wt = None
+        self._span_fill = None
+        self._win_held_steps = 0
+        self._win_whole_steps = 0
         # the allocator triple (built in _ensure_engine)
         self._alloc = None                        # kv.pages.PageAllocator
         self._prefix = None                       # kv.pages.PrefixCache
@@ -628,6 +655,16 @@ class ContinuousBatcher:
             out["moe"] = {"layers": len(cfg.expert_layers()),
                           "held": hi - lo, "routed": cfg.experts_routed,
                           "top_k": cfg.experts_top_k, **self._moe}
+        if cfg.has_window:
+            wt = self._wt
+            out["window"] = {
+                "layers": len(cfg.window_layers()), "window": cfg.window,
+                "pages": cfg.window_pages(self.slots, self.page),
+                "pages_held": self._win_held_steps,
+                "pages_whole": self._win_whole_steps,
+                "released": wt.released if wt is not None else 0}
+            if wt is not None:
+                out["window"]["alloc"] = wt.alloc.stats()
         if cfg.has_latent:
             # one row a token and layer, key and value at once
             out["latent"] = {"row_bytes": cfg.latent_row() * 4,
@@ -654,10 +691,11 @@ class ContinuousBatcher:
         import jax.numpy as jnp
 
         from ..kv.pages import (HostPagePool, PageAllocator,
-                                PrefixCache)
+                                PrefixCache, WindowTable)
         from .transformer_lm import (empty_paged_cache, jit_with_params,
                                      make_paged_io,
                                      make_paged_batch_decode,
+                                     make_paged_span_fill,
                                      make_paged_spec_verify,
                                      paged_page_bytes)
 
@@ -695,6 +733,12 @@ class ContinuousBatcher:
                 chunk_prefill, self.params, donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
             self._settok_j = jax.jit(_settok)
+            if self.cfg.has_window:
+                # a window schedule's prompts go into the pages in
+                # spans of one shape (prefill and insert decline it)
+                self._span_fill = jit_with_params(
+                    make_paged_span_fill(self.cfg, self.page),
+                    self.params, donate_argnums=(0,))
             if self.spec_k > 0:
                 # draft engine: the SMALL model runs k cheap steps per
                 # round over a page pool of its own, addressed through
@@ -732,6 +776,12 @@ class ContinuousBatcher:
         if self._alloc is None:
             pb = paged_page_bytes(self.cfg, self.page)
             self._alloc = PageAllocator(self.num_pages, self.page, pb)
+            if self.cfg.has_window:
+                self._wt = WindowTable(PageAllocator(
+                    self.cfg.window_pages(self.slots, self.page),
+                    self.page, paged_page_bytes(self.cfg, self.page,
+                                                window_class=True)),
+                    self.slots, self._pps)
             # a catch-up slice is the first block's only: grouped
             # heads without state layers serve with no prefix cache; a
             # model with state layers keeps one that declines, counted
@@ -845,7 +895,6 @@ class ContinuousBatcher:
         # arrived as pages and insert the same way.
         import jax.numpy as jnp
 
-        from ..kv.pages import count_evict
         ph = self._clock.switch
         imported = sess.cache1 is not None
         if imported:
@@ -865,13 +914,7 @@ class ContinuousBatcher:
         priv, why = self._alloc_with_reclaim(n_total - len(aliased),
                                              rank=sess.tier_rank)
         if priv is None:
-            ph(PH_EVICT)
-            for p in aliased:
-                self._alloc.release(p)
-            count_evict(why)
-            if not sess.stream.closed:
-                sess.stream.close(reason=why)
-            self._finalize_obs(sess, why)
+            self._refuse(sess, why, aliased)
             return
         # free = unOCCUPIED, not merely inactive: a chunk-filling
         # session holds its slot while _active is still False
@@ -897,6 +940,15 @@ class ContinuousBatcher:
             # identical values), no prefill and ZERO copies.  (With
             # state layers even an empty context takes the next branch:
             # its insert is what clears the slot's state.)
+            last = int(sess.prompt[-1])
+            start_len = ctx_len
+        elif self.cfg.has_window:
+            if not self._fill_spans(sess, free, row, ctx_len):
+                self._wt.release_slot(free)
+                self._refuse(sess, "kv_pool_exhausted", priv)
+                return
+            self.prefills_run += 1
+            ph(PH_INSERT_DISPATCH)
             last = int(sess.prompt[-1])
             start_len = ctx_len
         elif covered == 0 and not self.chunk_budget:
@@ -953,6 +1005,66 @@ class ContinuousBatcher:
         self._active[free] = True
         if self.spec_k > 0:
             self._draft_admit(sess)
+
+    def _refuse(self, sess: _Session, why: str, pages) -> None:
+        """An admission the pool cannot cover: the pages it held go
+        back and its stream closes under the NAMED reason."""
+        from ..kv.pages import count_evict
+        self._clock.switch(PH_EVICT)
+        self._alloc.release_all(pages)
+        count_evict(why)
+        if not sess.stream.closed:
+            sess.stream.close(reason=why)
+        self._finalize_obs(sess, why)
+
+    def _fill_spans(self, sess: _Session, slot: int, row, ctx_len: int
+                    ) -> bool:
+        """A window schedule's prompt, written into the slot's pages in
+        spans (``make_paged_span_fill``), all queued here: each span a
+        ``prefill_dispatch`` of its own, the window class's pages taken
+        and given back around it inside ``page_alloc``.  False where
+        the window class ran out of pages."""
+        import jax.numpy as jnp
+        ph = self._clock.switch
+        w, win, wt = self.cfg.fill_span, self.cfg.window, self._wt
+        ctx = sess.prompt[:-1]
+        row_d = jnp.asarray(row)
+        for start in range(0, ctx_len, w):
+            n = min(w, ctx_len - start)
+            ph(PH_PAGE_ALLOC)
+            if not wt.cover(slot, max(0, start - win + 1), start + n - 1):
+                return False
+            ph(PH_PREFILL_DISPATCH)
+            ids = np.zeros((w,), np.int32)
+            ids[:n] = ctx[start:start + n]
+            # (a private copy of the row: the next span's ``cover``
+            # changes it under a program that may not have read it yet)
+            self._cache = self._span_fill(
+                self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
+                np.int32(slot), np.int32(start), np.int32(n), ids)
+        return True
+
+    def _cover_windows(self) -> bool:
+        """Before a step is queued: every active slot's row of the
+        window class covers the window of the position the step writes
+        (pages wholly behind it go back), and the step's share of
+        ``kv_stats()["window"]`` is counted.  A page boundary a slot
+        crosses every ``page`` steps; between them nothing moves."""
+        wt, win, page = self._wt, self.cfg.window, self.page
+        held = whole = 0
+        for slot, sess in self._sessions.items():
+            if not self._active[slot]:
+                continue
+            p = min(sess.ctx_len + sess.queued, self.cfg.max_seq - 1)
+            if p // page >= wt.hi[slot] or \
+                    max(0, p - win + 1) // page > wt.lo[slot]:
+                if not wt.cover(slot, max(0, p - win + 1), p):
+                    return False
+            held += wt.held(slot)
+            whole += p // page + 1
+        self._win_held_steps += held
+        self._win_whole_steps += whole
+        return True
 
     def _spill_one(self, min_rank: int = 0) -> Optional[str]:
         """Park ONE live session's private pages in the host tier.
@@ -1317,8 +1429,11 @@ class ContinuousBatcher:
             self._active_up = self._active.copy()
             self._active_d = jnp.asarray(self._active_up)
             self._uploads += 1
-        if not np.array_equal(self._bt, self._bt_up):
-            self._bt_up = self._bt.copy()
+        # (a window schedule's step takes both classes' tables as one)
+        bt = self._bt if self._wt is None \
+            else np.stack([self._bt, self._wt.bt])
+        if not np.array_equal(bt, self._bt_up):
+            self._bt_up = bt.copy()
             self._bt_d = jnp.asarray(self._bt_up)
             self._uploads += 1
         return self._bt_d, self._tokens_d, self._active_d
@@ -1331,6 +1446,12 @@ class ContinuousBatcher:
         ``(slot, start, n, ids)``."""
         import jax.numpy as jnp
         ph = self._clock.switch
+        if self._wt is not None:
+            ph(PH_PAGE_ALLOC)
+            if not self._cover_windows():
+                # cannot be where the pool is ``LMConfig.window_pages``
+                raise RuntimeError("the window page class ran dry "
+                                   "under live sessions")
         ph(PH_STEP_DISPATCH)
         counts = None
         if ride is not None:
@@ -1389,6 +1510,9 @@ class ContinuousBatcher:
         ph(PH_TOKEN_WALK)
         pairs, finished = [], []
         last, pages_read = self.cfg.max_seq - 1, 0
+        # (a window layer reads the window's pages: the mean over the
+        # schedule's layers stands for the slot)
+        win, n_win = self.cfg.window, len(self.cfg.window_layers())
         for slot, sess in flight.snap:
             if self._sessions.get(slot) is not sess:
                 # evicted with this step in flight (its client hung
@@ -1398,13 +1522,16 @@ class ContinuousBatcher:
             tok = int(toks[slot])
             self._tokens[slot] = tok
             # the step attended over positions 0..ctx_len + sent
-            pages_read += min(sess.ctx_len + sess.sent, last) \
-                // self.page + 1
+            p = min(sess.ctx_len + sess.sent, last)
+            whole = p // self.page + 1
+            in_window = whole - max(0, p - win + 1) // self.page
+            pages_read += whole + (in_window - whole) * n_win \
+                / self.cfg.depth
             sess.sent += 1
             pairs.append((sess, tok))
             if sess.sent >= sess.max_new:
                 finished.append(sess)
-        self._attn_pages_read += pages_read
+        self._attn_pages_read += int(round(pages_read))
         self._attn_pages_table += len(pairs) * self._pps
         self._deliver(pairs, finished)
         return outer
@@ -1527,6 +1654,8 @@ class ContinuousBatcher:
             self._alloc.release_all(sess.pages)
             sess.pages = []
             self._bt[sess.slot] = 0
+        if self._wt is not None:
+            self._wt.release_slot(sess.slot)
         if not sess.stream.closed:
             sess.stream.close(reason=reason or "finished")
         self._finalize_obs(sess, reason or "finished")
@@ -1671,6 +1800,7 @@ class ContinuousBatcher:
                 self._d_cache = None   # the draft pool donated too
                 self._bt[:] = 0
                 self._alloc = None
+                self._wt = None
                 self._prefix = None
                 self._host = None
                 self._thread = None
@@ -1853,6 +1983,12 @@ class LMService(Service):
             fp += (f":{c.kv_heads}:{int(c.rope)}:{c.ffn}:{c.ffn_dim}:"
                    f"{c.schedule()}:"
                    f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
+        if c.has_window or c.parallel_block:
+            fp += (f":{c.head_dim}:{c.norm}:{int(c.parallel_block)}:"
+                   f"{c.window}:" + "".join(
+                       "gw"[bool(w)] for w in c.windows) + ":"
+                   + "".join("nr"[r] for r in c.ropes)
+                   + f":{c.rope_pairs}:{c.rope_theta}")
         if c.has_latent or c.has_experts:
             fp += (f":{c.q_lora_rank}x{c.kv_lora_rank}x{c.qk_nope_dim}x"
                    f"{c.qk_rope_dim}x{c.v_head_dim}:{c.ffn_schedule()}:"
@@ -1917,6 +2053,12 @@ class LMService(Service):
                 state_pool={"slots": self.decode_slots,
                             "bytes": self.decode_slots
                             * state_slot_bytes(c)})
+        if c.has_window:
+            # two page classes: whole contexts, and windows
+            info["window_pool"] = {
+                "layers": list(c.window_layers()), "window": c.window,
+                "pages": c.window_pages(self.decode_slots, self.page),
+                "fill_span": c.fill_span}
         if c.has_latent:
             # one pool a latent layer, a row a token: key and value
             info["latent_pool"] = {

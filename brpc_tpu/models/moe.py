@@ -192,7 +192,8 @@ def forward_grouped(params: Dict[str, Any], x, cfg: MoEConfig
 class ExpertConfig:
     def __init__(self, dim: int, hidden: int, routed: int,
                  held: Tuple[int, int], top_k: int,
-                 route_scale: float = 1.0, shared: int = 0):
+                 route_scale: float = 1.0, shared: int = 0,
+                 bias: bool = True, shared_scale: float = 1.0):
         assert 0 <= held[0] < held[1] <= routed and 1 <= top_k <= routed
         self.dim, self.hidden, self.routed = dim, hidden, routed
         self.held = (int(held[0]), int(held[1]))
@@ -200,6 +201,11 @@ class ExpertConfig:
         self.top_k = top_k
         self.route_scale = route_scale
         self.shared = shared
+        # a router that chooses by its scores alone has no ``bias``
+        # leaf; shared experts that are AVERAGED are the one gated MLP
+        # of ``shared * hidden`` times ``shared_scale`` = 1 / shared
+        self.bias = bool(bias)
+        self.shared_scale = float(shared_scale)
 
     def buffer_rows(self, rows: int) -> int:
         """The sorted buffer's rows for ``rows`` tokens: a token's
@@ -223,9 +229,11 @@ def init_served(rng, cfg: ExpertConfig) -> Dict[str, Any]:
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
     p = {"router": normal(ks[0], (d, cfg.routed), d),
-         "bias": jax.random.normal(ks[1], (cfg.routed,), jnp.float32) * 0.1,
          "w1": normal(ks[2], (n, d, 2 * e), d),
          "w2": normal(ks[3], (n, e, d), e)}
+    if cfg.bias:
+        p["bias"] = jax.random.normal(ks[1], (cfg.routed,),
+                                      jnp.float32) * 0.1
     if cfg.shared:
         p["ws1"] = normal(ks[4], (d, 2 * cfg.shared * e), d)
         p["ws2"] = normal(ks[5], (cfg.shared * e, d), cfg.shared * e)
@@ -244,7 +252,8 @@ def route(p: Dict[str, Any], t, cfg: ExpertConfig) -> Tuple[Any, Any]:
     sc = jax.nn.sigmoid(jnp.dot(
         t.astype(jnp.float32), p["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _top, ids = jax.lax.top_k(sc + p["bias"], cfg.top_k)
+    _top, ids = jax.lax.top_k(sc + p["bias"] if cfg.bias else sc,
+                              cfg.top_k)
     w = jnp.take_along_axis(sc, ids, axis=-1)
     w = w / (w.sum(axis=-1, keepdims=True) + 1e-20) * cfg.route_scale
     return ids, w
@@ -297,7 +306,9 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
         * w.reshape(T * k)[order][:, None]
     out = jnp.zeros((T, cfg.dim), jnp.float32).at[tok].add(ys)
     if cfg.shared:
-        out = out + _gated(t, p["ws1"], p["ws2"])
+        shared = _gated(t, p["ws1"], p["ws2"])
+        out = out + (shared if cfg.shared_scale == 1.0
+                     else shared * cfg.shared_scale)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()]
                        ).astype(jnp.int32)
     return out, counts

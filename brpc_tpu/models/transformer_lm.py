@@ -53,10 +53,15 @@ class LMConfig:
                  expert_dim: Optional[int] = None, experts_routed: int = 0,
                  experts_held: Optional[Tuple[int, int]] = None,
                  experts_top_k: int = 1, route_scale: float = 1.0,
-                 shared_experts: int = 0):
-        assert dim % heads == 0
-        assert not rope or (dim // heads) % 2 == 0, \
-            "head dim must be even for RoPE"
+                 shared_experts: int = 0, head_dim: Optional[int] = None,
+                 norm: str = "rms", parallel_block: bool = False,
+                 windows: Optional[Tuple[int, ...]] = None,
+                 ropes: Optional[Tuple[bool, ...]] = None,
+                 rope_pairs: str = "halves", router_bias: bool = True,
+                 shared_average: bool = False, fill_span: int = 1024):
+        assert head_dim is not None or dim % heads == 0
+        hd = dim // heads if head_dim is None else int(head_dim)
+        assert not rope or hd % 2 == 0, "head dim must be even for RoPE"
         self.vocab = vocab
         self.dim = dim
         self.heads = heads
@@ -73,8 +78,41 @@ class LMConfig:
         # name (UnsupportedBlock)
         self.kv_heads = heads if kv_heads is None else int(kv_heads)
         assert heads % self.kv_heads == 0
-        self.head_dim = dim // heads
+        # a head's size is its own where it is given (heads x head_dim
+        # need not be dim: ``wo`` maps it back)
+        self.head_dim = hd
         self.rope = bool(rope)
+        # a per-layer schedule of attention's reach and of its rotation:
+        # ``windows[i]`` > 0 lets position p of layer i attend p -
+        # windows[i] < j <= p only (0: every j <= p), ``ropes[i]`` says
+        # whether layer i rotates q and k at all.  Window layers' pages
+        # are a class of their own (``kv.pages.WindowTable``): given
+        # back once every position in them lies behind the window.
+        # ``rope_pairs`` "halves" rotates column i with i + hd/2,
+        # "interleaved" 2i with 2i + 1
+        self.windows = (0,) * depth if windows is None \
+            else tuple(int(w) for w in windows)
+        assert len(self.windows) == depth and min(self.windows) >= 0
+        self.has_window = any(self.windows)
+        self.window = max(self.windows)
+        assert set(self.windows) <= {0, self.window}, \
+            "window layers share one window (one page class)"
+        self.ropes = (self.rope,) * depth if ropes is None \
+            else tuple(bool(r) for r in ropes)
+        assert len(self.ropes) == depth
+        if ropes is not None:
+            self.rope = any(self.ropes)
+        assert rope_pairs in ("halves", "interleaved")
+        self.rope_pairs = rope_pairs
+        # "rms", or "layer": the mean subtracted, a gain and no bias
+        assert norm in ("rms", "layer")
+        self.norm = norm
+        # attention and feed-forward both read ONE norm of the layer's
+        # input and both add to it: x + A(h) + F(h), h = norm(x)
+        self.parallel_block = bool(parallel_block)
+        # a prompt of a window schedule is filled in spans of this many
+        # rows (``make_paged_span_fill``), never as one bucket
+        self.fill_span = int(fill_span)
         assert ffn in ("gelu", "gated_silu")
         self.ffn = ffn
         self.ffn_dim = dim * mlp_mult if ffn_dim is None else int(ffn_dim)
@@ -121,15 +159,28 @@ class LMConfig:
         self.experts_top_k = int(experts_top_k)
         self.route_scale = float(route_scale)
         self.shared_experts = int(shared_experts)
+        # a router without a correction bias (its leaf is absent, not a
+        # zero that is added), shared experts averaged and not summed
+        self.router_bias = bool(router_bias)
+        self.shared_average = bool(shared_average)
         if self.has_experts:
             lo, hi = self.experts_held
             assert expert_dim and 0 <= lo < hi <= self.experts_routed \
                 and 1 <= self.experts_top_k <= self.experts_routed
             if set(m for m, f in zip(self.mixers, self.ffns)
-                   if f == "experts") != {"mla"}:
+                   if f == "experts") - {"mla", "attn"}:
                 raise UnsupportedBlock(
                     "an expert feed-forward layer is served beside an "
-                    "'mla' mixer only")
+                    "'mla' or an 'attn' mixer only")
+        if self.has_window or self.parallel_block:
+            if set(self.mixers) != {"attn"}:
+                raise UnsupportedBlock(
+                    "window layers and the parallel block are served "
+                    "for a schedule of 'attn' mixers only")
+            if self.has_window and self.kv_heads == heads:
+                raise UnsupportedBlock(
+                    "window layers are served over the grouped page "
+                    "layout only (kv_heads < heads)")
         self.ssm_inner = int(ssm_expand) * dim
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
@@ -168,7 +219,22 @@ class LMConfig:
                 and self.kv_heads == self.heads
                 and self.rope and self.ffn == "gelu"
                 and self.ffn_dim == self.dim * self.mlp_mult
-                and not self.tie_embed and not self.final_norm)
+                and not self.tie_embed and not self.final_norm
+                and self.head_dim * self.heads == self.dim
+                and not self.has_window and all(self.ropes)
+                and self.rope_pairs == "halves" and self.norm == "rms"
+                and not self.parallel_block
+                and self.rope_theta == 10000.0)
+
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, w in enumerate(self.windows) if w)
+
+    def window_pages(self, slots: int, page: int) -> int:
+        """Pages of the window class for ``slots`` sessions: the window
+        and a page on either side of it for each, one span of a fill in
+        flight, and the garbage page."""
+        return slots * (self.window // page + 2) \
+            + -(-self.fill_span // page) + 1
 
     def schedule(self) -> str:
         """The mixers' initials in layer order (``"sass"``)."""
@@ -208,7 +274,9 @@ class LMConfig:
             dim=self.dim, hidden=self.expert_dim,
             routed=self.experts_routed, held=self.experts_held,
             top_k=self.experts_top_k, route_scale=self.route_scale,
-            shared=self.shared_experts)
+            shared=self.shared_experts, bias=self.router_bias,
+            shared_scale=1.0 / self.shared_experts
+            if self.shared_average else 1.0)
 
     def moe_cfg(self):
         from .moe import MoEConfig
@@ -234,6 +302,8 @@ def require_plain_block(cfg: LMConfig, what: str) -> None:
     why = "state layers (per-sequence recurrent state)" if cfg.has_state \
         else "latent attention (an 'mla' mixer and its latent cache)" \
         if cfg.has_latent \
+        else "window layers (a page class that gives pages back)" \
+        if cfg.has_window \
         else "a block other than MHA + rotary + GELU MLP + untied table"
     raise UnsupportedBlock(
         f"{what} declines {why}: only the paged serving factories "
@@ -318,7 +388,8 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
                 bk[0], (d, (cfg.heads + 2 * cfg.kv_heads) * hd), d),
                 "wo": normal(bk[1], (cfg.heads * hd, d), cfg.heads * hd)}
         blk["ln1"] = jnp.ones((d,), jnp.float32)
-        blk["ln2"] = jnp.ones((d,), jnp.float32)
+        if not cfg.parallel_block:
+            blk["ln2"] = jnp.ones((d,), jnp.float32)
         if cfg.ffns[i] == "experts":
             blk["moe"] = moe.init_served(bk[2], cfg.expert_cfg())
         else:
@@ -353,6 +424,17 @@ def _rmsnorm(x, g, eps: float = 1e-6):
     return x * g / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
 
 
+def _norm(cfg: "LMConfig", x, g):
+    """The block's norm: :func:`_rmsnorm`, or the mean-subtracting one
+    (a gain, no bias) where ``cfg.norm`` says ``"layer"``."""
+    import jax.numpy as jnp
+    if cfg.norm == "rms":
+        return _rmsnorm(x, g, cfg.norm_eps)
+    c = x - jnp.mean(x, axis=-1, keepdims=True)
+    return c * g / jnp.sqrt(jnp.mean(c * c, axis=-1, keepdims=True)
+                            + cfg.norm_eps)
+
+
 def _rope_tables(seq: int, head_dim: int):
     """sin/cos tables for rotary embedding, shaped (1, s, 1, d/2).
     Built once per forward and passed into every block so remat regions
@@ -373,6 +455,16 @@ def _rope(x, sin, cos):
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], axis=-1)
+
+
+def _rope_pairs(x, sin, cos):
+    """:func:`_rope` with the pairs interleaved: column ``2i`` turns
+    with ``2i + 1``."""
+    import jax.numpy as jnp
+    p = x.reshape(x.shape[:-1] + (x.shape[-1] // 2, 2))
+    a, b = p[..., 0], p[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape)
 
 
 def _split_qkv(cfg: LMConfig, qkv):
@@ -410,23 +502,30 @@ def _ffn(cfg: LMConfig, bp, h):
 def _ffn_residual(cfg: LMConfig, bp, x):
     """The second half of a serving layer, after either mixer: norm,
     :func:`_ffn`, residual."""
-    return x + _ffn(cfg, bp, _rmsnorm(x, bp["ln2"], cfg.norm_eps))
+    return x + _ffn(cfg, bp, _norm(cfg, x, bp["ln2"]))
+
+
+def _ffn_part(cfg: LMConfig, i: int, bp, h, live):
+    """Layer ``i``'s feed-forward by the schedule (``LMConfig.ffns``)
+    for normed rows ``h`` ``(b, w, dim)``: :func:`_ffn`, or the expert
+    layer (``moe.serve``) over the rows that are ``live`` ``(b, w)``.
+    Returns ``(out, counts)``, ``counts`` the expert layer's or None."""
+    if cfg.ffns[i] != "experts":
+        return _ffn(cfg, bp, h), None
+    from . import moe
+    b, w, d = h.shape
+    out, counts = moe.serve(bp["moe"], h.reshape(b * w, d),
+                            cfg.expert_cfg(), live.reshape(b * w))
+    return out.reshape(b, w, d), counts
 
 
 def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live):
-    """Layer ``i``'s second half by the feed-forward schedule
-    (``LMConfig.ffns``): :func:`_ffn_residual`, or norm, the expert
-    layer (``moe.serve``) over the rows that are ``live``, residual.
-    ``x`` and ``live`` are ``(b, w, dim)`` and ``(b, w)``.  Returns
-    ``(x, counts)``, ``counts`` the expert layer's or None."""
+    """Layer ``i``'s second half: norm, :func:`_ffn_part`, residual.
+    Returns ``(x, counts)``."""
     if cfg.ffns[i] != "experts":
         return _ffn_residual(cfg, bp, x), None
-    from . import moe
-    b, w, d = x.shape
-    out, counts = moe.serve(
-        bp["moe"], _rmsnorm(x, bp["ln2"], cfg.norm_eps).reshape(b * w, d),
-        cfg.expert_cfg(), live.reshape(b * w))
-    return x + out.reshape(b, w, d), counts
+    out, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln2"]), live)
+    return x + out, counts
 
 
 def _embed_rows(params, ids):
@@ -454,7 +553,7 @@ def _rope_at(cfg: LMConfig, pos):
     if not cfg.rope:
         return None
     half = cfg.head_dim // 2
-    freq = jnp.exp(-math.log(10000.0)
+    freq = jnp.exp(-math.log(cfg.rope_theta)
                    * jnp.arange(half, dtype=jnp.float32) / half)
     ang = jnp.asarray(pos).astype(jnp.float32)[..., None, None] * freq
     return jnp.sin(ang), jnp.cos(ang)
@@ -469,26 +568,35 @@ def _qkv(cfg: LMConfig, bp, x, rot):
     reaches the cached ones) is its own."""
     from ..ops.quant import qmatmul
     b, w, _ = x.shape
-    q, k, v = _split_qkv(cfg, qmatmul(
-        _rmsnorm(x, bp["ln1"], cfg.norm_eps), bp["wqkv"]))
+    q, k, v = _split_qkv(cfg, qmatmul(_norm(cfg, x, bp["ln1"]),
+                                      bp["wqkv"]))
     q = q.reshape(b, w, cfg.heads, cfg.head_dim)
     k = k.reshape(b, w, cfg.kv_heads, cfg.head_dim)
     v = v.reshape(b, w, cfg.kv_heads, cfg.head_dim)
     if rot is not None:
-        q = _rope(q, *rot)
-        k = _rope(k, *rot)
+        turn = _rope if cfg.rope_pairs == "halves" else _rope_pairs
+        q = turn(q, *rot)
+        k = turn(k, *rot)
     return q, k, v
 
 
-def _attn_out(cfg: LMConfig, bp, x, att):
-    """The second half of a serving attention layer: the attended
+def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None):
+    """The second half of a serving attention layer ``i``: the attended
     values ``att`` (``(b, w, heads, hd)``, or a step's ``(b, heads,
     hd)`` as its kernel returns them) through ``wo``, residual, then
-    :func:`_ffn_residual`."""
+    the layer's feed-forward (:func:`_ffn_scheduled`; ``live`` ``(b,
+    w)`` are the rows an expert layer routes).  In the parallel block
+    the feed-forward reads the norm attention read, and both add to
+    the layer's input.  Returns ``(x, counts)``, ``counts`` an expert
+    layer's or None."""
     from ..ops.quant import qmatmul
     b, w, _ = x.shape
-    x = x + qmatmul(att.reshape(b, w, cfg.heads * cfg.head_dim), bp["wo"])
-    return _ffn_residual(cfg, bp, x)
+    a = qmatmul(att.reshape(b, w, cfg.heads * cfg.head_dim), bp["wo"])
+    if cfg.parallel_block:
+        # the same norm :func:`_qkv` took: one in the compiled program
+        m, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln1"]), live)
+        return x + a + m, counts
+    return _ffn_scheduled(cfg, i, bp, x + a, live)
 
 
 def _logits(cfg: LMConfig, params, x):
@@ -496,7 +604,7 @@ def _logits(cfg: LMConfig, params, x):
     embedding table itself where it is tied."""
     from ..ops.quant import qmatmul
     if cfg.final_norm:
-        x = _rmsnorm(x, params["norm_f"], cfg.norm_eps)
+        x = _norm(cfg, x, params["norm_f"])
     return qmatmul(x, params["embed"].T if cfg.tie_embed
                    else params["unembed"])
 
@@ -576,10 +684,11 @@ def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
     return forward
 
 
-def _prefill_attn_layer(cfg: LMConfig, bp, x, rot):
-    """One attention layer of prompt processing, the one home of every
+def _prefill_attn_layer(cfg: LMConfig, bp, x, rot, i: int = 0, live=None):
+    """Attention layer ``i`` of prompt processing, the one home of every
     serving prefill's: returns (x, k, v) with k/v (``kv_heads`` of
-    them) written into fresh max_seq caches."""
+    them) written into fresh max_seq caches.  ``live`` ``(b, s)`` are
+    the rows an expert layer routes (a bucket's padding is not)."""
     import jax
     import jax.numpy as jnp
 
@@ -600,7 +709,7 @@ def _prefill_attn_layer(cfg: LMConfig, bp, x, rot):
     from ..ops.flash_attention import attention
     impl = "flash" if cfg.use_flash else cfg.attn_impl
     att = attention(q, k, v, causal=cfg.causal, impl=impl)
-    return _attn_out(cfg, bp, x, att), kc, vc
+    return _attn_out(cfg, bp, x, att, i, live)[0], kc, vc
 
 
 def make_prefill(cfg: LMConfig):
@@ -615,6 +724,14 @@ def make_prefill(cfg: LMConfig):
     import jax.numpy as jnp
 
     from . import mla_mixer, ssm_mixer
+
+    if cfg.has_window:
+        def declined(*_a, **_k):
+            raise UnsupportedBlock(
+                "make_prefill (a whole prompt into a max_seq cache) "
+                "declines window layers: their prompts are filled in "
+                "spans through the pages (make_paged_span_fill)")
+        return declined
 
     def prefill(params, ids, ctx_len):
         b, s = ids.shape
@@ -637,7 +754,9 @@ def make_prefill(cfg: LMConfig):
                 x, _counts = _ffn_scheduled(
                     cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
             else:
-                x, kc, vc = _prefill_attn_layer(cfg, bp, x, rot)
+                x, kc, vc = _prefill_attn_layer(
+                    cfg, bp, x, rot if cfg.ropes[i] else None, i,
+                    (jnp.arange(s) < ctx_len)[None])
                 cache[f"k{i}"], cache[f"v{i}"] = kc, vc
         last = jnp.take(x, jnp.maximum(ctx_len - 1, 0), axis=1)
         return cache, _logits(cfg, params, last)
@@ -683,7 +802,7 @@ def make_decode(cfg: LMConfig):
         p = jax.nn.softmax(s_mat, axis=-1)
         att = jnp.einsum("bhqk,bkhd->bqhd", p, vc,
                          preferred_element_type=jnp.float32)
-        return _attn_out(cfg, bp, x, att), kc, vc
+        return _attn_out(cfg, bp, x, att)[0], kc, vc
 
     def prefill(params, ids):
         b, s = ids.shape
@@ -887,10 +1006,11 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
 
-    def attend(q, k, v, pk, pv, bt, pos, att_pos):
+    def attend(q, k, v, pk, pv, bt, pos, att_pos, window=0):
         """The step's own half of an attention layer, one token per
         slot (``q``/``k``/``v`` ``(b, 1, heads, hd)``), block-table
-        addressing: write the row, attend over the live pages."""
+        addressing: write the row, attend over the live pages (of a
+        window layer: those the window reaches)."""
         b = q.shape[0]
         # scatter this step's row into each slot's CURRENT page
         page_idx = bt[jnp.arange(b), pos // page]
@@ -908,15 +1028,21 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         # ``att_pos`` are garbage and are never admitted); an inactive
         # slot's output is discarded, so it reads one page, not the
         # ``len`` its last session left behind
-        att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
-                                        page)
+        if cfg.has_window:
+            att = paged_attention.window_attention(
+                q[:, 0], pk, pv, bt, att_pos, page, window)
+        else:
+            att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
+                                            page)
         return att, pk, pv
 
-    def attn_layer(bp, x, pk, pv, bt, pos, att_pos, rot):
-        """One attention layer, one token per slot."""
-        q, k, v = _qkv(cfg, bp, x, rot)
-        att, pk, pv = attend(q, k, v, pk, pv, bt, pos, att_pos)
-        return _attn_out(cfg, bp, x, att), pk, pv
+    def attn_layer(i, bp, x, pk, pv, bt, pos, att_pos, rot, active):
+        """Attention layer ``i``, one token per slot."""
+        q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
+        att, pk, pv = attend(q, k, v, pk, pv, bt, pos, att_pos,
+                             cfg.windows[i])
+        x, cnt = _attn_out(cfg, bp, x, att, i, active[:, None])
+        return x, pk, pv, cnt
 
     def step(params, cache, bt, token, active):
         cache = dict(cache)
@@ -924,6 +1050,9 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         att_pos = jnp.where(active, pos, 0)
         x = _embed_rows(params, token)[:, None, :]
         rot = _rope_at(cfg, pos[:, None])
+        # a window schedule has a block table a page class: ``bt`` is
+        # ``(2, slots, max_seq // page)``, whole contexts then windows
+        bts = (bt, bt) if not cfg.has_window else (bt[0], bt[1])
         counts = []
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
@@ -947,10 +1076,12 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 x = _ffn_residual(cfg, bp, x + out[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
             else:
-                x, pk, pv = attn_layer(bp, x, cache[f"pk{i}"],
-                                       cache[f"pv{i}"], bt, pos, att_pos,
-                                       rot)
+                x, pk, pv, cnt = attn_layer(
+                    i, bp, x, cache[f"pk{i}"], cache[f"pv{i}"],
+                    bts[bool(cfg.windows[i])], pos, att_pos, rot, active)
                 cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+                if cnt is not None:
+                    counts.append(cnt)
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
         if counts:
@@ -1000,7 +1131,7 @@ def _riding_step(cfg: LMConfig, page: int, cw: int, attend):
                 pv, bt_row[None], page_idx[None], row[None], pos_s[None])
             att, pk, pv = attend(q[:b], k[:b], v[:b], pk, pv, bt, pos,
                                  att_pos)
-            x = _attn_out(cfg, bp, x, jnp.concatenate([att, att_s[0]]))
+            x = _attn_out(cfg, bp, x, jnp.concatenate([att, att_s[0]]))[0]
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
@@ -1026,7 +1157,9 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
     pool (page 0 reserved as the garbage page), per state layer the
     slots' recurrent state, plus the per-slot ``len`` vector.
     The block table is NOT here — it is host state
-    (``kv.pages.PageAllocator`` decides it), passed to the step."""
+    (``kv.pages.PageAllocator`` decides it), passed to the step.  A
+    window layer's pools hold ``cfg.window_pages(slots, page)`` pages:
+    a class of their own, with a garbage page 0 of its own."""
     import jax.numpy as jnp
     if cfg.max_seq % page:
         raise ValueError(
@@ -1034,10 +1167,14 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
     from . import ssm_mixer
 
     shape = _paged_pool_shape(cfg, num_pages, page)
+    if cfg.has_window:
+        wshape = _paged_pool_shape(cfg, cfg.window_pages(slots, page),
+                                   page)
     cache = {}
     for i in cfg.attn_layers():
-        cache[f"pk{i}"] = jnp.zeros(shape, jnp.float32)
-        cache[f"pv{i}"] = jnp.zeros(shape, jnp.float32)
+        shp = wshape if cfg.windows[i] else shape
+        cache[f"pk{i}"] = jnp.zeros(shp, jnp.float32)
+        cache[f"pv{i}"] = jnp.zeros(shp, jnp.float32)
     # a state layer holds no page: one block of recurrent state for
     # each SLOT, whatever the context length (the state pool)
     for i in cfg.ssm_layers():
@@ -1052,11 +1189,17 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
     return cache
 
 
-def paged_page_bytes(cfg: LMConfig, page: int) -> int:
+def paged_page_bytes(cfg: LMConfig, page: int,
+                     window_class: bool = False) -> int:
     """Device bytes one LOGICAL page pins across every attention
     layer's k+v pools and every latent layer's pool (the allocator's
-    per-page accounting unit)."""
-    return 2 * len(cfg.attn_layers()) * page * cfg.kv_heads \
+    per-page accounting unit); of a window schedule, across the layers
+    of the page's class."""
+    layers = len(cfg.attn_layers())
+    if cfg.has_window:
+        n_win = len(cfg.window_layers())
+        layers = n_win if window_class else layers - n_win
+    return 2 * layers * page * cfg.kv_heads \
         * cfg.head_dim * 4 + latent_row_bytes(cfg) * page  # float32
 
 
@@ -1087,7 +1230,7 @@ def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
     q, k, v = _qkv(cfg, bp, x, _rope_at(cfg, pos))
     att, pk, pv = _span_attend(cfg, q, k, v, pk, pv, bt, page_idx, row,
                                pos)
-    return _attn_out(cfg, bp, x, att), pk, pv
+    return _attn_out(cfg, bp, x, att)[0], pk, pv
 
 
 def _span_attend(cfg: LMConfig, q, k, v, pk, pv, bt, page_idx, row, pos):
@@ -1123,6 +1266,71 @@ def _slice_rows(cfg: LMConfig, page: int, bt_row, start, n, width: int):
     posc = jnp.minimum(start + j, cfg.max_seq - 1)
     page_idx = jnp.where(j < n, bt_row[posc // page], 0)
     return page_idx, posc % page, start + j
+
+
+def make_paged_span_fill(cfg: LMConfig, page: int):
+    """A window schedule's prompt pass: ``fill(params, cache,
+    bt_row[pps], btw_row[pps], slot, start, n, ids[fill_span]) ->
+    cache`` writes ``n`` context tokens of ``slot`` at positions
+    ``start..start+n-1`` straight into its pages (a global layer's
+    through ``bt_row``, a window layer's through ``btw_row``; padding
+    rows to the garbage page 0 of either class) and sets the slot's len
+    to ``start + n``.  A prompt is as many calls of this ONE program
+    as it has spans, in order: no ``max_seq`` cache, no bucket, nothing
+    to insert.  In each layer the span's rows are scattered, then its
+    queries attend over the pages (``ops/span_attention``): a global
+    layer's over the whole block table, a window layer's over the
+    ``(window + fill_span) // page + 2`` entries from the page that
+    holds the first position its first row reaches; so ``btw_row``
+    must hold live pages from there to the span's last row, and what
+    lies behind may have been given back.  The expert layer routes the
+    ``n`` real rows only.  Identical by construction with as many
+    single steps (scatter before gather, the step's mask)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..ops import span_attention
+
+    if not cfg.has_window:
+        def declined(*_a, **_k):
+            raise UnsupportedBlock(
+                "make_paged_span_fill serves window schedules only: "
+                "every other block fills through make_prefill + insert")
+        return declined
+    if cfg.max_seq % page:
+        raise ValueError(
+            f"page size {page} must divide max_seq {cfg.max_seq}")
+    w, kvh, pps = cfg.fill_span, cfg.kv_heads, cfg.max_seq // page
+    reach = min(pps, (cfg.window + w) // page + 2)
+
+    def fill(params, cache, bt_row, btw_row, slot, start, n, ids):
+        cache = dict(cache)
+        j = jnp.arange(w)
+        real = j < n
+        posc = jnp.minimum(start + j, cfg.max_seq - 1)
+        rows = (posc % page)[:, None] * kvh + jnp.arange(kvh)[None, :]
+        x = _embed_rows(params, ids)[None]                # (1, w, dim)
+        rot = _rope_at(cfg, start + j)
+        for i in range(cfg.depth):
+            bp, win = params[f"blk{i}"], cfg.windows[i]
+            row = btw_row if win else bt_row
+            page_idx = jnp.where(real, row[posc // page], 0)[:, None]
+            q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
+            pk = cache[f"pk{i}"].at[page_idx, rows].set(k[0])
+            pv = cache[f"pv{i}"].at[page_idx, rows].set(v[0])
+            if win:
+                p0 = jnp.clip((start - win + 1) // page, 0, pps - reach)
+                ids_p = jax.lax.dynamic_slice(row, (p0,), (reach,))
+            else:
+                p0, ids_p = 0, row
+            att = span_attention.attention(q[0], pk, pv, ids_p, start,
+                                           p0 * page, page, win)
+            x, _counts = _attn_out(cfg, bp, x, att[None], i, real[None])
+            cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+        cache["len"] = cache["len"].at[slot].set(start + n)
+        return cache
+
+    return fill
 
 
 def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
@@ -1171,6 +1379,11 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
     pps = cfg.max_seq // page
 
     def insert(cache, page_ids, src, slot):
+        if cfg.has_window:
+            raise UnsupportedBlock(
+                "make_paged_io insert declines window layers: no whole-"
+                "prompt cache exists to insert (make_paged_span_fill "
+                "writes the pages)")
         cache = dict(cache)
         shape = _paged_pool_shape(cfg, pps, page)
         for i in range(cfg.depth):

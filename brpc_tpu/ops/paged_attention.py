@@ -40,14 +40,16 @@ from .flash_attention import _resolve_interpret
 _WAVE_BYTES = 512 << 10
 
 
-def reference(q, pk, pv, bt, pos, page: Optional[int] = None):
+def reference(q, pk, pv, bt, pos, page: Optional[int] = None,
+              window: int = 0):
     """The plain formulation: gather every slot's whole block table
     into a ``(slots, max_seq, heads, hd)`` view and run a dense masked
     attention over it.  What the step runs off the TPU, and what the
     kernels are tested against.  Pools of three dimensions are the
     grouped layout, ``(num_pages, page * kv_heads, hd)`` with a row a
     (token, key/value head) pair and each key/value head shared by
-    ``heads // kv_heads`` query heads; they need ``page``."""
+    ``heads // kv_heads`` query heads; they need ``page``.  With
+    ``window`` a slot attends ``pos - window < j <= pos`` only."""
     import jax.numpy as jnp
 
     b, heads, hd = q.shape
@@ -65,6 +67,9 @@ def reference(q, pk, pv, bt, pos, page: Optional[int] = None):
     s_mat = jnp.einsum("bhd,bkhd->bhk", q, view(pk),
                        preferred_element_type=jnp.float32) / (hd ** 0.5)
     live = jnp.arange(max_seq)[None, :] <= pos[:, None]
+    if window:
+        live = live & (jnp.arange(max_seq)[None, :]
+                       > pos[:, None] - window)
     s_mat = jnp.where(live[:, None, :], s_mat, -1e30)
     p = jax.nn.softmax(s_mat, axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", p, view(pv),
@@ -342,6 +347,178 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
                          interpret=_resolve_interpret(interpret))
 
 
+# -- window layers beside global ones ----------------------------------------
+#
+# The grouped layout again, for a schedule in which some layers attend a
+# window of the context (``LMConfig.windows``): a slot's walk STARTS at
+# the page that holds the window's first position and masks that page's
+# head, so a window layer reads ``window // page + 1`` pages however
+# long the context is (entries of the block table behind the window may
+# have been given back: they are never read).  One grid step a slot,
+# the first wave of the next in flight while this one's last is
+# computed, as the latent kernel; a group's ``(g, hd)`` queries meet a
+# key/value head's ``(tokens, hd)`` rows on the MXU in bfloat16 with
+# float32 accumulation (in float32 at ``highest``, as
+# :func:`_grouped_kernel`, sixteen queries a head would cost six passes
+# a product and hold the pages' bytes back).  A window layer's call is
+# ``window_decode_attention`` in a device trace, a global layer's of
+# the same schedule ``paged_decode_attention``.
+
+
+def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
+                   kbuf, vbuf, sems, g_ref, *, page: int, kv_heads: int,
+                   wave: int, window: int):
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    heads, hd = q_ref.shape[1], q_ref.shape[2]
+    g = heads // kv_heads
+    toks = wave * page
+    rows = page * kv_heads
+    bf = jnp.bfloat16
+
+    def first_page(s):
+        """The page of the first position slot ``s`` attends."""
+        if not window:
+            return 0
+        return jnp.maximum(pos_ref[s] - window + 1, 0) // page
+
+    def n_pages(s):
+        return pos_ref[s] // page + 1 - first_page(s)
+
+    def wave_dma(s, w, buf, go):
+        for i in range(wave):
+            idx = w * wave + i
+
+            @pl.when(idx < n_pages(s))
+            def _():
+                pid = bt_ref[s, first_page(s) + idx]
+                dst = pl.ds(i * rows, rows)
+                for hbm, vmem, j in ((pk_hbm, kbuf, 0), (pv_hbm, vbuf, 1)):
+                    go(pltpu.make_async_copy(
+                        hbm.at[pid], vmem.at[buf, dst], sems.at[j, buf]))
+
+    start = functools.partial(wave_dma, go=lambda c: c.start())
+    wait = functools.partial(wave_dma, go=lambda c: c.wait())
+
+    @pl.when(b == 0)
+    def _():
+        g_ref[0] = 0
+        start(0, 0, 0)
+
+    tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
+    tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
+    n_waves = (n_pages(b) + wave - 1) // wave
+    pos = pos_ref[b]
+    base = first_page(b) * page
+    q = (q_ref[0] * (1.0 / hd ** 0.5)).astype(bf)         # (heads, hd)
+    nt = (((1,), (1,)), ((), ()))
+
+    def wave_body(w, carry):
+        m, l, acc = carry
+        gcount = g_ref[0]
+        buf = lax.rem(gcount, 2)
+
+        @pl.when(w + 1 < n_waves)
+        def _():
+            start(b, w + 1, 1 - buf)
+
+        @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
+        def _():
+            start(b + 1, 0, 1 - buf)
+
+        wait(b, w, buf)
+        # this wave's tokens lie at t0 + 0..toks-1; those past pos are
+        # stale or were never fetched, those at or behind pos - window
+        # are the first page's head
+        t0 = base + w * toks
+        hi = pos + 1 - t0
+        lo = pos - window + 1 - t0 if window else -1
+        ms, ls, accs = [], [], []
+        for kh in range(kv_heads):
+            sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
+                else pl.ds(0, toks)
+            k = kbuf[buf, sel, :].astype(bf)              # (toks, hd)
+            ok_col = tok_col < hi
+            ok_row = tok_row < hi
+            if window:
+                ok_col = jnp.logical_and(ok_col, tok_col >= lo)
+                ok_row = jnp.logical_and(ok_row, tok_row >= lo)
+            # a weight of zero on a stale row is still 0 * NaN
+            v = jnp.where(ok_col, vbuf[buf, sel, :], 0.0).astype(bf)
+            s = lax.dot_general(q[kh * g:(kh + 1) * g], k, nt,
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(ok_row, s, -1e30)               # (g, toks)
+            m_new = jnp.maximum(m[kh], s.max(axis=1, keepdims=True))
+            corr = jnp.exp(m[kh] - m_new)
+            p = jnp.exp(s - m_new)
+            ms.append(m_new)
+            ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
+            accs.append(acc[kh] * corr + jnp.dot(
+                p.astype(bf), v, preferred_element_type=jnp.float32))
+        g_ref[0] = gcount + 1
+        return ms, ls, accs
+
+    init = ([jnp.full((g, 1), -1e30, jnp.float32)] * kv_heads,
+            [jnp.zeros((g, 1), jnp.float32)] * kv_heads,
+            [jnp.zeros((g, hd), jnp.float32)] * kv_heads)
+    _m, l, acc = lax.fori_loop(0, n_waves, wave_body, init)
+    for kh in range(kv_heads):
+        o_ref[0, kh * g:(kh + 1) * g, :] = acc[kh] / l[kh]
+
+
+@functools.partial(jax.jit, static_argnames=("page", "window", "interpret"))
+def _window_call(q, pk, pv, bt, pos, page: int, window: int,
+                 interpret: bool):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    slots, heads, hd = q.shape
+    kv_heads = pk.shape[1] // page
+    wave = pages_per_wave(page, kv_heads, hd, bt.shape[1])
+    mine = pl.BlockSpec((1, heads, hd), lambda b, bt, pos: (b, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_window_kernel, page=page, kv_heads=kv_heads,
+                          wave=wave, window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots,),
+            in_specs=[mine,
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=mine,
+            scratch_shapes=[
+                pltpu.VMEM((2, wave * page * kv_heads, hd), pk.dtype),
+                pltpu.VMEM((2, wave * page * kv_heads, hd), pv.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),            # waves so far
+            ]),
+        out_shape=jax.ShapeDtypeStruct((slots, heads, hd), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="window_decode_attention" if window
+        else "paged_decode_attention",
+    )(bt, pos, q, pk, pv)
+
+
+def window_decode_attention(q, pk, pv, bt, pos, page: int, window: int = 0,
+                            interpret: Optional[bool] = None):
+    """``q (slots, heads, hd)`` against positions ``max(pos[b] - window
+    + 1, 0)..pos[b]`` of slot ``b`` (``window`` 0: ``0..pos[b]``) in the
+    grouped pools ``pk`` / ``pv (num_pages, page * kv_heads, hd)`` ->
+    ``(slots, heads, hd)`` float32.  Only the pages that hold those
+    positions are read; operands bfloat16, softmax and accumulation
+    float32."""
+    return _window_call(q, pk, pv, bt, pos, page=page, window=int(window),
+                        interpret=_resolve_interpret(interpret))
+
+
 # -- latent attention -------------------------------------------------------
 #
 # A third layout and a third kernel, under its own name
@@ -549,6 +726,16 @@ def attention(q, pk, pv, bt, pos, page: Optional[int] = None):
     if pk.ndim == 3:
         return paged_decode_attention_grouped(q, pk, pv, bt, pos, page)
     return paged_decode_attention(q, pk, pv, bt, pos)
+
+
+def window_attention(q, pk, pv, bt, pos, page: int, window: int = 0):
+    """The step's attention in a window schedule, for its window layers
+    and its global ones: :func:`window_decode_attention` on the TPU,
+    :func:`reference` under the window's mask on the cpu backend."""
+    from .device_ops import _on_tpu
+    if not _on_tpu():
+        return reference(q, pk, pv, bt, pos, page, window)
+    return window_decode_attention(q, pk, pv, bt, pos, page, window)
 
 
 def mla_attention(q_lat, q_rope, pc, bt, pos, scale: float):

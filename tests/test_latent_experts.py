@@ -582,10 +582,11 @@ DECLINES = {
     "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),
     "generate": _generate_declines,
     "flash_prefill": _flash_declines,
-    # experts beside another mixer than the latent one
-    "experts_beside_mha": lambda: T.LMConfig(
-        depth=2, ffns=("dense", "experts"), expert_dim=8, experts_routed=4,
-        experts_top_k=2),
+    # experts beside a state layer (beside "attn" they are served since
+    # the window schedule: tests/test_window_experts.py)
+    "experts_beside_ssm": lambda: T.LMConfig(
+        depth=2, mixers=("attn", "ssm"), ffns=("dense", "experts"),
+        expert_dim=8, experts_routed=4, experts_top_k=2),
 }
 
 
