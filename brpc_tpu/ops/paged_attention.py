@@ -356,7 +356,7 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
 # long the context is (entries of the block table behind the window may
 # have been given back: they are never read).  One grid step a slot,
 # the first wave of the next in flight while this one's last is
-# computed, as the latent kernel; a group's ``(g, hd)`` queries meet a
+# computed (two buffers); a group's ``(g, hd)`` queries meet a
 # key/value head's ``(tokens, hd)`` rows on the MXU in bfloat16 with
 # float32 accumulation (in float32 at ``highest``, as
 # :func:`_grouped_kernel`, sixteen queries a head would cost six passes
@@ -533,6 +533,12 @@ def window_decode_attention(q, pk, pv, bt, pos, page: int, window: int = 0,
 
 # tokens a wave of the latent kernel holds: two MXU tiles of key rows
 _LATENT_WAVE_TOKENS = 256
+# waves the latent kernel keeps in VMEM: the one it computes and three
+# whose pages are on their way.  Measured on the v5e at 2 to 8 (PERF.md
+# §6, PR 34): with two the copies of a short last wave or of a slot's
+# first end before the products that hide them, and the queue runs dry;
+# four reach what the copies reach alone, more add nothing
+_LATENT_RING = 4
 
 
 def mla_reference(q_lat, q_rope, pc, bt, pos, scale: float):
@@ -561,16 +567,31 @@ def mla_reference(q_lat, q_rope, pc, bt, pos, scale: float):
 
 
 def _latent_kernel(bt_ref, pos_ref, ql_ref, qr_ref, pc_hbm, o_ref,
-                   cbuf, sems, g_ref, m_scr, l_scr, acc_scr, *,
-                   page: int, wave: int, scale: float):
-    """One grid step a slot (the queries of one slot are a block; the
-    wave buffers, their semaphores and the buffer parity ``g`` live
-    across steps, so the first wave of the next slot is in flight while
-    this one's last is computed).  A wave's rows are tokens on the
-    sublanes: ``(heads, kv_lora + rope)`` queries meet ``(tokens,
-    kv_lora + rope)`` rows as the two matrices they are, on the MXU in
-    bfloat16 with float32 accumulation; the weights then meet the same
-    rows' first ``kv_lora`` lanes."""
+                   cbuf, sems, g_ref, *, page: int, wave: int, scale: float):
+    """One grid step a slot (the queries of one slot are a block).  The
+    slots' waves are ONE sequence through a ring of buffers: the ring,
+    its semaphores and the count of waves so far ``g`` live across grid
+    steps, and every wave starts the copies of the wave ``ring - 1``
+    after it, in this slot or in the next ones, so the copies never
+    wait for a slot to end.  What one wave costs beside its copies
+    decides the rest (a wave is ~900 instruction bundles against the
+    0.98 us its 16 pages take; every page's copy is ~30 scalar
+    instructions of address and bounds check):
+
+    - no branch in a wave: a copy that has no page to fetch is a
+      predicated instruction, not a block of its own, so the compiler
+      schedules the whole wave as one sequence;
+    - the next copies are started AFTER the wave's products, where
+      their scalar work runs beside the vector work (started first,
+      they stand between the wait and the first load of a row);
+    - the softmax's state is the loop's carry, not a scratch buffer
+      read and written back every wave.
+
+    A wave's rows are tokens on the sublanes: ``(heads, kv_lora +
+    rope)`` queries meet ``(tokens, kv_lora + rope)`` rows as the two
+    matrices they are, on the MXU in bfloat16 with float32
+    accumulation; the weights then meet the same rows' first
+    ``kv_lora`` lanes."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -578,21 +599,32 @@ def _latent_kernel(bt_ref, pos_ref, ql_ref, qr_ref, pc_hbm, o_ref,
 
     b = pl.program_id(0)
     slots = pl.num_programs(0)
-    kl, rope = ql_ref.shape[-1], qr_ref.shape[-1]
+    heads, kl, rope = ql_ref.shape[1], ql_ref.shape[2], qr_ref.shape[2]
+    ring = cbuf.shape[0]
     toks = wave * page
     bf = jnp.bfloat16
 
-    def n_pages(s):
-        return pos_ref[s] // page + 1
+    def n_waves(s):
+        return lax.div(pos_ref[s] + toks, toks)
+
+    def next_wave(s, w):
+        """The wave after wave ``w`` of slot ``s``; slot ``slots`` and
+        beyond: none."""
+        more = w + 1 < n_waves(jnp.minimum(s, slots - 1))
+        return jnp.where(more, s, s + 1), jnp.where(more, w + 1, 0)
 
     def wave_dma(s, w, buf, go):
+        """``go`` on the copy of every live page of slot ``s``'s wave
+        ``w``; none where ``s`` is past the last slot."""
+        at = jnp.minimum(s, slots - 1)
+        live = jnp.where(s < slots,
+                         lax.div(pos_ref[at], page) + 1 - w * wave, 0)
         for i in range(wave):
-            idx = w * wave + i
 
-            @pl.when(idx < n_pages(s))
+            @pl.when(i < live)
             def _():
                 go(pltpu.make_async_copy(
-                    pc_hbm.at[bt_ref[s, idx]],
+                    pc_hbm.at[bt_ref[at, w * wave + i]],
                     cbuf.at[buf, pl.ds(i * page, page)], sems.at[buf]))
 
     start = functools.partial(wave_dma, go=lambda c: c.start())
@@ -601,30 +633,21 @@ def _latent_kernel(bt_ref, pos_ref, ql_ref, qr_ref, pc_hbm, o_ref,
     @pl.when(b == 0)
     def _():
         g_ref[0] = 0
-        start(0, 0, 0)
+        s, w = jnp.int32(0), jnp.int32(0)
+        for buf in range(ring - 1):
+            start(s, w, buf)
+            s, w = next_wave(s, w)
 
     tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
     tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
-    n_waves = (n_pages(b) + wave - 1) // wave
-    m_scr[:] = jnp.full_like(m_scr, -1e30)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
     ql = (ql_ref[0] * scale).astype(bf)                   # (heads, kl)
     qr = (qr_ref[0] * scale).astype(bf)                   # (heads, rope)
     nt = (((1,), (1,)), ((), ()))
 
-    def wave_body(w, _):
+    def wave_body(w, carry):
+        m, l, acc = carry
         g = g_ref[0]
-        buf = lax.rem(g, 2)
-
-        @pl.when(w + 1 < n_waves)
-        def _():
-            start(b, w + 1, 1 - buf)
-
-        @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
-        def _():
-            start(b + 1, 0, 1 - buf)
-
+        buf = lax.rem(g, ring)
         wait(b, w, buf)
         lim = pos_ref[b] + 1 - w * toks
         # rows past pos are stale or were never fetched: they meet a
@@ -636,19 +659,26 @@ def _latent_kernel(bt_ref, pos_ref, ql_ref, qr_ref, pc_hbm, o_ref,
             + lax.dot_general(qr, rows[:, kl:kl + rope], nt,
                               preferred_element_type=jnp.float32)
         s = jnp.where(tok_row < lim, s, -1e30)            # (heads, toks)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        l_scr[:] = l_scr[:] * corr + p.sum(axis=1, keepdims=True)
-        m_scr[:] = m_new
-        acc_scr[:] = acc_scr[:] * corr + jnp.dot(
-            p.astype(bf), c, preferred_element_type=jnp.float32)
+        l = l * corr + p.sum(axis=1, keepdims=True)
+        acc = acc * corr + jnp.dot(p.astype(bf), c,
+                                   preferred_element_type=jnp.float32)
+        # into the buffer the wave before this one was read from
+        ahead = (b, w)
+        for _ in range(ring - 1):
+            ahead = next_wave(*ahead)
+        start(*ahead, lax.rem(g + ring - 1, ring))
         g_ref[0] = g + 1
-        return 0
+        return m_new, l, acc
 
-    lax.fori_loop(0, n_waves, wave_body, 0)
-    o_ref[0] = acc_scr[:] / l_scr[:]
+    _m, l, acc = lax.fori_loop(
+        0, n_waves(b), wave_body,
+        (jnp.full((heads, 1), -1e30, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, kl), jnp.float32)))
+    o_ref[0] = acc / l
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -676,12 +706,10 @@ def _latent_call(q_lat, q_rope, pc, bt, pos, scale: float,
             out_specs=pl.BlockSpec((1, heads, kl),
                                    lambda b, bt, pos: (b, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, wave * page, pc.shape[-1]), pc.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((_LATENT_RING, wave * page, pc.shape[-1]),
+                           pc.dtype),
+                pltpu.SemaphoreType.DMA((_LATENT_RING,)),
                 pltpu.SMEM((1,), jnp.int32),            # waves so far
-                pltpu.VMEM((heads, 1), jnp.float32),    # running max
-                pltpu.VMEM((heads, 1), jnp.float32),    # running denom
-                pltpu.VMEM((heads, kl), jnp.float32),   # accumulator
             ]),
         out_shape=jax.ShapeDtypeStruct((slots, heads, kl), jnp.float32),
         compiler_params=pltpu.CompilerParams(

@@ -212,6 +212,26 @@ def test_expert_gmm_compiles_for_the_v5e_at_real_widths(rows, k, n, one_chip):
     assert "ragged-dot" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
+def test_mla_kernel_compiles_for_the_v5e_at_real_widths(one_chip):
+    """Mosaic takes the latent kernel at ``kimi-k2.7-code.codegen``'s
+    shapes (64 slots of 64 x (512 + 64) queries, a pool of 7,169 pages
+    of 16 float32 rows padded to 640 lanes, a 128-wide table) as ONE
+    custom call under the name a device trace is read by, the pool goes
+    in as it lies (no copy), and nothing is run."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: pa.mla_decode_attention(*a, 0.1, interpret=False)
+    ).lower(arg((64, 64, 512), jnp.float32), arg((64, 64, 64), jnp.float32),
+            arg((7169, PAGE, 640), jnp.float32), arg((64, 128), jnp.int32),
+            arg((64,), jnp.int32)).compile()
+    calls = re.findall(r"%(mla_decode_attention[.\w]*) = (\S+) custom-call",
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0][1].startswith("f32[64,64,512]"), calls
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 # -- the counter ------------------------------------------------------------
 
 class _Stream:
