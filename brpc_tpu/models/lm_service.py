@@ -287,6 +287,7 @@ class _Flight(NamedTuple):
     snap: list              # [(slot, _Session)] of its active slots
     counts: object = None   # the expert layers' routing counts, (3,)
     #                         int32 on the device (None: no such layer)
+    ordinal: int = -1       # its record in the clock's RoundLog (-1: none)
 
 
 class ContinuousBatcher:
@@ -551,9 +552,12 @@ class ContinuousBatcher:
         self.prefills_run = 0
         self.spills = 0
         self.resumes = 0
-        # the batcher thread's cursor over the loop's phases; _run
-        # replaces it with one that also annotates the profiler's clock
+        # the batcher thread's cursor over the loop's phases, with this
+        # batcher's log of its steps; _run hands it the profiler's
+        # annotation classes
         self._clock = _lmt.PhaseClock()
+        self._rounds_cache = _lmt.LmTelemetryCache(
+            build=self._clock.rounds.counters)
 
     # -- public -----------------------------------------------------------
 
@@ -621,6 +625,17 @@ class ContinuousBatcher:
     def steps_run(self) -> int:
         return self._steps
 
+    def round_log(self, since: int = 0) -> list:
+        """The records of the decode steps still in the ring
+        (``lm_telemetry.ROUND_RING``), of ordinal ``since`` and later:
+        one dict of ``lm_telemetry.ROUND_FIELDS`` a step."""
+        return self._clock.rounds.records(since)
+
+    def rounds_window(self):
+        """``(prev, cur, dt)`` of ``kv_stats()["rounds"]``, refreshed
+        at most once a cache interval: the ``/lm`` page's rates."""
+        return self._rounds_cache.window()
+
     def kv_stats(self) -> dict:
         """Allocator-plane observability — the benchmark and the
         capacity tests read this (``alloc``, ``prefix`` and ``host``
@@ -634,6 +649,11 @@ class ContinuousBatcher:
                "phase_ns": _lmt.phase_total_ns(),
                "loop_ns": _lmt.loop_ns(),
                "queue": _lmt.queue_counters(),
+               # the steps by what stood in front of them, the device's
+               # dry time by phase, a first token's stages (this
+               # batcher's own; the ring: round_log())
+               "rounds": self._clock.rounds.counters(),
+               "first": self._clock.rounds.first_counters(),
                "lookahead": {"ahead": self._ahead, "sync": self._sync,
                              "uploads": self._uploads,
                              "slices": self._slices,
@@ -895,7 +915,8 @@ class ContinuousBatcher:
         # arrived as pages and insert the same way.
         import jax.numpy as jnp
 
-        ph = self._clock.switch
+        clock = self._clock
+        ph = clock.switch
         imported = sess.cache1 is not None
         if imported:
             ctx_len = sess.ctx_len
@@ -931,6 +952,7 @@ class ContinuousBatcher:
             # disagg import: blockify the imported contiguous cache
             self._cache = self._insert(self._cache, jnp.asarray(row),
                                        sess.cache1, jnp.int32(free))
+            clock.filling(1, ctx_len)
             sess.cache1 = None
             last = int(sess.last_token)
             start_len = ctx_len
@@ -955,6 +977,7 @@ class ContinuousBatcher:
             ph(PH_PREFILL_DISPATCH)
             cache1, ctx_len = bucketed_prefill(self._prefill, self.cfg,
                                                sess.prompt)
+            clock.filling(1, ctx_len)
             self.prefills_run += 1
             ph(PH_INSERT_DISPATCH)
             # the insert also takes the slot: in the same program
@@ -962,6 +985,7 @@ class ContinuousBatcher:
             # slot's last session left there
             self._cache = self._insert(self._cache, jnp.asarray(row),
                                        cache1, jnp.int32(free))
+            clock.filling(1, 0)
             self._state_inserts += int(self.cfg.has_state)
             last = int(sess.prompt[-1])
             start_len = ctx_len
@@ -1042,6 +1066,7 @@ class ContinuousBatcher:
             self._cache = self._span_fill(
                 self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
                 np.int32(slot), np.int32(start), np.int32(n), ids)
+            self._clock.filling(1, n)
         return True
 
     def _cover_windows(self) -> bool:
@@ -1113,8 +1138,10 @@ class ContinuousBatcher:
             return self._host.abort_reason() or "kv_host_tier_full"
         handles = []
         try:
-            blk = np.asarray(self._gather_j(
-                self._cache, jnp.asarray(self._bt[sess.slot])))
+            blk = self._gather_j(self._cache,
+                                 jnp.asarray(self._bt[sess.slot]))
+            self._clock.filling(1, 0)
+            blk = np.asarray(blk)
             for j in range(sess.n_alias, sess.n_alias + sess.n_priv):
                 h = self._host.stage(
                     blk[j].reshape(-1).view(np.uint8))
@@ -1185,6 +1212,7 @@ class ContinuousBatcher:
         sess.host_handles = None
         self._cache = self._scatter_j(self._cache, jnp.asarray(ids),
                                       jnp.asarray(blk))
+        self._clock.filling(1, sess.saved_len)
         self._cache = self._setlen_j(self._cache, jnp.int32(free),
                                      jnp.int32(sess.saved_len))
         row = np.zeros((self._pps,), np.int32)
@@ -1278,10 +1306,12 @@ class ContinuousBatcher:
         outer = ph(PH_PREFILL_DISPATCH)
         cache1, ctx_len = bucketed_prefill(self._d_prefill, self.cfg,
                                            sess.prompt)
+        self._clock.filling(1, ctx_len)
         ph(PH_INSERT_DISPATCH)
         slot = jnp.int32(sess.slot)
         self._d_cache = self._insert(
             self._d_cache, jnp.asarray(self._bt[sess.slot]), cache1, slot)
+        self._clock.filling(1, 0)
         self._d_cache = self._setlen_j(self._d_cache, slot,
                                        jnp.int32(ctx_len))
         ph(outer)
@@ -1371,6 +1401,7 @@ class ContinuousBatcher:
             else:
                 self._cache = self._chunk_j(
                     self._cache, jnp.asarray(self._bt[sess.slot]), *span)
+                self._clock.filling(1, n)
             self._slices += 1
             sess.fill += n
             count_sched("sched_catchup_slice" if catchup
@@ -1379,6 +1410,7 @@ class ContinuousBatcher:
                 sess.span.annotate("lm_chunk_slice")
             ph(PH_SCHED)
             if sess.fill >= sess.ctx_len:
+                self._clock.filled(sess.tl)
                 self._activate(sess)
         return ride
 
@@ -1445,7 +1477,8 @@ class ContinuousBatcher:
         ``ride``: the slice that goes on board (``_chunk_round``'s),
         ``(slot, start, n, ids)``."""
         import jax.numpy as jnp
-        ph = self._clock.switch
+        clock = self._clock
+        ph = clock.switch
         if self._wt is not None:
             ph(PH_PAGE_ALLOC)
             if not self._cover_windows():
@@ -1465,10 +1498,12 @@ class ContinuousBatcher:
         else:
             self._cache, logits = self._step(self._cache,
                                              *self._step_inputs())
+        queued_at = clock.stamp()
         # greedy, a program of its own: its result feeds the next step
         # as it lies, and starts on its way to the host for the walk
         self._tokens_d = toks = jnp.argmax(logits, axis=-1)
         toks.copy_to_host_async()
+        step = self._steps
         self._steps += 1
         if ahead:
             self._ahead += 1
@@ -1487,16 +1522,21 @@ class ContinuousBatcher:
                 # state layer's block must not move a position past
                 # the session's end), whenever this one is read
                 self._active[slot] = False
-        return _Flight(toks, snap, counts)
+        return _Flight(toks, snap, counts, clock.queued(
+            queued_at, step, len(snap), ahead,
+            int(ride[2]) if ride is not None else 0))
 
     def _land(self, flight: _Flight) -> int:
         """Block on a dispatched step's tokens, walk, emit, evict.
         Returns the phase the loop was in, for a caller that was in
         the middle of one."""
-        ph = self._clock.switch
+        clock = self._clock
+        ph = clock.switch
         # the round's one sync, in a phase of its own: one sample a step
         outer = ph(PH_DEVICE_WAIT)
+        t_wait = clock.t
         toks = self._read_tokens(flight.toks)
+        touched = 0
         if flight.counts is not None:
             # the same program made them: no second wait
             pairs_, touched, load = (int(c) for c in
@@ -1531,8 +1571,11 @@ class ContinuousBatcher:
             pairs.append((sess, tok))
             if sess.sent >= sess.max_new:
                 finished.append(sess)
-        self._attn_pages_read += int(round(pages_read))
+        pages_read = int(round(pages_read))
+        self._attn_pages_read += pages_read
         self._attn_pages_table += len(pairs) * self._pps
+        clock.landed(t_wait, flight.ordinal, self._flight is not None,
+                     pages_read, touched)
         self._deliver(pairs, finished)
         return outer
 
@@ -1555,7 +1598,7 @@ class ContinuousBatcher:
         ph = self._clock.switch
         ph(PH_STREAM_EMIT)
         dead = self._emit(pairs)
-        _lmt.on_emit(pairs)
+        _lmt.on_emit(pairs, self._clock.rounds)
         if dead or finished:
             ph(PH_EVICT)
         evicted = set()
@@ -1568,6 +1611,7 @@ class ContinuousBatcher:
         for sess in finished:
             if self._sessions.get(sess.slot) is sess:
                 self._evict(sess, "finished")
+        self._clock.delivered()
 
     def _spec_round(self):
         """One speculative round: k draft proposals per active slot
@@ -1582,8 +1626,10 @@ class ContinuousBatcher:
         import jax.numpy as jnp
         k = self.spec_k
         count_spec("spec_round")
-        ph = self._clock.switch
+        clock = self._clock
+        ph = clock.switch
         ph(PH_SPEC_DRAFT)
+        clock.filling(0, 0)     # the drafts: the device is not dry
         active = self._active.copy()
         act_j = jnp.asarray(active)
         bt_j = jnp.asarray(self._bt)
@@ -1597,12 +1643,17 @@ class ContinuousBatcher:
         # draft, verify, walk: three leaves and no enclosing sample;
         # spec_verify holds the round's sync, one sample a step
         ph(PH_SPEC_VERIFY)
+        t_wait = clock.t
         u = np.stack([self._tokens] + drafts, axis=1).astype(np.int32)
         self._cache, out, m = self._verify_j(
             self._cache, bt_j, jnp.asarray(u), act_j)
+        # the round is one record, its verify the step
+        ordinal = clock.queued(clock.stamp(), self._steps,
+                               int(active.sum()), False, 0)
         out = np.asarray(out)
         m = np.asarray(m)
         ph(PH_TOKEN_WALK)
+        clock.landed(t_wait, ordinal, False, 0, 0)
         self._d_cache = self._d_sync_j(self._d_cache, jnp.asarray(m),
                                        act_j)
         self._steps += 1
@@ -1641,7 +1692,8 @@ class ContinuousBatcher:
         sp = sess.span
         if sp is not None:
             sess.span = None
-            sp.annotate("lm_evict:" + reason)
+            sp.annotate(_lmt.round_note("lm_evict:" + reason,
+                                        self._clock.round_now()))
             sp.finish(0)
 
     def _evict(self, sess: _Session, reason: Optional[str]) -> None:
@@ -1670,8 +1722,7 @@ class ContinuousBatcher:
         try:
             self._ensure_engine()
             import jax.profiler as _prof
-            clock = self._clock = _lmt.PhaseClock(
-                _prof.TraceAnnotation, _prof.StepTraceAnnotation)
+            clock.bind(_prof.TraceAnnotation, _prof.StepTraceAnnotation)
             ph = clock.switch
             while True:
                 clock.round_end()
@@ -1734,6 +1785,11 @@ class ContinuousBatcher:
                     # queue behind it
                     self._admit(sess)
                     ph(PH_SCHED)
+                    if sess.slot >= 0:
+                        clock.joined()
+                        if sess.fill >= sess.ctx_len:
+                            # every program of its context is queued
+                            clock.filled(sess.tl)
                 # the Sarathi half BEFORE the decode round: a fill
                 # completed this round teacher-forces its first token
                 # on THIS round's step, which also carries the

@@ -19,10 +19,15 @@ Three planes, all fed by the ONE batcher thread and read passively:
   ``PhaseClock.switch`` is entry-listed in the blocking linter).
   Readers see racy-but-monotonic values, exactly like
   ``engine.telemetry()`` readers do;
+- **the step as a span** (ISSUE 35) — the same clock keeps, per batcher,
+  one record for each decode step it queued (:class:`RoundLog`: a ring
+  of ``ROUND_RING``, the ordinal the ``lm_round`` annotation's
+  ``step_num``): what was queued in front of it, when it landed,
+  whether the device had run dry; counters by that class, the dry time
+  by the phase the host was in, and the stages of a first token;
 - **session timelines** — a bounded ring of per-session records
-  (tier/tenant, prompt length, queue wait, TTFT, per-token ITL log2
-  histogram,
-  prefix hit class, peak pages held, spill/resume/preempt counts, close
+  (tier/tenant, prompt length, queue wait, TTFT, the widest gap and
+  the step that ended it, prefix hit class, peak pages held, spill/resume/preempt counts, close
   reason) that feeds per-tier ``lm_ttft_ms``/``lm_itl_ms`` percentile
   rows and the CLOSED ``LM_SLO_VERDICTS`` attainment counters
   (``lm_slo_attained_total{tier,verdict}``) judged against the
@@ -150,6 +155,116 @@ def phase_index(name: str) -> int:
     return LM_STEP_PHASES.index(name)
 
 
+# ---------------------------------------------------------------------------
+# The step as a span: one record a decode step, per batcher (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+# CLOSED enum (tools/check/enums.py pins every member to a test): what
+# stood in front of a step in the device's queue.  Each step falls in
+# ONE class, tested in this order.
+LM_ROUND_CLASSES = (
+    "restart",   # the step before it had landed with nothing queued
+    #              behind it: the gap holds a lull, not work
+    "fill",      # filling programs were queued since the step before
+    "ride",      # a catch-up slice on board
+    "plain",     # the step before it, and nothing else
+)
+RC_RESTART, RC_FILL, RC_RIDE, RC_PLAIN = range(4)
+
+ROUND_RING = 4096
+# a step whose ``device_wait`` was shorter than this had already
+# finished when the host asked: the HOST's work ended its gap
+LATE_WAIT_NS = 100_000
+ROUND_FIELDS = (
+    "ordinal",        # the ``lm_round`` annotation's ``step_num``
+    "pass_ns",        # top of the pass that dispatched it: the span's start
+    "dispatch_ns",    # ``_step`` has returned: queued for the device
+    "wait_ns",        # its ``device_wait``: how long the host stood waiting
+    "land_ns",        # the tokens are on the host: the span's end
+    "done_ns",        # tokens written, sessions evicted
+    "rows",           # active slots it decodes
+    "ahead",          # 1: the step before it was unread when it left
+    "ride_rows",      # rows of the slice on board
+    "fill_programs",  # filling programs queued since the step before it
+    "fill_rows",      # context rows they fill (true lengths)
+    "joins",          # sessions admitted since the step before it
+    "pages",          # pages it attended, by the batcher's count
+    "touched",        # held experts it touched
+    "dry_ns",         # the device stood dry this long before it
+    "cls",            # index into LM_ROUND_CLASSES
+    "gap_ns",         # its ``land_ns`` minus the one before it
+)
+
+
+class RoundLog:
+    """One batcher's decode steps as spans: a ring of ``ROUND_RING``
+    records (a preallocated column of ints a field, indexed by ordinal
+    modulo the ring), counters by what stood in front of the step, the
+    device's dry time by the phase the host was in, and the stages of a
+    first token.  Plain data: the batcher thread writes it through its
+    :class:`PhaseClock`, readers see racy-but-monotonic values."""
+
+    __slots__ = ROUND_FIELDS + (
+        "cls_n", "cls_gap_ns", "cls_rows", "cls_programs", "late",
+        "max_gap", "dry_by_phase", "first", "pend", "lull", "prev_land",
+        "prev_ord", "dry_sum", "dry_mark", "landed_idx")
+
+    def __init__(self):
+        for f in ROUND_FIELDS:
+            setattr(self, f, [0] * ROUND_RING)
+        self.ordinal = [-1] * ROUND_RING
+        n = len(LM_ROUND_CLASSES)
+        self.cls_n = [0] * n
+        self.cls_gap_ns = [0] * n
+        self.cls_rows = [0] * n         # fill: context rows; ride: the slice's
+        self.cls_programs = [0] * n
+        self.late = [0, 0]              # n, gap_ns
+        self.max_gap = [0, -1]          # ns, ordinal (restarts left out)
+        self.dry_by_phase = [0] * _NPHASES
+        # n, queue_ns, admit_ns, device_ns, emit_ns, ttft_ns
+        self.first = [0] * 6
+        # filling programs, their rows and joins since the last step
+        self.pend = [0, 0, 0]
+        self.lull = True                # nothing has been queued yet
+        self.prev_land = 0
+        self.prev_ord = -2
+        self.dry_sum = 0
+        self.dry_mark = 0
+        self.landed_idx = -1            # the record being delivered
+
+    def counters(self) -> dict:
+        """``kv_stats()["rounds"]``: monotonic, cheap to read."""
+        out = {}
+        for c, name in enumerate(LM_ROUND_CLASSES):
+            out[name] = {"n": self.cls_n[c], "gap_ns": self.cls_gap_ns[c]}
+            if c in (RC_FILL, RC_RIDE):
+                out[name]["rows"] = self.cls_rows[c]
+                out[name]["programs"] = self.cls_programs[c]
+        out["late"] = {"n": self.late[0], "gap_ns": self.late[1]}
+        out["max_gap_ns"], out["max_ordinal"] = self.max_gap
+        out["dry_ns"] = dict(zip(LM_STEP_PHASES, self.dry_by_phase))
+        return out
+
+    def first_counters(self) -> dict:
+        """``kv_stats()["first"]``: join -> first token in four stages
+        that add up to ``ttft_ns`` over the same ``n`` sessions."""
+        return dict(zip(("n", "queue_ns", "admit_ns", "device_ns",
+                         "emit_ns", "ttft_ns"), self.first))
+
+    def records(self, since: int = 0) -> list:
+        """The ring's records of ordinal ``since`` and later, oldest
+        first, each a dict of ``ROUND_FIELDS`` with ``cls`` by name."""
+        cols = [getattr(self, f) for f in ROUND_FIELDS]
+        out = []
+        for i in range(ROUND_RING):
+            if self.ordinal[i] >= since:
+                rec = {f: col[i] for f, col in zip(ROUND_FIELDS, cols)}
+                rec["cls"] = LM_ROUND_CLASSES[rec["cls"]]
+                out.append(rec)
+        out.sort(key=lambda r: r["ordinal"])
+        return out
+
+
 class PhaseClock:
     """The batcher thread's cursor over ``LM_STEP_PHASES``: ``switch``
     ends the phase the loop was in and starts the next on ONE clock
@@ -157,22 +272,36 @@ class PhaseClock:
     that is a phase of its own inside another (a spill inside a page
     allocation) switches back to what ``switch`` returned.
 
-    ``trace_cls`` / ``step_cls`` are ``jax.profiler.TraceAnnotation`` /
-    ``StepTraceAnnotation`` (handed in: this module imports no jax);
+    The batcher's OWN state hangs on it too (a second batcher has a
+    second clock): ``rounds``, the :class:`RoundLog` its step hooks
+    write (``filling`` ... ``delivered``), and ``dry``: set while
+    nothing is queued for the device, so that ``switch`` credits the
+    ended phase to the dry table as well.
+
+    ``bind`` hands in ``jax.profiler.TraceAnnotation`` /
+    ``StepTraceAnnotation`` (this module imports no jax);
     with no profile session, or one whose host tracer is off, building
     one is a flag test inside the profiler.  With ``lm_telemetry`` off
-    nothing is built and ``switch`` is the gate's single list read."""
+    nothing is built or written and every hook is the gate's single
+    list read."""
 
-    __slots__ = ("cur", "_t", "_gen", "_loop_t", "_ann", "_round",
-                 "_trace_cls", "_step_cls")
+    __slots__ = ("cur", "t", "_gen", "_loop_t", "_pass_t", "_ann",
+                 "_round", "_trace_cls", "_step_cls", "rounds", "dry")
 
     def __init__(self, trace_cls=None, step_cls=None):
         self.cur = -1               # the open phase; -1: none
-        self._t = 0
-        self._gen = -1              # the on-spell (_live[0]) of _t, _loop_t
+        self.t = 0                  # the last switch's clock read
+        self._gen = -1              # the on-spell (_live[0]) of t, _loop_t
         self._loop_t = 0
+        self._pass_t = 0            # the pass's first switch
         self._ann = None
         self._round = None
+        self._trace_cls = trace_cls
+        self._step_cls = step_cls
+        self.rounds = RoundLog()
+        self.dry = True             # nothing has been queued yet
+
+    def bind(self, trace_cls, step_cls) -> None:
         self._trace_cls = trace_cls
         self._step_cls = step_cls
 
@@ -195,12 +324,17 @@ class PhaseClock:
             self._loop_t = t
             cur = -1
         if cur >= 0:
-            ns = t - self._t
+            ns = t - self.t
             _phase_buckets[cur][_log2_bucket(ns)] += 1
             _phase_count[cur] += 1
-            _phase_total_ns[cur] += ns if ns > 0 else 0
+            if ns > 0:
+                _phase_total_ns[cur] += ns
+                if self.dry:
+                    log = self.rounds
+                    log.dry_by_phase[cur] += ns
+                    log.dry_sum += ns
         self.cur = idx
-        self._t = t
+        self.t = t
         if idx >= 0 and self._trace_cls is not None:
             self._ann = ann = self._trace_cls(_TRACE_NAMES[idx])
             ann.__enter__()
@@ -208,10 +342,12 @@ class PhaseClock:
 
     def tick(self) -> None:
         """Advance ``loop_ns`` to now: once a pass, after the pass's
-        first ``switch`` (so the phases never lead it at that point),
-        and once more before the loop blocks."""
+        first ``switch`` (so the phases never lead it at that point,
+        and that switch's read is the pass's start), and once more
+        before the loop blocks."""
         if self._gen != _live[0]:   # off, or on again and no switch yet
             return
+        self._pass_t = self.t
         t = _mono_ns()
         _loop_ns[0] += t - self._loop_t
         self._loop_t = t
@@ -232,10 +368,139 @@ class PhaseClock:
 
     def close(self) -> None:
         """The loop is leaving (idle exit or crash): credit the open
-        phase and bring ``loop_ns`` level with it."""
+        phase and bring ``loop_ns`` level with it; the thread's next
+        incarnation starts level again."""
         self.round_end()
         self.switch(-1)
         self.tick()
+        self._gen = -1
+
+    # -- the step hooks: batcher thread only, ints into preallocated
+    # lists, no lock; each entry-listed in tools/check/blocking.py ------
+
+    def _wet(self, t: int) -> None:
+        """A device program was queued at ``t`` with the device dry:
+        the open phase's time so far was dry, the rest is not."""
+        self.dry = False
+        ns = t - self.t
+        if self.cur >= 0 and ns > 0:
+            log = self.rounds
+            log.dry_by_phase[self.cur] += ns
+            log.dry_sum += ns
+
+    def filling(self, programs: int, rows: int) -> None:
+        """Filling programs (a prefill, an insert, a span, a slice not
+        on board) have just been queued, for ``rows`` context rows:
+        counted into the NEXT step's record."""
+        if self._gen != _live[0]:
+            return
+        pend = self.rounds.pend
+        pend[0] += programs
+        pend[1] += rows
+        if self.dry:
+            self._wet(_mono_ns())
+
+    def joined(self) -> None:
+        """A session was admitted into a slot."""
+        if self._gen == _live[0]:
+            self.rounds.pend[2] += 1
+
+    def filled(self, tl) -> None:
+        """The session's context needs no further filling program: the
+        clock read that ended its admission (or its last slice's
+        preparation) closes its ``admit`` stage."""
+        if tl is not None and self._gen == _live[0]:
+            tl.fill_ns = self.t
+
+    def stamp(self) -> int:
+        """The clock, for ``queued``; 0 with the gate off."""
+        return _mono_ns() if self._gen == _live[0] else 0
+
+    def queued(self, t: int, step: int, rows: int, ahead: bool,
+               ride_rows: int) -> int:
+        """Step ``step`` was queued for the device at ``t``
+        (``stamp()``): open its record.  Returns the record's ordinal
+        for ``landed``, -1 where nothing was written."""
+        if not t:
+            return -1
+        if self.dry:
+            self._wet(t)
+        log = self.rounds
+        i = step % ROUND_RING
+        pend = log.pend
+        log.ordinal[i] = step
+        log.pass_ns[i] = self._pass_t
+        log.dispatch_ns[i] = t
+        log.wait_ns[i] = log.land_ns[i] = log.done_ns[i] = 0
+        log.pages[i] = log.touched[i] = log.gap_ns[i] = 0
+        log.rows[i] = rows
+        log.ahead[i] = int(ahead)
+        log.ride_rows[i] = ride_rows
+        log.fill_programs[i], log.fill_rows[i], log.joins[i] = pend
+        log.dry_ns[i] = log.dry_sum - log.dry_mark
+        log.dry_mark = log.dry_sum
+        log.cls[i] = RC_RESTART if log.lull else RC_FILL if pend[0] \
+            else RC_RIDE if ride_rows else RC_PLAIN
+        log.lull = False
+        pend[0] = pend[1] = pend[2] = 0
+        return step
+
+    def landed(self, t_wait: int, ordinal: int, behind: bool,
+               pages: int, touched: int) -> None:
+        """The step's tokens are on the host: called in the phase that
+        follows its wait, whose start (``t``) is the span's end;
+        ``t_wait`` is where the wait began.  ``behind``: a later step
+        is already queued.  Closes the record, counts it under its
+        class, and finds the device dry where nothing is queued behind
+        it."""
+        if ordinal < 0 or self._gen != _live[0]:
+            return
+        log = self.rounds
+        i = ordinal % ROUND_RING
+        if log.ordinal[i] != ordinal:
+            return
+        t = self.t
+        wait = t - t_wait
+        gap = t - log.prev_land if log.prev_ord == ordinal - 1 else 0
+        log.prev_land, log.prev_ord = t, ordinal
+        log.wait_ns[i] = wait
+        log.land_ns[i] = t
+        log.pages[i] = pages
+        log.touched[i] = touched
+        log.gap_ns[i] = gap
+        cls = log.cls[i]
+        log.cls_n[cls] += 1
+        log.cls_gap_ns[cls] += gap
+        if cls == RC_FILL:
+            log.cls_rows[cls] += log.fill_rows[i]
+            log.cls_programs[cls] += log.fill_programs[i]
+        elif cls == RC_RIDE:
+            log.cls_rows[cls] += log.ride_rows[i]
+            log.cls_programs[cls] += 1
+        if wait < LATE_WAIT_NS:
+            log.late[0] += 1
+            log.late[1] += gap
+        if cls != RC_RESTART and gap > log.max_gap[0]:
+            log.max_gap[0], log.max_gap[1] = gap, ordinal
+        log.landed_idx = i
+        if not behind and not log.pend[0]:
+            self.dry = log.lull = True
+
+    def delivered(self) -> None:
+        """The landed step's tokens are written and its sessions
+        evicted."""
+        log = self.rounds
+        i = log.landed_idx
+        if i >= 0:
+            log.landed_idx = -1
+            if self._gen == _live[0]:
+                log.done_ns[i] = _mono_ns()
+
+    def round_now(self) -> int:
+        """The ordinal of the step being delivered, -1 outside one."""
+        log = self.rounds
+        i = log.landed_idx
+        return log.ordinal[i] if i >= 0 else -1
 
 
 def bucket_label(i: int, nbuckets: int = NBUCKETS) -> str:
@@ -313,10 +578,10 @@ class SessionTimeline:
     only."""
 
     __slots__ = ("seq", "tier", "tenant", "prompt_len", "max_new",
-                 "join_ns", "admit_ns", "first_ns", "last_ns", "tokens",
-                 "itl_buckets", "itl_max_ns", "prefix", "pages_peak",
-                 "spills", "resumes", "preempts", "close_reason",
-                 "verdict")
+                 "join_ns", "admit_ns", "fill_ns", "first_ns", "last_ns",
+                 "tokens", "itl_max_ns", "first_round", "worst_round",
+                 "prefix", "pages_peak", "spills", "resumes", "preempts",
+                 "close_reason", "verdict")
 
     def __init__(self, tier: str, tenant: str, prompt_len: int,
                  max_new: int, source: str):
@@ -327,11 +592,15 @@ class SessionTimeline:
         self.max_new = max_new
         self.join_ns = _mono_ns()
         self.admit_ns = 0             # the batcher took it from _pending
+        self.fill_ns = 0              # its admission's work was done
         self.first_ns = 0
         self.last_ns = 0
         self.tokens = 0
-        self.itl_buckets = [0] * NBUCKETS
         self.itl_max_ns = 0
+        # the ordinals (RoundLog) of the step that made its first token
+        # and of the one that ended its widest gap
+        self.first_round = -1
+        self.worst_round = -1
         self.prefix = source          # fresh|imported, refined at admit
         self.pages_peak = 0
         self.spills = 0
@@ -357,6 +626,8 @@ class SessionTimeline:
                 "queue_ms": self.queue_ms(),
                 "ttft_ms": self.ttft_ms(),
                 "itl_max_ms": self.itl_max_ns / 1e6,
+                "first_round": self.first_round,
+                "worst_round": self.worst_round,
                 "prefix": self.prefix, "pages_peak": self.pages_peak,
                 "spills": self.spills, "resumes": self.resumes,
                 "preempts": self.preempts,
@@ -427,31 +698,50 @@ def queue_counters() -> dict:
     return {"wait_ns": _queue_wait_ns[0], "admitted": _admitted[0]}
 
 
-def on_emit(pairs) -> None:
+def round_note(note: str, ordinal: int) -> str:
+    """A session span's annotation with the step it belongs to."""
+    return note if ordinal < 0 else f"{note} round={ordinal}"
+
+
+def on_emit(pairs, log: Optional[RoundLog] = None) -> None:
     """Per-step token timing (batcher thread only): ONE monotonic read
     for the whole step, then plain list increments per token — the
-    first token closes the session's TTFT, later ones feed its ITL
-    histogram and the tier aggregate.  Lock-free, allocation-free."""
+    first token closes the session's TTFT, later ones feed the tier's
+    ITL histogram.  ``log`` is the batcher's, with the step being
+    delivered landed: the first token's stages are summed into it and
+    the session notes which step made its first token and which ended
+    its widest gap.  Lock-free, allocation-free."""
     if not _live[0] or not pairs:
         return
     now = _mono_ns()
+    i = log.landed_idx if log is not None else -1
+    ordinal, land = (log.ordinal[i], log.land_ns[i]) if i >= 0 else (-1, 0)
     for sess, _tok in pairs:
         tl = sess.tl
         if tl is None:
             continue
         if tl.tokens == 0:
             tl.first_ns = now
+            tl.first_round = ordinal
             d = now - tl.join_ns
             _tier_ttft[tl.tier][_log2_bucket(d)] += 1
+            if land and tl.admit_ns and tl.fill_ns:
+                # join -> taken -> admission done -> step landed -> now
+                first = log.first
+                first[0] += 1
+                first[1] += tl.admit_ns - tl.join_ns
+                first[2] += tl.fill_ns - tl.admit_ns
+                first[3] += land - tl.fill_ns
+                first[4] += now - land
+                first[5] += d
             if sess.span is not None:
-                sess.span.annotate("lm_first_token")
+                sess.span.annotate(round_note("lm_first_token", ordinal))
         else:
             d = now - tl.last_ns
             if d > tl.itl_max_ns:
                 tl.itl_max_ns = d
-            b = _log2_bucket(d)
-            tl.itl_buckets[b] += 1
-            _tier_itl[tl.tier][b] += 1
+                tl.worst_round = ordinal
+            _tier_itl[tl.tier][_log2_bucket(d)] += 1
         tl.last_ns = now
         tl.tokens += 1
 
@@ -558,8 +848,10 @@ class LmTelemetryCache:
     pair a snapshot with the wrong interval.  ``builds`` counts actual
     snapshot constructions — the one-snapshot-per-interval test pin."""
 
-    def __init__(self, ttl_s: float = 0.25):
+    def __init__(self, ttl_s: float = 0.25, build=None):
         self._ttl = ttl_s
+        if build is not None:       # a snapshot of the caller's own
+            self._build = build
         self._lock = threading.Lock()
         self._snap = None
         self._t = 0.0
@@ -568,7 +860,6 @@ class LmTelemetryCache:
         self.builds = 0
 
     def _build(self) -> dict:
-        self.builds += 1
         from .lm_service import sched_counters, spec_counters
         try:
             from ..kv.pages import prefix_event_counters
@@ -597,6 +888,7 @@ class LmTelemetryCache:
         now = _mono_s()
         if self._snap is None or now - self._t >= self._ttl:
             snap = self._build()
+            self.builds += 1
             self._prev, self._prev_t = self._snap, self._t
             self._snap, self._t = snap, now
 
@@ -695,8 +987,6 @@ def lifetime_prefix_hit_ratio() -> float:
 
 _phase_var = PassiveDimension(("phase",), phase_counters,
                               name="lm_step_phase_total")
-_phase_ns_var = PassiveDimension(("phase",), phase_total_ns,
-                                 name="lm_step_phase_ns_total")
 
 
 def _phase_bucket_rows() -> dict:
@@ -727,7 +1017,6 @@ _windowed_var = PassiveDimension(
 
 _LM_VARS = (
     (_phase_var, "lm_step_phase_total"),
-    (_phase_ns_var, "lm_step_phase_ns_total"),
     (_phase_hist_var, "lm_step_phase_ns"),
     (_slo_var, "lm_slo_attained_total"),
     (_queue_var, "lm_queue_ms"),

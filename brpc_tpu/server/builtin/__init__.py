@@ -463,12 +463,46 @@ def _native(server, msg, rest):
     return 200, "application/json", json.dumps(out, indent=1)
 
 
+def _lm_rounds(bat) -> dict:
+    """The batcher's decode steps as spans: from "a stream stuttered"
+    (``recent_sessions``' ``worst_round``) to the step that did it and
+    what stood in front of it."""
+    from ...models.lm_telemetry import LM_ROUND_CLASSES
+
+    prev, cur, dt = bat.rounds_window()
+    # the classes, and the steps the host was late for, as RATES over
+    # the cache window (the first read has no window yet)
+    window = {}
+    if prev is not None:
+        for k in (*LM_ROUND_CLASSES, "late"):
+            n = cur[k]["n"] - prev[k]["n"]
+            gap = cur[k]["gap_ns"] - prev[k]["gap_ns"]
+            window[k] = {"per_s": round(n / dt, 2),
+                         "mean_gap_ms": round(gap / n / 1e6, 3) if n else 0}
+    log = bat.round_log()
+    shown = ("ordinal", "cls", "gap_ns", "wait_ns", "rows", "ahead",
+             "fill_programs", "fill_rows", "ride_rows", "joins", "dry_ns")
+    return {
+        "window_s": round(dt, 3),
+        "window": window,
+        "total": {k: v for k, v in cur.items() if k != "dry_ns"},
+        "dry_ns": cur["dry_ns"],
+        "last": log[-32:],
+        "widest": [{k: r[k] for k in shown} for r in sorted(
+            (r for r in log if r["cls"] != "restart"),
+            key=lambda r: -r["gap_ns"])[:8]],
+    }
+
+
 def _lm(server, msg, rest):
     """/lm — the serving-plane telemetry page (ISSUE 18): live decode
     sessions, recently finished session timelines, per-tier
     queue-wait/TTFT/ITL percentiles and SLO attainment, the batcher's
     loop by phase (histograms, totals, and how much of ``loop_ns`` they
-    account for), the queue counters, KV pool / state pool / prefix cache
+    account for), the queue counters, the decode steps by what stood in
+    front of them (``rounds``: class rates and mean gaps over the window,
+    the device's dry time by phase, the last records and the widest
+    gaps of the batcher's ring), KV pool / state pool / prefix cache
     / host tier occupancy, the model as ``LM.Info`` gives it (with its
     layer schedule), and the WINDOWED
     spec-accept and prefix-hit ratios (current behavior — the lifetime
@@ -505,6 +539,7 @@ def _lm(server, msg, rest):
     bat = getattr(lm, "_batcher", None) if lm is not None else None
     kv = bat.kv_stats() if bat is not None else {}
     out = {
+        "rounds": _lm_rounds(bat) if bat is not None else {},
         "live_sessions": cur["live"],
         "recent_sessions": cur["ring"][-32:],
         "queue_ms": {f"{t}|{q}": v

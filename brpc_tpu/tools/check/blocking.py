@@ -126,6 +126,16 @@ ENTRY_POINTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     # and is deliberately NOT entry-listed)
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "switch")),
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "tick")),
+    # the step hooks (ISSUE 35): one record a decode step, written by
+    # the same thread into the clock's preallocated RoundLog
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "filling")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "joined")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "filled")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "stamp")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "queued")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "landed")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "delivered")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "round_now")),
     ("brpc_tpu/models/lm_telemetry.py", ("on_admit",)),
     ("brpc_tpu/models/lm_telemetry.py", ("on_emit",)),
     ("brpc_tpu/models/lm_telemetry.py", ("open_timeline",)),

@@ -167,7 +167,8 @@ def check_enums(tree: Tree) -> List[Finding]:
                             reason_names.append((s, f"{rel} (sched)"))
         if rel.endswith("models/lm_telemetry.py"):
             # the serving-observability plane's closed enums (step-loop
-            # phase names + SLO attainment verdicts): PhaseClock.switch
+            # phase names, SLO attainment verdicts, and the classes of
+            # what stood in front of a decode step): PhaseClock.switch
             # indexes the phase table and count_slo asserts verdict
             # membership at runtime; every member needs a test anchor
             # here — an unpinned phase or verdict is free to drift out
@@ -176,7 +177,8 @@ def check_enums(tree: Tree) -> List[Finding]:
                 if isinstance(node, ast.Assign) \
                         and isinstance(node.targets[0], ast.Name) \
                         and node.targets[0].id in (
-                            "LM_STEP_PHASES", "LM_SLO_VERDICTS") \
+                            "LM_STEP_PHASES", "LM_SLO_VERDICTS",
+                            "LM_ROUND_CLASSES") \
                         and isinstance(node.value, ast.Tuple):
                     for e in node.value.elts:
                         s = _str_const(e)

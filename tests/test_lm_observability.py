@@ -414,7 +414,10 @@ def test_queue_wait_is_stamped_when_the_batcher_takes_the_session():
     assert recs[2]["queue_ms"] <= recs[2]["ttft_ms"]
     assert recs[10]["queue_ms"] < recs[2]["queue_ms"]
     assert span.notes[:2] == ["lm_join", "lm_admit"]
-    assert span.notes[2:] == ["lm_first_token", "lm_evict:finished"]
+    # each with the step it belongs to: the first session took steps
+    # 0-9, this one 10 and 11
+    assert span.notes[2:] == ["lm_first_token round=10",
+                              "lm_evict:finished round=11"]
     assert span.finished
     q1 = bat.kv_stats()["queue"]
     assert q1["admitted"] == 2
@@ -689,8 +692,9 @@ def test_disagg_decode_session_trace_stitched():
         assert dec_sess.parent_span_id in {s.span_id
                                            for s in imp_server}
         dec_notes = [t for _, t in dec_sess.annotations]
-        assert "lm_first_token" in dec_notes
-        assert dec_notes[-1] == "lm_evict:finished"
+        assert any(n.startswith("lm_first_token round=")
+                   for n in dec_notes)
+        assert dec_notes[-1].startswith("lm_evict:finished round=")
         assert dec_sess.trace_id == pre_sess.trace_id == trace_id
     finally:
         pre_srv.stop()
@@ -718,8 +722,8 @@ def test_monolithic_decode_session_span():
         (sess,) = by["LMService.DecodeSession"]
         notes = [t for _, t in sess.annotations]
         assert notes[0] == "lm_join"
-        assert "lm_first_token" in notes
-        assert notes[-1] == "lm_evict:finished"
+        assert "lm_first_token round=0" in notes
+        assert notes[-1] == "lm_evict:finished round=3"
         server_ids = {s.span_id for s in by["LM.Decode"]
                       if s.is_server}
         assert sess.parent_span_id in server_ids
@@ -777,6 +781,25 @@ def test_lm_portal_and_metrics_exposition():
         assert page["kv"]["phases"]["device_wait"] \
             == lm.batcher().steps_run()
         assert page["kv"]["queue"]["admitted"] == 1
+        # the steps as spans: from the session's widest gap to the step
+        # that ended it and what stood in front of it
+        rounds = page["rounds"]
+        assert [r["ordinal"] for r in rounds["last"]] == [0, 1, 2, 3]
+        assert rounds["last"][0]["cls"] == "restart"
+        assert rounds["last"][0]["fill_programs"] == 2
+        assert rounds["total"]["restart"]["n"] == 1
+        assert rounds["total"]["plain"]["n"] == 3
+        assert set(rounds["total"]) == {"restart", "fill", "ride", "plain",
+                                        "late", "max_gap_ns", "max_ordinal"}
+        # a second read inside the cache's interval has no new window
+        assert set(rounds["window"]) <= set(rounds["total"])
+        assert set(rounds["dry_ns"]) == set(LM_STEP_PHASE_PINS)
+        assert recent[0]["first_round"] == 0
+        assert recent[0]["worst_round"] in (1, 2, 3)
+        assert recent[0]["worst_round"] \
+            in [r["ordinal"] for r in rounds["widest"]]
+        assert rounds["total"]["max_ordinal"] == rounds["widest"][0]["ordinal"]
+        assert page["kv"]["first"]["n"] == 1
         # the same counters ride the Prometheus exposition
         status, body = _http_get(ep, "/metrics")
         assert status == 200
