@@ -35,6 +35,7 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_SCHED, PH_SPEC_DRAFT, PH_SPEC_VERIFY,
                            PH_STEP_DISPATCH, PH_STREAM_EMIT,
                            PH_TOKEN_WALK)
+from . import mla_mixer
 from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
                              latent_row_bytes, require_plain_block,
                              state_slot_bytes)
@@ -689,6 +690,8 @@ class ContinuousBatcher:
             # one row a token and layer, key and value at once
             out["latent"] = {"row_bytes": cfg.latent_row() * 4,
                              "layers": len(cfg.mla_layers()),
+                             "packed_bytes": mla_mixer.packed_bytes(
+                                 cfg, self.params),
                              "pool_bytes": self.num_pages * self.page
                              * latent_row_bytes(cfg)}
         if self._alloc is not None:
@@ -722,6 +725,11 @@ class ContinuousBatcher:
         if self._prefill is None:
             from ..ops import paged_attention
             from ..ops.device_ops import _on_tpu
+            # a latent layer's projections in the layout the step's
+            # products read, made ONCE and in this tree alone (the
+            # caller's leaves are never written; a tree LMService
+            # packed comes back as it is)
+            self.params = mla_mixer.pack_params(self.cfg, self.params)
             if _on_tpu():
                 # the step's kernel needs Pallas, 1.2 s of import on
                 # the chip's host: beside the first prefill programs'
@@ -1900,6 +1908,10 @@ class LMService(Service):
         self.max_new_cap = max_new_cap
         from ..ops.quant import quantized_nbytes
         self._param_bytes = quantized_nbytes(self.params)  # immutable
+        # a latent layer's projections packed for the step's products
+        # (models/mla_mixer.py): the same values, so the bytes above
+        # and the fingerprint stand; this tree is the service's own
+        self.params = mla_mixer.pack_params(self.cfg, self.params)
         # whole-completion scan generator: one device program per
         # request instead of one per token (per-token dispatch dominates
         # single-stream decode).  Programs compile per
@@ -2117,6 +2129,7 @@ class LMService(Service):
                 "fill_span": c.fill_span}
         if c.has_latent:
             # one pool a latent layer, a row a token: key and value
+            info["packed_bytes"] = mla_mixer.packed_bytes(c, self.params)
             info["latent_pool"] = {
                 "layers": len(c.mla_layers()), "row": c.latent_row(),
                 "row_bytes": c.latent_row() * 4,

@@ -25,6 +25,33 @@ same row for keys and for values (``LMConfig.latent_row``).
   read once (``ops.paged_attention.mla_attention``).  The same numbers
   as the expanded form up to rounding.
 
+Both read a PACKED layer (:func:`pack`): ``W_qb`` and ``W_kvb`` do not
+lie as the checkpoint has them but as the operands of the products
+that use them, each by head as ``(H, out, in)`` with the contraction
+last::
+
+    wq_h  (H, nope + rope, q_lora)  [q_nope_h, q_rope_h] = q wq_h_h^T
+    wk_b  (H, kv_lora, nope)        step: q'_h = q_nope_h wk_b_h^T
+                                    prefill: k_nope_h = c wk_b_h
+    wv_b  (H, v, kv_lora)           step: out_h = o_h wv_b_h^T
+                                    prefill: v_h = c wv_b_h^T
+
+Why packed, and once: the 64-row product with ``W_qb`` (which XLA
+reads head-major with the contraction last) and the per-head
+contractions of a reshaped and sliced ``W_kvb`` made XLA re-lay both
+weights in EVERY step: two synchronous ``copy`` operations a layer,
+37.7 + 16.8 MB at the benchmark's widths (PERF.md section 6, PR 36).
+A serving program's weights are jit ARGUMENTS
+(``transformer_lm.jit_with_params``), so a layout the compiler wants
+is made again each call, where a constant's would be folded at compile
+time (with the weights embedded in every program: PERF.md section 6,
+PR 21).  So a service packs when it takes its weights (``lm_service``:
+:func:`pack_params`) and every program of it reads the one packed tree
+as it lies; the prefill's two expansions contract the same forms (a
+transposed read inside a 50-ms program).  A program handed a layer as
+:func:`init_layer` makes it packs it in its own trace: the same
+numbers, the copies back.
+
 Rotation: the rotary dimensions pair as halves (``i`` with ``i +
 rope/2``, :func:`transformer_lm._rope`'s), at YaRN-scaled frequencies
 where the configuration has ``rope_yarn``.  Weight matmuls take bf16
@@ -34,6 +61,7 @@ float32.
 
 from __future__ import annotations
 
+import functools
 import math
 
 
@@ -114,18 +142,86 @@ def rotation(cfg, pos):
     return jnp.sin(ang) * m, jnp.cos(ang) * m
 
 
+_PACKED = ("wq_h", "wk_b", "wv_b")
+
+
+def _relayout(wq_b, wkv_b, heads: int, nope: int, v: int):
+    """``wq_b (ql, H x (nope + rope))`` and ``wkv_b (kl, H x (nope +
+    v))`` as ``_PACKED``: each product's operand by head as ``(H, out,
+    in)``, the contraction last.  The same values in the same dtype."""
+    wkv = wkv_b.reshape(wkv_b.shape[0], heads, nope + v)
+    return {"wq_h": wq_b.reshape(wq_b.shape[0], heads, -1).transpose(1, 2, 0),
+            "wk_b": wkv[..., :nope].transpose(1, 0, 2),
+            "wv_b": wkv[..., nope:].transpose(1, 2, 0)}
+
+
+@functools.lru_cache(maxsize=None)
+def _relayout_program(heads: int, nope: int, v: int):
+    """:func:`_relayout` jitted: one program for every layer of a
+    schedule (its widths are the cache's key)."""
+    import jax
+    return jax.jit(functools.partial(_relayout, heads=heads, nope=nope,
+                                     v=v))
+
+
+def pack(cfg, bp):
+    """One latent layer's leaves with ``wq_b`` and ``wkv_b`` replaced
+    by the three forms its products read (module docstring), in a new
+    dict: the caller's is never written.  A layer that is packed
+    already, or whose projections are no plain arrays
+    (``ops.quant.QuantTensor``), is returned as it is."""
+    from ..ops.quant import QuantTensor
+
+    if "wq_b" not in bp or isinstance(bp["wq_b"], QuantTensor) \
+            or isinstance(bp["wkv_b"], QuantTensor):
+        return bp
+    out = {k: w for k, w in bp.items() if k not in ("wq_b", "wkv_b")}
+    out.update(_relayout_program(cfg.heads, cfg.qk_nope_dim, cfg.v_head_dim)(
+        bp["wq_b"], bp["wkv_b"]))
+    return out
+
+
+def pack_params(cfg, params):
+    """``params`` with every latent layer packed (:func:`pack`): a new
+    tree that shares every other leaf, or ``params`` itself where
+    nothing is left to pack.  A service packs ONCE, when it takes its
+    weights, and hands every program the one tree."""
+    blocks = {f"blk{i}": pack(cfg, params[f"blk{i}"])
+              for i in cfg.mla_layers()}
+    if all(blocks[k] is params[k] for k in blocks):
+        return params
+    return {**params, **blocks}
+
+
+def packed_bytes(cfg, params) -> int:
+    """The bytes of the packed forms in ``params``: what the device
+    holds twice while a caller keeps the leaves it handed over."""
+    return sum(int(w.size) * w.dtype.itemsize
+               for i in cfg.mla_layers()
+               for k, w in params[f"blk{i}"].items() if k in _PACKED)
+
+
 def _project(cfg, bp, t, rot):
     """``t (..., dim)`` -> ``q_nope (..., H, nope)``, ``q_rope (..., H,
     rope)`` rotated, and the latent row as it is cached: ``c`` normed,
-    ``kr`` rotated, zeros up to ``LMConfig.latent_row_padded``."""
+    ``kr`` rotated, zeros up to ``LMConfig.latent_row_padded``.  ``bp``
+    is a PACKED layer: the query product reads ``wq_h`` as it lies,
+    head-major, and what is sliced is its RESULT."""
     import jax.numpy as jnp
 
     from ..ops.quant import mxu_matmul as _mm
-    from .transformer_lm import _rmsnorm, _rope
+    from ..ops.quant import mxu_operand as bf
+    from .transformer_lm import UnsupportedBlock, _rmsnorm, _rope
 
-    h, nope, rope = cfg.heads, cfg.qk_nope_dim, cfg.qk_rope_dim
-    q = _mm(_rmsnorm(_mm(t, bp["wq_a"]), bp["q_norm"], cfg.norm_eps),
-            bp["wq_b"]).reshape(*t.shape[:-1], h, nope + rope)
+    if "wq_h" not in bp:
+        raise UnsupportedBlock(
+            "latent attention multiplies plain arrays: 'wq_b' / 'wkv_b' "
+            "held as QuantTensor are not packed, and not served")
+    q = jnp.einsum(
+        "...q,hdq->...hd",
+        bf(_rmsnorm(_mm(t, bp["wq_a"]), bp["q_norm"], cfg.norm_eps)),
+        bf(bp["wq_h"]), preferred_element_type=jnp.float32)
+    nope = cfg.qk_nope_dim
     q_nope, q_rope = q[..., :nope], _rope(q[..., nope:], *rot)
     c, kr = jnp.split(_mm(t, bp["wkv_a"]), [cfg.kv_lora_rank], axis=-1)
     c = _rmsnorm(c, bp["kv_norm"], cfg.norm_eps)
@@ -133,14 +229,6 @@ def _project(cfg, bp, t, rot):
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row_padded()
                                     - cfg.latent_row(),), jnp.float32)
     return q_nope, q_rope, jnp.concatenate([c, kr, pad], axis=-1)
-
-
-def _wkv_b(cfg, bp):
-    """``W_kvb`` by head: the key part ``(kv_lora, H, nope)`` and the
-    value part ``(kv_lora, H, v)``."""
-    w = bp["wkv_b"].reshape(cfg.kv_lora_rank, cfg.heads,
-                            cfg.qk_nope_dim + cfg.v_head_dim)
-    return w[..., :cfg.qk_nope_dim], w[..., cfg.qk_nope_dim:]
 
 
 def prefill(cfg, bp, x, rot):
@@ -163,13 +251,13 @@ def prefill(cfg, bp, x, rot):
 
     b, s, _ = x.shape
     bf = quant.mxu_operand
+    bp = pack(cfg, bp)
     q_nope, q_rope, row = _project(cfg, bp, x, rot)
     c = row[..., :cfg.kv_lora_rank]
     kr = row[..., cfg.kv_lora_rank:cfg.latent_row()]
-    wk, wv = _wkv_b(cfg, bp)
-    k_nope = jnp.einsum("bsc,chn->bshn", bf(c), bf(wk),
+    k_nope = jnp.einsum("bsc,hcn->bshn", bf(c), bf(bp["wk_b"]),
                         preferred_element_type=jnp.float32)
-    v = jnp.einsum("bsc,chv->bshv", bf(c), bf(wv),
+    v = jnp.einsum("bsc,hvc->bshv", bf(c), bf(bp["wv_b"]),
                    preferred_element_type=jnp.float32)
     scores = (jnp.einsum("bqhn,bkhn->bhqk", bf(q_nope),
                          bf(k_nope),
@@ -202,14 +290,14 @@ def step(cfg, bp, x, pc, bt, pos, att_pos, rot, page: int):
 
     b = x.shape[0]
     bf = quant.mxu_operand
+    bp = pack(cfg, bp)
     q_nope, q_rope, row = _project(cfg, bp, x[:, None], rot)
     pc = pc.at[bt[jnp.arange(b), pos // page], pos % page].set(row[:, 0])
-    wk, wv = _wkv_b(cfg, bp)
-    q_lat = jnp.einsum("bhn,chn->bhc", bf(q_nope[:, 0]),
-                       bf(wk), preferred_element_type=jnp.float32)
+    q_lat = jnp.einsum("bhn,hcn->bhc", bf(q_nope[:, 0]), bf(bp["wk_b"]),
+                       preferred_element_type=jnp.float32)
     o_lat = paged_attention.mla_attention(
         q_lat, q_rope[:, 0], pc, bt, att_pos, softmax_scale(cfg))
-    att = jnp.einsum("bhc,chv->bhv", bf(o_lat), bf(wv),
+    att = jnp.einsum("bhc,hvc->bhv", bf(o_lat), bf(bp["wv_b"]),
                      preferred_element_type=jnp.float32)
     return quant.mxu_matmul(
         att.reshape(b, cfg.heads * cfg.v_head_dim), bp["wo"]), pc

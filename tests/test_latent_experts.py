@@ -546,31 +546,37 @@ class _FakeStream:
         self.closed, self.close_reason = True, reason
 
 
+def _served_tokens(lm, params, prompts, n=6):
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    bat = ContinuousBatcher(lm, params, slots=2, page=PAGE, pages=17,
+                            idle_linger_s=0.2)
+    streams = [_FakeStream() for _ in prompts]
+    with jax.default_matmul_precision("highest"):
+        for st, p in zip(streams, prompts):
+            bat.join(st, p, n)
+        deadline = time.monotonic() + 120.0
+        while not all(s.closed for s in streams) \
+                and time.monotonic() < deadline:
+            time.sleep(0.002)
+    assert [s.close_reason for s in streams] == ["finished"] * len(prompts)
+    return bat, [s.tokens for s in streams]
+
+
 def test_batcher_serves_the_references_tokens_and_counts_routing(
         model, f32_matmuls):
     """Three sessions on two slots (one waits, one slot is reused):
     each is served what the reference decodes greedily; the routing
     counts arrive with the tokens; the latent pool and the pages
     read are accounted; ``LM.Info`` shows the schedule."""
-    from brpc_tpu.models.lm_service import ContinuousBatcher, LMService
+    from brpc_tpu.models.lm_service import LMService
     cfg, m, lm, params = model
     ref = m.Reference(cfg, params)
     rng = np.random.default_rng(9)
     prompts = [rng.integers(0, 256, (n,), dtype=np.int32)
                for n in (5, 18, 1)]
-    bat = ContinuousBatcher(lm, params, slots=2, page=PAGE, pages=17,
-                            idle_linger_s=0.2)
-    streams = [_FakeStream() for _ in prompts]
-    with jax.default_matmul_precision("highest"):
-        for st, p in zip(streams, prompts):
-            bat.join(st, p, 6)
-        deadline = time.monotonic() + 120.0
-        while not all(s.closed for s in streams) \
-                and time.monotonic() < deadline:
-            time.sleep(0.002)
-    for st, p in zip(streams, prompts):
-        assert st.close_reason == "finished"
-        toks = np.asarray(st.tokens, np.int32)
+    bat, served = _served_tokens(lm, params, prompts)
+    for toks, p in zip(served, prompts):
+        toks = np.asarray(toks, np.int32)
         logits = ref.served_logits(p, toks)
         best = logits.max(axis=-1)
         assert (best - logits[np.arange(6), toks]
@@ -584,6 +590,7 @@ def test_batcher_serves_the_references_tokens_and_counts_routing(
     assert 0 < moe_c["experts_touched"] <= moe_c["local_pairs"]
     assert 1 <= moe_c["max_load"] <= 2
     assert kv["latent"] == {"row_bytes": 160, "layers": 3,
+                            "packed_bytes": _projection_bytes(lm, params),
                             "pool_bytes": 17 * PAGE * 3 * 128 * 4}
     assert kv["attn"]["pages_read"] > 0 and "prefix" not in kv
     assert bat._alloc.page_bytes == T.paged_page_bytes(lm, PAGE) \
@@ -596,6 +603,163 @@ def test_batcher_serves_the_references_tokens_and_counts_routing(
     assert info["latent_pool"] == {"layers": 3, "row": 40, "row_bytes": 160,
                                    "token_bytes": 3 * 128 * 4}
     assert b":dee:" in svc.model_fingerprint()
+
+
+# -- the projections packed once, at the service's start -----------------------
+
+def _projection_bytes(lm, params) -> int:
+    """``wq_b`` and ``wkv_b`` of every latent layer, as the caller
+    holds them."""
+    return sum(params[f"blk{i}"][k].nbytes
+               for i in lm.mla_layers() for k in ("wq_b", "wkv_b"))
+
+
+def _packed_against_unpacked(program, model, monkeypatch):
+    """``(unpacked, packed)`` outputs of one program of the toy model,
+    as numpy trees."""
+    cfg, m, lm, params = model
+    packed = mla_mixer.pack_params(lm, params)
+    assert "wq_h" in packed["blk0"] and "wq_b" not in packed["blk0"]
+    rng = np.random.default_rng(11)
+    if program == "batcher":
+        prompts = [rng.integers(0, 256, (n,), dtype=np.int32)
+                   for n in (7, 17)]
+        theirs, got = _served_tokens(lm, params, prompts)
+        assert "wq_h" in theirs.params["blk1"]
+        with monkeypatch.context() as mp:
+            mp.setattr(mla_mixer, "pack_params", lambda cfg, p: p)
+            ours, want = _served_tokens(lm, params, prompts)
+        assert "wq_b" in ours.params["blk1"]
+        return want, got
+    prefill, step = T.make_paged_batch_decode(lm, PAGE)
+    if program == "prefill":
+        ids = rng.integers(0, 256, (1, 16), dtype=np.int32)
+        run = lambda p: jax.jit(prefill)(p, ids, jnp.int32(13))  # noqa: E731
+    else:
+        cache = T.empty_paged_cache(lm, 9, 2, PAGE)
+        cache["len"] = jnp.asarray([3, 0], jnp.int32)
+        bt = jnp.asarray(np.arange(1, 9, dtype=np.int32).reshape(2, 4))
+        bt = jnp.pad(bt, ((0, 0), (0, lm.max_seq // PAGE - 4)))
+        tok = jnp.asarray([5, 9], jnp.int32)
+        run = lambda p: jax.jit(step)(      # noqa: E731
+            p, cache, bt, tok, jnp.asarray([True, True]))
+    with jax.default_matmul_precision("highest"):
+        return [jax.tree_util.tree_map(np.asarray, run(p))
+                for p in (params, packed)]
+
+
+@pytest.mark.parametrize("program", ["step", "prefill", "batcher"])
+def test_packed_layers_compute_what_unpacked_layers_compute(
+        model, f32_matmuls, monkeypatch, program):
+    """A tree the service packed against the tree as the caller holds
+    it (which the program packs in its own trace): the same values
+    through the same products, so in float32 the same bits, and
+    through the batcher the same tokens."""
+    want, got = _packed_against_unpacked(program, model, monkeypatch)
+    assert jax.tree_util.tree_structure(want) \
+        == jax.tree_util.tree_structure(got)
+    for a, b in zip(jax.tree_util.tree_leaves(want),
+                    jax.tree_util.tree_leaves(got)):
+        np.testing.assert_array_equal(a, b)
+
+
+_MOVES = ("slice", "dynamic_slice", "transpose", "concatenate", "gather",
+          "copy", "rev", "pad")
+_PASSES = ("convert_element_type", "reshape", "pjit", "jit", "closed_call",
+           "custom_jvp_call", "squeeze")
+
+
+def _relaid(jaxpr, var, path=()):
+    """The data movements (``_MOVES``) applied to ``var`` before a
+    product takes it: empty when it reaches every ``dot_general`` /
+    ``pallas_call`` as it lies."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for pos, v in enumerate(eqn.invars):
+            if v is not var:
+                continue
+            name = eqn.primitive.name
+            if name in ("dot_general", "pallas_call"):
+                continue
+            if name in _MOVES:
+                found.append(path + (name,))
+            elif name in _PASSES:
+                sub = [x for x in eqn.params.values()
+                       if hasattr(getattr(x, "jaxpr", x), "eqns")]
+                if sub:
+                    inner = getattr(sub[0], "jaxpr", sub[0])
+                    found += _relaid(inner, inner.invars[pos], path + (name,))
+                else:
+                    found += _relaid(jaxpr, eqn.outvars[0], path + (name,))
+            else:
+                found.append(path + ("?" + name,))
+    return found
+
+
+def test_packed_weights_reach_their_products_as_they_lie(model):
+    """What the chip's per-step ``copy`` of a weight betrays, read from
+    the traced step on the CPU: every matrix of a PACKED latent layer
+    enters its ``dot_general`` with no slice, transpose or
+    concatenation of it in the way (a cast to the MXU's operand type
+    and a reshape move nothing); of an unpacked layer ``wq_b`` and
+    ``wkv_b`` do not."""
+    cfg, m, lm, params = model
+    for tree, relaid in ((mla_mixer.pack_params(lm, params), set()),
+                         (params, {"wq_b", "wkv_b"})):
+        flat, _ = jax.tree_util.tree_flatten_with_path(
+            (tree, T.empty_paged_cache(lm, 9, 2, PAGE)))
+        jaxpr = _traced_step(lm, tree)
+        assert len(jaxpr.invars) == len(flat) + 3
+        got = set()
+        for (path, leaf), var in zip(flat, jaxpr.invars):
+            keys = [getattr(k, "key", None) for k in path]
+            if keys[1] in [f"blk{i}" for i in lm.mla_layers()] \
+                    and keys[2] != "moe" and leaf.ndim >= 2 \
+                    and _relaid(jaxpr, var):
+                got.add(keys[2])
+        assert got == relaid
+
+
+def test_pack_leaves_the_callers_tree_and_the_fingerprint_alone(model):
+    """The service's tree is its own: every leaf the caller holds is
+    the object it was, ``param_bytes`` and the fingerprint are those of
+    the tree as handed over, the cost is reported; a layer whose
+    projections are ``QuantTensor`` is not packed (and not served)."""
+    from brpc_tpu.models.lm_service import LMService
+    cfg, m, lm, params = model
+    before = {id(x) for x in jax.tree_util.tree_leaves(params)}
+    keys = {k: set(v) for k, v in params.items() if isinstance(v, dict)}
+    svc = LMService(cfg=lm, params=params, page=PAGE, decode_slots=2)
+    assert {id(x) for x in jax.tree_util.tree_leaves(params)} == before
+    assert {k: set(v) for k, v in params.items()
+            if isinstance(v, dict)} == keys
+    assert svc.params is not params and "wq_b" not in svc.params["blk2"]
+    assert svc.params["blk2"]["wo"] is params["blk2"]["wo"]
+    info = json.loads(svc.Info(None, b""))
+    assert info["param_bytes"] == quant.quantized_nbytes(params)
+    assert info["packed_bytes"] == _projection_bytes(lm, params) \
+        == mla_mixer.packed_bytes(lm, svc.params)
+    assert mla_mixer.packed_bytes(lm, params) == 0
+    assert f":{quant.quantized_nbytes(params)}:".encode() \
+        in svc.model_fingerprint()
+    held = dict(params["blk1"],
+                wq_b=quant.quantize_int8(params["blk1"]["wq_b"]))
+    assert mla_mixer.pack(lm, held) is held
+    mixed = mla_mixer.pack_params(lm, {**params, "blk1": held})
+    assert mixed["blk1"] is held and "wk_b" in mixed["blk0"]
+    with pytest.raises(T.UnsupportedBlock, match="QuantTensor"):
+        _traced_step(lm, mixed)
+
+
+def test_packing_twice_is_packing_once(model):
+    cfg, m, lm, params = model
+    once = mla_mixer.pack_params(lm, params)
+    assert mla_mixer.pack_params(lm, once) is once
+    assert mla_mixer.pack(lm, once["blk0"]) is once["blk0"]
+    first = T.LMConfig(vocab=64, dim=32, heads=4, depth=2, max_seq=64,
+                       remat=False)
+    plain = T.init_params(jax.random.PRNGKey(0), first)
+    assert mla_mixer.pack_params(first, plain) is plain
 
 
 # -- what declines, by name ----------------------------------------------------

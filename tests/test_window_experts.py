@@ -533,9 +533,12 @@ PARENTS = {
     "jamba2-3b": ("tests/toy_jamba/config.json",
                   {"step": "b95629a258e2c88f",
                    "prefill": "66821575d05d1acd"}),
+    # read anew at PR 36, which changed the latent layer's products on
+    # purpose (``mla_mixer.pack``: these are the programs of a tree as
+    # ``init_params`` makes it, packed in the trace)
     "kimi-k2.7-code": ("tests/toy_kimi/config.json",
-                       {"step": "1abde33ef64207de",
-                        "prefill": "dd224c3f9adad52a"}),
+                       {"step": "36ae0aedaf336e8f",
+                        "prefill": "dc62c7c78e19b846"}),
 }
 
 
