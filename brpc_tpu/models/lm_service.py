@@ -38,7 +38,7 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
 from . import mla_mixer
 from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
                              latent_row_bytes, require_plain_block,
-                             state_slot_bytes)
+                             state_kinds, state_slot_bytes)
 
 
 def pack_generate_request(prompt: np.ndarray, max_new: int) -> bytes:
@@ -362,11 +362,12 @@ class ContinuousBatcher:
       (``kv.pages.host_inflight_spills``) and expiry closes parked
       sessions under ``kv_spill_drain_aborted`` instead of leaking.
 
-    **Two kinds of state** (a layer schedule with state-space layers,
-    ``LMConfig.mixers``): the attention layers keep
-    pages per TOKEN as above; each state layer keeps one fixed block
-    per SLOT in the state pool (``sh<i>``/``sc<i>`` of the cache, its
-    size follows ``slots``).  Admission writes the prefill's state at
+    **Two kinds of state** (a layer schedule with state layers,
+    ``LMConfig.mixers`` ``"ssm"`` or ``"kda"``): the attention and
+    latent layers keep pages per TOKEN as above; each state layer keeps
+    one fixed block per SLOT in the state pool (``sh<i>``/``sc<i>`` of
+    the cache, its size follows ``slots``; ``kv_stats()["state"]``
+    says which kinds it holds).  Admission writes the prefill's state at
     the prompt's true length over whatever the slot's last session
     left; the step moves it only where the slot is active; eviction
     just lets the slot go.  A page of keys restores no recurrent
@@ -520,6 +521,11 @@ class ContinuousBatcher:
         self._state_releases = 0
         self._state_held_steps = 0
         self._state_slot_steps = 0
+        # the delta-rule layers' own: steps run and ACTIVE slots
+        # stepped (the blocks ``kda_step`` read and wrote, a layer),
+        # prompts filled and their true rows (``kda_scan``'s)
+        self._kda = {"steps": 0, "slot_steps": 0, "fills": 0,
+                     "fill_rows": 0}
         # the expert layers' routing, as the steps' own counts say
         # (read with each step's tokens): steps and rows stepped,
         # (token, expert) pairs on a held expert, held experts touched
@@ -578,6 +584,9 @@ class ContinuousBatcher:
                                      int(max_new), "fresh")
         if span is not None:
             span.annotate("lm_join")
+            if not self.cfg.plain_block():
+                # a block beyond the first: which mixer each layer has
+                span.annotate("lm_schedule:" + self.cfg.schedule())
         self._enqueue(sess)
 
     def _assign_tier(self, sess: _Session, tenant) -> None:
@@ -669,8 +678,12 @@ class ContinuousBatcher:
                          "slot_steps": self._state_slot_steps,
                          "bytes": self.slots * state_slot_bytes(self.cfg),
                          "inserts": self._state_inserts,
-                         "releases": self._state_releases}}
+                         "releases": self._state_releases,
+                         # by kind of state layer: layers, bytes a slot
+                         "kinds": state_kinds(self.cfg)}}
         cfg = self.cfg
+        if cfg.has_kda:
+            out["kda"] = {**out["state"]["kinds"]["kda"], **self._kda}
         if cfg.has_experts:
             lo, hi = cfg.experts_held
             out["moe"] = {"layers": len(cfg.expert_layers()),
@@ -995,6 +1008,9 @@ class ContinuousBatcher:
                                        cache1, jnp.int32(free))
             clock.filling(1, 0)
             self._state_inserts += int(self.cfg.has_state)
+            if self.cfg.has_kda:
+                self._kda["fills"] += 1
+                self._kda["fill_rows"] += ctx_len
             last = int(sess.prompt[-1])
             start_len = ctx_len
             if self._prefix is not None:
@@ -1530,6 +1546,9 @@ class ContinuousBatcher:
                 # state layer's block must not move a position past
                 # the session's end), whenever this one is read
                 self._active[slot] = False
+        if self.cfg.has_kda:
+            self._kda["steps"] += 1
+            self._kda["slot_steps"] += len(snap)
         return _Flight(toks, snap, counts, clock.queued(
             queued_at, step, len(snap), ahead,
             int(ride[2]) if ride is not None else 0))
@@ -2051,6 +2070,8 @@ class LMService(Service):
             fp += (f":{c.kv_heads}:{int(c.rope)}:{c.ffn}:{c.ffn_dim}:"
                    f"{c.schedule()}:"
                    f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
+        if c.has_kda:
+            fp += f":{c.kda_heads}x{c.kda_head_dim}x{c.kda_conv}"
         if c.has_window or c.parallel_block:
             fp += (f":{c.head_dim}:{c.norm}:{int(c.parallel_block)}:"
                    f"{c.window}:" + "".join(
@@ -2120,7 +2141,8 @@ class LMService(Service):
                 mixers=c.schedule(),
                 state_pool={"slots": self.decode_slots,
                             "bytes": self.decode_slots
-                            * state_slot_bytes(c)})
+                            * state_slot_bytes(c),
+                            "kinds": state_kinds(c)})
         if c.has_window:
             # two page classes: whole contexts, and windows
             info["window_pool"] = {

@@ -5,10 +5,12 @@ has it, in the two forms serving needs.
 For one position ``t`` of width ``dim`` (already normed), ``H`` heads::
 
     q      = RMSNorm(t W_qa) W_qb           dim -> q_lora -> H x (nope + rope)
+             (or t W_q, dim -> H x (nope + rope), where q_lora_rank is None)
     [c, kr] = t W_kva                        dim -> kv_lora + rope
     c      = RMSNorm(c)
     [k_nope_h, v_h] = c W_kvb               kv_lora -> H x (nope + v)
     q_rope_h, kr rotated at the position     kr is ONE row, shared by heads
+             (not rotated where LMConfig.ropes says so: no positional term)
     score_h = (q_nope_h . k_nope_h + q_rope_h . kr) * scale
     out    = [sum softmax(score_h) v_h]_h W_o        H x v -> dim
 
@@ -31,6 +33,7 @@ that use them, each by head as ``(H, out, in)`` with the contraction
 last::
 
     wq_h  (H, nope + rope, q_lora)  [q_nope_h, q_rope_h] = q wq_h_h^T
+          (a direct ``wq`` packs into the same form, ``q_lora`` = dim)
     wk_b  (H, kv_lora, nope)        step: q'_h = q_nope_h wk_b_h^T
                                     prefill: k_nope_h = c wk_b_h
     wv_b  (H, v, kv_lora)           step: out_h = o_h wv_b_h^T
@@ -79,9 +82,11 @@ def init_layer(key, cfg) -> dict:
     def normal(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
-    return {"wq_a": normal(ks[0], (d, ql), d),
-            "q_norm": jnp.ones((ql,), jnp.float32),
-            "wq_b": normal(ks[1], (ql, h * (nope + rope)), ql),
+    query = {"wq": normal(ks[1], (d, h * (nope + rope)), d)} if ql is None \
+        else {"wq_a": normal(ks[0], (d, ql), d),
+              "q_norm": jnp.ones((ql,), jnp.float32),
+              "wq_b": normal(ks[1], (ql, h * (nope + rope)), ql)}
+    return {**query,
             "wkv_a": normal(ks[2], (d, kl + rope), d),
             "kv_norm": jnp.ones((kl,), jnp.float32),
             "wkv_b": normal(ks[3], (kl, h * (nope + v)), kl),
@@ -166,18 +171,20 @@ def _relayout_program(heads: int, nope: int, v: int):
 
 def pack(cfg, bp):
     """One latent layer's leaves with ``wq_b`` and ``wkv_b`` replaced
-    by the three forms its products read (module docstring), in a new
+    (``wq`` where the query is direct) by the three forms its products
+    read (module docstring), in a new
     dict: the caller's is never written.  A layer that is packed
     already, or whose projections are no plain arrays
     (``ops.quant.QuantTensor``), is returned as it is."""
     from ..ops.quant import QuantTensor
 
-    if "wq_b" not in bp or isinstance(bp["wq_b"], QuantTensor) \
+    wq = "wq_b" if "wq_b" in bp else "wq"     # a direct query: one matrix
+    if wq not in bp or isinstance(bp[wq], QuantTensor) \
             or isinstance(bp["wkv_b"], QuantTensor):
         return bp
-    out = {k: w for k, w in bp.items() if k not in ("wq_b", "wkv_b")}
+    out = {k: w for k, w in bp.items() if k not in (wq, "wkv_b")}
     out.update(_relayout_program(cfg.heads, cfg.qk_nope_dim, cfg.v_head_dim)(
-        bp["wq_b"], bp["wkv_b"]))
+        bp[wq], bp["wkv_b"]))
     return out
 
 
@@ -204,7 +211,8 @@ def packed_bytes(cfg, params) -> int:
 def _project(cfg, bp, t, rot):
     """``t (..., dim)`` -> ``q_nope (..., H, nope)``, ``q_rope (..., H,
     rope)`` rotated, and the latent row as it is cached: ``c`` normed,
-    ``kr`` rotated, zeros up to ``LMConfig.latent_row_padded``.  ``bp``
+    ``kr`` rotated, zeros up to ``LMConfig.latent_row_padded``; with
+    ``rot`` None nothing is rotated.  ``bp``
     is a PACKED layer: the query product reads ``wq_h`` as it lies,
     head-major, and what is sliced is its RESULT."""
     import jax.numpy as jnp
@@ -217,15 +225,18 @@ def _project(cfg, bp, t, rot):
         raise UnsupportedBlock(
             "latent attention multiplies plain arrays: 'wq_b' / 'wkv_b' "
             "held as QuantTensor are not packed, and not served")
-    q = jnp.einsum(
-        "...q,hdq->...hd",
-        bf(_rmsnorm(_mm(t, bp["wq_a"]), bp["q_norm"], cfg.norm_eps)),
-        bf(bp["wq_h"]), preferred_element_type=jnp.float32)
+    q_in = _rmsnorm(_mm(t, bp["wq_a"]), bp["q_norm"], cfg.norm_eps) \
+        if "wq_a" in bp else t
+    q = jnp.einsum("...q,hdq->...hd", bf(q_in), bf(bp["wq_h"]),
+                   preferred_element_type=jnp.float32)
     nope = cfg.qk_nope_dim
-    q_nope, q_rope = q[..., :nope], _rope(q[..., nope:], *rot)
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    if rot is not None:
+        q_rope = _rope(q_rope, *rot)
     c, kr = jnp.split(_mm(t, bp["wkv_a"]), [cfg.kv_lora_rank], axis=-1)
     c = _rmsnorm(c, bp["kv_norm"], cfg.norm_eps)
-    kr = _rope(kr[..., None, :], *rot)[..., 0, :]
+    if rot is not None:
+        kr = _rope(kr[..., None, :], *rot)[..., 0, :]
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row_padded()
                                     - cfg.latent_row(),), jnp.float32)
     return q_nope, q_rope, jnp.concatenate([c, kr, pad], axis=-1)

@@ -58,7 +58,9 @@ class LMConfig:
                  windows: Optional[Tuple[int, ...]] = None,
                  ropes: Optional[Tuple[bool, ...]] = None,
                  rope_pairs: str = "halves", router_bias: bool = True,
-                 shared_average: bool = False, fill_span: int = 1024):
+                 shared_average: bool = False, fill_span: int = 1024,
+                 kda_heads: Optional[int] = None,
+                 kda_head_dim: Optional[int] = None, kda_conv: int = 4):
         assert head_dim is not None or dim % heads == 0
         hd = dim // heads if head_dim is None else int(head_dim)
         assert not rope or hd % 2 == 0, "head dim must be even for RoPE"
@@ -71,8 +73,11 @@ class LMConfig:
         # width, the embedding table as the unembedding, a final norm,
         # and a per-layer schedule of mixers, "attn", "ssm" (a
         # Mamba-1 state-space layer, models/ssm_mixer.py, whose state
-        # is per SEQUENCE, not per token) or "mla" (latent attention,
-        # models/mla_mixer.py, whose cache is ONE latent row a token).
+        # is per SEQUENCE, not per token), "mla" (latent attention,
+        # models/mla_mixer.py, whose cache is ONE latent row a token)
+        # or "kda" (a gated delta-rule linear-attention layer,
+        # models/kda_mixer.py, whose state is a matrix a head, per
+        # SEQUENCE as a state-space layer's).
         # The paged serving factories run all of it; every other
         # factory runs the first block only and declines the rest by
         # name (UnsupportedBlock)
@@ -121,14 +126,24 @@ class LMConfig:
         self.mixers = ("attn",) * depth if mixers is None \
             else tuple(mixers)
         assert len(self.mixers) == depth \
-            and set(self.mixers) <= {"attn", "ssm", "mla"}
-        self.has_state = "ssm" in self.mixers
+            and set(self.mixers) <= {"attn", "ssm", "mla", "kda"}
+        # state layers of either kind: one block a SLOT in the state
+        # pool, whatever the context's length
+        self.has_state = bool(set(STATE_MIXERS) & set(self.mixers))
+        self.has_kda = "kda" in self.mixers
+        self.kda_heads = heads if kda_heads is None else int(kda_heads)
+        self.kda_head_dim = hd if kda_head_dim is None \
+            else int(kda_head_dim)
+        self.kda_conv = int(kda_conv)
         self.norm_eps = float(norm_eps)
-        # latent attention: low-rank query and key/value paths, a
-        # rotary part of the head apart from the rest, YaRN-scaled
-        # frequencies (``rope_yarn``: factor, original_max, beta_fast,
-        # beta_slow, mscale, mscale_all_dim) and the softmax scale they
-        # bring.  ``heads`` latent heads need not divide ``dim``
+        # latent attention: low-rank query and key/value paths (the
+        # query's direct, one ``wq``, where ``q_lora_rank`` is None), a
+        # rotary part of the head apart from the rest (not rotated
+        # where ``ropes`` says so: the layer then has no positional
+        # term), YaRN-scaled frequencies (``rope_yarn``: factor,
+        # original_max, beta_fast, beta_slow, mscale, mscale_all_dim)
+        # and the softmax scale they bring.  ``heads`` latent heads
+        # need not divide ``dim``
         self.has_latent = "mla" in self.mixers
         self.rope_theta = float(rope_theta)
         self.rope_yarn = dict(rope_yarn) if rope_yarn else None
@@ -137,9 +152,15 @@ class LMConfig:
         self.v_head_dim = v_head_dim
         if self.has_latent:
             assert all(w and int(w) > 0 for w in (
-                q_lora_rank, kv_lora_rank, qk_nope_dim, qk_rope_dim,
-                v_head_dim)), "an mla mixer needs its five widths"
+                kv_lora_rank, qk_nope_dim, qk_rope_dim, v_head_dim)) \
+                and (q_lora_rank is None or int(q_lora_rank) > 0), \
+                "an mla mixer needs its widths"
             assert qk_rope_dim % 2 == 0
+            if len({self.ropes[i] for i, m in enumerate(self.mixers)
+                    if m == "mla"}) > 1:
+                raise UnsupportedBlock(
+                    "latent layers that rotate beside latent layers "
+                    "that do not are not served")
             # one rotation a program (``_rope_at``): the latent one
             assert not (self.rope and "attn" in self.mixers), \
                 "rotary 'attn' layers beside 'mla' layers are not served"
@@ -168,10 +189,10 @@ class LMConfig:
             assert expert_dim and 0 <= lo < hi <= self.experts_routed \
                 and 1 <= self.experts_top_k <= self.experts_routed
             if set(m for m, f in zip(self.mixers, self.ffns)
-                   if f == "experts") - {"mla", "attn"}:
+                   if f == "experts") - {"mla", "attn", "kda"}:
                 raise UnsupportedBlock(
                     "an expert feed-forward layer is served beside an "
-                    "'mla' or an 'attn' mixer only")
+                    "'mla', an 'attn' or a 'kda' mixer only")
         if self.has_window or self.parallel_block:
             if set(self.mixers) != {"attn"}:
                 raise UnsupportedBlock(
@@ -248,11 +269,14 @@ class LMConfig:
     def attn_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, m in enumerate(self.mixers) if m == "attn")
 
-    def ssm_layers(self) -> Tuple[int, ...]:
-        return tuple(i for i, m in enumerate(self.mixers) if m == "ssm")
-
     def mla_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, m in enumerate(self.mixers) if m == "mla")
+
+    def state_layers(self) -> Tuple[int, ...]:
+        """The layers that hold a block of the state pool, of either
+        kind."""
+        return tuple(i for i, m in enumerate(self.mixers)
+                     if m in STATE_MIXERS)
 
     def expert_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, f in enumerate(self.ffns) if f == "experts")
@@ -292,6 +316,18 @@ class UnsupportedBlock(NotImplementedError):
     grouped key/value heads, attention without rotary, the gated FFN,
     a tied table or a final norm outside the paged serving factories.
     Nothing runs such a model wrong silently."""
+
+
+# the mixer kinds whose state is one block a SLOT of the state pool
+STATE_MIXERS = ("ssm", "kda")
+
+
+def _state_mixer(kind: str):
+    """The module of a state layer's kind: both have ``init_layer``,
+    ``state_shapes``, ``state_bytes``, ``prefill(cfg, bp, x, ctx_len)``
+    and ``step(cfg, bp, x, state, tail, active)``."""
+    from . import kda_mixer, ssm_mixer
+    return {"ssm": ssm_mixer, "kda": kda_mixer}[kind]
 
 
 def require_plain_block(cfg: LMConfig, what: str) -> None:
@@ -360,7 +396,7 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
     import jax
     import jax.numpy as jnp
 
-    from . import mla_mixer, moe, ssm_mixer
+    from . import mla_mixer, moe
 
     if cfg.scan_layers or cfg.moe_experts > 0:
         raise UnsupportedBlock(
@@ -379,8 +415,8 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
     gated = cfg.ffn == "gated_silu"
     for i in range(cfg.depth):
         bk = jax.random.split(ks[2 + i], 5)
-        if cfg.mixers[i] == "ssm":
-            blk = ssm_mixer.init_layer(bk[0], cfg)
+        if cfg.mixers[i] in STATE_MIXERS:
+            blk = _state_mixer(cfg.mixers[i]).init_layer(bk[0], cfg)
         elif cfg.mixers[i] == "mla":
             blk = mla_mixer.init_layer(bk[0], cfg)
         else:
@@ -546,12 +582,12 @@ def _rope_at(cfg: LMConfig, pos):
     the serving rotation's one home.  A program makes them once and
     hands them to every layer, as ``make_forward`` does its tables."""
     import jax.numpy as jnp
+    if not cfg.rope:
+        return None
     if cfg.has_latent:
         # the rotary part of a latent head, at its own frequencies
         from . import mla_mixer
         return mla_mixer.rotation(cfg, pos)
-    if not cfg.rope:
-        return None
     half = cfg.head_dim // 2
     freq = jnp.exp(-math.log(cfg.rope_theta)
                    * jnp.arange(half, dtype=jnp.float32) / half)
@@ -717,13 +753,14 @@ def make_prefill(cfg: LMConfig):
     serves: ``prefill(params, ids[1, s], ctx_len) -> (cache, logits)``.
     ``ids`` is a zero-padded bucket and ``ctx_len`` its true length: a
     causal attention forgives the padding, a recurrence does not, so a
-    state layer returns its state AT ``ctx_len`` (``h<i>`` and the
-    convolution's tail ``c<i>``), an attention layer ``k<i>``/``v<i>``
+    state layer of either kind returns its state AT ``ctx_len``
+    (``h<i>`` and the convolution's tail ``c<i>``), an attention layer
+    ``k<i>``/``v<i>``
     as :func:`make_decode`'s does, a latent layer its latent rows
     ``l<i>``; the logits are those of position ``ctx_len - 1``."""
     import jax.numpy as jnp
 
-    from . import mla_mixer, ssm_mixer
+    from . import mla_mixer
 
     if cfg.has_window:
         def declined(*_a, **_k):
@@ -741,16 +778,21 @@ def make_prefill(cfg: LMConfig):
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
-            if cfg.mixers[i] == "ssm":
-                out, h, tail = ssm_mixer.prefill(
+            if cfg.mixers[i] in STATE_MIXERS:
+                # (a dense feed-forward's ``_ffn_scheduled`` is
+                # ``_ffn_residual``; the bucket's padding is routed to
+                # no expert)
+                out, h, tail = _state_mixer(cfg.mixers[i]).prefill(
                     cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps), ctx_len)
-                x = _ffn_residual(cfg, bp, x + out)
+                x, _counts = _ffn_scheduled(
+                    cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
                 cache[f"h{i}"], cache[f"c{i}"] = h, tail
             elif cfg.mixers[i] == "mla":
                 # a latent layer returns the rows its tokens cache
                 # (``l<i>``); the bucket's padding is routed nowhere
                 out, cache[f"l{i}"] = mla_mixer.prefill(
-                    cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps), rot)
+                    cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps),
+                    rot if cfg.ropes[i] else None)
                 x, _counts = _ffn_scheduled(
                     cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
             else:
@@ -971,9 +1013,10 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     A block beyond the first (``LMConfig.mixers``, ``kv_heads``, ...)
     is served here and nowhere else.  Only attention layers have pools
     (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
-    state layer has ``sh<i>``/``sc<i>``, one block of recurrent state
-    for each SLOT, which the step moves one position where the slot is
-    ``active``; a latent layer has ONE pool, ``pc<i>`` ``(num_pages,
+    state layer of either kind (``"ssm"``, ``"kda"``) has
+    ``sh<i>``/``sc<i>``, one block of recurrent state for each SLOT,
+    which the step moves one position where the slot is ``active``; a
+    latent layer has ONE pool, ``pc<i>`` ``(num_pages,
     page, kv_lora + rope`` padded to 128 lanes``)``: its keys and
     values are the same rows.
     Unrolled layers only.
@@ -1001,7 +1044,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
             f"page size {page} must divide max_seq {cfg.max_seq}")
 
     from ..ops import paged_attention
-    from . import mla_mixer, ssm_mixer
+    from . import mla_mixer
 
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
@@ -1062,19 +1105,25 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 # routed to no expert
                 out, cache[f"pc{i}"] = mla_mixer.step(
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
-                    cache[f"pc{i}"], bt, pos, att_pos, rot, page)
+                    cache[f"pc{i}"], bt, pos, att_pos,
+                    rot if cfg.ropes[i] else None, page)
                 x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
                                         active[:, None])
                 if cnt is not None:
                     counts.append(cnt)
-            elif cfg.mixers[i] == "ssm":
+            elif cfg.mixers[i] in STATE_MIXERS:
                 # the slot's recurrent state moves one position where
-                # the slot is active and stays where it is not
-                out, h, tail = ssm_mixer.step(
+                # the slot is active and stays where it is not (a
+                # ``"kda"`` layer's in place: an idle slot's block is
+                # not touched at all)
+                out, h, tail = _state_mixer(cfg.mixers[i]).step(
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
                     cache[f"sh{i}"], cache[f"sc{i}"], active)
-                x = _ffn_residual(cfg, bp, x + out[:, None])
+                x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
+                                        active[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
+                if cnt is not None:
+                    counts.append(cnt)
             else:
                 x, pk, pv, cnt = attn_layer(
                     i, bp, x, cache[f"pk{i}"], cache[f"pv{i}"],
@@ -1164,8 +1213,6 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    from . import ssm_mixer
-
     shape = _paged_pool_shape(cfg, num_pages, page)
     if cfg.has_window:
         wshape = _paged_pool_shape(cfg, cfg.window_pages(slots, page),
@@ -1177,8 +1224,8 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
         cache[f"pv{i}"] = jnp.zeros(shp, jnp.float32)
     # a state layer holds no page: one block of recurrent state for
     # each SLOT, whatever the context length (the state pool)
-    for i in cfg.ssm_layers():
-        h, tail = ssm_mixer.state_shapes(cfg, slots)
+    for i in cfg.state_layers():
+        h, tail = _state_mixer(cfg.mixers[i]).state_shapes(cfg, slots)
         cache[f"sh{i}"] = jnp.zeros(h, jnp.float32)
         cache[f"sc{i}"] = jnp.zeros(tail, jnp.float32)
     # a latent layer holds one row a token: one pool, key and value
@@ -1210,10 +1257,22 @@ def latent_row_bytes(cfg: LMConfig) -> int:
     return len(cfg.mla_layers()) * cfg.latent_row_padded() * 4  # float32
 
 
+def state_kinds(cfg: LMConfig) -> Dict[str, Dict[str, int]]:
+    """The state pool by kind of layer: for each kind the schedule
+    has, its ``layers`` and the ``slot_bytes`` one SLOT pins across
+    them."""
+    kinds = {}
+    for kind in STATE_MIXERS:
+        layers = cfg.mixers.count(kind)
+        if layers:
+            kinds[kind] = {"layers": layers, "slot_bytes": layers
+                           * _state_mixer(kind).state_bytes(cfg)}
+    return kinds
+
+
 def state_slot_bytes(cfg: LMConfig) -> int:
     """Device bytes one SLOT pins across every state layer's pool."""
-    from . import ssm_mixer
-    return len(cfg.ssm_layers()) * ssm_mixer.state_bytes(cfg)
+    return sum(k["slot_bytes"] for k in state_kinds(cfg).values())
 
 
 def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
@@ -1387,7 +1446,7 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         cache = dict(cache)
         shape = _paged_pool_shape(cfg, pps, page)
         for i in range(cfg.depth):
-            if cfg.mixers[i] == "ssm":
+            if cfg.mixers[i] in STATE_MIXERS:
                 for pool, new in ((f"sh{i}", f"h{i}"), (f"sc{i}", f"c{i}")):
                     cache[pool] = jax.lax.dynamic_update_slice(
                         cache[pool], src[new],

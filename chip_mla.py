@@ -4,7 +4,10 @@ mla_decode_attention`` against ``mla_reference``, at
 rope 64, pages of 16 rows, a 128-wide table, a pool of 7,169 pages of
 float32 rows padded to 640 lanes).
 
-    chiprun -- python3 chip_mla.py [seed]
+    chiprun -- python3 chip_mla.py [seed] [kimi-k2 | kimi-linear]
+
+(``kimi-linear``: ``kimi-linear-48b.codegen``'s, 128 slots, 32 heads, a
+pool of 16,385 pages, no YaRN in the scale.)
 
 - live lengths as the cell has them (uniform 256-2,047 a slot) and all
   slots at 1,024 (no partial wave);
@@ -44,6 +47,9 @@ from chip_gmm import CALLS, device_seconds  # noqa: E402
 SLOTS, HEADS, KL, ROPE, PAGE, TABLE, PAGES, ROW = 64, 64, 512, 64, 16, 128, \
     7169, 640
 SCALE = 192 ** -0.5 * 1.4159 ** 2       # the configuration's, with yarn
+# the other latent configuration's: (slots, heads, pages, scale)
+SHAPES = {"kimi-k2": (SLOTS, HEADS, PAGES, SCALE),
+          "kimi-linear": (128, 32, 16385, 192 ** -0.5)}
 HBM_GBS = 819.0
 TOLERANCE = 6e-2
 
@@ -121,11 +127,15 @@ def main() -> int:
         print(f"no TPU: {dev.platform}", file=sys.stderr)
         return 1
     seed = int(sys.argv[1]) if len(sys.argv) > 1 else 1
+    global SLOTS, HEADS, PAGES, SCALE
+    shape = sys.argv[2] if len(sys.argv) > 2 else "kimi-k2"
+    SLOTS, HEADS, PAGES, SCALE = SHAPES[shape]
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     log = open(os.path.join(ROOT, "chiprun_out", "mla.jsonl"), "a")
 
     def record(**kw):
-        line = json.dumps({"device": dev.device_kind, "seed": seed, **kw})
+        line = json.dumps({"device": dev.device_kind, "seed": seed,
+                           "shape": shape, **kw})
         print(line, flush=True)
         log.write(line + "\n")
         log.flush()

@@ -469,7 +469,10 @@ def test_info_shows_the_schedule_and_the_state_pool(model):
                     decode_slots=2)
     info = json.loads(svc.Info(None, b""))
     assert info["mixers"] == "sass" and info["kv_heads"] == 1
-    assert info["state_pool"] == {"slots": 2,
+    # the pool by kind of state layer (a "kda" layer's beside these:
+    # tests/test_linear_experts.py)
+    kinds = {"ssm": {"layers": 3, "slot_bytes": T.state_slot_bytes(cfg)}}
+    assert info["state_pool"] == {"slots": 2, "kinds": kinds,
                                   "bytes": 2 * T.state_slot_bytes(cfg)}
     assert b"sass" in svc.model_fingerprint()
 
