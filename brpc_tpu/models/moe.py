@@ -181,7 +181,18 @@ def forward_grouped(params: Dict[str, Any], x, cfg: MoEConfig
 # the HBM rate on the v5e) and not the buffer.  (``jax.lax.ragged_dot``,
 # which stood here until PR 32, followed the buffer's rows: a 512-row
 # MXU tile a touched expert, 36% of the HBM rate at a decode step's 16
-# rows in 512; PERF.md §6.)  The layer is TOLD which experts it holds:
+# rows in 512; PERF.md §6.)  The combine behind them is a kernel too
+# (``ops/expert_combine.py``, ``expert_combine`` in a device trace, one
+# call a layer): the buffer's LIVE rows, which lie at its front, each
+# weighed and added into its token's row of an output block that stays
+# in VMEM, the row tiles behind the last live row never fetched; its
+# cost follows the pairs that fell on a held expert (``counts[0]``) and
+# the output's own bytes, 6-9 us a step's layer on the v5e.  (The
+# scatter-add of the whole masked, weighed buffer, which stood here
+# until PR 38, took the buffer's rows one after another: 162 us at 14
+# live rows in 512 x 7,168; PERF.md §6.)  Off the TPU, and at a width
+# that fills no whole lanes, the same sum runs from the token's side in
+# plain XLA.  The layer is TOLD which experts it holds:
 # it routes over all of them, normalises over all the chosen ones, and
 # adds only its own experts' part; what the others would add is another
 # chip's.
@@ -275,13 +286,16 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
     dim), counts (3,) int32)``: ``sum_k w_k E_k(t)`` over the chosen
     experts HELD here plus the shared experts.  Rows not ``live`` (a
     bucket's padding, an idle slot) are routed nowhere, so they cost no
-    expert and count nowhere.  ``counts``: (token, expert) pairs that
-    fell on a held expert, held experts with at least one row, the most
-    rows one expert took."""
+    expert and count nowhere.  The routed sum is float32 and follows the
+    live rows (``ops/expert_combine.py``): the buffer's rows behind them
+    are neither computed nor read.  ``counts``: (token, expert) pairs
+    that fell on a held expert, held experts with at least one row, the
+    most rows one expert took."""
     import jax
     import jax.numpy as jnp
 
     from ..ops import quant
+    from ..ops.expert_combine import combine
     from ..ops.expert_gmm import expert_gmm
 
     T, k, n = t.shape[0], cfg.top_k, cfg.n_held
@@ -293,7 +307,6 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
     # sort the (token, choice) pairs by held expert; the others last
     key = jnp.where(local, ids - lo, n).reshape(T * k)
     order = jnp.argsort(key, stable=True)[:cfg.buffer_rows(T)]
-    skey = key[order]
     tok = order // k
     sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
                     dtype=jnp.int32)
@@ -301,10 +314,9 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
     xs = bf(t)[tok]                                         # (M, dim)
     gate, up = jnp.split(expert_gmm(xs, bf(p["w1"]), sizes), 2, axis=-1)
     ys = expert_gmm(bf(jax.nn.silu(gate) * up), bf(p["w2"]), sizes)
-    # rows past the last group were not computed: (M, dim)
-    ys = jnp.where((skey < n)[:, None], ys, 0.0) \
-        * w.reshape(T * k)[order][:, None]
-    out = jnp.zeros((T, cfg.dim), jnp.float32).at[tok].add(ys)
+    # the local pairs' rows are the first ``sizes.sum()``; the rows
+    # behind them were not computed
+    out = combine(ys, order, w, sizes.sum())
     if cfg.shared:
         shared = _gated(t, p["ws1"], p["ws2"])
         out = out + (shared if cfg.shared_scale == 1.0

@@ -12,11 +12,25 @@ experts of 7,168 -> 2 x 2,048 -> 7,168, bfloat16).
   x 8 choices), a 32nd of them live (12 of 384 experts are held here);
 - with ``tiles``: the kernel over row tiles and weight-block sizes.
 
+    chiprun -- python3 chip_gmm.py combine [tiles]
+
+- the combine behind the products (``ops/expert_combine.py``) alone, at
+  the four shapes the cells send, (tokens, buffer rows, dim, routed,
+  held): ``kimi-k2.7-code``'s step (64, 512, 7168, 384, 12) and 1,024-row
+  bucket (1024, 8192, 7168, 384, 12), ``kimi-linear-48b``'s step (128,
+  1024, 2304, 256, 32), ``command-a-plus``'s step (16, 128, 4096, 128,
+  16): the scatter-add of the whole masked, weighed buffer that stood in
+  ``serve`` until PR 38, the plain form from the token's side and the
+  kernel ``expert_combine``; microseconds a call and GB/s of the
+  LIVE rows' bytes.  With ``tiles``: the kernel over output-block and
+  row-tile sizes.
+
 A time is the device's own, from a profiler trace of 20 calls
 (``benchmarks/harness/xplane.py``), beside the host's clock over the
 same calls.  Every kernel result is checked against ``ragged_dot`` on
-the live rows.  One JSON line a measurement, appended to
-``chiprun_out/gmm.jsonl``.  Exits non-zero without a TPU.
+the live rows (the combine's forms against the scatter-add).  One JSON
+line a measurement, appended to ``chiprun_out/gmm.jsonl``.  Exits
+non-zero without a TPU.
 """
 import json
 import os
@@ -33,6 +47,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from benchmarks.harness import xplane  # noqa: E402
+from brpc_tpu.ops import expert_combine as C  # noqa: E402
 from brpc_tpu.ops import expert_gmm as G  # noqa: E402
 
 HELD = 12
@@ -74,12 +89,98 @@ def device_seconds(fns, args):
                    host[name]) for name in fns}, red
 
 
+# (tokens, buffer rows, dim, routed, held) as the cells send them
+COMBINE_SHAPES = {
+    "kimi_k2_step": (64, 512, 7168, 384, 12),
+    "kimi_linear_step": (128, 1024, 2304, 256, 32),
+    "command_a_step": (16, 128, 4096, 128, 16),
+    "kimi_k2_bucket": (1024, 8192, 7168, 384, 12),
+}
+TOP_K = 8
+
+
+def scatter_add(ys, order, w, n_live):
+    """``serve``'s combine until PR 38: the whole buffer masked,
+    weighed and scatter-added."""
+    k = w.shape[1]
+    ys = jnp.where((jnp.arange(ys.shape[0]) < n_live)[:, None], ys, 0.0) \
+        * w.reshape(-1)[order][:, None]
+    return jnp.zeros((w.shape[0], ys.shape[1]), jnp.float32).at[
+        order // k].add(ys)
+
+
+def combine_main(record, tiles: bool) -> int:
+    r = np.random.default_rng(38)
+    variants = {
+        "scatter_add": scatter_add,
+        "plain": C.plain,
+        "expert_combine": C.expert_combine,
+    }
+    if tiles:
+        for ob in (1, 2, 4, 8):
+            for tb in (256, 1024, 4096):
+                if (ob << 20, tb << 10) != (C._OUT_BYTES, C._TILE_BYTES):
+                    variants[f"expert_combine_out{ob}m_tile{tb}k"] = (
+                        lambda ys, order, w, n, ob=ob, tb=tb:
+                        C.expert_combine(ys, order, w, n,
+                                         out_bytes=ob << 20,
+                                         tile_bytes=tb << 10))
+    fns, args, meta = {}, {}, {}
+    for case, (t, m, dim, routed, held) in COMBINE_SHAPES.items():
+        ids = np.stack([r.permutation(routed)[:TOP_K] for _ in range(t)])
+        local = ids < held
+        key = np.where(local, ids, held).reshape(-1)
+        full = np.argsort(key, kind="stable").astype(np.int32)
+        live = int(local.sum())
+        ys = r.normal(size=(m, dim)).astype(np.float32)
+        ys[live:] = np.nan
+        w = r.uniform(0.1, 1.0, (t, TOP_K)).astype(np.float32)
+        for vname, v in variants.items():
+            def fn(ys, order, w, n, v=v, i=len(fns)):
+                return v(ys, order, w, n), jnp.int32(i)
+            name = f"{vname}_{case}"
+            fn.__name__ = name
+            fns[name] = jax.jit(fn)
+            args[name] = (jnp.asarray(ys), jnp.asarray(full[:m]),
+                          jnp.asarray(w), jnp.int32(live))
+            meta[name] = dict(case=case, variant=vname, tokens=t, rows=m,
+                              dim=dim, live=live,
+                              live_share=100.0 * live / m)
+    secs, red = device_seconds(fns, args)
+    worst = 0.0
+    for name, (dev_s, host_s) in secs.items():
+        md = meta[name]
+        kernel = [s for key, s in red["device_ops"]
+                  if key.startswith(f"jit_{name}: expert_combine")]
+        rec = dict(md, device_us=dev_s * 1e6, host_us=host_s * 1e6,
+                   kernel_us=sum(kernel) * 1e6 / CALLS if kernel else None,
+                   live_gbs=(md["live"] * md["dim"] * 4 / dev_s / 1e9
+                             if dev_s else None),
+                   buffer_gbs=(md["rows"] * md["dim"] * 4 / dev_s / 1e9
+                               if dev_s else None))
+        if md["variant"] != "scatter_add":
+            want = np.asarray(fns[f"scatter_add_{md['case']}"](
+                *args[f"scatter_add_{md['case']}"])[0])
+            got = np.asarray(fns[name](*args[name])[0])
+            rec["max_err"] = float(np.abs(got - want).max())
+            worst = max(worst, rec["max_err"])
+        record(**rec)
+    # what each plain form is made of, by operation, at the step's shape
+    for key, s in red["device_ops"]:
+        if "_kimi_k2_step:" in key and "expert_combine_out" not in key:
+            record(op=key, us_a_call=s * 1e6 / CALLS)
+    ok = worst < 1e-5
+    record(check="the combine's forms against the scatter-add",
+           max_err=worst, ok=ok)
+    return 0 if ok else 1
+
+
 def main() -> int:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(f"no TPU: {dev.platform}", file=sys.stderr)
         return 1
-    tiles = len(sys.argv) > 1 and sys.argv[1] == "tiles"
+    tiles = sys.argv[-1] == "tiles"
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     log = open(os.path.join(ROOT, "chiprun_out", "gmm.jsonl"), "a")
 
@@ -89,6 +190,8 @@ def main() -> int:
         log.write(line + "\n")
         log.flush()
 
+    if "combine" in sys.argv[1:]:
+        return combine_main(record, tiles)
     r = np.random.default_rng(7)
     weights = {k: jnp.asarray(r.normal(size=(HELD,) + s).astype(np.float32)
                               / np.sqrt(s[0]), jnp.bfloat16)

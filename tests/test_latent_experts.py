@@ -11,6 +11,7 @@ softmax, the expert layer a plain loop over the held experts; it
 imports nothing of the program.
 """
 
+import functools
 import hashlib
 import json
 import math
@@ -26,6 +27,7 @@ import pytest
 
 from brpc_tpu.models import mla_mixer, moe
 from brpc_tpu.models import transformer_lm as T
+from brpc_tpu.ops import expert_combine as comb
 from brpc_tpu.ops import expert_gmm as gmm
 from brpc_tpu.ops import paged_attention, quant
 from brpc_tpu.streaming import StreamOptions
@@ -376,6 +378,151 @@ def test_expert_gmm_kernel(case, dtype):
     tm = gmm._row_tile(rows, tm)
     reached = min(rows, -(-live // tm) * tm)
     assert (got[live:reached] == 0).all()
+
+
+# -- the combine behind it ---------------------------------------------------
+
+# name: (tokens, top_k, routed, held, dim, live rows or None for all,
+#        (out bytes, tile bytes) or None for the kernel's own)
+COMBINE_CASES = {
+    "a_step": (16, 4, 16, (0, 8), 256, None, None),
+    "a_bucket_in_row_tiles_and_column_blocks":
+        (64, 4, 32, (4, 12), 256, None, (64 * 128 * 4, 8 * 128 * 4)),
+    "fewer_held_than_top_k_cuts_the_buffer_short":
+        (12, 4, 8, (3, 5), 128, None, None),
+    "rows_not_live": (16, 4, 16, (0, 8), 128,
+                      [0, 2, 3, 7, 8, 13], None),
+    "an_expert_with_no_row": (3, 2, 32, (0, 16), 128, None, None),
+    "no_pair_local_at_all": (8, 2, 64, (62, 64), 128, [], None),
+    "every_row_live_in_a_buffer_that_is_no_multiple_of_the_row_tile":
+        (11, 3, 12, (0, 12), 128, None, (2 << 20, 8 * 128 * 4)),
+    "a_width_that_fills_no_whole_lanes": (16, 4, 16, (0, 8), 40, None,
+                                          None),
+}
+
+
+def _scatter_add(ys, order, w, n_live):
+    """``serve``'s combine as it stood until PR 38: the whole buffer
+    masked, weighed and scatter-added."""
+    live_row = (jnp.arange(ys.shape[0]) < n_live)[:, None]
+    return jnp.zeros((w.shape[0], ys.shape[1]), jnp.float32).at[
+        order // w.shape[1]].add(jnp.where(live_row, ys, 0.0)
+                                 * w.reshape(-1)[order][:, None])
+
+
+def _combine_operands(case):
+    """What ``moe.serve`` hands its combine, made as ``serve`` makes
+    it, the buffer's rows behind the live ones NaN; and the sum as it
+    stood until PR 38."""
+    tokens, k, routed, (lo, hi), dim, rows, _tiles = COMBINE_CASES[case]
+    r = np.random.default_rng(tokens * k + dim)
+    ecfg = moe.ExpertConfig(dim=dim, hidden=8, routed=routed, held=(lo, hi),
+                            top_k=k)
+    n = ecfg.n_held
+    ids = jnp.asarray(np.stack([r.permutation(routed)[:k]
+                                for _ in range(tokens)]), jnp.int32)
+    if case == "no_pair_local_at_all":
+        ids = ids % lo
+    w = jnp.asarray(r.uniform(0.1, 1.0, (tokens, k)).astype(np.float32))
+    local = (ids >= lo) & (ids < hi)
+    if rows is not None:
+        local = local & jnp.zeros((tokens,), bool).at[
+            jnp.asarray(rows, jnp.int32)].set(True)[:, None]
+    key = jnp.where(local, ids - lo, n).reshape(tokens * k)
+    order = jnp.argsort(key, stable=True)[:ecfg.buffer_rows(tokens)]
+    n_live = jnp.int32(local.sum())
+    # the local pairs' rows are the buffer's first
+    assert ((key[order] < n) == (jnp.arange(order.shape[0]) < n_live)).all()
+    ys = r.normal(size=(order.shape[0], dim)).astype(np.float32)
+    ys[int(n_live):] = np.nan
+    ys = jnp.asarray(ys)
+    return ys, order, w, n_live, np.asarray(_scatter_add(ys, order, w,
+                                                         n_live))
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel", "chosen"])
+@pytest.mark.parametrize("case", sorted(COMBINE_CASES))
+def test_the_combine_is_the_scatter_add_of_the_masked_weighed_buffer(
+        case, form, monkeypatch):
+    """Each form of the combine against the scatter-add of the WHOLE
+    buffer that stood in ``serve``: the plain form from the token's
+    side, the kernel (interpreted) from the live rows' side, and what
+    ``serve`` is handed off the TPU.  Only the order of a token's
+    additions may differ.  A width that fills no whole lanes has no
+    kernel: there ``combine`` takes the plain form on the TPU too."""
+    ys, order, w, n_live, want = _combine_operands(case)
+    dim, tiles = COMBINE_CASES[case][4], COMBINE_CASES[case][6]
+    assert np.isfinite(want).all()
+    if case == "no_pair_local_at_all":
+        assert int(n_live) == 0 and not want.any()
+    if form == "plain":
+        got = comb.plain(ys, order, w, n_live)
+    elif form == "chosen":
+        got = comb.combine(ys, order, w, n_live)
+    elif dim % 128:
+        from brpc_tpu.ops import device_ops
+        monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+        got = comb.combine(ys, order, w, n_live)
+    else:
+        kw = {} if tiles is None else dict(out_bytes=tiles[0],
+                                           tile_bytes=tiles[1])
+        if tiles is not None:
+            assert comb._tiles(w.shape[0], ys.shape[0], dim, *tiles) \
+                == (128, 8)
+        got = comb.expert_combine(ys, order, w, n_live, interpret=True,
+                                  **kw)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_serve_gives_what_it_gave_with_the_scatter_add(form, monkeypatch):
+    """``serve`` whole, against itself with the combine it had until
+    PR 38 in place of the new one: the same output to a rounding, the
+    same counts."""
+    ecfg = moe.ExpertConfig(dim=128, hidden=16, routed=16, held=(2, 8),
+                            top_k=4, route_scale=1.5, shared=1)
+    p = moe.init_served(jax.random.PRNGKey(5), ecfg)
+    x = jnp.asarray(np.random.default_rng(5).normal(size=(24, 128))
+                    .astype(np.float32))
+    live = jnp.asarray(np.arange(24) % 5 != 1)
+
+    if form == "kernel":
+        monkeypatch.setattr(
+            comb, "combine", functools.partial(comb.expert_combine,
+                                               interpret=True))
+    got, counts = moe.serve(p, x, ecfg, live)
+    monkeypatch.setattr(comb, "combine", _scatter_add)
+    want, counts0 = moe.serve(p, x, ecfg, live)
+    assert counts.tolist() == counts0.tolist()
+    assert counts[0] > 0 and float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_on_the_tpu_serve_calls_the_combine_kernel_once(monkeypatch):
+    """Traced as on the TPU at a width that fills whole lanes, the
+    layer holds two ``expert_gmm`` and one ``expert_combine`` and no
+    scatter-add of rows; at a width that does not, the plain form under
+    the same name's scope."""
+    from brpc_tpu.ops import device_ops
+    monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+
+    def kernels(dim):
+        ecfg = moe.ExpertConfig(dim=dim, hidden=128, routed=16, held=(0, 4),
+                                top_k=4)
+        p = jax.eval_shape(
+            lambda: moe.init_served(jax.random.PRNGKey(0), ecfg))
+        jaxpr = jax.make_jaxpr(lambda p, t: moe.serve(p, t, ecfg))(
+            p, jax.ShapeDtypeStruct((8, dim), jnp.float32)).jaxpr
+        return [_count_eqns(jaxpr, lambda e: e.primitive.name == "pallas_call"
+                            and e.params["name"] == name)
+                for name in ("expert_gmm", "expert_combine")] + [
+            _count_eqns(jaxpr, lambda e: e.primitive.name == "scatter-add"
+                        and e.outvars[0].aval.shape == (8, dim))]
+
+    assert kernels(128) == [2, 1, 0]
+    assert kernels(192) == [2, 0, 0]
 
 
 @pytest.mark.parametrize("sizes,rows,tm", [

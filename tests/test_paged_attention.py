@@ -212,6 +212,31 @@ def test_expert_gmm_compiles_for_the_v5e_at_real_widths(rows, k, n, one_chip):
     assert "ragged-dot" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
+@pytest.mark.parametrize("tokens,rows,dim", [
+    (64, 512, 7168), (128, 1024, 2304), (16, 128, 4096),
+    (1024, 8192, 7168), (1024, 8192, 2304), (1024, 8192, 4096)])
+def test_expert_combine_compiles_for_the_v5e_at_real_widths(
+        tokens, rows, dim, one_chip):
+    """Mosaic takes the combine's kernel at the three expert
+    configurations' shapes (a step's rows and a 1,024-row bucket's)
+    under the name a device trace is read by; the buffer goes in as it
+    lies (no copy, no temporary), and nothing is run."""
+    from brpc_tpu.ops.expert_combine import expert_combine
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda *a: expert_combine(*a, interpret=False)
+    ).lower(arg((rows, dim), jnp.float32), arg((rows,), jnp.int32),
+            arg((tokens, 8), jnp.float32), arg((), jnp.int32)).compile()
+    assert re.search(
+        rf"%expert_combine[.\d]* = f32\[{tokens},{dim}\]\S* custom-call",
+        compiled.as_text())
+    assert "scatter" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def test_mla_kernel_compiles_for_the_v5e_at_real_widths(one_chip):
     """Mosaic takes the latent kernel at ``kimi-k2.7-code.codegen``'s
     shapes (64 slots of 64 x (512 + 64) queries, a pool of 7,169 pages

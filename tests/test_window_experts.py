@@ -535,10 +535,13 @@ PARENTS = {
                    "prefill": "66821575d05d1acd"}),
     # read anew at PR 36, which changed the latent layer's products on
     # purpose (``mla_mixer.pack``: these are the programs of a tree as
-    # ``init_params`` makes it, packed in the trace)
+    # ``init_params`` makes it, packed in the trace), and at PR 38, which
+    # changed the expert layer's combine on purpose
+    # (``ops/expert_combine.py``: off the TPU its plain form, a gather
+    # from the token's side where the buffer's scatter-add stood)
     "kimi-k2.7-code": ("tests/toy_kimi/config.json",
-                       {"step": "36ae0aedaf336e8f",
-                        "prefill": "dc62c7c78e19b846"}),
+                       {"step": "c04b1284a35f76cf",
+                        "prefill": "c26f21a3e42ece05"}),
 }
 
 
