@@ -37,8 +37,9 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_TOKEN_WALK)
 from . import mla_mixer
 from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
-                             latent_row_bytes, require_plain_block,
-                             state_kinds, state_slot_bytes)
+                             latent_row_bytes, paged_page_bytes,
+                             require_plain_block, state_kinds,
+                             state_slot_bytes)
 
 
 def pack_generate_request(prompt: np.ndarray, max_new: int) -> bytes:
@@ -396,6 +397,18 @@ class ContinuousBatcher:
     ``kv_stats()["window"]`` counts pages held against what whole
     contexts would hold, over steps, and pages given back.
 
+    **Pages a pass** (a looped schedule, ``LMConfig.passes`` > 1: the
+    layers run several times a token, each pass attending keys and
+    values of its own): one block table and one allocator, a logical
+    page standing for its rows in EVERY pass (``paged_page_bytes``
+    counts them all), so admission, eviction and the page accounting
+    are the first block's.  Its prompts too go through the pages in
+    spans at admission (a ``max_seq`` stripe a (pass, layer) would be
+    gigabytes a join); no prefix cache is built for it, and park /
+    resume, slices, speculation and KV import are refused at
+    construction.  ``kv_stats()["loop"]`` counts the layer bodies its
+    steps ran and the spans its fills queued.
+
     **SLO-tiered scheduling** (ROADMAP item 4): the step loop is a
     latency-SLO scheduler over three per-tenant tiers resolved from
     the TLV-22 identity via a :class:`TierRegistry`:
@@ -532,6 +545,11 @@ class ContinuousBatcher:
         # (both summed over layers and steps), the most rows one took
         self._moe = {"steps": 0, "rows": 0, "local_pairs": 0,
                      "experts_touched": 0, "max_load": 0}
+        # a looped schedule's own: steps run and the layer bodies they
+        # ran (``passes * depth`` a step), prompts filled through the
+        # pages, their true rows and the spans queued for them
+        self._loop = {"steps": 0, "layer_passes": 0, "fills": 0,
+                      "fill_rows": 0, "fill_spans": 0}
         # the window class (kv.pages.WindowTable, built with the
         # engine): pages its sessions held at each step against what
         # their whole contexts would hold, summed over steps
@@ -585,8 +603,11 @@ class ContinuousBatcher:
         if span is not None:
             span.annotate("lm_join")
             if not self.cfg.plain_block():
-                # a block beyond the first: which mixer each layer has
-                span.annotate("lm_schedule:" + self.cfg.schedule())
+                # a block beyond the first: which mixer each layer
+                # has, and how often the stack is run where it is looped
+                span.annotate("lm_schedule:" + self.cfg.schedule()
+                              + (f"*{self.cfg.passes}"
+                                 if self.cfg.passes > 1 else ""))
         self._enqueue(sess)
 
     def _assign_tier(self, sess: _Session, tenant) -> None:
@@ -684,6 +705,10 @@ class ContinuousBatcher:
         cfg = self.cfg
         if cfg.has_kda:
             out["kda"] = {**out["state"]["kinds"]["kda"], **self._kda}
+        if cfg.passes > 1:
+            out["loop"] = {"passes": cfg.passes, "layers": cfg.depth,
+                           "token_bytes": paged_page_bytes(cfg, self.page)
+                           // self.page, **self._loop}
         if cfg.has_experts:
             lo, hi = cfg.experts_held
             out["moe"] = {"layers": len(cfg.expert_layers()),
@@ -732,8 +757,7 @@ class ContinuousBatcher:
                                      make_paged_io,
                                      make_paged_batch_decode,
                                      make_paged_span_fill,
-                                     make_paged_spec_verify,
-                                     paged_page_bytes)
+                                     make_paged_spec_verify)
 
         if self._prefill is None:
             from ..ops import paged_attention
@@ -774,9 +798,10 @@ class ContinuousBatcher:
                 chunk_prefill, self.params, donate_argnums=(0,))
             self._setlen_j = jax.jit(_setlen, donate_argnums=(0,))
             self._settok_j = jax.jit(_settok)
-            if self.cfg.has_window:
-                # a window schedule's prompts go into the pages in
-                # spans of one shape (prefill and insert decline it)
+            if self.cfg.has_window or self.cfg.passes > 1:
+                # a window schedule's prompts, and a looped one's, go
+                # into the pages in spans of one shape (prefill and
+                # insert decline them)
                 self._span_fill = jit_with_params(
                     make_paged_span_fill(self.cfg, self.page),
                     self.params, donate_argnums=(0,))
@@ -985,7 +1010,7 @@ class ContinuousBatcher:
             # its insert is what clears the slot's state.)
             last = int(sess.prompt[-1])
             start_len = ctx_len
-        elif self.cfg.has_window:
+        elif self._span_fill is not None:
             if not self._fill_spans(sess, free, row, ctx_len):
                 self._wt.release_slot(free)
                 self._refuse(sess, "kv_pool_exhausted", priv)
@@ -1067,11 +1092,12 @@ class ContinuousBatcher:
 
     def _fill_spans(self, sess: _Session, slot: int, row, ctx_len: int
                     ) -> bool:
-        """A window schedule's prompt, written into the slot's pages in
-        spans (``make_paged_span_fill``), all queued here: each span a
-        ``prefill_dispatch`` of its own, the window class's pages taken
-        and given back around it inside ``page_alloc``.  False where
-        the window class ran out of pages."""
+        """A window schedule's prompt, or a looped one's, written into
+        the slot's pages in spans (``make_paged_span_fill``), all
+        queued here: each span a ``prefill_dispatch`` of its own, the
+        window class's pages (where there is one) taken and given back
+        around it inside ``page_alloc``.  False where the window class
+        ran out of pages."""
         import jax.numpy as jnp
         ph = self._clock.switch
         w, win, wt = self.cfg.fill_span, self.cfg.window, self._wt
@@ -1079,18 +1105,29 @@ class ContinuousBatcher:
         row_d = jnp.asarray(row)
         for start in range(0, ctx_len, w):
             n = min(w, ctx_len - start)
-            ph(PH_PAGE_ALLOC)
-            if not wt.cover(slot, max(0, start - win + 1), start + n - 1):
-                return False
-            ph(PH_PREFILL_DISPATCH)
             ids = np.zeros((w,), np.int32)
             ids[:n] = ctx[start:start + n]
-            # (a private copy of the row: the next span's ``cover``
-            # changes it under a program that may not have read it yet)
-            self._cache = self._span_fill(
-                self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
-                np.int32(slot), np.int32(start), np.int32(n), ids)
+            where = (np.int32(slot), np.int32(start), np.int32(n), ids)
+            if wt is None:
+                ph(PH_PREFILL_DISPATCH)
+                self._cache = self._span_fill(self._cache, row_d, *where)
+            else:
+                ph(PH_PAGE_ALLOC)
+                if not wt.cover(slot, max(0, start - win + 1),
+                                start + n - 1):
+                    return False
+                ph(PH_PREFILL_DISPATCH)
+                # (a private copy of the row: the next span's ``cover``
+                # changes it under a program that may not have read it
+                # yet)
+                self._cache = self._span_fill(
+                    self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
+                    *where)
             self._clock.filling(1, n)
+        if wt is None:
+            self._loop["fills"] += 1
+            self._loop["fill_rows"] += ctx_len
+            self._loop["fill_spans"] += -(-ctx_len // w)
         return True
 
     def _cover_windows(self) -> bool:
@@ -1549,6 +1586,9 @@ class ContinuousBatcher:
         if self.cfg.has_kda:
             self._kda["steps"] += 1
             self._kda["slot_steps"] += len(snap)
+        if self.cfg.passes > 1:
+            self._loop["steps"] += 1
+            self._loop["layer_passes"] += self.cfg.passes * self.cfg.depth
         return _Flight(toks, snap, counts, clock.queued(
             queued_at, step, len(snap), ahead,
             int(ride[2]) if ride is not None else 0))
@@ -2072,6 +2112,9 @@ class LMService(Service):
                    f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
         if c.has_kda:
             fp += f":{c.kda_heads}x{c.kda_head_dim}x{c.kda_conv}"
+        if c.passes > 1 or c.post_norms:
+            # pages a pass; the norms behind the branches
+            fp += f":{c.passes}p{int(c.post_norms)}:{c.rope_theta}"
         if c.has_window or c.parallel_block:
             fp += (f":{c.head_dim}:{c.norm}:{int(c.parallel_block)}:"
                    f"{c.window}:" + "".join(
@@ -2143,6 +2186,13 @@ class LMService(Service):
                             "bytes": self.decode_slots
                             * state_slot_bytes(c),
                             "kinds": state_kinds(c)})
+        if c.passes > 1:
+            # the layers run several times a token, pages a pass
+            info["loop"] = {
+                "passes": c.passes, "layers": c.depth,
+                "post_norms": c.post_norms,
+                "token_bytes": paged_page_bytes(c, self.page) // self.page,
+                "fill_span": c.fill_span}
         if c.has_window:
             # two page classes: whole contexts, and windows
             info["window_pool"] = {

@@ -60,7 +60,9 @@ class LMConfig:
                  rope_pairs: str = "halves", router_bias: bool = True,
                  shared_average: bool = False, fill_span: int = 1024,
                  kda_heads: Optional[int] = None,
-                 kda_head_dim: Optional[int] = None, kda_conv: int = 4):
+                 kda_head_dim: Optional[int] = None, kda_conv: int = 4,
+                 passes: int = 1, post_norms: bool = False,
+                 exit_threshold: float = 1.0):
         assert head_dim is not None or dim % heads == 0
         hd = dim // heads if head_dim is None else int(head_dim)
         assert not rope or hd % 2 == 0, "head dim must be even for RoPE"
@@ -115,8 +117,9 @@ class LMConfig:
         # attention and feed-forward both read ONE norm of the layer's
         # input and both add to it: x + A(h) + F(h), h = norm(x)
         self.parallel_block = bool(parallel_block)
-        # a prompt of a window schedule is filled in spans of this many
-        # rows (``make_paged_span_fill``), never as one bucket
+        # a prompt of a window schedule, or of a looped one, is filled
+        # in spans of this many rows (``make_paged_span_fill``), never
+        # as one bucket
         self.fill_span = int(fill_span)
         assert ffn in ("gelu", "gated_silu")
         self.ffn = ffn
@@ -202,6 +205,39 @@ class LMConfig:
                 raise UnsupportedBlock(
                     "window layers are served over the grouped page "
                     "layout only (kv_heads < heads)")
+        # a looped schedule: the whole stack of layers is run ``passes``
+        # times a token, the weights shared by the passes; pass ``t``
+        # reads the row pass ``t - 1`` left (the final norm, where the
+        # block has one, closes EVERY pass) and attends the keys and
+        # values of ITS OWN pass, so a token pins ``passes`` rows a
+        # layer and a layer's pool holds ``passes`` times the pages
+        # (pass ``t`` of logical page ``p`` lies at ``t * num_pages +
+        # p``).  ``post_norms``: a norm of its own on each branch
+        # (attention's, the feed-forward's) before the residual adds
+        # it.  ``exit_threshold``: an exit gate (``exit_w``, ``exit_b``)
+        # lets a row leave at the first pass whose cumulated exit
+        # probability reaches it; at 1 that is always the last pass,
+        # the only value served: under it rows of one step would stop
+        # at different depths
+        self.passes = int(passes)
+        self.post_norms = bool(post_norms)
+        self.exit_threshold = float(exit_threshold)
+        assert self.passes >= 1
+        if self.exit_threshold < 1.0:
+            raise UnsupportedBlock(
+                f"exit_threshold {self.exit_threshold} (a token's depth "
+                "chosen by the exit gate) is not served: every row of a "
+                "step runs all the passes (exit_threshold 1)")
+        if self.passes > 1 or self.post_norms:
+            if set(self.mixers) != {"attn"} or self.has_experts \
+                    or self.has_window or self.parallel_block \
+                    or self.kv_heads != heads:
+                raise UnsupportedBlock(
+                    "more than one pass over the layers, and the norms "
+                    "behind attention and feed-forward, are served for "
+                    "a schedule of 'attn' mixers over whole heads with "
+                    "dense feed-forwards only: not beside a state, "
+                    "latent, window or expert layer, nor grouped heads")
         self.ssm_inner = int(ssm_expand) * dim
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
@@ -245,7 +281,8 @@ class LMConfig:
                 and not self.has_window and all(self.ropes)
                 and self.rope_pairs == "halves" and self.norm == "rms"
                 and not self.parallel_block
-                and self.rope_theta == 10000.0)
+                and self.rope_theta == 10000.0
+                and self.passes == 1 and not self.post_norms)
 
     def window_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, w in enumerate(self.windows) if w)
@@ -340,6 +377,8 @@ def require_plain_block(cfg: LMConfig, what: str) -> None:
         if cfg.has_latent \
         else "window layers (a page class that gives pages back)" \
         if cfg.has_window \
+        else "a looped schedule (the layers run several times a token, " \
+        "each pass with pages of its own)" if cfg.passes > 1 \
         else "a block other than MHA + rotary + GELU MLP + untied table"
     raise UnsupportedBlock(
         f"{what} declines {why}: only the paged serving factories "
@@ -412,6 +451,10 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         params["unembed"] = normal(ks[1], (d, cfg.vocab), d)
     if cfg.final_norm:
         params["norm_f"] = jnp.ones((d,), jnp.float32)
+    if cfg.passes > 1:
+        # the exit gate of a looped schedule (see ``LMConfig``)
+        params["exit_w"] = normal(ks[1], (d,), d)
+        params["exit_b"] = jnp.zeros((), jnp.float32)
     gated = cfg.ffn == "gated_silu"
     for i in range(cfg.depth):
         bk = jax.random.split(ks[2 + i], 5)
@@ -426,6 +469,9 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         blk["ln1"] = jnp.ones((d,), jnp.float32)
         if not cfg.parallel_block:
             blk["ln2"] = jnp.ones((d,), jnp.float32)
+        if cfg.post_norms:
+            blk["pn1"] = jnp.ones((d,), jnp.float32)
+            blk["pn2"] = jnp.ones((d,), jnp.float32)
         if cfg.ffns[i] == "experts":
             blk["moe"] = moe.init_served(bk[2], cfg.expert_cfg())
         else:
@@ -537,8 +583,12 @@ def _ffn(cfg: LMConfig, bp, h):
 
 def _ffn_residual(cfg: LMConfig, bp, x):
     """The second half of a serving layer, after either mixer: norm,
-    :func:`_ffn`, residual."""
-    return x + _ffn(cfg, bp, _norm(cfg, x, bp["ln2"]))
+    :func:`_ffn`, residual (where the block has post-norms the branch
+    is normed, ``pn2``, before the residual adds it)."""
+    out = _ffn(cfg, bp, _norm(cfg, x, bp["ln2"]))
+    if cfg.post_norms:
+        out = _norm(cfg, out, bp["pn2"])
+    return x + out
 
 
 def _ffn_part(cfg: LMConfig, i: int, bp, h, live):
@@ -628,6 +678,8 @@ def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None):
     from ..ops.quant import qmatmul
     b, w, _ = x.shape
     a = qmatmul(att.reshape(b, w, cfg.heads * cfg.head_dim), bp["wo"])
+    if cfg.post_norms:
+        a = _norm(cfg, a, bp["pn1"])
     if cfg.parallel_block:
         # the same norm :func:`_qkv` took: one in the compiled program
         m, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln1"]), live)
@@ -636,13 +688,49 @@ def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None):
 
 
 def _logits(cfg: LMConfig, params, x):
-    """Final norm (where the block has one) and the unembedding, the
-    embedding table itself where it is tied."""
-    from ..ops.quant import qmatmul
+    """Final norm (where the block has one) and the unembedding."""
     if cfg.final_norm:
         x = _norm(cfg, x, params["norm_f"])
+    return _unembed(cfg, params, x)
+
+
+def _unembed(cfg: LMConfig, params, x):
+    """The unembedding, the embedding table itself where it is tied."""
+    from ..ops.quant import qmatmul
     return qmatmul(x, params["embed"].T if cfg.tie_embed
                    else params["unembed"])
+
+
+def _looped(cfg: LMConfig, params, cache, x, layer):
+    """A looped schedule's layers (``LMConfig.passes``): the stack run
+    ``cfg.passes`` times as ONE loop of the compiled program (a body
+    of ``depth`` layers iterated with the pass as a value; ``lm_pass``
+    in a device trace), the pools carried through it and written in
+    place.  ``layer(i, bp, x, pk, pv, off) -> (x, pk, pv)`` is the
+    calling program's attention layer ``i``, ``off`` the first page of
+    the pass in every pool (``t * num_pages``: what shifts a block
+    table into the pass's pages, its garbage page included).  The
+    final norm closes every pass, so the row that leaves the last one
+    goes to :func:`_unembed` as it is.  Returns ``(x, cache)``."""
+    import jax
+
+    n_pages = cache["pk0"].shape[0] // cfg.passes
+    pools = {name: pool for name, pool in cache.items() if name != "len"}
+
+    def one_pass(t, carry):
+        x, pools = carry
+        pools = dict(pools)
+        for i in range(cfg.depth):
+            x, pools[f"pk{i}"], pools[f"pv{i}"] = layer(
+                i, params[f"blk{i}"], x, pools[f"pk{i}"], pools[f"pv{i}"],
+                t * n_pages)
+        if cfg.final_norm:
+            x = _norm(cfg, x, params["norm_f"])
+        return x, pools
+
+    with jax.named_scope("lm_pass"):
+        x, pools = jax.lax.fori_loop(0, cfg.passes, one_pass, (x, pools))
+    return x, {**cache, **pools}
 
 
 def make_forward(cfg: LMConfig, mesh=None, sp_axis: Optional[str] = None):
@@ -757,11 +845,20 @@ def make_prefill(cfg: LMConfig):
     (``h<i>`` and the convolution's tail ``c<i>``), an attention layer
     ``k<i>``/``v<i>``
     as :func:`make_decode`'s does, a latent layer its latent rows
-    ``l<i>``; the logits are those of position ``ctx_len - 1``."""
+    ``l<i>``; the logits are those of position ``ctx_len - 1``.  Window
+    layers and looped schedules decline: their prompts go through the
+    pages (:func:`make_paged_span_fill`)."""
     import jax.numpy as jnp
 
     from . import mla_mixer
 
+    if cfg.passes > 1:
+        def declined(*_a, **_k):
+            raise UnsupportedBlock(
+                "make_prefill (a whole prompt into a max_seq cache) "
+                f"declines a schedule of {cfg.passes} passes: "
+                + _looped_stripes(cfg))
+        return declined
     if cfg.has_window:
         def declined(*_a, **_k):
             raise UnsupportedBlock(
@@ -804,6 +901,16 @@ def make_prefill(cfg: LMConfig):
         return cache, _logits(cfg, params, last)
 
     return prefill
+
+
+def _looped_stripes(cfg: LMConfig) -> str:
+    """Why a looped schedule's prompt is not prefilled whole: the bytes
+    of a ``max_seq`` stripe a (pass, layer), for the decline's text."""
+    nbytes = cfg.passes * cfg.depth * 2 * cfg.max_seq * cfg.kv_heads \
+        * cfg.head_dim * 4
+    return (f"a max_seq stripe of keys and values a (pass, layer) is "
+            f"{nbytes:,} bytes for ONE join; its prompts are filled in "
+            "spans through the pages (make_paged_span_fill)")
 
 
 def make_decode(cfg: LMConfig):
@@ -1021,6 +1128,16 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     values are the same rows.
     Unrolled layers only.
 
+    A looped schedule (``LMConfig.passes`` > 1) runs ``passes x depth``
+    layer bodies a token, the passes ONE loop of the compiled program
+    (:func:`_looped`): each attention writes and reads the pages of
+    ITS (pass, layer).  A layer's pools hold ``passes * num_pages``
+    pages and pass ``t`` of logical page ``p`` lies at ``t * num_pages
+    + p``: the block table is shifted by a scalar, so one logical page
+    stands for its rows in every pass and the allocator, the garbage
+    page (one a pass) and the kernel keep their meaning.  The final
+    norm closes every pass and the last pass's row is unembedded.
+
     With ``chunk`` set a THIRD program rides along, also named
     ``step``: the step with one catch-up slice on board
     (Sarathi-style, one pass over the weights for both), ``step(params,
@@ -1093,6 +1210,15 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         att_pos = jnp.where(active, pos, 0)
         x = _embed_rows(params, token)[:, None, :]
         rot = _rope_at(cfg, pos[:, None])
+        if cfg.passes > 1:
+            x, cache = _looped(
+                cfg, params, cache, x,
+                lambda i, bp, x, pk, pv, off: attn_layer(
+                    i, bp, x, pk, pv, bt + off, pos, att_pos, rot,
+                    active)[:3])
+            cache["len"] = jnp.where(active, cache["len"] + 1,
+                                     cache["len"])
+            return cache, _unembed(cfg, params, x[:, 0])
         # a window schedule has a block table a page class: ``bt`` is
         # ``(2, slots, max_seq // page)``, whole contexts then windows
         bts = (bt, bt) if not cfg.has_window else (bt[0], bt[1])
@@ -1208,12 +1334,14 @@ def empty_paged_cache(cfg: LMConfig, num_pages: int, slots: int,
     The block table is NOT here — it is host state
     (``kv.pages.PageAllocator`` decides it), passed to the step.  A
     window layer's pools hold ``cfg.window_pages(slots, page)`` pages:
-    a class of their own, with a garbage page 0 of its own."""
+    a class of their own, with a garbage page 0 of its own.  A looped
+    schedule's pools hold ``cfg.passes * num_pages`` pages, a pass
+    after the other, each with its garbage page first."""
     import jax.numpy as jnp
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
-    shape = _paged_pool_shape(cfg, num_pages, page)
+    shape = _paged_pool_shape(cfg, cfg.passes * num_pages, page)
     if cfg.has_window:
         wshape = _paged_pool_shape(cfg, cfg.window_pages(slots, page),
                                    page)
@@ -1241,12 +1369,13 @@ def paged_page_bytes(cfg: LMConfig, page: int,
     """Device bytes one LOGICAL page pins across every attention
     layer's k+v pools and every latent layer's pool (the allocator's
     per-page accounting unit); of a window schedule, across the layers
-    of the page's class."""
+    of the page's class; of a looped schedule, in every pass (a logical
+    page stands for its rows in all of them)."""
     layers = len(cfg.attn_layers())
     if cfg.has_window:
         n_win = len(cfg.window_layers())
         layers = n_win if window_class else layers - n_win
-    return 2 * layers * page * cfg.kv_heads \
+    return 2 * cfg.passes * layers * page * cfg.kv_heads \
         * cfg.head_dim * 4 + latent_row_bytes(cfg) * page  # float32
 
 
@@ -1328,7 +1457,11 @@ def _slice_rows(cfg: LMConfig, page: int, bt_row, start, n, width: int):
 
 
 def make_paged_span_fill(cfg: LMConfig, page: int):
-    """A window schedule's prompt pass: ``fill(params, cache,
+    """The prompt pass of the schedules whose prompts go through the
+    pages: a window schedule's (below), and a looped schedule's
+    (:func:`_looped_span_fill`, another signature: one page class).
+
+    A window schedule's: ``fill(params, cache,
     bt_row[pps], btw_row[pps], slot, start, n, ids[fill_span]) ->
     cache`` writes ``n`` context tokens of ``slot`` at positions
     ``start..start+n-1`` straight into its pages (a global layer's
@@ -1350,15 +1483,18 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
 
     from ..ops import span_attention
 
-    if not cfg.has_window:
+    if not cfg.has_window and cfg.passes == 1:
         def declined(*_a, **_k):
             raise UnsupportedBlock(
-                "make_paged_span_fill serves window schedules only: "
-                "every other block fills through make_prefill + insert")
+                "make_paged_span_fill serves window schedules and "
+                "looped schedules only: every other block fills "
+                "through make_prefill + insert")
         return declined
     if cfg.max_seq % page:
         raise ValueError(
             f"page size {page} must divide max_seq {cfg.max_seq}")
+    if cfg.passes > 1:
+        return _looped_span_fill(cfg, page)
     w, kvh, pps = cfg.fill_span, cfg.kv_heads, cfg.max_seq // page
     reach = min(pps, (cfg.window + w) // page + 2)
 
@@ -1386,6 +1522,49 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
                                            p0 * page, page, win)
             x, _counts = _attn_out(cfg, bp, x, att[None], i, real[None])
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
+        cache["len"] = cache["len"].at[slot].set(start + n)
+        return cache
+
+    return fill
+
+
+def _looped_span_fill(cfg: LMConfig, page: int):
+    """A looped schedule's prompt pass (whole heads, no window, every
+    pass inside it): ``fill(params, cache, bt_row[pps], slot, start,
+    n, ids[fill_span]) -> cache`` writes ``n`` context tokens of
+    ``slot`` at positions ``start..start+n-1`` into its pages IN EVERY
+    PASS and sets the slot's len to ``start + n``; padding rows go to
+    the pass's garbage page.  A prompt is as many calls of this one
+    program as it has spans, in order.  The passes are the step's loop
+    (:func:`_looped`): in each (pass, layer) the span's rows are
+    scattered into the pass's pages, then its queries attend over them
+    (``ops/span_attention``, the whole block table), so pass ``t`` of
+    a later span finds pass ``t`` of the earlier ones.  Identical by
+    construction with as many single steps."""
+    import jax.numpy as jnp
+
+    from ..ops import span_attention
+
+    w = cfg.fill_span
+
+    def fill(params, cache, bt_row, slot, start, n, ids):
+        j = jnp.arange(w)
+        real = j < n
+        posc = jnp.minimum(start + j, cfg.max_seq - 1)
+        page_idx = jnp.where(real, bt_row[posc // page], 0)
+        row = posc % page
+        rot = _rope_at(cfg, start + j)
+
+        def layer(i, bp, x, pk, pv, off):
+            q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
+            pk = pk.at[page_idx + off, row].set(k[0])
+            pv = pv.at[page_idx + off, row].set(v[0])
+            att = span_attention.attention(q[0], pk, pv, bt_row + off,
+                                           start, 0, page)
+            return _attn_out(cfg, bp, x, att[None], i, real[None])[0], pk, pv
+
+        _x, cache = _looped(cfg, params, cache,
+                            _embed_rows(params, ids)[None], layer)
         cache["len"] = cache["len"].at[slot].set(start + n)
         return cache
 
@@ -1438,6 +1617,10 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
     pps = cfg.max_seq // page
 
     def insert(cache, page_ids, src, slot):
+        if cfg.passes > 1:
+            raise UnsupportedBlock(
+                f"make_paged_io insert declines a schedule of "
+                f"{cfg.passes} passes: " + _looped_stripes(cfg))
         if cfg.has_window:
             raise UnsupportedBlock(
                 "make_paged_io insert declines window layers: no whole-"

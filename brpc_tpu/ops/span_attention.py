@@ -1,11 +1,14 @@
 """Span attention — a span of a prompt's rows against the pages written
-so far, for key/value heads shared by groups of query heads and for
-layers whose reach is a window.
+so far: for key/value heads shared by groups of query heads and layers
+whose reach is a window, and for whole heads without one.
 
-A window schedule (``LMConfig.windows``) fills a prompt in spans of
-``fill_span`` rows (``transformer_lm.make_paged_span_fill``): a span's
-keys and values are scattered into its session's pages, then its
-queries attend over what lies in them.  The pages are gathered once
+A window schedule (``LMConfig.windows``) and a looped one
+(``LMConfig.passes``) fill a prompt in spans of ``fill_span`` rows
+(``transformer_lm.make_paged_span_fill``): a span's keys and values are
+scattered into its session's pages, then its queries attend over what
+lies in them.  A whole-head pool ``(num_pages, page, heads, hd)`` is
+the grouped layout with a group of one (the same bytes in the same
+order), and is taken as it is.  The pages are gathered once
 into ``(kv_heads, keys, hd)`` (a window layer: only those the window
 reaches) and a flash kernel (``span_flash_attention`` in a device
 trace) walks them in blocks with an online softmax: ``(kv_heads, query
@@ -33,12 +36,16 @@ _BLOCK_K = 512         # keys a block
 
 def _gather(pool, page_ids, page: int):
     """``pool (num_pages, page * kv_heads, hd)`` (a row a (token,
-    key/value head) pair) at ``page_ids (P,)`` -> ``(kv_heads, P *
-    page, hd)``."""
-    kvh = pool.shape[1] // page
+    key/value head) pair; or ``(num_pages, page, heads, hd)``) at
+    ``page_ids (P,)`` -> ``(kv_heads, P * page, hd)``."""
+    kvh = _kv_heads(pool, page)
     x = pool[page_ids].reshape(page_ids.shape[0] * page, kvh,
                                pool.shape[-1])
     return x.transpose(1, 0, 2)
+
+
+def _kv_heads(pool, page: int) -> int:
+    return pool.shape[2] if pool.ndim == 4 else pool.shape[1] // page
 
 
 def _allowed(qpos, kpos, window: int):
@@ -171,7 +178,7 @@ def span_flash_attention(q, pk, pv, page_ids, q0, k0, page: int,
     import jax.numpy as jnp
 
     w, heads, hd = q.shape
-    kvh = pk.shape[1] // page
+    kvh = _kv_heads(pk, page)
     g = heads // kvh
     bq = min(block_q, w)
     assert w % bq == 0

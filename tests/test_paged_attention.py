@@ -257,6 +257,29 @@ def test_mla_kernel_compiles_for_the_v5e_at_real_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+def test_span_kernel_over_whole_heads_compiles_for_the_v5e(one_chip):
+    """Mosaic takes ``span_flash_attention`` over a WHOLE-HEAD pool at
+    ``ouro-2.6b.batch``'s shapes (a span of 256 rows of 16 heads x 128
+    against a 128-wide block table of a pool of 4 x 196 pages: the
+    grouped layout with a group of one) as one custom call under the
+    name a device trace is read by; nothing is run."""
+    from brpc_tpu.ops import span_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((4 * 196, PAGE, 16, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda q, pk, pv, ids, start: span_attention.span_flash_attention(
+            q, pk, pv, ids, start, 0, PAGE, interpret=False)
+    ).lower(arg((256, 16, 128), jnp.float32), pool, pool,
+            arg((128,), jnp.int32), arg((), jnp.int32)).compile()
+    calls = re.findall(r"%(span_flash_attention[.\w]*) = (\S+) custom-call",
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0][1].startswith("f32[16,256,128]"), \
+        calls
+
+
 # -- the counter ------------------------------------------------------------
 
 class _Stream:
