@@ -20,8 +20,13 @@ of size ``d``::
 
 What a sequence carries from token to token is ``S`` (``H x d x d``
 float32, key x value: ``ops.delta_rule``'s layout) and the three
-convolutions' last ``kda_conv - 1`` inputs (one block of ``3 H d``
-columns, q then k then v).  Every weight matmul goes through
+convolutions' last ``n = kda_conv - 1`` inputs, a RING of ``n`` rows of
+``3 H x d`` (q's heads, then k's, then v's): the input of position ``t``
+lies in row ``t mod n``, so a step writes ONE row, over the oldest, at
+the slot's length modulo ``n`` (its phase), and the convolution reads
+the other rows where they lie, its taps turned by the phase.  A one-row
+scatter writes that row where the ring lies: an idle slot's row is
+dropped and its ring not touched.  Every weight matmul goes through
 ``qmatmul`` like the attention layers' (bf16 operands, float32
 accumulation); the convolution, the norms, the gates and the
 recurrence are float32.
@@ -75,7 +80,7 @@ def init_layer(key, cfg) -> dict:
 def state_shapes(cfg, batch: int) -> tuple:
     """``(S, tail)`` shapes of ``batch`` sequences' state."""
     h, hd = cfg.kda_heads, cfg.kda_head_dim
-    return ((batch, h, hd, hd), (batch, cfg.kda_conv - 1, 3 * h * hd))
+    return ((batch, h, hd, hd), (batch, cfg.kda_conv - 1, 3 * h, hd))
 
 
 def state_bytes(cfg) -> int:
@@ -86,15 +91,15 @@ def state_bytes(cfg) -> int:
 
 def _recurrence_inputs(cfg, bp, x, qkv):
     """``q, k, v, a (..., H, d)`` and ``b (..., H)`` of the recurrence
-    from the normed rows ``x`` and the convolved ``qkv``."""
+    from the normed rows ``x`` and the convolved ``qkv (..., 3 H, d)``."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.quant import qmatmul
 
     h, hd = cfg.kda_heads, cfg.kda_head_dim
-    heads = qkv.shape[:-1] + (h, hd)
-    q, k, v = (t.reshape(heads) for t in jnp.split(qkv, 3, axis=-1))
+    heads = qkv.shape[:-2] + (h, hd)
+    q, k, v = jnp.split(qkv, 3, axis=-2)
 
     def unit(t):
         return t * jax.lax.rsqrt(
@@ -122,10 +127,11 @@ def _out(cfg, bp, x, y):
 
 def prefill(cfg, bp, x, ctx_len):
     """``x (1, s, dim)``, normed, zero-padded past ``ctx_len`` ->
-    ``(out (1, s, dim), S, tail)``: the state after position
+    ``(out (1, s, dim), S, ring)``: the state after position
     ``ctx_len - 1`` (the recurrence is frozen past it) and the
     convolutions' inputs at ``ctx_len - kda_conv + 1 .. ctx_len - 1``
-    (zeros before the sequence's start)."""
+    (zeros before the sequence's start), each in the ring's row of its
+    position."""
     import jax
     import jax.numpy as jnp
 
@@ -133,22 +139,27 @@ def prefill(cfg, bp, x, ctx_len):
     from ..ops.quant import qmatmul
 
     s, kc = x.shape[1], cfg.kda_conv
+    state_shape, ring_shape = state_shapes(cfg, 1)
     qkv = qmatmul(x, bp["wqkv"])
     pad = jnp.pad(qkv, ((0, 0), (kc - 1, 0), (0, 0)))
     tail = jax.lax.dynamic_slice(pad, (0, ctx_len, 0),
                                  (1, kc - 1, qkv.shape[-1]))
+    # ``tail[j]`` is position ``ctx_len - (kc - 1) + j``
+    ring = jnp.roll(tail, ctx_len % (kc - 1), axis=1)
     qkv = jax.nn.silu(sum(bp["conv_w"][j] * pad[:, j:j + s]
                           for j in range(kc)))
-    q, k, v, a, b = _recurrence_inputs(cfg, bp, x, qkv)
+    q, k, v, a, b = _recurrence_inputs(
+        cfg, bp, x, qkv.reshape((1, s) + ring_shape[2:]))
     y, state = delta_rule.scan(
-        q, k, v, a, b, jnp.zeros(state_shapes(cfg, 1)[0], jnp.float32),
+        q, k, v, a, b, jnp.zeros(state_shape, jnp.float32),
         jnp.reshape(ctx_len, (1,)).astype(jnp.int32))
-    return _out(cfg, bp, x, y), state, tail
+    return _out(cfg, bp, x, y), state, ring.reshape(ring_shape)
 
 
-def step(cfg, bp, x, state, tail, active):
-    """``x (slots, dim)``, normed; ``state``, ``tail`` the layer's
-    state pool -> ``(out (slots, dim), state, tail)`` with the state of
+def step(cfg, bp, x, state, ring, active, lens):
+    """``x (slots, dim)``, normed; ``state``, ``ring`` the layer's
+    state pool; ``lens (slots,)`` the slots' lengths, the position this
+    step is -> ``(out (slots, dim), state, ring)`` with the state of
     ``active`` slots advanced one position."""
     import jax
     import jax.numpy as jnp
@@ -156,10 +167,27 @@ def step(cfg, bp, x, state, tail, active):
     from ..ops import delta_rule
     from ..ops.quant import qmatmul
 
-    qkv = qmatmul(x, bp["wqkv"])
-    window = jnp.concatenate([tail, qkv[:, None]], axis=1)
-    tail = jnp.where(active[:, None, None], window[:, 1:], tail)
-    qkv = jax.nn.silu(jnp.sum(bp["conv_w"][None] * window, axis=1))
+    n = cfg.kda_conv - 1
+    row = qmatmul(x, bp["wqkv"]).reshape(ring.shape[:1] + ring.shape[2:])
+    taps = bp["conv_w"].reshape((n + 1,) + ring.shape[2:])
+    phase = lens % n
+    turned = [(phase == p)[:, None, None] for p in range(n)]
+
+    def past(j):
+        # the input of position ``lens - n + j``, in row ``(phase + j)
+        # mod n`` of its slot's ring
+        rows = ring[:, j]
+        for p in range(1, n):
+            rows = jnp.where(turned[p], ring[:, (p + j) % n], rows)
+        return rows
+
+    qkv = jax.nn.silu(sum(taps[j] * past(j) for j in range(n))
+                      + taps[n] * row)
     q, k, v, a, b = _recurrence_inputs(cfg, bp, x, qkv)
     y, state = delta_rule.step(q, k, v, a, b, state, active)
-    return _out(cfg, bp, x, y), state, tail
+    # the new row over the oldest, in place; an idle slot's place is
+    # past its ring, so its row is dropped
+    ring = ring.at[jnp.arange(ring.shape[0]), jnp.where(active, phase, n)
+                   ].set(row, mode="drop", unique_indices=True,
+                         indices_are_sorted=True)
+    return _out(cfg, bp, x, y), state, ring

@@ -136,10 +136,13 @@ def prefill(cfg, bp, x, ctx_len):
     return qmatmul(y * jax.nn.silu(z), bp["out_proj"]), h, tail
 
 
-def step(cfg, bp, x, h, tail, active):
+def step(cfg, bp, x, h, tail, active, lens):
     """``x (slots, dim)``, normed; ``h``, ``tail`` the layer's state
-    pool -> ``(out (slots, dim), h, tail)`` with the state of
+    pool; ``lens (slots,)`` the slots' lengths, which every state layer
+    is handed and this one does not read (its tail is kept in time
+    order) -> ``(out (slots, dim), h, tail)`` with the state of
     ``active`` slots advanced one position."""
+    del lens
     import jax
     import jax.numpy as jnp
 

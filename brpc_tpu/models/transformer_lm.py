@@ -362,7 +362,7 @@ STATE_MIXERS = ("ssm", "kda")
 def _state_mixer(kind: str):
     """The module of a state layer's kind: both have ``init_layer``,
     ``state_shapes``, ``state_bytes``, ``prefill(cfg, bp, x, ctx_len)``
-    and ``step(cfg, bp, x, state, tail, active)``."""
+    and ``step(cfg, bp, x, state, tail, active, lens)``."""
     from . import kda_mixer, ssm_mixer
     return {"ssm": ssm_mixer, "kda": kda_mixer}[kind]
 
@@ -1241,10 +1241,12 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 # the slot's recurrent state moves one position where
                 # the slot is active and stays where it is not (a
                 # ``"kda"`` layer's in place: an idle slot's block is
-                # not touched at all)
+                # not touched at all; its tails are a ring that turns
+                # with the slot's length)
                 out, h, tail = _state_mixer(cfg.mixers[i]).step(
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
-                    cache[f"sh{i}"], cache[f"sc{i}"], active)
+                    cache[f"sh{i}"], cache[f"sc{i}"], active,
+                    cache["len"])
                 x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
                                         active[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail

@@ -20,15 +20,20 @@ nothing (``a`` is taken as 1 and ``b`` as 0 there); the batched decode
 step is one position a slot, and a slot that is not ``active`` is not
 touched at all.
 
+Every form here computes the write as ``k (b u)^T``: ``b`` is one number
+a head, so it scales the ROW ``u`` and ``k``'s column serves ``S'^T k``
+and the write both.  The kernels are held BIT-equal to
+:func:`sequential`, which therefore takes the same association.
+
 Layout.  A state lies ``(heads, d, d)`` with the KEY channel on the
 sublanes and the value on the lanes, so the two reductions over keys
 (``S'^T k``, ``S^T q``) are vector adds and the value rows (``v``,
-``u``, ``y``) are rows as they arrive.  What multiplies a state by KEY
-channel (``a``, ``k``, ``b k``, ``q``) must be a column: the wrappers
-hand the kernels those four packed and transposed, ``(..., d, 4
-heads)``, key on the sublanes and ``[q | k | a | b k]`` by head on the
-lanes (exactly 128 lanes at 32 heads), and a kernel takes head ``h``'s
-column by a static lane slice.
+``u``, ``b u``, ``y``) are rows as they arrive.  What multiplies a state
+by KEY channel (``a``, ``k``, ``q``) must be a column: the wrappers hand
+the kernels those three packed and transposed, ``(..., d, 3 heads)``,
+key on the sublanes and ``[q | k | a]`` by head on the lanes, and a
+kernel takes head ``h``'s column by a static lane slice.  ``b`` arrives
+as a row of ``heads`` numbers a position.
 
 - :func:`sequential` is the plain ``jax.numpy`` form, a ``lax.scan``
   over time: the tests' yardstick, and what runs off the TPU;
@@ -51,7 +56,7 @@ from .flash_attention import _resolve_interpret
 
 LANES = 128
 # positions a grid step of the sequence kernel covers: a chunk's packed
-# columns are (chunk, d, 128) float32, 2 MB at 32
+# columns are (chunk, d, 3 heads) float32, 2 MB at 32 (96 lanes lie in 128)
 _CHUNK = 32
 # the step's blocks (a slot's state in and out, twice each) pass the
 # 16 MB Mosaic scopes by default
@@ -73,7 +78,7 @@ def sequential(q, k, v, a, b, s0, lens):
         q_t, k_t, v_t, a_t, b_t = xs                 # (batch, heads, ...)
         s = a_t[..., None] * s
         u = v_t - jnp.sum(k_t[..., None] * s, axis=-2)
-        s = s + (b_t[..., None] * k_t)[..., None] * u[..., None, :]
+        s = s + k_t[..., None] * (b_t[..., None] * u)[..., None, :]
         return s, jnp.sum(q_t[..., None] * s, axis=-2)
 
     s, ys = jax.lax.scan(step, s0, tuple(
@@ -91,33 +96,33 @@ def _frozen_past(a, b, lens):
             jnp.where(live[:, :, None], b, 0.0))
 
 
-def _columns(q, k, a, b):
-    """``[q | k | a | b k]`` by head, transposed: ``(..., heads, d)``
-    four times -> ``(..., d, 4 heads)``, the key channel on the
-    sublanes."""
+def _columns(q, k, a):
+    """``[q | k | a]`` by head, transposed: ``(..., heads, d)`` three
+    times -> ``(..., d, 3 heads)``, the key channel on the sublanes."""
     import jax.numpy as jnp
 
-    packed = jnp.concatenate([q, k, a, b[..., None] * k], axis=-2)
-    return jnp.swapaxes(packed, -1, -2)
+    return jnp.swapaxes(jnp.concatenate([q, k, a], axis=-2), -1, -2)
 
 
-def _update(s, cols, v_row, h: int, heads: int):
-    """One position of one head: ``s (d, d)``; ``cols (d, 4 heads)``
-    the packed columns of the position; ``v_row (1, d)``.  Returns the
-    new state and ``y (1, d)``."""
+def _update(s, cols, b_row, v_row, h: int, heads: int):
+    """One position of one head: ``s (d, d)``; ``cols (d, 3 heads)``
+    the packed columns of the position; ``b_row (1, heads)``; ``v_row
+    (1, d)``.  Returns the new state and ``y (1, d)``."""
     import jax.numpy as jnp
 
     def col(j):
         return cols[:, j * heads + h:j * heads + h + 1]      # (d, 1)
 
+    # ``k``'s column is spread over the lanes once, for both its uses
+    k = jnp.broadcast_to(col(1), s.shape)
     s = col(2) * s
-    u = v_row - jnp.sum(col(1) * s, axis=0, keepdims=True)
-    s = s + col(3) * u
+    u = v_row - jnp.sum(k * s, axis=0, keepdims=True)
+    s = s + k * (b_row[:, h:h + 1] * u)
     return s, jnp.sum(col(0) * s, axis=0, keepdims=True)
 
 
-def _step_kernel(order_ref, n_ref, cols_ref, v_ref, s_in, s_ref, y_ref, *,
-                 heads: int):
+def _step_kernel(order_ref, n_ref, cols_ref, b_ref, v_ref, s_in, s_ref,
+                 y_ref, *, heads: int):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -128,9 +133,10 @@ def _step_kernel(order_ref, n_ref, cols_ref, v_ref, s_in, s_ref, y_ref, *,
     # writes a second time
     @pl.when(i < n_ref[0])
     def _():
-        cols = cols_ref[0]
+        cols, b_row = cols_ref[0], b_ref[0]
         for h in range(heads):
-            s, y = _update(s_in[0, h], cols, v_ref[0, h:h + 1], h, heads)
+            s, y = _update(s_in[0, h], cols, b_row, v_ref[0, h:h + 1], h,
+                           heads)
             s_ref[0, h] = s
             y_ref[0, h:h + 1] = y
 
@@ -166,20 +172,20 @@ def _step_call(q, k, v, a, b, s, active, interpret: bool):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots,),
-            in_specs=[pl.BlockSpec((1, d, 4 * heads), at(0, 0)), row,
-                      state],
+            in_specs=[pl.BlockSpec((1, d, 3 * heads), at(0, 0)),
+                      pl.BlockSpec((1, 1, heads), at(0, 0)), row, state],
             out_specs=[state, row]),
         out_shape=[jax.ShapeDtypeStruct(s.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
-        # the pool is updated where it lies (operand 4: after ``order``
-        # and ``n``)
-        input_output_aliases={4: 0},
+        # the pool is updated where it lies (operand 5: after ``order``,
+        # ``n``, the columns, ``b`` and ``v``)
+        input_output_aliases={5: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="kda_step",
-    )(order, n.reshape(1), _columns(q, k, a, b), v, s)
+    )(order, n.reshape(1), _columns(q, k, a), b[:, None], v, s)
     # an inactive slot's row was never written
     return jnp.where(active[:, None, None], y, 0.0), s
 
@@ -194,8 +200,8 @@ def kda_step(q, k, v, a, b, s, active, interpret: Optional[bool] = None):
                       interpret=_resolve_interpret(interpret))
 
 
-def _scan_kernel(cols_ref, v_ref, s0_ref, s_ref, y_ref, *, heads: int,
-                 chunk: int):
+def _scan_kernel(cols_ref, b_ref, v_ref, s0_ref, s_ref, y_ref, *,
+                 heads: int, chunk: int):
     from jax import lax
     from jax.experimental import pallas as pl
 
@@ -208,8 +214,8 @@ def _scan_kernel(cols_ref, v_ref, s0_ref, s_ref, y_ref, *, heads: int,
     # written back every position
     for h in range(heads):
         def body(t, s, h=h):
-            s, y = _update(s, cols_ref[0, t], v_ref[0, t, h:h + 1], h,
-                           heads)
+            s, y = _update(s, cols_ref[0, t], b_ref[0, pl.ds(t, 1)],
+                           v_ref[0, t, h:h + 1], h, heads)
             y_ref[0, t, h:h + 1] = y
             return s
 
@@ -235,8 +241,10 @@ def _scan_call(q, k, v, a, b, s0, lens, interpret: bool):
     s, y = pl.pallas_call(
         functools.partial(_scan_kernel, heads=heads, chunk=chunk),
         grid=(batch, n_pos // chunk),
-        in_specs=[pl.BlockSpec((1, chunk, d, 4 * heads),
-                               lambda i, l: (i, l, 0, 0)), seq, state],
+        in_specs=[pl.BlockSpec((1, chunk, d, 3 * heads),
+                               lambda i, l: (i, l, 0, 0)),
+                  pl.BlockSpec((1, chunk, heads), lambda i, l: (i, l, 0)),
+                  seq, state],
         out_specs=[state, seq],
         out_shape=[jax.ShapeDtypeStruct(s0.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
@@ -245,7 +253,7 @@ def _scan_call(q, k, v, a, b, s0, lens, interpret: bool):
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="kda_scan",
-    )(_columns(q, k, a, b), v, s0)
+    )(_columns(q, k, a), b, v, s0)
     return y, s
 
 

@@ -113,16 +113,17 @@ SCAN_CASES = {
 def test_kda_scan_kernel(name):
     """The sequence kernel, interpreted: the state AT THE TRUE LENGTH
     (a padded position decays nothing and writes nothing) and every
-    live position's output."""
+    live position's output, BIT-equal to the plain scan (the same
+    products in the same association: ``k (b u)``)."""
     batch, n_pos, heads, d, lens = SCAN_CASES[name]
     q, k, v, a, b, s0 = _recurrence_case(len(name), batch, n_pos, heads, d)
     lens = jnp.asarray(lens, jnp.int32)
     want_y, want_s = delta_rule.sequential(q, k, v, a, b, s0, lens)
     y, s = delta_rule.kda_scan(q, k, v, a, b, s0, lens, interpret=True)
-    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=1e-4)
+    assert np.asarray(s).tobytes() == np.asarray(want_s).tobytes()
     for i, n in enumerate(np.asarray(lens)):
-        np.testing.assert_allclose(np.asarray(y[i, :n]),
-                                   np.asarray(want_y[i, :n]), atol=1e-4)
+        assert np.asarray(y[i, :n]).tobytes() \
+            == np.asarray(want_y[i, :n]).tobytes()
     if name == "nothing_live":
         assert (np.asarray(s) == np.asarray(s0)).all()
 
@@ -130,10 +131,10 @@ def test_kda_scan_kernel(name):
 @pytest.mark.parametrize("active", [
     [1, 0, 1, 1, 0, 0], [0] * 6, [1] * 6, [0, 0, 0, 0, 0, 1], [1, 0, 0, 0, 0, 0]])
 def test_kda_step_kernel_updates_in_place_and_leaves_idle_slots(active):
-    """The step kernel, interpreted: active slots move one position, an
-    inactive slot's state is bit-equal to what it was (it is never
-    read: it may hold anything, NaN included), and the pool handed in
-    is the pool handed back."""
+    """The step kernel, interpreted: active slots move one position as
+    the plain scan moves them, an inactive slot's state is bit-equal
+    to what it was (it is never read: it may hold anything, NaN
+    included), and the pool handed in is the pool handed back."""
     q, k, v, a, b, s0 = _recurrence_case(sum(active), 6, 1, 2, 8)
     active = jnp.asarray(active, bool)
     idle = ~np.asarray(active)
@@ -144,14 +145,17 @@ def test_kda_step_kernel_updates_in_place_and_leaves_idle_slots(active):
     y, s = delta_rule.kda_step(q[:, 0], k[:, 0], v[:, 0], a[:, 0], b[:, 0],
                                jnp.asarray(pool), active, interpret=True)
     y, s = np.asarray(y), np.asarray(s)
+    # (bit-equal on the chip, ``chip_kimi_linear.py numerics``; the CPU
+    # backend's batched reduction rounds the last bit another way)
     np.testing.assert_allclose(s[~idle], np.asarray(want_s)[~idle],
-                               atol=1e-5)
+                               atol=1e-6)
     np.testing.assert_allclose(y[~idle], np.asarray(want_y)[~idle, 0],
-                               atol=1e-5)
+                               atol=1e-6)
     assert (y[idle] == 0).all()
     assert s[idle].tobytes() == pool[idle].tobytes()
     # in place: the kernel's pool result (0) aliases its pool operand
-    # (4: after the two prefetched scalars, the columns and ``v``)
+    # (5: after the two prefetched scalars, the columns, ``b`` and
+    # ``v``)
     jaxpr = jax.make_jaxpr(lambda *x: delta_rule.kda_step(
         *x, interpret=True))(q[:, 0], k[:, 0], v[:, 0], a[:, 0], b[:, 0],
                              s0, active).jaxpr
@@ -159,8 +163,8 @@ def test_kda_step_kernel_updates_in_place_and_leaves_idle_slots(active):
     _count_eqns(jaxpr, lambda e: e.primitive.name == "pallas_call"
                 and calls.append(e))
     assert len(calls) == 1
-    assert tuple(calls[0].params["input_output_aliases"]) == ((4, 0),)
-    assert calls[0].invars[4].aval.shape == s0.shape
+    assert tuple(calls[0].params["input_output_aliases"]) == ((5, 0),)
+    assert calls[0].invars[5].aval.shape == s0.shape
 
 
 # -- the mixer: prefill and step against a position at a time -----------------
@@ -183,7 +187,8 @@ def _stepwise(lm, bp, x, n):
     outs = []
     for t in range(n):
         out, state, tail = kda_mixer.step(
-            lm, bp, jnp.stack([x[0, t] * 0, x[0, t]]), state, tail, active)
+            lm, bp, jnp.stack([x[0, t] * 0, x[0, t]]), state, tail, active,
+            jnp.asarray([0, t], jnp.int32))
         outs.append(out[1])
     assert not np.asarray(state[0]).any() and not np.asarray(tail[0]).any()
     return (jnp.stack(outs) if outs else None), state[1], tail[1]
@@ -194,12 +199,20 @@ def test_prefill_returns_the_state_at_the_true_length(f32_matmuls, ctx_len):
     """A zero-padded bucket through :func:`kda_mixer.prefill` against
     the same positions one at a time: outputs of the live positions,
     the matrix state and the convolutions' tails as they stand after
-    ``ctx_len`` positions, whatever follows in the bucket."""
+    ``ctx_len`` positions, whatever follows in the bucket.  The tails
+    are a ring: position ``t``'s input lies in row ``t mod 3`` (the
+    lengths cover the three phases)."""
     lm, bp, x = _mixer_case()
     live = (jnp.arange(x.shape[1]) < ctx_len)[None, :, None]
     out, state, tail = kda_mixer.prefill(lm, bp, jnp.where(live, x, 0.0),
                                          jnp.int32(ctx_len))
     want_out, want_state, want_tail = _stepwise(lm, bp, x, ctx_len)
+    assert tail.shape == (1, 3, 6, 8)
+    inputs = np.asarray(x[0] @ bp["wqkv"]).reshape(-1, 6, 8)
+    for t in range(ctx_len - 3, ctx_len):
+        np.testing.assert_allclose(
+            np.asarray(tail[0, t % 3]), inputs[t] if t >= 0 else 0.0,
+            atol=1e-6)
     np.testing.assert_allclose(np.asarray(state[0]), np.asarray(want_state),
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(tail[0]), np.asarray(want_tail),
@@ -305,7 +318,8 @@ def test_the_state_pool_lies_beside_the_latent_pool(model):
     shapes = {k: v.shape for k, v in cache.items()}
     assert shapes == {
         **{f"sh{i}": (3, 4, 16, 16) for i in (0, 1, 2, 4)},
-        **{f"sc{i}": (3, 3, 192) for i in (0, 1, 2, 4)},
+        # a ring of three rows of ``3 H x d``: a row is one block
+        **{f"sc{i}": (3, 3, 12, 16) for i in (0, 1, 2, 4)},
         "pc3": (9, PAGE, 128), "len": (3,)}
     assert T.state_kinds(lm) == {"kda": {"layers": 4, "slot_bytes":
                                          4 * 4 * (4 * 16 * 16 + 3 * 192)}}
@@ -710,3 +724,133 @@ DECLINES = {
 def test_unported_paths_decline_by_name(path):
     with pytest.raises(T.UnsupportedBlock):
         DECLINES[path]()
+
+
+# -- the convolutions' tails are a ring ---------------------------------------
+# (these run last: up to here the file loads the six workers' machine as it
+# did before them, and the time-bound tests beside it see what they saw)
+
+def _time_ordered_step(lm, bp, x, state, tail, active):
+    """The step with the tails in TIME order, ``tail (slots, 3, 3 H
+    d)`` rewritten whole where the slot is active, as the program had
+    it before the ring."""
+    qkv = quant.qmatmul(x, bp["wqkv"])
+    window = jnp.concatenate([tail, qkv[:, None]], axis=1)
+    tail = jnp.where(active[:, None, None], window[:, 1:], tail)
+    qkv = jax.nn.silu(jnp.sum(bp["conv_w"][None] * window, axis=1))
+    q, k, v, a, b = kda_mixer._recurrence_inputs(
+        lm, bp, x, qkv.reshape(len(x), 3 * lm.kda_heads, lm.kda_head_dim))
+    y, state = delta_rule.sequential(
+        q[:, None], k[:, None], v[:, None], a[:, None], b[:, None], state,
+        active.astype(jnp.int32))
+    y = jnp.where(active[:, None, None], y[:, 0], 0.0)
+    return kda_mixer._out(lm, bp, x, y), state, tail
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["plain", "kda_step"])
+@pytest.mark.parametrize("ctx_len", [5, 6, 7])
+def test_the_ring_is_the_time_ordered_tail(f32_matmuls, monkeypatch,
+                                           ctx_len, kernel):
+    """Three prompts prefilled at ``ctx_len`` (the three phases), then
+    six steps under masks that part the slots' phases: outputs, states
+    and the ring against the time-ordered form, on the plain path and
+    through the interpreted kernel; an idle slot's three rows are
+    bit-identical after a step."""
+    monkeypatch.setattr(delta_rule, "_use_kernels", lambda d: kernel)
+    lm, bp, _x = _mixer_case()
+    xs = jax.random.normal(jax.random.PRNGKey(ctx_len), (3, 16, lm.dim))
+    live = (jnp.arange(8) < ctx_len)[None, :, None]
+    filled = [kda_mixer.prefill(lm, bp, jnp.where(live, xs[i:i + 1, :8],
+                                                  0.0), jnp.int32(ctx_len))
+              for i in range(3)]
+    state = jnp.concatenate([f[1] for f in filled])
+    ring = jnp.concatenate([f[2] for f in filled])
+    lens = np.full((3,), ctx_len)
+    # in time order: row j of a slot is position ``len - 3 + j``
+    want_tail = jnp.stack([ring[i, (np.arange(3) + lens[i]) % 3]
+                           for i in range(3)]).reshape(3, 3, -1)
+    want_state = state
+    masks = [[1, 1, 0], [1, 0, 1], [0, 0, 0], [1, 1, 1], [0, 1, 0],
+             [1, 0, 0]]
+    for t, mask in enumerate(masks):
+        active = jnp.asarray(mask, bool)
+        x = xs[:, 8 + t]
+        before = np.asarray(ring)
+        out, state, ring = kda_mixer.step(lm, bp, x, state, ring, active,
+                                          jnp.asarray(lens, jnp.int32))
+        want_out, want_state, want_tail = _time_ordered_step(
+            lm, bp, x, want_state, want_tail, active)
+        lens = lens + np.asarray(mask)
+        on = np.asarray(active)
+        np.testing.assert_allclose(np.asarray(out)[on],
+                                   np.asarray(want_out)[on], atol=1e-5)
+        np.testing.assert_allclose(np.asarray(state),
+                                   np.asarray(want_state), atol=1e-5)
+        for i in range(3):
+            got = np.asarray(ring[i, (np.arange(3) + lens[i]) % 3])
+            assert got.reshape(3, -1).tobytes() \
+                == np.asarray(want_tail[i]).tobytes()
+            if not on[i]:
+                assert np.asarray(ring[i]).tobytes() == before[i].tobytes()
+    assert sorted(lens % 3) == [0, 1, 2]        # the phases have parted
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["plain", "kda_step"])
+def test_the_step_writes_no_whole_tail_pool(model, monkeypatch, kernel):
+    """The traced step moves ONE row a slot of a KDA layer's ring: no
+    ``select_n`` and no ``concatenate`` (nor anything else) yields an
+    array the size of a whole tail pool but the one-row scatter, off
+    the TPU and, with every kernel in the trace, on it."""
+    from brpc_tpu.ops import device_ops
+    cfg, m, lm, params = model
+    if kernel:
+        monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+        monkeypatch.setattr(delta_rule, "_use_kernels", lambda d: True)
+    _prefill, step = T.make_paged_batch_decode(lm, PAGE)
+    cache = T.empty_paged_cache(lm, 9, 2, PAGE)
+    pool = cache["sc0"].shape
+    jaxpr = jax.make_jaxpr(step)(
+        params, cache, jnp.zeros((2, lm.max_seq // PAGE), jnp.int32),
+        jnp.zeros((2,), jnp.int32), jnp.asarray([True, False])).jaxpr
+    whole = []
+    _count_eqns(jaxpr, lambda e: any(
+        getattr(v.aval, "shape", None) == pool for v in e.outvars)
+        and whole.append(e.primitive.name))
+    assert whole == ["scatter"] * 4
+
+
+def _sequential_as_it_was(q, k, v, a, b, s0, lens):
+    """:func:`delta_rule.sequential` with the write as ``(b k) u^T``,
+    the association the yardstick had before ``b`` became the head's
+    scalar on the row ``u``."""
+    a, b = delta_rule._frozen_past(a, b, lens)
+
+    def step(s, xs):
+        q_t, k_t, v_t, a_t, b_t = xs
+        s = a_t[..., None] * s
+        u = v_t - jnp.sum(k_t[..., None] * s, axis=-2)
+        s = s + (b_t[..., None] * k_t)[..., None] * u[..., None, :]
+        return s, jnp.sum(q_t[..., None] * s, axis=-2)
+
+    s, ys = jax.lax.scan(step, s0, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (q, k, v, a, b)))
+    return jnp.moveaxis(ys, 0, 1), s
+
+
+@pytest.mark.parametrize("n_pos", [1, 8, 64])
+def test_the_yardstick_moved_by_rounding_alone(n_pos):
+    """The kernels are held bit-equal to ``sequential``, which changed
+    its association WITH them (``k (b u)`` for ``(b k) u``): so the
+    yardstick's own move is pinned here, at the cell's widths (32 heads
+    of 128): after ``n_pos`` positions the new form is within four
+    units in the last place of the largest entry of the old one."""
+    q, k, v, a, b, s0 = _recurrence_case(5, 2, n_pos, 32, 128)
+    lens = jnp.asarray([n_pos, max(n_pos - 3, 0)], jnp.int32)
+    y, s = delta_rule.sequential(q, k, v, a, b, s0, lens)
+    was_y, was_s = _sequential_as_it_was(q, k, v, a, b, s0, lens)
+    ulp = 2.0 ** -23
+    for got, was in ((s, was_s), (y, was_y)):
+        got, was = np.asarray(got), np.asarray(was)
+        assert np.abs(got - was).max() <= 4 * ulp * np.abs(was).max()
