@@ -409,6 +409,13 @@ class ContinuousBatcher:
     construction.  ``kv_stats()["loop"]`` counts the layer bodies its
     steps ran and the spans its fills queued.
 
+    Either kind's spans touch their own pages only: a span's rows go
+    into the pools as whole pages and its attention fetches the table
+    entries it reaches (``ops/span_attention``).  ``kv_stats()["fill"]``
+    counts ``spans``, their true ``rows``, ``pages_written``, and
+    ``pages_attended`` against ``pages_table`` (what whole block
+    tables would have been), a kind of layer counted once.
+
     **SLO-tiered scheduling** (ROADMAP item 4): the step loop is a
     latency-SLO scheduler over three per-tenant tiers resolved from
     the TLV-22 identity via a :class:`TierRegistry`:
@@ -550,6 +557,12 @@ class ContinuousBatcher:
         # pages, their true rows and the spans queued for them
         self._loop = {"steps": 0, "layer_passes": 0, "fills": 0,
                       "fill_rows": 0, "fill_spans": 0}
+        # the spans of the fills that go through the pages: their true
+        # rows, the pages they wrote, and the table entries their
+        # attention had to fetch against whole block tables (a kind of
+        # layer counted once, whatever the depth and the passes)
+        self._fill = {"spans": 0, "rows": 0, "pages_written": 0,
+                      "pages_attended": 0, "pages_table": 0}
         # the window class (kv.pages.WindowTable, built with the
         # engine): pages its sessions held at each step against what
         # their whole contexts would hold, summed over steps
@@ -709,6 +722,8 @@ class ContinuousBatcher:
             out["loop"] = {"passes": cfg.passes, "layers": cfg.depth,
                            "token_bytes": paged_page_bytes(cfg, self.page)
                            // self.page, **self._loop}
+        if cfg.has_window or cfg.passes > 1:
+            out["fill"] = dict(self._fill)
         if cfg.has_experts:
             lo, hi = cfg.experts_held
             out["moe"] = {"layers": len(cfg.expert_layers()),
@@ -1124,11 +1139,28 @@ class ContinuousBatcher:
                     self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
                     *where)
             self._clock.filling(1, n)
+            self._count_span(start, n)
         if wt is None:
             self._loop["fills"] += 1
             self._loop["fill_rows"] += ctx_len
             self._loop["fill_spans"] += -(-ctx_len // w)
         return True
+
+    def _count_span(self, start: int, n: int) -> None:
+        """One span's share of ``kv_stats()["fill"]``: what
+        ``ops/span_attention`` writes and fetches for it in a layer of
+        each kind the schedule has (whole contexts; a window)."""
+        from ..ops.span_attention import pages_fetched
+        f, cfg, page = self._fill, self.cfg, self.page
+        pps = cfg.max_seq // page
+        f["spans"] += 1
+        f["rows"] += n
+        f["pages_written"] += -(-n // page)
+        for win in sorted(set(cfg.windows)):
+            f["pages_attended"] += pages_fetched(
+                start, cfg.fill_span, page, pps, cfg.heads // cfg.kv_heads,
+                win)
+            f["pages_table"] += pps
 
     def _cover_windows(self) -> bool:
         """Before a step is queued: every active slot's row of the
@@ -2199,6 +2231,11 @@ class LMService(Service):
                 "layers": list(c.window_layers()), "window": c.window,
                 "pages": c.window_pages(self.decode_slots, self.page),
                 "fill_span": c.fill_span}
+        if c.has_window or c.passes > 1:
+            # prompts go through the pages in spans of whole pages
+            info["fill"] = {"fill_span": c.fill_span,
+                            "span_pages": c.fill_span // self.page,
+                            "table_pages": c.max_seq // self.page}
         if c.has_latent:
             # one pool a latent layer, a row a token: key and value
             info["packed_bytes"] = mla_mixer.packed_bytes(c, self.params)

@@ -1458,6 +1458,16 @@ def _slice_rows(cfg: LMConfig, page: int, bt_row, start, n, width: int):
     return page_idx, posc % page, start + j
 
 
+def _span_pages(cfg: LMConfig, page: int, bt_row, start, n):
+    """The ``fill_span // page`` entries of ``bt_row`` that a span of
+    ``n`` real rows from position ``start`` (a multiple of ``page``)
+    fills; a page wholly past ``start + n``: the garbage page 0."""
+    import jax.numpy as jnp
+    p = start // page + jnp.arange(cfg.fill_span // page)
+    return jnp.where(p * page < start + n,
+                     bt_row[jnp.minimum(p, cfg.max_seq // page - 1)], 0)
+
+
 def make_paged_span_fill(cfg: LMConfig, page: int):
     """The prompt pass of the schedules whose prompts go through the
     pages: a window schedule's (below), and a looped schedule's
@@ -1467,20 +1477,29 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
     bt_row[pps], btw_row[pps], slot, start, n, ids[fill_span]) ->
     cache`` writes ``n`` context tokens of ``slot`` at positions
     ``start..start+n-1`` straight into its pages (a global layer's
-    through ``bt_row``, a window layer's through ``btw_row``; padding
-    rows to the garbage page 0 of either class) and sets the slot's len
-    to ``start + n``.  A prompt is as many calls of this ONE program
-    as it has spans, in order: no ``max_seq`` cache, no bucket, nothing
-    to insert.  In each layer the span's rows are scattered, then its
-    queries attend over the pages (``ops/span_attention``): a global
-    layer's over the whole block table, a window layer's over the
-    ``(window + fill_span) // page + 2`` entries from the page that
-    holds the first position its first row reaches; so ``btw_row``
-    must hold live pages from there to the span's last row, and what
-    lies behind may have been given back.  The expert layer routes the
-    ``n`` real rows only.  Identical by construction with as many
-    single steps (scatter before gather, the step's mask)."""
-    import jax
+    through ``bt_row``, a window layer's through ``btw_row``) and sets
+    the slot's len to ``start + n``.  A prompt is as many calls of
+    this ONE program as it has spans, in order: no ``max_seq`` cache,
+    no bucket, nothing to insert.  ``start`` is a multiple of
+    ``fill_span``, itself whole pages.
+
+    In each layer the span's rows go into the pools as ``fill_span //
+    page`` whole pages (``span_attention.write``).  The last live
+    page of a prompt is partial: its rows from ``n`` on KEEP what lay
+    there (the kernel copies that page's real rows alone, the plain
+    form reads, merges and writes the span's pages; pinned by
+    ``test_a_span_writes_its_own_rows_and_nothing_else``), and a page
+    wholly past ``n`` names the class's garbage page 0, which stays
+    as it was: a span changes its ``n`` rows and nothing else.  Then
+    its queries attend over the pages
+    (``span_attention.attention``): a global layer's through the
+    block table, a window layer's through the ``(window + fill_span)
+    // page + 2`` entries from the page that holds the first position
+    its first row reaches; so ``btw_row`` must hold live pages from
+    there to the span's last row, and what lies behind may have been
+    given back.  The expert layer routes the ``n`` real rows only.
+    Identical by construction with as many single steps (scatter before
+    gather, the step's mask)."""
     import jax.numpy as jnp
 
     from ..ops import span_attention
@@ -1492,36 +1511,29 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
                 "looped schedules only: every other block fills "
                 "through make_prefill + insert")
         return declined
-    if cfg.max_seq % page:
+    if cfg.max_seq % page or cfg.fill_span % page:
         raise ValueError(
-            f"page size {page} must divide max_seq {cfg.max_seq}")
+            f"page size {page} must divide max_seq {cfg.max_seq} and "
+            f"fill_span {cfg.fill_span}: a span is whole pages")
     if cfg.passes > 1:
         return _looped_span_fill(cfg, page)
-    w, kvh, pps = cfg.fill_span, cfg.kv_heads, cfg.max_seq // page
-    reach = min(pps, (cfg.window + w) // page + 2)
+    w = cfg.fill_span
 
     def fill(params, cache, bt_row, btw_row, slot, start, n, ids):
         cache = dict(cache)
         j = jnp.arange(w)
         real = j < n
-        posc = jnp.minimum(start + j, cfg.max_seq - 1)
-        rows = (posc % page)[:, None] * kvh + jnp.arange(kvh)[None, :]
         x = _embed_rows(params, ids)[None]                # (1, w, dim)
         rot = _rope_at(cfg, start + j)
         for i in range(cfg.depth):
             bp, win = params[f"blk{i}"], cfg.windows[i]
             row = btw_row if win else bt_row
-            page_idx = jnp.where(real, row[posc // page], 0)[:, None]
+            mine = _span_pages(cfg, page, row, start, n)
             q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
-            pk = cache[f"pk{i}"].at[page_idx, rows].set(k[0])
-            pv = cache[f"pv{i}"].at[page_idx, rows].set(v[0])
-            if win:
-                p0 = jnp.clip((start - win + 1) // page, 0, pps - reach)
-                ids_p = jax.lax.dynamic_slice(row, (p0,), (reach,))
-            else:
-                p0, ids_p = 0, row
-            att = span_attention.attention(q[0], pk, pv, ids_p, start,
-                                           p0 * page, page, win)
+            pk = span_attention.write(cache[f"pk{i}"], k[0], mine, n, page)
+            pv = span_attention.write(cache[f"pv{i}"], v[0], mine, n, page)
+            att = span_attention.attention(q[0], pk, pv, row, start, page,
+                                           win)
             x, _counts = _attn_out(cfg, bp, x, att[None], i, real[None])
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = cache["len"].at[slot].set(start + n)
@@ -1535,14 +1547,16 @@ def _looped_span_fill(cfg: LMConfig, page: int):
     pass inside it): ``fill(params, cache, bt_row[pps], slot, start,
     n, ids[fill_span]) -> cache`` writes ``n`` context tokens of
     ``slot`` at positions ``start..start+n-1`` into its pages IN EVERY
-    PASS and sets the slot's len to ``start + n``; padding rows go to
-    the pass's garbage page.  A prompt is as many calls of this one
-    program as it has spans, in order.  The passes are the step's loop
-    (:func:`_looped`): in each (pass, layer) the span's rows are
-    scattered into the pass's pages, then its queries attend over them
-    (``ops/span_attention``, the whole block table), so pass ``t`` of
-    a later span finds pass ``t`` of the earlier ones.  Identical by
-    construction with as many single steps."""
+    PASS and sets the slot's len to ``start + n``.  A prompt is as
+    many calls of this one program as it has spans, in order.  The
+    passes are the step's loop (:func:`_looped`): in each (pass,
+    layer) the span's rows go into the pass's pages as whole pages
+    (:func:`make_paged_span_fill`'s rule for the partial one; the
+    garbage page is the pass's), then its queries attend over them
+    (``ops/span_attention``: the pages the span reaches, read where
+    they lie), so pass ``t`` of a later span finds pass ``t`` of the
+    earlier ones.  Identical by construction with as many single
+    steps."""
     import jax.numpy as jnp
 
     from ..ops import span_attention
@@ -1552,17 +1566,15 @@ def _looped_span_fill(cfg: LMConfig, page: int):
     def fill(params, cache, bt_row, slot, start, n, ids):
         j = jnp.arange(w)
         real = j < n
-        posc = jnp.minimum(start + j, cfg.max_seq - 1)
-        page_idx = jnp.where(real, bt_row[posc // page], 0)
-        row = posc % page
+        mine = _span_pages(cfg, page, bt_row, start, n)
         rot = _rope_at(cfg, start + j)
 
         def layer(i, bp, x, pk, pv, off):
             q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
-            pk = pk.at[page_idx + off, row].set(k[0])
-            pv = pv.at[page_idx + off, row].set(v[0])
+            pk = span_attention.write(pk, k[0], mine + off, n, page)
+            pv = span_attention.write(pv, v[0], mine + off, n, page)
             att = span_attention.attention(q[0], pk, pv, bt_row + off,
-                                           start, 0, page)
+                                           start, page)
             return _attn_out(cfg, bp, x, att[None], i, real[None])[0], pk, pv
 
         _x, cache = _looped(cfg, params, cache,

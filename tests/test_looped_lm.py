@@ -291,10 +291,40 @@ def test_an_idle_slots_pages_and_len_stay(model, f32_matmuls):
         assert np.abs(page[4:7]).min() > 0 and not page[7:].any()
 
 
+@pytest.mark.parametrize("start,n", [(0, 32), (32, 20), (32, 3), (224, 32)])
+def test_a_span_writes_its_own_rows_and_nothing_else(model, f32_matmuls,
+                                                     start, n):
+    """One span of slot 1 into pools that hold 3.0 everywhere: in every
+    pass the session's positions ``start .. start + n - 1`` are
+    written, and NOTHING else: the rows past ``n`` of the last live
+    page keep what lay there (``span_attention.write`` merges), a page
+    wholly past ``n`` and the pass's garbage page stay as they were."""
+    cfg, m, lm, params = model
+    pps = lm.max_seq // PAGE
+    cache = {name: jnp.full_like(a, 3.0) if name != "len" else a
+             for name, a in T.empty_paged_cache(lm, PAGES, 2, PAGE).items()}
+    bt = 1 + np.random.default_rng(n).permutation(PAGES - 1)[:pps] \
+        .astype(np.int32)
+    ids = np.arange(lm.fill_span, dtype=np.int32) + 7
+    fill = jax.jit(T.make_paged_span_fill(lm, PAGE))
+    after = fill(params, cache, jnp.asarray(bt), np.int32(1),
+                 np.int32(start), np.int32(n), ids)
+    assert after["len"].tolist() == [0, start + n]
+    for name in ("pk0", "pv1", "pk2"):
+        pool = np.asarray(after[name])
+        written = np.zeros(pool.shape[:2], bool)
+        for t in range(4):
+            for pos in range(start, start + n):
+                written[t * PAGES + bt[pos // PAGE], pos % PAGE] = True
+        assert (pool[~written] == 3.0).all()
+        assert (np.abs(pool[written] - 3.0).max(axis=(-1, -2)) > 0).all()
+
+
 # -- (c) the span kernel over whole heads --------------------------------------
 
+@pytest.mark.parametrize("paged", [True, False])
 @pytest.mark.parametrize("start,w", [(0, 64), (64, 64), (100, 128)])
-def test_span_flash_kernel_over_whole_heads(start, w):
+def test_span_flash_kernel_over_whole_heads(start, w, paged):
     """``span_flash_attention`` (interpreted) against the plain form
     over a whole-head pool ``(pages, page, heads, hd)``: the grouped
     layout with a group of one."""
@@ -306,7 +336,8 @@ def test_span_flash_kernel_over_whole_heads(start, w):
     ids = jnp.asarray(rng.permutation(39)[:pps] + 1, jnp.int32)
     want = span_attention.reference(q, pk, pv, ids, start, 0, PAGE)
     got = span_attention.span_flash_attention(q, pk, pv, ids, start, 0,
-                                              PAGE, interpret=True)
+                                              PAGE, interpret=True,
+                                              paged=paged)
     assert np.abs(np.asarray(got - want)).max() < 3e-2      # bf16 operands
     flat = pk.reshape(40, PAGE * heads, hd), pv.reshape(40, PAGE * heads, hd)
     np.testing.assert_array_equal(
@@ -370,6 +401,46 @@ def test_counts_against_hand_arithmetic():
         + 192 * 4 * 2048 * (256 * 512 + 256 * 257 / 2)
     assert b_fill == 2 * 4 * 48 * layer + token * 768
     assert m.fill_work(cfg, 5, 0) == (0.0, 0.0)
+
+
+def test_the_fills_counts_against_hand_arithmetic():
+    """``kv_stats()["fill"]`` over one cycle of the batch mix at the
+    cell's widths (no program runs: the fill is a stub): 20 spans of
+    256 rows, 4,600 real rows of 5,120, 288 pages written, and the
+    spans' attention fetches the 640 table entries they reach where
+    whole tables would be 2,560; ``pages_fetched`` is what the kernel
+    is held to by
+    ``test_the_span_kernel_reads_the_pages_it_reaches_where_they_lie``."""
+    from types import SimpleNamespace
+
+    from benchmarks.harness import spec
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    cfg, m = _bench("configs/ouro-2.6b.json")
+    mix = spec.load_json(os.path.join(spec.BENCH_DIR, "traffic",
+                                      "batch.json"))
+    prompts = mix["session"]["prompt_len"]["values"]
+    assert sorted(prompts) == [128, 256, 384, 512, 640, 768, 896, 1024]
+    lm = T.LMConfig(remat=False, **m.lm_kwargs(cfg))
+    bat = ContinuousBatcher(lm, {}, page=cfg["service"]["page"])
+    bat._span_fill = lambda cache, *_a: cache
+    for n in prompts:
+        assert bat._fill_spans(
+            SimpleNamespace(prompt=np.zeros((n,), np.int32)), 0,
+            np.zeros((128,), np.int32), n - 1)
+    fill = bat.kv_stats()["fill"]
+    assert fill == {"spans": 20, "rows": 4600, "pages_written": 288,
+                    "pages_attended": 640, "pages_table": 2560}
+    assert 20 * 256 == 5120 and sum(-(-(n - 1) // 16) for n in prompts) == 288
+    # 8 spans from row 0, 6 from 256, 4 from 512, 2 from 768
+    assert 8 * 16 + 6 * 32 + 4 * 48 + 2 * 64 == 640
+    assert bat.kv_stats()["loop"]["fill_spans"] == 20
+    # the window schedule at ITS cell's widths gathers what it can
+    # reach (sixteen query heads a key/value head: 16 query blocks a
+    # span): 322 entries a window layer, the table a global one
+    assert not span_attention.in_place(1024, 16)
+    assert span_attention.pages_fetched(4096, 1024, 16, 816, 16, 4096) == 322
+    assert span_attention.pages_fetched(4096, 1024, 16, 816, 16) == 816
+    assert span_attention.pages_fetched(512, 256, 16, 128, 1) == 48
 
 
 def test_the_toy_has_the_cells_structure():
@@ -468,6 +539,10 @@ def test_batcher_serves_the_references_tokens_and_counts_the_loop(
         "steps": kv["steps"], "layer_passes": 12 * kv["steps"],
         # (the prompt of one token has no context to fill)
         "fills": 2, "fill_rows": 4 + 69, "fill_spans": 1 + 3}
+    # contexts of 4 and 69 rows: spans from rows 0; 0, 32, 64 write 1;
+    # 2, 2, 1 pages and reach 2; 2, 4, 6 entries of a 16-wide table
+    assert kv["fill"] == {"spans": 4, "rows": 73, "pages_written": 6,
+                          "pages_attended": 14, "pages_table": 64}
     assert kv["prefills_run"] == 2 and "prefix" not in kv
     assert bat._prefix is None
     assert bat._alloc.page_bytes == T.paged_page_bytes(lm, PAGE) \
@@ -479,6 +554,8 @@ def test_batcher_serves_the_references_tokens_and_counts_the_loop(
     assert info["mixers"] == "aaa"
     assert info["loop"] == {"passes": 4, "layers": 3, "post_norms": True,
                             "token_bytes": token, "fill_span": 32}
+    assert info["fill"] == {"fill_span": 32, "span_pages": 2,
+                            "table_pages": 16}
     assert b":4p1:" in svc.model_fingerprint()
     assert svc.model_fingerprint() != LMService(
         cfg=_model(2)[2], params=params, page=PAGE,
@@ -536,6 +613,115 @@ def test_the_passes_are_one_loop_of_the_program(model, monkeypatch):
         jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
         assert _count_eqns(jaxpr, kernel(name)) == 3
         assert _count_eqns(jaxpr, loops) == 1
+
+
+def _pool_moves(jaxpr, pools):
+    """``(pages a gather takes from a pool, index rows of a scatter
+    into a pool)`` over the program, inner programs too."""
+    gathers, scatters = [], []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            shapes = [getattr(v.aval, "shape", None) for v in eqn.invars]
+            if eqn.primitive.name == "gather" and shapes[0] in pools:
+                gathers.append(eqn.outvars[0].aval.shape[0])
+            if eqn.primitive.name.startswith("scatter") \
+                    and shapes[0] in pools:
+                scatters.append(shapes[1][0])
+            if eqn.primitive.name != "pallas_call":
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+    walk(jaxpr)
+    return gathers, scatters
+
+
+@pytest.mark.parametrize("block,rows_a_block", [
+    ("looped", None), ("window", None), ("window", 64)])
+def test_a_span_moves_whole_pages_of_its_own(block, rows_a_block,
+                                             monkeypatch):
+    """The fill with the kernel chosen, as on the TPU: a pool is
+    written by ONE scatter of ``fill_span // page`` pages a layer (not
+    ``fill_span`` rows) behind a gather of as many (the merge of the
+    partial page), and where the span reads in place nothing else is
+    taken from a pool: no operand of the table's keys.  Where a span
+    takes several query blocks (the third case: the block shrunk to 64
+    rows) a window layer gathers the 6 entries it can reach and the
+    global one the table.  Either way ONE program whatever ``start``
+    and ``n`` are."""
+    from brpc_tpu.ops import device_ops
+    if block == "looped":
+        cfg, m = _bench()
+    else:
+        cfg, m = _bench("tests/toy_command_a/config.json")
+    lm = T.LMConfig(remat=False, **m.lm_kwargs(cfg))
+    params = T.init_params(jax.random.PRNGKey(0), lm)
+    pps, pages = lm.max_seq // PAGE, lm.fill_span // PAGE
+    cache = T.empty_paged_cache(lm, PAGES, 2, PAGE)
+    rows = (jnp.arange(pps, dtype=jnp.int32),) * (2 if lm.has_window else 1)
+
+    def args(start, n):
+        return (params, cache, *rows, np.int32(1), np.int32(start),
+                np.int32(n), np.zeros((lm.fill_span,), np.int32))
+
+    fill = jax.jit(T.make_paged_span_fill(lm, PAGE))
+    for start, n in ((0, 32), (32, 5), (224, 32)):
+        fill(*args(start, n))
+    assert fill._cache_size() == 1
+    monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+    if rows_a_block:
+        monkeypatch.setattr(span_attention, "_BLOCK_ROWS", rows_a_block)
+    pools = {a.shape for name, a in cache.items() if name != "len"}
+    gathers, scatters = _pool_moves(
+        jax.make_jaxpr(T.make_paged_span_fill(lm, PAGE))(*args(32, 5)).jaxpr,
+        pools)
+    assert scatters == [pages] * (2 * lm.depth)
+    wins = sum(map(bool, lm.windows))
+    read = [] if not rows_a_block else \
+        [6] * (2 * wins) + [pps] * (2 * (lm.depth - wins))
+    assert sorted(gathers) == sorted([pages] * (2 * lm.depth) + read)
+    assert pages == 2 and pps == 16 and lm.fill_span == 32
+
+
+def test_chip_span_refuses_the_cpu_and_rehearses_at_toy_widths(
+        monkeypatch, tmp_path, capsys):
+    """``chip_span.py`` (the span's kernels alone, on the chip): without
+    a TPU it says so and returns 2; with the device and the trace's
+    reduction faked its whole course runs at toy widths, every kernel
+    within its tolerance of the reference, a JSON line a measurement."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    import chip_span
+    monkeypatch.setattr(sys, "argv", ["chip_span.py", "5"])
+    assert chip_span.main() == 2
+    assert "needs a TPU" in capsys.readouterr().out
+
+    class Chip:
+        platform, device_kind = "tpu", "rehearsal"
+
+    monkeypatch.setattr(chip_span.jax, "devices", lambda: [Chip])
+    monkeypatch.setattr(chip_span.xplane, "reduce_trace",
+                        lambda *_a, **_k: {"programs": {}})
+    monkeypatch.setattr(chip_span.jax.profiler, "start_trace",
+                        lambda *_a, **_k: None)
+    monkeypatch.setattr(chip_span.jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(chip_span.xplane, "find_xplane", lambda path: path)
+    monkeypatch.setattr(chip_span, "ROOT", str(tmp_path))
+    monkeypatch.setattr(chip_span, "CALLS", 1)
+    monkeypatch.setattr(chip_span, "SHAPES", {
+        "ouro": (32, 4, 4, 32, 40, 16, 0, True, (32,)),
+        "longdoc_window": (32, 8, 2, 32, 40, 6, 40, False, (64,))})
+    monkeypatch.setattr(sys, "argv", [
+        "chip_span.py", "5", span_attention.__file__])
+    assert chip_span.main() == 0
+    lines = [json.loads(x) for x in open(
+        tmp_path / "chiprun_out" / "span.jsonl")]
+    assert {x["what"] for x in lines} == {
+        "kernel", "kernel_bk128", "kernel_bk512", "parent", "write_pages",
+        "write_plain", "write_rows"}
+    errs = [x["max_err"] for x in lines if x["what"] == "kernel"]
+    assert len(errs) == 2 and max(errs) < 2e-2
 
 
 # -- (h) what declines, by name -------------------------------------------------

@@ -274,10 +274,45 @@ def test_span_kernel_over_whole_heads_compiles_for_the_v5e(one_chip):
             q, pk, pv, ids, start, 0, PAGE, interpret=False)
     ).lower(arg((256, 16, 128), jnp.float32), pool, pool,
             arg((128,), jnp.int32), arg((), jnp.int32)).compile()
+    text = compiled.as_text()
     calls = re.findall(r"%(span_flash_attention[.\w]*) = (\S+) custom-call",
-                       compiled.as_text())
+                       text)
     assert len(calls) == 1 and calls[0][1].startswith("f32[16,256,128]"), \
         calls
+    # the keys are read where they lie: no operand of the table's 2,048
+    # keys, gathered, cast or transposed (the pools arrive as bitcasts)
+    assert "bf16[128,16,16,128]" not in text
+    assert "bf16[16,2048,128]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_span_kernel_over_a_window_compiles_for_the_v5e(one_chip):
+    """``span_flash_attention`` at a window layer of
+    ``command-a-plus.longdoc`` (a span of 1,024 rows of 128 query heads
+    on 8 key/value heads, a window of 4,096, the 322 entries the span
+    can reach in a pool of 4,193 pages): sixteen query blocks a span,
+    so the keys are gathered ONCE, 322 pages padded to eleven key
+    blocks; nothing is run."""
+    from brpc_tpu.ops import span_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert not span_attention.in_place(1024, 16)
+    assert span_attention.table_reach(816, 1024, PAGE, 4096) == 322
+    pool = arg((4193, PAGE * 8, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda q, pk, pv, ids, q0, k0: span_attention.span_flash_attention(
+            q, pk, pv, ids, q0, k0, PAGE, 4096, interpret=False)
+    ).lower(arg((1024, 128, 128), jnp.float32), pool, pool,
+            arg((322,), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"%(span_flash_attention[.\w]*) = (\S+) custom-call",
+                       text)
+    assert len(calls) == 1 and calls[0][1].startswith("f32[8,16384,128]"), \
+        calls
+    assert "bf16[8,5632,128]" in text
 
 
 # -- the counter ------------------------------------------------------------

@@ -234,6 +234,44 @@ def test_experts_beside_attention_without_windows(f32_matmuls, parallel):
     assert int(counts[0]) <= 2 and int(counts[1]) <= 2
 
 
+@pytest.mark.parametrize("start,n", [(0, 32), (64, 20), (64, 3), (224, 32)])
+def test_a_span_writes_its_own_rows_and_nothing_else(model, f32_matmuls,
+                                                     start, n):
+    """One span of slot 1 into pools that hold 3.0 everywhere, both
+    page classes: the session's positions ``start .. start + n - 1``
+    are written through the class's row and NOTHING else: the rows
+    past ``n`` of the last live page keep what lay there, a page
+    wholly past ``n`` and the garbage pages stay as they were."""
+    cfg, m, lm, params = model
+    pps = lm.max_seq // PAGE
+    cache = {name: jnp.full_like(a, 3.0) if name != "len" else a
+             for name, a in T.empty_paged_cache(
+                 lm, 2 * pps + 1, 2, PAGE).items()}
+    r = np.random.default_rng(n)
+    bt = 1 + r.permutation(2 * pps)[:pps].astype(np.int32)
+    n_win = cache["pk0"].shape[0]
+    btw = np.zeros((pps,), np.int32)
+    first = max(0, start - lm.window + 1) // PAGE
+    last = (start + n - 1) // PAGE
+    btw[first:last + 1] = 1 + r.permutation(n_win - 1)[:last + 1 - first]
+    ids = np.arange(lm.fill_span, dtype=np.int32) + 7
+    fill = jax.jit(T.make_paged_span_fill(lm, PAGE))
+    after = fill(params, cache, jnp.asarray(bt), jnp.asarray(btw),
+                 np.int32(1), np.int32(start), np.int32(n), ids)
+    assert after["len"].tolist() == [0, start + n]
+    kvh = lm.kv_heads
+    for i, row in ((0, btw), (3, bt)):
+        assert bool(lm.windows[i]) == (row is btw)
+        for name in (f"pk{i}", f"pv{i}"):
+            pool = np.asarray(after[name])
+            pool = pool.reshape(pool.shape[0], PAGE, kvh, -1)
+            written = np.zeros(pool.shape[:2], bool)
+            for pos in range(start, start + n):
+                written[row[pos // PAGE], pos % PAGE] = True
+            assert (pool[~written] == 3.0).all()
+            assert (np.abs(pool[written] - 3.0).max(axis=(-1, -2)) > 0).all()
+
+
 # -- (c) the kernels, interpreted ----------------------------------------------
 
 @pytest.mark.parametrize("kvh,window,pos", [
@@ -267,10 +305,13 @@ def test_window_decode_kernel(kvh, window, pos):
     np.testing.assert_allclose(got, want, atol=2e-2)
 
 
+@pytest.mark.parametrize("paged", [True, False])
 @pytest.mark.parametrize("window,start,w,keys_from", [
     (40, 0, 32, 0), (40, 64, 32, 16), (40, 192, 32, 144), (0, 0, 32, 0),
     (0, 96, 32, 0), (24, 48, 16, 16)])
-def test_span_flash_kernel(window, start, w, keys_from):
+def test_span_flash_kernel(window, start, w, keys_from, paged):
+    """Both forms of the kernel: the keys read where they lie, and
+    gathered once."""
     r = np.random.default_rng(start + window)
     kvh, g, hd, pages = 2, 4, 32, 40
     q = jnp.asarray(r.normal(size=(w, kvh * g, hd)), jnp.float32)
@@ -281,9 +322,98 @@ def test_span_flash_kernel(window, start, w, keys_from):
     args = (q, pk, pv, ids, jnp.int32(start), jnp.int32(keys_from), PAGE,
             window)
     got = span_attention.span_flash_attention(
-        *args, block_q=8, block_k=32, interpret=True)
+        *args, block_q=8, block_k=32, interpret=True, paged=paged)
     want = span_attention.reference(*args)
     np.testing.assert_allclose(got, want, atol=2e-2)
+
+
+@pytest.mark.parametrize("blocks", [(8, 32), (None, None)])
+@pytest.mark.parametrize("whole", [False, True])
+@pytest.mark.parametrize("window,start", [
+    (0, 0), (0, 32), (0, 224), (40, 0), (40, 32), (40, 224)])
+def test_the_span_kernel_reads_the_pages_it_reaches_where_they_lie(
+        window, start, whole, blocks):
+    """A span of 32 rows at the table's start, one span in and at its
+    end (``max_seq`` 256), both layouts: the table in scrambled order,
+    every entry ahead of the span's last row or behind the window's
+    reach naming page 0, which holds NaN.  ``pages_reached`` names
+    exactly the entries the kernel fetches: one of them on page 0
+    poisons the result."""
+    r = np.random.default_rng(start + window)
+    w, kvh, g, hd, pages, pps = 32, 2, 1 if whole else 4, 32, 40, 16
+    dims = (pages, PAGE, kvh, hd) if whole else (pages, PAGE * kvh, hd)
+    q = jnp.asarray(r.normal(size=(w, kvh * g, hd)), jnp.float32)
+    pk, pv = (jnp.asarray(r.normal(size=dims), jnp.float32).at[0].set(
+        jnp.nan) for _ in range(2))
+    first, end = span_attention.pages_reached(start, w, PAGE, window)
+    ids = np.zeros((pps,), np.int32)
+    ids[first:end] = 1 + r.permutation(pages - 1)[:end - first]
+    bq, bk = blocks
+
+    def kernel(table):
+        return span_attention.span_flash_attention(
+            q, pk, pv, jnp.asarray(table), jnp.int32(start), jnp.int32(0),
+            PAGE, window, block_q=bq, block_k=bk, interpret=True,
+            paged=True)
+
+    got = kernel(ids)
+    want = span_attention.reference(
+        q, jnp.nan_to_num(pk), jnp.nan_to_num(pv), jnp.asarray(ids),
+        jnp.int32(start), jnp.int32(0), PAGE, window)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-2)
+    for lost in (first, end - 1):
+        assert not bool(jnp.isfinite(kernel(
+            np.where(np.arange(pps) == lost, 0, ids))).all())
+
+
+@pytest.mark.parametrize("form", ["write_plain", "span_page_write"])
+@pytest.mark.parametrize("whole", [False, True])
+@pytest.mark.parametrize("n", [32, 27, 16, 3, 0])
+def test_whole_pages_hold_what_the_rows_scatter_wrote(n, whole, form):
+    """Both forms of ``span_attention.write`` (XLA's merge, and the
+    kernel's copies, interpreted) against a scatter of the ``n`` real
+    rows one at a time: the same pool everywhere: a row past ``n`` in
+    the last live page keeps what lay there, a page wholly past ``n``
+    names the garbage page, which stays as it was."""
+    r = np.random.default_rng(n)
+    w, kvh, hd, pages = 32, 2, 32, 12
+    dims = (pages, PAGE, kvh, hd) if whole else (pages, PAGE * kvh, hd)
+    pool = jnp.asarray(r.normal(size=dims), jnp.float32)
+    rows = jnp.asarray(r.normal(size=(w, kvh, hd)), jnp.float32)
+    table = np.asarray([7, 3], np.int32)
+    mine = np.where(np.arange(2) * PAGE < n, table, 0)
+    kw = {"interpret": True} if form == "span_page_write" else {}
+    got = np.asarray(getattr(span_attention, form)(
+        pool, rows, jnp.asarray(mine), jnp.int32(n), PAGE, **kw))
+    want = np.asarray(pool).reshape(pages, PAGE, kvh, hd).copy()
+    for j in range(n):
+        want[table[j // PAGE], j % PAGE] = np.asarray(rows[j])
+    np.testing.assert_array_equal(got, want.reshape(dims))
+
+
+def test_on_the_tpu_whole_tiles_are_written_by_the_kernel(monkeypatch):
+    """``span_attention.write`` with the TPU's choice made: rows of
+    whole tiles (8 key/value heads of 128) go through
+    ``span_page_write`` and nothing is gathered or scattered; narrower
+    rows (the toys') take XLA's merge."""
+    from brpc_tpu.ops import device_ops
+    monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+
+    def prims(kvh, hd):
+        pool = jnp.zeros((6, PAGE * kvh, hd), jnp.float32)
+        rows = jnp.zeros((32, kvh, hd), jnp.float32)
+        jaxpr = jax.make_jaxpr(
+            lambda pool, rows, ids, n: span_attention.write(
+                pool, rows, ids, n, PAGE))(
+            pool, rows, jnp.zeros((2,), jnp.int32), jnp.int32(20))
+        return str(jaxpr)
+
+    text = prims(8, 128)
+    assert "span_page_write" in text
+    assert "scatter" not in text and "gather" not in text
+    text = prims(2, 16)
+    assert "span_page_write" not in text and "scatter" in text
 
 
 # -- (d) the window class gives pages back ----------------------------------------
@@ -396,6 +526,16 @@ def test_batcher_gives_pages_back_and_counts_it(model, f32_matmuls):
     assert stats["moe"]["steps"] == stats["steps"] > 0
     assert "prefix" not in stats                # the prefix cache declines
     assert stats["prefills_run"] == 4
+    # the fills: contexts of 29, 199, 69 and 129 rows in spans of 32;
+    # a span from row s reaches (s + 32) / 16 entries of the global
+    # table and those from (s - 39) // 16 on of the window class's
+    starts = [s for n in (29, 199, 69, 129) for s in range(0, n, 32)]
+    assert stats["fill"] == {
+        "spans": 16, "rows": 426, "pages_written": 2 + 13 + 5 + 9,
+        "pages_attended": sum(2 * (s // 16 + 2) - max(s - 39, 0) // 16
+                              for s in starts),
+        "pages_table": 16 * 2 * 16}
+    assert stats["fill"]["pages_attended"] == 165
 
 
 # -- (e) the counts ------------------------------------------------------------
