@@ -1,7 +1,8 @@
 """LM serving over the framework — autoregressive generation as an RPC.
 
-The capstone wiring: the TransformerLM's KV-cache decode path
-(``make_decode``/``generate``) behind a Service, so a Channel client
+The capstone wiring: the TransformerLM behind a Service (``Generate``
+runs ``make_scan_generator``'s whole-completion program, ``Decode``
+streams from the paged :class:`ContinuousBatcher`), so a Channel client
 (or grpc/HTTP through the bridges) asks for completions the way it
 would ask any brpc-style service.  The reference's analogue is its
 model-serving example services; here the "model" is an actual LM.
@@ -32,8 +33,7 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_HOST_SPILL, PH_IDLE_WAIT,
                            PH_INSERT_DISPATCH, PH_PAGE_ALLOC,
                            PH_PREFILL_DISPATCH, PH_PREFIX_LOOKUP,
-                           PH_SCHED, PH_SPEC_DRAFT, PH_SPEC_VERIFY,
-                           PH_STEP_DISPATCH, PH_STREAM_EMIT,
+                           PH_SCHED, PH_STEP_DISPATCH, PH_STREAM_EMIT,
                            PH_TOKEN_WALK)
 from . import mla_mixer
 from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
@@ -121,10 +121,10 @@ class TierRegistry:
         return self._slo.get(tier, (None, None))
 
 
-# CLOSED enums (tools/check/enums.py pins every member to a test): the
-# scheduler's named decisions and the spec-decode round outcomes.
-# count_* assert membership so an unregistered name fails loudly at the
-# first count, not silently in a dashboard.
+# CLOSED enum (tools/check/enums.py pins every member to a test): the
+# scheduler's named decisions.  count_sched asserts membership so an
+# unregistered name fails loudly at the first count, not silently in a
+# dashboard.
 SLO_SCHED_EVENTS = (
     "sched_chunk_slice",        # one bounded prefill slice ran
     "sched_catchup_slice",      # slice replaying past a partial prefix hit
@@ -132,16 +132,8 @@ SLO_SCHED_EVENTS = (
     "sched_preempt_batch",      # batch-tier victim spilled under pressure
 )
 
-SPEC_DECODE_EVENTS = (
-    "spec_round",               # one draft+verify round ran
-    "spec_accept",              # draft token confirmed by the target
-    "spec_reject",              # draft token refuted by the target
-    "spec_fallback_plain",      # round fell back to one plain step
-)
-
 _sched_lock = threading.Lock()
 _sched = {r: 0 for r in SLO_SCHED_EVENTS}
-_spec = {r: 0 for r in SPEC_DECODE_EVENTS}
 
 
 def count_sched(event: str, n: int = 1) -> None:
@@ -150,34 +142,19 @@ def count_sched(event: str, n: int = 1) -> None:
         _sched[event] += n
 
 
-def count_spec(event: str, n: int = 1) -> None:
-    assert event in _spec, f"unregistered spec-decode event: {event}"
-    with _sched_lock:
-        _spec[event] += n
-
-
 def sched_counters() -> dict:
     with _sched_lock:
         return dict(_sched)
-
-
-def spec_counters() -> dict:
-    with _sched_lock:
-        return dict(_spec)
 
 
 def _reset_sched_for_tests() -> None:
     with _sched_lock:
         for k in _sched:
             _sched[k] = 0
-        for k in _spec:
-            _spec[k] = 0
 
 
 _sched_var = PassiveDimension(("event",), lambda: sched_counters(),
                               name="lm_slo_sched_total")
-_spec_var = PassiveDimension(("event",), lambda: spec_counters(),
-                             name="lm_spec_decode_total")
 
 
 # ``paged`` stays a keyword (default True) of ``ContinuousBatcher`` and
@@ -262,8 +239,7 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
 
 
 def _setlen(cache, slot, val):
-    """Jittable per-slot ``len`` poke (the engine's cache and the
-    spec-decode draft's)."""
+    """Jittable per-slot ``len`` poke of the engine's cache."""
     import jax.lax as lax
     cache = dict(cache)
     cache["len"] = lax.dynamic_update_slice(cache["len"], val[None],
@@ -325,8 +301,7 @@ class ContinuousBatcher:
     still owned when the step was queued, nobody reads the token, and
     the device runs programs in order, so a later join's insert lands
     after it.  With nothing in flight (the first step after idle) the
-    step is dispatched alone; a speculative
-    round reads its tokens before it ends, so nothing runs ahead there.
+    step is dispatched alone.
     ``kv_stats()["lookahead"]`` counts which way each step left.
 
     **A catch-up slice rides the step.**  A session that fills its
@@ -337,9 +312,9 @@ class ContinuousBatcher:
     decode rows and the slice's rows cross every weight together, the
     weights are read once), one slice a step; the slice that completes
     the context activates the session first, so its first token comes
-    out of the same step.  A slice that cannot ride is a program of
-    its own before the step, as all were: a speculative round's, and
-    what a ``prefill_chunk_tokens`` budget allows a round beyond one.
+    out of the same step.  What a ``prefill_chunk_tokens`` budget
+    allows a round beyond one slice runs as programs of their own
+    before the step.
     ``kv_stats()["lookahead"]`` counts ``slices`` and ``slices_rode``.
 
     **Paged KV** (the kv/pages allocator): one shared page pool per
@@ -374,7 +349,7 @@ class ContinuousBatcher:
     just lets the slot go.  A page of keys restores no recurrent
     state, so for such a model the prefix cache declines every lookup
     (counted, ``kv_stats()["prefix"]["declined_state"]``) and the
-    options that would park, catch up, import or speculate are refused
+    options that would park, catch up or import are refused
     at construction (:class:`UnsupportedBlock`).
 
     **Two page classes** (a schedule with window layers,
@@ -393,7 +368,7 @@ class ContinuousBatcher:
     length, each span a ``prefill_dispatch``), all of them at
     admission, between two steps.  The prefix cache declines it (a
     page it aliased would have to know its class), as do park/resume,
-    slices, speculation and KV import (refused at construction).
+    slices and KV import (refused at construction).
     ``kv_stats()["window"]`` counts pages held against what whole
     contexts would hold, over steps, and pages given back.
 
@@ -405,9 +380,9 @@ class ContinuousBatcher:
     are the first block's.  Its prompts too go through the pages in
     spans at admission (a ``max_seq`` stripe a (pass, layer) would be
     gigabytes a join); no prefix cache is built for it, and park /
-    resume, slices, speculation and KV import are refused at
-    construction.  ``kv_stats()["loop"]`` counts the layer bodies its
-    steps ran and the spans its fills queued.
+    resume, slices and KV import are refused at construction.
+    ``kv_stats()["loop"]`` counts the layer bodies its steps ran and
+    the spans its fills queued.
 
     Either kind's spans touch their own pages only: a span's rows go
     into the pools as whole pages and its attention fetches the table
@@ -434,16 +409,7 @@ class ContinuousBatcher:
       tier-then-footprint (batch-tier sessions park before standard,
       interactive last) with batch victims taken even BEFORE
       prefix-cache holds when the requester outranks them.  Every
-      decision counts under the closed ``SLO_SCHED_EVENTS`` enum;
-    - **speculative decoding** (``spec_decode_k``): a small draft
-      model proposes k tokens per active slot (k cheap steps over a
-      page pool of its own, addressed through the target's block
-      table), the target verifies all of them in ONE batched
-      multi-token program, accepted prefixes advance the page table
-      and rejections are a pure ``len`` rewind (the refuted
-      rows sit beyond the mask and are rewritten before ever being
-      admitted) — token identity with plain decode holds on both
-      paths.  Acceptance telemetry rides ``SPEC_DECODE_EVENTS``.
+      decision counts under the closed ``SLO_SCHED_EVENTS`` enum.
     """
 
     def __init__(self, cfg: LMConfig, params, slots: int = 8,
@@ -452,7 +418,6 @@ class ContinuousBatcher:
                  host_slots: int = 0, prefix: bool = True,
                  prefix_budget: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
-                 spec_decode_k: int = 0, draft_params=None,
                  tiers: Optional[TierRegistry] = None):
         self.cfg = cfg
         self.params = params
@@ -478,15 +443,10 @@ class ContinuousBatcher:
             if prefill_chunk_tokens else 0
         self._chunk_w = min(self.chunk_budget, cfg.max_seq) \
             if self.chunk_budget else min(64, cfg.max_seq)
-        self.spec_k = int(spec_decode_k)
-        self.draft_params = draft_params
-        if self.spec_k > 0 and draft_params is None:
-            raise ValueError("spec_decode_k requires draft_params")
         if not cfg.plain_block():
             # nothing runs such a model wrong silently: what this
             # engine does not port declines here, by name
             for on, what in (
-                    (self.spec_k > 0, "spec_decode_k (speculative verify)"),
                     (self.host_slots > 0,
                      "host_slots (park/resume and host spill)"),
                     (self.chunk_budget > 0,
@@ -580,12 +540,6 @@ class ContinuousBatcher:
         self._setlen_j = None
         self._settok_j = None
         self._chunk_j = None                      # chunked prefill slice
-        # spec-decode engine state (built when spec_k > 0)
-        self._d_prefill = None
-        self._d_step = None
-        self._d_cache = None
-        self._verify_j = None
-        self._d_sync_j = None
         self._parked: list = []                   # spilled sessions
         self.prefills_run = 0
         self.spills = 0
@@ -688,7 +642,7 @@ class ContinuousBatcher:
                "prefills_run": self.prefills_run,
                "spills": self.spills, "resumes": self.resumes,
                "parked": len(self._parked),
-               "sched": sched_counters(), "spec": spec_counters(),
+               "sched": sched_counters(),
                "phases": _lmt.phase_counters(),
                "phase_ns": _lmt.phase_total_ns(),
                "loop_ns": _lmt.loop_ns(),
@@ -764,15 +718,13 @@ class ContinuousBatcher:
         block-paged step, the page-granular I/O programs, and the
         allocator / prefix-cache / host-tier triple from ``kv.pages``."""
         import jax
-        import jax.numpy as jnp
 
         from ..kv.pages import (HostPagePool, PageAllocator,
                                 PrefixCache, WindowTable)
         from .transformer_lm import (empty_paged_cache, jit_with_params,
                                      make_paged_io,
                                      make_paged_batch_decode,
-                                     make_paged_span_fill,
-                                     make_paged_spec_verify)
+                                     make_paged_span_fill)
 
         if self._prefill is None:
             from ..ops import paged_attention
@@ -820,40 +772,10 @@ class ContinuousBatcher:
                 self._span_fill = jit_with_params(
                     make_paged_span_fill(self.cfg, self.page),
                     self.params, donate_argnums=(0,))
-            if self.spec_k > 0:
-                # draft engine: the SMALL model runs k cheap steps per
-                # round over a page pool of its own, addressed through
-                # the SAME block table (its inserts are the engine's
-                # ``insert`` over ``sess.pages``); the target verifies
-                # all k proposals in one width-(k+1) program.  Draft
-                # len sync is a pure arithmetic rewind — after k draft
-                # steps len = L + k, the target accepted m, so the
-                # draft keeps rows for L..L+m and rewinds k-1-m.
-                self._d_prefill = jit_with_params(prefill,
-                                                  self.draft_params)
-                self._d_step = jit_with_params(step, self.draft_params,
-                                               donate_argnums=(0,))
-                verify = make_paged_spec_verify(self.cfg, self.page,
-                                                self.spec_k + 1)
-                self._verify_j = jit_with_params(verify, self.params,
-                                                 donate_argnums=(0,))
-                k = self.spec_k
-
-                def _d_sync(cache, m, active):
-                    cache = dict(cache)
-                    cache["len"] = jnp.where(
-                        active, cache["len"] - (k - 1 - m),
-                        cache["len"])
-                    return cache
-
-                self._d_sync_j = jax.jit(_d_sync, donate_argnums=(0,))
         if self._cache is None:
             self._cache = empty_paged_cache(self.cfg, self.num_pages,
                                             self.slots, self.page)
             self._bt[:] = 0
-        if self.spec_k > 0 and self._d_cache is None:
-            self._d_cache = empty_paged_cache(self.cfg, self.num_pages,
-                                              self.slots, self.page)
         if self._alloc is None:
             pb = paged_page_bytes(self.cfg, self.page)
             self._alloc = PageAllocator(self.num_pages, self.page, pb)
@@ -1091,8 +1013,6 @@ class ContinuousBatcher:
         sess.fill = ctx_len
         self._set_token(free, last)
         self._active[free] = True
-        if self.spec_k > 0:
-            self._draft_admit(sess)
 
     def _refuse(self, sess: _Session, why: str, pages) -> None:
         """An admission the pool cannot cover: the pages it held go
@@ -1320,16 +1240,6 @@ class ContinuousBatcher:
         self._active[free] = sess.fill >= sess.ctx_len
         sess.slot = free
         self._sessions[free] = sess
-        if self._active[free] and self.spec_k > 0 \
-                and sess.prompt is not None:
-            # re-seed the DRAFT context for the resumed slot; rows for
-            # already-GENERATED tokens are not replayed, so acceptance
-            # dips until the draft re-anchors — correctness is the
-            # target's verify either way
-            self._draft_admit(sess)
-            self._d_cache = self._setlen_j(self._d_cache,
-                                           jnp.int32(free),
-                                           jnp.int32(sess.saved_len))
         self.resumes += 1
         tl = sess.tl
         if tl is not None:
@@ -1382,32 +1292,7 @@ class ContinuousBatcher:
                 still.append(sess)
         self._parked = still
 
-    # -- SLO scheduler: chunk rounds, spec rounds, plain rounds ------------
-
-    def _draft_admit(self, sess: _Session) -> None:
-        """Seed the DRAFT model's page pool for a newly active slot
-        (spec mode), through the slot's row of the block table.  The
-        draft is small — one bucketed prefill here is cheap, and it
-        keeps the draft's rows position-aligned with the target's
-        context (a page the target aliases from the prefix cache is
-        written again with the values it holds: the same tokens at the
-        same positions)."""
-        if self._d_cache is None or sess.prompt is None:
-            return
-        import jax.numpy as jnp
-        ph = self._clock.switch
-        outer = ph(PH_PREFILL_DISPATCH)
-        cache1, ctx_len = bucketed_prefill(self._d_prefill, self.cfg,
-                                           sess.prompt)
-        self._clock.filling(1, ctx_len)
-        ph(PH_INSERT_DISPATCH)
-        slot = jnp.int32(sess.slot)
-        self._d_cache = self._insert(
-            self._d_cache, jnp.asarray(self._bt[sess.slot]), cache1, slot)
-        self._clock.filling(1, 0)
-        self._d_cache = self._setlen_j(self._d_cache, slot,
-                                       jnp.int32(ctx_len))
-        ph(outer)
+    # -- SLO scheduler: chunk rounds, plain rounds -------------------------
 
     def _activate(self, sess: _Session) -> None:
         """A fully chunk-filled session goes live: the prompt's LAST
@@ -1427,8 +1312,6 @@ class ContinuousBatcher:
             if self._prefix is not None:
                 self._prefix.insert(sess.prompt[:-1],
                                     sess.pages[sess.n_alias:])
-        if self.spec_k > 0:
-            self._draft_admit(sess)
 
     def _chunk_round(self):
         """Spend this round's chunk budget: bounded prefill slices
@@ -1447,9 +1330,8 @@ class ContinuousBatcher:
         (one pass over the weights for both); a session it completes
         is activated here all the same, so that its first token comes
         out of that step.  With no budget set (partial prefix hits
-        only) that is the round's one slice.  A speculating batcher
-        reads every round before the next, so nothing rides there:
-        every slice runs here, as a program of its own."""
+        only) that is the round's one slice; a budget's slices before
+        the last run here, each a program of its own."""
         filling = [s for s in self._sessions.values()
                    if s.fill < s.ctx_len]
         if not filling:
@@ -1461,9 +1343,8 @@ class ContinuousBatcher:
                 and any(s.tier_rank > filling[0].tier_rank
                         for s in filling):
             count_sched("sched_interactive_first")
-        rides = self.spec_k == 0
         budget = self.chunk_budget if self.chunk_budget else (1 << 30)
-        most = 1 if rides and not self.chunk_budget else (1 << 30)
+        most = (1 << 30) if self.chunk_budget else 1
         plan = []                       # (session, rows), in order
         for sess in filling:
             if budget <= 0 or len(plan) >= most:
@@ -1488,7 +1369,7 @@ class ContinuousBatcher:
             ids[:n] = sess.prompt[sess.fill:sess.fill + n]
             span = (np.int32(sess.slot), np.int32(sess.fill), np.int32(n),
                     ids)
-            if rides and i == len(plan) - 1:
+            if i == len(plan) - 1:
                 ride = span
                 self._slices_rode += 1
             else:
@@ -1506,21 +1387,6 @@ class ContinuousBatcher:
                 self._clock.filled(sess.tl)
                 self._activate(sess)
         return ride
-
-    def _spec_ok(self) -> bool:
-        """Spec rounds need width = k+1 rows of headroom in EVERY
-        active slot, and a prompt to draft from (a disagg-imported
-        session has none) — otherwise the round falls back to one
-        plain step."""
-        for slot, sess in self._sessions.items():
-            if not self._active[slot]:
-                continue
-            if sess.prompt is None:
-                return False
-            if sess.ctx_len + sess.sent + self.spec_k + 1 \
-                    > self.cfg.max_seq:
-                return False
-        return True
 
     def _set_token(self, slot: int, tok: int) -> None:
         """The host names the token a slot feeds the next step (a
@@ -1700,82 +1566,12 @@ class ContinuousBatcher:
         _lmt.on_emit(pairs, self._clock.rounds)
         if dead or finished:
             ph(PH_EVICT)
-        evicted = set()
         for sess, reason in dead:
-            # a spec round emits several tokens per session —
-            # one eviction decision each
-            if id(sess) not in evicted:
-                evicted.add(id(sess))
-                self._evict(sess, reason)
+            self._evict(sess, reason)
         for sess in finished:
             if self._sessions.get(sess.slot) is sess:
                 self._evict(sess, "finished")
         self._clock.delivered()
-
-    def _spec_round(self):
-        """One speculative round: k draft proposals per active slot
-        (k cheap draft steps), ONE width-(k+1) target
-        verification, host-side emission of the accepted prefix plus
-        the target's own next token.  Token identity with plain decode
-        holds on BOTH paths: an accepted row holds exactly the k/v a
-        plain step would have written there, and a rejection is a pure
-        ``len`` rewind — the refuted rows sit beyond the mask and the
-        next round rewrites them before they are ever admitted (see
-        ``make_paged_spec_verify``)."""
-        import jax.numpy as jnp
-        k = self.spec_k
-        count_spec("spec_round")
-        clock = self._clock
-        ph = clock.switch
-        ph(PH_SPEC_DRAFT)
-        clock.filling(0, 0)     # the drafts: the device is not dry
-        active = self._active.copy()
-        act_j = jnp.asarray(active)
-        bt_j = jnp.asarray(self._bt)
-        cur = self._tokens.copy()
-        drafts = []
-        for _ in range(k):
-            self._d_cache, dl = self._d_step(self._d_cache, bt_j,
-                                             jnp.asarray(cur), act_j)
-            cur = np.asarray(jnp.argmax(dl, axis=-1)).astype(np.int32)
-            drafts.append(cur)
-        # draft, verify, walk: three leaves and no enclosing sample;
-        # spec_verify holds the round's sync, one sample a step
-        ph(PH_SPEC_VERIFY)
-        t_wait = clock.t
-        u = np.stack([self._tokens] + drafts, axis=1).astype(np.int32)
-        self._cache, out, m = self._verify_j(
-            self._cache, bt_j, jnp.asarray(u), act_j)
-        # the round is one record, its verify the step
-        ordinal = clock.queued(clock.stamp(), self._steps,
-                               int(active.sum()), False, 0)
-        out = np.asarray(out)
-        m = np.asarray(m)
-        ph(PH_TOKEN_WALK)
-        clock.landed(t_wait, ordinal, False, 0, 0)
-        self._d_cache = self._d_sync_j(self._d_cache, jnp.asarray(m),
-                                       act_j)
-        self._steps += 1
-        pairs, finished = [], []
-        for slot, sess in list(self._sessions.items()):
-            if not active[slot]:
-                continue
-            acc = int(m[slot])
-            count_spec("spec_accept", acc)
-            count_spec("spec_reject", k - 1 - acc)
-            emit = min(acc + 1, sess.max_new - sess.sent)
-            for j in range(emit):
-                tok = int(out[slot, j])
-                self._tokens[slot] = tok
-                sess.sent += 1
-                pairs.append((sess, tok))
-            sess.queued = sess.sent
-            if sess.sent >= sess.max_new:
-                finished.append(sess)
-        # a plain step after this one feeds from the host's vector
-        self._tokens_d = None
-        self._sync += 1
-        return pairs, finished
 
     def _finalize_obs(self, sess: _Session, reason: str) -> None:
         """Session-close observability (batcher thread): judge and
@@ -1907,21 +1703,11 @@ class ContinuousBatcher:
                 # the device goes from one to the other while the host
                 # walks, emits and evicts
                 landing, self._flight = self._flight, None
-                if ride is None and not self._active.any():
-                    # every occupied slot has its last step in flight
-                    # (or, speculating, is still filling)
-                    pass
-                elif self.spec_k == 0:
-                    # with nothing active too: the slice is work
+                if ride is not None or self._active.any():
+                    # a slice alone is work too; with neither, every
+                    # occupied slot has its last step in flight
                     self._flight = self._dispatch(landing is not None,
                                                   ride)
-                elif self._spec_ok():
-                    self._deliver(*self._spec_round())
-                else:
-                    # a speculating batcher reads every round before
-                    # the next: the plain step too
-                    count_spec("spec_fallback_plain")
-                    landing = self._dispatch(False)
                 if landing is not None:
                     self._land(landing)
         except Exception:
@@ -1952,7 +1738,6 @@ class ContinuousBatcher:
                 # allocator triple goes with the pool: its refcounts
                 # describe rows that no longer exist.
                 self._cache = None
-                self._d_cache = None   # the draft pool donated too
                 self._bt[:] = 0
                 self._alloc = None
                 self._wt = None
@@ -1981,7 +1766,6 @@ class LMService(Service):
                  page: int = 16, kv_pages: Optional[int] = None,
                  kv_host_slots: int = 0, prefix: bool = True,
                  prefill_chunk_tokens: Optional[int] = None,
-                 spec_decode_k: int = 0, draft_params=None,
                  tiers: Optional[TierRegistry] = None):
         import jax
 
@@ -2023,8 +1807,6 @@ class LMService(Service):
         self.prefix = bool(prefix)
         # SLO-scheduler knobs (ContinuousBatcher docstring)
         self.prefill_chunk_tokens = prefill_chunk_tokens
-        self.spec_decode_k = int(spec_decode_k)
-        self.draft_params = draft_params
         self.tiers = tiers
         self._batcher: Optional[ContinuousBatcher] = None
         self._batcher_lock = threading.Lock()
@@ -2038,8 +1820,6 @@ class LMService(Service):
                     host_slots=self.kv_host_slots,
                     prefix=self.prefix,
                     prefill_chunk_tokens=self.prefill_chunk_tokens,
-                    spec_decode_k=self.spec_decode_k,
-                    draft_params=self.draft_params,
                     tiers=self.tiers)
             return self._batcher
 

@@ -35,9 +35,9 @@ Three planes, all fed by the ONE batcher thread and read passively:
 - **snapshot cache** — a ``_TelemetryCache``-style short-TTL cache so
   /vars, /metrics and the ``/lm`` portal page all share ONE snapshot
   per interval (``window()`` additionally retains the previous snapshot
-  so the windowed ``spec_accept_rate`` / ``prefix_cache_hit_ratio``
-  reflect CURRENT behavior instead of lifetime averages — the lifetime
-  keys stay where perf_guard reads them).
+  so the windowed ``prefix_cache_hit_ratio`` reflects CURRENT
+  behavior instead of a lifetime average — the lifetime key stays
+  where perf_guard reads it).
 
 Everything here must stay importable without the native engine and
 without jax — the module is pure-Python bookkeeping; the batcher hands
@@ -88,9 +88,7 @@ LM_STEP_PHASES = (
     "catchup_slice",     # slice replaying past a partial prefix hit (enqueue)
     "step_dispatch",     # the step's inputs, _step and argmax (enqueue)
     "device_wait",       # the tokens come back: the round's one sync
-    "spec_draft",        # the k draft-model steps of a spec round
-    "spec_verify",       # the width-(k+1) target verification and its sync
-    "token_walk",        # tokens -> (session, token) pairs; spec: accept walk
+    "token_walk",        # tokens -> (session, token) pairs
     "stream_emit",       # one step's token writes + the timelines' stamps
     "evict",             # dead and finished sessions leave
     "host_spill",        # one session's D2H park
@@ -107,13 +105,11 @@ PH_CHUNK_SLICE = 6
 PH_CATCHUP_SLICE = 7
 PH_STEP_DISPATCH = 8
 PH_DEVICE_WAIT = 9
-PH_SPEC_DRAFT = 10
-PH_SPEC_VERIFY = 11
-PH_TOKEN_WALK = 12
-PH_STREAM_EMIT = 13
-PH_EVICT = 14
-PH_HOST_SPILL = 15
-PH_HOST_RESUME = 16
+PH_TOKEN_WALK = 10
+PH_STREAM_EMIT = 11
+PH_EVICT = 12
+PH_HOST_SPILL = 13
+PH_HOST_RESUME = 14
 
 _NPHASES = len(LM_STEP_PHASES)
 # the ONE source of the names on the profiler's clock
@@ -860,7 +856,7 @@ class LmTelemetryCache:
         self.builds = 0
 
     def _build(self) -> dict:
-        from .lm_service import sched_counters, spec_counters
+        from .lm_service import sched_counters
         try:
             from ..kv.pages import prefix_event_counters
             prefix = prefix_event_counters()
@@ -874,7 +870,6 @@ class LmTelemetryCache:
             "phase_hists": {p: list(_phase_buckets[i])
                             for i, p in enumerate(LM_STEP_PHASES)},
             "sched": sched_counters(),
-            "spec": spec_counters(),
             "prefix_events": prefix,
             "slo": slo_counters(),
             "queue_ms": _queue_rows(),
@@ -921,18 +916,6 @@ def _delta(cur: dict, prev, key: str) -> int:
     return c - prev.get(key, 0) if prev is not None else c
 
 
-def windowed_spec_accept_rate(cache=None) -> float:
-    """Accepted/proposed draft tokens over the LAST snapshot window —
-    the /vars answer to 'how is acceptance NOW', vs the lifetime
-    cumulative ``spec_accept_rate`` the bench/perf_guard keep."""
-    prev, cur, _dt = (cache or telemetry_cache()).window()
-    p = prev["spec"] if prev is not None else None
-    acc = _delta(cur["spec"], p, "spec_accept")
-    rej = _delta(cur["spec"], p, "spec_reject")
-    denom = acc + rej
-    return acc / denom if denom > 0 else 0.0
-
-
 def windowed_prefix_hit_ratio(cache=None) -> float:
     """(hit + partial) / lookups over the LAST snapshot window."""
     prev, cur, _dt = (cache or telemetry_cache()).window()
@@ -957,15 +940,6 @@ def windowed_slo_deltas(cache=None) -> dict:
         if d:
             out.setdefault(tier, {})[verdict] = d
     return out
-
-
-def lifetime_spec_accept_rate() -> float:
-    """The cumulative ratio (perf_guard continuity — the windowed
-    variant above is what /vars shows)."""
-    from .lm_service import spec_counters
-    c = spec_counters()
-    denom = c["spec_accept"] + c["spec_reject"]
-    return c["spec_accept"] / denom if denom > 0 else 0.0
 
 
 def lifetime_prefix_hit_ratio() -> float:
@@ -1010,9 +984,8 @@ _itl_var = PassiveDimension(("tier", "quantile"), _itl_rows,
                             name="lm_itl_ms")
 _windowed_var = PassiveDimension(
     ("ratio",),
-    lambda: {"spec_accept_rate": round(windowed_spec_accept_rate(), 4),
-             "prefix_cache_hit_ratio":
-                 round(windowed_prefix_hit_ratio(), 4)},
+    lambda: {"prefix_cache_hit_ratio":
+             round(windowed_prefix_hit_ratio(), 4)},
     name="lm_windowed")
 
 _LM_VARS = (

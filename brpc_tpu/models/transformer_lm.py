@@ -269,8 +269,7 @@ class LMConfig:
 
     def plain_block(self) -> bool:
         """True for the program's first block, the only one the
-        training, contiguous, scanned, speculative and export
-        factories run."""
+        training, contiguous, scanned and export factories run."""
         return (set(self.mixers) == {"attn"} and not self.has_experts
                 and self.norm_eps == 1e-6
                 and self.kv_heads == self.heads
@@ -626,7 +625,7 @@ def _rope_at(cfg: LMConfig, pos):
     """sin/cos of the rotary embedding at the positions ``pos``,
     anything that broadcasts to a program's ``(b, w)``: a scalar (one
     stream's step), ``(b, 1)`` (a step of slots at their own depths),
-    ``(w,)`` (a prompt, a chunk), ``(b, w)`` (speculative verify);
+    ``(w,)`` (a prompt, a chunk), ``(b, w)`` (a span a slot);
     None where the block has no rotary.  The math of
     :func:`_rope_tables`, so a slice rotates as the whole prompt does;
     the serving rotation's one home.  A program makes them once and
@@ -1408,15 +1407,15 @@ def state_slot_bytes(cfg: LMConfig) -> int:
 
 def _paged_span_layer(cfg: LMConfig, bp, x, pk, pv, bt, page_idx, row, pos):
     """One attention layer over a SPAN of ``w`` new positions a slot,
-    block-table addressing: the chunk slice's (its ``b = 1`` case) and
-    the speculative verify's.  ``x`` is ``(b, w, dim)``, ``bt`` ``(b,
-    max_seq // page)``; ``page_idx``, ``row`` and ``pos`` are ``(b,
-    w)``: where each new row is written, and the position it is
-    rotated and masked at.  Scatter before gather: the rows are
-    written, then each query attends over its slot's block table
-    gathered back into the contiguous ``max_seq`` view, under the live
-    mask ``key <= pos`` — the decode step's own, so a span is
-    identical by construction with as many single steps."""
+    block-table addressing: the chunk slice's (its ``b = 1`` case).
+    ``x`` is ``(b, w, dim)``, ``bt`` ``(b, max_seq // page)``;
+    ``page_idx``, ``row`` and ``pos`` are ``(b, w)``: where each new
+    row is written, and the position it is rotated and masked at.
+    Scatter before gather: the rows are written, then each query
+    attends over its slot's block table gathered back into the
+    contiguous ``max_seq`` view, under the live mask ``key <= pos`` —
+    the decode step's own, so a span is identical by construction with
+    as many single steps."""
     q, k, v = _qkv(cfg, bp, x, _rope_at(cfg, pos))
     att, pk, pv = _span_attend(cfg, q, k, v, pk, pv, bt, page_idx, row,
                                pos)
@@ -1706,79 +1705,6 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         return cache
 
     return gather, scatter, insert, chunk_prefill
-
-
-def make_paged_spec_verify(cfg: LMConfig, page: int, width: int):
-    """Speculative-decoding TARGET verification over the paged cache —
-    one multi-token step per round: ``width = k + 1`` candidate tokens
-    ``[x0, d1..dk]`` (the slot's pending token plus the draft model's
-    proposals) are scattered and attended in ONE program, and the
-    longest accepted prefix is computed on-device.
-
-    Returns ``verify(params, cache, bt, tokens[b, w], active[b]) ->
-    (cache, out[b, w], accepted[b])``:
-
-    - row ``j`` of ``out`` is the greedy argmax at position
-      ``len + j`` given context rows ``0..len+j`` — exactly the token
-      the plain decode step would emit after feeding ``tokens[:, :j+1]``
-      (:func:`_paged_span_layer`: same scatter-before-gather, same live
-      mask), which is the spec-decode token-identity contract;
-    - ``accepted`` is the per-slot length ``m`` of the draft prefix
-      matching the target (``d_i == out_{i-1}``), CAPPED at ``k - 1``
-      so the draft cache — which holds k/v for inputs ``u_0..u_{k-1}``
-      only — never runs ahead of a row it wrote (the standard
-      discard-the-bonus-token rule);
-    - ``len`` advances by ``m + 1`` for active slots (the emitted
-      tokens ``out[:, :m+1]``).  REJECTED rows ``len+m+1..len+k`` keep
-      their scattered garbage: they sit beyond the new len, and the
-      garbage-beyond-mask invariant (every admissible row is rewritten
-      by a later scatter before the live mask admits it) makes the
-      rollback a pure len rewind — no page-table mutation.
-
-    The caller must guarantee ``len + width <= max_seq`` for every
-    active slot (the batcher falls back to a plain step otherwise)."""
-    import jax.numpy as jnp
-
-    require_plain_block(cfg, "make_paged_spec_verify (speculative "
-                        "verify)")
-    if cfg.scan_layers:
-        raise NotImplementedError(
-            "spec verify supports unrolled layers only")
-    if cfg.max_seq % page:
-        raise ValueError(
-            f"page size {page} must divide max_seq {cfg.max_seq}")
-    w = int(width)
-    if w < 2:
-        raise ValueError("spec verify needs width >= 2 (k >= 1)")
-
-    def verify(params, cache, bt, tokens, active):
-        cache = dict(cache)
-        b = tokens.shape[0]
-        pos = jnp.minimum(
-            cache["len"][:, None] + jnp.arange(w)[None, :],
-            cfg.max_seq - 1)                       # (b, w)
-        # all w candidate rows are scattered (rejected ones become the
-        # garbage a later scatter overwrites — see docstring)
-        page_idx = bt[jnp.arange(b)[:, None], pos // page]
-        row = pos % page
-        x = params["embed"][tokens]                # (b, w, dim)
-        for i in range(cfg.depth):
-            x, pk, pv = _paged_span_layer(
-                cfg, params[f"blk{i}"], x, cache[f"pk{i}"],
-                cache[f"pv{i}"], bt, page_idx, row, pos)
-            cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
-        logits = _logits(cfg, params, x)           # (b, w, vocab)
-        out = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        # accepted prefix: d_i (= tokens[:, i]) vs out[:, i-1], capped
-        # at k-1 = w-2 (the bonus-token discard)
-        match = (tokens[:, 1:] == out[:, :w - 1]).astype(jnp.int32)
-        m = jnp.minimum(jnp.cumprod(match, axis=1).sum(axis=1),
-                        w - 2).astype(jnp.int32)   # (b,)
-        cache["len"] = jnp.where(active, cache["len"] + m + 1,
-                                 cache["len"])
-        return cache, out, m
-
-    return verify
 
 
 def make_decode_loop(cfg: LMConfig, steps: int):

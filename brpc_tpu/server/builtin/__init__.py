@@ -505,8 +505,8 @@ def _lm(server, msg, rest):
     gaps of the batcher's ring), KV pool / state pool / prefix cache
     / host tier occupancy, the model as ``LM.Info`` gives it (with its
     layer schedule), and the WINDOWED
-    spec-accept and prefix-hit ratios (current behavior — the lifetime
-    cumulative keys stay on the bench/perf_guard plane).  One
+    prefix-hit ratio (current behavior — the lifetime
+    cumulative key stays on the bench/perf_guard plane).  One
     LmTelemetryCache window renders the whole page, same discipline as
     /native's one engine snapshot."""
     from ...models import lm_telemetry as lmt
@@ -558,20 +558,15 @@ def _lm(server, msg, rest):
         "queue": cur["queue"],
         "windowed": {
             "window_s": round(dt, 3),
-            "spec_accept_rate":
-                round(lmt.windowed_spec_accept_rate(cache), 4),
             "prefix_cache_hit_ratio":
                 round(lmt.windowed_prefix_hit_ratio(cache), 4),
             "sched_rate_per_s": sched_rate,
         },
         "lifetime": {
-            "spec_accept_rate":
-                round(lmt.lifetime_spec_accept_rate(), 4),
             "prefix_cache_hit_ratio":
                 round(lmt.lifetime_prefix_hit_ratio(), 4),
         },
         "sched": cur["sched"],
-        "spec": cur["spec"],
         "prefix_events": cur["prefix_events"],
         "kv": kv,
         # what is served: widths and, for a block beyond the first, the

@@ -102,16 +102,14 @@ ENTRY_POINTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     ("brpc_tpu/kv/pages.py", ("KvPageStore", "release_owner")),
     ("brpc_tpu/kv/pages.py", ("drain_settle",)),
     ("brpc_tpu/kv/transport.py", ("KvTransport", "_settle")),
-    # SLO-tiered scheduler (ISSUE 17): the chunk-prefill round and the
-    # speculative-decode round run inside the batcher's step loop —
-    # every live session's next token waits on them, so a blocking
-    # primitive there is an ITL stall for the whole slot pool (the
-    # step loop itself, _run, carries its sanctioned idle sleep and is
-    # not entry-listed; these rounds must stay primitive-free)
+    # SLO-tiered scheduler (ISSUE 17): the chunk-prefill round runs
+    # inside the batcher's step loop — every live session's next token
+    # waits on it, so a blocking primitive there is an ITL stall for
+    # the whole slot pool (the step loop itself, _run, carries its
+    # sanctioned idle sleep and is not entry-listed; this round must
+    # stay primitive-free)
     ("brpc_tpu/models/lm_service.py",
      ("ContinuousBatcher", "_chunk_round")),
-    ("brpc_tpu/models/lm_service.py",
-     ("ContinuousBatcher", "_spec_round")),
     # the fourth chain binding (http_slim): enter/settle run inside
     # the kind-4 shim's per-burst GIL entry, on a loop thread
     ("brpc_tpu/server/interceptors.py",

@@ -150,16 +150,15 @@ def check_enums(tree: Tree) -> List[Finding]:
                         if s:
                             reason_names.append((s, f"{rel} (kv)"))
         if rel.endswith("models/lm_service.py"):
-            # the SLO scheduler's closed event enums (chunk-slice /
-            # preemption events + spec-decode outcomes): count_sched/
-            # count_spec assert membership at runtime, and every member
-            # needs a test anchor here — an unpinned scheduler event is
-            # free to drift out of the telemetry contract
+            # the SLO scheduler's closed event enum (chunk-slice /
+            # preemption events): count_sched asserts membership at
+            # runtime, and every member needs a test anchor here — an
+            # unpinned scheduler event is free to drift out of the
+            # telemetry contract
             for node in ast.walk(mod):
                 if isinstance(node, ast.Assign) \
                         and isinstance(node.targets[0], ast.Name) \
-                        and node.targets[0].id in (
-                            "SLO_SCHED_EVENTS", "SPEC_DECODE_EVENTS") \
+                        and node.targets[0].id == "SLO_SCHED_EVENTS" \
                         and isinstance(node.value, ast.Tuple):
                     for e in node.value.elts:
                         s = _str_const(e)

@@ -501,7 +501,6 @@ def _generate_declines():
 DECLINES = {
     "training": lambda: T.make_forward(_cfg()),
     "contiguous_decode": lambda: T.make_decode(_cfg()),
-    "spec_verify": lambda: T.make_paged_spec_verify(_cfg(), PAGE, 3),
     "kv_export_specs": lambda: T.kv_page_specs(_cfg()),
     "kv_export": lambda: T.export_decode_cache(_cfg(), {}),
     "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
@@ -509,7 +508,6 @@ DECLINES = {
     "host_spill": lambda: T.make_paged_io(_cfg(), PAGE)[0]({}, None),
     "host_resume": lambda: T.make_paged_io(_cfg(), PAGE)[1]({}, None, None),
     "catch_up": lambda: T.make_paged_io(_cfg(), PAGE, chunk=8)[3](),
-    "batcher_spec": lambda: _batcher(spec_decode_k=2, draft_params={}),
     "batcher_park": lambda: _batcher(host_slots=4),
     "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
     "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),

@@ -1,10 +1,10 @@
 """The serving programs' one attention layer (ISSUE 29): ``_qkv`` ->
 rows scattered through a block table -> attention over the gathered
-pages -> ``_attn_out``, as the chunk slice and the speculative verify
-run it (``transformer_lm._paged_span_layer``), held to the TRAINING
+pages -> ``_attn_out``, as the chunk slice runs it
+(``transformer_lm._paged_span_layer``), held to the TRAINING
 forward (``make_forward``, which shares none of it) position by
-position, at the widths the engine uses: one token (a step), a few (a
-verify round), a whole chunk."""
+position, at widths from one token (a step) over a few to a whole
+chunk."""
 
 import jax
 import jax.numpy as jnp

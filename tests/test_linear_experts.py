@@ -692,7 +692,6 @@ DECLINES = {
     "training": lambda: T.make_forward(_lm()),
     "train_step": lambda: T.make_train_step(_lm()),
     "contiguous_decode": lambda: T.make_decode(_lm()),
-    "spec_verify": lambda: T.make_paged_spec_verify(_lm(), PAGE, 3),
     "kv_export_specs": lambda: T.kv_page_specs(_lm()),
     "kv_export": lambda: T.export_decode_cache(_lm(), {}),
     "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
@@ -703,7 +702,6 @@ DECLINES = {
     "riding_step": lambda: T.make_paged_batch_decode(
         _lm(), PAGE, chunk=8)[2](),
     "span_fill": lambda: T.make_paged_span_fill(_lm(), PAGE)(),
-    "batcher_spec": lambda: _batcher(spec_decode_k=2, draft_params={}),
     "batcher_park": lambda: _batcher(host_slots=4),
     "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
     "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),

@@ -10,9 +10,9 @@ Five planes:
   count;
 - PROFILER INVARIANTS: per-phase histogram mass equals the phase
   count, counts are monotonic across sessions, and the count of the
-  phase that holds the round's sync (``device_wait``; ``spec_verify``
-  in a speculative round) equals the batcher's step counter exactly —
-  the profiler is wired to the loop, not near it;
+  phase that holds the round's sync (``device_wait``) equals the
+  batcher's step counter exactly — the profiler is wired to the loop,
+  not near it;
 - THE LOOP, ACCOUNTED FOR (ISSUE 25): the phases partition the loop
   (they add up to ``loop_ns`` and never nest), the sync falls in
   ``device_wait`` and not in ``step_dispatch``, the same names reach
@@ -62,9 +62,8 @@ from brpc_tpu.streaming import StreamOptions, stream_create
 LM_STEP_PHASE_PINS = (
     "sched", "idle_wait", "prefix_lookup", "page_alloc",
     "prefill_dispatch", "insert_dispatch", "chunk_slice",
-    "catchup_slice", "step_dispatch", "device_wait", "spec_draft",
-    "spec_verify", "token_walk", "stream_emit", "evict", "host_spill",
-    "host_resume",
+    "catchup_slice", "step_dispatch", "device_wait", "token_walk",
+    "stream_emit", "evict", "host_spill", "host_resume",
 )
 LM_SLO_VERDICT_PINS = ("slo_ok", "slo_ttft_miss", "slo_itl_miss",
                        "slo_untargeted")
@@ -199,7 +198,6 @@ def test_phase_profiler_invariants():
     totals = lmt.phase_total_ns()
     assert totals["device_wait"] > 0 and totals["sched"] > 0
     assert totals["host_spill"] == 0             # nothing spilled here
-    assert c1["spec_draft"] == c1["spec_verify"] == 0
     # monotonic across a second session, and still step-exact
     st2 = _join(bat, _prompt(4, 9), 4)
     _finish(st2)
@@ -210,22 +208,6 @@ def test_phase_profiler_invariants():
     assert c2["device_wait"] == bat.steps_run()
     assert sum(lmt.phase_histogram("device_wait")) \
         == c2["device_wait"]
-
-
-def test_spec_round_phases_recorded():
-    _reset()
-    cfg, params = _setup()
-    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
-                            spec_decode_k=3, draft_params=params)
-    st = _join(bat, _prompt(4, 8), 6)
-    _finish(st)
-    _quiet(bat)
-    c = lmt.phase_counters()
-    # a speculative round is three leaves and no enclosing sample:
-    # spec_verify holds its sync, one sample a step
-    assert c["spec_draft"] == c["spec_verify"] == bat.steps_run() >= 1
-    assert c["token_walk"] == bat.steps_run()
-    assert c["device_wait"] == c["step_dispatch"] == 0
 
 
 def test_profiler_disable_flag_stops_sampling():
@@ -575,22 +557,22 @@ def test_one_snapshot_per_interval():
 def test_windowed_ratios_reflect_current_window():
     """Lifetime counters carry history; the windowed ratios are deltas
     between consecutive snapshots — stale history cannot dilute them."""
-    from brpc_tpu.models.lm_service import count_spec
+    from brpc_tpu.kv.pages import count_prefix
     _reset()
-    # seed old history: 9 accepts, 1 reject (lifetime rate 0.9)
+    # seed old history: 9 hits, 1 miss (lifetime ratio 0.9)
     for _ in range(9):
-        count_spec("spec_accept")
-    count_spec("spec_reject")
-    assert lmt.lifetime_spec_accept_rate() == pytest.approx(0.9)
+        count_prefix("prefix_hit")
+    count_prefix("prefix_miss")
+    assert lmt.lifetime_prefix_hit_ratio() == pytest.approx(0.9)
     cache = lmt.LmTelemetryCache(ttl_s=0.0)      # every call refreshes
     cache.get()                                  # baseline snapshot
-    # the current window: 1 accept, 3 rejects
-    count_spec("spec_accept")
+    # the current window: 1 hit, 3 misses
+    count_prefix("prefix_hit")
     for _ in range(3):
-        count_spec("spec_reject")
-    assert lmt.windowed_spec_accept_rate(cache) == pytest.approx(0.25)
+        count_prefix("prefix_miss")
+    assert lmt.windowed_prefix_hit_ratio(cache) == pytest.approx(0.25)
     # lifetime is untouched by the windowing
-    assert lmt.lifetime_spec_accept_rate() == pytest.approx(10 / 14)
+    assert lmt.lifetime_prefix_hit_ratio() == pytest.approx(10 / 14)
 
 
 def test_windowed_prefix_ratio():
@@ -774,9 +756,8 @@ def test_lm_portal_and_metrics_exposition():
         assert recent[0]["verdict"] == "slo_untargeted"
         assert 0 <= recent[0]["queue_ms"] <= recent[0]["ttft_ms"]
         assert page["live_sessions"] == []
-        assert "spec_accept_rate" in page["windowed"]
         assert "prefix_cache_hit_ratio" in page["windowed"]
-        assert page["lifetime"]["spec_accept_rate"] == 0.0
+        assert page["lifetime"]["prefix_cache_hit_ratio"] == 0.0
         assert page["timeline_ring"]["len"] == 1
         assert page["kv"]["phases"]["device_wait"] \
             == lm.batcher().steps_run()
@@ -809,7 +790,7 @@ def test_lm_portal_and_metrics_exposition():
         assert 'lm_slo_attained_total{tier="standard",' \
             'verdict="slo_untargeted"}' in text
         assert 'lm_ttft_ms{tier="standard",quantile="p50"}' in text
-        assert 'lm_windowed{ratio="spec_accept_rate"}' in text
+        assert 'lm_windowed{ratio="prefix_cache_hit_ratio"}' in text
         assert 'lm_step_phase_ns{phase="device_wait",bin=' in text
     finally:
         srv.stop()

@@ -201,20 +201,6 @@ def test_uploads_only_where_membership_changes(model, page):
     assert look2["sync"] == 2
 
 
-def test_speculative_rounds_never_run_ahead(model):
-    cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
-                            spec_decode_k=3, draft_params=params,
-                            idle_linger_s=0.2)
-    p = _prompt(51, 8)
-    st = _join(bat, p, 9)
-    _finish(st)
-    _quiet(bat)
-    assert st.tokens == _want(model, p, 9)
-    look = bat.kv_stats()["lookahead"]
-    assert look["ahead"] == 0 and look["sync"] == bat.steps_run() >= 1
-
-
 # -- the order of a pass ------------------------------------------------------
 
 class _Recorder:

@@ -770,7 +770,6 @@ DECLINES = {
     "contiguous_decode": lambda: T.make_decode(_lm()),
     "whole_prompt_prefill": lambda: T.make_prefill(_lm())(),
     "insert": lambda: T.make_paged_io(_lm(), PAGE)[2]({}, None, None, 0),
-    "spec_verify": lambda: T.make_paged_spec_verify(_lm(), PAGE, 3),
     "kv_export_specs": lambda: T.kv_page_specs(_lm()),
     "kv_export": lambda: T.export_decode_cache(_lm(), {}),
     "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
@@ -780,7 +779,6 @@ DECLINES = {
     "catch_up": lambda: T.make_paged_io(_lm(), PAGE, chunk=8)[3](),
     "riding_step": lambda: T.make_paged_batch_decode(
         _lm(), PAGE, chunk=8)[2](),
-    "batcher_spec": lambda: _batcher(spec_decode_k=2, draft_params={}),
     "batcher_park": lambda: _batcher(host_slots=4),
     "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
     "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),

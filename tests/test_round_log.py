@@ -406,23 +406,6 @@ def test_two_batchers_keep_two_logs(model, level):
         assert bat.kv_stats()["first"]["n"] == 1
 
 
-def test_a_speculative_round_is_one_record(model, level):
-    cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
-                            spec_decode_k=3, draft_params=params,
-                            idle_linger_s=0.2)
-    st = _join(bat, _prompt(51, 8), 9)
-    _finish(st)
-    _wait(lambda: bat._thread is None, "the batcher never lingered out")
-    log = bat.round_log()
-    assert [x["ordinal"] for x in log] == list(range(bat.steps_run()))
-    assert all(x["ahead"] == 0 and x["land_ns"] > x["dispatch_ns"]
-               for x in log)
-    r = bat.kv_stats()["rounds"]
-    assert sum(r[cls]["n"] for cls in lmt.LM_ROUND_CLASSES) \
-        == bat.steps_run() >= 1
-
-
 # -- the real profiler, on the CPU --------------------------------------------
 
 def test_a_records_ordinal_is_its_rounds_step_num_in_a_trace(
@@ -540,6 +523,10 @@ def test_the_new_metrics_name_counters_the_program_has(model):
              for f in glob.glob(os.path.join(
                  spec.BENCH_DIR, "metrics", f"batcher.*_{stem}.json"))]
     assert len(files) == 27
+    # two phases left with speculative decoding (PR 42).  One file that
+    # no entry of BENCHMARK.json registers still sums them by name; the
+    # `benchmark` PR that registers it takes them out (ROADMAP S0)
+    gone = {"spec_draft", "spec_verify"}
     for path in files:
         m = spec.load_json(path)
         if m["reader"] == "round_excess":
@@ -547,10 +534,13 @@ def test_the_new_metrics_name_counters_the_program_has(model):
             continue
         assert m["reader"] == "counter_ratio_if_present"
         for p in m["num"] + m.get("den", []):
+            if p[-1] in gone:
+                assert path.endswith("batcher.chat_dry_host_share.json")
+                continue
             assert run.counter(run.c1, p) is not None, (path, p)
     host = spec.load_json(os.path.join(
         spec.BENCH_DIR, "metrics", "batcher.chat_dry_host_share.json"))
-    assert sorted(p[-1] for p in host["num"]) \
+    assert sorted(set(p[-1] for p in host["num"]) - gone) \
         == sorted(set(lmt.LM_STEP_PHASES) - {"idle_wait"})
 
 

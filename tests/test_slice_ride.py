@@ -255,23 +255,6 @@ def test_no_filling_slot_never_traces_the_riding_step(model):
 
 # -- (e) what cannot ride -----------------------------------------------------
 
-def test_speculative_rounds_run_their_slices_apart(model):
-    cfg, params = model
-    bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
-                            spec_decode_k=3, draft_params=params,
-                            idle_linger_s=0.2)
-    first, second = _docs(70)
-    _finish(_join(bat, first, 2))
-    st = _join(bat, second, 9)
-    _finish(st)
-    _quiet(bat)
-    assert st.tokens == _want(model, second, 9)
-    look = _look(bat)
-    assert look["slices"] == 2 and look["slices_rode"] == 0
-    assert bat._step_riding.func._cache_size() == 0
-    assert look["ahead"] == 0
-
-
 def test_a_budget_of_two_slices_a_round_rides_the_second(model):
     """``prefill_chunk_tokens`` 16: a long prompt takes whole rounds
     until its last five rows leave eleven of a round to the prompt

@@ -1,19 +1,19 @@
 """SLO-tiered batch scheduler (ISSUE 17): chunked prefill, priority
-preemption, speculative decoding.
+preemption.
 
 Three planes:
 
 - IDENTITY: every scheduler mode must emit the exact tokens of the
   monolithic greedy path — chunked prefill (slices of 4 and of 16),
-  partial prefix-hit catch-up, spec decode on BOTH the rejection and
-  the acceptance path, and a batch-tier session across park/resume;
+  partial prefix-hit catch-up, and a batch-tier session across
+  park/resume;
 - POLICY: interactive sessions get chunk budget first, and under pool
   pressure the spill victim is tier-then-footprint — an interactive
   session is NEVER parked while a batch-tier victim exists;
-- TELEMETRY: the closed ``SLO_SCHED_EVENTS`` / ``SPEC_DECODE_EVENTS``
-  enums are pinned member-by-member (the static enum checker requires
-  every name anchored here) and an unregistered event asserts loudly
-  at the first count.
+- TELEMETRY: the closed ``SLO_SCHED_EVENTS`` enum is pinned
+  member-by-member (the static enum checker requires every name
+  anchored here) and an unregistered event asserts loudly at the first
+  count.
 """
 
 import struct
@@ -26,8 +26,7 @@ import pytest
 
 from brpc_tpu.models.lm_service import (ContinuousBatcher, TierRegistry,
                                         _Session, _reset_sched_for_tests,
-                                        count_sched, count_spec,
-                                        sched_counters, spec_counters)
+                                        count_sched, sched_counters)
 from brpc_tpu.models.transformer_lm import (LMConfig, generate,
                                             init_params)
 from brpc_tpu.streaming import StreamOptions
@@ -39,21 +38,14 @@ from brpc_tpu.streaming import StreamOptions
 
 SLO_SCHED_PINS = ("sched_chunk_slice", "sched_catchup_slice",
                   "sched_interactive_first", "sched_preempt_batch")
-SPEC_DECODE_PINS = ("spec_round", "spec_accept", "spec_reject",
-                    "spec_fallback_plain")
 
 
 def test_sched_enums_match_pins():
-    from brpc_tpu.models.lm_service import (SLO_SCHED_EVENTS,
-                                            SPEC_DECODE_EVENTS)
+    from brpc_tpu.models.lm_service import SLO_SCHED_EVENTS
     assert SLO_SCHED_EVENTS == SLO_SCHED_PINS
-    assert SPEC_DECODE_EVENTS == SPEC_DECODE_PINS
     assert set(sched_counters()) == set(SLO_SCHED_PINS)
-    assert set(spec_counters()) == set(SPEC_DECODE_PINS)
     with pytest.raises(AssertionError):
         count_sched("sched_some_new_event")
-    with pytest.raises(AssertionError):
-        count_spec("spec_some_new_event")
 
 
 def test_tier_registry():
@@ -233,69 +225,6 @@ def test_partial_prefix_hit_catches_up_via_chunks():
     assert bat.prefills_run == pf                # the hit avoided one
     assert kv_pages.prefix_event_counters()["prefix_partial_hit"] == 1
     assert sched_counters()["sched_catchup_slice"] >= 1
-
-
-# ---------------------------------------------------------------------------
-# speculative decoding: bit-identity on both paths
-# ---------------------------------------------------------------------------
-
-def test_spec_decode_identity_rejection_path():
-    """A DIFFERENT draft model (wrong by construction): rejections
-    roll the page-table positions back and the emitted stream is
-    bit-identical with plain greedy decode — the verify step is the
-    ground truth regardless of draft quality."""
-    _reset()
-    cfg, params = _setup()
-    draft = init_params(jax.random.PRNGKey(1), cfg)
-    prompt = _prompt(4, 8)
-    want = np.asarray(generate(params, cfg, prompt[None, :], 6))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
-                            spec_decode_k=3, draft_params=draft)
-    st = _join(bat, prompt, 6)
-    _finish(st)
-    assert st.tokens == want.tolist()
-    assert st.close_reason == "finished"
-    sp = spec_counters()
-    assert sp["spec_round"] >= 1
-    assert sp["spec_reject"] >= 1
-
-
-def test_spec_decode_acceptance_and_fallback():
-    """The SAME weights as draft: some drafts verify (accepts > 0 —
-    acceptance is not total even self-speculatively, the draft and
-    verify programs are different einsum layouts and argmax ties
-    split), the stream stays bit-identical, and once the k+1-row
-    headroom runs out near max_seq the round falls back to a plain
-    step under its named reason."""
-    _reset()
-    cfg, params = _setup()
-    prompt = _prompt(4, 8)
-    want = np.asarray(generate(params, cfg, prompt[None, :], 24))[0]
-    bat = ContinuousBatcher(cfg, params, slots=2, page=16,
-                            spec_decode_k=3, draft_params=params)
-    st = _join(bat, prompt, 24)
-    _finish(st)
-    assert st.tokens == want.tolist()
-    assert st.close_reason == "finished"
-    sp = spec_counters()
-    assert sp["spec_round"] >= 1
-    assert sp["spec_accept"] >= 1
-    # a session with NO k+1-row headroom (ctx 29 + k + 1 > max_seq
-    # from its first round): every round falls back to a plain step
-    # under the named reason, stream still exact
-    long = _prompt(5, 30)
-    want2 = np.asarray(generate(params, cfg, long[None, :], 2))[0]
-    st2 = _join(bat, long, 2)
-    _finish(st2)
-    assert st2.tokens == want2.tolist()
-    assert st2.close_reason == "finished"
-    assert spec_counters()["spec_fallback_plain"] >= 1
-
-
-def test_spec_decode_constructor_contract():
-    cfg, params = _setup()
-    with pytest.raises(ValueError, match="draft_params"):
-        ContinuousBatcher(cfg, params, spec_decode_k=3)
 
 
 @pytest.mark.parametrize("cls", ["ContinuousBatcher", "LMService"])
