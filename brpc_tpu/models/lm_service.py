@@ -238,6 +238,19 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     return cache1, len(ctx)
 
 
+def _router_note(cfg: LMConfig) -> str:
+    """``":route@layer_input/softmax"`` where an expert layer's router
+    reads or scores otherwise than every block before it did (the rows
+    the experts are fed, a sigmoid): what the session span's
+    ``lm_schedule`` note and the model fingerprint carry of it; empty
+    for those blocks, whose notes and fingerprints stay what they
+    were."""
+    if not cfg.has_experts or (cfg.router_at, cfg.router_scoring) \
+            == ("ffn", "sigmoid"):
+        return ""
+    return f":route@{cfg.router_at}/{cfg.router_scoring}"
+
+
 def _setlen(cache, slot, val):
     """Jittable per-slot ``len`` poke of the engine's cache."""
     import jax.lax as lax
@@ -572,9 +585,12 @@ class ContinuousBatcher:
             if not self.cfg.plain_block():
                 # a block beyond the first: which mixer each layer
                 # has, and how often the stack is run where it is looped
+                # (and where its experts' router reads and scores
+                # otherwise than the rows they are fed, by a sigmoid)
                 span.annotate("lm_schedule:" + self.cfg.schedule()
                               + (f"*{self.cfg.passes}"
-                                 if self.cfg.passes > 1 else ""))
+                                 if self.cfg.passes > 1 else "")
+                              + _router_note(self.cfg))
         self._enqueue(sess)
 
     def _assign_tier(self, sess: _Session, tenant) -> None:
@@ -682,7 +698,9 @@ class ContinuousBatcher:
             lo, hi = cfg.experts_held
             out["moe"] = {"layers": len(cfg.expert_layers()),
                           "held": hi - lo, "routed": cfg.experts_routed,
-                          "top_k": cfg.experts_top_k, **self._moe}
+                          "top_k": cfg.experts_top_k,
+                          "scoring": cfg.router_scoring,
+                          "router_at": cfg.router_at, **self._moe}
         if cfg.has_window:
             wt = self._wt
             out["window"] = {
@@ -1938,7 +1956,7 @@ class LMService(Service):
                    f"{c.qk_rope_dim}x{c.v_head_dim}:{c.ffn_schedule()}:"
                    f"{c.expert_dim}x{c.experts_routed}x{c.experts_top_k}:"
                    f"{c.experts_held[0]}-{c.experts_held[1]}")
-        return fp.encode()
+        return (fp + _router_note(c)).encode()
 
     def Decode(self, cntl, request):
         """Server-streaming decode: same request wire format as
@@ -2030,5 +2048,7 @@ class LMService(Service):
                          "held": list(c.experts_held),
                          "top_k": c.experts_top_k, "dim": c.expert_dim,
                          "shared": c.shared_experts,
-                         "route_scale": c.route_scale})
+                         "route_scale": c.route_scale,
+                         "scoring": c.router_scoring,
+                         "router_at": c.router_at})
         return json.dumps(info).encode()

@@ -204,8 +204,10 @@ class ExpertConfig:
     def __init__(self, dim: int, hidden: int, routed: int,
                  held: Tuple[int, int], top_k: int,
                  route_scale: float = 1.0, shared: int = 0,
-                 bias: bool = True, shared_scale: float = 1.0):
+                 bias: bool = True, shared_scale: float = 1.0,
+                 scoring: str = "sigmoid", act: str = "silu"):
         assert 0 <= held[0] < held[1] <= routed and 1 <= top_k <= routed
+        assert scoring in ("sigmoid", "softmax") and act in ("silu", "relu")
         self.dim, self.hidden, self.routed = dim, hidden, routed
         self.held = (int(held[0]), int(held[1]))
         self.n_held = self.held[1] - self.held[0]
@@ -217,6 +219,10 @@ class ExpertConfig:
         # of ``shared * hidden`` times ``shared_scale`` = 1 / shared
         self.bias = bool(bias)
         self.shared_scale = float(shared_scale)
+        # what makes scores of the router's logits (a softmax is over
+        # ALL routed experts, before the choice), and the gate's
+        # activation in every expert, routed or shared
+        self.scoring, self.act = scoring, act
 
     def buffer_rows(self, rows: int) -> int:
         """The sorted buffer's rows for ``rows`` tokens: a token's
@@ -253,14 +259,18 @@ def init_served(rng, cfg: ExpertConfig) -> Dict[str, Any]:
 
 def route(p: Dict[str, Any], t, cfg: ExpertConfig) -> Tuple[Any, Any]:
     """``t (T, dim)`` float32 -> ``(ids (T, k), w (T, k))``: the top-k
-    of ``sigmoid(t W_r) + bias`` over ALL routed experts, weighed by
-    the scores WITHOUT the bias, renormalised over the chosen and
+    of ``score(t W_r) + bias`` over ALL routed experts (``score`` the
+    sigmoid, or the softmax over all of them: ``cfg.scoring``), weighed
+    by the scores WITHOUT the bias, renormalised over the chosen and
     scaled.  Float32 at ``highest``: a flipped choice changes a token's
-    output by a whole expert."""
+    output by a whole expert.  ``t`` is whatever the block's router
+    reads: the rows the experts are fed, or (``LMConfig.router_at``
+    ``"layer_input"``) the layer's input before its first norm."""
     import jax
     import jax.numpy as jnp
 
-    sc = jax.nn.sigmoid(jnp.dot(
+    score = jax.nn.sigmoid if cfg.scoring == "sigmoid" else jax.nn.softmax
+    sc = score(jnp.dot(
         t.astype(jnp.float32), p["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _top, ids = jax.lax.top_k(sc + p["bias"] if cfg.bias else sc,
@@ -270,21 +280,24 @@ def route(p: Dict[str, Any], t, cfg: ExpertConfig) -> Tuple[Any, Any]:
     return ids, w
 
 
-def _gated(x, w1, w2):
+def _gated(x, w1, w2, act=None):
     import jax
     import jax.numpy as jnp
 
     from ..ops.quant import mxu_matmul
 
     gate, up = jnp.split(mxu_matmul(x, w1), 2, axis=-1)
-    return mxu_matmul(jax.nn.silu(gate) * up, w2)
+    return mxu_matmul((act or jax.nn.silu)(gate) * up, w2)
 
 
-def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
+def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None, routed=None
           ) -> Tuple[Any, Any]:
     """The expert layer: ``t (T, dim)`` float32, normed -> ``(out (T,
     dim), counts (3,) int32)``: ``sum_k w_k E_k(t)`` over the chosen
-    experts HELD here plus the shared experts.  Rows not ``live`` (a
+    experts HELD here plus the shared experts.  ``routed`` is ``(ids,
+    w)`` where the caller routed (:func:`route` on rows of its own:
+    routing and the experts' input are then two operands); without it
+    the layer routes on ``t``.  Rows not ``live`` (a
     bucket's padding, an idle slot) are routed nowhere, so they cost no
     expert and count nowhere.  The routed sum is float32 and follows the
     live rows (``ops/expert_combine.py``): the buffer's rows behind them
@@ -300,25 +313,30 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None
 
     T, k, n = t.shape[0], cfg.top_k, cfg.n_held
     lo, hi = cfg.held
-    ids, w = route(p, t, cfg)
-    local = (ids >= lo) & (ids < hi)
-    if live is not None:
-        local = local & live[:, None]
-    # sort the (token, choice) pairs by held expert; the others last
-    key = jnp.where(local, ids - lo, n).reshape(T * k)
-    order = jnp.argsort(key, stable=True)[:cfg.buffer_rows(T)]
-    tok = order // k
-    sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
-                    dtype=jnp.int32)
+    # ``moe_route`` in a device trace: the router, the choice, the sort
+    # (and, in ``ops/expert_gmm.py``, the visit lists), apart from the
+    # products
+    with jax.named_scope("moe_route"):
+        ids, w = route(p, t, cfg) if routed is None else routed
+        local = (ids >= lo) & (ids < hi)
+        if live is not None:
+            local = local & live[:, None]
+        # sort the (token, choice) pairs by held expert; the others last
+        key = jnp.where(local, ids - lo, n).reshape(T * k)
+        order = jnp.argsort(key, stable=True)[:cfg.buffer_rows(T)]
+        tok = order // k
+        sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
+                        dtype=jnp.int32)
     bf = quant.mxu_operand
+    act = jax.nn.silu if cfg.act == "silu" else jax.nn.relu
     xs = bf(t)[tok]                                         # (M, dim)
     gate, up = jnp.split(expert_gmm(xs, bf(p["w1"]), sizes), 2, axis=-1)
-    ys = expert_gmm(bf(jax.nn.silu(gate) * up), bf(p["w2"]), sizes)
+    ys = expert_gmm(bf(act(gate) * up), bf(p["w2"]), sizes)
     # the local pairs' rows are the first ``sizes.sum()``; the rows
     # behind them were not computed
     out = combine(ys, order, w, sizes.sum())
     if cfg.shared:
-        shared = _gated(t, p["ws1"], p["ws2"])
+        shared = _gated(t, p["ws1"], p["ws2"], act)
         out = out + (shared if cfg.shared_scale == 1.0
                      else shared * cfg.shared_scale)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()]

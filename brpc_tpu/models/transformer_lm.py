@@ -62,7 +62,8 @@ class LMConfig:
                  kda_heads: Optional[int] = None,
                  kda_head_dim: Optional[int] = None, kda_conv: int = 4,
                  passes: int = 1, post_norms: bool = False,
-                 exit_threshold: float = 1.0):
+                 exit_threshold: float = 1.0, router_at: str = "ffn",
+                 router_scoring: str = "sigmoid"):
         assert head_dim is not None or dim % heads == 0
         hd = dim // heads if head_dim is None else int(head_dim)
         assert not rope or hd % 2 == 0, "head dim must be even for RoPE"
@@ -121,7 +122,9 @@ class LMConfig:
         # in spans of this many rows (``make_paged_span_fill``), never
         # as one bucket
         self.fill_span = int(fill_span)
-        assert ffn in ("gelu", "gated_silu")
+        # "gated_silu" / "gated_relu": ``(act(gate) * up) w2``, in the
+        # dense feed-forward and in every expert
+        assert ffn in ("gelu", "gated_silu", "gated_relu")
         self.ffn = ffn
         self.ffn_dim = dim * mlp_mult if ffn_dim is None else int(ffn_dim)
         self.tie_embed = bool(tie_embed)
@@ -187,6 +190,25 @@ class LMConfig:
         # zero that is added), shared experts averaged and not summed
         self.router_bias = bool(router_bias)
         self.shared_average = bool(shared_average)
+        # where an expert layer's router reads and what makes scores of
+        # its logits.  ``router_at`` "ffn": the normed rows the experts
+        # are fed (behind attention); "layer_input": the layer's INPUT,
+        # before its first norm and before attention, so the choice is
+        # made first in the layer and handed past attention
+        # (:func:`_early_route`).  ``router_scoring`` "sigmoid", or
+        # "softmax" over all routed experts before the choice
+        assert router_at in ("ffn", "layer_input")
+        assert router_scoring in ("sigmoid", "softmax")
+        self.router_at, self.router_scoring = router_at, router_scoring
+        if router_at == "layer_input" and (
+                set(self.mixers) != {"attn"} or set(self.ffns) != {"experts"}
+                or parallel_block or not self.has_window):
+            raise UnsupportedBlock(
+                "a router that reads the layer's input (router_at "
+                "'layer_input') is served in the sequential block of a "
+                "window schedule of 'attn' mixers whose every layer has "
+                "experts: the paged step and the span fill hand the "
+                "choice past attention, no other program does")
         if self.has_experts:
             lo, hi = self.experts_held
             assert expert_dim and 0 <= lo < hi <= self.experts_routed \
@@ -336,7 +358,9 @@ class LMConfig:
             top_k=self.experts_top_k, route_scale=self.route_scale,
             shared=self.shared_experts, bias=self.router_bias,
             shared_scale=1.0 / self.shared_experts
-            if self.shared_average else 1.0)
+            if self.shared_average else 1.0,
+            scoring=self.router_scoring,
+            act="relu" if self.ffn == "gated_relu" else "silu")
 
     def moe_cfg(self):
         from .moe import MoEConfig
@@ -454,7 +478,7 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         # the exit gate of a looped schedule (see ``LMConfig``)
         params["exit_w"] = normal(ks[1], (d,), d)
         params["exit_b"] = jnp.zeros((), jnp.float32)
-    gated = cfg.ffn == "gated_silu"
+    gated = cfg.ffn != "gelu"
     for i in range(cfg.depth):
         bk = jax.random.split(ks[2 + i], 5)
         if cfg.mixers[i] in STATE_MIXERS:
@@ -559,8 +583,9 @@ def _split_qkv(cfg: LMConfig, qkv):
 
 def _ffn(cfg: LMConfig, bp, h):
     """The feed-forward part of every serving layer: ``gelu(h w1) w2``;
-    gated, ``(silu(gate) * up) w2`` with gate and up side by side in
-    ``w1``; or the Mixture-of-Experts FFN (models/moe.py)."""
+    gated, ``(act(gate) * up) w2`` with gate and up side by side in
+    ``w1``, ``act`` the SiLU or the ReLU; or the Mixture-of-Experts FFN
+    (models/moe.py)."""
     import jax
     import jax.numpy as jnp
 
@@ -574,9 +599,10 @@ def _ffn(cfg: LMConfig, bp, h):
         out, _aux = forward_grouped(bp["moe"], h, cfg.moe_cfg())
         return out
     up = qmatmul(h, bp["w1"])
-    if cfg.ffn == "gated_silu":
+    if cfg.ffn != "gelu":
         gate, up = jnp.split(up, 2, axis=-1)
-        return qmatmul(jax.nn.silu(gate) * up, bp["w2"])
+        act = jax.nn.silu if cfg.ffn == "gated_silu" else jax.nn.relu
+        return qmatmul(act(gate) * up, bp["w2"])
     return qmatmul(jax.nn.gelu(up), bp["w2"])
 
 
@@ -590,27 +616,48 @@ def _ffn_residual(cfg: LMConfig, bp, x):
     return x + out
 
 
-def _ffn_part(cfg: LMConfig, i: int, bp, h, live):
+def _ffn_part(cfg: LMConfig, i: int, bp, h, live, routed=None):
     """Layer ``i``'s feed-forward by the schedule (``LMConfig.ffns``)
     for normed rows ``h`` ``(b, w, dim)``: :func:`_ffn`, or the expert
-    layer (``moe.serve``) over the rows that are ``live`` ``(b, w)``.
-    Returns ``(out, counts)``, ``counts`` the expert layer's or None."""
+    layer (``moe.serve``) over the rows that are ``live`` ``(b, w)``,
+    with the choice ``routed`` where the layer made it at its top
+    (:func:`_early_route`).  Returns ``(out, counts)``, ``counts`` the
+    expert layer's or None."""
     if cfg.ffns[i] != "experts":
         return _ffn(cfg, bp, h), None
     from . import moe
     b, w, d = h.shape
     out, counts = moe.serve(bp["moe"], h.reshape(b * w, d),
-                            cfg.expert_cfg(), live.reshape(b * w))
+                            cfg.expert_cfg(), live.reshape(b * w), routed)
     return out.reshape(b, w, d), counts
 
 
-def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live):
+def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live, routed=None):
     """Layer ``i``'s second half: norm, :func:`_ffn_part`, residual.
     Returns ``(x, counts)``."""
     if cfg.ffns[i] != "experts":
         return _ffn_residual(cfg, bp, x), None
-    out, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln2"]), live)
+    out, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln2"]), live,
+                            routed)
     return x + out, counts
+
+
+def _early_route(cfg: LMConfig, bp, x):
+    """The choice of a layer's experts where the block's router reads
+    the layer's INPUT (``LMConfig.router_at`` ``"layer_input"``): ``(ids,
+    w)`` (``moe.route``) of the rows ``x`` ``(b, w, dim)`` as they
+    enter the layer, un-normed; made FIRST in the layer and handed past
+    attention to :func:`_attn_out`, so nothing of it waits for, or
+    depends on, the layer's attention.  None where the router reads
+    what the experts read.  ``moe_route`` in a device trace."""
+    if cfg.router_at != "layer_input":
+        return None
+    import jax
+
+    from . import moe
+    with jax.named_scope("moe_route"):
+        return moe.route(bp["moe"], x.reshape(-1, cfg.dim),
+                         cfg.expert_cfg())
 
 
 def _embed_rows(params, ids):
@@ -665,12 +712,14 @@ def _qkv(cfg: LMConfig, bp, x, rot):
     return q, k, v
 
 
-def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None):
+def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None,
+              routed=None):
     """The second half of a serving attention layer ``i``: the attended
     values ``att`` (``(b, w, heads, hd)``, or a step's ``(b, heads,
     hd)`` as its kernel returns them) through ``wo``, residual, then
     the layer's feed-forward (:func:`_ffn_scheduled`; ``live`` ``(b,
-    w)`` are the rows an expert layer routes).  In the parallel block
+    w)`` are the rows an expert layer routes, ``routed`` their choice
+    where :func:`_early_route` made it).  In the parallel block
     the feed-forward reads the norm attention read, and both add to
     the layer's input.  Returns ``(x, counts)``, ``counts`` an expert
     layer's or None."""
@@ -683,7 +732,7 @@ def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None):
         # the same norm :func:`_qkv` took: one in the compiled program
         m, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln1"]), live)
         return x + a + m, counts
-    return _ffn_scheduled(cfg, i, bp, x + a, live)
+    return _ffn_scheduled(cfg, i, bp, x + a, live, routed)
 
 
 def _logits(cfg: LMConfig, params, x):
@@ -1137,6 +1186,10 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     page (one a pass) and the kernel keep their meaning.  The final
     norm closes every pass and the last pass's row is unembedded.
 
+    Where the experts' router reads the layer's input
+    (``LMConfig.router_at`` ``"layer_input"``) an attention layer makes
+    its choice first (:func:`_early_route`) and hands it past attention.
+
     With ``chunk`` set a THIRD program rides along, also named
     ``step``: the step with one catch-up slice on board
     (Sarathi-style, one pass over the weights for both), ``step(params,
@@ -1197,10 +1250,11 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
 
     def attn_layer(i, bp, x, pk, pv, bt, pos, att_pos, rot, active):
         """Attention layer ``i``, one token per slot."""
+        routed = _early_route(cfg, bp, x)
         q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
         att, pk, pv = attend(q, k, v, pk, pv, bt, pos, att_pos,
                              cfg.windows[i])
-        x, cnt = _attn_out(cfg, bp, x, att, i, active[:, None])
+        x, cnt = _attn_out(cfg, bp, x, att, i, active[:, None], routed)
         return x, pk, pv, cnt
 
     def step(params, cache, bt, token, active):
@@ -1496,7 +1550,9 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
     // page + 2`` entries from the page that holds the first position
     its first row reaches; so ``btw_row`` must hold live pages from
     there to the span's last row, and what lies behind may have been
-    given back.  The expert layer routes the ``n`` real rows only.
+    given back.  The expert layer routes the ``n`` real rows only (on
+    the rows as they ENTER the layer where the block's router reads
+    there: :func:`_early_route`, as the step does).
     Identical by construction with as many single steps (scatter before
     gather, the step's mask)."""
     import jax.numpy as jnp
@@ -1528,12 +1584,14 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
             bp, win = params[f"blk{i}"], cfg.windows[i]
             row = btw_row if win else bt_row
             mine = _span_pages(cfg, page, row, start, n)
+            routed = _early_route(cfg, bp, x)
             q, k, v = _qkv(cfg, bp, x, rot if cfg.ropes[i] else None)
             pk = span_attention.write(cache[f"pk{i}"], k[0], mine, n, page)
             pv = span_attention.write(cache[f"pv{i}"], v[0], mine, n, page)
             att = span_attention.attention(q[0], pk, pv, row, start, page,
                                            win)
-            x, _counts = _attn_out(cfg, bp, x, att[None], i, real[None])
+            x, _counts = _attn_out(cfg, bp, x, att[None], i, real[None],
+                                   routed)
             cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
         cache["len"] = cache["len"].at[slot].set(start + n)
         return cache

@@ -136,7 +136,8 @@ def _call(xs, w, sizes, tm: int, block_bytes: int, interpret: bool):
     if tiles_m * tm != m:
         xs = jnp.pad(xs, ((0, tiles_m * tm - m), (0, 0)))
     tk, tn = _blocks(k, n, w.dtype.itemsize, block_bytes)
-    offsets, group, tile, visits = visit_list(sizes, tiles_m, tm)
+    with jax.named_scope("moe_route"):      # ``models/moe.py serve``'s
+        offsets, group, tile, visits = visit_list(sizes, tiles_m, tm)
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(

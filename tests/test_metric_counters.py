@@ -71,6 +71,15 @@ def _window_block():
     return T.LMConfig(remat=False, **m.lm_kwargs(cfg)), params, PAGE
 
 
+def _early_routed_block():
+    from test_early_routed_experts import TOY
+    from test_window_experts import PAGE, _bench
+    cfg, m = _bench(TOY)
+    params = jax.tree_util.tree_map(lambda a: a.astype(jnp.float32),
+                                    m.make_params(cfg, 3))
+    return T.LMConfig(remat=False, **m.lm_kwargs(cfg)), params, PAGE
+
+
 # the toy whose ``kv_stats()`` has a section: the first block's, unless
 # the section is another block's own.  (Every section the registered
 # files name on this tree is reached by one of the three; a file whose
@@ -100,11 +109,9 @@ CASES = _registered()
 assert CASES, "BENCHMARK.json registers no counter metric with a kv path"
 
 
-@pytest.mark.parametrize("metric,paths", CASES,
-                         ids=[name for name, _ in CASES])
-def test_a_registered_metrics_kv_paths_end_at_counters(metric, paths):
+def _walk(metric, paths, toy_of):
     for path in paths:
-        snap = {"kv": _kv_stats(TOY_OF_SECTION.get(path[1], _first_block))}
+        snap = {"kv": _kv_stats(toy_of(path[1]))}
         for i, key in enumerate(path):
             assert isinstance(snap, dict) and key in snap, \
                 (metric, path, f"no {key!r} at {path[:i]}")
@@ -114,3 +121,22 @@ def test_a_registered_metrics_kv_paths_end_at_counters(metric, paths):
                 (metric, path, snap)
         else:
             assert _is_count(snap), (metric, path, snap)
+
+
+@pytest.mark.parametrize("metric,paths", CASES,
+                         ids=[name for name, _ in CASES])
+def test_a_registered_metrics_kv_paths_end_at_counters(metric, paths):
+    _walk(metric, paths, lambda sec: TOY_OF_SECTION.get(sec, _first_block))
+
+
+# the cell ``smallthinker-21b.longdoc`` reads every ``*.longdoc_*``
+# metric: each of their paths, on a toy of ITS block (a router at the
+# layer's input over two page classes), whatever the section
+LONGDOC = [c for c in CASES if ".longdoc_" in c[0]]
+assert LONGDOC
+
+
+@pytest.mark.parametrize("metric,paths", LONGDOC,
+                         ids=[name for name, _ in LONGDOC])
+def test_a_longdoc_metrics_kv_paths_on_the_early_routed_block(metric, paths):
+    _walk(metric, paths, lambda _sec: _early_routed_block)

@@ -315,6 +315,88 @@ def test_span_kernel_over_a_window_compiles_for_the_v5e(one_chip):
     assert "bf16[8,5632,128]" in text
 
 
+@pytest.mark.parametrize("window,pages", [(4096, 8321), (0, 15361)])
+def test_window_kernel_at_a_group_of_seven_compiles_for_the_v5e(
+        window, pages, one_chip):
+    """Mosaic takes the window schedule's decode kernel at
+    ``smallthinker-21b.longdoc``'s shapes (32 slots of 28 query heads on
+    4 key/value heads of 128: 7-row slices of the queries, ``(7, 1)`` and
+    ``(7, 128)`` accumulators, no whole sublane tile; an 816-wide table
+    over either page class's pool) under the two names a device trace
+    is read by; the pools go in as they lie, and nothing is run."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((pages, PAGE * 4, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda *a: pa.window_decode_attention(*a, PAGE, window,
+                                              interpret=False)
+    ).lower(arg((32, 28, 128), jnp.float32), pool, pool,
+            arg((32, 816), jnp.int32), arg((32,), jnp.int32)).compile()
+    name = "window_decode_attention" if window else "paged_decode_attention"
+    calls = re.findall(rf"%({name}[.\w]*) = (\S+) custom-call",
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0][1].startswith("f32[32,28,128]"), calls
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("window,pages,entries", [(4096, 8321, 322),
+                                                  (0, 15361, 816)])
+def test_span_kernel_at_a_group_of_seven_compiles_for_the_v5e(
+        window, pages, entries, one_chip):
+    """``span_flash_attention`` at ``smallthinker-21b.longdoc``'s shapes
+    (a span of 1,024 rows of 28 query heads on 4 key/value heads: 7,168
+    rows a key/value head, so the keys are gathered once and walked in
+    sixteen query blocks of 64 tokens = 448 rows), a window layer's 322
+    reachable entries and a global layer's whole table; nothing is
+    run."""
+    from brpc_tpu.ops import span_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert not span_attention.in_place(1024, 7)
+    assert span_attention.table_reach(816, 1024, PAGE, window) == entries
+    pool = arg((pages, PAGE * 4, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda q, pk, pv, ids, q0, k0: span_attention.span_flash_attention(
+            q, pk, pv, ids, q0, k0, PAGE, window, interpret=False)
+    ).lower(arg((1024, 28, 128), jnp.float32), pool, pool,
+            arg((entries,), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32)).compile()
+    calls = re.findall(r"%(span_flash_attention[.\w]*) = (\S+) custom-call",
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0][1].startswith("f32[4,7168,128]"), \
+        calls
+
+
+@pytest.mark.parametrize("rows", [192, 6144])
+@pytest.mark.parametrize("k,n", [(2560, 1536), (768, 2560)])
+def test_expert_gmm_over_64_small_experts_compiles_for_the_v5e(
+        rows, k, n, one_chip):
+    """The grouped product at ``smallthinker-21b``'s shapes: 64 groups,
+    none absent, the step's ``32 x 6`` rows and a span's ``1,024 x 6``;
+    an expert's ``w1`` (7.9 MB) goes by in two column blocks, its ``w2``
+    (3.9 MB) whole."""
+    from brpc_tpu.ops import expert_gmm as eg
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert eg._blocks(k, n, 2, eg._BLOCK_BYTES) == (
+        (2560, 768) if k == 2560 else (768, 2560))
+    compiled = jax.jit(
+        lambda *a: eg.expert_gmm(*a, interpret=False)
+    ).lower(arg((rows, k), jnp.bfloat16), arg((64, k, n), jnp.bfloat16),
+            arg((64,), jnp.int32)).compile()
+    # (the step's 192 rows are two 128-row tiles: the call writes 256)
+    padded = -(-rows // 128) * 128
+    assert re.search(
+        rf"%expert_gmm[.\d]* = f32\[{padded},{n}\]\S* custom-call",
+        compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 # -- the counter ------------------------------------------------------------
 
 class _Stream:

@@ -282,9 +282,15 @@ def test_a_span_writes_its_own_rows_and_nothing_else(model, f32_matmuls,
 def test_window_decode_kernel(kvh, window, pos):
     """16 query heads on each key/value head; the walk starts at the
     window's first page and masks its head.  Operands bfloat16."""
+    check_window_decode_kernel(kvh, window, pos, 16)
+
+
+def check_window_decode_kernel(kvh, window, pos, g):
+    """(``g`` query heads a key/value head:
+    ``tests/test_early_routed_experts.py`` runs these cases at 7.)"""
     r = np.random.default_rng(kvh)
     slots, hd, pps, pages = len(pos), 32, 16, 40
-    q = jnp.asarray(r.normal(size=(slots, 16 * kvh, hd)), jnp.float32)
+    q = jnp.asarray(r.normal(size=(slots, g * kvh, hd)), jnp.float32)
     pk, pv = (jnp.asarray(r.normal(size=(pages, PAGE * kvh, hd)),
                           jnp.float32) for _ in range(2))
     bt = np.asarray(1 + r.integers(0, pages - 1, (slots, pps)), np.int32)
@@ -312,8 +318,12 @@ def test_window_decode_kernel(kvh, window, pos):
 def test_span_flash_kernel(window, start, w, keys_from, paged):
     """Both forms of the kernel: the keys read where they lie, and
     gathered once."""
+    check_span_flash_kernel(window, start, w, keys_from, paged, 4)
+
+
+def check_span_flash_kernel(window, start, w, keys_from, paged, g):
     r = np.random.default_rng(start + window)
-    kvh, g, hd, pages = 2, 4, 32, 40
+    kvh, hd, pages = 2, 32, 40
     q = jnp.asarray(r.normal(size=(w, kvh * g, hd)), jnp.float32)
     pk, pv = (jnp.asarray(r.normal(size=(pages, PAGE * kvh, hd)),
                           jnp.float32) for _ in range(2))
@@ -576,47 +586,54 @@ def _lm(**kw):
     return T.LMConfig(**{"remat": False, **m.lm_kwargs(cfg), **kw})
 
 
-def _batcher(**kw):
-    from brpc_tpu.models.lm_service import ContinuousBatcher
-    return ContinuousBatcher(_lm(), {}, **{"page": PAGE, **kw})
+def declines(_lm) -> dict:
+    """The paths a window schedule declines, by name, for the block
+    ``_lm(**kw)`` makes (``tests/test_early_routed_experts.py`` runs
+    them for its own)."""
+    def _batcher(**kw):
+        from brpc_tpu.models.lm_service import ContinuousBatcher
+        return ContinuousBatcher(_lm(), {}, **{"page": PAGE, **kw})
+
+    def _generate_declines():
+        from brpc_tpu.client.controller import Controller
+        from brpc_tpu.models.lm_service import (LMService,
+                                                pack_generate_request)
+        lm = _lm()
+        svc = LMService(cfg=lm,
+                        params=T.init_params(jax.random.PRNGKey(1), lm))
+        cntl = Controller()
+        assert svc.Generate(cntl, pack_generate_request(
+            np.zeros((1, 4), np.int32), 2)) is None
+        raise T.UnsupportedBlock(cntl.error_text)
+
+    return {
+        "training": lambda: T.make_forward(_lm()),
+        "contiguous_decode": lambda: T.make_decode(_lm()),
+        "whole_prompt_prefill": lambda: T.make_prefill(_lm())(),
+        "insert": lambda: T.make_paged_io(_lm(), PAGE)[2]({}, None, None, 0),
+        "kv_export_specs": lambda: T.kv_page_specs(_lm()),
+        "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
+                                             _lm(scan_layers=True)),
+        "host_spill": lambda: T.make_paged_io(_lm(), PAGE)[0]({}, None),
+        "host_resume": lambda: T.make_paged_io(_lm(), PAGE)[1]({}, None, None),
+        "catch_up": lambda: T.make_paged_io(_lm(), PAGE, chunk=8)[3](),
+        "riding_step": lambda: T.make_paged_batch_decode(
+            _lm(), PAGE, chunk=8)[2](),
+        "batcher_park": lambda: _batcher(host_slots=4),
+        "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
+        "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),
+        "generate": _generate_declines,
+        # a span fill is a window schedule's alone
+        "span_fill_of_another_block": lambda: T.make_paged_span_fill(
+            T.LMConfig(depth=2, remat=False), PAGE)(),
+        # window layers beside another mixer, or over ungrouped heads
+        "windows_beside_ssm": lambda: T.LMConfig(
+            depth=2, mixers=("attn", "ssm"), windows=(8, 0), kv_heads=1),
+        "windows_over_whole_heads": lambda: T.LMConfig(depth=2, windows=(8, 0)),
+    }
 
 
-def _generate_declines():
-    from brpc_tpu.client.controller import Controller
-    from brpc_tpu.models.lm_service import LMService, pack_generate_request
-    lm = _lm()
-    svc = LMService(cfg=lm, params=T.init_params(jax.random.PRNGKey(1), lm))
-    cntl = Controller()
-    assert svc.Generate(cntl, pack_generate_request(
-        np.zeros((1, 4), np.int32), 2)) is None
-    raise T.UnsupportedBlock(cntl.error_text)
-
-
-DECLINES = {
-    "training": lambda: T.make_forward(_lm()),
-    "contiguous_decode": lambda: T.make_decode(_lm()),
-    "whole_prompt_prefill": lambda: T.make_prefill(_lm())(),
-    "insert": lambda: T.make_paged_io(_lm(), PAGE)[2]({}, None, None, 0),
-    "kv_export_specs": lambda: T.kv_page_specs(_lm()),
-    "scan_layers": lambda: T.init_params(jax.random.PRNGKey(0),
-                                         _lm(scan_layers=True)),
-    "host_spill": lambda: T.make_paged_io(_lm(), PAGE)[0]({}, None),
-    "host_resume": lambda: T.make_paged_io(_lm(), PAGE)[1]({}, None, None),
-    "catch_up": lambda: T.make_paged_io(_lm(), PAGE, chunk=8)[3](),
-    "riding_step": lambda: T.make_paged_batch_decode(
-        _lm(), PAGE, chunk=8)[2](),
-    "batcher_park": lambda: _batcher(host_slots=4),
-    "batcher_chunked": lambda: _batcher(prefill_chunk_tokens=16),
-    "kv_import": lambda: _batcher().join_imported(None, 0, 4, 2, {}),
-    "generate": _generate_declines,
-    # a span fill is a window schedule's alone
-    "span_fill_of_another_block": lambda: T.make_paged_span_fill(
-        T.LMConfig(depth=2, remat=False), PAGE)(),
-    # window layers beside another mixer, or over ungrouped heads
-    "windows_beside_ssm": lambda: T.LMConfig(
-        depth=2, mixers=("attn", "ssm"), windows=(8, 0), kv_heads=1),
-    "windows_over_whole_heads": lambda: T.LMConfig(depth=2, windows=(8, 0)),
-}
+DECLINES = declines(_lm)
 
 
 @pytest.mark.parametrize("path", sorted(DECLINES))
