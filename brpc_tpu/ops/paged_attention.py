@@ -355,8 +355,9 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
 # head, so a window layer reads ``window // page + 1`` pages however
 # long the context is (entries of the block table behind the window may
 # have been given back: they are never read).  One grid step a slot,
-# the first wave of the next in flight while this one's last is
-# computed (two buffers); a group's ``(g, hd)`` queries meet a
+# the slots' waves one sequence through a ring of buffers, the copies
+# of the next ``ring - 1`` waves in flight while one is computed, into
+# the next slots where this one ends; a group's ``(g, hd)`` queries meet a
 # key/value head's ``(tokens, hd)`` rows on the MXU in bfloat16 with
 # float32 accumulation (in float32 at ``highest``, as
 # :func:`_grouped_kernel`, sixteen queries a head would cost six passes
@@ -364,10 +365,28 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
 # ``window_decode_attention`` in a device trace, a global layer's of
 # the same schedule ``paged_decode_attention``.
 
+# waves the window kernel keeps in VMEM: the one it computes and five
+# whose pages are on their way (6 x 512 KB x 2 pools).  Measured on the
+# v5e at 2 to 8 (``chip_window.py``; PERF.md §6, PR 44): five or six
+# reach what the copies reach alone at both cells' pages; at 32-KB
+# pages four fall 3% short (a window's 257th page is a wave of its own,
+# whose instructions take what sixteen pages' do) and eight 6% (224
+# copies in flight); at 64-KB pages four to eight read the same
+_WINDOW_RING = 6
+
 
 def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
                    kbuf, vbuf, sems, g_ref, *, page: int, kv_heads: int,
                    wave: int, window: int):
+    """One grid step a slot; the slots' waves are ONE sequence through
+    the ring, as in :func:`_latent_kernel` (whose comment says why each
+    point pays): the ring, its semaphores and the count of waves so far
+    live across grid steps; a wave waits for its pages, computes, and
+    only THEN starts the copies of the wave ``ring - 1`` after it, in
+    this slot or the next ones, in the one block the wave is (a copy
+    with no page to fetch is a predicated instruction), so the copies'
+    scalar work runs beside the products' vector work.  A key page and
+    its value page signal one semaphore, the buffer's."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -377,6 +396,7 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     slots = pl.num_programs(0)
     heads, hd = q_ref.shape[1], q_ref.shape[2]
     g = heads // kv_heads
+    ring = kbuf.shape[0]
     toks = wave * page
     rows = page * kv_heads
     bf = jnp.bfloat16
@@ -385,22 +405,36 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
         """The page of the first position slot ``s`` attends."""
         if not window:
             return 0
-        return jnp.maximum(pos_ref[s] - window + 1, 0) // page
+        return lax.div(jnp.maximum(pos_ref[s] - window + 1, 0), page)
 
     def n_pages(s):
-        return pos_ref[s] // page + 1 - first_page(s)
+        return lax.div(pos_ref[s], page) + 1 - first_page(s)
+
+    def n_waves(s):
+        return lax.div(n_pages(s) + wave - 1, wave)
+
+    def next_wave(s, w):
+        """The wave after wave ``w`` of slot ``s``; slot ``slots`` and
+        beyond: none."""
+        more = w + 1 < n_waves(jnp.minimum(s, slots - 1))
+        return jnp.where(more, s, s + 1), jnp.where(more, w + 1, 0)
 
     def wave_dma(s, w, buf, go):
+        """``go`` on the copies of every live page of slot ``s``'s wave
+        ``w``, from the window's first page on; none where ``s`` is
+        past the last slot."""
+        at = jnp.minimum(s, slots - 1)
+        first = first_page(at) + w * wave
+        live = jnp.where(s < slots, n_pages(at) - w * wave, 0)
         for i in range(wave):
-            idx = w * wave + i
 
-            @pl.when(idx < n_pages(s))
+            @pl.when(i < live)
             def _():
-                pid = bt_ref[s, first_page(s) + idx]
+                pid = bt_ref[at, first + i]
                 dst = pl.ds(i * rows, rows)
-                for hbm, vmem, j in ((pk_hbm, kbuf, 0), (pv_hbm, vbuf, 1)):
+                for hbm, vmem in ((pk_hbm, kbuf), (pv_hbm, vbuf)):
                     go(pltpu.make_async_copy(
-                        hbm.at[pid], vmem.at[buf, dst], sems.at[j, buf]))
+                        hbm.at[pid], vmem.at[buf, dst], sems.at[buf]))
 
     start = functools.partial(wave_dma, go=lambda c: c.start())
     wait = functools.partial(wave_dma, go=lambda c: c.wait())
@@ -408,11 +442,13 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     @pl.when(b == 0)
     def _():
         g_ref[0] = 0
-        start(0, 0, 0)
+        s, w = jnp.int32(0), jnp.int32(0)
+        for buf in range(ring - 1):
+            start(s, w, buf)
+            s, w = next_wave(s, w)
 
     tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
     tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
-    n_waves = (n_pages(b) + wave - 1) // wave
     pos = pos_ref[b]
     base = first_page(b) * page
     q = (q_ref[0] * (1.0 / hd ** 0.5)).astype(bf)         # (heads, hd)
@@ -421,16 +457,7 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     def wave_body(w, carry):
         m, l, acc = carry
         gcount = g_ref[0]
-        buf = lax.rem(gcount, 2)
-
-        @pl.when(w + 1 < n_waves)
-        def _():
-            start(b, w + 1, 1 - buf)
-
-        @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
-        def _():
-            start(b + 1, 0, 1 - buf)
-
+        buf = lax.rem(gcount, ring)
         wait(b, w, buf)
         # this wave's tokens lie at t0 + 0..toks-1; those past pos are
         # stale or were never fetched, those at or behind pos - window
@@ -460,13 +487,18 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
             ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
             accs.append(acc[kh] * corr + jnp.dot(
                 p.astype(bf), v, preferred_element_type=jnp.float32))
+        # into the buffer the wave before this one was read from
+        ahead = (b, w)
+        for _ in range(ring - 1):
+            ahead = next_wave(*ahead)
+        start(*ahead, lax.rem(gcount + ring - 1, ring))
         g_ref[0] = gcount + 1
         return ms, ls, accs
 
     init = ([jnp.full((g, 1), -1e30, jnp.float32)] * kv_heads,
             [jnp.zeros((g, 1), jnp.float32)] * kv_heads,
             [jnp.zeros((g, hd), jnp.float32)] * kv_heads)
-    _m, l, acc = lax.fori_loop(0, n_waves, wave_body, init)
+    _m, l, acc = lax.fori_loop(0, n_waves(b), wave_body, init)
     for kh in range(kv_heads):
         o_ref[0, kh * g:(kh + 1) * g, :] = acc[kh] / l[kh]
 
@@ -493,9 +525,11 @@ def _window_call(q, pk, pv, bt, pos, page: int, window: int,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=mine,
             scratch_shapes=[
-                pltpu.VMEM((2, wave * page * kv_heads, hd), pk.dtype),
-                pltpu.VMEM((2, wave * page * kv_heads, hd), pv.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((_WINDOW_RING, wave * page * kv_heads, hd),
+                           pk.dtype),
+                pltpu.VMEM((_WINDOW_RING, wave * page * kv_heads, hd),
+                           pv.dtype),
+                pltpu.SemaphoreType.DMA((_WINDOW_RING,)),
                 pltpu.SMEM((1,), jnp.int32),            # waves so far
             ]),
         out_shape=jax.ShapeDtypeStruct((slots, heads, hd), jnp.float32),

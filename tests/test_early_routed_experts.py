@@ -23,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from test_window_experts import (PAGE, _bench, _gaps, _Paged,  # noqa: F401
+from test_window_experts import (PAGE, WINDOW_DECODE_CASES,  # noqa: F401
+                                 _bench, _gaps, _Paged,
                                  check_span_flash_kernel,
                                  check_window_decode_kernel, declines,
                                  f32_matmuls)
@@ -223,14 +224,12 @@ def test_softmax_router_against_hand_arithmetic():
 
 # -- (c) the kernels at a group of seven, interpreted ------------------------------
 
-@pytest.mark.parametrize("kvh,window,pos", [
-    (2, 40, [0, 15, 16, 39, 40, 41, 100, 255]),
-    (2, 0, [0, 15, 16, 39, 40, 41, 100, 255]),
-    (4, 48, [3, 47, 48, 49, 200, 254, 31, 32])])
-def test_window_decode_kernel_at_a_group_of_seven(kvh, window, pos):
+@pytest.mark.parametrize("name", [n for n in WINDOW_DECODE_CASES
+                                  if n != "kvh1_w24"])
+def test_window_decode_kernel_at_a_group_of_seven(name, monkeypatch):
     """7-row slices of the queries, ``(7, 1)`` and ``(7, hd)``
     accumulators: against ``paged_attention.reference``."""
-    check_window_decode_kernel(kvh, window, pos, 7)
+    check_window_decode_kernel(*WINDOW_DECODE_CASES[name], 7, monkeypatch)
 
 
 @pytest.mark.parametrize("paged", [True, False])

@@ -315,29 +315,48 @@ def test_span_kernel_over_a_window_compiles_for_the_v5e(one_chip):
     assert "bf16[8,5632,128]" in text
 
 
-@pytest.mark.parametrize("window,pages", [(4096, 8321), (0, 15361)])
-def test_window_kernel_at_a_group_of_seven_compiles_for_the_v5e(
-        window, pages, one_chip):
-    """Mosaic takes the window schedule's decode kernel at
-    ``smallthinker-21b.longdoc``'s shapes (32 slots of 28 query heads on
-    4 key/value heads of 128: 7-row slices of the queries, ``(7, 1)`` and
-    ``(7, 128)`` accumulators, no whole sublane tile; an 816-wide table
-    over either page class's pool) under the two names a device trace
-    is read by; the pools go in as they lie, and nothing is run."""
+def _window_kernel_compiles(one_chip, slots, heads, kvh, window, pages):
+    """The window schedule's decode kernel through Mosaic at a cell's
+    shapes (an 816-wide table over either page class's pool): ONE
+    custom call under the name a device trace is read by, the pools in
+    as they lie (no temporary, no array of ``max_seq`` keys a slot),
+    and nothing is run."""
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = arg((pages, PAGE * 4, 128), jnp.float32)
-    compiled = jax.jit(
+    pool = arg((pages, PAGE * kvh, 128), jnp.float32)
+    lowered = jax.jit(
         lambda *a: pa.window_decode_attention(*a, PAGE, window,
                                               interpret=False)
-    ).lower(arg((32, 28, 128), jnp.float32), pool, pool,
-            arg((32, 816), jnp.int32), arg((32,), jnp.int32)).compile()
+    ).lower(arg((slots, heads, 128), jnp.float32), pool, pool,
+            arg((slots, 816), jnp.int32), arg((slots,), jnp.int32))
+    assert slots * 816 * PAGE * kvh * 128 not in _array_sizes(
+        lowered.as_text())
+    compiled = lowered.compile()
     name = "window_decode_attention" if window else "paged_decode_attention"
     calls = re.findall(rf"%({name}[.\w]*) = (\S+) custom-call",
                        compiled.as_text())
-    assert len(calls) == 1 and calls[0][1].startswith("f32[32,28,128]"), calls
+    assert len(calls) == 1 and calls[0][1].startswith(
+        f"f32[{slots},{heads},128]"), calls
     assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("window,pages", [(4096, 8321), (0, 15361)])
+def test_window_kernel_at_a_group_of_seven_compiles_for_the_v5e(
+        window, pages, one_chip):
+    """``smallthinker-21b.longdoc``'s shapes: 32 slots of 28 query heads
+    on 4 key/value heads of 128 (7-row slices of the queries, ``(7, 1)``
+    and ``(7, 128)`` accumulators, no whole sublane tile), pages of 32
+    KB a pool, 16 a wave."""
+    _window_kernel_compiles(one_chip, 32, 28, 4, window, pages)
+
+
+@pytest.mark.parametrize("window,pages", [(4096, 4193), (0, 9601)])
+def test_window_kernel_at_a_group_of_sixteen_compiles_for_the_v5e(
+        window, pages, one_chip):
+    """``command-a-plus.longdoc``'s shapes: 16 slots of 128 query heads
+    on 8 key/value heads of 128, pages of 64 KB a pool, 8 a wave."""
+    _window_kernel_compiles(one_chip, 16, 128, 8, window, pages)
 
 
 @pytest.mark.parametrize("window,pages,entries", [(4096, 8321, 322),

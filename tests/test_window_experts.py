@@ -274,18 +274,60 @@ def test_a_span_writes_its_own_rows_and_nothing_else(model, f32_matmuls,
 
 # -- (c) the kernels, interpreted ----------------------------------------------
 
-@pytest.mark.parametrize("kvh,window,pos", [
-    (2, 40, [0, 15, 16, 39, 40, 41, 100, 255]),
-    (2, 0, [0, 15, 16, 39, 40, 41, 100, 255]),
-    (4, 48, [3, 47, 48, 49, 200, 254, 31, 32]),
-    (1, 24, [0, 23, 24, 25, 64, 65, 128, 250])])
-def test_window_decode_kernel(kvh, window, pos):
+# ``(kvh, window, wave_pages, pos)``.  The first four are a wave a slot
+# (16 pages of 2-4 KB fit one); the others shrink the wave, as
+# ``tests/test_paged_attention.py``'s ``wave_pages`` cases do, so that
+# the ring of ``paged_attention._WINDOW_RING`` (6) buffers turns: the
+# copies started five waves ahead cross slots' ends, windows' first
+# pages and the last slot's end
+WINDOW_DECODE_CASES = {
+    "kvh2_w40": (2, 40, None, [0, 15, 16, 39, 40, 41, 100, 255]),
+    "kvh2_global": (2, 0, None, [0, 15, 16, 39, 40, 41, 100, 255]),
+    "kvh4_w48": (4, 48, None, [3, 47, 48, 49, 200, 254, 31, 32]),
+    "kvh1_w24": (1, 24, None, [0, 23, 24, 25, 64, 65, 128, 250]),
+    # waves of 2 pages: slots of 1, 2, ring - 1, ring and ring + 1
+    # waves following each other, a last slot shorter than the ring
+    "global_around_the_ring": (2, 0, 2, [20, 40, 150, 180, 210, 5]),
+    "global_around_the_ring_falling": (2, 0, 2, [210, 180, 150, 40, 20]),
+    # fourteen slots of one wave: the ring wraps twice across slots
+    "global_one_wave_slots": (1, 0, 2, [0, 31, 16, 3, 30, 1, 17, 2, 29, 15,
+                                        4, 18, 28, 0]),
+    # sixteen waves in a slot, then one
+    "global_one_page_waves": (4, 0, 1, [0, 255, 15, 16, 100]),
+    # a window of 88 over waves of one page: 1, 2, 5, 6, 6 and 7 waves,
+    # the last two from a first page behind which the table reads 0
+    "window_around_the_ring": (2, 88, 1, [5, 20, 70, 80, 200, 208, 3]),
+    # the same slots without the window: 1, 2, 5, 6, 13, 14, 1 waves
+    "global_beside_that_window": (2, 0, 1, [5, 20, 70, 80, 200, 208, 3]),
+    # a window that starts mid-page (position 61: row 13 of page 3)
+    # after a slot with no window page behind it, and the other way
+    "window_mid_page_after_none_behind": (2, 40, 1,
+                                          [10, 100, 7, 255, 39, 40]),
+    "kvh4_w48_two_page_waves": (4, 48, 2, [3, 47, 48, 49, 200, 254, 31, 32]),
+}
+
+
+def test_the_shrunk_waves_turn_the_ring():
+    """The cases named for it hold slots of 1, 2, ring - 1, ring and
+    ring + 1 waves: a deeper or shallower ring needs other cases."""
+    ring = paged_attention._WINDOW_RING
+    for name in ("global_around_the_ring", "global_around_the_ring_falling",
+                 "window_around_the_ring"):
+        _kvh, window, wave_pages, pos = WINDOW_DECODE_CASES[name]
+        first = [max(0, p - window + 1) // PAGE if window else 0 for p in pos]
+        waves = {-(-(p // PAGE + 1 - f) // wave_pages)
+                 for p, f in zip(pos, first)}
+        assert {1, 2, ring - 1, ring, ring + 1} <= waves, (name, waves)
+
+
+@pytest.mark.parametrize("name", list(WINDOW_DECODE_CASES))
+def test_window_decode_kernel(name, monkeypatch):
     """16 query heads on each key/value head; the walk starts at the
     window's first page and masks its head.  Operands bfloat16."""
-    check_window_decode_kernel(kvh, window, pos, 16)
+    check_window_decode_kernel(*WINDOW_DECODE_CASES[name], 16, monkeypatch)
 
 
-def check_window_decode_kernel(kvh, window, pos, g):
+def check_window_decode_kernel(kvh, window, wave_pages, pos, g, monkeypatch):
     """(``g`` query heads a key/value head:
     ``tests/test_early_routed_experts.py`` runs these cases at 7.)"""
     r = np.random.default_rng(kvh)
@@ -294,16 +336,29 @@ def check_window_decode_kernel(kvh, window, pos, g):
     pk, pv = (jnp.asarray(r.normal(size=(pages, PAGE * kvh, hd)),
                           jnp.float32) for _ in range(2))
     bt = np.asarray(1 + r.integers(0, pages - 1, (slots, pps)), np.int32)
-    # what lies behind the window has been given back: the kernel must
-    # not need those entries, and page 0 holds NaN here
+    # what lies behind the window has been given back and what lies
+    # past the last live page was never taken: the kernel must not need
+    # those entries (a copy started waves ahead least of all), and page
+    # 0 holds NaN here
     pk, pv = pk.at[0].set(jnp.nan), pv.at[0].set(jnp.nan)
-    if window:
-        for s, p in enumerate(pos):
+    for s, p in enumerate(pos):
+        if window:
             bt[s, :max(0, p - window + 1) // PAGE] = 0
-            bt[s, p // PAGE + 1:] = 0
+        bt[s, p // PAGE + 1:] = 0
     pos = jnp.asarray(pos, jnp.int32)
-    got = paged_attention.window_decode_attention(
-        q, pk, pv, jnp.asarray(bt), pos, PAGE, window, interpret=True)
+    kernel = paged_attention.window_decode_attention
+    if wave_pages:
+        monkeypatch.setattr(paged_attention, "_WAVE_BYTES",
+                            wave_pages * PAGE * kvh * hd * 4)
+        assert paged_attention.pages_per_wave(PAGE, kvh, hd, pps) \
+            == wave_pages
+
+        # traced here and now: a cached trace keeps the wave it was
+        # traced with
+        kernel = jax.jit(paged_attention._window_call.__wrapped__,
+                         static_argnames=("page", "window", "interpret"))
+    got = kernel(q, pk, pv, jnp.asarray(bt), pos, page=PAGE, window=window,
+                 interpret=True)
     clean = lambda p: jnp.nan_to_num(p)                   # noqa: E731
     want = paged_attention.reference(q, clean(pk), clean(pv),
                                      jnp.asarray(bt), pos, PAGE, window)
