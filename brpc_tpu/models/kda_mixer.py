@@ -142,10 +142,7 @@ def prefill(cfg, bp, x, ctx_len):
     state_shape, ring_shape = state_shapes(cfg, 1)
     qkv = qmatmul(x, bp["wqkv"])
     pad = jnp.pad(qkv, ((0, 0), (kc - 1, 0), (0, 0)))
-    tail = jax.lax.dynamic_slice(pad, (0, ctx_len, 0),
-                                 (1, kc - 1, qkv.shape[-1]))
-    # ``tail[j]`` is position ``ctx_len - (kc - 1) + j``
-    ring = jnp.roll(tail, ctx_len % (kc - 1), axis=1)
+    ring = ring_of(pad, ctx_len, kc - 1)
     qkv = jax.nn.silu(sum(bp["conv_w"][j] * pad[:, j:j + s]
                           for j in range(kc)))
     q, k, v, a, b = _recurrence_inputs(
@@ -162,7 +159,6 @@ def step(cfg, bp, x, state, ring, active, lens):
     step is -> ``(out (slots, dim), state, ring)`` with the state of
     ``active`` slots advanced one position."""
     import jax
-    import jax.numpy as jnp
 
     from ..ops import delta_rule
     from ..ops.quant import qmatmul
@@ -171,7 +167,39 @@ def step(cfg, bp, x, state, ring, active, lens):
     row = qmatmul(x, bp["wqkv"]).reshape(ring.shape[:1] + ring.shape[2:])
     taps = bp["conv_w"].reshape((n + 1,) + ring.shape[2:])
     phase = lens % n
-    turned = [(phase == p)[:, None, None] for p in range(n)]
+    qkv = jax.nn.silu(ring_conv(ring, row, taps, phase))
+    q, k, v, a, b = _recurrence_inputs(cfg, bp, x, qkv)
+    y, state = delta_rule.step(q, k, v, a, b, state, active)
+    ring = ring_write(ring, row, active, phase)
+    return _out(cfg, bp, x, y), state, ring
+
+
+# -- the convolutions' ring (the Mamba-2 mixer's too) -------------------------
+
+def ring_of(pad, ctx_len, n: int):
+    """The ring a prefill leaves: ``pad (1, n + s, ...)``, a bucket's
+    inputs behind ``n`` zero rows -> ``(1, n, ...)``, the inputs of
+    positions ``ctx_len - n .. ctx_len - 1`` (zeros before the
+    sequence's start), position ``t``'s in row ``t mod n``."""
+    import jax
+    import jax.numpy as jnp
+
+    tail = jax.lax.dynamic_slice(pad, (0, ctx_len) + (0,) * (pad.ndim - 2),
+                                 (1, n) + pad.shape[2:])
+    # ``tail[j]`` is position ``ctx_len - n + j``
+    return jnp.roll(tail, ctx_len % n, axis=1)
+
+
+def ring_conv(ring, row, taps, phase):
+    """One position of a depthwise causal convolution for each slot:
+    ``ring (slots, n, ...)`` the last ``n`` inputs, ``row (slots, ...)``
+    this position's, ``taps (n + 1, ...)`` oldest first, ``phase`` the
+    slots' lengths modulo ``n`` -> the taps' sum."""
+    import jax.numpy as jnp
+
+    n = ring.shape[1]
+    turned = [(phase == p)[(slice(None),) + (None,) * (row.ndim - 1)]
+              for p in range(n)]
 
     def past(j):
         # the input of position ``lens - n + j``, in row ``(phase + j)
@@ -181,13 +209,17 @@ def step(cfg, bp, x, state, ring, active, lens):
             rows = jnp.where(turned[p], ring[:, (p + j) % n], rows)
         return rows
 
-    qkv = jax.nn.silu(sum(taps[j] * past(j) for j in range(n))
-                      + taps[n] * row)
-    q, k, v, a, b = _recurrence_inputs(cfg, bp, x, qkv)
-    y, state = delta_rule.step(q, k, v, a, b, state, active)
-    # the new row over the oldest, in place; an idle slot's place is
-    # past its ring, so its row is dropped
-    ring = ring.at[jnp.arange(ring.shape[0]), jnp.where(active, phase, n)
+    return sum(taps[j] * past(j) for j in range(n)) + taps[n] * row
+
+
+def ring_write(ring, row, active, phase):
+    """The ring with each ACTIVE slot's ``row`` over its oldest (row
+    ``phase``), in place; an idle slot's place is past its ring, so its
+    row is dropped."""
+    import jax.numpy as jnp
+
+    n = ring.shape[1]
+    return ring.at[jnp.arange(ring.shape[0]),
+                   jnp.where(active, phase, n)
                    ].set(row, mode="drop", unique_indices=True,
                          indices_are_sorted=True)
-    return _out(cfg, bp, x, y), state, ring

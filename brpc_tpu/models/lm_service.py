@@ -228,14 +228,19 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     length beside the bucket: a causal attention forgives the zero
     padding, a recurrent state does not."""
     ctx = prompt[:-1]
-    bucket = 1
-    while bucket < max(len(ctx), 1):
-        bucket <<= 1
-    bucket = min(bucket, cfg.max_seq)
-    padded = np.zeros((bucket,), np.int32)
+    padded = np.zeros((_bucket(cfg, len(ctx)),), np.int32)
     padded[:len(ctx)] = ctx
     cache1, _logits = prefill_j(padded[None, :], np.int32(len(ctx)))
     return cache1, len(ctx)
+
+
+def _bucket(cfg: LMConfig, ctx_len: int) -> int:
+    """The prefill's bucket for a context of ``ctx_len``: the next
+    power of two, at most ``max_seq``."""
+    bucket = 1
+    while bucket < max(ctx_len, 1):
+        bucket <<= 1
+    return min(bucket, cfg.max_seq)
 
 
 def _router_note(cfg: LMConfig) -> str:
@@ -249,6 +254,18 @@ def _router_note(cfg: LMConfig) -> str:
             == ("ffn", "sigmoid"):
         return ""
     return f":route@{cfg.router_at}/{cfg.router_scoring}"
+
+
+def _scale_note(cfg: LMConfig) -> str:
+    """``":scaled<embed>/<residual>/<attn>/<logit>"`` where the block
+    scales its residual stream (``LMConfig.embed_scale`` ...); empty for
+    every block that does not, whose fingerprints stay what they
+    were."""
+    scales = (cfg.embed_scale, cfg.residual_scale, cfg.attn_scale,
+              cfg.logit_scale)
+    if scales == (1.0, 1.0, None, 1.0):
+        return ""
+    return ":scaled" + "/".join(str(x) for x in scales)
 
 
 def _setlen(cache, slot, val):
@@ -352,14 +369,14 @@ class ContinuousBatcher:
       sessions under ``kv_spill_drain_aborted`` instead of leaking.
 
     **Two kinds of state** (a layer schedule with state layers,
-    ``LMConfig.mixers`` ``"ssm"`` or ``"kda"``): the attention and
-    latent layers keep pages per TOKEN as above; each state layer keeps
-    one fixed block per SLOT in the state pool (``sh<i>``/``sc<i>`` of
-    the cache, its size follows ``slots``; ``kv_stats()["state"]``
-    says which kinds it holds).  Admission writes the prefill's state at
-    the prompt's true length over whatever the slot's last session
-    left; the step moves it only where the slot is active; eviction
-    just lets the slot go.  A page of keys restores no recurrent
+    ``LMConfig.mixers`` ``"ssm"``, ``"kda"`` or ``"mamba2"``): the
+    attention and latent layers keep pages per TOKEN as above; each
+    state layer keeps one fixed block per SLOT in the state pool
+    (``sh<i>``/``sc<i>`` of the cache, its size follows ``slots``;
+    ``kv_stats()["state"]`` says which kinds it holds).  Admission
+    writes the prefill's state at the prompt's true length over
+    whatever the slot's last session left; the step moves it only where
+    the slot is active; eviction just lets the slot go.  A page of keys restores no recurrent
     state, so for such a model the prefix cache declines every lookup
     (counted, ``kv_stats()["prefix"]["declined_state"]``) and the
     options that would park, catch up or import are refused
@@ -527,6 +544,11 @@ class ContinuousBatcher:
         # prompts filled and their true rows (``kda_scan``'s)
         self._kda = {"steps": 0, "slot_steps": 0, "fills": 0,
                      "fill_rows": 0}
+        # the Mamba-2 layers' own, as the delta rule's, and the chunks
+        # of the prompts' scans (a layer's, the bucket's padding
+        # included: what ``ssd_chunk_scan`` ran)
+        self._mamba2 = {"steps": 0, "slot_steps": 0, "fills": 0,
+                        "fill_rows": 0, "chunks": 0}
         # the expert layers' routing, as the steps' own counts say
         # (read with each step's tokens): steps and rows stepped,
         # (token, expert) pairs on a held expert, held experts touched
@@ -697,6 +719,9 @@ class ContinuousBatcher:
         cfg = self.cfg
         if cfg.has_kda:
             out["kda"] = {**out["state"]["kinds"]["kda"], **self._kda}
+        if cfg.has_mamba2:
+            out["mamba2"] = {**out["state"]["kinds"]["mamba2"],
+                             **self._mamba2}
         if cfg.passes > 1:
             out["loop"] = {"passes": cfg.passes, "layers": cfg.depth,
                            "token_bytes": paged_page_bytes(cfg, self.page)
@@ -1001,6 +1026,11 @@ class ContinuousBatcher:
             if self.cfg.has_kda:
                 self._kda["fills"] += 1
                 self._kda["fill_rows"] += ctx_len
+            if self.cfg.has_mamba2:
+                self._mamba2["fills"] += 1
+                self._mamba2["fill_rows"] += ctx_len
+                self._mamba2["chunks"] += -(-_bucket(self.cfg, ctx_len)
+                                            // self.cfg.mamba2_chunk)
             last = int(sess.prompt[-1])
             start_len = ctx_len
             if self._prefix is not None:
@@ -1510,6 +1540,9 @@ class ContinuousBatcher:
         if self.cfg.has_kda:
             self._kda["steps"] += 1
             self._kda["slot_steps"] += len(snap)
+        if self.cfg.has_mamba2:
+            self._mamba2["steps"] += 1
+            self._mamba2["slot_steps"] += len(snap)
         if self.cfg.passes > 1:
             self._loop["steps"] += 1
             self._loop["layer_passes"] += self.cfg.passes * self.cfg.depth
@@ -1951,6 +1984,10 @@ class LMService(Service):
                    f"{c.ssm_inner}x{c.ssm_state}x{c.ssm_conv}")
         if c.has_kda:
             fp += f":{c.kda_heads}x{c.kda_head_dim}x{c.kda_conv}"
+        if c.has_mamba2:
+            fp += (f":{c.mamba2_heads}x{c.mamba2_head_dim}x{c.mamba2_state}"
+                   f"x{c.mamba2_groups}x{c.mamba2_conv}x{c.mamba2_chunk}")
+        fp += _scale_note(c)
         if c.passes > 1 or c.post_norms:
             # pages a pass; the norms behind the branches
             fp += f":{c.passes}p{int(c.post_norms)}:{c.rope_theta}"
@@ -2063,4 +2100,18 @@ class LMService(Service):
                          "route_scale": c.route_scale,
                          "scoring": c.router_scoring,
                          "router_at": c.router_at})
+        if c.has_mamba2:
+            # the Mamba-2 layers' widths and the batcher's counts
+            # (``kv_stats()["mamba2"]``)
+            bat = self._batcher
+            info["mamba2"] = {
+                "heads": c.mamba2_heads, "head_dim": c.mamba2_head_dim,
+                "state": c.mamba2_state, "groups": c.mamba2_groups,
+                "conv": c.mamba2_conv, "chunk": c.mamba2_chunk,
+                **state_kinds(c)["mamba2"],
+                **(bat._mamba2 if bat is not None else {})}
+        if _scale_note(c):
+            info["scales"] = {"embed": c.embed_scale,
+                              "residual": c.residual_scale,
+                              "attn": c.attn_scale, "logit": c.logit_scale}
         return json.dumps(info).encode()

@@ -63,7 +63,14 @@ class LMConfig:
                  kda_head_dim: Optional[int] = None, kda_conv: int = 4,
                  passes: int = 1, post_norms: bool = False,
                  exit_threshold: float = 1.0, router_at: str = "ffn",
-                 router_scoring: str = "sigmoid"):
+                 router_scoring: str = "sigmoid",
+                 mamba2_heads: Optional[int] = None,
+                 mamba2_head_dim: int = 64, mamba2_state: int = 128,
+                 mamba2_groups: int = 1, mamba2_conv: int = 4,
+                 mamba2_chunk: int = 256, embed_scale: float = 1.0,
+                 residual_scale: float = 1.0,
+                 attn_scale: Optional[float] = None,
+                 logit_scale: float = 1.0):
         assert head_dim is not None or dim % heads == 0
         hd = dim // heads if head_dim is None else int(head_dim)
         assert not rope or hd % 2 == 0, "head dim must be even for RoPE"
@@ -78,9 +85,11 @@ class LMConfig:
         # Mamba-1 state-space layer, models/ssm_mixer.py, whose state
         # is per SEQUENCE, not per token), "mla" (latent attention,
         # models/mla_mixer.py, whose cache is ONE latent row a token)
-        # or "kda" (a gated delta-rule linear-attention layer,
+        # "kda" (a gated delta-rule linear-attention layer,
         # models/kda_mixer.py, whose state is a matrix a head, per
-        # SEQUENCE as a state-space layer's).
+        # SEQUENCE as a state-space layer's) or "mamba2" (a Mamba-2
+        # state-space layer, models/mamba2_mixer.py: one scalar decay a
+        # head over a matrix state, per SEQUENCE too).
         # The paged serving factories run all of it; every other
         # factory runs the first block only and declines the rest by
         # name (UnsupportedBlock)
@@ -132,7 +141,7 @@ class LMConfig:
         self.mixers = ("attn",) * depth if mixers is None \
             else tuple(mixers)
         assert len(self.mixers) == depth \
-            and set(self.mixers) <= {"attn", "ssm", "mla", "kda"}
+            and set(self.mixers) <= set(MIXER_INITIALS)
         # state layers of either kind: one block a SLOT in the state
         # pool, whatever the context's length
         self.has_state = bool(set(STATE_MIXERS) & set(self.mixers))
@@ -141,6 +150,29 @@ class LMConfig:
         self.kda_head_dim = hd if kda_head_dim is None \
             else int(kda_head_dim)
         self.kda_conv = int(kda_conv)
+        # a Mamba-2 layer's widths: ``mamba2_heads`` heads of
+        # ``mamba2_head_dim`` (d_inner = their product), a state of
+        # ``mamba2_state`` a head row, ``mamba2_groups`` groups of heads
+        # sharing B and C, a convolution of ``mamba2_conv`` taps, and
+        # prompts scanned in chunks of ``mamba2_chunk`` positions
+        self.has_mamba2 = "mamba2" in self.mixers
+        self.mamba2_heads = 2 * dim // int(mamba2_head_dim) \
+            if mamba2_heads is None else int(mamba2_heads)
+        self.mamba2_head_dim = int(mamba2_head_dim)
+        self.mamba2_state = int(mamba2_state)
+        self.mamba2_groups = int(mamba2_groups)
+        self.mamba2_conv = int(mamba2_conv)
+        self.mamba2_chunk = int(mamba2_chunk)
+        assert self.mamba2_heads % self.mamba2_groups == 0
+        # four scalars of the residual stream (Granite's; 1 / None:
+        # none): the table's rows times ``embed_scale``; each branch
+        # times ``residual_scale`` before the residual adds it;
+        # attention's softmax over ``q k^T * attn_scale`` (None: the
+        # head size's ``hd^-0.5``); the logits times ``logit_scale``
+        self.embed_scale = float(embed_scale)
+        self.residual_scale = float(residual_scale)
+        self.attn_scale = None if attn_scale is None else float(attn_scale)
+        self.logit_scale = float(logit_scale)
         self.norm_eps = float(norm_eps)
         # latent attention: low-rank query and key/value paths (the
         # query's direct, one ``wq``, where ``q_lora_rank`` is None), a
@@ -214,10 +246,12 @@ class LMConfig:
             assert expert_dim and 0 <= lo < hi <= self.experts_routed \
                 and 1 <= self.experts_top_k <= self.experts_routed
             if set(m for m, f in zip(self.mixers, self.ffns)
-                   if f == "experts") - {"mla", "attn", "kda"}:
+                   if f == "experts") - {"mla", "attn", "kda", "mamba2"}:
                 raise UnsupportedBlock(
                     "an expert feed-forward layer is served beside an "
-                    "'mla', an 'attn' or a 'kda' mixer only")
+                    "'mla' or an 'attn' mixer, and of the state layers "
+                    "beside a 'kda' or a 'mamba2' one: not beside a "
+                    "Mamba-1 'ssm' layer")
         if self.has_window or self.parallel_block:
             if set(self.mixers) != {"attn"}:
                 raise UnsupportedBlock(
@@ -303,7 +337,9 @@ class LMConfig:
                 and self.rope_pairs == "halves" and self.norm == "rms"
                 and not self.parallel_block
                 and self.rope_theta == 10000.0
-                and self.passes == 1 and not self.post_norms)
+                and self.passes == 1 and not self.post_norms
+                and self.embed_scale == 1.0 and self.residual_scale == 1.0
+                and self.attn_scale is None and self.logit_scale == 1.0)
 
     def window_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, w in enumerate(self.windows) if w)
@@ -316,8 +352,9 @@ class LMConfig:
             + -(-self.fill_span // page) + 1
 
     def schedule(self) -> str:
-        """The mixers' initials in layer order (``"sass"``)."""
-        return "".join(m[0] for m in self.mixers)
+        """The mixers' initials in layer order (``"sass"``;
+        :data:`MIXER_INITIALS`)."""
+        return "".join(MIXER_INITIALS[m] for m in self.mixers)
 
     def ffn_schedule(self) -> str:
         """The feed-forward parts' initials in layer order
@@ -379,15 +416,21 @@ class UnsupportedBlock(NotImplementedError):
 
 
 # the mixer kinds whose state is one block a SLOT of the state pool
-STATE_MIXERS = ("ssm", "kda")
+STATE_MIXERS = ("ssm", "kda", "mamba2")
+
+# each mixer kind's letter in ``LMConfig.schedule()`` (a Mamba-2
+# layer's is "M": "m" is latent attention's)
+MIXER_INITIALS = {"attn": "a", "ssm": "s", "mla": "m", "kda": "k",
+                  "mamba2": "M"}
 
 
 def _state_mixer(kind: str):
-    """The module of a state layer's kind: both have ``init_layer``,
+    """The module of a state layer's kind: each has ``init_layer``,
     ``state_shapes``, ``state_bytes``, ``prefill(cfg, bp, x, ctx_len)``
     and ``step(cfg, bp, x, state, tail, active, lens)``."""
-    from . import kda_mixer, ssm_mixer
-    return {"ssm": ssm_mixer, "kda": kda_mixer}[kind]
+    from . import kda_mixer, mamba2_mixer, ssm_mixer
+    return {"ssm": ssm_mixer, "kda": kda_mixer,
+            "mamba2": mamba2_mixer}[kind]
 
 
 def require_plain_block(cfg: LMConfig, what: str) -> None:
@@ -613,7 +656,17 @@ def _ffn_residual(cfg: LMConfig, bp, x):
     out = _ffn(cfg, bp, _norm(cfg, x, bp["ln2"]))
     if cfg.post_norms:
         out = _norm(cfg, out, bp["pn2"])
-    return x + out
+    return _residual(cfg, x, out)
+
+
+def _residual(cfg: LMConfig, x, *branches):
+    """The residual: ``x`` plus each branch, the branches times
+    ``cfg.residual_scale`` where the block scales them."""
+    if cfg.residual_scale == 1.0:
+        for out in branches:
+            x = x + out
+        return x
+    return x + cfg.residual_scale * sum(branches)
 
 
 def _ffn_part(cfg: LMConfig, i: int, bp, h, live, routed=None):
@@ -639,7 +692,7 @@ def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live, routed=None):
         return _ffn_residual(cfg, bp, x), None
     out, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln2"]), live,
                             routed)
-    return x + out, counts
+    return _residual(cfg, x, out), counts
 
 
 def _early_route(cfg: LMConfig, bp, x):
@@ -660,12 +713,14 @@ def _early_route(cfg: LMConfig, bp, x):
                          cfg.expert_cfg())
 
 
-def _embed_rows(params, ids):
+def _embed_rows(cfg: LMConfig, params, ids):
     """The table's rows as the float32 residual (a table stored in
-    bfloat16 is widened here, once)."""
+    bfloat16 is widened here, once), times ``cfg.embed_scale`` where
+    the block scales them."""
     import jax.numpy as jnp
     x = params["embed"][ids]
-    return x if x.dtype == jnp.float32 else x.astype(jnp.float32)
+    x = x if x.dtype == jnp.float32 else x.astype(jnp.float32)
+    return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
 
 
 def _rope_at(cfg: LMConfig, pos):
@@ -731,8 +786,8 @@ def _attn_out(cfg: LMConfig, bp, x, att, i: int = 0, live=None,
     if cfg.parallel_block:
         # the same norm :func:`_qkv` took: one in the compiled program
         m, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln1"]), live)
-        return x + a + m, counts
-    return _ffn_scheduled(cfg, i, bp, x + a, live, routed)
+        return _residual(cfg, x, a, m), counts
+    return _ffn_scheduled(cfg, i, bp, _residual(cfg, x, a), live, routed)
 
 
 def _logits(cfg: LMConfig, params, x):
@@ -743,10 +798,12 @@ def _logits(cfg: LMConfig, params, x):
 
 
 def _unembed(cfg: LMConfig, params, x):
-    """The unembedding, the embedding table itself where it is tied."""
+    """The unembedding, the embedding table itself where it is tied;
+    the logits times ``cfg.logit_scale`` where the block scales them."""
     from ..ops.quant import qmatmul
-    return qmatmul(x, params["embed"].T if cfg.tie_embed
-                   else params["unembed"])
+    out = qmatmul(x, params["embed"].T if cfg.tie_embed
+                  else params["unembed"])
+    return out if cfg.logit_scale == 1.0 else out * cfg.logit_scale
 
 
 def _looped(cfg: LMConfig, params, cache, x, layer):
@@ -880,7 +937,8 @@ def _prefill_attn_layer(cfg: LMConfig, bp, x, rot, i: int = 0, live=None):
     # layer — honoring the same impl override as make_forward
     from ..ops.flash_attention import attention
     impl = "flash" if cfg.use_flash else cfg.attn_impl
-    att = attention(q, k, v, causal=cfg.causal, impl=impl)
+    att = attention(q, k, v, causal=cfg.causal, impl=impl,
+                    scale=cfg.attn_scale)
     return _attn_out(cfg, bp, x, att, i, live)[0], kc, vc
 
 
@@ -918,7 +976,7 @@ def make_prefill(cfg: LMConfig):
     def prefill(params, ids, ctx_len):
         b, s = ids.shape
         assert b == 1 and s <= cfg.max_seq
-        x = _embed_rows(params, ids)
+        x = _embed_rows(cfg, params, ids)
         rot = _rope_at(cfg, jnp.arange(s))
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
@@ -930,7 +988,8 @@ def make_prefill(cfg: LMConfig):
                 out, h, tail = _state_mixer(cfg.mixers[i]).prefill(
                     cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps), ctx_len)
                 x, _counts = _ffn_scheduled(
-                    cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
+                    cfg, i, bp, _residual(cfg, x, out),
+                    (jnp.arange(s) < ctx_len)[None])
                 cache[f"h{i}"], cache[f"c{i}"] = h, tail
             elif cfg.mixers[i] == "mla":
                 # a latent layer returns the rows its tokens cache
@@ -939,7 +998,8 @@ def make_prefill(cfg: LMConfig):
                     cfg, bp, _rmsnorm(x, bp["ln1"], cfg.norm_eps),
                     rot if cfg.ropes[i] else None)
                 x, _counts = _ffn_scheduled(
-                    cfg, i, bp, x + out, (jnp.arange(s) < ctx_len)[None])
+                    cfg, i, bp, _residual(cfg, x, out),
+                    (jnp.arange(s) < ctx_len)[None])
             else:
                 x, kc, vc = _prefill_attn_layer(
                     cfg, bp, x, rot if cfg.ropes[i] else None, i,
@@ -1168,7 +1228,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
     A block beyond the first (``LMConfig.mixers``, ``kv_heads``, ...)
     is served here and nowhere else.  Only attention layers have pools
     (``pk<i>``/``pv<i>``; grouped heads: :func:`_paged_pool_shape`); a
-    state layer of either kind (``"ssm"``, ``"kda"``) has
+    state layer of any kind (``"ssm"``, ``"kda"``, ``"mamba2"``) has
     ``sh<i>``/``sc<i>``, one block of recurrent state for each SLOT,
     which the step moves one position where the slot is ``active``; a
     latent layer has ONE pool, ``pc<i>`` ``(num_pages,
@@ -1219,6 +1279,8 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
 
     grouped = cfg.kv_heads != cfg.heads
     kvh = cfg.kv_heads
+    # the softmax's scale, where the block gives one of its own
+    scaled = {} if cfg.attn_scale is None else {"scale": cfg.attn_scale}
 
     def attend(q, k, v, pk, pv, bt, pos, att_pos, window=0):
         """The step's own half of an attention layer, one token per
@@ -1247,7 +1309,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 q[:, 0], pk, pv, bt, att_pos, page, window)
         else:
             att = paged_attention.attention(q[:, 0], pk, pv, bt, att_pos,
-                                            page)
+                                            page, **scaled)
         return att, pk, pv
 
     def attn_layer(i, bp, x, pk, pv, bt, pos, att_pos, rot, active):
@@ -1263,7 +1325,7 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         cache = dict(cache)
         pos = jnp.minimum(cache["len"], cfg.max_seq - 1)
         att_pos = jnp.where(active, pos, 0)
-        x = _embed_rows(params, token)[:, None, :]
+        x = _embed_rows(cfg, params, token)[:, None, :]
         rot = _rope_at(cfg, pos[:, None])
         if cfg.passes > 1:
             x, cache = _looped(
@@ -1288,7 +1350,8 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
                     cache[f"pc{i}"], bt, pos, att_pos,
                     rot if cfg.ropes[i] else None, page)
-                x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
+                x, cnt = _ffn_scheduled(cfg, i, bp,
+                                        _residual(cfg, x, out[:, None]),
                                         active[:, None])
                 if cnt is not None:
                     counts.append(cnt)
@@ -1302,7 +1365,8 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                     cfg, bp, _rmsnorm(x[:, 0], bp["ln1"], cfg.norm_eps),
                     cache[f"sh{i}"], cache[f"sc{i}"], active,
                     cache["len"])
-                x, cnt = _ffn_scheduled(cfg, i, bp, x + out[:, None],
+                x, cnt = _ffn_scheduled(cfg, i, bp,
+                                        _residual(cfg, x, out[:, None]),
                                         active[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
                 if cnt is not None:
@@ -1412,7 +1476,8 @@ def _looped_riding_step(cfg: LMConfig, page: int, cw: int, attend):
         # shapes, XLA took them out by an async slice queued behind the
         # weights' prefetch (8.4 ms a step) and moved a layer's pool
         # through VMEM and back (PERF.md §6, PR 45)
-        x = _embed_rows(params, jnp.concatenate([ids, token]))[:, None, :]
+        x = _embed_rows(cfg, params,
+                        jnp.concatenate([ids, token]))[:, None, :]
         rot = _rope_at(cfg, jnp.concatenate([start + jnp.arange(w), pos])
                        [:, None])
 
@@ -1641,7 +1706,7 @@ def make_paged_span_fill(cfg: LMConfig, page: int):
         cache = dict(cache)
         j = jnp.arange(w)
         real = j < n
-        x = _embed_rows(params, ids)[None]                # (1, w, dim)
+        x = _embed_rows(cfg, params, ids)[None]           # (1, w, dim)
         rot = _rope_at(cfg, start + j)
         for i in range(cfg.depth):
             bp, win = params[f"blk{i}"], cfg.windows[i]
@@ -1695,7 +1760,7 @@ def _looped_span_fill(cfg: LMConfig, page: int):
             return _attn_out(cfg, bp, x, att[None], i, real[None])[0], pk, pv
 
         _x, cache = _looped(cfg, params, cache,
-                            _embed_rows(params, ids)[None], layer)
+                            _embed_rows(cfg, params, ids)[None], layer)
         cache["len"] = cache["len"].at[slot].set(start + n)
         return cache
 

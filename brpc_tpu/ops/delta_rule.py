@@ -148,6 +148,30 @@ def _step_kernel(order_ref, n_ref, cols_ref, b_ref, v_ref, s_in, s_ref,
         y_ref[...] = jnp.zeros_like(y_ref)
 
 
+def active_order(active):
+    """What a step kernel over the state pool walks: ``(order, n)``,
+    the ``n`` ACTIVE slots first, in order, then the last of them
+    repeated (int32, both scalar-prefetched).  A grid step ``i`` names
+    slot ``order[i]``'s blocks (:func:`active_block`); the steps past
+    ``n`` name the last active slot's again, which the pipeline neither
+    fetches nor writes a second time, so an inactive slot's block is
+    never read nor written."""
+    import jax.numpy as jnp
+
+    n = jnp.sum(active.astype(jnp.int32))
+    order = jnp.argsort(jnp.logical_not(active), stable=True
+                        ).astype(jnp.int32)
+    order = jnp.where(jnp.arange(active.shape[0]) < n, order,
+                      order[jnp.maximum(n - 1, 0)])
+    return order, n
+
+
+def active_block(*rest):
+    """The index map of a slot's block in a grid over
+    :func:`active_order`: ``(order[i],) + rest``."""
+    return lambda i, order, n: (order[i],) + rest
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _step_call(q, k, v, a, b, s, active, interpret: bool):
     import jax.numpy as jnp
@@ -155,15 +179,8 @@ def _step_call(q, k, v, a, b, s, active, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     slots, heads, d = q.shape
-    # the active slots first, in order; then the last of them repeated
-    n = jnp.sum(active.astype(jnp.int32))
-    order = jnp.argsort(jnp.logical_not(active), stable=True
-                        ).astype(jnp.int32)
-    order = jnp.where(jnp.arange(slots) < n, order,
-                      order[jnp.maximum(n - 1, 0)])
-
-    def at(*rest):
-        return lambda i, order, n: (order[i],) + rest
+    order, n = active_order(active)
+    at = active_block
 
     state = pl.BlockSpec((1, heads, d, d), at(0, 0, 0))
     row = pl.BlockSpec((1, heads, d), at(0, 0))

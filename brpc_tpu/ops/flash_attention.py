@@ -607,15 +607,18 @@ def flash_attention(q, k, v, causal: bool = False,
 DENSE_FLASH_CROSSOVER = 2048
 
 
-def dense_attention(q, k, v, causal: bool = False):
+def dense_attention(q, k, v, causal: bool = False,
+                    scale: Optional[float] = None):
     """XLA-fused dense attention — materializes the (s, s) scores and
     lets the compiler tile the matmul chain onto the MXU.  The fastest
-    impl below the crossover; the correctness oracle everywhere."""
+    impl below the crossover; the correctness oracle everywhere.  The
+    softmax is over ``q k^T * scale`` (None: ``d^-0.5``)."""
     import jax
     import jax.numpy as jnp
 
     d = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (d ** 0.5)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    s = s / (d ** 0.5) if scale is None else s * scale
     if causal:
         n = q.shape[1]
         pos = jnp.arange(n)
@@ -628,7 +631,8 @@ def dense_attention(q, k, v, causal: bool = False):
 def attention(q, k, v, causal: bool = False, impl: str = "auto",
               block_q: Optional[int] = None,
               block_k: Optional[int] = None,
-              interpret: Optional[bool] = None):
+              interpret: Optional[bool] = None,
+              scale: Optional[float] = None):
     """Sequence-adaptive attention dispatch.
 
     ``impl="auto"`` picks dense (XLA-fused, O(s²) memory) below
@@ -637,14 +641,18 @@ def attention(q, k, v, causal: bool = False, impl: str = "auto",
     Off-TPU, auto always picks dense: the kernel would run in Pallas
     interpret mode there, which is never the faster choice.
     ``impl="dense"``/``"flash"`` force.  Shapes are static under jit,
-    so the choice is made at trace time: no runtime branching."""
+    so the choice is made at trace time: no runtime branching.
+    ``scale`` is the softmax's (None: ``d^-0.5``, the kernel's own: any
+    other reaches it through the queries)."""
     if impl == "auto":
         from .device_ops import _on_tpu
         impl = "flash" if (q.shape[1] >= DENSE_FLASH_CROSSOVER
                            and _on_tpu()) else "dense"
     if impl == "dense":
-        return dense_attention(q, k, v, causal)
+        return dense_attention(q, k, v, causal, scale)
     if impl == "flash":
+        if scale is not None:
+            q = q * (scale * q.shape[-1] ** 0.5)
         return flash_attention(q, k, v, causal, block_q=block_q,
                                block_k=block_k, interpret=interpret)
     raise ValueError(f"unknown attention impl {impl!r}")

@@ -41,7 +41,7 @@ _WAVE_BYTES = 512 << 10
 
 
 def reference(q, pk, pv, bt, pos, page: Optional[int] = None,
-              window: int = 0):
+              window: int = 0, scale: Optional[float] = None):
     """The plain formulation: gather every slot's whole block table
     into a ``(slots, max_seq, heads, hd)`` view and run a dense masked
     attention over it.  What the step runs off the TPU, and what the
@@ -49,7 +49,8 @@ def reference(q, pk, pv, bt, pos, page: Optional[int] = None,
     grouped layout, ``(num_pages, page * kv_heads, hd)`` with a row a
     (token, key/value head) pair and each key/value head shared by
     ``heads // kv_heads`` query heads; they need ``page``.  With
-    ``window`` a slot attends ``pos - window < j <= pos`` only."""
+    ``window`` a slot attends ``pos - window < j <= pos`` only.  The
+    softmax is over ``q k^T * scale`` (None: ``hd^-0.5``)."""
     import jax.numpy as jnp
 
     b, heads, hd = q.shape
@@ -65,7 +66,8 @@ def reference(q, pk, pv, bt, pos, page: Optional[int] = None,
             jnp.repeat(x, heads // kv_heads, axis=2)
 
     s_mat = jnp.einsum("bhd,bkhd->bhk", q, view(pk),
-                       preferred_element_type=jnp.float32) / (hd ** 0.5)
+                       preferred_element_type=jnp.float32)
+    s_mat = s_mat / (hd ** 0.5) if scale is None else s_mat * scale
     live = jnp.arange(max_seq)[None, :] <= pos[:, None]
     if window:
         live = live & (jnp.arange(max_seq)[None, :]
@@ -83,7 +85,7 @@ def pages_per_wave(page: int, heads: int, hd: int, pps: int) -> int:
 
 def _kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
             kbuf, vbuf, sems, m_scr, l_scr, acc_scr, *,
-            page: int, heads: int, wave: int):
+            page: int, heads: int, wave: int, scale: Optional[float]):
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -91,7 +93,7 @@ def _kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
 
     slots, _, hd = q_ref.shape
     toks = wave * page
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
 
     def n_pages(b):
         return pos_ref[b] // page + 1
@@ -164,8 +166,9 @@ def _kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     lax.fori_loop(0, slots, slot_body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_call(q, pk, pv, bt, pos, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_call(q, pk, pv, bt, pos, scale: Optional[float],
+                interpret: bool):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -176,7 +179,8 @@ def _paged_call(q, pk, pv, bt, pos, interpret: bool):
     toks = wave * page
     whole = pl.BlockSpec((slots, heads, hd), lambda i, bt, pos: (0, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, page=page, heads=heads, wave=wave),
+        functools.partial(_kernel, page=page, heads=heads, wave=wave,
+                          scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -199,19 +203,21 @@ def _paged_call(q, pk, pv, bt, pos, interpret: bool):
 
 
 def paged_decode_attention(q, pk, pv, bt, pos,
+                           scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """``q (slots, heads, hd)`` against positions ``0..pos[b]`` of slot
     ``b``, whose pages ``bt[b, :pos[b] // page + 1]`` lie in the pools
     ``pk`` / ``pv (num_pages, page, heads, hd)`` -> ``(slots, heads,
     hd)`` float32.  Block-table entries past the last live page are
-    never read.  Traced once however many layers call it."""
-    return _paged_call(q, pk, pv, bt, pos,
+    never read.  Traced once however many layers call it.  ``scale`` as
+    :func:`reference`'s."""
+    return _paged_call(q, pk, pv, bt, pos, scale=scale,
                        interpret=_resolve_interpret(interpret))
 
 
 def _grouped_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
                     kbuf, vbuf, sems, *, page: int, kv_heads: int,
-                    wave: int):
+                    wave: int, scale: Optional[float]):
     """Key/value heads shared by groups of query heads: a wave's rows
     are (token, key/value head) pairs on the sublanes, so a group's
     ``(g, hd)`` queries meet one head's ``(tokens, hd)`` keys as the
@@ -227,7 +233,7 @@ def _grouped_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     g = heads // kv_heads
     toks = wave * page
     rows = page * kv_heads
-    scale = 1.0 / (hd ** 0.5)
+    scale = 1.0 / (hd ** 0.5) if scale is None else scale
     hi = lax.Precision.HIGHEST
 
     def n_pages(b):
@@ -307,8 +313,9 @@ def _grouped_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     lax.fori_loop(0, slots, slot_body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("page", "interpret"))
-def _grouped_call(q, pk, pv, bt, pos, page: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("page", "scale", "interpret"))
+def _grouped_call(q, pk, pv, bt, pos, page: int, scale: Optional[float],
+                  interpret: bool):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -319,7 +326,7 @@ def _grouped_call(q, pk, pv, bt, pos, page: int, interpret: bool):
     whole = pl.BlockSpec((slots, heads, hd), lambda i, bt, pos: (0, 0, 0))
     return pl.pallas_call(
         functools.partial(_grouped_kernel, page=page, kv_heads=kv_heads,
-                          wave=wave),
+                          wave=wave, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
@@ -339,11 +346,12 @@ def _grouped_call(q, pk, pv, bt, pos, page: int, interpret: bool):
 
 
 def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
+                                   scale: Optional[float] = None,
                                    interpret: Optional[bool] = None):
     """:func:`paged_decode_attention` for ``heads`` query heads over
     fewer key/value heads: the pools are ``(num_pages, page *
     kv_heads, hd)``, a row a (token, key/value head) pair."""
-    return _grouped_call(q, pk, pv, bt, pos, page=page,
+    return _grouped_call(q, pk, pv, bt, pos, page=page, scale=scale,
                          interpret=_resolve_interpret(interpret))
 
 
@@ -776,18 +784,20 @@ def import_pallas() -> None:
     import jax.experimental.pallas.tpu  # noqa: F401
 
 
-def attention(q, pk, pv, bt, pos, page: Optional[int] = None):
+def attention(q, pk, pv, bt, pos, page: Optional[int] = None,
+              scale: Optional[float] = None):
     """The step's attention: the kernel on the TPU, :func:`reference`
     on the cpu backend (where the kernel would only be interpreted), as
     ``flash_attention.attention(impl="auto")`` chooses for prefill.
     Pools of three dimensions are the grouped layout and need
-    ``page``."""
+    ``page``; ``scale`` is the softmax's (None: ``hd^-0.5``)."""
     from .device_ops import _on_tpu
     if not _on_tpu():
-        return reference(q, pk, pv, bt, pos, page)
+        return reference(q, pk, pv, bt, pos, page, scale=scale)
     if pk.ndim == 3:
-        return paged_decode_attention_grouped(q, pk, pv, bt, pos, page)
-    return paged_decode_attention(q, pk, pv, bt, pos)
+        return paged_decode_attention_grouped(q, pk, pv, bt, pos, page,
+                                              scale)
+    return paged_decode_attention(q, pk, pv, bt, pos, scale)
 
 
 def window_attention(q, pk, pv, bt, pos, page: int, window: int = 0):
