@@ -16,12 +16,13 @@ of ``P``, a state of ``N``, ``G`` groups of heads sharing ``B`` and
     out  = RMSNorm(y * silu(z); g_norm) W_out          the norm over d_inner
 
 What a sequence carries from token to token is ``S`` (``H x P x N``
-float32: ``ops.ssd``'s layout) and the convolution's last ``conv - 1``
-inputs, a RING (``kda_mixer.ring_of``): position ``t``'s ``xBC`` in row
-``t mod (conv - 1)``, written where it lies by a one-row scatter that
-drops an idle slot's row.  Every weight matmul goes through ``qmatmul``
-(bf16 operands, float32 accumulation); the convolution, ``dt``, the
-recurrence and the gated norm are float32.
+float32, laid in the pool as ``ops.ssd.to_pool`` has it: ``N`` on the
+sublanes, ``(head, p)`` on the lanes) and the convolution's last
+``conv - 1`` inputs, a RING (``kda_mixer.ring_of``): position ``t``'s
+``xBC`` in row ``t mod (conv - 1)``, written where it lies by a one-row
+scatter that drops an idle slot's row.  Every weight matmul goes
+through ``qmatmul`` (bf16 operands, float32 accumulation); the
+convolution, ``dt``, the recurrence and the gated norm are float32.
 
 - :func:`prefill`: a whole zero-padded bucket from the zero state in
   the chunked form (``ops.ssd.chunk_scan``, chunks of ``LMConfig
@@ -75,9 +76,12 @@ def init_layer(key, cfg) -> dict:
 
 
 def state_shapes(cfg, batch: int) -> tuple:
-    """``(S, ring)`` shapes of ``batch`` sequences' state."""
-    return ((batch, cfg.mamba2_heads, cfg.mamba2_head_dim,
-             cfg.mamba2_state),
+    """``(S, ring)`` shapes of ``batch`` sequences' state, ``S`` as the
+    pool lays it (``ops.ssd.pool_shape``)."""
+    from ..ops.ssd import pool_shape
+
+    return ((batch,) + pool_shape(cfg.mamba2_heads, cfg.mamba2_head_dim,
+                                  cfg.mamba2_state),
             (batch, cfg.mamba2_conv - 1, conv_width(cfg)))
 
 
@@ -115,9 +119,9 @@ def _out(cfg, bp, y, z):
 def prefill(cfg, bp, x, ctx_len):
     """``x (1, s, dim)``, normed, zero-padded past ``ctx_len`` ->
     ``(out (1, s, dim), S, ring)``: the state after position ``ctx_len
-    - 1`` and the convolution's inputs at ``ctx_len - conv + 1 ..
-    ctx_len - 1`` (zeros before the sequence's start), each in the
-    ring's row of its position."""
+    - 1``, in the pool's layout, and the convolution's inputs at
+    ``ctx_len - conv + 1 .. ctx_len - 1`` (zeros before the sequence's
+    start), each in the ring's row of its position."""
     import jax
     import jax.numpy as jnp
 
@@ -137,8 +141,9 @@ def prefill(cfg, bp, x, ctx_len):
                    jax.nn.softplus(dt + bp["dt_bias"]), 0.0)
     y, state = ssd.chunk_scan(
         xs, dt, -jnp.exp(bp["a_log"]), b, c, bp["d"],
-        jnp.zeros(state_shapes(cfg, 1)[0], jnp.float32), cfg.mamba2_chunk)
-    return _out(cfg, bp, y.reshape(1, s, di), z), state, ring
+        jnp.zeros((1,) + xs.shape[2:] + (cfg.mamba2_state,), jnp.float32),
+        cfg.mamba2_chunk)
+    return _out(cfg, bp, y.reshape(1, s, di), z), ssd.to_pool(state), ring
 
 
 def step(cfg, bp, x, state, ring, active, lens):

@@ -29,15 +29,20 @@ Two forms a caller needs, as ``ops/delta_rule.py`` has them:
   ACTIVE slot (``delta_rule.active_order``), so an idle slot's block is
   neither read nor written.  Its plain form is :func:`sequential` (a
   ``lax.scan`` over positions, the tests' yardstick and what runs off
-  the TPU), which takes the same association as the kernel.
+  the TPU), which takes the same association as the kernel but for
+  the order of ``y``'s sum over ``N``.
 
-Layout.  A state lies ``(heads, P, N)``: ``N`` (128 at the served
-widths) on the lanes, so a head's state is whole vector registers and
-the pool is read and written where it lies.  ``dt x`` multiplies the
-state by ROW, so the kernel takes ``x`` transposed, ``(P, heads)``, a
-head's column a static lane slice, and returns ``y`` the same way;
-``B`` and ``C`` are rows as they arrive; ``dt``, the decay ``exp(dt
-A)`` and ``D`` are scalars, read from SMEM.
+Layout.  The equation's state is ``(heads, P, N)``, and so are
+:func:`sequential` and :func:`chunk_scan`'s.  The POOL lays a slot's
+state transposed, ``(heads P // w, N, w)`` (:func:`to_pool`,
+:func:`from_pool`): ``N`` on the sublanes and the flattened ``(head,
+p)`` index on the lanes, ``w`` = 128 lanes a row where ``heads P``
+fills whole rows (at ``P`` = 64 a row holds two heads side by side).
+Every lane is then one ``(head, p)`` pair and the step is elementwise:
+row ``r`` is ``S_r = decay_r S_r + B (dt x)_r`` with ``B`` a column
+spread over the lanes, and ``y_r`` a sum over the sublanes.  ``x``
+arrives as rows in its own ``(heads P)`` order and ``y`` leaves so;
+the kernel builds each row's decay, ``dt`` and ``D`` from SMEM scalars.
 """
 
 from __future__ import annotations
@@ -51,9 +56,34 @@ from .delta_rule import active_block, active_order
 from .flash_attention import _resolve_interpret
 
 LANES = 128
+SUBLANES = 8
 # the step's blocks (a slot's 4 MB state in and out, twice each) pass
 # the 16 MB Mosaic scopes by default
 _VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def pool_shape(heads: int, p: int, n: int) -> tuple:
+    """One slot's state as the pool lays it: ``(rows, N, w)``, ``w``
+    128 lanes where ``heads P`` fills whole rows of them, else one row
+    of ``heads P``."""
+    w = LANES if heads * p % LANES == 0 else heads * p
+    return (heads * p // w, n, w)
+
+
+def to_pool(s):
+    """``(..., heads, P, N)``, the equation's layout -> the pool's
+    ``(..., rows, N, w)`` (:func:`pool_shape`)."""
+    *lead, heads, p, n = s.shape
+    rows, _n, w = pool_shape(heads, p, n)
+    t = s.reshape(*lead, heads * p, n).swapaxes(-1, -2)
+    return t.reshape(*lead, n, rows, w).swapaxes(-2, -3)
+
+
+def from_pool(s, heads: int):
+    """The pool's ``(..., rows, N, w)`` -> ``(..., heads, P, N)``."""
+    *lead, rows, n, w = s.shape
+    t = s.swapaxes(-2, -3).reshape(*lead, n, rows * w).swapaxes(-1, -2)
+    return t.reshape(*lead, heads, rows * w // heads, n)
 
 
 def _by_head(t, heads: int):
@@ -146,26 +176,45 @@ def chunk_scan(x, dt, a, b, c, d, s0, chunk: int):
     return y + d[:, None] * x[:, :n_pos], s.reshape(s0.shape)
 
 
+def _by_lane(value, r: int, w: int, p: int, lane):
+    """Row ``r``'s ``(1, w)`` lanes of a number a head (``value(h)``, a
+    scalar read from SMEM): each lane takes its head's."""
+    import jax.numpy as jnp
+
+    first, last = r * w // p, (r * w + w - 1) // p
+    out = jnp.full((1, w), value(last))
+    for h in range(last - 1, first - 1, -1):
+        out = jnp.where(lane < (h + 1) * p - r * w, value(h), out)
+    return out
+
+
 def _step_kernel(order_ref, n_ref, dt_ref, da_ref, d_ref, x_ref, b_ref,
-                 c_ref, s_in, s_ref, y_ref, *, heads: int, groups: int):
+                 c_ref, s_in, s_ref, y_ref, *, p: int, group_rows: int):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
     i = pl.program_id(0)
-    hg = heads // groups
+    rows, n, w = s_in.shape[1:]
 
     # grid step i is the i-th ACTIVE slot (``delta_rule.active_order``)
     @pl.when(i < n_ref[0])
     def _():
-        xs = x_ref[0]                                     # (P, heads)
-        for h in range(heads):
-            g = h // hg
-            x = xs[:, h:h + 1]                            # (P, 1)
-            s = da_ref[0, 0, h] * s_in[0, h] \
-                + (dt_ref[0, 0, h] * x) * b_ref[0, g:g + 1]
-            s_ref[0, h] = s
-            y_ref[0, :, h:h + 1] = jnp.sum(s * c_ref[0, g:g + 1], axis=1,
-                                           keepdims=True) + d_ref[h] * x
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+        # a group's B and C columns spread over the lanes once a slot
+        spread = {}
+        for r in range(rows):
+            g = r // group_rows
+            if g not in spread:
+                spread[g] = [jnp.broadcast_to(t[0, :, g:g + 1], (n, w))
+                             for t in (b_ref, c_ref)]
+            b, c = spread[g]
+            x = x_ref[0, r:r + 1]                         # (1, w)
+            xdt = _by_lane(lambda h: dt_ref[0, 0, h], r, w, p, lane) * x
+            s = _by_lane(lambda h: da_ref[0, 0, h], r, w, p, lane) \
+                * s_in[0, r] + b * xdt
+            s_ref[0, r] = s
+            y_ref[0, r:r + 1] = jnp.sum(s * c, axis=0, keepdims=True) \
+                + _by_lane(lambda h: d_ref[h], r, w, p, lane) * x
 
     # no slot is active: the one block that was fetched goes back as it
     # came
@@ -183,25 +232,33 @@ def _step_call(x, dt, a, b, c, d, s, active, interpret: bool):
 
     slots, heads, p = x.shape
     groups, n = b.shape[1:]
+    rows, _n, w = pool_shape(heads, p, n)
+    if s.shape != (slots, rows, n, w) \
+            or not _rows_in_one_group(heads, p, n, groups):
+        raise ValueError(
+            f"ssd_step takes a pool of {(slots, rows, n, w)} whose rows of "
+            f"lanes lie in one group each and N whole sublane tiles: "
+            f"pool {s.shape}, {heads} heads of {p} in {groups} groups, "
+            f"N {n}")
     order, n_act = active_order(active)
     at = active_block
     smem = pltpu.SMEM
     # a slot's ``dt`` and decay: ``(1, 1, heads)`` blocks, whole in
     # their last two dimensions
-    row = pl.BlockSpec((1, 1, heads), at(0, 0), memory_space=smem)
-    cols = pl.BlockSpec((1, p, heads), at(0, 0))
-    state = pl.BlockSpec((1, heads, p, n), at(0, 0, 0))
+    per_head = pl.BlockSpec((1, 1, heads), at(0, 0), memory_space=smem)
+    lanes = pl.BlockSpec((1, rows, w), at(0, 0))
+    cols = pl.BlockSpec((1, n, groups), at(0, 0))
+    state = pl.BlockSpec((1, rows, n, w), at(0, 0, 0))
     s, y = pl.pallas_call(
-        functools.partial(_step_kernel, heads=heads, groups=groups),
+        functools.partial(_step_kernel, p=p, group_rows=rows // groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots,),
-            in_specs=[row, row, pl.BlockSpec(memory_space=smem), cols,
-                      pl.BlockSpec((1, groups, n), at(0, 0)),
-                      pl.BlockSpec((1, groups, n), at(0, 0)), state],
-            out_specs=[state, cols]),
+            in_specs=[per_head, per_head, pl.BlockSpec(memory_space=smem),
+                      lanes, cols, cols, state],
+            out_specs=[state, lanes]),
         out_shape=[jax.ShapeDtypeStruct(s.shape, jnp.float32),
-                   jax.ShapeDtypeStruct((slots, p, heads), jnp.float32)],
+                   jax.ShapeDtypeStruct((slots, rows, w), jnp.float32)],
         # the pool is updated where it lies (operand 8: after ``order``,
         # ``n``, ``dt``, the decay, ``D``, ``x``, ``B`` and ``C``)
         input_output_aliases={8: 0},
@@ -211,36 +268,45 @@ def _step_call(x, dt, a, b, c, d, s, active, interpret: bool):
         interpret=interpret,
         name="ssd_step",
     )(order, n_act.reshape(1), dt[:, None], jnp.exp(dt * a)[:, None], d,
-      jnp.swapaxes(x, 1, 2), b, c, s)
+      x.reshape(slots, rows, w), b.swapaxes(1, 2), c.swapaxes(1, 2), s)
     # an inactive slot's row was never written
-    return jnp.where(active[:, None, None], jnp.swapaxes(y, 1, 2), 0.0), s
+    return jnp.where(active[:, None, None], y.reshape(x.shape), 0.0), s
 
 
 def ssd_step(x, dt, a, b, c, d, s, active, interpret: Optional[bool] = None):
     """One position for each slot.  ``x (slots, heads, P)``; ``dt
     (slots, heads)``; ``a``, ``d (heads,)``; ``b``, ``c (slots, groups,
-    N)``; ``s (slots, heads, P, N)``, updated in place; ``active
-    (slots,)`` bool: a slot that is not keeps its state, which is
-    neither read nor written, and its ``y`` is zero -> ``y`` like ``x``
-    (``S C + D x``, before the gate) and the pool."""
+    N)``; ``s (slots, rows, N, w)`` the pool (:func:`pool_shape`),
+    updated in place; ``active (slots,)`` bool: a slot that is not
+    keeps its state, which is neither read nor written, and its ``y``
+    is zero -> ``y`` like ``x`` (``S C + D x``, before the gate) and the
+    pool.  A row of lanes must lie in one group of heads and ``N`` be
+    whole sublane tiles (:func:`_rows_in_one_group`)."""
     return _step_call(x, dt, a, b, c, d, s, active,
                       interpret=_resolve_interpret(interpret))
 
 
-def _use_kernel(n: int) -> bool:
+def _rows_in_one_group(heads: int, p: int, n: int, groups: int) -> bool:
+    return (heads // groups) * p % LANES == 0 and n % SUBLANES == 0
+
+
+def _use_kernel(heads: int, p: int, n: int, groups: int) -> bool:
     from .device_ops import _on_tpu
-    return n % LANES == 0 and _on_tpu()
+    return _rows_in_one_group(heads, p, n, groups) and _on_tpu()
 
 
 def step(x, dt, a, b, c, d, s, active):
     """What the batched decode step calls (shapes as :func:`ssd_step`):
-    the kernel on the TPU where a head's state is whole lanes, the plain
-    scan elsewhere."""
+    the kernel on the TPU where a row of the pool's lanes lies in one
+    group, the plain scan in the equation's layout elsewhere."""
     import jax.numpy as jnp
 
-    if _use_kernel(b.shape[-1]):
+    heads, p = x.shape[1:]
+    groups, n = b.shape[1:]
+    if _use_kernel(heads, p, n, groups):
         return ssd_step(x, dt, a, b, c, d, s, active)
     with jax.named_scope("ssd_step"):
         y, s = sequential(x[:, None], dt[:, None], a, b[:, None],
-                          c[:, None], d, s, active.astype(jnp.int32))
-    return jnp.where(active[:, None, None], y[:, 0], 0.0), s
+                          c[:, None], d, from_pool(s, heads),
+                          active.astype(jnp.int32))
+    return jnp.where(active[:, None, None], y[:, 0], 0.0), to_pool(s)

@@ -117,26 +117,47 @@ def test_the_chunked_form_is_the_scan(name):
     np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=2e-5)
 
 
+# (heads, P): one row of 32 lanes; two heads a row of 128; a head a row
+@pytest.mark.parametrize("heads,p", [(4, 8), (4, 64), (3, 128)])
+def test_the_pool_lays_n_on_the_sublanes_and_head_p_on_the_lanes(heads, p):
+    """:func:`ssd.to_pool` puts entry ``(h, p, n)`` of a slot's state at
+    ``(row, n, lane)`` with ``row w + lane = h P + p``, and
+    :func:`ssd.from_pool` takes it back bit for bit."""
+    s = jax.random.normal(jax.random.PRNGKey(p), (2, 3, heads, p, 16))
+    pool = ssd.to_pool(s)
+    rows, n, w = ssd.pool_shape(heads, p, 16)
+    assert pool.shape == (2, 3, rows, n, w) and rows * w == heads * p
+    assert w == (128 if heads * p % 128 == 0 else heads * p)
+    flat = np.asarray(s).reshape(2, 3, heads * p, n)
+    want = flat.reshape(2, 3, rows, w, n).swapaxes(-1, -2)
+    assert np.asarray(pool).tobytes() == want.tobytes()
+    assert np.asarray(ssd.from_pool(pool, heads)).tobytes() \
+        == np.asarray(s).tobytes()
+
+
 @pytest.mark.parametrize("active", [
     [1, 0, 1, 1, 0, 0], [0] * 6, [1] * 6, [0, 0, 0, 0, 0, 1]])
 def test_ssd_step_kernel_updates_in_place_and_leaves_idle_slots(active):
-    """The step kernel, interpreted: active slots move one position as
-    the plain scan moves them, an inactive slot's state is bit-equal to
-    what it was (it is never read: it may hold anything, NaN included)
-    and its ``y`` is zero, and the pool handed in is the pool handed
-    back."""
-    x, dt, a, b, c, d, s0 = _recurrence_case(sum(active), 6, 1, 4, 8, 16, 2)
+    """The step kernel, interpreted, at 4 heads of 64 in 2 groups (two
+    heads share a row of lanes, a row a group): active slots move one
+    position as the plain scan moves them (both read in the equation's
+    layout), an inactive slot's state is bit-equal to what it was (it
+    is never read: it may hold anything, NaN included) and its ``y`` is
+    zero, and the pool handed in is the pool handed back."""
+    x, dt, a, b, c, d, s0 = _recurrence_case(sum(active), 6, 1, 4, 64, 16,
+                                             2)
     active = jnp.asarray(active, bool)
     idle = ~np.asarray(active)
-    pool = np.array(s0)
+    pool = np.array(ssd.to_pool(s0))
+    assert pool.shape == (6, 2, 16, 128)
     pool[idle, 0, 0, 0] = np.nan
     want_y, want_s = ssd.sequential(x, dt, a, b, c, d, s0,
                                     active.astype(jnp.int32))
     args = (x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], d)
     y, s = ssd.ssd_step(*args, jnp.asarray(pool), active, interpret=True)
     y, s = np.asarray(y), np.asarray(s)
-    np.testing.assert_allclose(s[~idle], np.asarray(want_s)[~idle],
-                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(ssd.from_pool(s, 4))[~idle],
+                               np.asarray(want_s)[~idle], atol=1e-6)
     np.testing.assert_allclose(y[~idle], np.asarray(want_y)[~idle, 0],
                                atol=1e-5)
     assert (y[idle] == 0).all()
@@ -144,13 +165,46 @@ def test_ssd_step_kernel_updates_in_place_and_leaves_idle_slots(active):
     # in place: the kernel's pool result (0) aliases its pool operand
     # (8: after the two prefetched scalars, dt, the decay, D, x, B, C)
     jaxpr = jax.make_jaxpr(lambda *t: ssd.ssd_step(*t, interpret=True))(
-        *args, s0, active).jaxpr
+        *args, jnp.asarray(pool), active).jaxpr
     calls = []
     _count_eqns(jaxpr, lambda e: e.primitive.name == "pallas_call"
                 and calls.append(e))
     assert len(calls) == 1
     assert tuple(calls[0].params["input_output_aliases"]) == ((8, 0),)
-    assert calls[0].invars[8].aval.shape == s0.shape
+    assert calls[0].invars[8].aval.shape == pool.shape
+
+
+# (heads, P, N, groups): rows that straddle two groups; N not whole
+# sublane tiles
+@pytest.mark.parametrize("shape", [(4, 64, 16, 4), (4, 64, 12, 2)])
+def test_a_pool_the_kernel_cannot_take_runs_the_plain_scan(shape,
+                                                           monkeypatch):
+    """Where a row of lanes would straddle a group (``B`` and ``C``
+    would differ along it) or ``N`` is not whole sublane tiles,
+    :func:`ssd.step` runs the plain scan on the TPU too, in the pool's
+    one layout, and the kernel refuses the shape."""
+    from brpc_tpu.ops import device_ops
+    heads, p, n, groups = shape
+    monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
+    assert not ssd._use_kernel(heads, p, n, groups)
+    assert ssd._use_kernel(heads, p, 16, 2)
+    x, dt, a, b, c, d, s0 = _recurrence_case(1, 3, 1, heads, p, n, groups)
+    active = jnp.asarray([True, False, True])
+    args = (x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], d, ssd.to_pool(s0),
+            active)
+    jaxpr = jax.make_jaxpr(ssd.step)(*args).jaxpr
+    assert _count_eqns(jaxpr, lambda e: e.primitive.name == "pallas_call") \
+        == 0
+    monkeypatch.setattr(device_ops, "_on_tpu", lambda: False)
+    y, s = ssd.step(*args)
+    want_y, want_s = ssd.sequential(x, dt, a, b, c, d, s0,
+                                    active.astype(jnp.int32))
+    np.testing.assert_allclose(np.asarray(ssd.from_pool(s, heads)),
+                               np.asarray(want_s), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(y)[[0, 2]],
+                               np.asarray(want_y)[[0, 2], 0], atol=1e-5)
+    with pytest.raises(ValueError, match="one group each"):
+        ssd.ssd_step(*args, interpret=True)
 
 
 # -- the mixer: the chunked prefill against a position at a time --------------
@@ -212,6 +266,45 @@ def test_the_chunked_prefill_is_the_recurrence_at_the_true_length(
                                np.asarray(rings[ctx_len]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(out[0, :ctx_len]),
                                np.asarray(outs[:ctx_len]), atol=1e-4)
+
+
+def test_the_mixers_step_is_the_same_through_the_kernel(f32_matmuls,
+                                                        monkeypatch):
+    """:func:`mamba2_mixer.step` as the TPU runs it (the interpreted
+    kernel: 4 heads of 32 in one group fill a row of lanes) against the
+    plain scan, nine positions from a state that is not zero, slot 0
+    idle: the outputs and the pool, which has one layout on both."""
+    lm = T.LMConfig(vocab=32, dim=16, heads=2, depth=1, max_seq=1024,
+                    remat=False, mixers=("mamba2",), mamba2_heads=4,
+                    mamba2_head_dim=32, mamba2_state=16, mamba2_groups=1,
+                    norm_eps=1e-5)
+    bp = mamba2_mixer.init_layer(jax.random.PRNGKey(2), lm)
+    x = jax.random.normal(jax.random.PRNGKey(3), (9, 3, lm.dim))
+    shape, ring_shape = mamba2_mixer.state_shapes(lm, 3)
+    assert shape == (3, 1, 16, 128)
+    state0 = jax.random.normal(jax.random.PRNGKey(4), shape)
+    active = jnp.asarray([False, True, True])
+
+    def run():
+        state, ring = state0, jnp.zeros(ring_shape)
+        outs = []
+        for t in range(9):
+            out, state, ring = mamba2_mixer.step(
+                lm, bp, x[t], state, ring, active, jnp.full((3,), t))
+            outs.append(out)
+        return np.asarray(jnp.stack(outs)), np.asarray(state)
+
+    with jax.default_matmul_precision("highest"):
+        want_out, want_state = run()
+        monkeypatch.setattr(ssd, "_use_kernel", ssd._rows_in_one_group)
+        jaxpr = jax.make_jaxpr(lambda s: mamba2_mixer.step(
+            lm, bp, x[0], s, jnp.zeros(ring_shape), active,
+            jnp.zeros((3,), jnp.int32)))(state0).jaxpr
+        assert _count_eqns(jaxpr, _named("ssd_step")) == 1
+        out, state = run()
+    np.testing.assert_allclose(out, want_out, atol=1e-5)
+    np.testing.assert_allclose(state, want_state, atol=1e-5)
+    assert state[0].tobytes() == np.asarray(state0[0]).tobytes()
 
 
 def test_the_mixer_is_the_references_layer(model, f32_matmuls):
@@ -358,7 +451,9 @@ def test_the_state_pool_lies_beside_the_pages(model):
     shapes = {k: v.shape for k, v in cache.items()}
     conv = 8 * 16 + 2 * 2 * 16
     assert shapes == {
-        **{f"sh{i}": (3, 8, 16, 16) for i in (0, 1, 3, 4)},
+        # 8 heads of 16 = one row of 128 lanes over the state's 16
+        # sublanes (``ssd.to_pool``)
+        **{f"sh{i}": (3, 1, 16, 128) for i in (0, 1, 3, 4)},
         # a ring of three rows of the convolution's channels
         **{f"sc{i}": (3, 3, conv) for i in (0, 1, 3, 4)},
         "pk2": (9, PAGE * 2, 16), "pv2": (9, PAGE * 2, 16), "len": (3,)}
@@ -594,24 +689,30 @@ def _named(name):
 
 
 def test_the_step_calls_ssd_step_once_a_mamba2_layer(model, monkeypatch):
-    """With the kernels chosen (as on the TPU) the step holds one
-    ``ssd_step`` a Mamba-2 layer, the paged attention once and the
-    grouped expert product twice a layer; the prefill's chunks run
-    under ``ssd_chunk_scan``, one scan a Mamba-2 layer."""
+    """On the TPU the step holds one ``ssd_step`` a Mamba-2 layer where
+    a row of the pool's lanes lies in one group (the toy in ONE group:
+    8 heads of 16 fill a row), none where a row straddles two (the toy
+    as it is, 2 groups of 4 heads: half a row each); the paged
+    attention once and the grouped expert product twice a layer; the
+    prefill's chunks run under ``ssd_chunk_scan``, one scan a Mamba-2
+    layer."""
     from brpc_tpu.ops import device_ops
     cfg, m, lm, params = model
     monkeypatch.setattr(device_ops, "_on_tpu", lambda: True)
-    monkeypatch.setattr(ssd, "_use_kernel", lambda n: True)
-    prefill, step = T.make_paged_batch_decode(lm, PAGE)
-    cache = T.empty_paged_cache(lm, 9, 2, PAGE)
-    bt = jnp.zeros((2, lm.max_seq // PAGE), jnp.int32)
-    jaxpr = jax.make_jaxpr(step)(
-        params, cache, bt, jnp.zeros((2,), jnp.int32),
-        jnp.asarray([True, False])).jaxpr
-    assert [_count_eqns(jaxpr, _named(k)) for k in (
-        "ssd_step", "paged_decode_attention", "expert_gmm")] \
-        == [m.kernel_calls(cfg, k) for k in (
-            "ssd_step", "paged_decode_attention", "expert_gmm")] == [4, 1, 10]
+    one = T.LMConfig(remat=False, **{**m.lm_kwargs(cfg), "mamba2_groups": 1})
+    names = ("ssd_step", "paged_decode_attention", "expert_gmm")
+    for lm_, want in ((one, [4, 1, 10]), (lm, [0, 1, 10])):
+        step = T.make_paged_batch_decode(lm_, PAGE)[1]
+        cache = T.empty_paged_cache(lm_, 9, 2, PAGE)
+        bt = jnp.zeros((2, lm_.max_seq // PAGE), jnp.int32)
+        p_ = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0),
+                                                  lm_))
+        jaxpr = jax.make_jaxpr(step)(
+            p_, cache, bt, jnp.zeros((2,), jnp.int32),
+            jnp.asarray([True, False])).jaxpr
+        assert [_count_eqns(jaxpr, _named(k)) for k in names] == want
+    assert [m.kernel_calls(cfg, k) for k in names] == [4, 1, 10]
+    prefill = T.make_paged_batch_decode(lm, PAGE)[0]
     monkeypatch.setattr(device_ops, "_on_tpu", lambda: False)
     text = jax.jit(prefill).lower(params, jnp.zeros((1, 64), jnp.int32),
                                   jnp.int32(40)).as_text(debug_info=True)
