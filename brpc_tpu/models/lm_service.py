@@ -234,6 +234,16 @@ def bucketed_prefill(prefill_j, cfg: LMConfig, prompt: np.ndarray):
     return cache1, len(ctx)
 
 
+def _written(cache):
+    """An output that the program which returned ``cache`` wrote, for
+    the clock's readiness probe: a pool (every cache program writes
+    every layer's), never ``len``, which most of them hand through."""
+    for name, leaf in cache.items():
+        if name != "len":
+            return leaf
+    return cache["len"]
+
+
 def _bucket(cfg: LMConfig, ctx_len: int) -> int:
     """The prefill's bucket for a context of ``ctx_len``: the next
     power of two, at most ``max_seq``."""
@@ -690,13 +700,14 @@ class ContinuousBatcher:
                "spills": self.spills, "resumes": self.resumes,
                "parked": len(self._parked),
                "sched": sched_counters(),
-               "phases": _lmt.phase_counters(),
-               "phase_ns": _lmt.phase_total_ns(),
-               "loop_ns": _lmt.loop_ns(),
+               "phases": self._clock.phase_counters(),
+               "phase_ns": self._clock.phase_total_ns(),
+               "loop_ns": self._clock.loop_ns,
                "queue": _lmt.queue_counters(),
                # the steps by what stood in front of them, the device's
-               # dry time by phase, a first token's stages (this
-               # batcher's own; the ring: round_log())
+               # dry and starved time by phase, a first token's stages
+               # (this batcher's own, as the phase tables are; the
+               # ring: round_log())
                "rounds": self._clock.rounds.counters(),
                "first": self._clock.rounds.first_counters(),
                "lookahead": {"ahead": self._ahead, "sync": self._sync,
@@ -987,7 +998,7 @@ class ContinuousBatcher:
             # disagg import: blockify the imported contiguous cache
             self._cache = self._insert(self._cache, jnp.asarray(row),
                                        sess.cache1, jnp.int32(free))
-            clock.filling(1, ctx_len)
+            clock.filling(1, ctx_len, _written(self._cache))
             sess.cache1 = None
             last = int(sess.last_token)
             start_len = ctx_len
@@ -1013,7 +1024,7 @@ class ContinuousBatcher:
             ph(PH_PREFILL_DISPATCH)
             cache1, ctx_len = bucketed_prefill(self._prefill, self.cfg,
                                                sess.prompt)
-            clock.filling(1, ctx_len)
+            clock.filling(1, ctx_len, _written(cache1))
             self.prefills_run += 1
             ph(PH_INSERT_DISPATCH)
             # the insert also takes the slot: in the same program
@@ -1021,7 +1032,7 @@ class ContinuousBatcher:
             # slot's last session left there
             self._cache = self._insert(self._cache, jnp.asarray(row),
                                        cache1, jnp.int32(free))
-            clock.filling(1, 0)
+            clock.filling(1, 0, _written(self._cache))
             self._state_inserts += int(self.cfg.has_state)
             if self.cfg.has_kda:
                 self._kda["fills"] += 1
@@ -1050,6 +1061,7 @@ class ContinuousBatcher:
             start_len = covered
         self._cache = self._setlen_j(self._cache, jnp.int32(free),
                                      jnp.int32(start_len))
+        clock.follow(self._cache["len"])
         sess.pages = list(aliased) + list(priv)
         sess.n_alias = n_alias
         sess.n_priv = len(priv)
@@ -1110,7 +1122,7 @@ class ContinuousBatcher:
             self._cache = self._span_fill(
                 self._cache, row_d, jnp.asarray(wt.bt[slot].copy()),
                 np.int32(slot), np.int32(start), np.int32(n), ids)
-            self._clock.filling(1, n)
+            self._clock.filling(1, n, _written(self._cache))
             self._count_span(start, n)
         return True
 
@@ -1201,7 +1213,7 @@ class ContinuousBatcher:
         try:
             blk = self._gather_j(self._cache,
                                  jnp.asarray(self._bt[sess.slot]))
-            self._clock.filling(1, 0)
+            self._clock.filling(1, 0, blk)
             blk = np.asarray(blk)
             for j in range(sess.n_alias, sess.n_alias + sess.n_priv):
                 h = self._host.stage(
@@ -1273,9 +1285,10 @@ class ContinuousBatcher:
         sess.host_handles = None
         self._cache = self._scatter_j(self._cache, jnp.asarray(ids),
                                       jnp.asarray(blk))
-        self._clock.filling(1, sess.saved_len)
+        self._clock.filling(1, sess.saved_len, _written(self._cache))
         self._cache = self._setlen_j(self._cache, jnp.int32(free),
                                      jnp.int32(sess.saved_len))
+        self._clock.follow(self._cache["len"])
         row = np.zeros((self._pps,), np.int32)
         row[:n_alias] = sess.pages
         row[n_alias:n_used] = priv
@@ -1426,7 +1439,7 @@ class ContinuousBatcher:
             else:
                 self._cache = self._chunk_j(
                     self._cache, jnp.asarray(self._bt[sess.slot]), *span)
-                self._clock.filling(1, n)
+                self._clock.filling(1, n, _written(self._cache))
             self._slices += 1
             if self.cfg.passes > 1:
                 self._count_span(sess.fill, n)
@@ -1549,7 +1562,7 @@ class ContinuousBatcher:
             self._loop["spans_rode"] += ride is not None
         return _Flight(toks, snap, counts, clock.queued(
             queued_at, step, len(snap), ahead,
-            int(ride[2]) if ride is not None else 0))
+            int(ride[2]) if ride is not None else 0, toks))
 
     def _land(self, flight: _Flight) -> int:
         """Block on a dispatched step's tokens, walk, emit, evict.

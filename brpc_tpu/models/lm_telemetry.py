@@ -13,7 +13,9 @@ Three planes, all fed by the ONE batcher thread and read passively:
   ("lm/<phase>")`` and each pass that runs a step in
   ``StepTraceAnnotation("lm_round")``, so a profile taken with the host
   tracer on shows the loop under these names beside the device's
-  programs.  The write side is the engine-telemetry pattern: plain
+  programs.  The tables are each batcher's own, on its clock; the
+  process-wide rows sum the live clocks.  The write side is the
+  engine-telemetry pattern: plain
   per-thread counters bumped by the batcher thread ONLY — never a lock
   in the step loop (the histograms are preallocated lists;
   ``PhaseClock.switch`` is entry-listed in the blocking linter).
@@ -22,9 +24,11 @@ Three planes, all fed by the ONE batcher thread and read passively:
 - **the step as a span** (ISSUE 35) — the same clock keeps, per batcher,
   one record for each decode step it queued (:class:`RoundLog`: a ring
   of ``ROUND_RING``, the ordinal the ``lm_round`` annotation's
-  ``step_num``): what was queued in front of it, when it landed,
-  whether the device had run dry; counters by that class, the dry time
-  by the phase the host was in, and the stages of a first token;
+  ``step_num``): what was queued in front of it, when it landed and
+  when the host first knew it finished, whether the device had run
+  dry or been starved by a late host; counters by that class, the
+  dry and starved time by the phase the host was in, and the stages
+  of a first token;
 - **session timelines** — a bounded ring of per-session records
   (tier/tenant, prompt length, queue wait, TTFT, the widest gap and
   the step that ended it, prefix hit class, peak pages held, spill/resume/preempt counts, close
@@ -48,9 +52,13 @@ from __future__ import annotations
 
 import itertools
 import threading
+import weakref
 from collections import deque
 from time import monotonic as _mono_s
 from time import monotonic_ns as _mono_ns
+# the profiler's clock: the TSL profiler stamps a trace's host events
+# with absl::GetCurrentTimeNanos() and converts the device's onto it
+from time import time_ns as _trace_ns
 from typing import Optional
 
 from ..butil.flags import define_flag, get_flag, watch_flag
@@ -120,12 +128,10 @@ ROUND_TRACE_NAME = "lm_round"
 # [2^(i-1), 2^i) ns; 40 buckets reach ~9 minutes — beyond any phase
 NBUCKETS = 40
 
-_phase_buckets = [[0] * NBUCKETS for _ in LM_STEP_PHASES]
-_phase_count = [0] * _NPHASES
-_phase_total_ns = [0] * _NPHASES
-# wall time of the loop, advanced once a pass by a clock read of its
-# own: what the phases' totals have to add up to
-_loop_ns = [0]
+# every batcher's clock (its own phase tables); the process-wide rows
+# (/vars, /metrics, the /lm page) are their sums
+_clocks: "weakref.WeakSet" = weakref.WeakSet()
+_clocks_lock = threading.Lock()
 
 # flag-cached enable gate (the rpcz _rpcz_live idiom): one list read on
 # the hot path instead of a flags-table lookup per phase sample.  0 is
@@ -168,13 +174,12 @@ LM_ROUND_CLASSES = (
 RC_RESTART, RC_FILL, RC_RIDE, RC_PLAIN = range(4)
 
 ROUND_RING = 4096
-# a step whose ``device_wait`` was shorter than this had already
-# finished when the host asked: the HOST's work ended its gap
-LATE_WAIT_NS = 100_000
 ROUND_FIELDS = (
     "ordinal",        # the ``lm_round`` annotation's ``step_num``
     "pass_ns",        # top of the pass that dispatched it: the span's start
     "dispatch_ns",    # ``_step`` has returned: queued for the device
+    "seen_ns",        # the first clock read that knew its tokens ready:
+    #                   an upper bound of its end on the device
     "wait_ns",        # its ``device_wait``: how long the host stood waiting
     "land_ns",        # the tokens are on the host: the span's end
     "done_ns",        # tokens written, sessions evicted
@@ -187,6 +192,7 @@ ROUND_FIELDS = (
     "pages",          # pages it attended, by the batcher's count
     "touched",        # held experts it touched
     "dry_ns",         # the device stood dry this long before it
+    "starved_ns",     # ... or starved (seen drained) this long
     "cls",            # index into LM_ROUND_CLASSES
     "gap_ns",         # its ``land_ns`` minus the one before it
 )
@@ -196,14 +202,17 @@ class RoundLog:
     """One batcher's decode steps as spans: a ring of ``ROUND_RING``
     records (a preallocated column of ints a field, indexed by ordinal
     modulo the ring), counters by what stood in front of the step, the
-    device's dry time by the phase the host was in, and the stages of a
-    first token.  Plain data: the batcher thread writes it through its
+    device's idle time by the phase the host was in (dry: nothing was
+    queued; starved: the last program queued was seen finished, and
+    the bracket of that finish), and the stages of a first token.
+    Plain data: the batcher thread writes it through its
     :class:`PhaseClock`, readers see racy-but-monotonic values."""
 
     __slots__ = ROUND_FIELDS + (
         "cls_n", "cls_gap_ns", "cls_rows", "cls_programs", "late",
-        "max_gap", "dry_by_phase", "first", "pend", "lull", "prev_land",
-        "prev_ord", "dry_sum", "dry_mark", "landed_idx")
+        "max_gap", "dry_by_phase", "starved_by_phase", "bracket_by_phase",
+        "starved_n", "first", "pend", "lull", "prev_land", "prev_ord",
+        "dry_sum", "dry_mark", "starved_sum", "starved_mark", "landed_idx")
 
     def __init__(self):
         for f in ROUND_FIELDS:
@@ -217,6 +226,9 @@ class RoundLog:
         self.late = [0, 0]              # n, gap_ns
         self.max_gap = [0, -1]          # ns, ordinal (restarts left out)
         self.dry_by_phase = [0] * _NPHASES
+        self.starved_by_phase = [0] * _NPHASES
+        self.bracket_by_phase = [0] * _NPHASES
+        self.starved_n = 0
         # n, queue_ns, admit_ns, device_ns, emit_ns, ttft_ns
         self.first = [0] * 6
         # filling programs, their rows and joins since the last step
@@ -226,10 +238,15 @@ class RoundLog:
         self.prev_ord = -2
         self.dry_sum = 0
         self.dry_mark = 0
+        self.starved_sum = 0
+        self.starved_mark = 0
         self.landed_idx = -1            # the record being delivered
 
     def counters(self) -> dict:
-        """``kv_stats()["rounds"]``: monotonic, cheap to read."""
+        """``kv_stats()["rounds"]``: monotonic, cheap to read.
+        ``clock`` is the host's monotonic clock (the records' and the
+        tables') and the profiler's, read back to back: a record's
+        time on a trace is its own plus ``trace_ns - mono_ns``."""
         out = {}
         for c, name in enumerate(LM_ROUND_CLASSES):
             out[name] = {"n": self.cls_n[c], "gap_ns": self.cls_gap_ns[c]}
@@ -239,6 +256,11 @@ class RoundLog:
         out["late"] = {"n": self.late[0], "gap_ns": self.late[1]}
         out["max_gap_ns"], out["max_ordinal"] = self.max_gap
         out["dry_ns"] = dict(zip(LM_STEP_PHASES, self.dry_by_phase))
+        out["starved_ns"] = dict(zip(LM_STEP_PHASES, self.starved_by_phase))
+        out["starved_bracket_ns"] = dict(zip(LM_STEP_PHASES,
+                                             self.bracket_by_phase))
+        out["starved"] = {"n": self.starved_n}
+        out["clock"] = {"mono_ns": _mono_ns(), "trace_ns": _trace_ns()}
         return out
 
     def first_counters(self) -> dict:
@@ -268,21 +290,39 @@ class PhaseClock:
     that is a phase of its own inside another (a spill inside a page
     allocation) switches back to what ``switch`` returned.
 
-    The batcher's OWN state hangs on it too (a second batcher has a
-    second clock): ``rounds``, the :class:`RoundLog` its step hooks
-    write (``filling`` ... ``delivered``), and ``dry``: set while
-    nothing is queued for the device, so that ``switch`` credits the
-    ended phase to the dry table as well.
+    The batcher's OWN state hangs on it (a second batcher has a second
+    clock): the phase tables (``count``, ``total_ns``, ``buckets``,
+    ``loop_ns``), ``rounds``, the :class:`RoundLog` its step hooks
+    write (``filling`` ... ``delivered``), and the device's idle time
+    as the host can know it:
+
+    - ``dry``: nothing is queued for the device (from a step landing
+      with nothing behind it to the next program's queueing);
+    - ``starved``: the last program queued was SEEN finished.  Each
+      hook that queues a program hands in one of its outputs (``ref``:
+      one no later program donates); while it is held and unseen,
+      every ``switch`` tests it once (``_ready``).  The first that
+      sees it ready, with nothing queued behind it, marks the device
+      starved from that clock read until the next program is queued.
+      The phase in which it finished, from the last read that saw it
+      running, is the finish's uncertainty: ``bracket_by_phase``.
+
+    ``switch`` credits an ended phase to the dry or the starved table
+    as well; dry + starved is a lower bound of the device's idle time
+    between programs, and the bracket added an upper bound.
 
     ``bind`` hands in ``jax.profiler.TraceAnnotation`` /
     ``StepTraceAnnotation`` (this module imports no jax);
     with no profile session, or one whose host tracer is off, building
     one is a flag test inside the profiler.  With ``lm_telemetry`` off
-    nothing is built or written and every hook is the gate's single
-    list read."""
+    nothing is built, written or tested and every hook is the gate's
+    single list read."""
 
     __slots__ = ("cur", "t", "_gen", "_loop_t", "_pass_t", "_ann",
-                 "_round", "_trace_cls", "_step_cls", "rounds", "dry")
+                 "_round", "_trace_cls", "_step_cls", "rounds", "dry",
+                 "starved", "count", "total_ns", "buckets", "loop_ns",
+                 "_ref", "_older", "_busy_t", "_seen_lo", "_step_hi",
+                 "__weakref__")
 
     def __init__(self, trace_cls=None, step_cls=None):
         self.cur = -1               # the open phase; -1: none
@@ -296,16 +336,36 @@ class PhaseClock:
         self._step_cls = step_cls
         self.rounds = RoundLog()
         self.dry = True             # nothing has been queued yet
+        self.starved = False
+        self.count = [0] * _NPHASES
+        self.total_ns = [0] * _NPHASES
+        self.buckets = [[0] * NBUCKETS for _ in LM_STEP_PHASES]
+        # wall time of the loop, advanced once a pass by a clock read
+        # of its own: what the phases' totals have to add up to
+        self.loop_ns = 0
+        self._ref = None            # the last program's output, unseen
+        self._older = None          # an earlier one nothing consumed
+        self._busy_t = 0            # the last read that knew it running
+        self._seen_lo = 0           # the oldest step that may be unseen
+        self._step_hi = -1          # the last step queued
+        with _clocks_lock:
+            _clocks.add(self)
 
     def bind(self, trace_cls, step_cls) -> None:
         self._trace_cls = trace_cls
         self._step_cls = step_cls
 
+    def phase_counters(self) -> dict:
+        return dict(zip(LM_STEP_PHASES, self.count))
+
+    def phase_total_ns(self) -> dict:
+        return dict(zip(LM_STEP_PHASES, self.total_ns))
+
     def switch(self, idx: int) -> int:
         """Enter phase ``idx`` (-1: none), crediting the time since the
         last switch to the phase that was open; returns that phase.
         Lock-free: preallocated per-phase lists, an int bit_length for
-        the log2 bucket."""
+        the log2 bucket, at most one readiness test."""
         gen = _live[0]
         if not gen:
             return -1
@@ -318,17 +378,25 @@ class PhaseClock:
         if self._gen != gen:        # first switch, or off and on again:
             self._gen = gen         # phases and loop_ns start level
             self._loop_t = t
+            self._ref = self._older = None
+            self.starved = False
             cur = -1
         if cur >= 0:
             ns = t - self.t
-            _phase_buckets[cur][_log2_bucket(ns)] += 1
-            _phase_count[cur] += 1
+            self.buckets[cur][_log2_bucket(ns)] += 1
+            self.count[cur] += 1
             if ns > 0:
-                _phase_total_ns[cur] += ns
-                if self.dry:
-                    log = self.rounds
-                    log.dry_by_phase[cur] += ns
-                    log.dry_sum += ns
+                self.total_ns[cur] += ns
+                if self.dry or self.starved:
+                    self._idle(cur, ns)
+        ref = self._ref
+        if ref is not None:
+            if self._ready(ref):
+                self._finished(t, t, cur)
+                if not self.dry:
+                    self.starved = True
+            else:
+                self._busy_t = t
         self.cur = idx
         self.t = t
         if idx >= 0 and self._trace_cls is not None:
@@ -345,7 +413,7 @@ class PhaseClock:
             return
         self._pass_t = self.t
         t = _mono_ns()
-        _loop_ns[0] += t - self._loop_t
+        self.loop_ns += t - self._loop_t
         self._loop_t = t
 
     def round_begin(self, step: int) -> None:
@@ -374,27 +442,98 @@ class PhaseClock:
     # -- the step hooks: batcher thread only, ints into preallocated
     # lists, no lock; each entry-listed in tools/check/blocking.py ------
 
+    @staticmethod
+    def _ready(ref) -> bool:
+        """Has the program that made ``ref`` finished?  (The one
+        readiness test, by name: a test's fake device answers here.)"""
+        try:
+            return ref.is_ready()
+        except Exception:           # a later program consumed it
+            return False
+
+    def _idle(self, cur: int, ns: int) -> None:
+        """``ns`` of phase ``cur`` in which the device stood idle: to
+        the dry table while nothing was queued, else the starved one."""
+        log = self.rounds
+        if self.dry:
+            log.dry_by_phase[cur] += ns
+            log.dry_sum += ns
+        else:
+            log.starved_by_phase[cur] += ns
+            log.starved_sum += ns
+
+    def _finished(self, t: int, seen: int, cur: int) -> None:
+        """The last program queued (or the one a program just queued
+        consumed) ended at or before ``t`` and after ``_busy_t``: that
+        stretch of phase ``cur`` goes to the bracket, and every step
+        queued so far is seen at ``seen`` (0: not known)."""
+        self._ref = self._older = None
+        log = self.rounds
+        ns = t - self._busy_t
+        if cur >= 0 and ns > 0 and not self.dry:
+            log.bracket_by_phase[cur] += ns
+        if seen:
+            for o in range(self._seen_lo, self._step_hi + 1):
+                i = o % ROUND_RING
+                if log.ordinal[i] == o and not log.seen_ns[i]:
+                    log.seen_ns[i] = seen
+            self._seen_lo = self._step_hi + 1
+
+    def _behind(self, t: int, ref, wet: bool = True) -> None:
+        """A program was queued at ``t``: the one it followed is tested
+        once more.  Where a program consumed (donated) its output, an
+        earlier output still running says the device was busy; with
+        none, whether it ran out is not known and the stretch goes to
+        the bracket.  The idle stretch it ends is credited (a dry one
+        only where ``wet``); ``ref`` is followed from here."""
+        old = self._ref
+        if old is not None:
+            gone = _gone(old)
+            if not self.dry:
+                if gone:
+                    older = self._older
+                    if older is None or _gone(older) or self._ready(older):
+                        self._finished(t, 0, self.cur)
+                elif self._ready(old):
+                    self._finished(t, _mono_ns(), self.cur)
+            if not gone:
+                self._older = old
+        if self.starved or (wet and self.dry):
+            self._wet(t)
+        self._ref = ref
+        self._busy_t = t
+
     def _wet(self, t: int) -> None:
-        """A device program was queued at ``t`` with the device dry:
-        the open phase's time so far was dry, the rest is not."""
-        self.dry = False
+        """A device program was queued at ``t`` with the device idle
+        (dry or starved): the open phase's time so far was idle, the
+        rest is not."""
         ns = t - self.t
         if self.cur >= 0 and ns > 0:
-            log = self.rounds
-            log.dry_by_phase[self.cur] += ns
-            log.dry_sum += ns
+            self._idle(self.cur, ns)
+        if self.starved:
+            self.starved = False
+            self.rounds.starved_n += 1
+        self.dry = False
 
-    def filling(self, programs: int, rows: int) -> None:
+    def filling(self, programs: int, rows: int, ref=None) -> None:
         """Filling programs (a prefill, an insert, a span, a slice not
         on board) have just been queued, for ``rows`` context rows:
-        counted into the NEXT step's record."""
+        counted into the NEXT step's record.  ``ref``: an output of
+        the last of them."""
         if self._gen != _live[0]:
             return
         pend = self.rounds.pend
         pend[0] += programs
         pend[1] += rows
-        if self.dry:
-            self._wet(_mono_ns())
+        self._behind(_mono_ns(), ref)
+
+    def follow(self, ref) -> None:
+        """A program that fills nothing (a slot's length) has just been
+        queued: the probe follows its output.  It ends a starved
+        device, not a dry one (dry counts the filling programs and the
+        steps alone)."""
+        if self._gen == _live[0]:
+            self._behind(_mono_ns(), ref, wet=False)
 
     def joined(self) -> None:
         """A session was admitted into a slot."""
@@ -413,21 +552,21 @@ class PhaseClock:
         return _mono_ns() if self._gen == _live[0] else 0
 
     def queued(self, t: int, step: int, rows: int, ahead: bool,
-               ride_rows: int) -> int:
+               ride_rows: int, ref=None) -> int:
         """Step ``step`` was queued for the device at ``t``
-        (``stamp()``): open its record.  Returns the record's ordinal
-        for ``landed``, -1 where nothing was written."""
+        (``stamp()``), ``ref`` its tokens: open its record.  Returns
+        the record's ordinal for ``landed``, -1 where nothing was
+        written."""
         if not t:
             return -1
-        if self.dry:
-            self._wet(t)
+        self._behind(t, ref)
         log = self.rounds
         i = step % ROUND_RING
         pend = log.pend
         log.ordinal[i] = step
         log.pass_ns[i] = self._pass_t
         log.dispatch_ns[i] = t
-        log.wait_ns[i] = log.land_ns[i] = log.done_ns[i] = 0
+        log.seen_ns[i] = log.wait_ns[i] = log.land_ns[i] = log.done_ns[i] = 0
         log.pages[i] = log.touched[i] = log.gap_ns[i] = 0
         log.rows[i] = rows
         log.ahead[i] = int(ahead)
@@ -435,10 +574,15 @@ class PhaseClock:
         log.fill_programs[i], log.fill_rows[i], log.joins[i] = pend
         log.dry_ns[i] = log.dry_sum - log.dry_mark
         log.dry_mark = log.dry_sum
+        log.starved_ns[i] = log.starved_sum - log.starved_mark
+        log.starved_mark = log.starved_sum
         log.cls[i] = RC_RESTART if log.lull else RC_FILL if pend[0] \
             else RC_RIDE if ride_rows else RC_PLAIN
         log.lull = False
         pend[0] = pend[1] = pend[2] = 0
+        self._step_hi = step
+        if self._seen_lo < step - 1:    # only the step before can be
+            self._seen_lo = step - 1    # unseen and in flight
         return step
 
     def landed(self, t_wait: int, ordinal: int, behind: bool,
@@ -447,8 +591,9 @@ class PhaseClock:
         follows its wait, whose start (``t``) is the span's end;
         ``t_wait`` is where the wait began.  ``behind``: a later step
         is already queued.  Closes the record, counts it under its
-        class, and finds the device dry where nothing is queued behind
-        it."""
+        class (and as late where it was seen finished before its wait
+        began), and finds the device dry where nothing is queued
+        behind it."""
         if ordinal < 0 or self._gen != _live[0]:
             return
         log = self.rounds
@@ -459,6 +604,11 @@ class PhaseClock:
         wait = t - t_wait
         gap = t - log.prev_land if log.prev_ord == ordinal - 1 else 0
         log.prev_land, log.prev_ord = t, ordinal
+        seen = log.seen_ns[i]
+        if not seen:
+            log.seen_ns[i] = t
+        if self._seen_lo <= ordinal:
+            self._seen_lo = ordinal + 1
         log.wait_ns[i] = wait
         log.land_ns[i] = t
         log.pages[i] = pages
@@ -473,14 +623,18 @@ class PhaseClock:
         elif cls == RC_RIDE:
             log.cls_rows[cls] += log.ride_rows[i]
             log.cls_programs[cls] += 1
-        if wait < LATE_WAIT_NS:
+        if seen and seen <= t_wait:
             log.late[0] += 1
             log.late[1] += gap
         if cls != RC_RESTART and gap > log.max_gap[0]:
             log.max_gap[0], log.max_gap[1] = gap, ordinal
         log.landed_idx = i
         if not behind and not log.pend[0]:
+            # a starved spell that ends in a lull is no step's to carry
             self.dry = log.lull = True
+            self.starved = False
+            self._ref = self._older = None
+            log.starved_mark = log.starved_sum
 
     def delivered(self) -> None:
         """The landed step's tokens are written and its sessions
@@ -499,6 +653,20 @@ class PhaseClock:
         return log.ordinal[i] if i >= 0 else -1
 
 
+def _gone(ref) -> bool:
+    """A later program consumed (donated) ``ref``: it cannot be
+    tested."""
+    try:
+        return ref.is_deleted()
+    except Exception:
+        return True
+
+
+def _live_clocks() -> list:
+    with _clocks_lock:
+        return list(_clocks)
+
+
 def bucket_label(i: int, nbuckets: int = NBUCKETS) -> str:
     """Exclusive upper-bound label for log2 bucket i (the engine Hist
     convention — deliberately ``bin``, not Prometheus's cumulative
@@ -506,21 +674,39 @@ def bucket_label(i: int, nbuckets: int = NBUCKETS) -> str:
     return "+Inf" if i >= nbuckets - 1 else str(1 << i)
 
 
+def _summed(col: str) -> list:
+    out = [0] * _NPHASES
+    for c in _live_clocks():
+        for i, n in enumerate(getattr(c, col)):
+            out[i] += n
+    return out
+
+
 def phase_counters() -> dict:
-    return {p: _phase_count[i] for i, p in enumerate(LM_STEP_PHASES)}
+    """Samples by phase, summed over every live batcher's clock."""
+    return dict(zip(LM_STEP_PHASES, _summed("count")))
 
 
 def phase_total_ns() -> dict:
-    return {p: _phase_total_ns[i]
-            for i, p in enumerate(LM_STEP_PHASES)}
+    return dict(zip(LM_STEP_PHASES, _summed("total_ns")))
+
+
+def _phase_hists() -> list:
+    out = [[0] * NBUCKETS for _ in LM_STEP_PHASES]
+    for c in _live_clocks():
+        for i, hist in enumerate(c.buckets):
+            row = out[i]
+            for b, n in enumerate(hist):
+                row[b] += n
+    return out
 
 
 def phase_histogram(name: str) -> list:
-    return list(_phase_buckets[phase_index(name)])
+    return _phase_hists()[phase_index(name)]
 
 
 def loop_ns() -> int:
-    return _loop_ns[0]
+    return sum(c.loop_ns for c in _live_clocks())
 
 
 # ---------------------------------------------------------------------------
@@ -867,8 +1053,7 @@ class LmTelemetryCache:
             "phase_ns": phase_total_ns(),
             "loop_ns": loop_ns(),
             "queue": queue_counters(),
-            "phase_hists": {p: list(_phase_buckets[i])
-                            for i, p in enumerate(LM_STEP_PHASES)},
+            "phase_hists": dict(zip(LM_STEP_PHASES, _phase_hists())),
             "sched": sched_counters(),
             "prefix_events": prefix,
             "slo": slo_counters(),
@@ -965,8 +1150,8 @@ _phase_var = PassiveDimension(("phase",), phase_counters,
 
 def _phase_bucket_rows() -> dict:
     out = {}
-    for i, p in enumerate(LM_STEP_PHASES):
-        for b, c in enumerate(_phase_buckets[i]):
+    for p, hist in zip(LM_STEP_PHASES, _phase_hists()):
+        for b, c in enumerate(hist):
             if c:
                 out[(p, bucket_label(b))] = c
     return out
@@ -1011,12 +1196,13 @@ def expose_lm_variables() -> None:
 
 def _reset_for_tests(ring: Optional[int] = None) -> None:
     global _ring, _cache
-    for i in range(_NPHASES):
-        _phase_count[i] = 0
-        _phase_total_ns[i] = 0
-        for b in range(NBUCKETS):
-            _phase_buckets[i][b] = 0
-    _loop_ns[0] = 0
+    for c in _live_clocks():
+        for i in range(_NPHASES):
+            c.count[i] = 0
+            c.total_ns[i] = 0
+            for b in range(NBUCKETS):
+                c.buckets[i][b] = 0
+        c.loop_ns = 0
     _queue_wait_ns[0] = 0
     _admitted[0] = 0
     _slo_table()

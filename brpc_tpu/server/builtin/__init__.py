@@ -481,12 +481,19 @@ def _lm_rounds(bat) -> dict:
                          "mean_gap_ms": round(gap / n / 1e6, 3) if n else 0}
     log = bat.round_log()
     shown = ("ordinal", "cls", "gap_ns", "wait_ns", "rows", "ahead",
-             "fill_programs", "fill_rows", "ride_rows", "joins", "dry_ns")
+             "fill_programs", "fill_rows", "ride_rows", "joins", "dry_ns",
+             "starved_ns")
+    # the device's idle time by the phase the host was in: dry (nothing
+    # queued), starved (the last program seen finished, the next not
+    # queued) and the bracket of those finishes; for an operator with
+    # no profiler running
+    tables = ("dry_ns", "starved_ns", "starved_bracket_ns")
     return {
         "window_s": round(dt, 3),
         "window": window,
-        "total": {k: v for k, v in cur.items() if k != "dry_ns"},
-        "dry_ns": cur["dry_ns"],
+        "total": {k: v for k, v in cur.items()
+                  if k not in tables and k != "clock"},
+        **{k: cur[k] for k in tables},
         "last": log[-32:],
         "widest": [{k: r[k] for k in shown} for r in sorted(
             (r for r in log if r["cls"] != "restart"),
@@ -501,7 +508,8 @@ def _lm(server, msg, rest):
     loop by phase (histograms, totals, and how much of ``loop_ns`` they
     account for), the queue counters, the decode steps by what stood in
     front of them (``rounds``: class rates and mean gaps over the window,
-    the device's dry time by phase, the last records and the widest
+    the device's dry and starved time by phase with the starved time's
+    bracket, the last records and the widest
     gaps of the batcher's ring), KV pool / state pool / prefix cache
     / host tier occupancy, the model as ``LM.Info`` gives it (with its
     layer schedule), and the WINDOWED

@@ -127,6 +127,7 @@ ENTRY_POINTS: Tuple[Tuple[str, Tuple[str, ...]], ...] = (
     # the step hooks (ISSUE 35): one record a decode step, written by
     # the same thread into the clock's preallocated RoundLog
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "filling")),
+    ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "follow")),
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "joined")),
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "filled")),
     ("brpc_tpu/models/lm_telemetry.py", ("PhaseClock", "stamp")),

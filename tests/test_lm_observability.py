@@ -771,10 +771,18 @@ def test_lm_portal_and_metrics_exposition():
         assert rounds["total"]["restart"]["n"] == 1
         assert rounds["total"]["plain"]["n"] == 3
         assert set(rounds["total"]) == {"restart", "fill", "ride", "plain",
-                                        "late", "max_gap_ns", "max_ordinal"}
+                                        "late", "max_gap_ns", "max_ordinal",
+                                        "starved"}
         # a second read inside the cache's interval has no new window
         assert set(rounds["window"]) <= set(rounds["total"])
         assert set(rounds["dry_ns"]) == set(LM_STEP_PHASE_PINS)
+        # beside the dry table, by phase: the starved time and the
+        # bracket of the finishes the probe saw (no profiler needed)
+        assert set(rounds["starved_ns"]) == set(LM_STEP_PHASE_PINS)
+        assert set(rounds["starved_bracket_ns"]) == set(LM_STEP_PHASE_PINS)
+        assert rounds["total"]["starved"]["n"] >= 0
+        assert all("seen_ns" in r and "starved_ns" in r
+                   for r in rounds["last"])
         assert recent[0]["first_round"] == 0
         assert recent[0]["worst_round"] in (1, 2, 3)
         assert recent[0]["worst_round"] \

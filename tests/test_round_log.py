@@ -52,24 +52,35 @@ def level():
 class FakeDevice:
     """Programs run in the order they were queued: a step for
     ``STEP_S`` (``RIDE_S`` more with a slice on board), a filling
-    program for ``FILL_S``.  It listens where the batcher says it has
-    queued one, and ``read`` returns a step's tokens when it is done."""
+    program for ``FILL_S``, a slot's length in no time.  It listens
+    where the batcher says it has queued one, ``read`` returns a
+    step's tokens when it is done, and the clock's readiness test is
+    answered from when the program that made the output is done
+    (``free_at`` as it was queued)."""
 
     def __init__(self, monkeypatch, step_s=STEP_S):
         self.step_s = step_s
         self.free_at = 0.0          # when everything queued is done
         self.done = deque()         # of the steps not yet read
+        self.done_at = {}           # id(ref) -> (ref, when it is done)
+        self.asked = 0              # readiness tests
         filling, queued = lmt.PhaseClock.filling, lmt.PhaseClock.queued
+        follow = lmt.PhaseClock.follow
         real = ContinuousBatcher._read_tokens
 
-        def on_filling(clock, programs, rows):
-            self.run(FILL_S * programs)
-            filling(clock, programs, rows)
+        def on_filling(clock, programs, rows, ref=None):
+            self.note(ref, self.run(FILL_S * programs))
+            filling(clock, programs, rows, ref)
 
-        def on_queued(clock, t, step, rows, ahead, ride_rows):
-            self.done.append(self.run(
-                self.step_s + (RIDE_S if ride_rows else 0.0)))
-            return queued(clock, t, step, rows, ahead, ride_rows)
+        def on_follow(clock, ref):
+            self.note(ref, self.run(0.0))
+            follow(clock, ref)
+
+        def on_queued(clock, t, step, rows, ahead, ride_rows, ref=None):
+            at = self.run(self.step_s + (RIDE_S if ride_rows else 0.0))
+            self.done.append(at)
+            self.note(ref, at)
+            return queued(clock, t, step, rows, ahead, ride_rows, ref)
 
         def read(toks):
             wait = self.done.popleft() - time.monotonic()
@@ -77,14 +88,24 @@ class FakeDevice:
                 time.sleep(wait)
             return real(toks)
 
+        def ready(ref):
+            self.asked += 1
+            return time.monotonic() >= self.done_at[id(ref)][1]
+
         monkeypatch.setattr(lmt.PhaseClock, "filling", on_filling)
+        monkeypatch.setattr(lmt.PhaseClock, "follow", on_follow)
         monkeypatch.setattr(lmt.PhaseClock, "queued", on_queued)
+        monkeypatch.setattr(lmt.PhaseClock, "_ready", staticmethod(ready))
         monkeypatch.setattr(ContinuousBatcher, "_read_tokens",
                             staticmethod(read))
 
     def run(self, seconds):
         self.free_at = max(self.free_at, time.monotonic()) + seconds
         return self.free_at
+
+    def note(self, ref, at):
+        if ref is not None:
+            self.done_at[id(ref)] = (ref, at)
 
 
 class Watching(_Stream):
@@ -197,9 +218,17 @@ def test_scripted_run_lands_each_step_in_its_class(model, level,
     assert (fill["fill_rows"], fill["joins"], fill["ahead"]) == (59, 1, 1)
     for x in log.values():
         assert x["pass_ns"] < x["dispatch_ns"] < x["land_ns"] <= x["done_ns"]
+        # known finished no later than its tokens were on the host
+        assert x["dispatch_ns"] < x["seen_ns"] <= x["land_ns"]
         assert 0 <= x["wait_ns"] <= x["land_ns"] - x["dispatch_ns"] + 1e6
         assert x["pages"] > 0 and x["touched"] == 0
     assert all(x["dry_ns"] == 0 for x in by_cls["plain"])
+    # a slow device and a quick host: the device is never seen starved
+    # for long (a step queued behind one that finished while the host
+    # walked, wrote and dispatched holds the walk's time at the most)
+    starved = sum(r["starved_ns"][p] - r0["starved_ns"][p]
+                  for p in r["starved_ns"])
+    assert starved <= steps * SLACK_S * 1e9
     # the sessions: which step made the first token, which the widest gap
     recs = {x["max_new"]: x for x in lmt.timeline_records()}
     assert recs[60]["first_round"] == steps0
@@ -222,14 +251,19 @@ def test_scripted_run_lands_each_step_in_its_class(model, level,
 def test_a_step_the_device_finished_before_the_host_asked_is_late(
         model, level, monkeypatch):
     """A host that takes 30 ms to write a step's tokens beside a device
-    that needs 2 ms a step: from the second step on each step has
-    landed before the host asks for it, its wait is under
-    ``LATE_WAIT_NS``, and the gap it ended is the host's."""
+    that needs 2 ms a step: from the second step on, the probe sees
+    each step finished before the host asks for it (``late``, from the
+    records' ``seen_ns``, not from how long the wait took), and the
+    gap it ended is the host's.  The device is not dry (the next step
+    is queued before the last is read): it is STARVED, and the write
+    is the phase in which it ran out: its 30 ms, of which 28 idle,
+    are the bracket under ``stream_emit``; the idle time after it, to
+    the next step's queueing, is starved time."""
     cfg, params = model
     bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.2)
     _warm(bat)
-    FakeDevice(monkeypatch, step_s=0.002)
+    dev = FakeDevice(monkeypatch, step_s=0.002)
 
     class Slow(_Stream):
         def write(self, data):
@@ -237,6 +271,7 @@ def test_a_step_the_device_finished_before_the_host_asked_is_late(
             return super().write(data)
 
     r0 = bat.kv_stats()["rounds"]
+    steps0 = bat.steps_run()
     st = Slow()
     bat.join(st, _prompt(21, 10), 12)
     _finish(st)
@@ -245,15 +280,28 @@ def test_a_step_the_device_finished_before_the_host_asked_is_late(
     late = r["late"]["n"] - r0["late"]["n"]
     assert late >= 9                            # of 12: not the first
     assert r["late"]["gap_ns"] - r0["late"]["gap_ns"] >= late * 0.029e9
-    waits = [x["wait_ns"] for x in bat.round_log()[-10:]]
-    assert sorted(waits)[len(waits) // 2] < lmt.LATE_WAIT_NS
+    log = bat.round_log(since=steps0)
+    assert len(log) == 12 and dev.asked > 0
+    # late is the probe's: seen finished before its wait began
+    assert sum(x["seen_ns"] <= x["land_ns"] - x["wait_ns"]
+               for x in log) == late
     # a late host is no dry device: the next step was queued
-    assert all(x["dry_ns"] == 0 for x in bat.round_log()[-10:])
+    assert all(x["dry_ns"] == 0 for x in log[1:])
+    grew = {k: {p: r[k][p] - r0[k][p] for p in lmt.LM_STEP_PHASES}
+            for k in ("dry_ns", "starved_ns", "starved_bracket_ns")}
+    assert grew["starved_bracket_ns"]["stream_emit"] \
+        >= (0.030 - 0.002) * (late - 1) * 1e9
+    starved = sum(grew["starved_ns"].values())
+    assert starved > 0 and r["starved"]["n"] - r0["starved"]["n"] >= late - 1
+    # each step queued onto a starved device carries what it ended
+    # (the last one's starved time, ended by its landing, is no step's)
+    assert 0 < sum(x["starved_ns"] for x in log) <= starved
+    assert sum(x["starved_ns"] > 0 for x in log) >= late - 1
 
 
 # -- the clock alone ----------------------------------------------------------
 
-def _one_step(clock, step, behind=True, ride_rows=0, fills=()):
+def _one_step(clock, step, behind=True, ride_rows=0, fills=(), ref=None):
     """One pass of the loop's shape around the clock: top, the fills,
     dispatch, land, deliver."""
     clock.switch(lmt.PH_SCHED)
@@ -262,7 +310,8 @@ def _one_step(clock, step, behind=True, ride_rows=0, fills=()):
         clock.switch(lmt.PH_PREFILL_DISPATCH)
         clock.filling(programs, rows)
     clock.switch(lmt.PH_STEP_DISPATCH)
-    ordinal = clock.queued(clock.stamp(), step, 1, step > 0, ride_rows)
+    ordinal = clock.queued(clock.stamp(), step, 1, step > 0, ride_rows,
+                           ref)
     clock.switch(lmt.PH_DEVICE_WAIT)
     t_wait = clock.t
     clock.switch(lmt.PH_TOKEN_WALK)
@@ -364,10 +413,210 @@ def test_dry_time_is_credited_by_phase_until_a_program_is_queued(level):
     assert clock.dry and clock.rounds.lull
 
 
-def test_nothing_is_written_with_telemetry_off(model, level):
+class Ref:
+    """An output of a program the clock follows, done at ``at`` (the
+    monotonic clock, in seconds); ``gone``: a later program consumed
+    it."""
+
+    def __init__(self, at=0.0, gone=False):
+        self.at, self.gone = at, gone
+
+    def is_ready(self):
+        return time.monotonic() >= self.at
+
+    def is_deleted(self):
+        return self.gone
+
+
+def _ms(ns):
+    return ns / 1e6
+
+
+def test_a_lull_is_dry_and_not_starved(level):
+    """The last step lands with nothing queued behind it: the time
+    until the next program goes to ``dry_ns`` alone, although the probe
+    had seen the step finish while the host still waited for it."""
+    clock = lmt.PhaseClock()
+    _one_step(clock, 0, behind=False, ref=Ref())
+    c0 = clock.rounds.counters()
+    clock.switch(lmt.PH_IDLE_WAIT)
+    time.sleep(0.02)
+    clock.switch(lmt.PH_SCHED)
+    clock.tick()
+    clock.switch(lmt.PH_PREFILL_DISPATCH)
+    clock.filling(1, 5, Ref(time.monotonic() + 3600))
+    clock.switch(lmt.PH_STEP_DISPATCH)
+    c = clock.rounds.counters()
+    assert c["dry_ns"]["idle_wait"] - c0["dry_ns"]["idle_wait"] >= 20e6
+    assert c["starved_ns"] == c0["starved_ns"]
+    assert c["starved"]["n"] == 0
+    assert clock.dry is False and clock.starved is False
+    # the host waited for a step that had already finished (starved in
+    # its wait), then the lull: the next step carries the lull as dry
+    # time and the starved spell that ended in it as nobody's
+    assert sum(c0["starved_ns"].values()) > 0
+    clock.queued(clock.stamp(), 1, 1, False, 0, Ref(time.monotonic() + 9))
+    rec = clock.rounds.records(since=1)[0]
+    assert rec["starved_ns"] == 0 and rec["dry_ns"] >= 20e6
+
+
+def test_a_fill_queued_behind_a_finished_step_ends_the_starvation(level):
+    """A step's tokens are seen ready at a switch with nothing queued
+    behind them: the device is starved from that clock read; the
+    filling program queued next ends it and its output is what the
+    probe follows; the step after carries the starved time it ended;
+    the phase in which the step finished is its bracket."""
+    clock = lmt.PhaseClock()
+    clock.switch(lmt.PH_SCHED)
+    clock.tick()
+    clock.switch(lmt.PH_STEP_DISPATCH)
+    done = time.monotonic() + 0.010
+    clock.queued(clock.stamp(), 0, 1, False, 0, Ref(done))
+    clock.switch(lmt.PH_DEVICE_WAIT)        # running: not seen
+    assert clock._ref is not None and not clock.starved
+    time.sleep(0.015)                       # it finishes in here
+    clock.switch(lmt.PH_PAGE_ALLOC)         # seen: starved from here
+    assert clock.starved and clock._ref is None
+    time.sleep(0.010)
+    clock.switch(lmt.PH_PREFILL_DISPATCH)
+    time.sleep(0.005)
+    fill = Ref(time.monotonic() + 3600)
+    clock.filling(1, 9, fill)
+    assert not clock.starved and clock._ref is fill
+    time.sleep(0.005)
+    clock.switch(lmt.PH_INSERT_DISPATCH)
+    c = clock.rounds.counters()
+    starved, bracket = c["starved_ns"], c["starved_bracket_ns"]
+    assert starved["page_alloc"] >= 10e6
+    assert 5e6 <= starved["prefill_dispatch"] < 10e6
+    assert starved["device_wait"] == starved["insert_dispatch"] == 0
+    assert bracket["device_wait"] >= 15e6
+    assert sum(bracket.values()) == bracket["device_wait"]
+    assert c["starved"]["n"] == 1
+    rec = clock.rounds.records()[0]
+    # dry before the first step only: starved is no dry time
+    assert sum(c["dry_ns"].values()) == rec["dry_ns"]
+    assert 0 < rec["seen_ns"] == clock.rounds.seen_ns[0]
+    clock.switch(lmt.PH_STEP_DISPATCH)
+    clock.queued(clock.stamp(), 1, 1, True, 0, Ref(time.monotonic() + 3600))
+    rec = clock.rounds.records(since=1)[0]
+    assert rec["starved_ns"] == sum(starved.values())
+
+
+@pytest.mark.parametrize("finish_ms", [3, 12, 30])
+def test_dry_plus_starved_and_the_bracket_hold_the_idle_time(
+        level, finish_ms):
+    """A program that finishes inside some phase: the idle time from
+    its end to the next program's queueing lies between the starved
+    time and the starved time plus the bracket, and the bracket is the
+    phase in which it finished.  A program still running when the
+    next one is queued leaves neither."""
+    clock = lmt.PhaseClock()
+    clock.switch(lmt.PH_SCHED)
+    clock.tick()
+    clock.switch(lmt.PH_STEP_DISPATCH)
+    t0 = time.monotonic_ns()
+    finish = t0 + finish_ms * 1_000_000
+    clock.queued(clock.stamp(), 0, 1, False, 0, Ref(finish / 1e9))
+    starts = [(lmt.PH_STEP_DISPATCH, 0)]
+    for idx in (lmt.PH_CHUNK_SLICE, lmt.PH_DEVICE_WAIT, lmt.PH_TOKEN_WALK,
+                lmt.PH_STREAM_EMIT):
+        time.sleep(0.005)
+        clock.switch(idx)
+        starts.append((idx, clock.t))
+    time.sleep(0.002)
+    nxt = clock.stamp()
+    clock.queued(nxt, 1, 1, True, 0, Ref(time.monotonic() + 3600))
+    c = clock.rounds.counters()
+    starved = sum(c["starved_ns"].values())
+    bracket = c["starved_bracket_ns"]
+    idle = max(0, nxt - finish)
+    assert starved <= idle <= starved + sum(bracket.values())
+    if finish >= nxt:
+        assert starved == sum(bracket.values()) == 0
+        assert c["starved"]["n"] == 0
+        return
+    inside = [idx for idx, t in starts if t <= finish][-1]
+    assert {p for p, ns in bracket.items() if ns} \
+        == {lmt.LM_STEP_PHASES[inside]}
+    assert c["starved"]["n"] == 1
+    # the step is known finished no later than the switch after it
+    # finished: an upper bound of its end, on the host's clock
+    seen = clock.rounds.records()[0]["seen_ns"]
+    assert finish <= seen <= nxt
+
+
+@pytest.mark.parametrize("ready_at_wait", [True, False])
+def test_late_is_what_the_probe_saw_before_the_wait(level, ready_at_wait):
+    """A step is late where it was seen finished at or before the
+    switch that began its ``device_wait``, however long the wait took
+    (a host preempted inside a wait of a step that was running is not
+    late)."""
+    clock = lmt.PhaseClock()
+    clock.switch(lmt.PH_SCHED)
+    clock.tick()
+    clock.switch(lmt.PH_STEP_DISPATCH)
+    done = time.monotonic() + (0.0 if ready_at_wait else 0.005)
+    ordinal = clock.queued(clock.stamp(), 0, 1, False, 0, Ref(done))
+    clock.switch(lmt.PH_DEVICE_WAIT)
+    t_wait = clock.t
+    if not ready_at_wait:
+        time.sleep(0.010)
+    clock.switch(lmt.PH_TOKEN_WALK)
+    clock.landed(t_wait, ordinal, False, 1, 0)
+    rec = clock.rounds.records()[0]
+    assert clock.rounds.counters()["late"]["n"] == int(ready_at_wait)
+    assert (rec["seen_ns"] == t_wait) == ready_at_wait
+    assert rec["seen_ns"] <= rec["land_ns"]
+
+
+def test_an_output_a_later_program_consumed_brackets_its_stretch(level):
+    """A program consumed (donated) the output the probe followed
+    before it could be tested again: whether the device ran out in
+    between is not known, so the stretch from the last read that saw
+    it running to the new program goes to the bracket, and nothing to
+    the starved table."""
+    clock = lmt.PhaseClock()
+    clock.switch(lmt.PH_SCHED)
+    clock.tick()
+    clock.switch(lmt.PH_INSERT_DISPATCH)
+    old = Ref(time.monotonic() + 3600)
+    clock.filling(1, 0, old)
+    time.sleep(0.004)
+    old.gone = True
+    clock.follow(Ref(time.monotonic() + 3600))
+    c = clock.rounds.counters()
+    assert c["starved_bracket_ns"]["insert_dispatch"] >= 4e6
+    assert sum(c["starved_ns"].values()) == 0
+    # ... but an earlier output still running says the device was busy
+    # (a prefill, whose insert the slot's length consumed)
+    clock.switch(lmt.PH_PREFILL_DISPATCH)
+    clock.filling(1, 9, Ref(time.monotonic() + 3600))
+    clock.switch(lmt.PH_INSERT_DISPATCH)
+    insert = Ref(time.monotonic() + 3600)
+    clock.filling(1, 0, insert)
+    time.sleep(0.004)
+    insert.gone = True
+    clock.follow(Ref(time.monotonic() + 3600))
+    assert clock.rounds.counters()["starved_bracket_ns"] \
+        == c["starved_bracket_ns"]
+    # a slot's length does not end a dry spell
+    dry = lmt.PhaseClock()
+    dry.switch(lmt.PH_INSERT_DISPATCH)
+    dry.follow(Ref())
+    assert dry.dry
+
+
+def test_nothing_is_written_with_telemetry_off(model, level, monkeypatch):
+    """With the gate off nothing is written and the readiness probe is
+    never called; on again, it is."""
     cfg, params = model
     bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE,
                             idle_linger_s=0.2)
+    asked = []
+    ready = lmt.PhaseClock._ready
+    monkeypatch.setattr(lmt.PhaseClock, "_ready", staticmethod(
+        lambda ref: asked.append(ref) or ready(ref)))
     assert set_flag("lm_telemetry", "false")
     try:
         st = _join(bat, _prompt(31, 9), 5)
@@ -376,10 +625,16 @@ def test_nothing_is_written_with_telemetry_off(model, level):
         assert bat.steps_run() == 5
         kv = bat.kv_stats()
         zero = lmt.RoundLog()
-        assert kv["rounds"] == zero.counters()
+
+        def level_(c):
+            return {k: v for k, v in c.items() if k != "clock"}
+
+        assert level_(kv["rounds"]) == level_(zero.counters())
         assert kv["first"] == zero.first_counters()
         assert bat.round_log() == []
         assert bat._clock.t == 0 and bat._clock.rounds.pend == [0, 0, 0]
+        assert bat._clock._ref is None and asked == []
+        assert kv["loop_ns"] == 0 and not any(kv["phase_ns"].values())
     finally:
         assert set_flag("lm_telemetry", "true")
     # on again: the next step opens the log, at the batcher's own count
@@ -388,6 +643,7 @@ def test_nothing_is_written_with_telemetry_off(model, level):
     _wait(lambda: bat._thread is None, "the batcher never lingered out")
     assert [x["ordinal"] for x in bat.round_log()] == [5, 6, 7, 8]
     assert bat.kv_stats()["first"]["n"] == 1
+    assert asked
 
 
 def test_two_batchers_keep_two_logs(model, level):
@@ -399,11 +655,32 @@ def test_two_batchers_keep_two_logs(model, level):
     _finish(_join(a, _prompt(41, 9), 6), _join(b, _prompt(42, 9), 3))
     _wait(lambda: a._thread is None and b._thread is None,
           "a batcher never lingered out")
+    kvs = []
     for bat, steps in ((a, 6), (b, 3)):
-        r = bat.kv_stats()["rounds"]
+        kv = bat.kv_stats()
+        r = kv["rounds"]
         assert sum(r[cls]["n"] for cls in lmt.LM_ROUND_CLASSES) == steps
         assert len(bat.round_log()) == steps
-        assert bat.kv_stats()["first"]["n"] == 1
+        assert kv["first"]["n"] == 1
+        # the phase tables are the batcher's own too: one wait a step,
+        # its idle time within its own loop's
+        assert kv["phases"]["device_wait"] == steps
+        assert 0 < sum(kv["phase_ns"].values()) <= kv["loop_ns"]
+        assert set(r["starved_ns"]) == set(lmt.LM_STEP_PHASES)
+        assert sum(r["dry_ns"].values()) + sum(r["starved_ns"].values()) \
+            <= kv["loop_ns"]
+        for p, ns in r["starved_ns"].items():
+            assert ns <= kv["phase_ns"][p], p
+        kvs.append(kv)
+    assert a._clock.count is not b._clock.count
+    assert a._clock.rounds.starved_by_phase \
+        is not b._clock.rounds.starved_by_phase
+    # the process's rows are their sums
+    assert lmt.loop_ns() == kvs[0]["loop_ns"] + kvs[1]["loop_ns"]
+    assert lmt.phase_counters()["device_wait"] == 9
+    assert lmt.phase_total_ns() == {
+        p: kvs[0]["phase_ns"][p] + kvs[1]["phase_ns"][p]
+        for p in lmt.LM_STEP_PHASES}
 
 
 # -- the real profiler, on the CPU --------------------------------------------
@@ -444,6 +721,18 @@ def test_a_records_ordinal_is_its_rounds_step_num_in_a_trace(
         if e.name == lmt.ROUND_TRACE_NAME)
     log = bat.round_log(since=steps0)
     assert [x["ordinal"] for x in log] == list(range(steps0, steps0 + 6))
+    # the clock pair puts a record on the trace's clock (whose events
+    # count from the session's ``profile_start_time``): each pass's
+    # ``lm_round`` opens a little after the switch that started it
+    clock = bat.kv_stats()["rounds"]["clock"]
+    start = [dict(p.stats)["profile_start_time"] for p in data.planes
+             if "profile_start_time" in dict(p.stats)]
+    assert len(start) == 1
+    shift = clock["trace_ns"] - clock["mono_ns"] - start[0]
+    starts = {n: s for s, _e, n in rounds}
+    lead = sorted(starts[x["ordinal"]] - (x["pass_ns"] + shift)
+                  for x in log)
+    assert -2e6 < lead[len(lead) // 2] < 2e6, lead
     # six passes dispatch a step; the seventh lands the last and
     # carries the number the next step would have had
     assert [r[2] for r in rounds] == [x["ordinal"] for x in log] \
@@ -519,10 +808,11 @@ def test_the_new_metrics_name_counters_the_program_has(model):
     run.due_in_window = lambda: []
     files = [f for stem in ("join_stall_ms", "join_round_share",
                             "ride_stall_ms", "dry_share", "dry_host_share",
-                            "late_step_share", "first_device_ms")
+                            "late_step_share", "first_device_ms",
+                            "starved_share")
              for f in glob.glob(os.path.join(
                  spec.BENCH_DIR, "metrics", f"batcher.*_{stem}.json"))]
-    assert len(files) == 27
+    assert len(files) == 32
     # two phases left with speculative decoding (PR 42).  One file that
     # no entry of BENCHMARK.json registers still sums them by name; the
     # `benchmark` PR that registers it takes them out (ROADMAP S0)
@@ -557,6 +847,9 @@ LM_TEL = "brpc_tpu/models/lm_telemetry.py"
     ("queued", "        log.dispatch_ns[i] = t\n"),
     ("landed", "        log.landed_idx = i\n"),
     ("delivered", "                log.done_ns[i] = _mono_ns()\n"),
+    ("follow", "            self._behind(_mono_ns(), ref, wet=False)\n"),
+    ("queued", "        log.seen_ns[i] = log.wait_ns[i] = log.land_ns[i]"),
+    ("switch", "                self._busy_t = t\n"),
 ])
 def test_a_lock_in_a_step_hook_is_caught(hook, anchor):
     """Every hook that writes the step log runs inside the batcher's

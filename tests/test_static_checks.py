@@ -457,9 +457,9 @@ def test_drift_lock_in_step_loop_profiler():
     ZERO-locks hot-path contract is linter-enforced, not
     reviewed-by-hope."""
     LM_TEL = "brpc_tpu/models/lm_telemetry.py"
-    ov = _mutate(LM_TEL, "            _phase_count[cur] += 1",
+    ov = _mutate(LM_TEL, "            self.count[cur] += 1",
                  "            _obs_lock.acquire()\n"
-                 "            _phase_count[cur] += 1")
+                 "            self.count[cur] += 1")
     ov[LM_TEL] = ov[LM_TEL].replace(
         '_live = [1 if get_flag("lm_telemetry", True) else 0]',
         "_obs_lock = threading.Lock()\n"
