@@ -36,8 +36,8 @@ from .lm_telemetry import (PH_CATCHUP_SLICE, PH_CHUNK_SLICE,
                            PH_SCHED, PH_STEP_DISPATCH, PH_STREAM_EMIT,
                            PH_TOKEN_WALK)
 from . import mla_mixer
-from .transformer_lm import (LMConfig, UnsupportedBlock, init_params,
-                             latent_row_bytes, paged_page_bytes,
+from .transformer_lm import (LMConfig, UnsupportedBlock, attention_forms,
+                             init_params, latent_row_bytes, paged_page_bytes,
                              require_plain_block, state_kinds,
                              state_slot_bytes)
 
@@ -715,7 +715,9 @@ class ContinuousBatcher:
                              "slices": self._slices,
                              "slices_rode": self._slices_rode},
                "attn": {"pages_read": self._attn_pages_read,
-                        "pages_table": self._attn_pages_table},
+                        "pages_table": self._attn_pages_table,
+                        # layers by the form of their decode kernel
+                        "forms": attention_forms(self.cfg)},
                # a slot's block of the state pool (no bytes where the
                # schedule has no state layer)
                "state": {"slots": self.slots,
@@ -2066,7 +2068,8 @@ class LMService(Service):
                 "heads": c.heads, "depth": c.depth,
                 "max_seq": c.max_seq,
                 "quantized": self.quantized,
-                "param_bytes": self._param_bytes}
+                "param_bytes": self._param_bytes,
+                "attention": {"forms": attention_forms(c)}}
         if not c.plain_block():
             info.update(
                 kv_heads=c.kv_heads, ffn=c.ffn, ffn_dim=c.ffn_dim,

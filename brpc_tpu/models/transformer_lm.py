@@ -1582,6 +1582,20 @@ def state_kinds(cfg: LMConfig) -> Dict[str, Dict[str, int]]:
     return kinds
 
 
+def attention_forms(cfg: LMConfig) -> Dict[str, int]:
+    """The decode step's attention layers by the form they run
+    (``paged_attention.decode_form`` of their pools' layout), each with
+    the number of layers that run it."""
+    from ..ops.paged_attention import decode_form
+
+    forms: Dict[str, int] = {}
+    layout = "grouped" if cfg.kv_heads != cfg.heads else "whole"
+    for form in ([decode_form(layout, cfg.has_window)] * len(cfg.attn_layers())
+                 + [decode_form("latent")] * len(cfg.mla_layers())):
+        forms[form] = forms.get(form, 0) + 1
+    return forms
+
+
 def state_slot_bytes(cfg: LMConfig) -> int:
     """Device bytes one SLOT pins across every state layer's pool."""
     return sum(k["slot_bytes"] for k in state_kinds(cfg).values())

@@ -21,8 +21,8 @@ The arithmetic is float32 throughout, as XLA runs the einsums of
 
 Where fewer key/value heads serve groups of query heads the pool is
 ``(num_pages, page * kv_heads, hd)``, a row a (token, key/value head)
-pair, and a second kernel under the same name walks it
-(:func:`_grouped_kernel`).
+pair, and the window kernel walks it with no window, under the same
+name, its products float32 at ``highest`` (:func:`_window_kernel`).
 """
 
 from __future__ import annotations
@@ -215,163 +215,39 @@ def paged_decode_attention(q, pk, pv, bt, pos,
                        interpret=_resolve_interpret(interpret))
 
 
-def _grouped_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
-                    kbuf, vbuf, sems, *, page: int, kv_heads: int,
-                    wave: int, scale: Optional[float]):
-    """Key/value heads shared by groups of query heads: a wave's rows
-    are (token, key/value head) pairs on the sublanes, so a group's
-    ``(g, hd)`` queries meet one head's ``(tokens, hd)`` keys as the
-    two matrices they are, on the MXU in float32 (``highest``: the
-    arithmetic of :func:`reference`).  Same walk over live
-    pages, same double buffer, same online softmax as :func:`_kernel`."""
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    slots, heads, hd = q_ref.shape
-    g = heads // kv_heads
-    toks = wave * page
-    rows = page * kv_heads
-    scale = 1.0 / (hd ** 0.5) if scale is None else scale
-    hi = lax.Precision.HIGHEST
-
-    def n_pages(b):
-        return pos_ref[b] // page + 1
-
-    def wave_dma(b, w, buf, go):
-        for i in range(wave):
-            idx = w * wave + i
-
-            @pl.when(idx < n_pages(b))
-            def _():
-                pid = bt_ref[b, idx]
-                dst = pl.ds(i * rows, rows)
-                for hbm, vmem, j in ((pk_hbm, kbuf, 0), (pv_hbm, vbuf, 1)):
-                    go(pltpu.make_async_copy(
-                        hbm.at[pid], vmem.at[buf, dst], sems.at[j, buf]))
-
-    start = functools.partial(wave_dma, go=lambda c: c.start())
-    wait = functools.partial(wave_dma, go=lambda c: c.wait())
-
-    tok_col = lax.broadcasted_iota(jnp.int32, (toks, 1), 0)
-    tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
-
-    start(0, 0, 0)
-
-    def slot_body(b, gcount):
-        n_waves = (n_pages(b) + wave - 1) // wave
-        q = q_ref[b] * scale                              # (heads, hd)
-
-        def wave_body(w, carry):
-            gcount, m, l, acc = carry
-            buf = lax.rem(gcount, 2)
-
-            @pl.when(w + 1 < n_waves)
-            def _():
-                start(b, w + 1, 1 - buf)
-
-            @pl.when(jnp.logical_and(w + 1 == n_waves, b + 1 < slots))
-            def _():
-                start(b + 1, 0, 1 - buf)
-
-            wait(b, w, buf)
-            lim = pos_ref[b] + 1 - w * toks
-            ms, ls, accs = [], [], []
-            for kh in range(kv_heads):
-                sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
-                    else pl.ds(0, toks)
-                k = kbuf[buf, sel, :]                     # (toks, hd)
-                # rows past pos are stale or were never fetched: they
-                # meet a zero weight, and 0 * NaN is NaN
-                v = jnp.where(tok_col < lim, vbuf[buf, sel, :], 0.0)
-                qh = q[kh * g:(kh + 1) * g]               # (g, hd)
-                s = lax.dot_general(qh, k, (((1,), (1,)), ((), ())),
-                                    precision=hi,
-                                    preferred_element_type=jnp.float32)
-                s = jnp.where(tok_row < lim, s, -1e30)    # (g, toks)
-                m_prev = m[kh]
-                m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-                corr = jnp.exp(m_prev - m_new)
-                p = jnp.exp(s - m_new)
-                ms.append(m_new)
-                ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
-                accs.append(acc[kh] * corr + jnp.dot(
-                    p, v, precision=hi,
-                    preferred_element_type=jnp.float32))
-            return gcount + 1, ms, ls, accs
-
-        init = (gcount,
-                [jnp.full((g, 1), -1e30, jnp.float32)] * kv_heads,
-                [jnp.zeros((g, 1), jnp.float32)] * kv_heads,
-                [jnp.zeros((g, hd), jnp.float32)] * kv_heads)
-        gcount, _m, l, acc = lax.fori_loop(0, n_waves, wave_body, init)
-        for kh in range(kv_heads):
-            o_ref[b, kh * g:(kh + 1) * g, :] = acc[kh] / l[kh]
-        return gcount
-
-    lax.fori_loop(0, slots, slot_body, 0)
-
-
-@functools.partial(jax.jit, static_argnames=("page", "scale", "interpret"))
-def _grouped_call(q, pk, pv, bt, pos, page: int, scale: Optional[float],
-                  interpret: bool):
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    slots, heads, hd = q.shape
-    kv_heads = pk.shape[1] // page
-    wave = pages_per_wave(page, kv_heads, hd, bt.shape[1])
-    whole = pl.BlockSpec((slots, heads, hd), lambda i, bt, pos: (0, 0, 0))
-    return pl.pallas_call(
-        functools.partial(_grouped_kernel, page=page, kv_heads=kv_heads,
-                          wave=wave, scale=scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(1,),
-            in_specs=[whole,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=whole,
-            scratch_shapes=[
-                pltpu.VMEM((2, wave * page * kv_heads, hd), pk.dtype),
-                pltpu.VMEM((2, wave * page * kv_heads, hd), pv.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ]),
-        out_shape=jax.ShapeDtypeStruct((slots, heads, hd), jnp.float32),
-        interpret=interpret,
-        name="paged_decode_attention",
-    )(bt, pos, q, pk, pv)
-
-
 def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
                                    scale: Optional[float] = None,
                                    interpret: Optional[bool] = None):
     """:func:`paged_decode_attention` for ``heads`` query heads over
     fewer key/value heads: the pools are ``(num_pages, page *
-    kv_heads, hd)``, a row a (token, key/value head) pair."""
-    return _grouped_call(q, pk, pv, bt, pos, page=page, scale=scale,
-                         interpret=_resolve_interpret(interpret))
+    kv_heads, hd)``, a row a (token, key/value head) pair.  The window
+    kernel's walk with no window, its products float32 at ``highest``
+    (the arithmetic of :func:`reference`)."""
+    return _window_call(q, pk, pv, bt, pos, page=page, window=0,
+                        scale=scale, exact=True,
+                        interpret=_resolve_interpret(interpret))
 
 
-# -- window layers beside global ones ----------------------------------------
+# -- the grouped layout: window layers beside global ones, and grouped heads --
 #
-# The grouped layout again, for a schedule in which some layers attend a
-# window of the context (``LMConfig.windows``): a slot's walk STARTS at
-# the page that holds the window's first position and masks that page's
-# head, so a window layer reads ``window // page + 1`` pages however
-# long the context is (entries of the block table behind the window may
-# have been given back: they are never read).  One grid step a slot,
-# the slots' waves one sequence through a ring of buffers, the copies
-# of the next ``ring - 1`` waves in flight while one is computed, into
-# the next slots where this one ends; a group's ``(g, hd)`` queries meet a
-# key/value head's ``(tokens, hd)`` rows on the MXU in bfloat16 with
-# float32 accumulation (in float32 at ``highest``, as
-# :func:`_grouped_kernel`, sixteen queries a head would cost six passes
-# a product and hold the pages' bytes back).  A window layer's call is
-# ``window_decode_attention`` in a device trace, a global layer's of
-# the same schedule ``paged_decode_attention``.
+# A schedule in which some layers attend a window of the context
+# (``LMConfig.windows``): a slot's walk STARTS at the page that holds the
+# window's first position and masks that page's head, so a window layer
+# reads ``window // page + 1`` pages however long the context is
+# (entries of the block table behind the window may have been given
+# back: they are never read).  One grid step a slot, the slots' waves
+# one sequence through a ring of buffers, the copies of the next ``ring
+# - 1`` waves in flight while one is computed, into the next slots where
+# this one ends; a group's ``(g, hd)`` queries meet a key/value head's
+# ``(tokens, hd)`` rows on the MXU.  A window schedule's products take
+# bfloat16 operands with float32 accumulation, as its configurations
+# state; grouped heads with no window
+# (:func:`paged_decode_attention_grouped`) take float32 ones at
+# ``highest``, the arithmetic of :func:`reference`: a wave's six passes
+# a product still take fewer instruction bundles than its page copies
+# take time (``chip_window.py bundles``).  A window layer's call is
+# ``window_decode_attention`` in a device trace, a global layer's and a
+# grouped one's ``paged_decode_attention``.
 
 # waves the window kernel keeps in VMEM: the one it computes and five
 # whose pages are on their way (6 x 512 KB x 2 pools).  Measured on the
@@ -385,7 +261,8 @@ _WINDOW_RING = 6
 
 def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
                    kbuf, vbuf, sems, g_ref, *, page: int, kv_heads: int,
-                   wave: int, window: int):
+                   wave: int, window: int, scale: Optional[float],
+                   exact: bool):
     """One grid step a slot; the slots' waves are ONE sequence through
     the ring, as in :func:`_latent_kernel` (whose comment says why each
     point pays): the ring, its semaphores and the count of waves so far
@@ -394,7 +271,9 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     this slot or the next ones, in the one block the wave is (a copy
     with no page to fetch is a predicated instruction), so the copies'
     scalar work runs beside the products' vector work.  A key page and
-    its value page signal one semaphore, the buffer's."""
+    its value page signal one semaphore, the buffer's.  ``exact``: the
+    products float32 at ``highest`` (the arithmetic of
+    :func:`reference`); else bfloat16 operands."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -407,7 +286,9 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     ring = kbuf.shape[0]
     toks = wave * page
     rows = page * kv_heads
-    bf = jnp.bfloat16
+    # the products' operands and precision
+    dt, prec = (jnp.float32, lax.Precision.HIGHEST) if exact \
+        else (jnp.bfloat16, None)
 
     def first_page(s):
         """The page of the first position slot ``s`` attends."""
@@ -459,7 +340,8 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     tok_row = lax.broadcasted_iota(jnp.int32, (1, toks), 1)
     pos = pos_ref[b]
     base = first_page(b) * page
-    q = (q_ref[0] * (1.0 / hd ** 0.5)).astype(bf)         # (heads, hd)
+    scale = 1.0 / hd ** 0.5 if scale is None else scale
+    q = (q_ref[0] * scale).astype(dt)                     # (heads, hd)
     nt = (((1,), (1,)), ((), ()))
 
     def wave_body(w, carry):
@@ -477,15 +359,16 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
         for kh in range(kv_heads):
             sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
                 else pl.ds(0, toks)
-            k = kbuf[buf, sel, :].astype(bf)              # (toks, hd)
+            k = kbuf[buf, sel, :].astype(dt)              # (toks, hd)
             ok_col = tok_col < hi
             ok_row = tok_row < hi
             if window:
                 ok_col = jnp.logical_and(ok_col, tok_col >= lo)
                 ok_row = jnp.logical_and(ok_row, tok_row >= lo)
             # a weight of zero on a stale row is still 0 * NaN
-            v = jnp.where(ok_col, vbuf[buf, sel, :], 0.0).astype(bf)
+            v = jnp.where(ok_col, vbuf[buf, sel, :], 0.0).astype(dt)
             s = lax.dot_general(q[kh * g:(kh + 1) * g], k, nt,
+                                precision=prec,
                                 preferred_element_type=jnp.float32)
             s = jnp.where(ok_row, s, -1e30)               # (g, toks)
             m_new = jnp.maximum(m[kh], s.max(axis=1, keepdims=True))
@@ -494,7 +377,8 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
             ms.append(m_new)
             ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
             accs.append(acc[kh] * corr + jnp.dot(
-                p.astype(bf), v, preferred_element_type=jnp.float32))
+                p.astype(dt), v, precision=prec,
+                preferred_element_type=jnp.float32))
         # into the buffer the wave before this one was read from
         ahead = (b, w)
         for _ in range(ring - 1):
@@ -511,9 +395,11 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
         o_ref[0, kh * g:(kh + 1) * g, :] = acc[kh] / l[kh]
 
 
-@functools.partial(jax.jit, static_argnames=("page", "window", "interpret"))
+@functools.partial(jax.jit, static_argnames=("page", "window", "interpret",
+                                             "scale", "exact"))
 def _window_call(q, pk, pv, bt, pos, page: int, window: int,
-                 interpret: bool):
+                 interpret: bool, scale: Optional[float] = None,
+                 exact: bool = False):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -524,7 +410,8 @@ def _window_call(q, pk, pv, bt, pos, page: int, window: int,
     mine = pl.BlockSpec((1, heads, hd), lambda b, bt, pos: (b, 0, 0))
     return pl.pallas_call(
         functools.partial(_window_kernel, page=page, kv_heads=kv_heads,
-                          wave=wave, window=window),
+                          wave=wave, window=window, scale=scale,
+                          exact=exact),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(slots,),
@@ -784,17 +671,37 @@ def import_pallas() -> None:
     import jax.experimental.pallas.tpu  # noqa: F401
 
 
-def attention(q, pk, pv, bt, pos, page: Optional[int] = None,
-              scale: Optional[float] = None):
-    """The step's attention: the kernel on the TPU, :func:`reference`
-    on the cpu backend (where the kernel would only be interpreted), as
-    ``flash_attention.attention(impl="auto")`` chooses for prefill.
-    Pools of three dimensions are the grouped layout and need
-    ``page``; ``scale`` is the softmax's (None: ``hd^-0.5``)."""
+def decode_form(layout: str, windowed: bool = False) -> str:
+    """The form of the decode step's attention for a layer whose pools
+    are ``layout`` (``whole``, ``grouped`` or ``latent``) in a window
+    schedule or not: the one rule the three entry points below dispatch
+    by and ``kv_stats()["attn"]["forms"]`` reports.  On the cpu backend
+    ``reference`` (the kernels would only be interpreted); on the TPU
+    ``latent`` (:func:`mla_decode_attention`), ``ring_bf16`` for every
+    layer of a window schedule (:func:`window_decode_attention`),
+    ``ring_f32`` for grouped heads otherwise
+    (:func:`paged_decode_attention_grouped`), ``vpu`` for whole heads
+    (:func:`paged_decode_attention`)."""
     from .device_ops import _on_tpu
     if not _on_tpu():
+        return "reference"
+    if layout == "latent":
+        return "latent"
+    if windowed:
+        return "ring_bf16"
+    return "ring_f32" if layout == "grouped" else "vpu"
+
+
+def attention(q, pk, pv, bt, pos, page: Optional[int] = None,
+              scale: Optional[float] = None):
+    """The step's attention, as :func:`decode_form` chooses (as
+    ``flash_attention.attention(impl="auto")`` chooses for prefill).
+    Pools of three dimensions are the grouped layout and need
+    ``page``; ``scale`` is the softmax's (None: ``hd^-0.5``)."""
+    form = decode_form("grouped" if pk.ndim == 3 else "whole")
+    if form == "reference":
         return reference(q, pk, pv, bt, pos, page, scale=scale)
-    if pk.ndim == 3:
+    if form == "ring_f32":
         return paged_decode_attention_grouped(q, pk, pv, bt, pos, page,
                                               scale)
     return paged_decode_attention(q, pk, pv, bt, pos, scale)
@@ -804,8 +711,7 @@ def window_attention(q, pk, pv, bt, pos, page: int, window: int = 0):
     """The step's attention in a window schedule, for its window layers
     and its global ones: :func:`window_decode_attention` on the TPU,
     :func:`reference` under the window's mask on the cpu backend."""
-    from .device_ops import _on_tpu
-    if not _on_tpu():
+    if decode_form("grouped", windowed=True) == "reference":
         return reference(q, pk, pv, bt, pos, page, window)
     return window_decode_attention(q, pk, pv, bt, pos, page, window)
 
@@ -813,7 +719,6 @@ def window_attention(q, pk, pv, bt, pos, page: int, window: int = 0):
 def mla_attention(q_lat, q_rope, pc, bt, pos, scale: float):
     """The step's latent attention: :func:`mla_decode_attention` on the
     TPU, :func:`mla_reference` on the cpu backend."""
-    from .device_ops import _on_tpu
-    if not _on_tpu():
+    if decode_form("latent") == "reference":
         return mla_reference(q_lat, q_rope, pc, bt, pos, scale)
     return mla_decode_attention(q_lat, q_rope, pc, bt, pos, scale)

@@ -271,10 +271,7 @@ def test_ssm_step_kernel_matches_sequential_and_skips_inactive():
                                np.asarray(y0)[act, 0], atol=2e-5)
 
 
-@pytest.mark.parametrize("heads,kv_heads,hd,page,pps", [
-    (20, 1, 128, 16, 16), (4, 2, 128, 16, 8), (5, 1, 8, 4, 8)],
-    ids=["20on1", "4on2", "toy"])
-def test_grouped_paged_attention_kernel(heads, kv_heads, hd, page, pps):
+def _grouped_case(heads, kv_heads, hd, page, pps):
     r = np.random.default_rng(0)
     slots, pages = 4, 40
     q = r.normal(size=(slots, heads, hd)).astype(np.float32)
@@ -282,10 +279,36 @@ def test_grouped_paged_attention_kernel(heads, kv_heads, hd, page, pps):
               for _ in range(2))
     bt = r.integers(1, pages, size=(slots, pps)).astype(np.int32)
     pos = np.asarray([0, page * pps - 1, 37 % (page * pps), page], np.int32)
-    want = paged_attention.reference(q, pk, pv, bt, pos, page)
+    return q, pk, pv, bt, pos
+
+
+@pytest.mark.parametrize("heads,kv_heads,hd,page,pps,scale", [
+    (20, 1, 128, 16, 16, None), (4, 2, 128, 16, 8, None),
+    (5, 1, 8, 4, 8, None), (32, 8, 128, 16, 8, None),
+    (32, 8, 128, 16, 8, 0.0078125)],
+    ids=["20on1", "4on2", "toy", "32on8", "32on8_scaled"])
+def test_grouped_paged_attention_kernel(heads, kv_heads, hd, page, pps,
+                                        scale):
+    """The window kernel's walk at window 0 in its float32-accurate form
+    (``granite-4.0-h-small``'s 32 on 8 with and without its softmax
+    scale of 1/128): float32's accuracy, as the reference."""
+    args = _grouped_case(heads, kv_heads, hd, page, pps)
+    want = paged_attention.reference(*args, page, scale=scale)
     got = paged_attention.paged_decode_attention_grouped(
-        q, pk, pv, bt, pos, page, interpret=True)
+        *args, page, scale, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_the_bar_tells_the_bf16_form_from_the_float32_one():
+    """The same inputs through the same walk with bfloat16 operands miss
+    the bar the float32-accurate form meets: 2e-5 tells them apart."""
+    args = _grouped_case(32, 8, 128, 16, 8)
+    want = np.asarray(paged_attention.reference(*args, 16))
+    exact, bf16 = (np.asarray(paged_attention._window_call(
+        *map(jnp.asarray, args), page=16, window=0, exact=e,
+        interpret=True)) for e in (True, False))
+    assert np.abs(exact - want).max() < 2e-5
+    assert np.abs(bf16 - want).max() > 20 * 2e-5
 
 
 # -- through the batcher -------------------------------------------------------
@@ -475,6 +498,26 @@ def test_info_shows_the_schedule_and_the_state_pool(model):
     assert info["state_pool"] == {"slots": 2, "kinds": kinds,
                                   "bytes": 2 * T.state_slot_bytes(cfg)}
     assert b"sass" in svc.model_fingerprint()
+    # off the TPU every layer runs the plain form
+    assert info["attention"] == {"forms": {"reference": 1}}
+
+
+def test_kv_stats_names_the_decode_form_of_each_attention_layer(model):
+    """On the TPU grouped heads with no window run the ring walk's
+    float32-accurate form, whole heads the first block's VPU kernel; off
+    it every layer runs the plain gather."""
+    from unittest import mock
+
+    from brpc_tpu.models.lm_service import ContinuousBatcher
+    from brpc_tpu.ops import device_ops
+    cfg, params = model
+    bat = ContinuousBatcher(cfg, params, slots=1, page=PAGE)
+    assert bat.kv_stats()["attn"]["forms"] == {"reference": 1}
+    with mock.patch.object(device_ops, "_on_tpu", lambda: True):
+        assert bat.kv_stats()["attn"]["forms"] == {"ring_f32": 1}
+        assert T.attention_forms(_cfg(kv_heads=5)) == {"vpu": 1}
+        assert T.attention_forms(_cfg(mixers=("attn",) * 4)) == \
+            {"ring_f32": 4}
 
 
 # -- what declines, by name ----------------------------------------------------

@@ -9,7 +9,8 @@
 - NO ``max_seq`` COPY: the lowered kernel holds no array of
   ``slots x max_seq x heads x hd`` elements (the reference does);
 - THE COUNTER: ``kv_stats()["attn"]`` grows by the pages of live
-  positions and by the block table's width, a step and an active slot.
+  positions and by the block table's width, a step and an active slot,
+  and names the kernel's form by layers.
 """
 import re
 import struct
@@ -359,6 +360,101 @@ def test_window_kernel_at_a_group_of_sixteen_compiles_for_the_v5e(
     _window_kernel_compiles(one_chip, 16, 128, 8, window, pages)
 
 
+@pytest.mark.parametrize("slots,heads,kvh,pages", [
+    (64, 32, 8, 8193), (8, 20, 1, 1025)], ids=["granite", "jamba"])
+def test_grouped_kernel_compiles_for_the_v5e(slots, heads, kvh, pages,
+                                             one_chip):
+    """Grouped heads with no window, float32-accurate, at
+    ``granite-4.0-h-small.codegen``'s shapes (64 slots of 32 query heads
+    on 8 key/value heads, 8 pages of 64 KB a wave) and
+    ``jamba2-3b.chat``'s (8 slots of 20 on 1, 64 pages of 8 KB a wave),
+    a 128-wide table: ONE custom call under the name a device trace is
+    read by, the pools in as they lie; nothing is run."""
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((pages, PAGE * kvh, 128), jnp.float32)
+    compiled = jax.jit(
+        lambda *a: pa.paged_decode_attention_grouped(
+            *a, PAGE, 0.0078125, interpret=False)
+    ).lower(arg((slots, heads, 128), jnp.float32), pool, pool,
+            arg((slots, 128), jnp.int32), arg((slots,), jnp.int32)).compile()
+    calls = re.findall(r"%(paged_decode_attention[.\w]*) = (\S+) custom-call",
+                       compiled.as_text())
+    assert len(calls) == 1 and calls[0][1].startswith(
+        f"f32[{slots},{heads},128]"), calls
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def _mosaic_bodies(text):
+    """The Mosaic kernels of a lowered program, printed without their
+    locations (a serialized body carries its source lines)."""
+    import base64
+
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        with ir.Context() as ctx, ir.Location.unknown():
+            ctx.allow_unregistered_dialects = True
+            out.append(ir.Module.parse(base64.b64decode(body)).operation
+                       .get_asm(enable_debug_info=False))
+    return out
+
+
+# the bfloat16 form's Mosaic body at the two window cells' shapes: the
+# first 16 hex digits of the sha256 of what _mosaic_bodies prints for the
+# body this test lowers, read at the commit before the grouped heads took
+# the window kernel's walk (a327410).  Another JAX or libtpu prints
+# another text for the same kernel; re-read them there, at that commit.
+BODIES_READ_UNDER = {"jax": "0.9.0", "libtpu": "0.0.34"}
+WINDOW_BODIES = {(32, 28, 4, 4096, 8321): "325ea85d2ac1c4dd",
+                 (32, 28, 4, 0, 15361): "07f8cfc795765951",
+                 (16, 128, 8, 4096, 4193): "ae1019a8a6c13fee",
+                 (16, 128, 8, 0, 9601): "7c74ae5758dee642"}
+
+
+def _versions():
+    from importlib import metadata
+
+    out = {}
+    for pkg in BODIES_READ_UNDER:
+        try:
+            out[pkg] = metadata.version(pkg)
+        except metadata.PackageNotFoundError:
+            out[pkg] = None
+    return out
+
+
+@pytest.mark.skipif(_versions() != BODIES_READ_UNDER,
+                    reason=f"the bodies' hashes were read under "
+                    f"{BODIES_READ_UNDER}, not {_versions()}")
+@pytest.mark.parametrize("shape", list(WINDOW_BODIES), ids=str)
+def test_window_kernel_lowers_to_the_parents_body(shape, one_chip):
+    """``window_decode_attention`` (``smallthinker-21b.longdoc``'s and
+    ``command-a-plus.longdoc``'s shapes, window and global) lowers to
+    the Mosaic text it had before it gained its float32-accurate form:
+    those cells run the kernel they ran."""
+    import hashlib
+
+    slots, heads, kvh, window, pages = shape
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg((pages, PAGE * kvh, 128), jnp.float32)
+    text = jax.jit(
+        lambda *a: pa.window_decode_attention(*a, PAGE, window,
+                                              interpret=False)
+    ).lower(arg((slots, heads, 128), jnp.float32), pool, pool,
+            arg((slots, 816), jnp.int32),
+            arg((slots,), jnp.int32)).as_text()
+    bodies = _mosaic_bodies(text)
+    assert len(bodies) == 1
+    assert hashlib.sha256(bodies[0].encode()).hexdigest()[:16] \
+        == WINDOW_BODIES[shape]
+
+
 @pytest.mark.parametrize("window,pages,entries", [(4096, 8321, 322),
                                                   (0, 15361, 816)])
 def test_span_kernel_at_a_group_of_seven_compiles_for_the_v5e(
@@ -453,7 +549,8 @@ def test_kv_stats_counts_the_pages_a_step_attends_over():
                    remat=False)
     params = init_params(jax.random.PRNGKey(0), cfg)
     bat = ContinuousBatcher(cfg, params, slots=2, page=PAGE)
-    assert bat.kv_stats()["attn"] == {"pages_read": 0, "pages_table": 0}
+    assert bat.kv_stats()["attn"] == {"pages_read": 0, "pages_table": 0,
+                                      "forms": {"reference": 2}}
     pps = cfg.max_seq // PAGE
     read = table = 0
     for seed, (n_prompt, max_new) in enumerate([(14, 6), (31, 5), (3, 2)]):
@@ -463,5 +560,6 @@ def test_kv_stats_counts_the_pages_a_step_attends_over():
         read += sum((n_prompt - 1 + j) // PAGE + 1 for j in range(max_new))
         table += max_new * pps
         assert bat.kv_stats()["attn"] == {"pages_read": read,
-                                          "pages_table": table}
+                                          "pages_table": table,
+                                          "forms": {"reference": 2}}
     assert table == bat.steps_run() * pps
