@@ -728,12 +728,20 @@ class ContinuousBatcher:
                          "inserts": self._state_inserts,
                          "releases": self._state_releases,
                          # by kind of state layer: layers, bytes a slot
-                         "kinds": state_kinds(self.cfg)}}
+                         "kinds": state_kinds(self.cfg)},
+               # the layers by their parts: a mixer alone, a
+               # feed-forward alone (by kind), or both
+               "schedule": self.cfg.layer_parts()}
         cfg = self.cfg
         if cfg.has_kda:
             out["kda"] = {**out["state"]["kinds"]["kda"], **self._kda}
         if cfg.has_mamba2:
+            # the gated norm's groups and their width
             out["mamba2"] = {**out["state"]["kinds"]["mamba2"],
+                             "groups": cfg.mamba2_groups,
+                             "norm_group": cfg.mamba2_heads
+                             * cfg.mamba2_head_dim
+                             // cfg.mamba2_norm_groups,
                              **self._mamba2}
         if cfg.passes > 1:
             out["loop"] = {"passes": cfg.passes, "layers": cfg.depth,
@@ -743,11 +751,17 @@ class ContinuousBatcher:
             out["fill"] = dict(self._fill)
         if cfg.has_experts:
             lo, hi = cfg.experts_held
+            ecfg = cfg.expert_cfg()
             out["moe"] = {"layers": len(cfg.expert_layers()),
                           "held": hi - lo, "routed": cfg.experts_routed,
                           "top_k": cfg.experts_top_k,
                           "scoring": cfg.router_scoring,
-                          "router_at": cfg.router_at, **self._moe}
+                          "router_at": cfg.router_at,
+                          # the experts' form: the activation, whether
+                          # it gates, the shared MLP's width
+                          "act": ecfg.act, "gated": ecfg.gated,
+                          "shared_dim": ecfg.shared_dim if ecfg.shared
+                          else 0, **self._moe}
         if cfg.has_window:
             wt = self._wt
             out["window"] = {
@@ -2002,6 +2016,8 @@ class LMService(Service):
         if c.has_mamba2:
             fp += (f":{c.mamba2_heads}x{c.mamba2_head_dim}x{c.mamba2_state}"
                    f"x{c.mamba2_groups}x{c.mamba2_conv}x{c.mamba2_chunk}")
+            if c.mamba2_norm_groups != 1:
+                fp += f"n{c.mamba2_norm_groups}"        # the norm's groups
         fp += _scale_note(c)
         if c.passes > 1 or c.post_norms:
             # pages a pass; the norms behind the branches

@@ -13,7 +13,12 @@ of ``P``, a state of ``N``, ``G`` groups of heads sharing ``B`` and
     dt_h = softplus(dt_h + dt_bias_h),  A_h = -exp(A_log_h)
     S_h  = exp(dt_h A_h) S_h + (dt_h x_h) B_g^T        P x N, float32
     y_h  = S_h C_g + D_h x_h
-    out  = RMSNorm(y * silu(z); g_norm) W_out          the norm over d_inner
+    out  = RMSNorm(y * silu(z); g_norm) W_out          each of R groups of
+                                                       d_inner / R channels
+                                                       by its own RMS
+
+``R`` is ``LMConfig.mamba2_norm_groups``: 1 (all of ``d_inner`` at
+once, as Granite's modelling code has it) or ``G`` (Nemotron-H's).
 
 What a sequence carries from token to token is ``S`` (``H x P x N``
 float32, laid in the pool as ``ops.ssd.to_pool`` has it: ``N`` on the
@@ -106,14 +111,25 @@ def _split(cfg, xbc):
 
 
 def _out(cfg, bp, y, z):
-    """The gated norm over ``d_inner`` and ``W_out``."""
+    """The gated norm and ``W_out``: ``y * silu(z)``, each of the
+    ``mamba2_norm_groups`` groups of channels divided by its own RMS
+    (one group: all of ``d_inner``), times the gain (``mamba2_norm`` in
+    a device trace)."""
     import jax
 
     from ..ops.quant import qmatmul
     from .transformer_lm import _rmsnorm
 
-    return qmatmul(_rmsnorm(y * jax.nn.silu(z), bp["norm"], cfg.norm_eps),
-                   bp["out_proj"])
+    g = cfg.mamba2_norm_groups
+    with jax.named_scope("mamba2_norm"):
+        v = y * jax.nn.silu(z)
+        if g == 1:
+            v = _rmsnorm(v, bp["norm"], cfg.norm_eps)
+        else:
+            v = _rmsnorm(v.reshape(v.shape[:-1] + (g, -1)),
+                         bp["norm"].reshape(g, -1),
+                         cfg.norm_eps).reshape(v.shape)
+    return qmatmul(v, bp["out_proj"])
 
 
 def prefill(cfg, bp, x, ctx_len):

@@ -30,7 +30,7 @@ the shard that owns it, /root/reference/src/brpc/partition_channel.h):
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 
 class MoEConfig:
@@ -171,7 +171,8 @@ def forward_grouped(params: Dict[str, Any], x, cfg: MoEConfig
 # The expert layer of a served schedule (``LMConfig.ffns``
 # ``"experts"``), as DeepSeek-V3's modelling code has it: sigmoid
 # scores, a correction bias that CHOOSES and does not weigh, the chosen
-# weights renormalised and scaled, every expert a gated MLP, shared
+# weights renormalised and scaled, every expert a gated MLP (or, of
+# ``act`` "relu2", the ungated ``relu(t W_up)^2 W_down``), shared
 # experts on every token.  Nothing is dropped: the rows routed to the
 # experts held here are sorted by expert into a buffer of the worst
 # case and run as two grouped matrix products, each one call of the
@@ -205,9 +206,11 @@ class ExpertConfig:
                  held: Tuple[int, int], top_k: int,
                  route_scale: float = 1.0, shared: int = 0,
                  bias: bool = True, shared_scale: float = 1.0,
-                 scoring: str = "sigmoid", act: str = "silu"):
+                 scoring: str = "sigmoid", act: str = "silu",
+                 shared_dim: Optional[int] = None):
         assert 0 <= held[0] < held[1] <= routed and 1 <= top_k <= routed
-        assert scoring in ("sigmoid", "softmax") and act in ("silu", "relu")
+        assert scoring in ("sigmoid", "softmax") \
+            and act in ("silu", "relu", "relu2")
         self.dim, self.hidden, self.routed = dim, hidden, routed
         self.held = (int(held[0]), int(held[1]))
         self.n_held = self.held[1] - self.held[0]
@@ -221,8 +224,20 @@ class ExpertConfig:
         self.shared_scale = float(shared_scale)
         # what makes scores of the router's logits (a softmax is over
         # ALL routed experts, before the choice), and the gate's
-        # activation in every expert, routed or shared
+        # activation in every expert, routed or shared; "relu2" has no
+        # gate: ``relu(t W_up)^2 W_down``, ``w1`` the up product alone,
+        # its rows the hidden width's as ``w2``'s are: ``(n, hidden,
+        # dim)``, read transposed.  (Laid ``(n, dim, hidden)`` at
+        # nemotron-3-nano-30b's hidden of 1,856, no whole lane tiles,
+        # the device's default layout put ``dim`` on the lanes and the
+        # kernel's operand was copied into row-major every step: 39%
+        # of the step's device time, measured on one v5e chip.)
         self.scoring, self.act = scoring, act
+        self.gated = act != "relu2"
+        # the shared experts' one MLP: ``shared * hidden`` wide unless
+        # it has a width of its own
+        self.shared_dim = shared * hidden if shared_dim is None \
+            else int(shared_dim)
 
     def buffer_rows(self, rows: int) -> int:
         """The sorted buffer's rows for ``rows`` tokens: a token's
@@ -233,27 +248,29 @@ class ExpertConfig:
 
 def init_served(rng, cfg: ExpertConfig) -> Dict[str, Any]:
     """Seeded weights of one served expert layer: the router over ALL
-    routed experts and its correction bias, the HELD experts' gated
-    MLPs stacked (``w1`` holds gate and up side by side), the shared
-    experts as one gated MLP of ``shared * hidden``."""
+    routed experts and its correction bias, the HELD experts' MLPs
+    stacked (a gated one's ``w1`` holds gate and up side by side, an
+    ungated one's lies ``(n, hidden, dim)``), the shared experts as one
+    MLP of ``shared_dim``."""
     import jax
     import jax.numpy as jnp
 
-    d, e, n = cfg.dim, cfg.hidden, cfg.n_held
+    d, e, n, sd = cfg.dim, cfg.hidden, cfg.n_held, cfg.shared_dim
+    up = 2 if cfg.gated else 1
     ks = jax.random.split(rng, 6)
 
     def normal(k, shape, fan_in):
         return jax.random.normal(k, shape, jnp.float32) / math.sqrt(fan_in)
 
     p = {"router": normal(ks[0], (d, cfg.routed), d),
-         "w1": normal(ks[2], (n, d, 2 * e), d),
+         "w1": normal(ks[2], (n, d, 2 * e) if cfg.gated else (n, e, d), d),
          "w2": normal(ks[3], (n, e, d), e)}
     if cfg.bias:
         p["bias"] = jax.random.normal(ks[1], (cfg.routed,),
                                       jnp.float32) * 0.1
     if cfg.shared:
-        p["ws1"] = normal(ks[4], (d, 2 * cfg.shared * e), d)
-        p["ws2"] = normal(ks[5], (cfg.shared * e, d), cfg.shared * e)
+        p["ws1"] = normal(ks[4], (d, up * sd), d)
+        p["ws2"] = normal(ks[5], (sd, d), sd)
     return p
 
 
@@ -280,14 +297,24 @@ def route(p: Dict[str, Any], t, cfg: ExpertConfig) -> Tuple[Any, Any]:
     return ids, w
 
 
-def _gated(x, w1, w2, act=None):
+def _mlp(cfg: ExpertConfig, x, w1, w2):
+    """One MLP of the layer's form (the shared experts'): ``_hidden(x
+    w1) w2``."""
+    from ..ops.quant import mxu_matmul
+
+    return mxu_matmul(_hidden(cfg, mxu_matmul(x, w1)), w2)
+
+
+def _hidden(cfg: ExpertConfig, h):
+    """An expert's hidden rows from its first product ``h``: ``act(gate)
+    * up``, or ``relu(h)^2`` where the experts are not gated."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.quant import mxu_matmul
-
-    gate, up = jnp.split(mxu_matmul(x, w1), 2, axis=-1)
-    return mxu_matmul((act or jax.nn.silu)(gate) * up, w2)
+    if not cfg.gated:
+        return jnp.square(jax.nn.relu(h))
+    gate, up = jnp.split(h, 2, axis=-1)
+    return (jax.nn.silu if cfg.act == "silu" else jax.nn.relu)(gate) * up
 
 
 def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None, routed=None
@@ -328,15 +355,15 @@ def serve(p: Dict[str, Any], t, cfg: ExpertConfig, live=None, routed=None
         sizes = jnp.sum(key[:, None] == jnp.arange(n)[None, :], axis=0,
                         dtype=jnp.int32)
     bf = quant.mxu_operand
-    act = jax.nn.silu if cfg.act == "silu" else jax.nn.relu
     xs = bf(t)[tok]                                         # (M, dim)
-    gate, up = jnp.split(expert_gmm(xs, bf(p["w1"]), sizes), 2, axis=-1)
-    ys = expert_gmm(bf(act(gate) * up), bf(p["w2"]), sizes)
+    h = expert_gmm(xs, bf(p["w1"]), sizes, transposed=not cfg.gated)
+    ys = expert_gmm(bf(_hidden(cfg, h)), bf(p["w2"]), sizes)
     # the local pairs' rows are the first ``sizes.sum()``; the rows
     # behind them were not computed
     out = combine(ys, order, w, sizes.sum())
     if cfg.shared:
-        shared = _gated(t, p["ws1"], p["ws2"], act)
+        with jax.named_scope("shared_expert"):
+            shared = _mlp(cfg, t, p["ws1"], p["ws2"])
         out = out + (shared if cfg.shared_scale == 1.0
                      else shared * cfg.shared_scale)
     counts = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()]

@@ -53,7 +53,8 @@ class LMConfig:
                  expert_dim: Optional[int] = None, experts_routed: int = 0,
                  experts_held: Optional[Tuple[int, int]] = None,
                  experts_top_k: int = 1, route_scale: float = 1.0,
-                 shared_experts: int = 0, head_dim: Optional[int] = None,
+                 shared_experts: int = 0, shared_dim: Optional[int] = None,
+                 head_dim: Optional[int] = None,
                  norm: str = "rms", parallel_block: bool = False,
                  windows: Optional[Tuple[int, ...]] = None,
                  ropes: Optional[Tuple[bool, ...]] = None,
@@ -66,7 +67,8 @@ class LMConfig:
                  router_scoring: str = "sigmoid",
                  mamba2_heads: Optional[int] = None,
                  mamba2_head_dim: int = 64, mamba2_state: int = 128,
-                 mamba2_groups: int = 1, mamba2_conv: int = 4,
+                 mamba2_groups: int = 1, mamba2_norm_groups: int = 1,
+                 mamba2_conv: int = 4,
                  mamba2_chunk: int = 256, embed_scale: float = 1.0,
                  residual_scale: float = 1.0,
                  attn_scale: Optional[float] = None,
@@ -132,12 +134,16 @@ class LMConfig:
         # as one bucket
         self.fill_span = int(fill_span)
         # "gated_silu" / "gated_relu": ``(act(gate) * up) w2``, in the
-        # dense feed-forward and in every expert
-        assert ffn in ("gelu", "gated_silu", "gated_relu")
+        # dense feed-forward and in every expert; "relu2": ``relu(h
+        # w1)^2 w2``, not gated
+        assert ffn in ("gelu", "gated_silu", "gated_relu", "relu2")
         self.ffn = ffn
         self.ffn_dim = dim * mlp_mult if ffn_dim is None else int(ffn_dim)
         self.tie_embed = bool(tie_embed)
         self.final_norm = bool(final_norm)
+        # a layer's mixer may be "none": the layer is its feed-forward
+        # alone, with that part's one norm (``ffns`` may say "none" of
+        # a layer with a mixer, never of one without)
         self.mixers = ("attn",) * depth if mixers is None \
             else tuple(mixers)
         assert len(self.mixers) == depth \
@@ -154,16 +160,21 @@ class LMConfig:
         # ``mamba2_head_dim`` (d_inner = their product), a state of
         # ``mamba2_state`` a head row, ``mamba2_groups`` groups of heads
         # sharing B and C, a convolution of ``mamba2_conv`` taps, and
-        # prompts scanned in chunks of ``mamba2_chunk`` positions
+        # prompts scanned in chunks of ``mamba2_chunk`` positions; the
+        # gated norm divides each of ``mamba2_norm_groups`` groups of
+        # channels by its own RMS (1: all of d_inner at once)
         self.has_mamba2 = "mamba2" in self.mixers
         self.mamba2_heads = 2 * dim // int(mamba2_head_dim) \
             if mamba2_heads is None else int(mamba2_heads)
         self.mamba2_head_dim = int(mamba2_head_dim)
         self.mamba2_state = int(mamba2_state)
         self.mamba2_groups = int(mamba2_groups)
+        self.mamba2_norm_groups = int(mamba2_norm_groups)
         self.mamba2_conv = int(mamba2_conv)
         self.mamba2_chunk = int(mamba2_chunk)
-        assert self.mamba2_heads % self.mamba2_groups == 0
+        assert self.mamba2_heads % self.mamba2_groups == 0 \
+            and self.mamba2_heads * self.mamba2_head_dim \
+            % self.mamba2_norm_groups == 0
         # four scalars of the residual stream (Granite's; 1 / None:
         # none): the table's rows times ``embed_scale``; each branch
         # times ``residual_scale`` before the residual adds it;
@@ -207,9 +218,12 @@ class LMConfig:
         # drops beside shared ones).  ``experts_held`` is the range of
         # routed expert ids THIS program holds: it routes over all
         # ``experts_routed`` and adds its own experts' part
+        # ("none": the layer is its mixer alone).
         self.ffns = ("dense",) * depth if ffns is None else tuple(ffns)
         assert len(self.ffns) == depth \
-            and set(self.ffns) <= {"dense", "experts"}
+            and set(self.ffns) <= {"dense", "experts", "none"}
+        assert ("none", "none") not in zip(self.mixers, self.ffns), \
+            "a layer has a mixer, a feed-forward or both"
         self.has_experts = "experts" in self.ffns
         self.expert_dim = expert_dim
         self.experts_routed = int(experts_routed)
@@ -218,6 +232,10 @@ class LMConfig:
         self.experts_top_k = int(experts_top_k)
         self.route_scale = float(route_scale)
         self.shared_experts = int(shared_experts)
+        # the shared experts' one MLP is ``shared_experts * expert_dim``
+        # wide unless it has a width of its own
+        self.shared_dim = self.shared_experts * int(expert_dim or 0) \
+            if shared_dim is None else int(shared_dim)
         # a router without a correction bias (its leaf is absent, not a
         # zero that is added), shared experts averaged and not summed
         self.router_bias = bool(router_bias)
@@ -246,7 +264,8 @@ class LMConfig:
             assert expert_dim and 0 <= lo < hi <= self.experts_routed \
                 and 1 <= self.experts_top_k <= self.experts_routed
             if set(m for m, f in zip(self.mixers, self.ffns)
-                   if f == "experts") - {"mla", "attn", "kda", "mamba2"}:
+                   if f == "experts") - {"mla", "attn", "kda", "mamba2",
+                                         "none"}:
                 raise UnsupportedBlock(
                     "an expert feed-forward layer is served beside an "
                     "'mla' or an 'attn' mixer, and of the state layers "
@@ -294,6 +313,13 @@ class LMConfig:
                     "a schedule of 'attn' mixers over whole heads with "
                     "dense feed-forwards only: not beside a state, "
                     "latent, window or expert layer, nor grouped heads")
+        if self.single_part() and (self.has_window or self.parallel_block
+                                   or self.passes > 1):
+            raise UnsupportedBlock(
+                "layers of one part (a mixer alone or a feed-forward "
+                "alone) are served in the sequential block without "
+                "windows or passes: the paged step, make_prefill and "
+                "insert walk them, no span fill does")
         self.ssm_inner = int(ssm_expand) * dim
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
@@ -331,6 +357,7 @@ class LMConfig:
                 and self.kv_heads == self.heads
                 and self.rope and self.ffn == "gelu"
                 and self.ffn_dim == self.dim * self.mlp_mult
+                and set(self.ffns) == {"dense"}
                 and not self.tie_embed and not self.final_norm
                 and self.head_dim * self.heads == self.dim
                 and not self.has_window and all(self.ropes)
@@ -340,6 +367,23 @@ class LMConfig:
                 and self.passes == 1 and not self.post_norms
                 and self.embed_scale == 1.0 and self.residual_scale == 1.0
                 and self.attn_scale is None and self.logit_scale == 1.0)
+
+    def single_part(self) -> bool:
+        """True where some layer is a mixer alone or a feed-forward
+        alone (``"none"`` in ``mixers`` or ``ffns``)."""
+        return "none" in self.mixers or "none" in self.ffns
+
+    def layer_parts(self) -> Dict[str, Dict[str, int]]:
+        """The layers by their parts: ``mixer_only`` and ``ffn_only``
+        by kind, ``both`` by ``"<mixer>+<ffn>"``, each kind with its
+        number of layers."""
+        parts: Dict[str, Dict[str, int]] = {
+            "mixer_only": {}, "ffn_only": {}, "both": {}}
+        for m, f in zip(self.mixers, self.ffns):
+            part, kind = ("ffn_only", f) if m == "none" else \
+                ("mixer_only", m) if f == "none" else ("both", f"{m}+{f}")
+            parts[part][kind] = parts[part].get(kind, 0) + 1
+        return parts
 
     def window_layers(self) -> Tuple[int, ...]:
         return tuple(i for i, w in enumerate(self.windows) if w)
@@ -397,7 +441,9 @@ class LMConfig:
             shared_scale=1.0 / self.shared_experts
             if self.shared_average else 1.0,
             scoring=self.router_scoring,
-            act="relu" if self.ffn == "gated_relu" else "silu")
+            act={"gated_relu": "relu", "relu2": "relu2"}.get(self.ffn,
+                                                               "silu"),
+            shared_dim=self.shared_dim)
 
     def moe_cfg(self):
         from .moe import MoEConfig
@@ -419,9 +465,10 @@ class UnsupportedBlock(NotImplementedError):
 STATE_MIXERS = ("ssm", "kda", "mamba2")
 
 # each mixer kind's letter in ``LMConfig.schedule()`` (a Mamba-2
-# layer's is "M": "m" is latent attention's)
+# layer's is "M": "m" is latent attention's; a layer without a mixer
+# "-")
 MIXER_INITIALS = {"attn": "a", "ssm": "s", "mla": "m", "kda": "k",
-                  "mamba2": "M"}
+                  "mamba2": "M", "none": "-"}
 
 
 def _state_mixer(kind: str):
@@ -439,6 +486,8 @@ def require_plain_block(cfg: LMConfig, what: str) -> None:
     if cfg.plain_block():
         return
     why = "state layers (per-sequence recurrent state)" if cfg.has_state \
+        else "layers of one part (a mixer alone or a feed-forward " \
+        "alone)" if cfg.single_part() \
         else "latent attention (an 'mla' mixer and its latent cache)" \
         if cfg.has_latent \
         else "window layers (a page class that gives pages back)" \
@@ -521,26 +570,29 @@ def _init_block_params(rng, cfg: LMConfig) -> Dict[str, Any]:
         # the exit gate of a looped schedule (see ``LMConfig``)
         params["exit_w"] = normal(ks[1], (d,), d)
         params["exit_b"] = jnp.zeros((), jnp.float32)
-    gated = cfg.ffn != "gelu"
+    gated = cfg.ffn not in ("gelu", "relu2")
     for i in range(cfg.depth):
         bk = jax.random.split(ks[2 + i], 5)
+        # a part the layer does not have has no leaves, its norm none
+        blk = {}
         if cfg.mixers[i] in STATE_MIXERS:
             blk = _state_mixer(cfg.mixers[i]).init_layer(bk[0], cfg)
         elif cfg.mixers[i] == "mla":
             blk = mla_mixer.init_layer(bk[0], cfg)
-        else:
+        elif cfg.mixers[i] == "attn":
             blk = {"wqkv": normal(
                 bk[0], (d, (cfg.heads + 2 * cfg.kv_heads) * hd), d),
                 "wo": normal(bk[1], (cfg.heads * hd, d), cfg.heads * hd)}
-        blk["ln1"] = jnp.ones((d,), jnp.float32)
-        if not cfg.parallel_block:
+        if cfg.mixers[i] != "none":
+            blk["ln1"] = jnp.ones((d,), jnp.float32)
+        if not cfg.parallel_block and cfg.ffns[i] != "none":
             blk["ln2"] = jnp.ones((d,), jnp.float32)
         if cfg.post_norms:
             blk["pn1"] = jnp.ones((d,), jnp.float32)
             blk["pn2"] = jnp.ones((d,), jnp.float32)
         if cfg.ffns[i] == "experts":
             blk["moe"] = moe.init_served(bk[2], cfg.expert_cfg())
-        else:
+        elif cfg.ffns[i] == "dense":
             blk["w1"] = normal(bk[2], (d, 2 * f if gated else f), d)
             blk["w2"] = normal(bk[3], (f, d), f)
         params[f"blk{i}"] = blk
@@ -627,8 +679,8 @@ def _split_qkv(cfg: LMConfig, qkv):
 def _ffn(cfg: LMConfig, bp, h):
     """The feed-forward part of every serving layer: ``gelu(h w1) w2``;
     gated, ``(act(gate) * up) w2`` with gate and up side by side in
-    ``w1``, ``act`` the SiLU or the ReLU; or the Mixture-of-Experts FFN
-    (models/moe.py)."""
+    ``w1``, ``act`` the SiLU or the ReLU; ``relu(h w1)^2 w2``; or the
+    Mixture-of-Experts FFN (models/moe.py)."""
     import jax
     import jax.numpy as jnp
 
@@ -642,6 +694,8 @@ def _ffn(cfg: LMConfig, bp, h):
         out, _aux = forward_grouped(bp["moe"], h, cfg.moe_cfg())
         return out
     up = qmatmul(h, bp["w1"])
+    if cfg.ffn == "relu2":
+        return qmatmul(jnp.square(jax.nn.relu(up)), bp["w2"])
     if cfg.ffn != "gelu":
         gate, up = jnp.split(up, 2, axis=-1)
         act = jax.nn.silu if cfg.ffn == "gated_silu" else jax.nn.relu
@@ -686,8 +740,11 @@ def _ffn_part(cfg: LMConfig, i: int, bp, h, live, routed=None):
 
 
 def _ffn_scheduled(cfg: LMConfig, i: int, bp, x, live, routed=None):
-    """Layer ``i``'s second half: norm, :func:`_ffn_part`, residual.
-    Returns ``(x, counts)``."""
+    """Layer ``i``'s second half: norm, :func:`_ffn_part`, residual;
+    ``x`` as it is where the layer has no feed-forward.  Returns ``(x,
+    counts)``."""
+    if cfg.ffns[i] == "none":
+        return x, None
     if cfg.ffns[i] != "experts":
         return _ffn_residual(cfg, bp, x), None
     out, counts = _ffn_part(cfg, i, bp, _norm(cfg, x, bp["ln2"]), live,
@@ -981,7 +1038,11 @@ def make_prefill(cfg: LMConfig):
         cache = {"len": jnp.int32(s)}
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
-            if cfg.mixers[i] in STATE_MIXERS:
+            if cfg.mixers[i] == "none":
+                # a layer of its feed-forward alone
+                x, _counts = _ffn_scheduled(
+                    cfg, i, bp, x, (jnp.arange(s) < ctx_len)[None])
+            elif cfg.mixers[i] in STATE_MIXERS:
                 # (a dense feed-forward's ``_ffn_scheduled`` is
                 # ``_ffn_residual``; the bucket's padding is routed to
                 # no expert)
@@ -1342,7 +1403,11 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
         counts = []
         for i in range(cfg.depth):
             bp = params[f"blk{i}"]
-            if cfg.mixers[i] == "mla":
+            if cfg.mixers[i] == "none":
+                # a layer of its feed-forward alone; an idle slot's row
+                # is routed to no expert
+                x, cnt = _ffn_scheduled(cfg, i, bp, x, active[:, None])
+            elif cfg.mixers[i] == "mla":
                 # the slot's latent row is written, then the absorbed
                 # attention reads its live pages; an idle slot's row is
                 # routed to no expert
@@ -1353,8 +1418,6 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                 x, cnt = _ffn_scheduled(cfg, i, bp,
                                         _residual(cfg, x, out[:, None]),
                                         active[:, None])
-                if cnt is not None:
-                    counts.append(cnt)
             elif cfg.mixers[i] in STATE_MIXERS:
                 # the slot's recurrent state moves one position where
                 # the slot is active and stays where it is not (a
@@ -1369,15 +1432,13 @@ def make_paged_batch_decode(cfg: LMConfig, page: int,
                                         _residual(cfg, x, out[:, None]),
                                         active[:, None])
                 cache[f"sh{i}"], cache[f"sc{i}"] = h, tail
-                if cnt is not None:
-                    counts.append(cnt)
             else:
                 x, pk, pv, cnt = attn_layer(
                     i, bp, x, cache[f"pk{i}"], cache[f"pv{i}"],
                     bts[bool(cfg.windows[i])], pos, att_pos, rot, active)
                 cache[f"pk{i}"], cache[f"pv{i}"] = pk, pv
-                if cnt is not None:
-                    counts.append(cnt)
+            if cnt is not None:
+                counts.append(cnt)
         cache["len"] = jnp.where(active, cache["len"] + 1,
                                  cache["len"])
         if counts:
@@ -1854,6 +1915,8 @@ def make_paged_io(cfg: LMConfig, page: int, chunk: Optional[int] = None):
         cache = dict(cache)
         shape = _paged_pool_shape(cfg, pps, page)
         for i in range(cfg.depth):
+            if cfg.mixers[i] == "none":
+                continue
             if cfg.mixers[i] in STATE_MIXERS:
                 for pool, new in ((f"sh{i}", f"h{i}"), (f"sc{i}", f"c{i}")):
                     cache[pool] = jax.lax.dynamic_update_slice(

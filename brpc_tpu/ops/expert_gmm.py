@@ -3,10 +3,11 @@
 ``xs (M, K)`` holds the rows that fell on the experts held here,
 sorted by expert, at the FRONT of a buffer sized for the worst case;
 ``sizes (n,)`` says how many rows each expert took; ``w (n, K, N)`` are
-the experts' weights as stored.  Row ``r`` of group ``g`` gives ``xs[r]
-@ w[g]``.  A decode step puts ~16 rows into a 512-row buffer, 1-3 an
-expert, so the product is bound by the bytes of the TOUCHED experts'
-weights and by nothing else, if the kernel lets it be:
+the experts' weights as stored (or ``(n, N, K)``, ``transposed``).  Row
+``r`` of group ``g`` gives ``xs[r] @ w[g]`` (``xs[r] @ w[g].T``).  A
+decode step puts ~16 rows into a 512-row buffer, 1-3 an expert, so the
+product is bound by the bytes of the TOUCHED experts' weights and by
+nothing else, if the kernel lets it be:
 
 - the grid walks VISITS, not the buffer: a visit is one (row tile,
   expert) pair that has rows.  The list is built from ``sizes``
@@ -79,8 +80,11 @@ def _blocks(k: int, n: int, itemsize: int, block_bytes: int):
     """``(tk, tn)``: the whole contraction and the widest column block
     of whole 128-lane tiles that divides ``n`` and fits ``block_bytes``;
     where a 128-wide slab of the whole contraction does not fit, the
-    contraction is cut too.  Widths that are no multiple of 128 (toy
-    sizes) go whole."""
+    contraction is cut too.  A width that is no multiple of 128 goes
+    whole: an output tile's lanes must be whole tiles or the whole row
+    (a published expert width takes this branch, nemotron-3-nano-30b's
+    1,856: its up product's block is ``(896, 1856)``, its down
+    product's ``(1856, 896)``; the toys' widths take it too)."""
     def widest(full: int, other: int) -> int:
         fits = [t for t in range(128, full + 1, 128)
                 if full % t == 0 and t * other * itemsize <= block_bytes]
@@ -98,7 +102,7 @@ def _row_tile(m: int, tm: int) -> int:
 
 
 def _kernel(offs_ref, group_ref, tile_ref, x_ref, w_ref, o_ref, acc, *,
-            tm: int):
+            tm: int, transposed: bool = False):
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -109,8 +113,15 @@ def _kernel(offs_ref, group_ref, tile_ref, x_ref, w_ref, o_ref, acc, *,
     def _():
         acc[...] = jnp.zeros_like(acc)
 
-    acc[...] += jnp.dot(x_ref[...], w_ref[...],
-                        preferred_element_type=jnp.float32)
+    if transposed:
+        # a ``(tn, tk)`` block of the weights as they lie: the MXU takes
+        # its rows as the product's columns
+        acc[...] += lax.dot_general(x_ref[...], w_ref[...],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    else:
+        acc[...] += jnp.dot(x_ref[...], w_ref[...],
+                            preferred_element_type=jnp.float32)
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _():
@@ -123,14 +134,15 @@ def _kernel(offs_ref, group_ref, tile_ref, x_ref, w_ref, o_ref, acc, *,
         o_ref[...] = jnp.where(mine, acc[...], held)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("tm", "block_bytes", "interpret"))
-def _call(xs, w, sizes, tm: int, block_bytes: int, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("tm", "block_bytes", "interpret",
+                                             "transposed"))
+def _call(xs, w, sizes, tm: int, block_bytes: int, interpret: bool,
+          transposed: bool = False):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    (m, k), n = xs.shape, w.shape[2]
+    (m, k), n = xs.shape, w.shape[1 if transposed else 2]
     tm = _row_tile(m, tm)
     tiles_m = -(-m // tm)
     if tiles_m * tm != m:
@@ -138,17 +150,23 @@ def _call(xs, w, sizes, tm: int, block_bytes: int, interpret: bool):
     tk, tn = _blocks(k, n, w.dtype.itemsize, block_bytes)
     with jax.named_scope("moe_route"):      # ``models/moe.py serve``'s
         offsets, group, tile, visits = visit_list(sizes, tiles_m, tm)
+    if transposed:
+        w_spec = pl.BlockSpec((None, tn, tk), lambda j, v, kb, offs, grp,
+                              til: (grp[v], j, kb))
+        body = functools.partial(_kernel, tm=tm, transposed=True)
+    else:
+        w_spec = pl.BlockSpec((None, tk, tn), lambda j, v, kb, offs, grp,
+                              til: (grp[v], kb, j))
+        body = functools.partial(_kernel, tm=tm)
     out = pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        body,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n // tn, visits, k // tk),
             in_specs=[
                 pl.BlockSpec((tm, tk),
                              lambda j, v, kb, offs, grp, til: (til[v], kb)),
-                pl.BlockSpec((None, tk, tn),
-                             lambda j, v, kb, offs, grp, til:
-                             (grp[v], kb, j)),
+                w_spec,
             ],
             out_specs=pl.BlockSpec(
                 (tm, tn), lambda j, v, kb, offs, grp, til: (til[v], j)),
@@ -167,14 +185,19 @@ def _call(xs, w, sizes, tm: int, block_bytes: int, interpret: bool):
 
 def expert_gmm(xs, w, sizes, tm: int = _ROW_TILE,
                block_bytes: int = _BLOCK_BYTES,
-               interpret: Optional[bool] = None):
+               interpret: Optional[bool] = None, transposed: bool = False):
     """``xs (M, K)`` rows sorted by group from row 0, ``w (n, K, N)``,
     ``sizes (n,)`` int32 -> ``(M, N)`` float32: row ``r`` of group
     ``g`` is ``xs[r] @ w[g]``, operands as they come (bfloat16 in the
-    served program), accumulated in float32.  What
+    served program), accumulated in float32.  ``transposed``: ``w`` is
+    ``(n, N, K)`` and row ``r`` gives ``xs[r] @ w[g].T`` (weights whose
+    ``N`` is no multiple of 128 lie so: the device's default layout of
+    ``(n, K, N)`` would put ``K`` on the lanes, and the kernel, which
+    reads its operands row-major, would have them copied each call).  What
     ``jax.lax.ragged_dot`` gives, but for the rows behind the last
     group: those of a row tile some group reaches read 0, the others
     are NOT WRITTEN.  The cost follows the rows present: a grid step a
     (row tile, group) pair with rows, a weight fetch a touched group."""
     return _call(xs, w, sizes, tm=tm, block_bytes=block_bytes,
-                 interpret=_resolve_interpret(interpret))
+                 interpret=_resolve_interpret(interpret),
+                 transposed=transposed)

@@ -496,7 +496,7 @@ def test_the_shares_add_up_to_the_uncut_layer(f32_matmuls):
     half = cfg["num_local_experts"]
     with jax.default_matmul_precision("highest"):
         uncut = m._experts(t, p, cfg, False, held=(0, routed))
-        shared = moe._gated(t, p["ws1"], p["ws2"])
+        shared = moe._mlp(share(0, routed), t, p["ws1"], p["ws2"])
         total, pairs = shared, 0
         for lo in (0, half):
             mine = {**p, "w1": p["w1"][lo:lo + half],
@@ -646,6 +646,7 @@ def test_batcher_serves_the_references_tokens_and_counts_the_state(
     assert kv["state"]["inserts"] == 3 and kv["state"]["releases"] == 3
     # buckets of 4, 64 and 1 positions, chunks of 16: 1 + 4 + 1
     assert kv["mamba2"] == {"layers": 4, "slot_bytes": slot_bytes,
+                            "groups": 2, "norm_group": 128,
                             "steps": kv["steps"], "slot_steps": 18,
                             "fills": 3, "fill_rows": 4 + 39 + 0,
                             "chunks": 6}

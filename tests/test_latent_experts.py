@@ -266,8 +266,8 @@ def test_nothing_is_dropped_where_the_capacity_layer_drops(f32_matmuls):
     # 32 pairs on 2 experts, 16 rows each
     assert counts.tolist() == [32, 2, 16]
     ids, w = moe.route(p, x[:1], ecfg)
-    hand = sum(float(w[0, k]) * np.asarray(moe._gated(
-        x[:1], p["w1"][int(ids[0, k])], p["w2"][int(ids[0, k])]))
+    hand = sum(float(w[0, k]) * np.asarray(moe._mlp(
+        ecfg, x[:1], p["w1"][int(ids[0, k])], p["w2"][int(ids[0, k])]))
         for k in range(2))
     np.testing.assert_allclose(out[:1], hand, atol=1e-5)
 
@@ -286,8 +286,8 @@ def test_rows_that_are_not_live_route_nowhere(f32_matmuls):
                                np.asarray(only), atol=1e-5)
     # a row that is not live keeps the shared expert alone
     np.testing.assert_allclose(
-        np.asarray(out[1:2]), np.asarray(moe._gated(x[1:2], p["ws1"],
-                                                    p["ws2"])), atol=1e-5)
+        np.asarray(out[1:2]), np.asarray(moe._mlp(ecfg, x[1:2], p["ws1"],
+                                                  p["ws2"])), atol=1e-5)
     assert ecfg.buffer_rows(6) == 18
 
 
@@ -307,7 +307,7 @@ def test_the_shares_add_up_to_the_uncut_layer(f32_matmuls):
                     .astype(np.float32))
     with jax.default_matmul_precision("highest"):
         uncut = m._experts(t, p, cfg, False, held=(0, routed))
-        shared = moe._gated(t, p["ws1"], p["ws2"])
+        shared = moe._mlp(whole, t, p["ws1"], p["ws2"])
         total, pairs = shared, 0
         for lo in range(0, routed, 4):
             share = moe.ExpertConfig(dim=d, hidden=e, routed=routed,

@@ -399,7 +399,7 @@ def test_the_shares_add_up_to_the_uncut_layer(f32_matmuls):
                     .astype(np.float32))
     with jax.default_matmul_precision("highest"):
         uncut = m._experts(t, p, cfg, False, held=(0, routed))
-        shared = moe._gated(t, p["ws1"], p["ws2"])
+        shared = moe._mlp(whole, t, p["ws1"], p["ws2"])
         total, pairs = shared, 0
         for lo in range(0, routed, 4):
             share = moe.ExpertConfig(dim=d, hidden=e, routed=routed,

@@ -361,13 +361,16 @@ def test_window_kernel_at_a_group_of_sixteen_compiles_for_the_v5e(
 
 
 @pytest.mark.parametrize("slots,heads,kvh,pages", [
-    (64, 32, 8, 8193), (8, 20, 1, 1025)], ids=["granite", "jamba"])
+    (64, 32, 8, 8193), (8, 20, 1, 1025), (128, 32, 2, 16385)],
+    ids=["granite", "jamba", "nemotron"])
 def test_grouped_kernel_compiles_for_the_v5e(slots, heads, kvh, pages,
                                              one_chip):
     """Grouped heads with no window, float32-accurate, at
     ``granite-4.0-h-small.codegen``'s shapes (64 slots of 32 query heads
-    on 8 key/value heads, 8 pages of 64 KB a wave) and
-    ``jamba2-3b.chat``'s (8 slots of 20 on 1, 64 pages of 8 KB a wave),
+    on 8 key/value heads, 8 pages of 64 KB a wave),
+    ``jamba2-3b.chat``'s (8 slots of 20 on 1, 64 pages of 8 KB a wave)
+    and ``nemotron-3-nano-30b.codegen``'s (128 slots of 32 on 2: groups
+    of sixteen),
     a 128-wide table: ONE custom call under the name a device trace is
     read by, the pools in as they lie; nothing is run."""
     def arg(shape, dtype):
@@ -510,6 +513,64 @@ def test_expert_gmm_over_64_small_experts_compiles_for_the_v5e(
         rf"%expert_gmm[.\d]* = f32\[{padded},{n}\]\S* custom-call",
         compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("rows", [768, 6144])
+@pytest.mark.parametrize("product", ["up", "down"])
+def test_expert_gmm_at_14_and_a_half_lane_tiles_compiles_for_the_v5e(
+        product, rows, one_chip):
+    """The grouped product at ``nemotron-3-nano-30b``'s expert width of
+    1,856 (no whole lane tiles: the whole-width blocks), the step's 128
+    x 6 rows and a 1,024-row bucket's, 64 held experts: up with the
+    weights as an ungated expert lays them, ``(64, 1856, 2688)`` read
+    transposed, and down ``(64, 1856, 2688)``.  The weights go in as
+    they lie, with no copy; laid ``(64, 2688, 1856)`` the up weights are
+    copied into the kernel's row-major layout on every call."""
+    from brpc_tpu.ops import expert_gmm as eg
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up = product == "up"
+    k, n = (2688, 1856) if up else (1856, 2688)
+    assert eg._blocks(k, n, 2, eg._BLOCK_BYTES) == (
+        (896, 1856) if up else (1856, 896))
+
+    def compiled(transposed):
+        return jax.jit(
+            lambda *a: eg.expert_gmm(*a, interpret=False,
+                                     transposed=transposed)
+        ).lower(arg((rows, k), jnp.bfloat16),
+                arg((64, n, k) if transposed else (64, k, n), jnp.bfloat16),
+                arg((64,), jnp.int32)).compile().as_text()
+
+    text = compiled(up)
+    assert re.search(rf"%expert_gmm[.\d]* = f32\[{rows},{n}\]\S* custom-call",
+                     text)
+    assert not re.search(r"= bf16\[64,\d+,\d+\]\S* copy\(", text)
+    if up:
+        assert re.search(r"= bf16\[64,2688,1856\]\S* copy\(",
+                         compiled(False))
+
+
+def test_ssd_step_in_eight_groups_compiles_for_the_v5e(one_chip):
+    """The SSD update at ``nemotron-3-nano-30b.codegen``'s shapes: 128
+    slots of 64 heads of 64 x 128 in 8 groups (four rows of lanes a
+    group), the pool in place; nothing is run."""
+    from brpc_tpu.ops import ssd
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = (128,) + ssd.pool_shape(64, 64, 128)
+    assert pool == (128, 32, 128, 128)
+    compiled = jax.jit(
+        lambda *a: ssd.ssd_step(*a, interpret=False), donate_argnums=(6,)
+    ).lower(arg((128, 64, 64)), arg((128, 64)), arg((64,)),
+            arg((128, 8, 128)), arg((128, 8, 128)), arg((64,)), arg(pool),
+            arg((128,), jnp.bool_)).compile()
+    assert re.search(r"%ssd_step[.\w]* = \(f32\[128,32,128,128\]",
+                     compiled.as_text())
 
 
 # -- the counter ------------------------------------------------------------
