@@ -22,7 +22,11 @@ The arithmetic is float32 throughout, as XLA runs the einsums of
 Where fewer key/value heads serve groups of query heads the pool is
 ``(num_pages, page * kv_heads, hd)``, a row a (token, key/value head)
 pair, and the window kernel walks it with no window, under the same
-name, its products float32 at ``highest`` (:func:`_window_kernel`).
+name, its products float32-accurate (:func:`_window_kernel`): each
+float32 operand is three bfloat16 parts whose sum is exactly the value
+(:func:`_parts`), a page tile is handed to the MXU once a part, and the
+nine products of the parts are summed in float32
+(:func:`_exact_product`).
 """
 
 from __future__ import annotations
@@ -221,8 +225,8 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
     """:func:`paged_decode_attention` for ``heads`` query heads over
     fewer key/value heads: the pools are ``(num_pages, page *
     kv_heads, hd)``, a row a (token, key/value head) pair.  The window
-    kernel's walk with no window, its products float32 at ``highest``
-    (the arithmetic of :func:`reference`)."""
+    kernel's walk with no window, its products float32-accurate in
+    three exact bfloat16 parts (:func:`_exact_product`)."""
     return _window_call(q, pk, pv, bt, pos, page=page, window=0,
                         scale=scale, exact=True,
                         interpret=_resolve_interpret(interpret))
@@ -242,12 +246,16 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
 # ``(tokens, hd)`` rows on the MXU.  A window schedule's products take
 # bfloat16 operands with float32 accumulation, as its configurations
 # state; grouped heads with no window
-# (:func:`paged_decode_attention_grouped`) take float32 ones at
-# ``highest``, the arithmetic of :func:`reference`: a wave's six passes
-# a product still take fewer instruction bundles than its page copies
-# take time (``chip_window.py bundles``).  A window layer's call is
-# ``window_decode_attention`` in a device trace, a global layer's and a
-# grouped one's ``paged_decode_attention``.
+# (:func:`paged_decode_attention_grouped`) keep float32's accuracy:
+# each float32 tile is split on the VPU into three bfloat16 parts that
+# sum to it exactly, the big operand's (a key or value tile's) parts are
+# handed to the MXU one pass each and the small operand's (the group's
+# queries, or its weights) ride every pass stacked on the rows, so a
+# tile is pushed three times where ``highest`` pushed it six, and the
+# nine products of the parts, each exact, are summed in float32
+# (``chip_window.py bundles`` counts the pushes and bundles a wave).  A
+# window layer's call is ``window_decode_attention`` in a device trace,
+# a global layer's and a grouped one's ``paged_decode_attention``.
 
 # waves the window kernel keeps in VMEM: the one it computes and five
 # whose pages are on their way (6 x 512 KB x 2 pools).  Measured on the
@@ -257,6 +265,59 @@ def paged_decode_attention_grouped(q, pk, pv, bt, pos, page: int,
 # whose instructions take what sixteen pages' do) and eight 6% (224
 # copies in flight); at 64-KB pages four to eight read the same
 _WINDOW_RING = 6
+
+
+def _parts(x):
+    """Three parts of float32 ``x``, each a bfloat16 value held in
+    float32, whose sum is ``x`` exactly: its leading eight significant
+    bits, the next eight, the rest (eight at most).  Cut, not rounded,
+    so no part overflows; exact wherever ``x`` and its parts are
+    normal numbers (``|x| >= 2**-102``)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def cut(y):
+        bits = lax.bitcast_convert_type(y, jnp.int32) & jnp.int32(-65536)
+        return lax.bitcast_convert_type(bits, jnp.float32)
+
+    hi = cut(x)
+    rest = x - hi
+    mid = cut(rest)
+    return hi, mid, rest - mid
+
+
+def _stacked(x):
+    """``x (n, m)`` float32 -> its three parts stacked on the rows,
+    ``(3 n, m)`` bfloat16."""
+    import jax.numpy as jnp
+
+    return jnp.concatenate(_parts(x), axis=0).astype(jnp.bfloat16)
+
+
+def _exact_product(a3, b, n: int, keys: bool):
+    """The float32 product of ``a`` and ``b (rows, m)`` from ``a``'s
+    stacked parts ``a3 (3 n, .)`` and ``b``'s three parts, one MXU pass
+    a part of ``b``: ``a . b^T`` (``keys``: ``(n, rows)``) or ``a . b``
+    (``(n, m)``).  ``b``'s tiles are the weights the MXU is handed,
+    three times where ``highest`` hands them six; the nine products of
+    the parts are exact in bfloat16 x bfloat16 -> float32 and summed in
+    float32.  ``b`` is split 128 rows at a time, beside the passes that
+    take them."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    dims = (((1,), (1,)), ((), ())) if keys else (((1,), (0,)), ((), ()))
+    out = []
+    for r in range(0, b.shape[0], 128):
+        lhs = a3 if keys else a3[:, r:r + 128]
+        y = None
+        for part in _parts(b[r:r + 128]):
+            z = lax.dot_general(lhs, part.astype(jnp.bfloat16), dims,
+                                preferred_element_type=jnp.float32)
+            y = z if y is None else y + z
+        out.append(y)
+    out = jnp.concatenate(out, axis=1) if keys else sum(out[1:], out[0])
+    return out[:n] + out[n:2 * n] + out[2 * n:]
 
 
 def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
@@ -272,8 +333,8 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     with no page to fetch is a predicated instruction), so the copies'
     scalar work runs beside the products' vector work.  A key page and
     its value page signal one semaphore, the buffer's.  ``exact``: the
-    products float32 at ``highest`` (the arithmetic of
-    :func:`reference`); else bfloat16 operands."""
+    products float32-accurate, in three exact bfloat16 parts
+    (:func:`_exact_product`); else bfloat16 operands."""
     import jax.numpy as jnp
     from jax import lax
     from jax.experimental import pallas as pl
@@ -286,9 +347,6 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     ring = kbuf.shape[0]
     toks = wave * page
     rows = page * kv_heads
-    # the products' operands and precision
-    dt, prec = (jnp.float32, lax.Precision.HIGHEST) if exact \
-        else (jnp.bfloat16, None)
 
     def first_page(s):
         """The page of the first position slot ``s`` attends."""
@@ -341,8 +399,67 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
     pos = pos_ref[b]
     base = first_page(b) * page
     scale = 1.0 / hd ** 0.5 if scale is None else scale
-    q = (q_ref[0] * scale).astype(dt)                     # (heads, hd)
+    q = q_ref[0] * scale                                  # (heads, hd)
+    if exact:
+        # a group's queries, their three parts stacked: (3 g, hd)
+        q3 = [_stacked(q[kh * g:(kh + 1) * g]) for kh in range(kv_heads)]
+    else:
+        q = q.astype(jnp.bfloat16)
     nt = (((1,), (1,)), ((), ()))
+
+    def exact_heads(buf, hi, lo, m, l, acc):
+        """The exact form's wave, in three phases over the key/value
+        heads: every head's scores, then every head's softmax, then
+        every head's weighted values, so one head's MXU passes run while
+        another's softmax is on the VPU (a head at a time puts them in
+        series: 1,617 bundles a wave at granite's shapes, 1,107 so)."""
+        ok_col, ok_row = tok_col < hi, tok_row < hi
+        if window:
+            ok_col = jnp.logical_and(ok_col, tok_col >= lo)
+            ok_row = jnp.logical_and(ok_row, tok_row >= lo)
+        sel = [pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1
+               else pl.ds(0, toks) for kh in range(kv_heads)]
+        s = [jnp.where(ok_row, _exact_product(q3[kh], kbuf[buf, sel[kh], :],
+                                              g, keys=True), -1e30)
+             for kh in range(kv_heads)]                   # (g, toks)
+        m_new = [jnp.maximum(mk, sk.max(axis=1, keepdims=True))
+                 for mk, sk in zip(m, s)]
+        corr = [jnp.exp(mk - nk) for mk, nk in zip(m, m_new)]
+        p = [jnp.exp(sk - nk) for sk, nk in zip(s, m_new)]
+        l_new = [lk * ck + pk.sum(axis=1, keepdims=True)
+                 for lk, ck, pk in zip(l, corr, p)]
+        # a weight of zero on a stale row is still 0 * NaN
+        acc_new = [acc[kh] * corr[kh] + _exact_product(
+            _stacked(p[kh]), jnp.where(ok_col, vbuf[buf, sel[kh], :], 0.0),
+            g, keys=False) for kh in range(kv_heads)]
+        return m_new, l_new, acc_new
+
+    def bf16_heads(buf, hi, lo, m, l, acc):
+        """The bfloat16 form's wave, a key/value head at a time."""
+        ms, ls, accs = [], [], []
+        for kh in range(kv_heads):
+            sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
+                else pl.ds(0, toks)
+            k = kbuf[buf, sel, :].astype(jnp.bfloat16)    # (toks, hd)
+            ok_col = tok_col < hi
+            ok_row = tok_row < hi
+            if window:
+                ok_col = jnp.logical_and(ok_col, tok_col >= lo)
+                ok_row = jnp.logical_and(ok_row, tok_row >= lo)
+            # a weight of zero on a stale row is still 0 * NaN
+            v = jnp.where(ok_col, vbuf[buf, sel, :], 0.0).astype(jnp.bfloat16)
+            s = lax.dot_general(q[kh * g:(kh + 1) * g], k, nt,
+                                preferred_element_type=jnp.float32)
+            s = jnp.where(ok_row, s, -1e30)               # (g, toks)
+            m_new = jnp.maximum(m[kh], s.max(axis=1, keepdims=True))
+            corr = jnp.exp(m[kh] - m_new)
+            p = jnp.exp(s - m_new)
+            ms.append(m_new)
+            ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
+            accs.append(acc[kh] * corr + jnp.dot(
+                p.astype(jnp.bfloat16), v,
+                preferred_element_type=jnp.float32))
+        return ms, ls, accs
 
     def wave_body(w, carry):
         m, l, acc = carry
@@ -355,30 +472,8 @@ def _window_kernel(bt_ref, pos_ref, q_ref, pk_hbm, pv_hbm, o_ref,
         t0 = base + w * toks
         hi = pos + 1 - t0
         lo = pos - window + 1 - t0 if window else -1
-        ms, ls, accs = [], [], []
-        for kh in range(kv_heads):
-            sel = pl.ds(kh, toks, stride=kv_heads) if kv_heads > 1 \
-                else pl.ds(0, toks)
-            k = kbuf[buf, sel, :].astype(dt)              # (toks, hd)
-            ok_col = tok_col < hi
-            ok_row = tok_row < hi
-            if window:
-                ok_col = jnp.logical_and(ok_col, tok_col >= lo)
-                ok_row = jnp.logical_and(ok_row, tok_row >= lo)
-            # a weight of zero on a stale row is still 0 * NaN
-            v = jnp.where(ok_col, vbuf[buf, sel, :], 0.0).astype(dt)
-            s = lax.dot_general(q[kh * g:(kh + 1) * g], k, nt,
-                                precision=prec,
-                                preferred_element_type=jnp.float32)
-            s = jnp.where(ok_row, s, -1e30)               # (g, toks)
-            m_new = jnp.maximum(m[kh], s.max(axis=1, keepdims=True))
-            corr = jnp.exp(m[kh] - m_new)
-            p = jnp.exp(s - m_new)
-            ms.append(m_new)
-            ls.append(l[kh] * corr + p.sum(axis=1, keepdims=True))
-            accs.append(acc[kh] * corr + jnp.dot(
-                p.astype(dt), v, precision=prec,
-                preferred_element_type=jnp.float32))
+        ms, ls, accs = (exact_heads if exact else bf16_heads)(
+            buf, hi, lo, m, l, acc)
         # into the buffer the wave before this one was read from
         ahead = (b, w)
         for _ in range(ring - 1):

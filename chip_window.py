@@ -9,7 +9,9 @@ widths of the cells that run it (pages of 16 tokens of float32):
   pools of 4,193 and 9,601;
 - ``granite`` (``granite-4.0-h-small.codegen``): 64 slots, 32 on 8
   (64 KB), a pool of 8,193 pages, the softmax's scale 1/128;
-- ``jamba`` (``jamba2-3b.chat``): 8 slots, 20 on 1 (8 KB), 1,025 pages.
+- ``jamba`` (``jamba2-3b.chat``): 8 slots, 20 on 1 (8 KB), 1,025 pages;
+- ``nemotron`` (``nemotron-3-nano-30b.codegen``): 128 slots, 32 on 2
+  (16 KB), a pool of 16,385 pages.
 
     chiprun -- python3 chip_window.py [seed] [another paged_attention.py]
                                       [shape,shape,...]
@@ -17,8 +19,8 @@ widths of the cells that run it (pages of 16 tokens of float32):
 The first two shapes (an 816-wide table, live lengths uniform
 2,048-13,056 a slot) run the bfloat16 form, under ``window`` 4,096
 (``window_decode_attention`` in the trace) and 0
-(``paged_decode_attention``); the last two (a 128-wide table, live
-lengths uniform 128-2,047) run the float32 form at window 0
+(``paged_decode_attention``); the last three (a 128-wide table, live
+lengths uniform 128-2,047) run the float32-accurate form at window 0
 (``paged_decode_attention_grouped``):
 
 - the kernel as the tree has it, then with rings of 2 to 8 buffers,
@@ -42,17 +44,17 @@ values) and over the rows the model needs (what
 measurement, appended to ``chiprun_out/window.jsonl``.  Exits non-zero
 without a TPU, or where a kernel is further from the reference (float32
 at ``highest``) than its form's gate: 3e-2 for bfloat16 operands (they
-read ~5e-3 of a unit-variance value), 1e-4 for float32 products at
-``highest``.
+read ~5e-3 of a unit-variance value), 1e-4 for the float32-accurate
+form.
 
     python3 chip_window.py bundles [another paged_attention.py]
 
-No chip: compiles the float32 form, the bfloat16 form and the other
-file's grouped kernel at ``granite``'s shapes for a described v5e with
-the compiler's instruction listing on (a child process a form, which the
-compiler ends with an abort AFTER it has written the listing), and
-prints each one's bundles, in all and in the loop over a wave, with the
-MXU's weight pushes and products there.
+No chip: compiles the float32-accurate form, the bfloat16 form and the
+other file's grouped kernel at each float32-accurate shape for a
+described v5e with the compiler's instruction listing on (a child
+process a form and shape, which the compiler ends with an abort AFTER it
+has written the listing), and prints each one's bundles, in all and in
+the loop over a wave, with the MXU's weight pushes and products there.
 """
 import contextlib
 import glob
@@ -82,9 +84,10 @@ PAGE, HD, WINDOW = 16, 128, 4096
 SHAPES = {"smallthinker": (32, 28, 4, 8321, 15361),
           "command-a": (16, 128, 8, 4193, 9601),
           "granite": (64, 32, 8, None, 8193),
-          "jamba": (8, 20, 1, None, 1025)}
+          "jamba": (8, 20, 1, None, 1025),
+          "nemotron": (128, 32, 2, None, 16385)}
 # the float32-accurate shapes: the softmax's scale (None: hd^-0.5)
-EXACT = {"granite": 0.0078125, "jamba": None}
+EXACT = {"granite": 0.0078125, "jamba": None, "nemotron": None}
 # (table width, live lengths) by form
 TABLES = {False: (816, (2048, 13056)), True: (128, (128, 2048))}
 HBM_GBS = 819.0
@@ -277,10 +280,9 @@ def _load(path):
     return mod
 
 
-def bundle_variants(path: str) -> dict:
-    """The forms whose bundles :func:`bundles` counts, at ``granite``'s
-    shapes."""
-    scale = EXACT["granite"]
+def bundle_variants(path: str, shape: str) -> dict:
+    """The forms whose bundles :func:`bundles` counts, at ``shape``."""
+    scale = EXACT[shape]
     mine = dict(mod=pa, exact=True, scale=scale)
     out = {"tree": mine, "bf16": dict(mod=pa, scale=scale)}
     if path:
@@ -288,23 +290,23 @@ def bundle_variants(path: str) -> dict:
     return out
 
 
-def compile_for_v5e(which: str, path: str) -> None:
-    """Compile one form at ``granite``'s shapes for a described v5e (a
-    child of :func:`bundles`, with the listing's flags in its
-    environment)."""
+def compile_for_v5e(which: str, path: str, shape: str) -> None:
+    """Compile one form at ``shape`` for a described v5e (a child of
+    :func:`bundles`, with the listing's flags in its environment)."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
-    slots, heads, kvh, _w, pages = SHAPES["granite"]
+    slots, heads, kvh, _w, pages = SHAPES[shape]
     table = TABLES[True][0]
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     pool = arg((pages, PAGE * kvh, HD), jnp.float32)
-    fn = kernel(window=0, interpret=False, **bundle_variants(path)[which])
+    fn = kernel(window=0, interpret=False,
+                **bundle_variants(path, shape)[which])
     jax.jit(fn).lower(arg((slots, heads, HD), jnp.float32), pool, pool,
                       arg((slots, table), jnp.int32),
                       arg((slots,), jnp.int32)).compile()
@@ -312,8 +314,8 @@ def compile_for_v5e(which: str, path: str) -> None:
 
 def count_bundles(dump: str) -> dict:
     """The kernel's final bundles: in all, and in the loop over a wave
-    (the inner loop's header to its back edge), with the weight pushes
-    and products the MXU is handed there."""
+    (its header to the first branch after it, the back edge), with the
+    weight pushes and products the MXU is handed there."""
     paths = listings(dump)
     bundles = []
     for line in open(paths[0]):
@@ -324,8 +326,8 @@ def count_bundles(dump: str) -> dict:
                             len(m.group(3)), line))
     head = min(at for at, mark, depth, _l in bundles
                if mark == "LB" and depth == 2)
-    back = max(at for at, _m, depth, line in bundles
-               if depth == 2 and "sbr.rel" in line)
+    back = min(at for at, _m, _d, line in bundles
+               if at > head and "sbr.rel" in line)
     wave = [line for at, _m, _d, line in bundles if head <= at <= back]
     return {"bundles": len(bundles), "wave": back - head + 1,
             "weight_pushes": sum(line.count("vmatpush") for line in wave),
@@ -341,7 +343,8 @@ def listings(dump: str) -> list:
 
 def bundles() -> int:
     path = sys.argv[2] if len(sys.argv) > 2 else ""
-    for which in bundle_variants(path):
+    for shape, which in ((s, w) for s in EXACT
+                         for w in bundle_variants(path, s)):
         with tempfile.TemporaryDirectory(prefix="window_bundles_") as dump:
             # the listing's flags before any the environment gives, so
             # the listing is that of the program those flags compile
@@ -351,16 +354,17 @@ def bundles() -> int:
                        TPU_LOG_DIR="disabled", LIBTPU_INIT_ARGS=flags)
             child = subprocess.run(
                 [sys.executable, os.path.abspath(__file__), "_compile",
-                 which, path], env=env, stdin=subprocess.DEVNULL,
+                 which, path, shape], env=env, stdin=subprocess.DEVNULL,
                 stdout=subprocess.DEVNULL, stderr=subprocess.PIPE,
                 text=True)
             # the compiler aborts AFTER writing the listing, so a
             # non-zero exit alone says nothing: no listing does
             if not listings(dump):
                 sys.stderr.write(child.stderr[-4000:])
-                raise SystemExit(f"{which}: the compile wrote no listing "
+                raise SystemExit(f"{which} at {shape}: the compile wrote "
+                                 f"no listing "
                                  f"(exit code {child.returncode})")
-            print(json.dumps({"kernel": which, "shape": "granite",
+            print(json.dumps({"kernel": which, "shape": shape,
                               "target": "v5e (described)",
                               **count_bundles(dump)}), flush=True)
     return 0
@@ -370,5 +374,5 @@ if __name__ == "__main__":
     if sys.argv[1:2] == ["bundles"]:
         sys.exit(bundles())
     if sys.argv[1:2] == ["_compile"]:
-        sys.exit(compile_for_v5e(sys.argv[2], sys.argv[3]))
+        sys.exit(compile_for_v5e(*sys.argv[2:5]))
     sys.exit(main())

@@ -6,6 +6,9 @@
   page boundaries, ragged batches, an inactive slot, aliased pages, 16
   and 32 heads, and a garbage page full of NaN that nothing past
   ``pos`` may bring into the output;
+- THE GROUPED HEADS' FLOAT32-ACCURATE FORM: three bfloat16 parts sum to
+  each float32 value bit for bit, and the window kernel's exact form
+  meets the plain gather within 1e-5 at groups of 4, 16 and 20;
 - NO ``max_seq`` COPY: the lowered kernel holds no array of
   ``slots x max_seq x heads x hd`` elements (the reference does);
 - THE COUNTER: ``kv_stats()["attn"]`` grows by the pages of live
@@ -153,6 +156,92 @@ def test_lowered_kernel_holds_no_max_seq_copy():
     assert whole not in _array_sizes(kernel.lower(*args).as_text())
     assert whole in _array_sizes(
         jax.jit(pa.reference).lower(*args).as_text())
+
+
+# -- the grouped heads' float32-accurate form: three exact bfloat16 parts ----
+
+def _spanning_floats(rng, n):
+    """``n`` float32 values of every sign and binary exponent from -102
+    to 127 with random significands, then the largest float32, the
+    smallest of those exponents, and both zeros."""
+    e = rng.integers(-102, 128, n)
+    x = np.ldexp(rng.uniform(1.0, 2.0, n), e) * rng.choice([-1.0, 1.0], n)
+    edges = [np.finfo(np.float32).max, -np.finfo(np.float32).max,
+             2.0 ** -102, -(2.0 ** -102), 0.0, -0.0]
+    return np.concatenate([x, edges]).astype(np.float32)
+
+
+def test_the_three_parts_are_bfloat16_and_sum_to_the_value_bit_for_bit():
+    x = _spanning_floats(np.random.default_rng(53), 4090).reshape(-1, 128)
+    parts = [np.asarray(t) for t in jax.jit(pa._parts)(jnp.asarray(x))]
+    for t in parts:
+        assert t.dtype == np.float32 and np.isfinite(t).all()
+        # a bfloat16 value: the MXU takes it as it is
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(t).astype(jnp.bfloat16)
+                       .astype(jnp.float32)), t)
+    hi, mid, lo = parts
+    got = (hi + mid) + lo
+    assert np.array_equal(got, x)
+    nonzero = x != 0
+    assert np.array_equal(got.view(np.int32)[nonzero],
+                          x.view(np.int32)[nonzero])
+    # each part below the one before it: eight bits, then eight more
+    big = np.abs(x) >= 2.0 ** -80
+    assert (np.abs(mid[big]) <= np.abs(hi[big]) * 2.0 ** -7).all()
+    assert (np.abs(lo[big]) <= np.abs(hi[big]) * 2.0 ** -15).all()
+
+
+def _exact_case(heads, kvh, hd, pps, pos, seed=0):
+    """Grouped pools whose dead block-table entries are page 0, full of
+    NaN, and whose rows past each slot's last position, on its last
+    page, are NaN too; the same pools with zeros there."""
+    r = np.random.default_rng(seed)
+    slots, pages = len(pos), len(pos) * pps + 1
+    q = _normal(r, (slots, heads, hd))
+    pk, pv = (_normal(r, (pages, PAGE * kvh, hd)) for _ in range(2))
+    bt = np.zeros((slots, pps), np.int32)
+    free = iter(r.permutation(np.arange(1, pages)))
+    for b, p in enumerate(pos):
+        for i in range(p // PAGE + 1):
+            bt[b, i] = next(free)
+        past = (p % PAGE + 1) * kvh
+        for pool in (pk, pv):
+            pool[bt[b, p // PAGE], past:] = np.nan
+    for pool in (pk, pv):
+        pool[0] = np.nan
+    clean = [np.nan_to_num(x) for x in (pk, pv)]
+    args = (q, pk, pv, bt, np.asarray(pos, np.int32))
+    return [jnp.asarray(a) for a in args], \
+        [jnp.asarray(a) for a in (q, *clean, bt, np.asarray(pos, np.int32))]
+
+
+@pytest.mark.parametrize("heads,kvh,wave_pages", [
+    (32, 8, 3), (32, 2, 10), (20, 1, 16)],
+    ids=["groups-of-4", "groups-of-16", "groups-of-20"])
+def test_split_form_matches_the_reference(heads, kvh, wave_pages,
+                                          monkeypatch):
+    """The float32-accurate form (three bfloat16 parts a float32 tile,
+    one MXU pass a part) against the plain gather in float32, within
+    1e-5: a slot that fills its table in several waves (the last one
+    partly dead), a one-page slot beside it, a slot whose last page is
+    partial with NaN past its position, and waves of 48, 160 and 256
+    rows a key/value head (one partial tile; a whole one and a partial
+    one; two whole ones)."""
+    hd, pps = 128, 40
+    pos = [pps * PAGE - 1, 0, 3 * PAGE + 6, 100]
+    args, clean = _exact_case(heads, kvh, hd, pps, pos)
+    monkeypatch.setattr(pa, "_WAVE_BYTES", wave_pages * PAGE * kvh * hd * 4)
+    assert pa.pages_per_wave(PAGE, kvh, hd, pps) == wave_pages
+    # traced here and now: a cached trace keeps the wave it was traced with
+    kernel = jax.jit(pa._window_call.__wrapped__,
+                     static_argnames=("page", "window", "interpret",
+                                      "scale", "exact"))
+    got = np.asarray(kernel(*args, page=PAGE, window=0, interpret=True,
+                            exact=True))
+    want = np.asarray(pa.reference(*clean, PAGE))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=0)
 
 
 # -- the kernel through the chip's own compiler, at the cells' widths --------
